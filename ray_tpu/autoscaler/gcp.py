@@ -339,7 +339,7 @@ class FakeGcloudTpuAPI(GcloudTpuAPI):
         for worker_index in range(hosts):
             env = dict(os.environ)
             env["PYTHONPATH"] = repo_root + os.pathsep + env.get("PYTHONPATH", "")
-            env.setdefault("JAX_PLATFORMS", "cpu")
+            env["JAX_PLATFORMS"] = "cpu"
             env["TPU_WORKER_ID"] = str(worker_index)
             # run the EXACT command the real path would ship over ssh,
             # substituting THIS interpreter for whatever remote python the

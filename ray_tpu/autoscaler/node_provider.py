@@ -208,7 +208,10 @@ class SubprocessNodeProvider(NodeProvider):
         for _ in range(count):
             resources = dict(node_type.resources)
             cpus = resources.pop("CPU", 1)
-            env = dict(_os.environ)
+            # a same-host child of this process is CPU by rule, whatever
+            # the inherited env says: one process holds a chip, and the
+            # parent (which touched jax in rt.init) is that process
+            env = {**_os.environ, "JAX_PLATFORMS": "cpu"}
             import uuid as _uuid
 
             pid = f"proc-{_uuid.uuid4().hex[:12]}"

@@ -94,7 +94,17 @@ class ObjectStore:
         self._shm = shm_store
         self._hbm_used = 0
         self._host_used = 0
-        self._hbm_budget = hbm_budget if hbm_budget is not None else cfg.object_store_hbm_bytes or _auto_hbm_budget()
+        # None = derive from the device's memory limit at the first
+        # device-array put (_maybe_spill). Building a store must not START
+        # a jax backend: agents and CPU-only drivers build one too, and on
+        # a TPU host the process that starts the backend takes the chip.
+        # It does IMPORT jax, here on the constructing thread: left to the
+        # first users, the reporter and a task thread import it at the same
+        # time and one of them sees a partially initialized module.
+        import jax  # noqa: F401
+        self._hbm_budget: Optional[int] = (
+            hbm_budget if hbm_budget is not None else cfg.object_store_hbm_bytes or None
+        )
         self._host_budget = host_budget if host_budget is not None else cfg.object_store_host_bytes
         self._spill_dir = cfg.spill_dir
         # bounded spill tier (overload survival, ISSUE 9): bytes currently
@@ -329,12 +339,18 @@ class ObjectStore:
     #: thread must FREE, not SPILL — plasma's evict-after-refcount ordering
     pressure_callback = None
 
+    def _hbm_over_locked(self) -> int:
+        """Device-tier bytes over budget; resolves the automatic budget the
+        first time a device array is actually held."""
+        if not self._hbm_used:
+            return 0
+        if self._hbm_budget is None:
+            self._hbm_budget = _auto_hbm_budget()
+        return max(0, self._hbm_used - self._hbm_budget)
+
     def _maybe_spill(self) -> None:
         with self._lock:
-            over = (
-                self._hbm_used > self._hbm_budget
-                or self._host_used > self._host_budget
-            )
+            over = self._hbm_over_locked() or self._host_used > self._host_budget
         if over and self.pressure_callback is not None:
             try:
                 # apply pending out-of-scope deletions before copying
@@ -345,8 +361,9 @@ class ObjectStore:
             except Exception:  # noqa: BLE001 — pressure relief is best-effort
                 pass
         with self._lock:
-            if self._hbm_used > self._hbm_budget:
-                self._spill_device_locked(self._hbm_used - self._hbm_budget)
+            hbm_over = self._hbm_over_locked()
+            if hbm_over:
+                self._spill_device_locked(hbm_over)
             if self._host_used > self._host_budget:
                 self._spill_host_locked(self._host_used - self._host_budget)
 
@@ -481,15 +498,16 @@ class ObjectStore:
 
 
 def _auto_hbm_budget() -> int:
-    cfg = get_config()
-    try:
-        import jax
+    """``object_store_hbm_fraction`` of the first local device's memory
+    limit. Called once an accelerator array is in the store, so the backend
+    is already up in this process and a failure here is a real one."""
+    import jax
 
-        dev = jax.devices()[0]
-        stats = dev.memory_stats() or {}
-        limit = stats.get("bytes_limit")
-        if limit:
-            return int(limit * cfg.object_store_hbm_fraction)
-    except Exception:
-        pass
-    return 4 * 1024**3
+    dev = jax.local_devices()[0]
+    limit = (dev.memory_stats() or {}).get("bytes_limit")
+    if not limit:
+        raise RuntimeError(
+            f"{dev.device_kind!r} reports no memory limit to size the object "
+            "store's device tier from; set Config.object_store_hbm_bytes"
+        )
+    return int(limit * get_config().object_store_hbm_fraction)

@@ -8,12 +8,12 @@ ring-buffer time series the UI graphs.
 Transport: agents piggyback samples on the existing ``resource_report``
 control message (no extra channel, no extra socket); the head node samples
 itself on a local thread.  Sampling is /proc-based (no psutil in the
-image); TPU memory comes from jax ``memory_stats`` where the backend
-serves it cheaply.
+image); TPU memory comes from jax ``memory_stats``.
 """
 
 from __future__ import annotations
 
+import logging
 import os
 import threading
 import time
@@ -27,7 +27,8 @@ class SystemSampler:
 
     def __init__(self):
         self._last_cpu: Optional[tuple] = None
-        self._tpu_ok: Optional[bool] = None  # None = not probed yet
+        # None = not probed yet; False = no accelerator to sample
+        self._tpu_dev = None
 
     def _cpu_times(self):
         try:
@@ -54,31 +55,30 @@ class SystemSampler:
             pass
         return total, avail
 
-    def _tpu_memory(self):
-        """(bytes_in_use, bytes_limit) or None.  Probed once: backends whose
-        memory_stats round-trips a network tunnel are disabled (the sampler
-        runs on a tight tick)."""
-        if self._tpu_ok is False:
-            return None
-        try:
-            import jax
+    def _probe_tpu(self):
+        """This process's first accelerator, or False when its jax backend
+        is the CPU or did not start. The sampler runs on a report tick that
+        must keep going, so a failed start is logged here, once."""
+        import jax
 
-            dev = jax.devices()[0]  # backend init happens HERE, untimed
-            if dev.platform == "cpu":
-                self._tpu_ok = False
-                return None
-            # time only the stats call itself: >50ms means it crosses a
-            # network tunnel — too slow to poll on the report tick
-            t0 = time.perf_counter()
-            stats = dev.memory_stats() or {}
-            if self._tpu_ok is None:
-                self._tpu_ok = (time.perf_counter() - t0) < 0.05
-                if not self._tpu_ok:
-                    return None
-            return int(stats.get("bytes_in_use", 0)), int(stats.get("bytes_limit", 0))
-        except Exception:  # noqa: BLE001 — no device / unsupported backend
-            self._tpu_ok = False
+        try:
+            dev = jax.local_devices()[0]
+        except RuntimeError:
+            logging.getLogger(__name__).exception(
+                "jax backend did not start; TPU memory will not be sampled"
+            )
+            return False
+        return dev if dev.platform != "cpu" else False
+
+    def _tpu_memory(self):
+        """(bytes_in_use, bytes_limit) of this process's first accelerator,
+        or None when there is none to sample (probed once)."""
+        if self._tpu_dev is None:
+            self._tpu_dev = self._probe_tpu()
+        if self._tpu_dev is False:
             return None
+        stats = self._tpu_dev.memory_stats() or {}
+        return int(stats.get("bytes_in_use", 0)), int(stats.get("bytes_limit", 0))
 
     def sample(self) -> dict:
         out: dict = {"ts": time.time()}

@@ -41,3 +41,8 @@ class JittedStep:
 
     def __call__(self, *args):
         return self._fn(*args)
+
+    def lower(self, *args):
+        """The wrapped jit's ``lower`` — so a sharded step can be inspected
+        and AOT-compiled the same way as the mesh-less ``jax.jit`` one."""
+        return self._fn.lower(*args)

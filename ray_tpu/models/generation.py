@@ -37,6 +37,7 @@ from ray_tpu.models.transformer import (
     gather_paged_kv,
     scatter_paged_kv,
 )
+from ray_tpu.ops import backend
 
 KVCache = Dict[str, jax.Array]
 
@@ -104,7 +105,8 @@ def forward_with_cache(
 
     ``use_decode_kernel``: route single-token steps through the Pallas
     decode-attention kernel (``ray_tpu.ops.decode_attention``); default
-    auto — on for TPU, off elsewhere (the plain-XLA grouped einsum).
+    auto — ``ops.backend.on_tpu()`` (off the chip: the plain-XLA grouped
+    einsum).
 
     ``use_prefill_kernel``: ONLY valid when every row's positions start at
     0 (the :func:`prefill` contract) — then attention sees just this
@@ -131,9 +133,8 @@ def forward_with_cache(
     kv_pos = jnp.arange(S)
     # key s visible to query t iff s <= position(t): causal over the cache
     vis = kv_pos[None, None, None, :] <= positions[:, None, :, None]  # [B,1,T,S]
-    on_tpu = jax.default_backend() == "tpu"
     if use_decode_kernel is None:
-        use_decode_kernel = on_tpu
+        use_decode_kernel = backend.on_tpu()
     decode_kernel = use_decode_kernel and T == 1
     prefill_kernel = bool(use_prefill_kernel) and T > 1
 
@@ -215,11 +216,13 @@ def paged_forward_with_cache(
 
     Writes this call's K/V into the pool through the block tables and
     attends over every cached position up to ``positions``. Single-token
-    calls route through the Pallas paged decode kernel on TPU (the block
-    table rides scalar prefetch — pages stream from HBM with no gather
-    copy); everywhere else the pool is gathered to a dense view and the
-    attention lines are IDENTICAL to the dense path's, which is what makes
-    paged serving byte-equal to the dense cache under ``JAX_PLATFORMS=cpu``.
+    calls route through the Pallas paged decode kernel when
+    ``use_decode_kernel`` (default: ``ops.backend.on_tpu()`` — the ONE
+    select for this kernel; the op itself has none) — the block table rides
+    scalar prefetch, pages stream from HBM with no gather copy. Otherwise
+    the pool is gathered to a dense view and the attention lines are
+    IDENTICAL to the dense path's, which is what makes paged serving
+    byte-equal to the dense cache under ``JAX_PLATFORMS=cpu``.
 
     ``valid`` masks bucket-padded tail tokens out of the cache write (their
     K/V routes to the garbage page 0); their logits still compute and are
@@ -241,7 +244,7 @@ def paged_forward_with_cache(
     kv_pos = jnp.arange(cap)
     vis = kv_pos[None, None, None, :] <= positions[:, None, :, None]  # [B,1,T,cap]
     if use_decode_kernel is None:
-        use_decode_kernel = jax.default_backend() == "tpu"
+        use_decode_kernel = backend.on_tpu()
     decode_kernel = use_decode_kernel and T == 1
 
     def layer_fn(x, layer_kc_vc):
@@ -316,7 +319,7 @@ def paged_decode_step(
 def _single_device_params(params) -> bool:
     """True iff on TPU and the embed param is a CONCRETE single-device
     array (tracers and multi-device shardings return False)."""
-    if jax.default_backend() != "tpu":
+    if not backend.on_tpu():
         return False
     emb = params.get("embed") if isinstance(params, dict) else None
     if not isinstance(emb, jax.Array) or isinstance(emb, jax.core.Tracer):

@@ -26,6 +26,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ray_tpu.models.common import JittedStep, dense_init
 from ray_tpu.models.common import patchify as _patchify
 from ray_tpu.models.transformer import _dense_ffn, _rms_norm
+from ray_tpu.ops import backend
 from ray_tpu.ops.attention import flash_attention, mha
 
 
@@ -136,7 +137,7 @@ def vit_forward(
     custom call) — sharded runs take the einsum attention path.
     """
     use_flash = cfg.attention == "flash" or (
-        cfg.attention == "auto" and jax.default_backend() == "tpu" and act_spec is None
+        cfg.attention == "auto" and backend.on_tpu() and act_spec is None
     )
     x = patchify(cfg, images.astype(cfg.dtype)) @ params["patch_embed"].astype(cfg.dtype)
     B = x.shape[0]
@@ -190,6 +191,11 @@ def make_vit_train_step(
     act_spec = None
     dp_ax = None
     if mesh is not None:
+        if cfg.attention == "flash":
+            raise ValueError(
+                'attention="flash" cannot run under a mesh (GSPMD cannot '
+                'partition a Mosaic kernel); use "auto" or "dense"'
+            )
         dp_ax = dp if dp in mesh.axis_names else None
         act_spec = P(dp_ax, None, None)
 

@@ -30,7 +30,9 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from ray_tpu.ops._compat import pltpu
+from jax.experimental.pallas import tpu as pltpu
+
+from ray_tpu.ops import backend
 
 NEG_INF = -1e30
 _LANES = 128  # m/l scratch is lane-replicated to keep stores 2-D tileable
@@ -389,28 +391,14 @@ def _reference_attention(q, k, v, sm_scale: float, causal: bool):
 
 
 def default_blocks(head_dim: int) -> tuple:
-    """Measured on a real v5e (scan-amortized, ray_tpu/scripts/kernel_bench.py):
+    """(block_q, block_k) for the flash kernels: (512, 1024) at every shape.
 
-    fwd-only (ms per call):
-
-    ==========  =========  =========  =========
-    shape       128x128    256x512    512x1024
-    ==========  =========  =========  =========
-    32k, D=64   1201 ms    1166 ms    **820 ms**
-    8k,  D=64    316 ms     279 ms    **245 ms**
-    8k,  D=128  **103 ms**  211 ms     264 ms
-    ==========  =========  =========  =========
-
-    fwd+bwd (the 602M-param train step, T=2048/D=128, bench.py model_mfu):
-    512x1024 reaches **53.4% MFU** vs 34.4% with 128x128 — the backward
-    kernels amortize scratch traffic over big tiles and dominate the step.
-
-    Default: (512, 1024) — training is the flagship path and wins there at
-    every measured shape. The one measured exception (fwd-ONLY at
-    T>=8k/D>=128, where 128x128 is ~2.6x faster) is an inference-shaped
-    workload; pass explicit block sizes there.
+    The 602M train step (T=2048, D=128) compiles and runs with these on a
+    v5e (``chip_smoke.py``). No per-shape block timing taken from a compiled
+    kernel is on record — ROADMAP S7 owns measuring one from a device trace
+    before this grows a per-shape table.
     """
-    del head_dim  # shape-independent today; kept for future dispatch
+    del head_dim  # shape-independent today
     return (512, 1024)
 
 
@@ -425,8 +413,7 @@ def flash_attention(
 ):
     """Blockwise flash attention. q,k,v: [B, H, T, D].
 
-    Block sizes default per head_dim from the measured table in
-    :func:`default_blocks`.
+    Block sizes default to :func:`default_blocks`.
 
     Thin wrapper over :func:`flash_attention_with_lse` (an unused lse
     output costs a zero cotangent, which folds away in the backward).
@@ -505,7 +492,7 @@ _flash_with_lse_cv.defvjp(_fwd_lse, _bwd_lse)
 
 
 def _use_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    return not backend.on_tpu()
 
 
 def mha(q, k, v, *, causal: bool = True, sm_scale: Optional[float] = None):

@@ -19,11 +19,11 @@ bandwidth. The kernel:
 :func:`paged_decode_attention` is the block-pool variant (PagedAttention,
 Kwon et al. 2023): K/V live in a shared pool of fixed-size pages
 ``[num_blocks, block_size, Hkv, D]`` and each sequence names its pages in
-an ``int32[B, max_blocks]`` block table. On TPU the table rides Pallas
-scalar prefetch (``PrefetchScalarGridSpec``) so the BlockSpec index maps
-gather pages straight out of HBM — no materialized per-sequence cache copy.
-Off TPU a ``jnp.take`` gather reduces to the dense math, which is what
-tier-1 exercises under ``JAX_PLATFORMS=cpu``.
+an ``int32[B, max_blocks]`` block table. The table rides Pallas scalar
+prefetch (``PrefetchScalarGridSpec``) so the BlockSpec index maps gather
+pages straight out of HBM — no materialized per-sequence cache copy.
+``use_kernel=False`` is the plain-XLA reference (a ``jnp.take`` gather that
+reduces to the dense math) the kernel is checked against.
 
 No backward pass: decode is inference-only. Non-TPU backends run in
 interpret mode (tests exercise the same code path on CPU).
@@ -37,7 +37,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from ray_tpu.ops._compat import pltpu
+from jax.experimental.pallas import tpu as pltpu
 
 from ray_tpu.ops.attention import NEG_INF, _LANES, _use_interpret
 
@@ -117,12 +117,12 @@ def decode_attention(
 
     # Prefer shrinking the block to a divisor of S over padding: padding
     # copies the ENTIRE cache (the op's whole byte budget) just to round the
-    # last block. Only fall back to a padded copy when every divisor is tiny.
+    # last block. A block narrower than S must be a multiple of 128 — it is
+    # the sublane dim of the K/V tiles AND the lane dim of the bias row —
+    # or Mosaic refuses the BlockSpec; no such divisor means pad.
     bs = min(block_s, S)
     if S % bs:
-        d = next((d for d in range(bs, 0, -1) if S % d == 0), 1)
-        if d >= 128:
-            bs = d
+        bs = next((d for d in range(bs - bs % _LANES, 0, -_LANES) if S % d == 0), bs)
     pad_s = (-S) % bs
     if pad_s:
         k_cache = jnp.pad(k_cache, ((0, 0), (0, 0), (0, pad_s), (0, 0)))
@@ -160,19 +160,23 @@ def _paged_decode_kernel(
     q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
     *, sm_scale: float, block_size: int,
 ):
-    """Grid (B, Hkv, M): M innermost walks the sequence's logical blocks.
+    """Grid (B, M): M innermost walks the sequence's logical blocks.
 
-    The same online-softmax state machine as :func:`_decode_kernel`; the
-    difference is purely WHERE K/V come from — the BlockSpec index maps
-    read ``tables_ref`` (scalar prefetch) to stream physical pages, so
-    q_ref/k_ref/v_ref arrive here exactly as in the dense kernel. Validity
-    is derived in-kernel from ``lengths_ref`` instead of a bias input, and
-    logical blocks wholly past the valid prefix skip their FLOPs.
+    One grid step holds one whole physical page: k_ref/v_ref are
+    ``[block_size, Hkv, D]`` (the page axis squeezed by the BlockSpec, whose
+    index map reads ``tables_ref`` to pick the page), q_ref/o_ref are
+    ``[Hkv, rep_p, D]`` and the online-softmax scratch carries a leading
+    ``Hkv`` axis. The KV heads are walked inside the kernel — a block that
+    squeezed the second-minor ``Hkv`` axis of the pool is not a shape Mosaic
+    can tile. Per head the state machine is :func:`_decode_kernel`'s;
+    validity is derived in-kernel from ``lengths_ref`` instead of a bias
+    input, and logical blocks wholly past the valid prefix skip their FLOPs.
     """
     bi = pl.program_id(0)
-    si = pl.program_id(2)
-    num_s = pl.num_programs(2)
+    si = pl.program_id(1)
+    num_s = pl.num_programs(1)
     length = lengths_ref[bi]
+    n_kv = q_ref.shape[0]
 
     @pl.when(si == 0)
     def _init():
@@ -182,40 +186,42 @@ def _paged_decode_kernel(
 
     @pl.when(si * block_size < length)
     def _accum():
-        q = q_ref[...].astype(jnp.float32) * sm_scale
-        k = k_ref[...].astype(jnp.float32)
-        v = v_ref[...].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
         pos = si * block_size + jax.lax.broadcasted_iota(jnp.int32, (1, block_size), 1)
-        s = s + jnp.where(pos < length, 0.0, NEG_INF)  # [rep_p, block_size]
+        bias = jnp.where(pos < length, 0.0, NEG_INF)  # [1, block_size]
+        for g in range(n_kv):
+            q = q_ref[g].astype(jnp.float32) * sm_scale
+            k = k_ref[:, g, :].astype(jnp.float32)
+            v = v_ref[:, g, :].astype(jnp.float32)
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            )
+            s = s + bias  # [rep_p, block_size]
 
-        m_prev = m_scr[:, :1]
-        l_prev = l_scr[:, :1]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-        alpha = jnp.exp(jnp.where(m_prev == NEG_INF, NEG_INF, m_prev - m_new))
-        p = jnp.exp(s - m_new)
-        l_new = l_prev * alpha + p.sum(axis=-1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
+            m_prev = m_scr[g, :, :1]
+            l_prev = l_scr[g, :, :1]
+            m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+            alpha = jnp.exp(jnp.where(m_prev == NEG_INF, NEG_INF, m_prev - m_new))
+            p = jnp.exp(s - m_new)
+            l_new = l_prev * alpha + p.sum(axis=-1, keepdims=True)
+            acc_scr[g] = acc_scr[g] * alpha + jax.lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            )
+            m_scr[g] = jnp.broadcast_to(m_new, m_scr.shape[1:])
+            l_scr[g] = jnp.broadcast_to(l_new, l_scr.shape[1:])
 
     @pl.when(si == num_s - 1)
     def _final():
-        l = l_scr[:, :1]
-        empty = m_scr[:, :1] <= NEG_INF * 0.5  # lengths[b] == 0: emit zeros
+        l = l_scr[:, :, :1]
+        empty = m_scr[:, :, :1] <= NEG_INF * 0.5  # lengths[b] == 0: emit zeros
         out = jnp.where(empty, 0.0, acc_scr[...] / jnp.where(l == 0, 1.0, l))
         o_ref[...] = out.astype(o_ref.dtype)
 
 
 def _paged_decode_xla(qg, k_pages, v_pages, block_tables, lengths, scale):
-    """``jnp.take`` fallback: gather each sequence's pages into a dense
+    """Plain-XLA reference: gather each sequence's pages into a dense
     [B, Hkv, M*bs, D] view and run the masked grouped einsum — the exact
-    math of the dense path, so tier-1 (``JAX_PLATFORMS=cpu``) checks paged
-    serving byte-for-byte against the dense cache."""
+    math of the dense path; the kernel is compared against it (tier-1 in
+    interpret mode, ``chip_smoke.py`` compiled)."""
     g = jnp.take(k_pages, block_tables, axis=0)  # [B, M, bs, Hkv, D]
     B, M, bs, Hkv, D = g.shape
     k = jnp.transpose(g, (0, 3, 1, 2, 4)).reshape(B, Hkv, M * bs, D)
@@ -241,15 +247,17 @@ def paged_decode_attention(
     lengths: jax.Array,       # [B] int32: valid cache entries per sequence
     *,
     sm_scale: Optional[float] = None,
-    use_kernel: Optional[bool] = None,
+    use_kernel: bool = True,
 ) -> jax.Array:
     """Decode attention over a paged KV pool; returns [B, H, D].
 
     Table entries past ``ceil(lengths[b] / block_size)`` may point anywhere
     valid (the engine points them at the reserved garbage page 0) — they are
-    masked out, never normalized in. ``use_kernel`` default: Pallas on TPU,
-    gather fallback elsewhere (forcing it on runs the kernel in interpret
-    mode, which is how the kernel itself is tested on CPU).
+    masked out, never normalized in. ``use_kernel=False`` is the plain-XLA
+    gather reference. There is no auto-select here: callers that choose by
+    platform (``models/generation.py``) ask ``ops.backend.on_tpu()`` once
+    and pass the answer; off the chip the kernel runs in interpret mode,
+    which is how the kernel itself is tested on CPU.
     """
     import math
 
@@ -258,8 +266,6 @@ def paged_decode_attention(
     M = block_tables.shape[1]
     n_rep = H // Hkv
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(D)
-    if use_kernel is None:
-        use_kernel = jax.default_backend() == "tpu"
 
     qg = q.reshape(B, Hkv, n_rep, D)
     if not use_kernel:
@@ -269,22 +275,21 @@ def paged_decode_attention(
     rep_p = -(-n_rep // _MIN_REP) * _MIN_REP
     if rep_p != n_rep:
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, rep_p - n_rep), (0, 0)))
-    grid = (B, Hkv, M)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,  # block_tables, lengths — usable in index maps
-        grid=grid,
+        grid=(B, M),
         in_specs=[
-            pl.BlockSpec((None, None, rep_p, D), lambda b, g, s, bt, ln: (b, g, 0, 0)),
+            pl.BlockSpec((None, Hkv, rep_p, D), lambda b, s, bt, ln: (b, 0, 0, 0)),
             # the paged gather: logical block s of sequence b streams from
-            # physical page bt[b, s] — one DMA per (group, block), no copy
-            pl.BlockSpec((None, bs, None, D), lambda b, g, s, bt, ln: (bt[b, s], 0, g, 0)),
-            pl.BlockSpec((None, bs, None, D), lambda b, g, s, bt, ln: (bt[b, s], 0, g, 0)),
+            # physical page bt[b, s] — one DMA per page, no copy
+            pl.BlockSpec((None, bs, Hkv, D), lambda b, s, bt, ln: (bt[b, s], 0, 0, 0)),
+            pl.BlockSpec((None, bs, Hkv, D), lambda b, s, bt, ln: (bt[b, s], 0, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((None, None, rep_p, D), lambda b, g, s, bt, ln: (b, g, 0, 0)),
+        out_specs=pl.BlockSpec((None, Hkv, rep_p, D), lambda b, s, bt, ln: (b, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((rep_p, _LANES), jnp.float32),
-            pltpu.VMEM((rep_p, _LANES), jnp.float32),
-            pltpu.VMEM((rep_p, D), jnp.float32),
+            pltpu.VMEM((Hkv, rep_p, _LANES), jnp.float32),
+            pltpu.VMEM((Hkv, rep_p, _LANES), jnp.float32),
+            pltpu.VMEM((Hkv, rep_p, D), jnp.float32),
         ],
     )
     out = pl.pallas_call(
@@ -292,7 +297,7 @@ def paged_decode_attention(
         out_shape=jax.ShapeDtypeStruct((B, Hkv, rep_p, D), q.dtype),
         grid_spec=grid_spec,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=_use_interpret(),
     )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32), qg, k_pages, v_pages)

@@ -25,7 +25,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from ray_tpu.ops._compat import pltpu
+from jax.experimental.pallas import tpu as pltpu
 
 from ray_tpu.ops.attention import _use_interpret
 

@@ -1,17 +1,8 @@
-"""jax version-compat shims shared by the parallel package.
+"""Two workarounds the parallel package still needs on jax 0.9.
 
-One module owns every rename this package straddles, so the next jax API
-move is a one-file fix:
-
-  * ``shard_map`` — promoted from ``jax.experimental.shard_map`` to
-    ``jax.shard_map``.
-  * ``lax.axis_size`` — absent before jax 0.5; ``lax.psum(1, axis)`` is the
-    classic spelling and constant-folds to the mesh axis size.
-  * the shard_map replication-checking kwarg — renamed
-    ``check_rep`` -> ``check_vma``.
-  * ``with_sharding_constraint`` with a bare ``PartitionSpec`` — newer jax
-    raises unless a mesh context is ambient; ``constraint_sharding`` binds
-    the spec to a concrete ``NamedSharding`` so call sites work either way.
+  * ``with_sharding_constraint`` with a bare ``PartitionSpec`` raises unless
+    a mesh context is ambient; ``constraint_sharding`` binds the spec to a
+    concrete ``NamedSharding`` so call sites work either way.
   * ``jnp.roll`` on sharded operands — the SPMD partitioner miscompiles a
     rolled array consumed by a gather (garbage values, NaN losses);
     ``spmd_roll`` lowers to a mod-iota gather that partitions correctly.
@@ -20,33 +11,13 @@ move is a one-file fix:
 from __future__ import annotations
 
 import jax.numpy as jnp
-from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec
-
-try:
-    from jax import shard_map
-except ImportError:  # older jax: pre-promotion location
-    from jax.experimental.shard_map import shard_map  # noqa: F401
-
-
-def axis_size(axis_name: str):
-    fn = getattr(lax, "axis_size", None)
-    return fn(axis_name) if fn is not None else lax.psum(1, axis_name)
-
-
-def shard_map_unchecked(fn, mesh, in_specs, out_specs):
-    """shard_map with replication/vma checking off — the kwarg was renamed
-    check_rep -> check_vma across jax versions."""
-    try:
-        return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False)
-    except TypeError:
-        return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False)
 
 
 def constraint_sharding(mesh, spec):
     """Bind a ``PartitionSpec`` to ``mesh`` for ``with_sharding_constraint``.
 
-    Newer jax refuses a bare spec unless a mesh context manager is active at
+    jax refuses a bare spec unless a mesh context manager is active at
     the *trace* site; a ``NamedSharding`` works with or without one. Passes
     through unchanged when there is no mesh (or no spec) to bind."""
     if mesh is None or spec is None or not isinstance(spec, PartitionSpec):
@@ -57,11 +28,11 @@ def constraint_sharding(mesh, spec):
 def spmd_roll(x, shift: int, axis: int):
     """``jnp.roll`` that survives the SPMD partitioner.
 
-    On current jax/XLA a ``jnp.roll`` whose output feeds a gather
+    On jax 0.9 / XLA a ``jnp.roll`` whose output feeds a gather
     (``take_along_axis``) returns garbage when the operands are sharded —
     the partitioner mis-propagates the roll's halo exchange. An explicit
     mod-iota gather expresses the same permutation with a replicated index
-    vector, which partitions correctly on every version we straddle."""
+    vector, which partitions correctly."""
     axis = axis % x.ndim
     n = x.shape[axis]
     idx = (jnp.arange(n) - shift) % n

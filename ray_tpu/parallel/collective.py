@@ -24,7 +24,6 @@ from typing import Any, Dict, List, Optional
 import numpy as np
 from jax import lax
 
-from ray_tpu.parallel._compat import axis_size as _axis_size
 
 # --------------------------------------------------------------------------
 # layer 1: SPMD functional collectives (use inside shard_map)
@@ -75,7 +74,7 @@ def send_recv(x, axis_name: str, *, shift: int = 1):
     """Neighbor exchange on a ring (send to rank+shift, recv from
     rank-shift) — the building block of ring attention and pipeline
     parallelism (reference send/recv: collective.py:531,594)."""
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     perm = [(i, (i + shift) % n) for i in range(n)]
     return lax.ppermute(x, axis_name, perm)
 
@@ -85,7 +84,7 @@ def axis_index(axis_name: str):
 
 
 def axis_size(axis_name: str):
-    return _axis_size(axis_name)
+    return lax.axis_size(axis_name)
 
 
 def barrier(axis_name: str):

@@ -18,10 +18,9 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ray_tpu.parallel._compat import axis_size as _axis_size, shard_map_unchecked as _shard_map_unchecked
 from ray_tpu.parallel.ring import _to_varying
 
 
@@ -40,7 +39,7 @@ def pipeline_apply(
     Returns [M, ...] outputs (replicated — produced on the last stage and
     psum-broadcast).
     """
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     stage = lax.axis_index(axis_name)
     M = microbatches.shape[0]
     x_shape = microbatches.shape[1:]
@@ -92,9 +91,6 @@ def pipeline_sharded(
         return pipeline_apply(stage_fn, params, mb, axis_name)
 
     param_specs = jax.tree.map(lambda _: P(axis_name), stacked_params)
-    # checking off: old-jax replication inference trips over the lax.cond
-    # branches inside pipeline_apply (its own error message suggests
-    # check_rep=False); new jax handles the vma typing via _to_varying
-    return _shard_map_unchecked(
-        inner, mesh, (param_specs, P()), P()
+    return shard_map(
+        inner, mesh=mesh, in_specs=(param_specs, P()), out_specs=P()
     )(stacked_params, microbatches)

@@ -24,23 +24,15 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-from ray_tpu.parallel._compat import axis_size as _axis_size, shard_map_unchecked as _shard_map_unchecked
 
 NEG_INF = -1e30
 
 
 def _to_varying(x, axis_name: str):
-    """Mark an array as device-varying over the axis (shard_map vma typing;
-    no-op on jax versions without pcast)."""
-    pcast = getattr(lax, "pcast", None)
-    if pcast is None:
-        return x
-    try:
-        return pcast(x, (axis_name,), to="varying")
-    except TypeError:
-        return pcast(x, (axis_name,))
+    """Mark an array as device-varying over the axis (shard_map vma typing)."""
+    return lax.pcast(x, (axis_name,), to="varying")
 
 
 def ring_attention(
@@ -60,7 +52,7 @@ def ring_attention(
     (earlier shard), causally (the diagonal shard), or not at all (later
     shard) — picked per step with ``lax.switch``.
     """
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     my_block = lax.axis_index(axis_name)
     B, H, Tq, D = q.shape
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(D)
@@ -127,7 +119,9 @@ def ring_attention_sharded(
     )
     # check_vma=False: pallas_call out_shapes carry no vma annotation, and
     # the kernel outputs are trivially device-varying over the shard axis
-    return _shard_map_unchecked(fn, mesh, (spec, spec, spec), spec)(q, k, v)
+    return shard_map(
+        fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec, check_vma=False
+    )(q, k, v)
 
 
 # --------------------------------------------------------------------------
@@ -155,4 +149,6 @@ def ulysses_attention(q, k, v, axis_name: str, *, causal: bool = True, sm_scale:
 def ulysses_attention_sharded(q, k, v, mesh: Mesh, axis_name: str = "sp", *, causal: bool = True, sm_scale=None):
     spec = P(None, None, axis_name, None)
     fn = functools.partial(ulysses_attention, axis_name=axis_name, causal=causal, sm_scale=sm_scale)
-    return _shard_map_unchecked(fn, mesh, (spec, spec, spec), spec)(q, k, v)
+    return shard_map(
+        fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec, check_vma=False
+    )(q, k, v)

@@ -32,8 +32,9 @@ def main() -> None:
     parser.add_argument("--shm", default="")
     args = parser.parse_args()
 
-    # Workers never touch the TPU — keep jax off the device if imported.
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    # Workers never touch the TPU (the process that spawned this one may
+    # hold it) — keep jax off the device if imported.
+    os.environ["JAX_PLATFORMS"] = "cpu"
 
     # chaos: a RAY_TPU_FAILPOINTS spec exported on the driver (spawn passes
     # the environment through) arms the same failpoints in this worker

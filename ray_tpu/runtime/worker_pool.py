@@ -167,10 +167,10 @@ class ProcessWorkerPool:
             elif action is not None:
                 raise RuntimeError(f"failpoint worker_pool.spawn: {action}")
         # Hand the child the driver's full sys.path and start it with -S:
-        # site processing re-runs any sitecustomize, which on TPU hosts can
-        # initialize a jax/PJRT client — seconds of CPU burned per worker
-        # and (on small hosts) stolen from the driver. The explicit path
-        # covers site-packages and the repo, so imports still resolve.
+        # site processing (.pth files, any sitecustomize) is pure spawn
+        # latency for a worker — 10-25 ms per interpreter start on this
+        # installation's host CPU. The explicit path covers site-packages
+        # and the repo, so imports still resolve.
         import ray_tpu
 
         pkg_parent = os.path.dirname(os.path.dirname(os.path.abspath(ray_tpu.__file__)))
@@ -185,6 +185,8 @@ class ProcessWorkerPool:
                 + (["--shm", self._shm_name] if self._shm_name else []),
                 env={
                     **os.environ,
+                    # a chip belongs to one process: the driver/agent that
+                    # spawned this worker may hold it, so a worker never may
                     "JAX_PLATFORMS": "cpu",
                     "PYTHONPATH": pythonpath,
                     # pipes are block-buffered; prints must reach the driver live

@@ -19,16 +19,39 @@ import threading
 import time
 
 
+def serving_config():
+    """The serving-class decoder this script (and ``chip_smoke.py``) runs:
+    ~284M params (GPT-2-medium scale, tied embeddings), bf16, GQA 16q/8kv —
+    shapes that tile the MXU."""
+    import jax.numpy as jnp
+
+    from ray_tpu.models import TransformerConfig
+
+    return TransformerConfig(
+        vocab_size=32000, d_model=1024, n_layers=16, n_heads=16, n_kv_heads=8,
+        d_ff=4096, max_seq_len=1024, attention="dense", dtype=jnp.bfloat16,
+    )
+
+
 def main(out_path: str | None = None) -> dict:
     import jax
     import jax.numpy as jnp
 
     from ray_tpu.models import TransformerConfig, init_params
+    from ray_tpu.ops import backend
     from ray_tpu.serve.llm import LLMEngine
 
     import os
 
-    if os.environ.get("RAY_TPU_LLM_BENCH_TINY"):
+    backend.use_compile_cache()
+    dev = jax.devices()[0]
+    tiny = bool(os.environ.get("RAY_TPU_LLM_BENCH_TINY"))
+    if not tiny and not backend.on_tpu():
+        # a tokens/s taken on the host CPU is not this row's metric
+        raise SystemExit(
+            f"llm_bench measures the engine on a TPU; jax reports {dev.platform!r}"
+        )
+    if tiny:
         # in-suite smoke: exercises the same waves/warmup/accounting paths
         cfg = TransformerConfig(
             vocab_size=97, d_model=32, n_layers=2, n_heads=4, n_kv_heads=2,
@@ -36,12 +59,7 @@ def main(out_path: str | None = None) -> dict:
         )
         B, new_tokens, prompt_len, seq_cap = 2, 4, 3, 128
     else:
-        # serving-class decoder: ~284M params (GPT-2-medium scale, tied
-        # embeddings), bf16, GQA 16q/8kv — shapes that tile the MXU
-        cfg = TransformerConfig(
-            vocab_size=32000, d_model=1024, n_layers=16, n_heads=16, n_kv_heads=8,
-            d_ff=4096, max_seq_len=1024, attention="dense", dtype=jnp.bfloat16,
-        )
+        cfg = serving_config()
         B, new_tokens, prompt_len, seq_cap = 8, 128, 64, 1024
     params = init_params(cfg, jax.random.key(0))
     n_params = sum(x.size for x in jax.tree.leaves(params))
@@ -101,7 +119,8 @@ def main(out_path: str | None = None) -> dict:
             "waves": waves,
             "total_tokens": total,
             "wall_s": round(dt, 2),
-            "device": jax.devices()[0].device_kind,
+            "platform": dev.platform,
+            "device": dev.device_kind,
         },
     }
     print(json.dumps(result))

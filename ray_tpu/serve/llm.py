@@ -69,6 +69,7 @@ from ray_tpu.models.generation import (
 from ray_tpu.models.transformer import TransformerConfig
 from ray_tpu.observability import metric_defs
 from ray_tpu.observability.sketch import LatencySketch
+from ray_tpu.ops import backend
 from ray_tpu.runtime import admission
 from ray_tpu.runtime.context import (
     current_deadline_ts,
@@ -304,7 +305,7 @@ class LLMEngine:
             # (ray_tpu.models.transformer.param_specs), the KV cache's head
             # axis over tp when divisible; GSPMD partitions the einsum
             # attention, so decode collectives ride ICI. The Pallas decode
-            # kernel is bypassed (it would need a shard_map wrapper).
+            # kernel is bypassed (GSPMD cannot partition a Mosaic kernel).
             from jax.sharding import NamedSharding, PartitionSpec as P
 
             from ray_tpu.models.transformer import _kv_tp_ok, shard_params
@@ -404,7 +405,7 @@ class LLMEngine:
         # under a mesh the einsum path partitions via GSPMD; the Pallas
         # kernel paths stay for the single-device engine
         use_kernel = None if mesh is None else False
-        prefill_kernel = mesh is None and jax.default_backend() == "tpu"
+        prefill_kernel = mesh is None and backend.on_tpu()
 
         @jax.jit
         def _prefill_one(params, tokens, length):
@@ -862,6 +863,27 @@ class LLMEngine:
                 "prefix_evictions": self._prefix.evictions if self._prefix is not None else 0,
                 "cow_copies": self._cow_count,
             }
+
+    def lowered_decode_text(self) -> str:
+        """StableHLO text of the decode program as the loop runs it (same
+        params, cache and slot-array shapes). ``chip_smoke.py`` looks for
+        ``tpu_custom_call`` in it: which attention path the engine compiled
+        is read from the program, not assumed from a flag."""
+
+        def abstract(x):
+            return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=x.sharding)
+
+        params, cache = jax.tree.map(abstract, (self.params, self._cache))
+        toks = jax.ShapeDtypeStruct((self.B,), jnp.int32)
+        temps = jax.ShapeDtypeStruct((self.B,), jnp.float32)
+        if self.cache_kind == "paged":
+            bt = jax.ShapeDtypeStruct(self._block_tables.shape, jnp.int32)
+            lowered = self._decode_k_paged.lower(
+                params, cache, toks, toks, temps, self._key, bt
+            )
+        else:
+            lowered = self._decode_k.lower(params, cache, toks, toks, temps, self._key)
+        return lowered.as_text()
 
     def admission_snapshot(self) -> Dict[str, Any]:
         """Bounds + depths for GET /api/overload (admission source)."""
@@ -1893,6 +1915,9 @@ class LLMServer:
 
     def stats(self) -> Dict[str, Any]:
         return self.engine.stats()
+
+    def lowered_decode_text(self) -> str:
+        return self.engine.lowered_decode_text()
 
     # -- disaggregated prefill/decode (called by the router's dispatcher) --
     def disagg_prefill(self, request: Dict[str, Any], mig_id: str) -> dict:

@@ -215,7 +215,7 @@ def _spawn_chaos_agent(address, fp_spec, seed):
     env = dict(os.environ)
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env["PYTHONPATH"] = repo + os.pathsep + env.get("PYTHONPATH", "")
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env["JAX_PLATFORMS"] = "cpu"
     env["RAY_TPU_FAILPOINTS"] = fp_spec
     env["RAY_TPU_FAILPOINT_SEED"] = str(seed)
     log_dir = "/tmp/rt_agent_logs"

@@ -14,7 +14,7 @@ EXAMPLES = pathlib.Path(__file__).resolve().parent.parent / "examples"
 
 def _run(name: str, timeout: int = 240) -> str:
     env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = str(EXAMPLES.parent) + os.pathsep + env.get("PYTHONPATH", "")
     proc = subprocess.run(
         [sys.executable, str(EXAMPLES / name)],

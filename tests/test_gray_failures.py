@@ -215,7 +215,7 @@ def _spawn_agent(address):
     env = dict(os.environ)
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env["PYTHONPATH"] = repo + os.pathsep + env.get("PYTHONPATH", "")
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env["JAX_PLATFORMS"] = "cpu"
     log_dir = "/tmp/rt_agent_logs"
     os.makedirs(log_dir, exist_ok=True)
     log = open(os.path.join(log_dir, f"gray_agent_{os.getpid()}_{time.monotonic_ns()}.log"), "w")

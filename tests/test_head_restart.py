@@ -69,7 +69,7 @@ def test_head_restart_from_snapshot_agents_rejoin(tmp_path):
     snap = str(tmp_path / "control.snap")
     env = dict(os.environ)
     env["PYTHONPATH"] = REPO_ROOT + os.pathsep + env.get("PYTHONPATH", "")
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env["JAX_PLATFORMS"] = "cpu"
 
     head_a = subprocess.Popen(
         [sys.executable, "-c", HEAD_RUNNER.format(repo=REPO_ROOT, snap=snap, port=port)],
@@ -258,7 +258,7 @@ def _run_restart_under_load(attempt):
         snap = os.path.join(tmp, "control.snap")
         env = dict(os.environ)
         env["PYTHONPATH"] = REPO_ROOT + os.pathsep + env.get("PYTHONPATH", "")
-        env.setdefault("JAX_PLATFORMS", "cpu")
+        env["JAX_PLATFORMS"] = "cpu"
 
         head_a = subprocess.Popen(
             [sys.executable, "-c", HEAD_RUNNER_LOAD.format(repo=REPO_ROOT, snap=snap, port=port)],

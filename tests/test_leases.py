@@ -311,7 +311,7 @@ def _spawn_agent(address, resources='{"remote": 4}'):
     env = dict(os.environ)
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env["PYTHONPATH"] = repo + os.pathsep + env.get("PYTHONPATH", "")
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env["JAX_PLATFORMS"] = "cpu"
     return subprocess.Popen(
         [sys.executable, "-m", "ray_tpu.runtime.agent", "--address", address,
          "--num-cpus", "2", "--resources", resources],

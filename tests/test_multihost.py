@@ -27,7 +27,7 @@ REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 def _spawn_agent(address, num_cpus=2, extra_resources='{"remote": 4}'):
     env = dict(os.environ)
     env["PYTHONPATH"] = REPO_ROOT + os.pathsep + env.get("PYTHONPATH", "")
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env["JAX_PLATFORMS"] = "cpu"
     # stderr goes to a per-pid file, not an unread PIPE: when a test fails
     # because an agent silently died, the traceback (or its absence — clean
     # exit vs crash) is the difference between a diagnosis and a shrug

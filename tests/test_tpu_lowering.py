@@ -1,0 +1,303 @@
+"""What the chip will be asked to compile, checked without a chip.
+
+The installed jax/libtpu can cross-lower for TPU from a CPU host
+(``lower(lowering_platforms=("tpu",))``) and AOT-compile against a chipless
+``v5e:2x2`` topology. With ``ops.backend.on_tpu`` answering as the chip
+will, every Pallas kernel and the engine's paged decode program are lowered
+at ``chip_smoke.py``'s shapes — a BlockSpec Mosaic cannot tile is refused
+right here — and, where the topology can be built, compiled for real (an
+oversize-VMEM kernel or a Mosaic call GSPMD cannot partition is refused at
+that step). An AOT compile is a lead, not a pass: only ``chip_smoke.py`` on
+the chip shows the program runs and computes the right numbers.
+
+Also pins the two rules the lowering relies on: one comparison against the
+jax default backend in the package, and a compile cache placed from outside.
+"""
+
+import functools
+import os
+import re
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+import chip_smoke
+from bench import TRAIN_BATCH, train_config
+from ray_tpu.ops import backend
+from ray_tpu.ops.attention import flash_attention
+from ray_tpu.ops.decode_attention import decode_attention, paged_decode_attention
+from ray_tpu.ops.quantization import int8_matmul
+from ray_tpu.scripts.llm_bench import serving_config
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+FULL = chip_smoke.FULL
+BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
+
+
+@pytest.fixture
+def as_chip(monkeypatch):
+    """Answer the one platform predicate as the chip will."""
+    monkeypatch.setattr(backend, "on_tpu", lambda: True)
+
+
+@functools.cache
+def _v5e_topology():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2").devices
+    except Exception as exc:  # noqa: BLE001 — no libtpu / no chipless topology here
+        return exc
+
+
+@pytest.fixture
+def v5e():
+    """Four chipless ``TPU v5 lite`` devices to AOT-compile against. The
+    persistent cache is off meanwhile: an AOT executable can be written to
+    it but never read back, so it would only grow the directory."""
+    devices = _v5e_topology()
+    if isinstance(devices, Exception):
+        pytest.skip(f"cannot build a chipless v5e:2x2 topology: {devices!r}")
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        yield devices
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+
+
+def _flash_grad(q, k, v):
+    return jax.grad(lambda q, k, v: flash_attention(q, k, v).astype(F32).sum(), argnums=(0, 1, 2))(q, k, v)
+
+
+def _kernel_cases():
+    f, p, d, g = FULL["flash"], FULL["prefill"], FULL["decode"], FULL["paged"]
+    qkv = [((f["B"], f["H"], f["T"], f["D"]), BF16)] * 3
+    yield "flash_fwd", flash_attention, qkv
+    yield "flash_bwd", _flash_grad, qkv
+    for T in p["widths"]:
+        yield f"prefill_T{T}", flash_attention, [((1, p["H"], T, p["D"]), BF16)] * 3
+    for T in (33, 100, 2049):  # ragged: padded to the block, tail masked in-kernel
+        yield f"flash_ragged_T{T}", flash_attention, [((1, 2, T, 64), BF16)] * 3
+    B, H, Hkv, D = d["B"], d["H"], d["Hkv"], d["D"]
+    # S=1000 has no 128-multiple divisor: the block search must pad, not pick 500
+    for S, dt in ((d["S"], BF16), (1000, BF16), (1000, F32), (4096, F32)):
+        yield f"decode_dense_S{S}_{dt.__name__}", decode_attention, [
+            ((B, H, D), dt), ((B, Hkv, S, D), dt), ((B, Hkv, S, D), dt), ((B,), I32)]
+    B, H, Hkv, D = g["B"], g["H"], g["Hkv"], g["D"]
+    for bs in (g["bs"], 128):
+        for dt in (BF16, F32):
+            M = g["M"] * g["bs"] // bs
+            pool = ((B * M + 1, bs, Hkv, D), dt)
+            yield f"decode_paged_bs{bs}_{dt.__name__}", paged_decode_attention, [
+                ((B, H, D), dt), pool, pool, ((B, M), I32), ((B,), I32)]
+    yield "int8_matmul", int8_matmul, [((512, 1024), BF16), ((1024, 1024), jnp.int8), ((1024,), F32)]
+
+
+KERNEL_CASES = list(_kernel_cases())
+
+
+def _abstract(specs, sharding=None):
+    return [jax.ShapeDtypeStruct(shape, dt, sharding=sharding) for shape, dt in specs]
+
+
+def _lower_for_tpu(fn, args):
+    lowered = jax.jit(fn).trace(*args).lower(lowering_platforms=("tpu",))
+    assert "tpu_custom_call" in lowered.as_text(), "lowered without a Mosaic kernel"
+    return lowered
+
+
+@pytest.mark.parametrize("name,fn,specs", KERNEL_CASES, ids=[c[0] for c in KERNEL_CASES])
+def test_kernel_lowers_for_tpu(as_chip, name, fn, specs):
+    _lower_for_tpu(fn, _abstract(specs))
+
+
+@pytest.mark.parametrize("name,fn,specs", KERNEL_CASES, ids=[c[0] for c in KERNEL_CASES])
+def test_kernel_compiles_for_v5e(as_chip, v5e, name, fn, specs):
+    _lower_for_tpu(fn, _abstract(specs, SingleDeviceSharding(v5e[0]))).compile()
+
+
+def _engine_decode_args(sharding=None):
+    """The default engine's decode step at ``chip_smoke``'s serving shapes:
+    ``paged_decode_step`` with the kernel auto-selected, as ``LLMEngine``
+    builds it (``serve/llm.py:_decode_k_paged``)."""
+    from ray_tpu.models import init_params
+    from ray_tpu.models.generation import init_paged_cache
+
+    cfg = serving_config()
+    s = FULL["serve"]
+    bs = 16  # Config.kv_block_size default
+    M = s["seq"] // bs
+
+    def abstract(tree):
+        return jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding), tree
+        )
+
+    params = abstract(jax.eval_shape(lambda: init_params(cfg, jax.random.key(0))))
+    cache = abstract(jax.eval_shape(lambda: init_paged_cache(cfg, s["slots"] * M + 1, bs)))
+    toks, bt = _abstract([((s["slots"],), I32), ((s["slots"], M), I32)], sharding)
+    return cfg, (params, cache, toks, toks, bt)
+
+
+def _engine_decode_step(cfg):
+    from ray_tpu.models.generation import paged_decode_step
+
+    return lambda params, cache, toks, pos, bt: paged_decode_step(cfg, params, cache, toks, pos, bt)
+
+
+def test_engine_paged_decode_program_lowers_for_tpu(as_chip):
+    cfg, args = _engine_decode_args()
+    _lower_for_tpu(_engine_decode_step(cfg), args)
+
+
+def test_engine_paged_decode_program_compiles_for_v5e(as_chip, v5e):
+    cfg, args = _engine_decode_args(SingleDeviceSharding(v5e[0]))
+    _lower_for_tpu(_engine_decode_step(cfg), args).compile()
+
+
+# --------------------------------------------------------------------------
+# make_train_step, AOT
+# --------------------------------------------------------------------------
+def _abstract_train_state(cfg, mesh=None, device=None):
+    """The train state's shapes with the shardings ``sharded_init`` gives
+    them: params per ``param_specs``, adam moments like their params, step
+    counts replicated."""
+    from ray_tpu.models.transformer import _kv_tp_ok, make_train_step, param_specs
+
+    init_state, _ = make_train_step(cfg)
+    state = jax.eval_shape(init_state, jax.random.key(0))
+
+    def struct(x, sharding):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
+
+    if mesh is None:
+        return jax.tree.map(lambda x: struct(x, SingleDeviceSharding(device)), state)
+    specs = param_specs(cfg, kv_tp=_kv_tp_ok(cfg, mesh, "tp"))
+    params_def = jax.tree.structure(state["params"])
+
+    def params_like(node):
+        return jax.tree.structure(node) == params_def
+
+    def place(node):
+        if params_like(node):  # the params, adam's mu and nu
+            return jax.tree.map(lambda x, s: struct(x, NamedSharding(mesh, s)), node, specs)
+        return struct(node, NamedSharding(mesh, P()))
+
+    return jax.tree.map(place, state, is_leaf=params_like)
+
+
+def test_train_step_602m_compiles_for_one_v5e_and_fits(as_chip, v5e):
+    """The job ``bench.py`` and ``chip_smoke.py`` name, at the batch they
+    name: compiles with its Mosaic kernels and fits one chip's 16 GiB."""
+    from ray_tpu.models.transformer import make_train_step
+
+    cfg = train_config()
+    _, train_step = make_train_step(cfg)
+    state = _abstract_train_state(cfg, device=v5e[0])
+    tokens = jax.ShapeDtypeStruct((TRAIN_BATCH, cfg.max_seq_len), I32, sharding=SingleDeviceSharding(v5e[0]))
+    lowered = train_step.trace(state, tokens).lower(lowering_platforms=("tpu",))
+    assert "tpu_custom_call" in lowered.as_text()
+    mem = lowered.compile().memory_analysis()
+    need = mem.argument_size_in_bytes + mem.temp_size_in_bytes + mem.output_size_in_bytes - mem.alias_size_in_bytes
+    assert need < 16 * 2**30, f"train step needs {need / 2**30:.2f} GiB"
+
+
+@pytest.mark.parametrize("attention", ["auto", "dense", "ring"])
+def test_train_step_compiles_under_a_four_chip_mesh(as_chip, v5e, attention):
+    """Every attention mode ``make_train_step`` accepts with a mesh compiles
+    for four real chips (602M widths, depth cut to 2: the refusals this
+    guards — an unpartitionable Mosaic call, a bad block — are per layer)."""
+    import dataclasses
+
+    from ray_tpu.models.transformer import make_train_step
+
+    cfg = dataclasses.replace(train_config(), attention=attention, n_layers=2)
+    mesh = Mesh(np.array(v5e).reshape(1, 2, 2), ("dp", "sp", "tp"))
+    _, train_step = make_train_step(cfg, mesh=mesh)
+    state = _abstract_train_state(cfg, mesh=mesh)
+    tokens = jax.ShapeDtypeStruct((2, cfg.max_seq_len), I32, sharding=NamedSharding(mesh, P("dp", None)))
+    lowered = train_step.lower(state, tokens)
+    assert ("tpu_custom_call" in lowered.as_text()) == (attention == "ring")
+    lowered.compile()
+
+
+def test_flash_under_a_mesh_is_refused_at_build_time():
+    """CPU meshes used to accept this (interpret mode lowers to ordinary
+    ops GSPMD can partition) and chips refuse it; now both refuse, early."""
+    import dataclasses
+
+    from ray_tpu.models.transformer import make_train_step
+
+    mesh = Mesh(np.array(jax.devices()[:4]).reshape(2, 2), ("dp", "tp"))
+    cfg = dataclasses.replace(train_config(), attention="flash")
+    with pytest.raises(ValueError, match='"ring"'):
+        make_train_step(cfg, mesh=mesh)
+
+
+# --------------------------------------------------------------------------
+# the rules
+# --------------------------------------------------------------------------
+def test_default_backend_is_compared_in_one_place():
+    hits = []
+    for root, _, files in os.walk(os.path.join(REPO, "ray_tpu")):
+        for name in files:
+            if name.endswith(".py"):
+                path = os.path.join(root, name)
+                with open(path) as f:
+                    for n, line in enumerate(f, 1):
+                        if re.search(r"default_backend\(\)", line):
+                            hits.append(f"{os.path.relpath(path, REPO)}:{n}")
+    assert len(hits) == 1 and hits[0].startswith("ray_tpu/ops/backend.py:"), hits
+
+
+_CACHE_PROBE = """
+import jax
+set_in_code = []
+update = jax.config.update
+jax.config.update = lambda key, value: (set_in_code.append(key), update(key, value))
+from ray_tpu.ops.backend import use_compile_cache
+print(use_compile_cache())
+print(jax.config.jax_compilation_cache_dir)
+print("jax_compilation_cache_dir" in set_in_code)
+"""
+
+
+def _cache_probe(env_dir):
+    """(what the helper returned, what jax holds, whether code set it) in a
+    fresh process with ``JAX_COMPILATION_CACHE_DIR`` set to ``env_dir``."""
+    env = {k: v for k, v in os.environ.items() if k != "JAX_COMPILATION_CACHE_DIR"}
+    if env_dir:
+        env["JAX_COMPILATION_CACHE_DIR"] = env_dir
+    out = subprocess.run(
+        [sys.executable, "-c", _CACHE_PROBE], env=env, cwd=REPO,
+        capture_output=True, text=True, timeout=120, check=True,
+    ).stdout.split()
+    return out[0], out[1], out[2] == "True"
+
+
+def test_compile_cache_unset_is_one_fixed_path_in_the_checkout():
+    fixed = os.path.join(REPO, ".jax_cache")
+    assert _cache_probe(None) == _cache_probe(None) == (fixed, fixed, True)
+
+
+def test_compile_cache_env_is_left_to_jax(tmp_path):
+    """Placed from outside: jax reads the variable, the code sets nothing."""
+    placed = str(tmp_path / "placed")
+    assert _cache_probe(placed) == (placed, placed, False)
+
+
+def test_chip_smoke_fails_without_a_chip():
+    """No flag, no accelerator: non-zero exit and no result on stdout."""
+    proc = subprocess.run(
+        [sys.executable, os.path.join(REPO, "chip_smoke.py")],
+        env={**os.environ, "JAX_PLATFORMS": "cpu"}, cwd=REPO,
+        capture_output=True, text=True, timeout=300,
+    )
+    assert proc.returncode != 0
+    assert proc.stdout.strip() == ""
+    assert "platform 'cpu'" in proc.stderr
