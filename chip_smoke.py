@@ -17,15 +17,18 @@ at the full widths the repo names, with random weights made from a seed:
   dp1·sp2·tp2 mesh, then a tensor-parallel engine.
 
 All legs run in THIS process: a chip belongs to one process, and the engine
-is freed before the 15 GiB train step. The last line of stdout is one JSON
-object, ``{"ok": ..., "device": {"platform", "kind", "count"}, ...,
-"claim": null}``; the exit code is 0 only if every leg passed. With no TPU
-(or a ``device_kind`` ``bench.py`` has no peak for) nothing is printed on
-stdout and the exit code is non-zero — there is no CPU fallback.
+is freed before the 15 GiB train step. Stdout carries two JSON lines: the
+report (versions, compile cache, every leg's numbers, ``"claim": null``) and
+then, LAST, the verdict the driver parses — exactly
+``{"ok": true|false, "device": {"platform": ..., "kind": ..., "count": N}}``
+with the device as jax reports it and no other key. The exit code is 0 only
+if every leg passed. With no TPU (or a ``device_kind`` ``bench.py`` has no
+peak for) nothing is printed on stdout and the exit code is non-zero — there
+is no CPU fallback.
 
 ``--rehearsal`` is the one exception, for the builder and never the driver:
 the same legs at toy sizes on whatever backend jax has (Pallas in interpret
-mode on the CPU), to debug the script before spending chip time. Its summary
+mode on the CPU), to debug the script before spending chip time. Its report
 says ``"rehearsal": true`` and proves nothing about the chip.
 """
 
@@ -478,6 +481,11 @@ def leg_four_chip(sz, on_chip):
     return {"ring_train": ring, "tp_engine": {"requests": len(outs), "cache_kind": engine.cache_kind}}
 
 
+def verdict(summary: dict) -> dict:
+    """The last stdout line: the two keys the driver's contract names, no more."""
+    return {"ok": summary["ok"], "device": summary["device"]}
+
+
 LEGS = {"kernels": leg_kernels, "serving": leg_serving, "training": leg_training,
         "four_chip": leg_four_chip}
 
@@ -566,7 +574,8 @@ def main(argv=None) -> int:
     )
     summary["claim"] = None
     faulthandler.cancel_dump_traceback_later()
-    print(json.dumps(summary), flush=True)
+    print(json.dumps(summary))
+    print(json.dumps(verdict(summary)), flush=True)
     return 0 if summary["ok"] else 1
 
 

@@ -15,6 +15,7 @@ jax default backend in the package, and a compile cache placed from outside.
 """
 
 import functools
+import json
 import os
 import re
 import subprocess
@@ -301,3 +302,19 @@ def test_chip_smoke_fails_without_a_chip():
     assert proc.returncode != 0
     assert proc.stdout.strip() == ""
     assert "platform 'cpu'" in proc.stderr
+
+
+def test_chip_smoke_last_line_is_the_verdict_and_nothing_else():
+    """The driver parses the last stdout line: exactly ``ok`` and ``device``
+    (``platform``, ``kind``, ``count``). No leg ran, so ``ok`` is false."""
+    proc = subprocess.run(
+        [sys.executable, os.path.join(REPO, "chip_smoke.py"), "--rehearsal", "--legs", ""],
+        env={**os.environ, "JAX_PLATFORMS": "cpu"}, cwd=REPO,
+        capture_output=True, text=True, timeout=300,
+    )
+    assert proc.returncode == 1
+    report, last = (json.loads(line) for line in proc.stdout.strip().splitlines())
+    assert set(last) == {"ok", "device"} and last["ok"] is False
+    assert last["device"] == {"platform": "cpu", "kind": "cpu", "count": jax.device_count()}
+    assert report["rehearsal"] is True and report["claim"] is None and report["legs"] == {}
+    assert chip_smoke.verdict(report) == last
