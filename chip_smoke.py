@@ -182,12 +182,12 @@ def leg_kernels(sz, on_chip):
         )
 
     @jax.jit
-    def ref_decode(q, k_pages, v_pages, tables, lengths):
+    def ref_decode(q, k_pool, v_pool, tables, lengths, layer):
         B, H, D = q.shape
-        Hkv = k_pages.shape[2]
+        Hkv = k_pool.shape[-1] // D
         with jax.default_matmul_precision("highest"):
             out = _paged_decode_xla(
-                q.reshape(B, Hkv, H // Hkv, D), k_pages, v_pages, tables, lengths, D ** -0.5
+                q.reshape(B, Hkv, H // Hkv, D), k_pool, v_pool, tables, lengths, layer, D ** -0.5
             )
         return out.reshape(B, H, D)
 
@@ -228,26 +228,27 @@ def leg_kernels(sz, on_chip):
     kc, vc = rand(21, (B, Hkv, S, D)), rand(22, (B, Hkv, S, D))
     lengths = jnp.asarray(np.linspace(1, S, B).astype(np.int32))
     out = _compile(jax.jit(decode_attention), q, kc, vc, lengths, on_chip=on_chip)(q, kc, vc, lengths)
-    ref = ref_decode(
-        q, jnp.swapaxes(kc, 1, 2), jnp.swapaxes(vc, 1, 2),
-        jnp.arange(B, dtype=jnp.int32)[:, None], lengths,
-    )
+    def as_pool(c):  # one layer, one S-token page per sequence
+        return jnp.swapaxes(c, 1, 2).reshape(1, B, S, Hkv * D)
+
+    ref = ref_decode(q, as_pool(kc), as_pool(vc), jnp.arange(B, dtype=jnp.int32)[:, None], lengths, 0)
     _check("decode_dense", out, ref, FWD_REL_TOL, errs)
 
-    # paged decode kernel over the engine's pool layout, shuffled tables,
-    # ragged lengths (one empty row, one full)
+    # paged decode kernel over the engine's pool layout (the second of three
+    # layers), shuffled tables, ragged lengths (one empty row, one full)
     g = sz["paged"]
     B, H, Hkv, D, M, bs = g["B"], g["H"], g["Hkv"], g["D"], g["M"], g["bs"]
     N = B * M + 1
     q = rand(30, (B, H, D))
-    kp, vp = rand(31, (N, bs, Hkv, D)), rand(32, (N, bs, Hkv, D))
+    kp, vp = rand(31, (3, N, bs, Hkv * D)), rand(32, (3, N, bs, Hkv * D))
     rng = np.random.default_rng(0)
     bt = jnp.asarray(rng.permutation(np.arange(1, N)).reshape(B, M).astype(np.int32))
     lengths = jnp.asarray(np.linspace(0, M * bs, B).astype(np.int32))
-    out = _compile(jax.jit(paged_decode_attention), q, kp, vp, bt, lengths, on_chip=on_chip)(
-        q, kp, vp, bt, lengths
+    layer = jnp.int32(1)
+    out = _compile(jax.jit(paged_decode_attention), q, kp, vp, bt, lengths, layer, on_chip=on_chip)(
+        q, kp, vp, bt, lengths, layer
     )
-    ref = ref_decode(q, kp, vp, bt, lengths)
+    ref = ref_decode(q, kp, vp, bt, lengths, layer)
     _check("decode_paged", out[1:], ref[1:], FWD_REL_TOL, errs)
     assert not bool(jnp.any(out[0])), "decode_paged: an empty row must be zeros"
     return {"rel_err": errs, "tolerance": {"fwd": FWD_REL_TOL, "bwd": BWD_REL_TOL}}

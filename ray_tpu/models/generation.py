@@ -34,8 +34,6 @@ from ray_tpu.models.transformer import (
     _moe_ffn,
     _rms_norm,
     _rope,
-    gather_paged_kv,
-    scatter_paged_kv,
 )
 from ray_tpu.ops import backend
 
@@ -52,16 +50,51 @@ def init_cache(cfg: TransformerConfig, batch: int, max_len: int, dtype=None) -> 
 def init_paged_cache(
     cfg: TransformerConfig, num_blocks: int, block_size: int, dtype=None
 ) -> KVCache:
-    """Paged KV pool: {"k","v"}: [L, num_blocks, block_size, Hkv, Dh].
+    """Paged KV pool: {"k","v"}: [L, num_blocks, block_size, Hkv*Dh].
 
     Unlike :func:`init_cache` there is no batch axis — sequences own sets
     of pages named by an ``int32[B, max_blocks]`` block table, so HBM is
     proportional to tokens actually cached, not ``B * max_len``. Page 0 is
     reserved by convention as the garbage page (all-zero table entries and
-    masked writes land there)."""
+    masked writes land there).
+
+    A token's KV heads lie side by side on the minor axis: the TPU's natural
+    layout of ``[.., block_size, Hkv*Dh]`` is unpadded row-major pages, which
+    is what the paged decode kernel DMAs, so the serve programs update the
+    pool in place and read it where it lies. (With ``Dh`` alone minor-most,
+    a 64-wide head pads to 128 lanes and XLA puts the page axis on the lanes
+    instead — every layer then pays a layout conversion of its whole slice.)
+    """
     dt = dtype or cfg.dtype
-    shape = (cfg.n_layers, num_blocks, block_size, cfg.kv_heads, cfg.head_dim)
+    shape = (cfg.n_layers, num_blocks, block_size, cfg.kv_heads * cfg.head_dim)
     return {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
+
+
+def paged_cache_spec(heads_axis: Optional[str]):
+    """PartitionSpec of a paged pool sharded over KV heads: the heads are
+    the leading factor of the minor axis, so an even split of that axis over
+    ``heads_axis`` gives each shard whole heads. (The dense cache's spec,
+    ``P(None, None, heads_axis, None, None)``, names axis 2 — here that is
+    the page's token axis.)"""
+    from jax.sharding import PartitionSpec as P
+
+    return P(None, None, None, heads_axis)
+
+
+def _paged_write_index(block_tables, positions, valid, block_size):
+    """(page, offset) of each written token, flattened to ``[B*T]``: the
+    paged analog of the dense path's per-row write offset. Rows marked
+    invalid (bucket padding) and positions past a row's table (post-finish
+    decode overshoot walks into all-zero table entries) land in the reserved
+    garbage page 0, so a write can never corrupt another sequence's pages."""
+    M = block_tables.shape[1]
+    blk = jnp.clip(positions // block_size, 0, M - 1)  # [B, T] logical block
+    phys = jnp.take_along_axis(block_tables, blk, axis=1)  # [B, T] physical page
+    if valid is not None:
+        # padded positions may exceed the table capacity entirely, where the
+        # clip above would alias the LAST real block — route them to page 0
+        phys = jnp.where(valid, phys, 0)
+    return phys.reshape(-1), (positions % block_size).reshape(-1)
 
 
 def copy_paged_page(cache: KVCache, src, dst) -> KVCache:
@@ -78,6 +111,24 @@ def copy_paged_page(cache: KVCache, src, dst) -> KVCache:
     return {
         kk: cache[kk].at[:, dst].set(cache[kk][:, src]) for kk in ("k", "v")
     }
+
+
+def export_paged_page(cfg: TransformerConfig, cache: KVCache, page) -> jax.Array:
+    """One physical page as a migration block ``[2, L, block_size, Hkv, Dh]``
+    (k then v): the format replicas exchange names the heads, whatever the
+    pool's own row layout is. Indexing materializes NEW buffers, so the block
+    survives later donated steps."""
+    block = jnp.stack([cache["k"][:, page], cache["v"][:, page]])
+    return block.reshape(*block.shape[:3], cfg.kv_heads, cfg.head_dim)
+
+
+def write_paged_pages(cache: KVCache, blocks, pages) -> KVCache:
+    """Land migration blocks ``[N, 2, L, block_size, Hkv, Dh]``
+    (:func:`export_paged_page`'s format) in the pool, block ``n`` at physical
+    page ``pages[n]``, in one scatter per pool. Duplicate ``(block, page)``
+    pairs are idempotent (identical bytes to the same page)."""
+    rows = jnp.swapaxes(blocks.reshape(*blocks.shape[:4], -1), 0, 2)  # [L, 2, N, bs, Hkv*Dh]
+    return {kk: cache[kk].at[:, pages].set(rows[:, i]) for i, kk in enumerate(("k", "v"))}
 
 
 def _write_kv(cache_layer: jax.Array, new: jax.Array, starts: jax.Array) -> jax.Array:
@@ -229,6 +280,10 @@ def paged_forward_with_cache(
     simply never read. Chunked prefill is just this function called with
     ``positions`` starting mid-sequence — visibility is positional, so a
     chunk sees all previously cached chunks plus its own causal prefix.
+
+    The stacked pool is the layer loop's carry: layer ``l`` scatters its
+    K/V rows into ``pool[l]`` in place and attention reads them from there,
+    so a donated cache is never sliced, re-laid-out or copied.
     """
     B, T = tokens.shape
     M = block_tables.shape[1]
@@ -247,36 +302,45 @@ def paged_forward_with_cache(
         use_decode_kernel = backend.on_tpu()
     decode_kernel = use_decode_kernel and T == 1
 
-    def layer_fn(x, layer_kc_vc):
+    phys, off = _paged_write_index(block_tables, positions, valid, bs)
+
+    def dense_view(pool, l):
+        # [B, Hkv, cap, Dh] view of layer l through the block tables, so the
+        # attention lines below are verbatim the dense path's. Transpose, then
+        # merge pages: splitting the heads off a merged [cap] axis first is
+        # the same view, but XLA:CPU then fuses the einsum differently and the
+        # logits leave the dense cache's by ~1e-6
+        g = pool[l, block_tables].reshape(B, M, bs, hkv, cfg.head_dim)
+        return jnp.transpose(g, (0, 3, 1, 2, 4)).reshape(B, hkv, cap, cfg.head_dim)
+
+    def layer_fn(carry, layer_xs):
+        x, kc, vc = carry
         if layer_scales is not None:
-            layer_q, lsc, kc, vc = layer_kc_vc
+            layer_q, lsc, l = layer_xs
             layer = {
                 k: (layer_q[k].astype(jnp.float32) * lsc[k]).astype(cfg.param_dtype)
                 for k in layer_q
             }
         else:
-            layer, kc, vc = layer_kc_vc
+            layer, l = layer_xs
         h = _rms_norm(x, layer["attn_norm"])
         q = jnp.einsum("btd,dhk->bthk", h, layer["wq"].astype(h.dtype))
         k = jnp.einsum("btd,dhk->bthk", h, layer["wk"].astype(h.dtype))
         v = jnp.einsum("btd,dhk->bthk", h, layer["wv"].astype(h.dtype))
         q, k = _rope(q, positions, cfg.rope_theta), _rope(k, positions, cfg.rope_theta)
-        kc = scatter_paged_kv(kc, k, block_tables, positions, valid)
-        vc = scatter_paged_kv(vc, v, block_tables, positions, valid)
+        kc = kc.at[l, phys, off].set(k.reshape(B * T, -1).astype(kc.dtype))
+        vc = vc.at[l, phys, off].set(v.reshape(B * T, -1).astype(vc.dtype))
         if decode_kernel:
             from ray_tpu.ops.decode_attention import paged_decode_attention
 
             o = paged_decode_attention(
-                q[:, 0], kc, vc, block_tables, starts + 1, sm_scale=scale
+                q[:, 0], kc, vc, block_tables, starts + 1, l, sm_scale=scale
             )[:, None]
             o = o.astype(x.dtype)
         else:
-            # gather the pool to a dense [B, Hkv, cap, Dh] view, then the
-            # grouped-query attention lines below are verbatim the dense
-            # path's — masked positions contribute exactly-0.0 weight, so
-            # page-0 garbage never reaches the output
-            kd = gather_paged_kv(kc, block_tables)
-            vd = gather_paged_kv(vc, block_tables)
+            # masked positions contribute exactly-0.0 weight, so page-0
+            # garbage never reaches the output
+            kd, vd = dense_view(kc, l), dense_view(vc, l)
             qg = q.reshape(B, T, hkv, n_rep, cfg.head_dim)
             s_ = jnp.einsum(
                 "btgrk,bgsk->bgrts", qg.astype(jnp.float32), kd.astype(jnp.float32)
@@ -288,13 +352,14 @@ def paged_forward_with_cache(
         x = x + jnp.einsum("bthk,hkd->btd", o, layer["wo"].astype(o.dtype))
         h = _rms_norm(x, layer["ffn_norm"])
         ffn = _moe_ffn(cfg, layer, h) if cfg.num_experts > 0 else _dense_ffn(layer, h)
-        return x + ffn, (kc, vc)
+        return (x + ffn, kc, vc), None
 
+    layer_ids = jnp.arange(cfg.n_layers, dtype=jnp.int32)
     if layer_scales is not None:
-        xs = (params["layers"], layer_scales, cache["k"], cache["v"])
+        xs = (params["layers"], layer_scales, layer_ids)
     else:
-        xs = (params["layers"], cache["k"], cache["v"])
-    x, (ks, vs) = jax.lax.scan(layer_fn, x, xs)
+        xs = (params["layers"], layer_ids)
+    (x, ks, vs), _ = jax.lax.scan(layer_fn, (x, cache["k"], cache["v"]), xs)
     x = _rms_norm(x, params["final_norm"])
     logits = jnp.einsum("btd,vd->btv", x, params["embed"].astype(x.dtype))
     return logits.astype(jnp.float32), {"k": ks, "v": vs}

@@ -284,46 +284,6 @@ def _gqa_mha(qt, k, v, *, causal: bool, sm_scale: float):
     return o.reshape(B, H, T, Dh).astype(qt.dtype)
 
 
-def gather_paged_kv(pages: jax.Array, block_tables: jax.Array) -> jax.Array:
-    """Dense view of a paged KV pool: ``[N, bs, Hkv, Dh]`` gathered through
-    ``int32[B, M]`` block tables -> ``[B, Hkv, M*bs, Dh]``.
-
-    This is the attention-over-block-table path for backends without the
-    Pallas paged kernel: one ``jnp.take`` on the page axis, then the cache
-    looks exactly like the dense ``[B, Hkv, S, Dh]`` layout, so downstream
-    attention math is shared verbatim with the dense path (which is what
-    makes dense/paged byte-equivalence testable on CPU)."""
-    g = jnp.take(pages, block_tables, axis=0)  # [B, M, bs, Hkv, Dh]
-    B, M, bs, Hkv, Dh = g.shape
-    return jnp.transpose(g, (0, 3, 1, 2, 4)).reshape(B, Hkv, M * bs, Dh)
-
-
-def scatter_paged_kv(
-    pages: jax.Array,         # [N, bs, Hkv, Dh] shared pool (donated by callers)
-    new: jax.Array,           # [B, T, Hkv, Dh] this call's K or V
-    block_tables: jax.Array,  # [B, M] int32 physical page per logical block
-    positions: jax.Array,     # [B, T] int32 absolute write positions
-    valid: Optional[jax.Array] = None,  # [B, T] bool; False -> garbage page 0
-) -> jax.Array:
-    """Write ``new`` into the pool at per-row ``positions`` routed through
-    the block tables (the paged analog of the dense vmapped
-    ``dynamic_update_slice``). Rows marked invalid (bucket padding) and
-    positions past a row's table (post-finish decode overshoot walks into
-    all-zero table entries) land in the reserved garbage page 0, so a write
-    can never corrupt another sequence's pages."""
-    bs = pages.shape[1]
-    M = block_tables.shape[1]
-    blk = jnp.clip(positions // bs, 0, M - 1)  # [B, T] logical block
-    off = positions % bs
-    phys = jnp.take_along_axis(block_tables, blk, axis=1)  # [B, T] physical page
-    if valid is not None:
-        # padded positions may exceed the table capacity entirely, where the
-        # clip above would alias the LAST real block — route them to page 0
-        phys = jnp.where(valid, phys, 0)
-    upd = new.reshape(-1, new.shape[-2], new.shape[-1]).astype(pages.dtype)
-    return pages.at[phys.reshape(-1), off.reshape(-1)].set(upd)
-
-
 def _attention(cfg: TransformerConfig, q, k, v, use_flash: bool, mesh=None, sp_axis=None):
     # q: [B, T, H, Dh]; k, v: [B, T, Hkv, Dh] (unrepeated under GQA)
     n_rep = cfg.n_heads // cfg.kv_heads

@@ -17,11 +17,14 @@ bandwidth. The kernel:
   cache need no recompilation.
 
 :func:`paged_decode_attention` is the block-pool variant (PagedAttention,
-Kwon et al. 2023): K/V live in a shared pool of fixed-size pages
-``[num_blocks, block_size, Hkv, D]`` and each sequence names its pages in
-an ``int32[B, max_blocks]`` block table. The table rides Pallas scalar
-prefetch (``PrefetchScalarGridSpec``) so the BlockSpec index maps gather
-pages straight out of HBM — no materialized per-sequence cache copy.
+Kwon et al. 2023): K/V live in a shared, layer-stacked pool of fixed-size
+pages ``[L, num_blocks, block_size, Hkv*D]`` (a page row holds all KV heads
+side by side on the lanes, so the pool's natural device layout is unpadded
+row-major pages) and each sequence names its pages in an
+``int32[B, max_blocks]`` block table. The table and the layer index ride
+Pallas scalar prefetch (``PrefetchScalarGridSpec``) so the BlockSpec index
+maps gather pages straight out of the stacked pool in HBM — no per-layer
+slice, no materialized per-sequence cache copy.
 ``use_kernel=False`` is the plain-XLA reference (a ``jnp.take`` gather that
 reduces to the dense math) the kernel is checked against.
 
@@ -156,27 +159,31 @@ def decode_attention(
 
 
 def _paged_decode_kernel(
-    tables_ref, lengths_ref,  # scalar-prefetch: [B, M] int32 page ids, [B] int32
+    tables_ref, lengths_ref, layer_ref,  # scalar-prefetch: [B, M] page ids, [B], [1]
     q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
-    *, sm_scale: float, block_size: int,
+    *, sm_scale: float, block_size: int, pack: int,
 ):
     """Grid (B, M): M innermost walks the sequence's logical blocks.
 
-    One grid step holds one whole physical page: k_ref/v_ref are
-    ``[block_size, Hkv, D]`` (the page axis squeezed by the BlockSpec, whose
-    index map reads ``tables_ref`` to pick the page), q_ref/o_ref are
-    ``[Hkv, rep_p, D]`` and the online-softmax scratch carries a leading
-    ``Hkv`` axis. The KV heads are walked inside the kernel — a block that
-    squeezed the second-minor ``Hkv`` axis of the pool is not a shape Mosaic
-    can tile. Per head the state machine is :func:`_decode_kernel`'s;
-    validity is derived in-kernel from ``lengths_ref`` instead of a bias
-    input, and logical blocks wholly past the valid prefix skip their FLOPs.
+    One grid step holds one whole physical page of one layer: k_ref/v_ref
+    are ``[block_size, Hkv*D]`` (the layer and page axes squeezed by the
+    BlockSpec, whose index map reads ``layer_ref`` and ``tables_ref`` to
+    pick them). The KV heads are walked inside the kernel in static lane
+    tiles of ``W = pack*D`` lanes, ``pack`` neighbouring heads to a tile, so
+    a 64-wide head still loads whole 128-lane tiles: q_ref is
+    ``[Hkv, rep_p, W]`` with head ``g``'s query in its own ``D`` lanes of its
+    tile and zeros in its neighbours' (their K lanes drop out of the scores
+    as exact zeros), and o_ref/acc carry ``W`` lanes of which the caller
+    keeps head ``g``'s own. Per head the state machine is
+    :func:`_decode_kernel`'s; validity is derived in-kernel from
+    ``lengths_ref`` instead of a bias input, and logical blocks wholly past
+    the valid prefix skip their FLOPs.
     """
     bi = pl.program_id(0)
     si = pl.program_id(1)
     num_s = pl.num_programs(1)
     length = lengths_ref[bi]
-    n_kv = q_ref.shape[0]
+    n_kv, _, w = q_ref.shape
 
     @pl.when(si == 0)
     def _init():
@@ -189,9 +196,10 @@ def _paged_decode_kernel(
         pos = si * block_size + jax.lax.broadcasted_iota(jnp.int32, (1, block_size), 1)
         bias = jnp.where(pos < length, 0.0, NEG_INF)  # [1, block_size]
         for g in range(n_kv):
+            if g % pack == 0:  # a new lane tile: widened once for its heads
+                k = k_ref[:, g // pack * w:(g // pack + 1) * w].astype(jnp.float32)
+                v = v_ref[:, g // pack * w:(g // pack + 1) * w].astype(jnp.float32)
             q = q_ref[g].astype(jnp.float32) * sm_scale
-            k = k_ref[:, g, :].astype(jnp.float32)
-            v = v_ref[:, g, :].astype(jnp.float32)
             s = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
             )
@@ -217,17 +225,19 @@ def _paged_decode_kernel(
         o_ref[...] = out.astype(o_ref.dtype)
 
 
-def _paged_decode_xla(qg, k_pages, v_pages, block_tables, lengths, scale):
-    """Plain-XLA reference: gather each sequence's pages into a dense
-    [B, Hkv, M*bs, D] view and run the masked grouped einsum — the exact
-    math of the dense path; the kernel is compared against it (tier-1 in
-    interpret mode, ``chip_smoke.py`` compiled)."""
-    g = jnp.take(k_pages, block_tables, axis=0)  # [B, M, bs, Hkv, D]
-    B, M, bs, Hkv, D = g.shape
-    k = jnp.transpose(g, (0, 3, 1, 2, 4)).reshape(B, Hkv, M * bs, D)
-    v = jnp.transpose(
-        jnp.take(v_pages, block_tables, axis=0), (0, 3, 1, 2, 4)
-    ).reshape(B, Hkv, M * bs, D)
+def _paged_decode_xla(qg, k_pool, v_pool, block_tables, lengths, layer, scale):
+    """Plain-XLA reference: gather each sequence's pages of ``layer`` into a
+    dense [B, Hkv, M*bs, D] view and run the masked grouped einsum — the
+    exact math of the dense path; the kernel is compared against it (tier-1
+    in interpret mode, ``chip_smoke.py`` compiled)."""
+    B, Hkv, _, D = qg.shape
+    M, bs = block_tables.shape[1], k_pool.shape[2]
+
+    def dense(pool):
+        g = pool[layer, block_tables]  # [B, M, bs, Hkv*D]
+        return jnp.transpose(g.reshape(B, M * bs, Hkv, D), (0, 2, 1, 3))
+
+    k, v = dense(k_pool), dense(v_pool)
     s = jnp.einsum(
         "bgrk,bgsk->bgrs", qg.astype(jnp.float32), k.astype(jnp.float32)
     ) * scale  # [B, Hkv, n_rep, S]
@@ -241,16 +251,20 @@ def _paged_decode_xla(qg, k_pages, v_pages, block_tables, lengths, scale):
 
 def paged_decode_attention(
     q: jax.Array,             # [B, H, D] one query row per sequence
-    k_pages: jax.Array,       # [num_blocks, block_size, Hkv, D] shared pool
-    v_pages: jax.Array,       # [num_blocks, block_size, Hkv, D]
+    k_pool: jax.Array,        # [L, num_blocks, block_size, Hkv*D] shared pool
+    v_pool: jax.Array,        # [L, num_blocks, block_size, Hkv*D]
     block_tables: jax.Array,  # [B, M] int32 physical page per logical block
     lengths: jax.Array,       # [B] int32: valid cache entries per sequence
+    layer: jax.Array,         # int32 scalar: which layer of the pool to read
     *,
     sm_scale: Optional[float] = None,
     use_kernel: bool = True,
 ) -> jax.Array:
-    """Decode attention over a paged KV pool; returns [B, H, D].
+    """Decode attention over one layer of a paged KV pool; returns [B, H, D].
 
+    The pool is read where it lies: ``layer`` only steers the page DMAs, so
+    a caller looping over layers hands in the same stacked buffer each time.
+    ``Hkv`` is what the pool's row width and ``D`` (from ``q``) imply.
     Table entries past ``ceil(lengths[b] / block_size)`` may point anywhere
     valid (the engine points them at the reserved garbage page 0) — they are
     masked out, never normalized in. ``use_kernel=False`` is the plain-XLA
@@ -262,43 +276,62 @@ def paged_decode_attention(
     import math
 
     B, H, D = q.shape
-    _, bs, Hkv, _ = k_pages.shape
+    _, _, bs, row = k_pool.shape
+    Hkv = row // D
     M = block_tables.shape[1]
     n_rep = H // Hkv
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(D)
 
     qg = q.reshape(B, Hkv, n_rep, D)
     if not use_kernel:
-        out = _paged_decode_xla(qg, k_pages, v_pages, block_tables, lengths, scale)
+        out = _paged_decode_xla(qg, k_pool, v_pool, block_tables, lengths, layer, scale)
         return out.astype(q.dtype).reshape(B, H, D)
 
     rep_p = -(-n_rep // _MIN_REP) * _MIN_REP
     if rep_p != n_rep:
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, rep_p - n_rep), (0, 0)))
+    # heads narrower than a lane tile share one: `pack` neighbours to a tile
+    # of W lanes, so the kernel never slices inside a tile (a 64-lane slice
+    # at a 64-lane offset cost twice the aligned load per page on the v5e)
+    pack = math.gcd(Hkv, _LANES // D) if _LANES % D == 0 else 1
+    W = pack * D
+    own = jnp.arange(Hkv) % pack  # which D lanes of its tile are head g's own
+    if pack > 1:
+        # q into its own lanes, exact zeros in the neighbours'
+        own_lanes = jax.nn.one_hot(own, pack, dtype=qg.dtype)  # [Hkv, pack]
+        qg = (qg[:, :, :, None, :] * own_lanes[None, :, None, :, None]).reshape(B, Hkv, rep_p, W)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # block_tables, lengths — usable in index maps
+        num_scalar_prefetch=3,  # block_tables, lengths, layer — usable in index maps
         grid=(B, M),
         in_specs=[
-            pl.BlockSpec((None, Hkv, rep_p, D), lambda b, s, bt, ln: (b, 0, 0, 0)),
+            pl.BlockSpec((None, Hkv, rep_p, W), lambda b, s, bt, ln, ly: (b, 0, 0, 0)),
             # the paged gather: logical block s of sequence b streams from
-            # physical page bt[b, s] — one DMA per page, no copy
-            pl.BlockSpec((None, bs, Hkv, D), lambda b, s, bt, ln: (bt[b, s], 0, 0, 0)),
-            pl.BlockSpec((None, bs, Hkv, D), lambda b, s, bt, ln: (bt[b, s], 0, 0, 0)),
+            # physical page bt[b, s] of layer ly[0] — one DMA per page, no copy
+            pl.BlockSpec((None, None, bs, row), lambda b, s, bt, ln, ly: (ly[0], bt[b, s], 0, 0)),
+            pl.BlockSpec((None, None, bs, row), lambda b, s, bt, ln, ly: (ly[0], bt[b, s], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((None, Hkv, rep_p, D), lambda b, s, bt, ln: (b, 0, 0, 0)),
+        out_specs=pl.BlockSpec((None, Hkv, rep_p, W), lambda b, s, bt, ln, ly: (b, 0, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((Hkv, rep_p, _LANES), jnp.float32),
             pltpu.VMEM((Hkv, rep_p, _LANES), jnp.float32),
-            pltpu.VMEM((Hkv, rep_p, D), jnp.float32),
+            pltpu.VMEM((Hkv, rep_p, W), jnp.float32),
         ],
     )
     out = pl.pallas_call(
-        functools.partial(_paged_decode_kernel, sm_scale=scale, block_size=bs),
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, rep_p, D), q.dtype),
+        functools.partial(_paged_decode_kernel, sm_scale=scale, block_size=bs, pack=pack),
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, rep_p, W), q.dtype),
         grid_spec=grid_spec,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=_use_interpret(),
-    )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32), qg, k_pages, v_pages)
-    return out[:, :, :n_rep, :].reshape(B, H, D)
+    )(
+        block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
+        jnp.asarray(layer, jnp.int32).reshape(1), qg, k_pool, v_pool,
+    )
+    out = out[:, :, :n_rep]
+    if pack > 1:  # keep each head's own lanes of its tile
+        out = jnp.take_along_axis(
+            out.reshape(B, Hkv, n_rep, pack, D), own[None, :, None, None, None], axis=3
+        )
+    return out.reshape(B, H, D)

@@ -10,7 +10,7 @@ dictated by XLA's static-shape compilation model:
   the static-shape price, paid in exchange for zero recompiles at any
   admission pattern).
 - **Paged KV cache (default).** K/V live in a shared HBM pool of
-  fixed-size pages ``[L, num_blocks, block_size, Hkv, Dh]``; each slot
+  fixed-size pages ``[L, num_blocks, block_size, Hkv*Dh]``; each slot
   names its pages in a static-shape ``int32[B, max_blocks_per_slot]`` block
   table (PagedAttention, Kwon et al. 2023). Admission is block-aware — a
   request is admitted when enough PAGES are free, so HBM capacity is
@@ -59,12 +59,14 @@ from ray_tpu.exceptions import DeadlineExceededError
 from ray_tpu.models.generation import (
     copy_paged_page,
     decode_step,
+    export_paged_page,
     filter_top_k_top_p,
     forward_with_cache,
     init_cache,
     init_paged_cache,
     paged_decode_step,
     paged_forward_with_cache,
+    write_paged_pages,
 )
 from ray_tpu.models.transformer import TransformerConfig
 from ray_tpu.observability import metric_defs
@@ -515,25 +517,16 @@ class LLMEngine:
             # donated so XLA copies the page in place in the pool buffers
             self._copy_page = jax.jit(copy_paged_page, donate_argnums=(0,))
 
-            @functools.partial(jax.jit, donate_argnums=(0,))
-            def _write_blocks(cache, kvs, pages):
-                """Land a migrated block set into the pool in ONE donated
-                scatter: ``kvs`` is ``[N, 2, L, block_size, Hkv, Dh]`` (k
-                then v per block), ``pages`` the destination page of each.
-                Per-block writes cost a dispatch each — 24 blocks of a
-                long prompt stall the engine loop ~10ms on the bench box.
-                Callers bucket-pad N by repeating the last (block, page)
-                pair (identical bytes to the same page, so the duplicate
-                scatter indices stay idempotent), keeping the compile
-                count at O(log blocks), not one per block count."""
-                out = {}
-                for i, kk in enumerate(("k", "v")):
-                    out[kk] = cache[kk].at[:, pages].set(
-                        jnp.swapaxes(kvs[:, i], 0, 1)
-                    )
-                return out
-
-            self._write_blocks = _write_blocks
+            # Land a migrated block set ``[N, 2, L, block_size, Hkv, Dh]`` into
+            # the pool in ONE donated scatter: per-block writes cost a
+            # dispatch each — 24 blocks of a long prompt stall the engine
+            # loop ~10ms on the bench box. Callers bucket-pad N by repeating
+            # the last (block, page) pair (the duplicate scatter indices stay
+            # idempotent), keeping the compile count at O(log blocks), not
+            # one per block count.
+            self._write_blocks = jax.jit(write_paged_pages, donate_argnums=(0,))
+            # the page index is traced: every exported block shares one compile
+            self._export_page = jax.jit(functools.partial(export_paged_page, cfg_))
             self._prefill_chunk = _prefill_chunk
             self._decode_k_paged = _decode_k_paged
 
@@ -1416,14 +1409,12 @@ class LLMEngine:
         n_blocks = -(-tp // bs)
         req.generated = [tok0]
         self._note_first_token(req)
-        # engine-thread-only cache reads: jnp indexing materializes NEW
+        # engine-thread-only cache reads: the exported blocks are NEW
         # buffers, so the copies survive later donated steps
         arrays = []
         for bidx in range(n_blocks):
             page = int(self._block_tables[req.slot, bidx])
-            arrays.append(
-                jnp.stack([self._cache["k"][:, page], self._cache["v"][:, page]])
-            )
+            arrays.append(self._export_page(self._cache, page))
         if arrays:
             jax.block_until_ready(arrays[-1])
         transfer_addr = device_plane.transfer_address()

@@ -1,10 +1,13 @@
 """Paged KV cache + chunked prefill tests.
 
-Four contracts:
+Five contracts:
 - allocator: typed exhaustion shed, no fragmentation across churn, double
   frees raise (leak checks must see corruption, not absorb it)
-- ops: paged_decode_attention == dense decode_attention through a shuffled
-  block table (XLA fallback and interpret-mode Pallas kernel)
+- ops: paged_decode_attention over one layer of the stacked pool == dense
+  decode_attention through a shuffled block table (XLA fallback and
+  interpret-mode Pallas kernel; MHA, GQA, head_dim 64 and 128)
+- runner: the paged pool carried through the layer loop gives the dense
+  cache's logits bit for bit on the CPU
 - engine identity: the paged engine is token-identical to the dense engine
   under greedy decoding, and chunked prefill is token-identical to one-shot
   for every chunk width
@@ -120,41 +123,226 @@ def test_allocator_no_fragmentation_across_churn():
 
 
 # --------------------------------------------------------------------------
-# paged_decode_attention op
+# paged_decode_attention op: one layer of a stacked, lane-dense pool
 # --------------------------------------------------------------------------
-def _paged_op_case(seed=0, B=3, H=8, Hkv=2, D=16, S=64, bs=16):
+# (H, Hkv, D): the cells' MHA shape, two GQA groupings, an aligned head
+OP_SHAPES = {"mha_d64": (4, 4, 64), "gqa8_2_d64": (8, 2, 64), "gqa4_1_d128": (4, 1, 128)}
+op_shapes = pytest.mark.parametrize("shape", list(OP_SHAPES.values()), ids=list(OP_SHAPES))
+
+
+def _paged_op_case(shape, seed=0, S=64, bs=16, layers=3):
+    """q, a 3-layer pool ``[L, N, bs, Hkv*D]`` whose layers hold different
+    data, a shuffled table, lengths with an empty row, a partial last page
+    and a full row — and, per layer, the dense decode kernel's answer over
+    that layer's gathered pages."""
     from ray_tpu.ops.decode_attention import decode_attention
 
+    H, Hkv, D = shape
     rng = np.random.default_rng(seed)
-    M = S // bs
+    lengths = jnp.asarray([5, S, 17, 0], jnp.int32)
+    B, M = len(lengths), S // bs
     N = B * M + 1
     q = jnp.asarray(rng.normal(size=(B, H, D)), jnp.float32)
-    k_pages = jnp.asarray(rng.normal(size=(N, bs, Hkv, D)), jnp.float32)
-    v_pages = jnp.asarray(rng.normal(size=(N, bs, Hkv, D)), jnp.float32)
+    k_pool = jnp.asarray(rng.normal(size=(layers, N, bs, Hkv * D)), jnp.float32)
+    v_pool = jnp.asarray(rng.normal(size=(layers, N, bs, Hkv * D)), jnp.float32)
     # shuffled table: physical placement must not matter
     perm = rng.permutation(np.arange(1, N))
     bt = jnp.asarray(perm[: B * M].reshape(B, M).astype(np.int32))
-    lengths = jnp.asarray([5, S, 17], jnp.int32)
-    kd = jnp.transpose(jnp.take(k_pages, bt, axis=0), (0, 3, 1, 2, 4)).reshape(B, Hkv, S, D)
-    vd = jnp.transpose(jnp.take(v_pages, bt, axis=0), (0, 3, 1, 2, 4)).reshape(B, Hkv, S, D)
-    ref = decode_attention(q, kd, vd, lengths)
-    return q, k_pages, v_pages, bt, lengths, ref
+
+    def dense(pool, layer):
+        return jnp.transpose(pool[layer][bt].reshape(B, S, Hkv, D), (0, 2, 1, 3))
+
+    refs = [decode_attention(q, dense(k_pool, l), dense(v_pool, l), lengths) for l in range(layers)]
+    return q, k_pool, v_pool, bt, lengths, refs
 
 
-def test_paged_decode_attention_matches_dense_xla():
+@op_shapes
+def test_paged_decode_attention_matches_dense_xla(shape):
     from ray_tpu.ops.decode_attention import paged_decode_attention
 
-    q, kp, vp, bt, lengths, ref = _paged_op_case()
-    out = paged_decode_attention(q, kp, vp, bt, lengths, use_kernel=False)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
+    q, kp, vp, bt, lengths, refs = _paged_op_case(shape)
+    out = paged_decode_attention(q, kp, vp, bt, lengths, 1, use_kernel=False)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(refs[1]), atol=1e-5)
 
 
-def test_paged_decode_attention_kernel_interpret():
+@op_shapes
+def test_paged_decode_attention_kernel_interpret(shape):
     from ray_tpu.ops.decode_attention import paged_decode_attention
 
-    q, kp, vp, bt, lengths, ref = _paged_op_case(seed=3)
-    out = paged_decode_attention(q, kp, vp, bt, lengths, use_kernel=True)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
+    q, kp, vp, bt, lengths, refs = _paged_op_case(shape, seed=3)
+    out = paged_decode_attention(q, kp, vp, bt, lengths, 1, use_kernel=True)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(refs[1]), atol=1e-5)
+    xla = paged_decode_attention(q, kp, vp, bt, lengths, 1, use_kernel=False)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(xla), atol=1e-5)
+    assert not np.asarray(out[3]).any()  # lengths == 0: zeros, not garbage-V means
+
+
+@pytest.mark.parametrize("use_kernel", [True, False], ids=["kernel", "xla"])
+@pytest.mark.parametrize("layer", [0, 1, 2])
+def test_paged_decode_attention_reads_the_named_layer(layer, use_kernel):
+    """The layer index steers the page DMAs: every layer of the pool gives
+    its own answer (traced, as the runner's layer loop passes it), and no
+    other layer's."""
+    from ray_tpu.ops.decode_attention import paged_decode_attention
+
+    q, kp, vp, bt, lengths, refs = _paged_op_case(OP_SHAPES["gqa8_2_d64"], seed=5)
+    fn = jax.jit(lambda l: paged_decode_attention(q, kp, vp, bt, lengths, l, use_kernel=use_kernel))
+    out = np.asarray(fn(jnp.int32(layer)))
+    np.testing.assert_allclose(out, np.asarray(refs[layer]), atol=1e-5)
+    for other in set(range(3)) - {layer}:
+        assert np.abs(out - np.asarray(refs[other]))[:3].max() > 1e-2
+
+
+# --------------------------------------------------------------------------
+# the model runner: paged pool == dense cache, in logits
+# --------------------------------------------------------------------------
+def _runner_cfg(shape):
+    H, Hkv, D = shape
+    return TransformerConfig(
+        vocab_size=61, d_model=H * D, n_layers=3, n_heads=H, n_kv_heads=Hkv, d_ff=64,
+        attention="dense", dtype=jnp.float32,
+    )
+
+
+def _dense_and_paged_logits(cfg, params, *, use_decode_kernel=False, layer_scales=None):
+    """Two ragged prompts prefilled in 8-wide pieces (the last one padded; the
+    paged side masks it with ``valid``) and decoded 3 tokens, call for call
+    through the dense cache and through a shuffled pool of 4-token pages."""
+    from ray_tpu.models.generation import (
+        forward_with_cache, init_cache, init_paged_cache, paged_decode_step,
+        paged_forward_with_cache,
+    )
+
+    rng = np.random.default_rng(1)
+    chunk, steps, bs = 8, 3, 4
+    lens = [11, 6]
+    B, S = len(lens), 32
+    M = S // bs
+    prompts = [rng.integers(1, cfg.vocab_size, n) for n in lens]
+    nxt = jnp.asarray(rng.integers(1, cfg.vocab_size, (steps, B)), jnp.int32)
+    pos = jnp.asarray(lens, jnp.int32)
+    kw = {"layer_scales": layer_scales}
+    dense, dense_out = init_cache(cfg, B, S), []
+    pool, paged_out = init_paged_cache(cfg, B * M + 1, bs), []
+    bt = jnp.asarray(rng.permutation(np.arange(1, B * M + 1)).reshape(B, M).astype(np.int32))
+
+    for b, prompt in enumerate(prompts):
+        row = init_cache(cfg, 1, S)
+        for start in range(0, len(prompt), chunk):
+            piece = prompt[start:start + chunk]
+            toks = np.zeros((1, chunk), np.int64)
+            toks[0, : len(piece)] = piece
+            positions = (start + jnp.arange(chunk))[None]
+            d_logits, row = forward_with_cache(cfg, params, row, jnp.asarray(toks), positions, **kw)
+            p_logits, pool = paged_forward_with_cache(
+                cfg, params, pool, bt[b:b + 1], jnp.asarray(toks), positions,
+                valid=(jnp.arange(chunk) < len(piece))[None], use_decode_kernel=False, **kw)
+        dense = {kk: dense[kk].at[:, b].set(row[kk][:, 0]) for kk in dense}
+        dense_out.append(d_logits[0, len(piece) - 1])
+        paged_out.append(p_logits[0, len(piece) - 1])
+    for t in range(steps):
+        d_logits, dense = forward_with_cache(
+            cfg, params, dense, nxt[t][:, None], (pos + t)[:, None], use_decode_kernel=False, **kw)
+        p_logits, pool = paged_decode_step(
+            cfg, params, pool, nxt[t], pos + t, bt, use_decode_kernel=use_decode_kernel, **kw)
+        dense_out.append(d_logits[:, 0])
+        paged_out.append(p_logits)
+    assert np.asarray(pool["k"][:, 0]).any()  # the padded tail's writes went to the garbage page
+    return dense_out, paged_out
+
+
+@op_shapes
+def test_paged_runner_bit_identical_to_dense_cache(shape):
+    """Chunked prefill (with a masked, padded tail) and decode through the
+    carried pool give the dense cache's logits bit for bit on the CPU."""
+    cfg = _runner_cfg(shape)
+    dense, paged = _dense_and_paged_logits(cfg, init_params(cfg, jax.random.key(2)))
+    for d, p in zip(dense, paged):
+        np.testing.assert_array_equal(np.asarray(d), np.asarray(p))
+
+
+@op_shapes
+def test_paged_runner_decode_kernel_matches_dense_cache(shape):
+    """The same with single-token steps through the (interpret-mode) Pallas
+    kernel reading the stacked pool at the loop's layer index."""
+    cfg = _runner_cfg(shape)
+    dense, paged = _dense_and_paged_logits(
+        cfg, init_params(cfg, jax.random.key(2)), use_decode_kernel=True)
+    for d, p in zip(dense, paged):
+        np.testing.assert_allclose(np.asarray(d), np.asarray(p), atol=2e-4, rtol=2e-4)
+
+
+def test_paged_runner_int8_layer_scales_bit_identical_to_dense_cache():
+    """The int8 path's scales ride the layer loop's xs beside the layers and
+    the layer index; the pool still rides the carry."""
+    from ray_tpu.ops.quantization import quantize_layers
+
+    cfg = _runner_cfg(OP_SHAPES["gqa8_2_d64"])
+    params = init_params(cfg, jax.random.key(2))
+    layers_q, scales = quantize_layers(params["layers"])
+    dense, paged = _dense_and_paged_logits(cfg, {**params, "layers": layers_q}, layer_scales=scales)
+    for d, p in zip(dense, paged):
+        np.testing.assert_array_equal(np.asarray(d), np.asarray(p))
+
+
+def test_migration_blocks_round_trip_through_the_pool():
+    """Exported pages keep the ticket's block format (heads named) and land
+    in another pool's pages byte for byte, duplicates of the bucket padding
+    included."""
+    from ray_tpu.models.generation import export_paged_page, init_paged_cache, write_paged_pages
+
+    cfg = _runner_cfg(OP_SHAPES["gqa8_2_d64"])
+    bs, rng = 4, np.random.default_rng(4)
+    shape = init_paged_cache(cfg, 6, bs)["k"].shape
+    src = {kk: jnp.asarray(rng.normal(size=shape), jnp.float32) for kk in ("k", "v")}
+    blocks = [export_paged_page(cfg, src, page) for page in (3, 1, 5)]
+    assert blocks[0].shape == (2, cfg.n_layers, bs, cfg.kv_heads, cfg.head_dim)
+    # head 1 of the block is the second Dh-wide run of the pool's row
+    np.testing.assert_array_equal(
+        np.asarray(blocks[0][0, :, :, 1]), np.asarray(src["k"][:, 3, :, cfg.head_dim:]))
+    dst = write_paged_pages(
+        init_paged_cache(cfg, 6, bs), jnp.stack(blocks + blocks[-1:]), jnp.asarray([2, 4, 1, 1]))
+    for kk in ("k", "v"):
+        np.testing.assert_array_equal(np.asarray(dst[kk][:, [2, 4, 1]]), np.asarray(src[kk][:, [3, 1, 5]]))
+        assert not np.asarray(dst[kk][:, [0, 3, 5]]).any()
+    for got, want in zip((export_paged_page(cfg, dst, page) for page in (2, 4, 1)), blocks):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_paged_cache_spec_shards_heads_not_page_tokens():
+    """Under a mesh the pool splits over KV heads (the leading factor of its
+    minor axis), each device holding whole heads of every page; the runner
+    keeps that sharding on the cache it returns and computes the same
+    logits as unsharded."""
+    from jax.sharding import Mesh, NamedSharding
+
+    from ray_tpu.models.generation import (
+        init_paged_cache, paged_cache_spec, paged_forward_with_cache)
+
+    cfg = _runner_cfg(OP_SHAPES["gqa8_2_d64"])
+    params = init_params(cfg, jax.random.key(2))
+    bs, M = 4, 4
+    pool = init_paged_cache(cfg, M + 1, bs)
+    bt = jnp.arange(1, M + 1, dtype=jnp.int32)[None]
+    toks = jnp.arange(1, 9, dtype=jnp.int32)[None]
+    pos = jnp.arange(8)[None]
+    ref_logits, ref_pool = paged_forward_with_cache(cfg, params, pool, bt, toks, pos)
+
+    mesh = Mesh(np.array(jax.devices()[:2]), ("tp",))
+    sharding = NamedSharding(mesh, paged_cache_spec("tp"))
+    sharded = {kk: jax.device_put(v, sharding) for kk, v in pool.items()}
+    shard = sharded["k"].addressable_shards[0].data.shape
+    assert shard == (cfg.n_layers, M + 1, bs, cfg.head_dim)  # one of two heads; whole pages
+    logits, out = jax.jit(
+        lambda c: paged_forward_with_cache(cfg, params, c, bt, toks, pos), out_shardings=(None, sharding)
+    )(sharded)
+    np.testing.assert_allclose(np.asarray(logits), np.asarray(ref_logits), atol=1e-5)
+    for kk in ("k", "v"):
+        np.testing.assert_allclose(np.asarray(out[kk]), np.asarray(ref_pool[kk]), atol=1e-5)
+        # device 0 holds head 0 of every token of every page
+        np.testing.assert_allclose(
+            np.asarray(out[kk].addressable_shards[0].data),
+            np.asarray(ref_pool[kk][..., : cfg.head_dim]), atol=1e-5)
 
 
 # --------------------------------------------------------------------------

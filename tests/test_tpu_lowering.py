@@ -90,12 +90,19 @@ def _kernel_cases():
         yield f"decode_dense_S{S}_{dt.__name__}", decode_attention, [
             ((B, H, D), dt), ((B, Hkv, S, D), dt), ((B, Hkv, S, D), dt), ((B,), I32)]
     B, H, Hkv, D = g["B"], g["H"], g["Hkv"], g["D"]
+
+    def paged(H, Hkv, D, bs, dt):
+        M = g["M"] * g["bs"] // bs
+        pool = ((2, B * M + 1, bs, Hkv * D), dt)  # two layers: the index map picks one
+        return [((B, H, D), dt), pool, pool, ((B, M), I32), ((B,), I32), ((), I32)]
+
     for bs in (g["bs"], 128):
         for dt in (BF16, F32):
-            M = g["M"] * g["bs"] // bs
-            pool = ((B * M + 1, bs, Hkv, D), dt)
-            yield f"decode_paged_bs{bs}_{dt.__name__}", paged_decode_attention, [
-                ((B, H, D), dt), pool, pool, ((B, M), I32), ((B,), I32)]
+            yield f"decode_paged_bs{bs}_{dt.__name__}", paged_decode_attention, paged(H, Hkv, D, bs, dt)
+    # the heads are lane tiles of the page row: MHA at the cells' 64-wide head
+    # (two heads to a 128-lane tile), and one 128-wide head (its own tile) under GQA
+    yield "decode_paged_mha32_d64", paged_decode_attention, paged(32, 32, 64, g["bs"], BF16)
+    yield "decode_paged_gqa4_1_d128", paged_decode_attention, paged(4, 1, 128, g["bs"], BF16)
     yield "int8_matmul", int8_matmul, [((512, 1024), BF16), ((1024, 1024), jnp.int8), ((1024,), F32)]
 
 
@@ -104,6 +111,13 @@ KERNEL_CASES = list(_kernel_cases())
 
 def _abstract(specs, sharding=None):
     return [jax.ShapeDtypeStruct(shape, dt, sharding=sharding) for shape, dt in specs]
+
+
+def _abstract_tree(make, sharding=None):
+    """The shapes of what ``make()`` would build, placed by ``sharding``."""
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding), jax.eval_shape(make)
+    )
 
 
 def _lower_for_tpu(fn, args):
@@ -134,13 +148,8 @@ def _engine_decode_args(sharding=None):
     bs = 16  # Config.kv_block_size default
     M = s["seq"] // bs
 
-    def abstract(tree):
-        return jax.tree.map(
-            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding), tree
-        )
-
-    params = abstract(jax.eval_shape(lambda: init_params(cfg, jax.random.key(0))))
-    cache = abstract(jax.eval_shape(lambda: init_paged_cache(cfg, s["slots"] * M + 1, bs)))
+    params = _abstract_tree(lambda: init_params(cfg, jax.random.key(0)), sharding)
+    cache = _abstract_tree(lambda: init_paged_cache(cfg, s["slots"] * M + 1, bs), sharding)
     toks, bt = _abstract([((s["slots"],), I32), ((s["slots"], M), I32)], sharding)
     return cfg, (params, cache, toks, toks, bt)
 
@@ -159,6 +168,64 @@ def test_engine_paged_decode_program_lowers_for_tpu(as_chip):
 def test_engine_paged_decode_program_compiles_for_v5e(as_chip, v5e):
     cfg, args = _engine_decode_args(SingleDeviceSharding(v5e[0]))
     _lower_for_tpu(_engine_decode_step(cfg), args).compile()
+
+
+def _pool_sized_ops(hlo, sizes):
+    """The optimized HLO's ``copy`` / ``dynamic-slice`` / ``dynamic-update-slice``
+    instructions (fused or not) whose result has one of ``sizes`` elements."""
+    found = []
+    for line in hlo.splitlines():
+        m = re.match(r"\s*(?:ROOT )?\S+ = \w+\[([\d,]*)\]\S* (copy|dynamic-slice|dynamic-update-slice)\(", line)
+        if m and int(np.prod([int(d) for d in m.group(1).split(",") if d])) in sizes:
+            found.append(line.strip()[:160])
+    return found
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill_chunk"])
+def test_engine_paged_programs_update_the_pool_in_place(as_chip, v5e, program):
+    """The serve programs with the cache donated, at a pool whose one layer
+    is 64 MiB: the compiler needs less scratch than one layer's slice of one
+    pool, and no instruction copies, slices out or writes back a layer's or
+    a whole pool's worth of elements. (The pool used to be re-laid-out per
+    layer and stacked into a second pool: 5.4 GiB of temporaries at the
+    benchmark's size, and more device time than the attention kernel.)"""
+    import dataclasses
+
+    from ray_tpu.models import init_params
+    from ray_tpu.models.generation import (
+        init_paged_cache, paged_decode_step, paged_forward_with_cache)
+
+    # a small vocabulary: what is left among the temporaries is the weights the
+    # compiler prefetches into on-chip memory (``S(1)``), ~20 MiB here
+    cfg = dataclasses.replace(serving_config(), n_layers=4, vocab_size=4096)
+    one = SingleDeviceSharding(v5e[0])
+    B, bs, M, N, C = 8, 16, 64, 4096, 256
+
+    params = _abstract_tree(lambda: init_params(cfg, jax.random.key(0)), one)
+    cache = _abstract_tree(lambda: init_paged_cache(cfg, N, bs), one)
+    layer_elems = int(np.prod(cache["k"].shape[1:]))
+    layer_bytes = layer_elems * cache["k"].dtype.itemsize
+    assert layer_bytes == 64 * 2**20
+
+    if program == "decode":
+        def fn(params, cache, toks, pos, bt):
+            return paged_decode_step(cfg, params, cache, toks, pos, bt)
+
+        args = _abstract([((B,), I32), ((B,), I32), ((B, M), I32)], one)
+    else:
+        def fn(params, cache, toks, bt, start, length):
+            positions = start + jnp.arange(C)[None, :]
+            valid = (jnp.arange(C) < length)[None, :]
+            return paged_forward_with_cache(
+                cfg, params, cache, bt, toks, positions, valid=valid, use_decode_kernel=False)
+
+        args = _abstract([((1, C), I32), ((1, M), I32), ((), I32), ((), I32)], one)
+    compiled = jax.jit(fn, donate_argnums=(1,)).trace(params, cache, *args).lower(
+        lowering_platforms=("tpu",)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * cfg.n_layers * layer_bytes  # both pools donated through
+    assert mem.temp_size_in_bytes < layer_bytes, f"{mem.temp_size_in_bytes / 2**20:.1f} MiB of temporaries"
+    assert _pool_sized_ops(compiled.as_text(), {layer_elems, cfg.n_layers * layer_elems}) == []
 
 
 # --------------------------------------------------------------------------
