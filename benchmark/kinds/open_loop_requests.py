@@ -4,7 +4,9 @@ whether or not earlier ones have finished.
 Parameters (the traffic file): ``rate`` (requests/s), ``prompt_tokens`` and
 ``output_tokens`` (distributions, see ``stratify.stratified_sizes``),
 ``ramp_s`` (the same process before the window: served, not scored),
-``first_token_timeout_s``.
+``first_token_timeout_s``, and above the knee ``"backlog": "expected"``
+(``serving.backlog_close``; the order of first tokens is then judged:
+``serving.overtaken``).
 """
 
 from __future__ import annotations
@@ -14,7 +16,7 @@ from typing import Any, Dict, List
 
 import numpy as np
 
-from benchmark import serving, stratify
+from benchmark import serving, stratify, system
 
 
 def schedule(p: Dict[str, Any], seconds: float) -> List[Dict[str, Any]]:
@@ -39,6 +41,15 @@ def schedule(p: Dict[str, Any], seconds: float) -> List[Dict[str, Any]]:
     return out
 
 
+def served_check_prompt_tokens(run: Dict[str, Any]) -> List[int]:
+    """Prompt lengths of the served-path check, requests like the traffic's
+    own: a short prompt, one of two prefill chunks, and one of three or as
+    long as the logits check's longest (``run.correctness.max_prompt``),
+    whichever is longer."""
+    C = run["prefill_chunk_tokens"]
+    return [C // 4, C + C // 3, max(2 * C + C // 2, int(run["correctness"]["max_prompt"]))]
+
+
 def run(ctx) -> Dict[str, Any]:
     return serving.run_served(ctx, drive)
 
@@ -49,9 +60,7 @@ def drive(ctx, served: serving.Served, p: Dict[str, Any], seconds: float, seed=N
     plan = schedule(p, seconds)
     rng = np.random.default_rng([seed, 2])
     vocab = served.cfg.vocab_size
-    # requests like the traffic's own: a short prompt, one of two prefill chunks, one of three
-    C = served.run["prefill_chunk_tokens"]
-    served.check_served([[rng.integers(1, vocab, size=n).tolist()] for n in (C // 4, C + C // 3, 2 * C + C // 2)])
+    served.check_served([[rng.integers(1, vocab, size=n).tolist()] for n in served_check_prompt_tokens(served.run)])
     turns = [
         serving.Turn(r["due"], rng.integers(1, vocab, size=r["prompt_len"]).tolist(),
                      r["max_tokens"], r["scored"])
@@ -71,7 +80,7 @@ def drive(ctx, served: serving.Served, p: Dict[str, Any], seconds: float, seed=N
         threads.append(th)
     serving.sleep_until(window[1])
     probe.window_closed()
-    serving.wait_for_first_tokens(turns, float(p["first_token_timeout_s"]))
+    serving.wait_for_first_tokens(turns, float(p["first_token_timeout_s"]), serving.backlog_close(p, window))
     stop.set()
     for th in threads:
         th.join(timeout=30)
@@ -80,5 +89,19 @@ def drive(ctx, served: serving.Served, p: Dict[str, Any], seconds: float, seed=N
 
 def finish(ctx, served, p, turns, window, probe) -> Dict[str, Any]:
     m = serving.serve_metrics(turns, window)
-    verdict = serving.judge(turns, served.cfg.vocab_size, served.correctness)
-    return {"window": window, "turns": turns, "values": m, "probe": probe, "served": served, **verdict}
+    memory_peak = system.memory_peak_bytes(ctx.cell["chips"])  # before the reference runs beside the engine
+    served.correctness["window sample"] = serving.check_window_against_reference(served, turns, window, ctx.seed)
+    ctx.log(f"the window's finished requests against the reference: {served.correctness['window sample']}")
+    close = serving.backlog_close(p, window)
+    verdict = serving.judge(turns, served.cfg.vocab_size, served.correctness, close)
+    compared = {"failed_requests": [verdict["failed"], 0]}
+    if close is not None:
+        ctx.log(f"{verdict['waiting']} scored requests were still waiting for a first token when the window closed")
+        compared["overtaken_requests"] = [verdict["overtaken"], serving.OVERTAKEN_LIMIT]
+    for name, check, value, limit in (("paged_rel_err", "paged runner", "rel_err", "rel_tol"),
+                                      ("served_worst_deficit_sd", "served path", "worst_deficit_sd", "near_tie_sd"),
+                                      ("window_worst_deficit_sd", "window sample", "worst_deficit_sd", "near_tie_sd")):
+        if value in served.correctness.get(check, {}):
+            compared[name] = [served.correctness[check][value], served.correctness[check][limit]]
+    return {"window": window, "turns": turns, "values": m, "probe": probe, "served": served,
+            "compared": compared, "memory_peak_bytes": memory_peak, **verdict}

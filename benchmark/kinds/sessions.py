@@ -112,7 +112,7 @@ def drive(ctx, served: serving.Served, p: Dict[str, Any], seconds: float, seed=N
     probe.window_closed()
     with lock:
         snapshot = list(turns)
-    serving.wait_for_first_tokens(snapshot, float(p["first_token_timeout_s"]))
+    serving.wait_for_first_tokens(snapshot, float(p["first_token_timeout_s"]), serving.backlog_close(p, window))
     stop.set()
     for th in threads:
         th.join(timeout=30)

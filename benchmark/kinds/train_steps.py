@@ -113,4 +113,5 @@ def run(ctx) -> Dict[str, Any]:
         "reasons": reasons, "probe": probe, "steps": inside, "losses": in_window_losses,
         "tokens_per_step": tokens_per_step, "correctness": correctness,
         "flops_per_token": model.train_flops_per_token(config, T),
+        "compared": {"first_loss_rel_err": [correctness["rel_err"], tol], "nonfinite_losses": [bad, 0]},
     }

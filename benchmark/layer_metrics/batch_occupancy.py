@@ -1,11 +1,7 @@
 """Mean ``active_slots`` of the engine's ``stats()``, sampled twice a
 second inside the window."""
+from benchmark import readers
 
 
 def read(run):
-    sampler = run["probe"].sampler
-    if sampler is None:
-        return None
-    w0, w1 = run["window"]
-    xs = [s["active_slots"] for t, s in sampler.samples if w0 <= t <= w1]
-    return sum(xs) / len(xs) if xs else None
+    return readers.mean_or_none([s["active_slots"] for s in readers.stats_in_window(run)])

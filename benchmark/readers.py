@@ -35,6 +35,20 @@ def quantile_or_none(values, q):
     return yardstick.quantile(values, q) if values else None
 
 
+def stats_in_window(run) -> List[Dict[str, Any]]:
+    """The engine's ``stats()`` as the sampler read them inside the window
+    (twice a second); empty where the run sampled nothing."""
+    sampler = run["probe"].sampler
+    if sampler is None:
+        return []
+    w0, w1 = run["window"]
+    return [s for t, s in sampler.samples if w0 <= t <= w1]
+
+
+def mean_or_none(values):
+    return sum(values) / len(values) if values else None
+
+
 def counter_delta(run, key: str) -> Optional[float]:
     p = run["probe"]
     if p.stats_open is None or p.stats_close is None:

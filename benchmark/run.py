@@ -211,7 +211,7 @@ def main(argv=None) -> int:
     probe.read_trace()
     run["events"] = probe.events
 
-    device["memory_peak_bytes"] = system.memory_peak_bytes(cell["chips"])
+    device["memory_peak_bytes"] = run.get("memory_peak_bytes") or system.memory_peak_bytes(cell["chips"])
     metrics = {}
     if not args.trace:
         values = dict(run["values"], setup_s=run["setup_s"])
@@ -245,6 +245,10 @@ def main(argv=None) -> int:
         # no time from a rehearsal may stand under a metric's name
         line = {"rehearsal_host_only": True, "correct": line["correct"], "attempted": line["attempted"],
                 "failed": line["failed"], "metric_names": sorted(metrics), "device": system.device_info()}
+    # each number that decided ``correct`` beside its limit: last on stderr, and last in the line
+    line["compared"] = {k: {"value": v, "limit": lim} for k, (v, lim) in run.get("compared", {}).items()}
+    for k, x in line["compared"].items():
+        log(f"compared {k}: {x['value']} (limit {x['limit']})")
     sys.stdout.flush()
     print(json.dumps(line), flush=True)
     return 0

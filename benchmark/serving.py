@@ -59,7 +59,11 @@ def check_paged_against_reference(cfg, params, config: Dict[str, Any], seed: int
     """Prefill, then a few decode steps, through the paged cache (the model
     runner the engine jits: ``paged_forward_with_cache`` with its decode
     kernel as the backend selects it) against the reference's full forward
-    pass, in logits."""
+    pass, in logits. A prompt longer than one chunk is prefilled as the
+    engine prefills it: in successive chunks of ``prefill_chunk_tokens`` at
+    running start positions through one program; the longest prompt is then
+    ``max_prompt`` long. With ``max_prompt`` within one chunk the prompts,
+    shapes and draws are those of PR 24."""
     import jax
     import jax.numpy as jnp
 
@@ -69,20 +73,20 @@ def check_paged_against_reference(cfg, params, config: Dict[str, Any], seed: int
     run, cc = config["run"], config["run"]["correctness"]
     n, maxp, k = cc["prompts"], cc["max_prompt"], cc["decode_steps"]
     C, bs = run["prefill_chunk_tokens"], run["kv_block_size"]
-    assert maxp <= C, "the sampled prompts fit one prefill chunk"
     rng = np.random.default_rng([seed, 7])
     lens = rng.integers(max(2, maxp // 4), maxp + 1, size=n)
+    if maxp > C:
+        lens[0] = maxp  # the check always holds a prompt of every chunk count up to the longest
     prompts = [rng.integers(1, cfg.vocab_size, size=int(L)) for L in lens]
-    M = -(-(C + k) // bs)
+    M = -(-(-(-maxp // C) * C + k) // bs)  # pages for the chunk-padded longest prompt and the decoded tokens
     cache = init_paged_cache(cfg, n * M + 1, bs)
     bt = jnp.asarray(np.arange(1, n * M + 1, dtype=np.int32).reshape(n, M))
 
     @jax.jit
-    def prefill(params, cache, toks, bt, length):
-        positions = jnp.arange(C)[None, :]
+    def prefill(params, cache, toks, bt, start, length):  # one chunk at a traced start, as the engine's ``_prefill_chunk``
         valid = (jnp.arange(C) < length)[None, :]
         logits, cache = paged_forward_with_cache(
-            cfg, params, cache, bt, toks, positions, valid=valid, use_decode_kernel=False)
+            cfg, params, cache, bt, toks, start + jnp.arange(C)[None, :], valid=valid, use_decode_kernel=False)
         return jax.lax.dynamic_index_in_dim(logits[0], length - 1, 0, keepdims=False), cache
 
     @jax.jit
@@ -91,9 +95,11 @@ def check_paged_against_reference(cfg, params, config: Dict[str, Any], seed: int
 
     first = []
     for i, p in enumerate(prompts):
-        toks = np.zeros((1, C), np.int32)
-        toks[0, : len(p)] = p
-        lg, cache = prefill(params, cache, jnp.asarray(toks), bt[i : i + 1], jnp.int32(len(p)))
+        for start in range(0, len(p), C):
+            piece = p[start : start + C]
+            toks = np.zeros((1, C), np.int32)
+            toks[0, : len(piece)] = piece
+            lg, cache = prefill(params, cache, jnp.asarray(toks), bt[i : i + 1], jnp.int32(start), jnp.int32(len(piece)))
         first.append(lg)
     got = [jnp.stack(first)]  # [n, V] at position len - 1
     generated = []
@@ -123,6 +129,30 @@ def check_paged_against_reference(cfg, params, config: Dict[str, Any], seed: int
             "vectors": int(n * (1 + k)), "ok": finite and err < cc["rel_tol"]}
 
 
+def served_token_deficits(served: "Served", ref_logits, prompt: List[int], tokens: List[int]) -> List[float]:
+    """One pass of the reference over ``prompt`` and the tokens served after
+    it: by how much each served token's logit lies under the reference's best
+    at its position, in units of that position's logit spread (0 for the
+    reference's own top choice)."""
+    import jax.numpy as jnp
+
+    k = len(tokens)
+    seq = prompt + tokens[:-1]
+    padded = np.zeros(-(-len(seq) // 512) * 512, np.int32)  # causal: what follows changes nothing before it
+    padded[: len(seq)] = seq
+    positions = np.minimum(len(prompt) - 1 + np.arange(-(-k // 64) * 64), len(seq) - 1)  # few shapes to compile
+    lg = np.asarray(ref_logits(served.params, jnp.asarray(padded), jnp.asarray(positions)))[:k]
+    got = lg[np.arange(k), np.asarray(tokens)]
+    return ((lg.max(-1) - got) / lg.std(-1)).tolist()
+
+
+def deficits_verdict(deficits: List[float], margin: float, t0: float) -> Dict[str, Any]:
+    worst = max(deficits)
+    return {"ok": bool(np.isfinite(worst) and worst <= margin), "tokens": len(deficits),
+            "not_top1": sum(1 for d in deficits if d > 0), "worst_deficit_sd": worst,
+            "near_tie_sd": margin, "seconds": now() - t0}
+
+
 def check_served_against_reference(served: "Served", conversations: List[List[List[int]]]) -> Dict[str, Any]:
     """The served path itself, as the traffic uses it. A conversation is a
     list of pieces of new tokens: its j-th request is everything before it
@@ -133,8 +163,6 @@ def check_served_against_reference(served: "Served", conversations: List[List[Li
     ``near_tie_sd`` of the top in units of that position's logit spread
     (bf16 may swap near-ties; a wrong context lands some 3-5 spreads below,
     PERF.md section 4)."""
-    import jax.numpy as jnp
-
     cc = served.run["correctness"]
     k, margin = int(cc["served_tokens"]), float(cc["near_tie_sd"])
     ref_logits, _ = system.model_module(served.config).make_reference(served.config)
@@ -148,17 +176,39 @@ def check_served_against_reference(served: "Served", conversations: List[List[Li
             served.stream(turn, threading.Event())
             if turn.error or len(turn.tokens) != k:
                 return {"ok": False, "why": f"a checked request failed: {turn.error or turn.tokens}"}
-            seq = prompt + turn.tokens[:-1]
-            padded = np.zeros(-(-len(seq) // 512) * 512, np.int32)  # causal: what follows changes nothing before it
-            padded[: len(seq)] = seq
-            lg = np.asarray(ref_logits(served.params, jnp.asarray(padded), jnp.arange(len(prompt) - 1, len(seq))))
-            got = lg[np.arange(k), np.asarray(turn.tokens)]
-            deficits.extend(((lg.max(-1) - got) / lg.std(-1)).tolist())
+            deficits.extend(served_token_deficits(served, ref_logits, prompt, turn.tokens))
             history = prompt + turn.tokens
-    worst = max(deficits)
-    return {"ok": bool(np.isfinite(worst) and worst <= margin), "tokens": len(deficits),
-            "not_top1": sum(1 for d in deficits if d > 0), "worst_deficit_sd": worst,
-            "near_tie_sd": margin, "seconds": now() - t0}
+    return deficits_verdict(deficits, margin, t0)
+
+
+WINDOW_SAMPLE = 4  # finished requests of the window that are held to the reference once it has closed
+
+
+def check_window_against_reference(served: "Served", turns: List[Turn], window, seed: int) -> Dict[str, Any]:
+    """What the timed path itself produced, under the window's own load:
+    once the window has closed, ``WINDOW_SAMPLE`` of the requests that
+    finished since it opened (the longest in prompt and reply, and the rest
+    drawn from the seed; the ramp's count, they were served in the window
+    too) go through the reference once each, prompt and served tokens, and
+    every served token is held to ``near_tie_sd`` as in
+    ``check_served_against_reference``. Greedy tokens only: the traffic
+    samples none."""
+    margin = float(served.run["correctness"]["near_tie_sd"])
+    finished = [t for t in turns if not t.error and t.tokens and len(t.tokens) == t.max_tokens
+                and t.token_times[-1] >= window[0]]
+    if not finished:
+        return {"ok": False, "why": "no request finished in the window: nothing to hold to the reference"}
+    finished.sort(key=lambda t: (-(t.prompt_len + t.max_tokens), t.due))
+    rest = finished[1:]
+    picks = np.random.default_rng([seed, 11]).permutation(len(rest))[: WINDOW_SAMPLE - 1]
+    sample = [finished[0]] + [rest[int(i)] for i in picks]
+    ref_logits, _ = system.model_module(served.config).make_reference(served.config)
+    t0 = now()
+    deficits: List[float] = []
+    for t in sample:
+        deficits.extend(served_token_deficits(served, ref_logits, t.prompt, t.tokens))
+    return dict(deficits_verdict(deficits, margin, t0), requests=len(sample), finished=len(finished),
+                longest=sample[0].prompt_len + sample[0].max_tokens)
 
 
 class Served:
@@ -304,12 +354,31 @@ def first_k(turn: Turn) -> int:
     return min(FIRST_K, turn.max_tokens)
 
 
-def wait_for_first_tokens(turns: List[Turn], timeout_s: float) -> None:
-    """After the window closes: wait only until every scored request has its
-    first ``FIRST_K`` tokens (or has failed), at most ``timeout_s``."""
+def backlog_close(p: Dict[str, Any], window) -> Optional[float]:
+    """The window's close if the traffic file says ``"backlog": "expected"``
+    (a rate above the knee: requests still waiting for a first token when the
+    window closes are what an overloaded open loop is), else None."""
+    if p.get("backlog") is None:
+        return None
+    if p["backlog"] != "expected":
+        raise ValueError(f'traffic key "backlog" is "expected" or absent, not {p["backlog"]!r}')
+    return window[1]
+
+
+def answered_by(t: Turn, close: Optional[float]) -> bool:
+    """Whether ``t`` is judged: always, or (a backlog is expected) only if by
+    ``close`` it had a first token or had been answered with an error."""
+    return close is None or bool(t.error) or (bool(t.token_times) and t.token_times[0] <= close)
+
+
+def wait_for_first_tokens(turns: List[Turn], timeout_s: float, close: Optional[float] = None) -> None:
+    """After the window closes: wait only until every scored request (with a
+    backlog expected: every one that had its first token by ``close``) has
+    its first ``FIRST_K`` tokens (or has failed), at most ``timeout_s``."""
+    due = [t for t in turns if t.scored and t.sent is not None and answered_by(t, close)]
     deadline = now() + timeout_s
     while now() < deadline:
-        if all(len(t.token_times) >= first_k(t) or t.error for t in turns if t.scored and t.sent is not None):
+        if all(len(t.token_times) >= first_k(t) or t.error for t in due):
             return
         time.sleep(0.01)
 
@@ -325,8 +394,10 @@ def serve_metrics(turns: List[Turn], window) -> Dict[str, Any]:
     for t in turns:
         gaps.extend(1e3 * g for g in yardstick.gaps_ending_in(t.token_times, window))
     late = [1e3 * (t.sent - t.due) for t in turns if t.sent is not None]
+    streamed = sum(yardstick.count_in(t.token_times, window) for t in turns)
     q = yardstick.quantile
     return {
+        "output_tokens_per_s": streamed / (window[1] - window[0]) if streamed else None,
         "ttft_p50_ms": q(ttft, 0.5) if ttft else None,
         "ttft_p90_ms": q(ttft, 0.9) if ttft else None,
         "ttft_mean_ms": sum(ttft) / len(ttft) if ttft else None,
@@ -338,12 +409,34 @@ def serve_metrics(turns: List[Turn], window) -> Dict[str, Any]:
     }
 
 
-def judge(turns: List[Turn], vocab: int, correctness: Dict[str, Any]) -> Dict[str, Any]:
+OVERTAKEN_LIMIT = 2  # two requests due within a millisecond may change places between the generator and the engine's queue
+
+
+def overtaken(turns: List[Turn], close: float) -> int:
+    """Admission keeps the order of arrival: how many requests had a first
+    token by ``close`` although one that was due before them was still
+    waiting for its own then (0 where every first token came in turn). An
+    engine that starves the long prompts, admits the shortest first or loses
+    a request leaves such requests behind it, however fast it is."""
+    sent = [t for t in turns if t.sent is not None and t.due <= close and not t.error]
+    waiting = [t.due for t in sent if not answered_by(t, close)]
+    if not waiting:
+        return 0
+    return sum(1 for t in sent if answered_by(t, close) and t.due > min(waiting))
+
+
+def judge(turns: List[Turn], vocab: int, correctness: Dict[str, Any],
+          close: Optional[float] = None) -> Dict[str, Any]:
     """``attempted``, ``failed`` and the reasons a run is not ``correct``:
     what the system returned, never how the host treated the generator (its
     lateness is inside every time counted from ``due`` and is reported as
-    ``loadgen_late_p99_ms``; PERF.md section 4)."""
-    scored = [t for t in turns if t.scored]
+    ``loadgen_late_p99_ms``; PERF.md section 4). With ``close`` (see
+    ``backlog_close``) the scored requests still waiting for a first token
+    at the close are counted as ``waiting`` and not judged one by one; what
+    is judged of them is their order: no more than ``OVERTAKEN_LIMIT``
+    requests may have been answered past one that still waits."""
+    everyone = [t for t in turns if t.scored]
+    scored = [t for t in everyone if answered_by(t, close)]
     reasons = []
     failed = 0
     for t in scored:
@@ -360,4 +453,10 @@ def judge(turns: List[Turn], vocab: int, correctness: Dict[str, Any]) -> Dict[st
         reasons.append(f"{failed} scored requests failed, e.g. {first}")
     if bad_tokens:
         reasons.append(f"{bad_tokens} requests returned too many tokens or ids outside the vocabulary")
-    return {"attempted": len(scored), "failed": failed, "reasons": reasons}
+    out = {"attempted": len(scored), "failed": failed, "reasons": reasons, "waiting": len(everyone) - len(scored)}
+    if close is not None:
+        out["overtaken"] = overtaken(turns, close)
+        if out["overtaken"] > OVERTAKEN_LIMIT:
+            reasons.append(f"{out['overtaken']} requests were answered past one that was due before them and still "
+                           f"waited at the close (limit {OVERTAKEN_LIMIT}): admission does not keep the order of arrival")
+    return out

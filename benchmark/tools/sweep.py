@@ -2,9 +2,14 @@
 set-up it runs the cell's traffic at several rates in one process (a ramp
 and a window each, the engine drained in between) and prints one JSON line a
 rate: the tails, the TTFT by thirds of the window (a growing backlog shows as
-a rising third), the backlog at the window's end, the residence time. The
-cell's rate is then written into its traffic file as a number; the
-benchmark's command never searches.
+a rising third), the requests without a first token at the window's close,
+the output tokens/s, the residence time. The cell's rate is then written
+into its traffic file as a number; the benchmark's command never searches.
+It is for the serve cells: ``smollm2-1.7b-serve.chat-steady`` and
+``.agent-prefix`` (0.8 and 0.35: shares of their knees), and
+``.chat-saturated`` (1.3 x the chat knee; its file's ``"backlog":
+"expected"`` is honoured, so a request left waiting at the close is not a
+failure there).
 
     python3 benchmark/tools/sweep.py --workload <cell> --rates 2.5,3,3.5 --seconds 40
 """
@@ -57,14 +62,16 @@ def main() -> int:
                 thirds.append(round(q(xs, 0.5), 1) if xs else None)
             done = [t for t in out["turns"] if len(t.tokens) == t.max_tokens and t.token_times]
             residence = [t.token_times[-1] - t.due for t in done]
-            tokens_in_window = sum(1 for t in out["turns"] for x in t.token_times if w0 <= x <= w1)
+            waiting = sum(1 for t in out["turns"] if t.sent is not None and t.sent <= w1 and not t.error
+                          and not (t.token_times and t.token_times[0] <= w1))
             print(json.dumps({
                 "rate": rate, "attempted": out["attempted"], "failed": out["failed"],
                 "values": {k: (round(v, 2) if v is not None else None) for k, v in out["values"].items()},
                 "ttft_p50_by_third_ms": thirds,
-                "at_window_end": {k: end_stats[k] for k in ("queued", "active_slots", "prefilling", "kv_blocks_in_use")},
+                "without_first_token_at_close": waiting, "not_judged": out["waiting"],
+                "overtaken": out.get("overtaken"), "window_sample": served.correctness.get("window sample"),
+                "after_cancel": {k: end_stats[k] for k in ("queued", "active_slots", "prefilling", "kv_blocks_in_use")},
                 "residence_s_mean": round(sum(residence) / len(residence), 2) if residence else None,
-                "output_tokens_per_s": round(tokens_in_window / (w1 - w0), 1),
                 "reasons": out["reasons"],
             }), flush=True)
             deadline = time.time() + 120

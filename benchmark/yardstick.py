@@ -73,6 +73,13 @@ def gaps_ending_in(token_times: Sequence[float], window: Tuple[float, float]) ->
     return [b - a for a, b in zip(token_times, token_times[1:]) if w0 <= b <= w1]
 
 
+def count_in(times: Sequence[float], window: Tuple[float, float]) -> int:
+    """How many of ``times`` (streamed tokens' arrival stamps) lie inside the
+    window, its edges included."""
+    w0, w1 = window
+    return sum(1 for x in times if w0 <= x <= w1)
+
+
 def mfu_percent(flops_per_token: float, tokens_per_s: float, chips: int, peak_flops: float) -> float:
     """Model FLOP/s utilisation in percent: needed FLOPs per token times
     tokens per second (of the whole cell) over chips times peak."""

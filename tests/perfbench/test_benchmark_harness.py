@@ -357,10 +357,8 @@ def test_the_manifest_is_sound_and_its_files_exist():
 def test_the_manifest_names_what_the_issue_names():
     e2e = {x["name"] for x in MANIFEST["end_to_end"]}
     assert {"itl_p99_ms", "tokens_per_s", "setup_s"} <= e2e
-    assert {c["name"] for c in MANIFEST["configs"]} == {
-        "smollm2-1.7b-serve", "smollm2-1.7b-train-l8", "smollm2-1.7b-train-ring4"}
-    four = [w["name"] for w in MANIFEST["workloads"] if w["chips"] == 4]
-    assert four in ([], ["smollm2-1.7b-train-ring4.steps"])
+    assert {"smollm2-1.7b-serve", "smollm2-1.7b-train-l8", "smollm2-1.7b-train-ring4"} <= {
+        c["name"] for c in MANIFEST["configs"]}, "a later PR adds configurations; it takes none away"
 
 
 def _broken(edit):
