@@ -4,10 +4,20 @@ The reference has no inference engine in core (Serve wraps user callables;
 its LLM examples delegate to vLLM). Here decoding is first-class and
 TPU-first:
 
-- **Static shapes everywhere**: the cache is a preallocated ring of
-  ``[n_layers, B, kv_heads, max_len, head_dim]`` buffers; prefill and every
-  decode step are fixed-shape XLA programs, so the whole generate loop jits
-  to one compiled executable (``lax.scan`` over steps — no per-token Python).
+- **Static shapes everywhere**: the cache is either a preallocated ring of
+  ``[n_layers, B, kv_heads, max_len, head_dim]`` buffers (:func:`init_cache`,
+  :func:`forward_with_cache`) or a paged pool ``[n_layers, pages, page_size,
+  kv_heads*head_dim]`` named by per-sequence block tables
+  (:func:`init_paged_cache`, :func:`paged_forward_with_cache`: the serving
+  engine's path); prefill and every decode step are fixed-shape XLA programs,
+  so the whole generate loop jits to one compiled executable (``lax.scan``
+  over steps — no per-token Python).
+- **One block**: both loops call the block functions of
+  ``models/transformer.py`` (projections, query/key norms, RoPE, output gate,
+  sandwich norms, dense or expert FFN), so they compute ``forward``'s function
+  of the same parameter tree. A layer's kind (sliding window or full, RoPE or
+  none) rides the layer scan as per-layer values; the window reaches the
+  dense-view mask and the decode kernels as a scalar beside ``lengths``.
 - **Ragged batches without ragged shapes**: per-sequence write offsets go
   through a vmapped ``dynamic_update_slice`` (lowers to an in-place scatter)
   and visibility is a ``key_pos <= query_pos`` mask — the padded tail of a
@@ -23,6 +33,7 @@ Used by ``ray_tpu.serve.llm`` (continuous batching) and directly via
 from __future__ import annotations
 
 import math
+from functools import partial
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -30,10 +41,17 @@ import jax.numpy as jnp
 
 from ray_tpu.models.transformer import (
     TransformerConfig,
-    _dense_ffn,
-    _moe_ffn,
     _rms_norm,
-    _rope,
+    block_attn_out,
+    block_ffn,
+    block_qkv,
+    embed_tokens,
+    flash_by_kind,
+    in_window,
+    layer_kinds,
+    layer_stacks,
+    scanned_leaves,
+    unembed,
 )
 from ray_tpu.ops import backend
 
@@ -177,7 +195,6 @@ def forward_with_cache(
     h_heads, hkv = cfg.n_heads, cfg.kv_heads
     n_rep = h_heads // hkv
     scale = 1.0 / math.sqrt(cfg.head_dim)
-    from ray_tpu.models.transformer import embed_tokens
 
     x = embed_tokens(cfg, params, tokens)
     starts = positions[:, 0]
@@ -188,70 +205,76 @@ def forward_with_cache(
         use_decode_kernel = backend.on_tpu()
     decode_kernel = use_decode_kernel and T == 1
     prefill_kernel = bool(use_prefill_kernel) and T > 1
+    _refuse_scales_on_two_stacks(cfg, layer_scales)
 
-    def layer_fn(x, layer_kc_vc):
+    def layer_fn(stack, x, layer_xs):
         if layer_scales is not None:
-            layer_q, lsc, kc, vc = layer_kc_vc
-            layer = {
-                k: (layer_q[k].astype(jnp.float32) * lsc[k]).astype(cfg.param_dtype)
-                for k in layer_q
-            }
+            layer_q, lsc, kind, index, kc, vc = layer_xs
+            layer = _dequantized(cfg, layer_q, lsc)
         else:
-            layer, kc, vc = layer_kc_vc
-        h = _rms_norm(x, layer["attn_norm"])
-        q = jnp.einsum("btd,dhk->bthk", h, layer["wq"].astype(h.dtype))
-        k = jnp.einsum("btd,dhk->bthk", h, layer["wk"].astype(h.dtype))
-        v = jnp.einsum("btd,dhk->bthk", h, layer["wv"].astype(h.dtype))
-        q, k = _rope(q, positions, cfg.rope_theta), _rope(k, positions, cfg.rope_theta)
+            layer, kind, index, kc, vc = layer_xs
+        window = None if kind is None else kind["window"]
+        h = _rms_norm(x, layer["attn_norm"], cfg.norm_eps)
+        q, k, v = block_qkv(cfg, layer, h, positions, kind)
         kc = _write_kv(kc, k, starts)
         vc = _write_kv(vc, v, starts)
         if decode_kernel:
             from ray_tpu.ops.decode_attention import decode_attention
 
-            o = decode_attention(q[:, 0], kc, vc, starts + 1, sm_scale=scale)[:, None]
+            o = decode_attention(q[:, 0], kc, vc, starts + 1, sm_scale=scale, window=window)[:, None]
             o = o.astype(x.dtype)
         elif prefill_kernel:
             # positions start at 0 for every row (prefill contract): the
             # visible keys are exactly this call's own K/V — causal flash
             # over T tokens, no [T, S] cache-wide mask
-            from ray_tpu.ops.attention import flash_attention
-
             kr = jnp.repeat(k, n_rep, axis=2) if n_rep > 1 else k
             vr = jnp.repeat(v, n_rep, axis=2) if n_rep > 1 else v
-            o = flash_attention(
-                jnp.transpose(q, (0, 2, 1, 3)),
-                jnp.transpose(kr, (0, 2, 1, 3)),
-                jnp.transpose(vr, (0, 2, 1, 3)),
-                scale,
-                True,
-            )
-            o = jnp.transpose(o, (0, 2, 1, 3)).astype(x.dtype)
+            qt, kt, vt = (jnp.transpose(a, (0, 2, 1, 3)) for a in (q, kr, vr))
+            o = jnp.transpose(flash_by_kind(cfg, qt, kt, vt, scale, window), (0, 2, 1, 3)).astype(x.dtype)
         else:
             # grouped-query attention against the whole cache
+            seen = vis if window is None else vis & in_window(
+                kv_pos[None, None, None, :], positions[:, None, :, None], window)
             qg = q.reshape(B, T, hkv, n_rep, cfg.head_dim)
             s_ = jnp.einsum(
                 "btgrk,bgsk->bgrts", qg.astype(jnp.float32), kc.astype(jnp.float32)
             ) * scale  # [B, Hkv, n_rep, T, S]
-            s_ = jnp.where(vis[:, :, None], s_, -1e30)
+            s_ = jnp.where(seen[:, :, None], s_, -1e30)
             p = jax.nn.softmax(s_, axis=-1)
             o = jnp.einsum("bgrts,bgsk->btgrk", p, vc.astype(jnp.float32))
             o = o.reshape(B, T, h_heads, cfg.head_dim).astype(x.dtype)
-        x = x + jnp.einsum("bthk,hkd->btd", o, layer["wo"].astype(o.dtype))
-        h = _rms_norm(x, layer["ffn_norm"])
-        ffn = _moe_ffn(cfg, layer, h) if cfg.num_experts > 0 else _dense_ffn(layer, h)
-        return x + ffn, (kc, vc)
+        x = block_attn_out(cfg, layer, x, h, o)
+        x, _ = block_ffn(cfg, layer, x, stack=stack, index=index)
+        return x, (kc, vc)
 
-    if layer_scales is not None:
-        xs = (params["layers"], layer_scales, cache["k"], cache["v"])
-    else:
-        xs = (params["layers"], cache["k"], cache["v"])
-    x, (ks, vs) = jax.lax.scan(layer_fn, x, xs)
-    x = _rms_norm(x, params["final_norm"])
-    logits = jnp.einsum("btd,vd->btv", x, params["embed"].astype(x.dtype))
-    return logits.astype(jnp.float32), {"k": ks, "v": vs}
+    stacks = layer_stacks(cfg, params)
+    ks, vs = [], []
+    for stack, first, last in stacks:
+        whole = len(stacks) == 1  # the one stack scans the cache as it is, unsliced
+        kc, vc = (cache["k"], cache["v"]) if whole else (cache["k"][first:last], cache["v"][first:last])
+        xs = (scanned_leaves(cfg, stack), layer_kinds(cfg, first, last), jnp.arange(last - first), kc, vc)
+        if layer_scales is not None:
+            xs = (stack, layer_scales) + xs[1:]
+        x, (k_out, v_out) = jax.lax.scan(partial(layer_fn, stack), x, xs)
+        ks.append(k_out)
+        vs.append(v_out)
+    ks, vs = (a[0] if len(a) == 1 else jnp.concatenate(a) for a in (ks, vs))
+    return unembed(cfg, params, x), {"k": ks, "v": vs}
 
 
-def paged_forward_with_cache(
+def _dequantized(cfg: TransformerConfig, layer_q, scales):
+    return {k: (layer_q[k].astype(jnp.float32) * scales[k]).astype(cfg.param_dtype) for k in layer_q}
+
+
+def _refuse_scales_on_two_stacks(cfg: TransformerConfig, layer_scales) -> None:
+    if layer_scales is not None and (cfg.dense_stack or cfg.dropless):
+        raise ValueError(
+            "layer_scales (int8 weight-only serving) cover one stack of layers whose weights ride the scan: a "
+            "config with num_dense_layers > 0 or dropless expert layers is not quantized by this path"
+        )
+
+
+def paged_forward_counted(
     cfg: TransformerConfig,
     params: Dict[str, Any],
     cache: KVCache,            # paged pool from init_paged_cache
@@ -262,8 +285,9 @@ def paged_forward_with_cache(
     valid: Optional[jax.Array] = None,  # [B, T] bool: False = pad, don't cache
     use_decode_kernel: Optional[bool] = None,
     layer_scales: Optional[Dict[str, jax.Array]] = None,
-) -> Tuple[jax.Array, KVCache]:
-    """:func:`forward_with_cache` over a paged pool instead of dense rows.
+) -> Tuple[jax.Array, KVCache, Dict[str, jax.Array]]:
+    """:func:`forward_with_cache` over a paged pool instead of dense rows:
+    (logits, cache, the expert layers' counts).
 
     Writes this call's K/V into the pool through the block tables and
     attends over every cached position up to ``positions``. Single-token
@@ -284,6 +308,13 @@ def paged_forward_with_cache(
     The stacked pool is the layer loop's carry: layer ``l`` scatters its
     K/V rows into ``pool[l]`` in place and attention reads them from there,
     so a donated cache is never sliced, re-laid-out or copied.
+
+    The counts are what the dropless expert layers did with this call's
+    ``valid`` tokens (all, where ``valid`` is None): ``{"assignments":
+    int32[E], "pairs_hit": int32}``, the (token, choice) pairs each expert
+    got summed over the expert layers, and how many (layer, expert) pairs got
+    at least one; zeros for any other config. A caller that drops them pays
+    nothing: they fall out of the compiled program.
     """
     B, T = tokens.shape
     M = block_tables.shape[1]
@@ -292,7 +323,6 @@ def paged_forward_with_cache(
     h_heads, hkv = cfg.n_heads, cfg.kv_heads
     n_rep = h_heads // hkv
     scale = 1.0 / math.sqrt(cfg.head_dim)
-    from ray_tpu.models.transformer import embed_tokens
 
     x = embed_tokens(cfg, params, tokens)
     starts = positions[:, 0]
@@ -301,6 +331,7 @@ def paged_forward_with_cache(
     if use_decode_kernel is None:
         use_decode_kernel = backend.on_tpu()
     decode_kernel = use_decode_kernel and T == 1
+    _refuse_scales_on_two_stacks(cfg, layer_scales)
 
     phys, off = _paged_write_index(block_tables, positions, valid, bs)
 
@@ -313,56 +344,61 @@ def paged_forward_with_cache(
         g = pool[l, block_tables].reshape(B, M, bs, hkv, cfg.head_dim)
         return jnp.transpose(g, (0, 3, 1, 2, 4)).reshape(B, hkv, cap, cfg.head_dim)
 
-    def layer_fn(carry, layer_xs):
+    def layer_fn(stack, first, carry, layer_xs):
         x, kc, vc = carry
         if layer_scales is not None:
-            layer_q, lsc, l = layer_xs
-            layer = {
-                k: (layer_q[k].astype(jnp.float32) * lsc[k]).astype(cfg.param_dtype)
-                for k in layer_q
-            }
+            layer_q, lsc, kind, l = layer_xs
+            layer = _dequantized(cfg, layer_q, lsc)
         else:
-            layer, l = layer_xs
-        h = _rms_norm(x, layer["attn_norm"])
-        q = jnp.einsum("btd,dhk->bthk", h, layer["wq"].astype(h.dtype))
-        k = jnp.einsum("btd,dhk->bthk", h, layer["wk"].astype(h.dtype))
-        v = jnp.einsum("btd,dhk->bthk", h, layer["wv"].astype(h.dtype))
-        q, k = _rope(q, positions, cfg.rope_theta), _rope(k, positions, cfg.rope_theta)
+            layer, kind, l = layer_xs
+        window = None if kind is None else kind["window"]
+        h = _rms_norm(x, layer["attn_norm"], cfg.norm_eps)
+        q, k, v = block_qkv(cfg, layer, h, positions, kind)
         kc = kc.at[l, phys, off].set(k.reshape(B * T, -1).astype(kc.dtype))
         vc = vc.at[l, phys, off].set(v.reshape(B * T, -1).astype(vc.dtype))
         if decode_kernel:
             from ray_tpu.ops.decode_attention import paged_decode_attention
 
             o = paged_decode_attention(
-                q[:, 0], kc, vc, block_tables, starts + 1, l, sm_scale=scale
+                q[:, 0], kc, vc, block_tables, starts + 1, l, sm_scale=scale, window=window
             )[:, None]
             o = o.astype(x.dtype)
         else:
             # masked positions contribute exactly-0.0 weight, so page-0
             # garbage never reaches the output
+            seen = vis if window is None else vis & in_window(
+                kv_pos[None, None, None, :], positions[:, None, :, None], window)
             kd, vd = dense_view(kc, l), dense_view(vc, l)
             qg = q.reshape(B, T, hkv, n_rep, cfg.head_dim)
             s_ = jnp.einsum(
                 "btgrk,bgsk->bgrts", qg.astype(jnp.float32), kd.astype(jnp.float32)
             ) * scale  # [B, Hkv, n_rep, T, cap]
-            s_ = jnp.where(vis[:, :, None], s_, -1e30)
+            s_ = jnp.where(seen[:, :, None], s_, -1e30)
             p = jax.nn.softmax(s_, axis=-1)
             o = jnp.einsum("bgrts,bgsk->btgrk", p, vd.astype(jnp.float32))
             o = o.reshape(B, T, h_heads, cfg.head_dim).astype(x.dtype)
-        x = x + jnp.einsum("bthk,hkd->btd", o, layer["wo"].astype(o.dtype))
-        h = _rms_norm(x, layer["ffn_norm"])
-        ffn = _moe_ffn(cfg, layer, h) if cfg.num_experts > 0 else _dense_ffn(layer, h)
-        return (x + ffn, kc, vc), None
+        x = block_attn_out(cfg, layer, x, h, o)
+        x, counts = block_ffn(cfg, layer, x, valid, stack=stack, index=l - first)
+        return (x, kc, vc), counts
 
-    layer_ids = jnp.arange(cfg.n_layers, dtype=jnp.int32)
-    if layer_scales is not None:
-        xs = (params["layers"], layer_scales, layer_ids)
-    else:
-        xs = (params["layers"], layer_ids)
-    (x, ks, vs), _ = jax.lax.scan(layer_fn, (x, cache["k"], cache["v"]), xs)
-    x = _rms_norm(x, params["final_norm"])
-    logits = jnp.einsum("btd,vd->btv", x, params["embed"].astype(x.dtype))
-    return logits.astype(jnp.float32), {"k": ks, "v": vs}
+    carry = (x, cache["k"], cache["v"])
+    assignments = jnp.zeros((max(cfg.num_experts, 1),), jnp.int32)
+    pairs_hit = jnp.zeros((), jnp.int32)
+    for stack, first, last in layer_stacks(cfg, params):
+        xs = (scanned_leaves(cfg, stack), layer_kinds(cfg, first, last), jnp.arange(first, last, dtype=jnp.int32))
+        if layer_scales is not None:
+            xs = (stack, layer_scales) + xs[1:]
+        carry, counts = jax.lax.scan(partial(layer_fn, stack, first), carry, xs)
+        if counts is not None:  # a dropless expert stack: [layers, E] assignments of the valid tokens
+            assignments = assignments + counts.sum(0)
+            pairs_hit = pairs_hit + jnp.sum(counts > 0).astype(jnp.int32)
+    x, ks, vs = carry
+    return unembed(cfg, params, x), {"k": ks, "v": vs}, {"assignments": assignments, "pairs_hit": pairs_hit}
+
+
+def paged_forward_with_cache(*args, **kwargs) -> Tuple[jax.Array, KVCache]:
+    """:func:`paged_forward_counted` without the counts: (logits, cache)."""
+    return paged_forward_counted(*args, **kwargs)[:2]
 
 
 def paged_decode_step(

@@ -7,8 +7,19 @@ nets are the closest analog, ``rllib/core/rl_module/rl_module.py``):
   to ONE XLA program; sharding is declared with ``PartitionSpec`` and GSPMD
   propagates collectives (psum over ``tp``, all-gather over ``sp`` for KV).
 - bfloat16 activations, float32 params/optimizer — the MXU-native recipe.
-- RMSNorm + RoPE + SwiGLU; optional top-2 MoE FFN whose expert dimension
-  shards over the ``ep`` mesh axis (expert parallelism).
+- RMSNorm + RoPE + SwiGLU. The FFN is dense or a top-k expert layer whose
+  expert dimension shards over the ``ep`` mesh axis: dropless (assignments
+  sorted by expert, one grouped matrix product a projection, T x k rows
+  whatever the imbalance) with softmax or sigmoid scores, a selection bias,
+  and shared experts beside the routed; or capacity dispatch
+  (``moe_capacity_factor > 0``: softmax top-k, overflow dropped).
+- One block, many families: ``head_dim``, norm eps, embedding scale and tying
+  are fields; query/key norms, an output gate and sandwich norms are
+  switches; ``layer_types`` gives each layer its attention kind (sliding
+  window or full, RoPE or none), and the kinds ride the layer scan as
+  per-layer values, so one traced body serves all of them. Leading dense
+  layers and expert layers are two stacks, scanned one after the other.
+  ``docs/models.md`` shows how a published config maps onto the fields.
 - Attention: Pallas flash kernel (``ray_tpu.ops.attention``) on a single
   chip (no mesh); XLA einsum attention under any mesh; or
   ``attention="ring"`` — sequence-parallel ring attention
@@ -31,7 +42,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ray_tpu.ops import backend
-from ray_tpu.ops.attention import NEG_INF, flash_attention, mha
+from ray_tpu.ops.attention import NEG_INF, flash_attention_with_lse, mha
 
 
 @dataclasses.dataclass(frozen=True)
@@ -46,10 +57,10 @@ class TransformerConfig:
     rope_theta: float = 10000.0
     num_experts: int = 0          # 0 => dense FFN
     expert_top_k: int = 2
-    # 0 => dense dispatch (every expert computes every token — exact, the
-    # small-scale default); > 0 => GShard/Switch capacity dispatch: expert
-    # slots = ceil(top_k * T * factor / E), FLOPs per token drop from E
-    # expert-FFNs to top_k, overflow tokens fall through the residual
+    # 0 => dropless dispatch (the T x k assignments sorted by expert, one
+    # grouped product a projection: exact, FLOPs follow top_k); > 0 =>
+    # GShard/Switch capacity dispatch: expert slots = ceil(top_k * T * factor
+    # / E), overflow tokens fall through the residual
     moe_capacity_factor: float = 0.0
     dtype: Any = jnp.bfloat16     # activation dtype
     param_dtype: Any = jnp.float32
@@ -65,10 +76,57 @@ class TransformerConfig:
     # Python loop (bigger HLO, but remat saves stay plain buffers instead
     # of scan-stacked dynamic-update-slices — worth ~25% step time at 602M)
     scan_layers: bool = True
+    # ---- beyond the Llama block. Every default keeps the function and the
+    # parameter tree of a config that does not name the field.
+    head_dim: Optional[int] = None      # None => d_model // n_heads
+    norm_eps: float = 1e-6
+    embed_scale: Optional[float] = None  # input embedding multiplier; None => sqrt(d_model)
+    tie_embeddings: bool = True         # False => params["head"], a [V, d] leaf of its own
+    qk_norm: bool = False               # RMSNorm over head_dim on q and k, before RoPE
+    attn_gate: bool = False             # o * sigmoid(h @ wg) before wo
+    post_norms: bool = False            # sandwich: RMSNorm on each branch's output before the residual add
+    # per-layer attention kinds: "sliding" (key j visible to query i iff
+    # i - sliding_window < j <= i; RoPE) or "full" (causal; RoPE unless
+    # rope_full_layers is False). None => every layer full with RoPE
+    layer_types: Optional[Tuple[str, ...]] = None
+    sliding_window: int = 0
+    rope_full_layers: bool = True
+    # expert layers: the first num_dense_layers are dense at d_ff (a stack of
+    # their own, params["dense_layers"]), the rest route over num_experts of
+    # width expert_d_ff (None => d_ff) plus num_shared_experts always-on ones
+    num_dense_layers: int = 0
+    expert_d_ff: Optional[int] = None
+    num_shared_experts: int = 0
+    router_score: str = "softmax"       # softmax | sigmoid, in float32
+    route_norm: bool = True             # selected weights / their sum
+    route_scale: float = 1.0
+    router_bias: bool = False           # per-expert bias added for SELECTION only (params: "router_bias")
 
     def __post_init__(self):
-        if self.d_model % self.n_heads:
-            raise ValueError(f"d_model {self.d_model} not divisible by n_heads {self.n_heads}")
+        if self.head_dim is None:
+            if self.d_model % self.n_heads:
+                raise ValueError(f"d_model {self.d_model} not divisible by n_heads {self.n_heads}")
+            object.__setattr__(self, "head_dim", self.d_model // self.n_heads)
+        if self.layer_types is not None:
+            object.__setattr__(self, "layer_types", tuple(self.layer_types))
+            bad = set(self.layer_types) - {"sliding", "full"}
+            if bad or len(self.layer_types) != self.n_layers:
+                raise ValueError(
+                    f'layer_types must name "sliding" or "full" for each of the {self.n_layers} layers; '
+                    f"got {self.layer_types!r}"
+                )
+            if "sliding" in self.layer_types and self.sliding_window < 1:
+                raise ValueError("a sliding layer needs sliding_window >= 1")
+        if self.router_score not in ("softmax", "sigmoid"):
+            raise ValueError(f'router_score must be "softmax" or "sigmoid"; got {self.router_score!r}')
+        if self.num_experts > 0 and not 0 <= self.num_dense_layers < self.n_layers:
+            raise ValueError(f"num_dense_layers {self.num_dense_layers} must leave an expert layer of {self.n_layers}")
+        if self.moe_capacity_factor > 0 and (self.router_score != "softmax" or not self.route_norm
+                                             or self.route_scale != 1.0 or self.router_bias
+                                             or self.num_shared_experts > 0):
+            # the capacity dispatch keeps its softmax top-k; nothing is silently ignored
+            raise ValueError("router_score, route_norm, route_scale, router_bias and num_shared_experts "
+                             "belong to the dropless expert layer: leave moe_capacity_factor at 0")
         if self.remat not in (False, True, "full", "dots"):
             # a typo like "Dots" would silently select full-layer recompute
             raise ValueError(f'remat must be False, True, "full", or "dots"; got {self.remat!r}')
@@ -79,20 +137,48 @@ class TransformerConfig:
             )
 
     @property
-    def head_dim(self) -> int:
-        return self.d_model // self.n_heads
-
-    @property
     def kv_heads(self) -> int:
         return self.n_kv_heads or self.n_heads
+
+    @property
+    def dense_stack(self) -> int:
+        """Layers of ``params["dense_layers"]`` (0: one stack holds every layer)."""
+        return self.num_dense_layers if self.num_experts > 0 else 0
+
+    @property
+    def expert_layers(self) -> int:
+        return self.n_layers - self.num_dense_layers if self.num_experts > 0 else 0
+
+    @property
+    def dropless(self) -> bool:
+        """Expert layers on the dropless dispatch (their weights are read
+        where they lie, not as slices riding the layer scan)."""
+        return self.num_experts > 0 and self.moe_capacity_factor == 0
+
+    @property
+    def expert_width(self) -> int:
+        return self.expert_d_ff or self.d_ff
+
+    @property
+    def layer_windows(self) -> Optional[Tuple[int, ...]]:
+        """Per layer, the window in tokens (0: full attention); None where no layer has a kind."""
+        if self.layer_types is None:
+            return None
+        return tuple(self.sliding_window if t == "sliding" else 0 for t in self.layer_types)
+
+    @property
+    def layer_rope(self) -> Optional[Tuple[bool, ...]]:
+        if self.layer_types is None:
+            return None
+        return tuple(t == "sliding" or self.rope_full_layers for t in self.layer_types)
 
 
 # ---------------------------------------------------------------------------
 # init
 # ---------------------------------------------------------------------------
 def init_params(cfg: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
-    # third split kept (not dropped) so existing seeds reproduce their init
-    k_embed, k_layers, _k_unused = jax.random.split(key, 3)
+    # third split: the untied output head's key (existing seeds reproduce their init)
+    k_embed, k_layers, k_head = jax.random.split(key, 3)
     pd = cfg.param_dtype
     d, h, hkv, dh, ff = cfg.d_model, cfg.n_heads, cfg.kv_heads, cfg.head_dim, cfg.d_ff
 
@@ -103,40 +189,68 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
 
     layer_keys = jax.random.split(k_layers, cfg.n_layers)
 
-    def one_layer(k):
+    def one_layer(k, experts: bool):
         ks = jax.random.split(k, 8)
         layer = {
             "attn_norm": jnp.ones((d,), pd),
             "wq": dense_init(ks[0], (d, h, dh), d),
             "wk": dense_init(ks[1], (d, hkv, dh), d),
             "wv": dense_init(ks[2], (d, hkv, dh), d),
-            "wo": dense_init(ks[3], (h, dh, d), d),
+            "wo": dense_init(ks[3], (h, dh, d), h * dh),
             "ffn_norm": jnp.ones((d,), pd),
         }
-        if cfg.num_experts > 0:
-            e = cfg.num_experts
+        # leaves of the newer switches draw from keys folded off the layer's
+        # own, so the eight splits above stay what they were
+        if cfg.qk_norm:
+            layer["q_norm"] = jnp.ones((dh,), pd)
+            layer["k_norm"] = jnp.ones((dh,), pd)
+        if cfg.attn_gate:
+            layer["wg"] = dense_init(jax.random.fold_in(k, 8), (d, h, dh), d)
+        if cfg.post_norms:
+            layer["post_attn_norm"] = jnp.ones((d,), pd)
+            layer["post_ffn_norm"] = jnp.ones((d,), pd)
+        if experts:
+            e, fe = cfg.num_experts, cfg.expert_width
             layer["router"] = dense_init(ks[7], (d, e), d)
-            layer["we1"] = dense_init(ks[4], (e, d, ff), d)
-            layer["we3"] = dense_init(ks[5], (e, d, ff), d)
-            layer["we2"] = dense_init(ks[6], (e, ff, d), ff)
+            layer["we1"] = dense_init(ks[4], (e, d, fe), d)
+            layer["we3"] = dense_init(ks[5], (e, d, fe), d)
+            layer["we2"] = dense_init(ks[6], (e, fe, d), fe)
+            if cfg.router_bias:
+                # a buffer the router's balance rule moves, not a gradient; zero
+                # when training starts. Drawn small here so that serving code
+                # which dropped it (or put it into the weights) computes another function
+                layer["router_bias"] = 0.01 * jax.random.normal(jax.random.fold_in(k, 9), (e,), jnp.float32)
+            if cfg.num_shared_experts:
+                fs = cfg.num_shared_experts * fe
+                layer["ws1"] = dense_init(jax.random.fold_in(k, 10), (d, fs), d)
+                layer["ws3"] = dense_init(jax.random.fold_in(k, 11), (d, fs), d)
+                layer["ws2"] = dense_init(jax.random.fold_in(k, 12), (fs, d), fs)
         else:
             layer["w1"] = dense_init(ks[4], (d, ff), d)
             layer["w3"] = dense_init(ks[5], (d, ff), d)
             layer["w2"] = dense_init(ks[6], (ff, d), ff)
         return layer
 
-    # stacked layers: leaves get a leading [n_layers] dim, scanned in forward.
-    layers = jax.tree.map(lambda *xs: jnp.stack(xs), *[one_layer(k) for k in layer_keys])
-    return {
-        # tied embedding/unembed: init at 1/sqrt(d) std (unembed wants unit
-        # row norms so init logits are O(1) — std-1 rows made the model a
-        # confident token-COPIER at init: diag logit ~= |E_t|^2 ~= d); the
-        # input path multiplies by sqrt(d) in forward() to keep the residual
-        # stream at its usual scale (Gemma-style tied-embedding recipe)
+    def stack(keys, experts: bool):
+        # stacked layers: leaves get a leading [layers] dim, scanned in forward.
+        return jax.tree.map(lambda *xs: jnp.stack(xs), *[one_layer(k, experts) for k in keys])
+
+    nd = cfg.dense_stack
+    params = {
+        # embedding at 1/sqrt(d) std (a tied unembed wants unit row norms so
+        # init logits are O(1) — std-1 rows made the model a confident
+        # token-COPIER at init: diag logit ~= |E_t|^2 ~= d); the input path
+        # multiplies by cfg.embed_scale (default sqrt(d)) in embed_tokens() to
+        # keep the residual stream at its usual scale
         "embed": dense_init(k_embed, (cfg.vocab_size, d), d),
-        "layers": layers,
+        "layers": stack(layer_keys[nd:], cfg.num_experts > 0),
         "final_norm": jnp.ones((d,), pd),
     }
+    if nd:
+        params["dense_layers"] = stack(layer_keys[:nd], False)
+    if not cfg.tie_embeddings:
+        params["head"] = dense_init(k_head, (cfg.vocab_size, d), d)
+    return params
 
 
 # ---------------------------------------------------------------------------
@@ -158,24 +272,43 @@ def param_specs(
     e.g. :func:`make_train_step`, decide automatically)."""
     ep = ep or dp
     kv = tp if kv_tp else None
-    layer_specs = {
-        "attn_norm": P(None, None),
-        "wq": P(None, None, tp, None),
-        "wk": P(None, None, kv, None),
-        "wv": P(None, None, kv, None),
-        "wo": P(None, tp, None, None),
-        "ffn_norm": P(None, None),
-    }
-    if cfg.num_experts > 0:
-        layer_specs.update(
-            router=P(None, None, None),
-            we1=P(None, ep, None, tp),
-            we3=P(None, ep, None, tp),
-            we2=P(None, ep, tp, None),
-        )
-    else:
-        layer_specs.update(w1=P(None, None, tp), w3=P(None, None, tp), w2=P(None, tp, None))
-    return {"embed": P(tp, None), "layers": layer_specs, "final_norm": P(None)}
+
+    def layer_specs(experts: bool):
+        specs = {
+            "attn_norm": P(None, None),
+            "wq": P(None, None, tp, None),
+            "wk": P(None, None, kv, None),
+            "wv": P(None, None, kv, None),
+            "wo": P(None, tp, None, None),
+            "ffn_norm": P(None, None),
+        }
+        if cfg.qk_norm:
+            specs.update(q_norm=P(None, None), k_norm=P(None, None))
+        if cfg.attn_gate:
+            specs["wg"] = P(None, None, tp, None)
+        if cfg.post_norms:
+            specs.update(post_attn_norm=P(None, None), post_ffn_norm=P(None, None))
+        if experts:
+            specs.update(
+                router=P(None, None, None),
+                we1=P(None, ep, None, tp),
+                we3=P(None, ep, None, tp),
+                we2=P(None, ep, tp, None),
+            )
+            if cfg.router_bias:
+                specs["router_bias"] = P(None, None)
+            if cfg.num_shared_experts:
+                specs.update(ws1=P(None, None, tp), ws3=P(None, None, tp), ws2=P(None, tp, None))
+        else:
+            specs.update(w1=P(None, None, tp), w3=P(None, None, tp), w2=P(None, tp, None))
+        return specs
+
+    specs = {"embed": P(tp, None), "layers": layer_specs(cfg.num_experts > 0), "final_norm": P(None)}
+    if cfg.dense_stack:
+        specs["dense_layers"] = layer_specs(False)
+    if not cfg.tie_embeddings:
+        specs["head"] = P(tp, None)
+    return specs
 
 
 def _kv_tp_ok(cfg: TransformerConfig, mesh: Mesh, tp: str) -> bool:
@@ -263,11 +396,12 @@ def _repeat_kv(x, n_rep: int):
     return jnp.broadcast_to(x[:, :, :, None, :], (B, T, Hkv, n_rep, Dh)).reshape(B, T, Hkv * n_rep, Dh)
 
 
-def _gqa_mha(qt, k, v, *, causal: bool, sm_scale: float):
+def _gqa_mha(qt, k, v, *, causal: bool, sm_scale: float, window=None):
     """Grouped-query attention, K/V kept at kv-head width (no materialized
     repeat — decode/train HBM traffic stays 1/n_rep of the MHA layout).
 
-    qt: [B, H, T, Dh]; k, v: [B, T, Hkv, Dh]."""
+    qt: [B, H, T, Dh]; k, v: [B, T, Hkv, Dh]. ``window`` (an int or a traced
+    scalar; 0 or None: none) hides keys at or before ``i - window``."""
     B, H, T, Dh = qt.shape
     Hkv = k.shape[2]
     n_rep = H // Hkv
@@ -278,20 +412,32 @@ def _gqa_mha(qt, k, v, *, causal: bool, sm_scale: float):
     if causal:
         S = s.shape[-1]
         mask = jnp.arange(S)[None, :] <= jnp.arange(T)[:, None]
+        if window is not None:
+            mask = mask & in_window(jnp.arange(S)[None, :], jnp.arange(T)[:, None], window)
         s = jnp.where(mask[None, None, None], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bgrts,bgsd->bgrtd", p, vt.astype(jnp.float32))
     return o.reshape(B, H, T, Dh).astype(qt.dtype)
 
 
-def _attention(cfg: TransformerConfig, q, k, v, use_flash: bool, mesh=None, sp_axis=None):
-    # q: [B, T, H, Dh]; k, v: [B, T, Hkv, Dh] (unrepeated under GQA)
+def in_window(kv_pos, q_pos, window):
+    """Key ``j`` is inside query ``i``'s window iff ``j > i - window``; a
+    window of 0 is none. ``window`` may be traced (it rides the layer scan)."""
+    return (kv_pos > q_pos - window) | (window <= 0)
+
+
+def _attention(cfg: TransformerConfig, q, k, v, use_flash: bool, mesh=None, sp_axis=None, window=None):
+    # q: [B, T, H, Dh]; k, v: [B, T, Hkv, Dh] (unrepeated under GQA).
+    # window: this layer's (an int where the layers are unrolled, a traced
+    # scalar where they are scanned; None where the config names no kinds)
     n_rep = cfg.n_heads // cfg.kv_heads
     qt = jnp.transpose(q, (0, 2, 1, 3))
     if not use_flash and cfg.attention != "ring":
         # grouped einsum path: K/V never widen to n_heads
-        o = _gqa_mha(qt, k, v, causal=True, sm_scale=1.0 / math.sqrt(cfg.head_dim))
+        o = _gqa_mha(qt, k, v, causal=True, sm_scale=1.0 / math.sqrt(cfg.head_dim), window=window)
         return jnp.transpose(o, (0, 2, 1, 3))
+    if window is not None and cfg.attention == "ring":
+        raise ValueError('attention="ring" has no sliding window: use "auto", "flash" or "dense" with layer_types')
     # the Pallas flash / ring kernels take [B, H, T, Dh] with full heads
     k, v = _repeat_kv(k, n_rep), _repeat_kv(v, n_rep)
     kt, vt = (jnp.transpose(x, (0, 2, 1, 3)) for x in (k, v))
@@ -318,30 +464,23 @@ def _attention(cfg: TransformerConfig, q, k, v, use_flash: bool, mesh=None, sp_a
         if pad:
             o = o[:, :, :T]
     elif use_flash:
-        o = flash_attention(qt, kt, vt, None, True)
+        o = flash_by_kind(cfg, qt, kt, vt, None, window)
     else:
         o = mha(qt, kt, vt, causal=True)
     return jnp.transpose(o, (0, 2, 1, 3))
 
 
-def _moe_ffn(cfg: TransformerConfig, layer, x):
-    """Top-k MoE dispatcher. ``moe_capacity_factor > 0`` routes through the
-    capacity formulation (:func:`_moe_ffn_capacity` — top_k FFNs per
-    token); otherwise dense dispatch: every expert computes every token and
-    the router mask selects — exact, and fine when E is small."""
-    if cfg.moe_capacity_factor > 0:
-        return _moe_ffn_capacity(cfg, layer, x)
-    e, k = cfg.num_experts, cfg.expert_top_k
-    logits = jnp.einsum("btd,de->bte", x.astype(jnp.float32), layer["router"].astype(jnp.float32))
-    gates = jax.nn.softmax(logits, axis=-1)
-    topv, topi = jax.lax.top_k(gates, k)
-    mask = jnp.sum(jax.nn.one_hot(topi, e, dtype=gates.dtype) * topv[..., None], axis=-2)  # [B,T,E]
-    mask = (mask / (jnp.sum(mask, -1, keepdims=True) + 1e-9)).astype(x.dtype)
-    h = jnp.einsum("btd,edf->betf", x, layer["we1"].astype(x.dtype))
-    g = jnp.einsum("btd,edf->betf", x, layer["we3"].astype(x.dtype))
-    h = jax.nn.silu(g) * h
-    out = jnp.einsum("betf,efd->betd", h, layer["we2"].astype(x.dtype))
-    return jnp.einsum("betd,bte->btd", out, mask)
+def flash_by_kind(cfg: TransformerConfig, qt, kt, vt, sm_scale, window):
+    """Causal flash attention ([B, H, T, Dh] operands) of a layer whose
+    ``window`` is None (the config names no kinds), an int (unrolled layers)
+    or a traced scalar riding the layer scan: the kernel's window is static,
+    so a scanned layer picks its kind by ``cond``."""
+    def flash(w):
+        return lambda: flash_attention_with_lse(qt, kt, vt, sm_scale, True, window=w)[0]
+
+    if window is None or isinstance(window, int):
+        return flash(window or None)()
+    return jax.lax.cond(window > 0, flash(cfg.sliding_window or None), flash(None))
 
 
 def _moe_ffn_capacity(cfg: TransformerConfig, layer, x):
@@ -383,6 +522,165 @@ def _dense_ffn(layer, x):
     return h @ layer["w2"].astype(x.dtype)
 
 
+def route(cfg: TransformerConfig, layer, x2):
+    """The dropless layer's router on tokens ``x2`` [N, d]: scores in float32
+    (softmax or sigmoid over all E), the k experts with the largest
+    ``score + bias`` (the bias selects, it never weighs), their scores as
+    weights, normalised and scaled. Returns (experts int32[N, k], weights f32[N, k])."""
+    # float32 in fact, not in name: the TPU's default matmul precision would
+    # round both operands to bf16, and the 8th and 9th expert lie close
+    logits = jnp.dot(x2.astype(jnp.float32), layer["router"].astype(jnp.float32), precision="highest")
+    scores = jax.nn.sigmoid(logits) if cfg.router_score == "sigmoid" else jax.nn.softmax(logits, axis=-1)
+    choose = scores + layer["router_bias"].astype(jnp.float32) if cfg.router_bias else scores
+    _, experts = jax.lax.top_k(choose, cfg.expert_top_k)
+    weights = jnp.take_along_axis(scores, experts, axis=-1)
+    if cfg.route_norm:
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
+    return experts.astype(jnp.int32), weights * cfg.route_scale
+
+
+def grouped_matmul(rows, weights, group_sizes):
+    """``rows[start_e : start_e + group_sizes[e]] @ weights[e]`` for every
+    group ``e``: rows [R, a] sorted by group, weights [E, a, b] -> [R, b].
+    ``jax.lax.ragged_dot``: on the TPU one native grouped kernel whose FLOPs
+    follow R and which reads only the groups that hold rows."""
+    return jax.lax.ragged_dot(rows, weights.astype(rows.dtype), group_sizes)
+
+
+EXPERT_WEIGHTS = ("we1", "we3", "we2")
+
+
+def scanned_leaves(cfg: TransformerConfig, stack):
+    """What of a layer stack rides the layer scan as xs: every leaf but a
+    dropless expert layer's three weight stacks. A scan hands its body a
+    slice of each xs leaf, and a slice that feeds a kernel is a copy: 0.5 GB
+    a projection a layer a step at 128 experts of 2048 x 1024. The grouped
+    products read the whole stack where it lies instead (``moe_ffn_dropless``)."""
+    if cfg.dropless and "we1" in stack:
+        return {k: v for k, v in stack.items() if k not in EXPERT_WEIGHTS}
+    return stack
+
+
+def moe_ffn_dropless(cfg: TransformerConfig, layer, x, valid=None, *, stack=None, index=0):
+    """The dropless routed + shared expert layer: route, sort the N x k
+    assignments by expert, one grouped product a projection over exactly
+    those N x k rows, unsort, weigh and add; the shared experts see every
+    token. Nothing is dropped at any imbalance and every shape is static.
+
+    The experts' weights come from ``layer`` (``[E, ..]`` leaves) or, inside a
+    layer loop, from ``stack`` (the whole ``[L, E, ..]`` leaves) at layer
+    ``index``, which may be traced: the stack is read as ``L x E`` groups of
+    which only this layer's hold rows, so nothing is sliced out of it.
+
+    Returns (out [B, T, d], assignments int32[E]): how many (token, choice)
+    pairs each expert got, counting only tokens ``valid`` [B, T] marks. Bucket
+    padding and idle decode rows still compute (their rows are there), but
+    they follow the first valid token's experts, so they make the products
+    read no expert that no real token asked for."""
+    B, T, d = x.shape
+    N, E, k = B * T, cfg.num_experts, cfg.expert_top_k
+    x2 = x.reshape(N, d)
+    experts, weights = route(cfg, layer, x2)
+    if valid is not None:
+        real = valid.reshape(N)
+        experts = jnp.where(real[:, None], experts, experts[jnp.argmax(real)][None, :])
+    flat = experts.reshape(N * k)
+    order = jnp.argsort(flat)                       # assignments grouped by expert (stable)
+    group_sizes = jnp.bincount(flat, length=E).astype(jnp.int32)
+    rows = x2[order // k]                           # [N*k, d]: each assignment's token
+    w = {name: layer[name][None] for name in EXPERT_WEIGHTS} if stack is None else stack
+    L = w["we1"].shape[0]
+    in_stack = jnp.zeros((L, E), jnp.int32).at[index].set(group_sizes).reshape(L * E)
+
+    def product(a, name):
+        return grouped_matmul(a, w[name].reshape(L * E, *w[name].shape[2:]), in_stack)
+
+    out = product(jax.nn.silu(product(rows, "we3")) * product(rows, "we1"), "we2")  # [N*k, d]
+    back = jnp.argsort(order)                       # where each (token, choice) landed
+    out = out[back].reshape(N, k, d)
+    y = jnp.einsum("nkd,nk->nd", out, weights.astype(out.dtype))
+    if cfg.num_shared_experts:
+        shared = jax.nn.silu(x2 @ layer["ws3"].astype(x.dtype)) * (x2 @ layer["ws1"].astype(x.dtype))
+        y = y + shared @ layer["ws2"].astype(x.dtype)
+    if valid is None:
+        counted = group_sizes
+    else:
+        counted = jnp.zeros((E,), jnp.int32).at[flat].add(jnp.repeat(real, k).astype(jnp.int32))
+    return y.reshape(B, T, d).astype(x.dtype), counted
+
+
+# ---------------------------------------------------------------------------
+# the block, written once: forward(), forward_with_cache() and
+# paged_forward_with_cache() differ only in where K and V live
+# ---------------------------------------------------------------------------
+def layer_stacks(cfg: TransformerConfig, params):
+    """The stacked layer trees in order, each with its layer range:
+    ``[(tree, first, last + 1)]``. Leading dense layers are a stack of their
+    own (their leaves differ from an expert layer's), scanned first."""
+    nd = cfg.dense_stack
+    stacks = [(params["dense_layers"], 0, nd)] if nd else []
+    return stacks + [(params["layers"], nd, cfg.n_layers)]
+
+
+def layer_kinds(cfg: TransformerConfig, first: int, last: int):
+    """What rides the layer scan beside layers ``[first, last)``' weights:
+    each layer's window (0: full attention) and whether it applies RoPE, as
+    arrays; None where the config names no kinds (every layer full, RoPE)."""
+    if cfg.layer_types is None:
+        return None
+    return {"window": jnp.asarray(cfg.layer_windows[first:last], jnp.int32),
+            "rope": jnp.asarray(cfg.layer_rope[first:last], bool)}
+
+
+def block_qkv(cfg: TransformerConfig, layer, h, positions, kind=None):
+    """Projections, query/key norms and RoPE of one layer: q [B,T,H,Dh], k, v
+    [B,T,Hkv,Dh]. ``kind``: this layer's entry of :func:`layer_kinds`."""
+    q = jnp.einsum("btd,dhk->bthk", h, layer["wq"].astype(h.dtype))
+    k = jnp.einsum("btd,dhk->bthk", h, layer["wk"].astype(h.dtype))
+    v = jnp.einsum("btd,dhk->bthk", h, layer["wv"].astype(h.dtype))
+    if cfg.qk_norm:
+        q, k = _rms_norm(q, layer["q_norm"], cfg.norm_eps), _rms_norm(k, layer["k_norm"], cfg.norm_eps)
+    rq, rk = _rope(q, positions, cfg.rope_theta), _rope(k, positions, cfg.rope_theta)
+    if kind is None:
+        return rq, rk, v
+    return jnp.where(kind["rope"], rq, q), jnp.where(kind["rope"], rk, k), v
+
+
+def block_attn_out(cfg: TransformerConfig, layer, x, h, o):
+    """The attention branch's tail: output gate, ``wo``, post-norm, residual. o: [B,T,H,Dh]."""
+    if cfg.attn_gate:
+        o = o * jax.nn.sigmoid(jnp.einsum("btd,dhk->bthk", h, layer["wg"].astype(h.dtype)))
+    a = jnp.einsum("bthk,hkd->btd", o, layer["wo"].astype(o.dtype))
+    if cfg.post_norms:
+        a = _rms_norm(a, layer["post_attn_norm"], cfg.norm_eps)
+    return x + a
+
+
+def block_ffn(cfg: TransformerConfig, layer, x, valid=None, *, stack=None, index=0):
+    """The feed-forward branch: dense or expert layer by the layer's own
+    leaves (``stack``, ``index``: where a layer loop keeps the dropless
+    experts' weights, see :func:`scanned_leaves`). Returns (x, the dropless
+    layer's assignment counts or None)."""
+    h = _rms_norm(x, layer["ffn_norm"], cfg.norm_eps)
+    counts = None
+    if "router" not in layer:
+        ffn = _dense_ffn(layer, h)
+    elif cfg.moe_capacity_factor > 0:
+        ffn = _moe_ffn_capacity(cfg, layer, h)
+    else:
+        ffn, counts = moe_ffn_dropless(cfg, layer, h, valid, stack=stack, index=index)
+    if cfg.post_norms:
+        ffn = _rms_norm(ffn, layer["post_ffn_norm"], cfg.norm_eps)
+    return x + ffn, counts
+
+
+def unembed(cfg: TransformerConfig, params, x):
+    """Final norm and output head (the embedding table when tied): [B,T,V] float32."""
+    x = _rms_norm(x, params["final_norm"], cfg.norm_eps)
+    table = params["embed"] if cfg.tie_embeddings else params["head"]
+    return jnp.einsum("btd,vd->btv", x, table.astype(x.dtype)).astype(jnp.float32)
+
+
 def forward(
     cfg: TransformerConfig,
     params: Dict[str, Any],
@@ -400,52 +698,49 @@ def forward(
     x = embed_tokens(cfg, params, tokens)
     positions = jnp.broadcast_to(jnp.arange(T)[None, :], (B, T))
 
-    def layer_fn(x, layer):
-        h = _rms_norm(x, layer["attn_norm"])
-        q = jnp.einsum("btd,dhk->bthk", h, layer["wq"].astype(h.dtype))
-        k = jnp.einsum("btd,dhk->bthk", h, layer["wk"].astype(h.dtype))
-        v = jnp.einsum("btd,dhk->bthk", h, layer["wv"].astype(h.dtype))
-        q, k = _rope(q, positions, cfg.rope_theta), _rope(k, positions, cfg.rope_theta)
-        o = _attention(cfg, q, k, v, use_flash, mesh=mesh, sp_axis=sp_axis)
-        x = x + jnp.einsum("bthk,hkd->btd", o, layer["wo"].astype(o.dtype))
-        h = _rms_norm(x, layer["ffn_norm"])
-        ffn = _moe_ffn(cfg, layer, h) if cfg.num_experts > 0 else _dense_ffn(layer, h)
-        x = x + ffn
+    def layer_fn(stack, x, layer_xs):
+        layer, kind, index = layer_xs
+        h = _rms_norm(x, layer["attn_norm"], cfg.norm_eps)
+        q, k, v = block_qkv(cfg, layer, h, positions, kind)
+        o = _attention(cfg, q, k, v, use_flash, mesh=mesh, sp_axis=sp_axis,
+                       window=None if kind is None else kind["window"])
+        x = block_attn_out(cfg, layer, x, h, o)
+        x, _ = block_ffn(cfg, layer, x, stack=stack, index=index)
         if act_spec is not None:
             x = jax.lax.with_sharding_constraint(x, act_spec)
         return x, None
 
-    if cfg.remat == "dots":
-        step = jax.checkpoint(
-            layer_fn, policy=jax.checkpoint_policies.dots_saveable
-        )
-    elif cfg.remat:
-        step = jax.checkpoint(layer_fn)
-    else:
-        step = layer_fn
-    if cfg.scan_layers:
-        x, _ = jax.lax.scan(step, x, params["layers"])
-    else:
+    for stack, first, last in layer_stacks(cfg, params):
+        step = partial(layer_fn, stack)
+        if cfg.remat == "dots":
+            step = jax.checkpoint(step, policy=jax.checkpoint_policies.dots_saveable)
+        elif cfg.remat:
+            step = jax.checkpoint(step)
+        leaves = scanned_leaves(cfg, stack)
+        if cfg.scan_layers:
+            x, _ = jax.lax.scan(step, x, (leaves, layer_kinds(cfg, first, last), jnp.arange(last - first)))
+            continue
         # Unrolled layer loop: under remat, scan stacks every saved
         # activation through dynamic-update-slice writes (and reads them
         # back by dynamic-slice in bwd) — measured ~25% of a 602M train
         # step on v5e.  Straight-line layers keep saves as plain buffers.
-        for i in range(cfg.n_layers):
-            layer_i = jax.tree_util.tree_map(lambda a: a[i], params["layers"])
-            x, _ = step(x, layer_i)
-    x = _rms_norm(x, params["final_norm"])
-    logits = jnp.einsum("btd,vd->btv", x, params["embed"].astype(x.dtype))
-    return logits.astype(jnp.float32)
+        for i in range(first, last):
+            layer_i = jax.tree_util.tree_map(lambda a: a[i - first], leaves)
+            kind = None if cfg.layer_types is None else {
+                "window": cfg.layer_windows[i], "rope": cfg.layer_rope[i]}
+            x, _ = step(x, (layer_i, kind, i - first))
+    return unembed(cfg, params, x)
 
 
 def embed_tokens(cfg: TransformerConfig, params, tokens) -> jax.Array:
-    """THE tied-embedding input path (training forward AND cached decode
-    import this — a drifted copy would make serving logits diverge from
-    training by the scale factor): sqrt(d) input scale pairs with the
-    1/sqrt(d)-std embedding init so the residual stream keeps its usual
-    magnitude while unembed rows stay ~unit-norm (init logits O(1), never
-    an input-copier)."""
-    return params["embed"].astype(cfg.dtype)[tokens] * math.sqrt(cfg.d_model)
+    """THE embedding input path (training forward AND cached decode import
+    this — a drifted copy would make serving logits diverge from training
+    by the scale factor): the input scale (``cfg.embed_scale``, default
+    sqrt(d)) pairs with the 1/sqrt(d)-std embedding init so the residual
+    stream keeps its usual magnitude while tied unembed rows stay
+    ~unit-norm (init logits O(1), never an input-copier)."""
+    scale = math.sqrt(cfg.d_model) if cfg.embed_scale is None else cfg.embed_scale
+    return params["embed"].astype(cfg.dtype)[tokens] * scale
 
 
 def loss_fn(cfg: TransformerConfig, params, tokens, *, act_spec=None, mesh=None, sp_axis=None) -> jax.Array:

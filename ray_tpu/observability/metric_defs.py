@@ -422,6 +422,19 @@ LLM_PREFIX_EVICTIONS = _reg.counter(
     "tie-break) to return pages to a short pool or to respect "
     "prefix_cache_max_blocks.",
 )
+LLM_MOE_ASSIGNMENTS = _reg.counter(
+    "llm_moe_assignments_total",
+    "(token, choice) pairs the dropless expert layers routed, summed over "
+    "expert layers and program runs (valid tokens only: bucket padding and "
+    "idle decode rows are not counted). Tokens x top_k x expert layers.",
+)
+LLM_MOE_EXPERTS_HIT = _reg.counter(
+    "llm_moe_experts_hit_total",
+    "(layer, expert) pairs that received at least one token, summed over "
+    "program runs: the expert weight matrices a run had to read. Over "
+    "runs x expert layers x experts it is the share of expert weights "
+    "touched per step.",
+)
 
 # Serving SLO families (request-scope observability): ms-scale boundaries
 # matching observability/sketch.py SERVING_LATENCY_BOUNDS — the coarse
@@ -570,6 +583,8 @@ ALL_METRICS = [
     LLM_PREFIX_CACHE_BLOCKS,
     LLM_KV_BLOCKS_SHARED,
     LLM_PREFIX_EVICTIONS,
+    LLM_MOE_ASSIGNMENTS,
+    LLM_MOE_EXPERTS_HIT,
     LLM_TTFT,
     LLM_INTER_TOKEN,
     SERVE_REQUEST_PHASE,

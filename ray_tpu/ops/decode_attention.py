@@ -104,8 +104,11 @@ def decode_attention(
     *,
     sm_scale: Optional[float] = None,
     block_s: int = 512,
+    window=None,
 ) -> jax.Array:
-    """Returns [B, H, D]. H must be a multiple of Hkv (GQA groups)."""
+    """Returns [B, H, D]. H must be a multiple of Hkv (GQA groups).
+    ``window`` (an int or a traced scalar; 0 or None: none): only the last
+    ``window`` valid entries are visible (:func:`window_start`)."""
     import math
 
     B, H, D = q.shape
@@ -131,7 +134,11 @@ def decode_attention(
         k_cache = jnp.pad(k_cache, ((0, 0), (0, 0), (0, pad_s), (0, 0)))
         v_cache = jnp.pad(v_cache, ((0, 0), (0, 0), (0, pad_s), (0, 0)))
     Sp = S + pad_s
-    bias = jnp.where(jnp.arange(Sp)[None, :] < lengths[:, None], 0.0, NEG_INF).astype(jnp.float32)
+    pos = jnp.arange(Sp)[None, :]
+    visible = pos < lengths[:, None]
+    if window is not None:
+        visible = visible & (pos >= window_start(lengths, window)[:, None])
+    bias = jnp.where(visible, 0.0, NEG_INF).astype(jnp.float32)
 
     grid = (B, Hkv, Sp // bs)
     out = pl.pallas_call(
@@ -158,10 +165,16 @@ def decode_attention(
     return out[:, :, :n_rep, :].reshape(B, H, D)
 
 
+def window_start(lengths, window):
+    """First cache position a decode query at ``lengths - 1`` still sees
+    under a sliding window: key ``j`` is visible iff ``j > i - window``, so
+    ``lengths - window``, floored at 0; 0 where ``window`` is 0 (none)."""
+    return jnp.where(window > 0, jnp.maximum(lengths - window, 0), 0)
+
+
 def _paged_decode_kernel(
     tables_ref, lengths_ref, layer_ref,  # scalar-prefetch: [B, M] page ids, [B], [1]
-    q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
-    *, sm_scale: float, block_size: int, pack: int,
+    *refs, sm_scale: float, block_size: int, pack: int, windowed: bool,
 ):
     """Grid (B, M): M innermost walks the sequence's logical blocks.
 
@@ -177,8 +190,16 @@ def _paged_decode_kernel(
     keeps head ``g``'s own. Per head the state machine is
     :func:`_decode_kernel`'s; validity is derived in-kernel from
     ``lengths_ref`` instead of a bias input, and logical blocks wholly past
-    the valid prefix skip their FLOPs.
+    the valid prefix skip their FLOPs. ``windowed``: a fourth scalar-prefetch
+    ref ``[1]`` carries this call's sliding window (0: none); blocks wholly
+    before ``length - window`` skip their FLOPs too (and their DMA: the index
+    map holds them on the first visible page), the first visible block is
+    masked inside.
     """
+    if windowed:
+        window_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr = refs
+    else:
+        q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr = refs
     bi = pl.program_id(0)
     si = pl.program_id(1)
     num_s = pl.num_programs(1)
@@ -191,10 +212,21 @@ def _paged_decode_kernel(
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    @pl.when(si * block_size < length)
+    # computed below ``_init``, not above it: where the predicate sits changes
+    # the lowered body and with it the cost of every grid step (3-7% of a
+    # SmolLM2 decode step on the v5e), so moving it is a measured change
+    live = si * block_size < length
+    if windowed:
+        first = window_start(length, window_ref[0])
+        live = jnp.logical_and(live, (si + 1) * block_size > first)
+
+    @pl.when(live)
     def _accum():
         pos = si * block_size + jax.lax.broadcasted_iota(jnp.int32, (1, block_size), 1)
-        bias = jnp.where(pos < length, 0.0, NEG_INF)  # [1, block_size]
+        visible = pos < length
+        if windowed:
+            visible = jnp.logical_and(visible, pos >= first)
+        bias = jnp.where(visible, 0.0, NEG_INF)  # [1, block_size]
         for g in range(n_kv):
             if g % pack == 0:  # a new lane tile: widened once for its heads
                 k = k_ref[:, g // pack * w:(g // pack + 1) * w].astype(jnp.float32)
@@ -225,7 +257,7 @@ def _paged_decode_kernel(
         o_ref[...] = out.astype(o_ref.dtype)
 
 
-def _paged_decode_xla(qg, k_pool, v_pool, block_tables, lengths, layer, scale):
+def _paged_decode_xla(qg, k_pool, v_pool, block_tables, lengths, layer, scale, window=None):
     """Plain-XLA reference: gather each sequence's pages of ``layer`` into a
     dense [B, Hkv, M*bs, D] view and run the masked grouped einsum — the
     exact math of the dense path; the kernel is compared against it (tier-1
@@ -241,7 +273,10 @@ def _paged_decode_xla(qg, k_pool, v_pool, block_tables, lengths, layer, scale):
     s = jnp.einsum(
         "bgrk,bgsk->bgrs", qg.astype(jnp.float32), k.astype(jnp.float32)
     ) * scale  # [B, Hkv, n_rep, S]
-    vis = jnp.arange(M * bs)[None, :] < lengths[:, None]
+    pos = jnp.arange(M * bs)[None, :]
+    vis = pos < lengths[:, None]
+    if window is not None:
+        vis = vis & (pos >= window_start(lengths, window)[:, None])
     s = jnp.where(vis[:, None, None, :], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     # a fully-masked row softmaxes to uniform garbage; zero it like the kernel
@@ -259,8 +294,13 @@ def paged_decode_attention(
     *,
     sm_scale: Optional[float] = None,
     use_kernel: bool = True,
+    window=None,
 ) -> jax.Array:
     """Decode attention over one layer of a paged KV pool; returns [B, H, D].
+
+    ``window`` (an int or a traced scalar, e.g. this layer's entry riding the
+    layer scan; 0: none; None: the kernel is built without it): only the
+    last ``window`` valid entries of each sequence are visible.
 
     The pool is read where it lies: ``layer`` only steers the page DMAs, so
     a caller looping over layers hands in the same stacked buffer each time.
@@ -284,7 +324,7 @@ def paged_decode_attention(
 
     qg = q.reshape(B, Hkv, n_rep, D)
     if not use_kernel:
-        out = _paged_decode_xla(qg, k_pool, v_pool, block_tables, lengths, layer, scale)
+        out = _paged_decode_xla(qg, k_pool, v_pool, block_tables, lengths, layer, scale, window)
         return out.astype(q.dtype).reshape(B, H, D)
 
     rep_p = -(-n_rep // _MIN_REP) * _MIN_REP
@@ -300,17 +340,29 @@ def paged_decode_attention(
         # q into its own lanes, exact zeros in the neighbours'
         own_lanes = jax.nn.one_hot(own, pack, dtype=qg.dtype)  # [Hkv, pack]
         qg = (qg[:, :, :, None, :] * own_lanes[None, :, None, :, None]).reshape(B, Hkv, rep_p, W)
+    windowed = window is not None
+    scalars = [block_tables.astype(jnp.int32), lengths.astype(jnp.int32), jnp.asarray(layer, jnp.int32).reshape(1)]
+    if windowed:
+        scalars.append(jnp.asarray(window, jnp.int32).reshape(1))
+
+    def page(b, s, bt, ln, ly, *wn):
+        # the paged gather: logical block s of sequence b streams from
+        # physical page bt[b, s] of layer ly[0] — one DMA per page, no copy.
+        # Blocks behind the window name the first visible page instead: a
+        # block index that repeats is not fetched again
+        if wn:
+            s = jnp.maximum(s, window_start(ln[b], wn[0][0]) // bs)
+        return (ly[0], bt[b, s], 0, 0)
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,  # block_tables, lengths, layer — usable in index maps
+        num_scalar_prefetch=len(scalars),  # block_tables, lengths, layer(, window) — usable in index maps
         grid=(B, M),
         in_specs=[
-            pl.BlockSpec((None, Hkv, rep_p, W), lambda b, s, bt, ln, ly: (b, 0, 0, 0)),
-            # the paged gather: logical block s of sequence b streams from
-            # physical page bt[b, s] of layer ly[0] — one DMA per page, no copy
-            pl.BlockSpec((None, None, bs, row), lambda b, s, bt, ln, ly: (ly[0], bt[b, s], 0, 0)),
-            pl.BlockSpec((None, None, bs, row), lambda b, s, bt, ln, ly: (ly[0], bt[b, s], 0, 0)),
+            pl.BlockSpec((None, Hkv, rep_p, W), lambda b, s, *_: (b, 0, 0, 0)),
+            pl.BlockSpec((None, None, bs, row), page),
+            pl.BlockSpec((None, None, bs, row), page),
         ],
-        out_specs=pl.BlockSpec((None, Hkv, rep_p, W), lambda b, s, bt, ln, ly: (b, 0, 0, 0)),
+        out_specs=pl.BlockSpec((None, Hkv, rep_p, W), lambda b, s, *_: (b, 0, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((Hkv, rep_p, _LANES), jnp.float32),
             pltpu.VMEM((Hkv, rep_p, _LANES), jnp.float32),
@@ -318,17 +370,14 @@ def paged_decode_attention(
         ],
     )
     out = pl.pallas_call(
-        functools.partial(_paged_decode_kernel, sm_scale=scale, block_size=bs, pack=pack),
+        functools.partial(_paged_decode_kernel, sm_scale=scale, block_size=bs, pack=pack, windowed=windowed),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, rep_p, W), q.dtype),
         grid_spec=grid_spec,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=_use_interpret(),
-    )(
-        block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
-        jnp.asarray(layer, jnp.int32).reshape(1), qg, k_pool, v_pool,
-    )
+    )(*scalars, qg, k_pool, v_pool)
     out = out[:, :, :n_rep]
     if pack > 1:  # keep each head's own lanes of its tile
         out = jnp.take_along_axis(

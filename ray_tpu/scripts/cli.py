@@ -560,6 +560,25 @@ def cmd_nodes(args) -> int:
     return 0
 
 
+def _print_llm_model_lines(src) -> None:
+    """What an engine's snapshot says of the model's own mechanisms: the
+    dropless expert layers' load and the share of cached tokens a windowed
+    model's decode step reads. Nothing for a model with neither."""
+    if src.get("moe_expert_layers"):
+        per = src.get("moe_expert_assignments") or [0]
+        mean = sum(per) / len(per)
+        steps = src.get("decode_steps", 0) * src["moe_expert_layers"] * len(per)
+        print(
+            f"  experts: {src.get('moe_assignments', 0)} assignments over {len(per)} experts x "
+            f"{src['moe_expert_layers']} layers, busiest/mean "
+            f"{(max(per) / mean if mean else 0.0):.2f}, "
+            f"{(100.0 * src.get('moe_experts_hit_decode', 0) / steps if steps else 0.0):.0f}% of "
+            f"(layer, expert) pairs hit a decode step"
+        )
+    if src.get("kv_read_share", 1.0) < 1.0:
+        print(f"  kv read share: {100.0 * src['kv_read_share']:.0f}% of cached tokens visible to a decode step")
+
+
 def cmd_overload(args) -> int:
     """``rt overload``: the admission-control spine at a glance — per-layer
     bounds vs current depths, lifetime shed totals by (layer, reason), the
@@ -622,6 +641,7 @@ def cmd_overload(args) -> int:
                     f"{src.get('prefix_tokens_reused', 0)} tokens reused, "
                     f"{src.get('prefix_evictions', 0)} evictions"
                 )
+            _print_llm_model_lines(src)
             lat = src.get("latency", {})
             ttft, itl = lat.get("ttft", {}), lat.get("inter_token", {})
             if ttft.get("count"):
@@ -702,6 +722,7 @@ def cmd_llm(args) -> int:
                 )
             else:
                 print("  prefix cache: off")
+            _print_llm_model_lines(src)
         lat = src.get("latency", {})
         parts = []
         for name in ("ttft", "inter_token", "queue_wait", "e2e"):

@@ -64,8 +64,7 @@ from ray_tpu.models.generation import (
     forward_with_cache,
     init_cache,
     init_paged_cache,
-    paged_decode_step,
-    paged_forward_with_cache,
+    paged_forward_counted,
     write_paged_pages,
 )
 from ray_tpu.models.transformer import TransformerConfig
@@ -319,6 +318,13 @@ class LLMEngine:
             params = shard_params(params, mesh, cfg, tp=tp, ep=tp)
             kv_ax = tp if _kv_tp_ok(cfg, mesh, tp) else None
             self._kv_spec = NamedSharding(mesh, P(None, None, kv_ax, None, None))
+        if quantize and (cfg.dense_stack or cfg.dropless):
+            # no silent path: the int8 scales ride ONE stack of layers whose
+            # every weight is an xs leaf of the layer scan
+            raise ValueError(
+                "quantize=True does not cover a config with num_dense_layers > 0 or dropless expert layers "
+                "(two layer stacks; expert weights read where they lie): serve it unquantized"
+            )
         if quantize:
             # weight-only int8 on the stacked layer LINEAR weights (norm
             # gains and the embedding stay full precision). Scales ride the
@@ -383,6 +389,15 @@ class LLMEngine:
         # until release paths free enough blocks
         self._held_req: Optional[GenRequest] = None
         self._prefill_chunk_count = 0
+        self._decode_step_count = 0
+        # the dropless expert layers' own counters (models/generation.py,
+        # ``paged_forward_counted``): the prefill and decode programs return
+        # them beside the tokens and the loop adds them up when it reads the
+        # step's tokens. For any other config the programs drop them
+        self._moe_counted = cfg.dropless
+        self._moe_expert_assignments = np.zeros(max(cfg.num_experts, 1), np.int64)
+        self._moe_experts_hit = 0
+        self._moe_experts_hit_decode = 0
         # disaggregated serving: staged exports parked by migration id
         # (the extracted block arrays outlive the prefill request's pool
         # pages — those retire into the prefix cache at export) and the
@@ -402,6 +417,7 @@ class LLMEngine:
         self._key = jax.random.key(np.random.randint(0, 2**31 - 1))
 
         cfg_ = cfg
+        moe_counted = self._moe_counted
         layer_scales = self._layer_scales
         kv_spec = self._kv_spec
         # under a mesh the einsum path partitions via GSPMD; the Pallas
@@ -489,29 +505,37 @@ class LLMEngine:
                 C = toks.shape[1]
                 positions = start + jnp.arange(C)[None, :]
                 valid = (jnp.arange(C) < length)[None, :]
-                logits, cache = paged_forward_with_cache(
+                logits, cache, moe = paged_forward_counted(
                     cfg_, params, cache, bt, toks, positions,
                     valid=valid, layer_scales=layer_scales, use_decode_kernel=False,
                 )
                 last = jax.lax.dynamic_index_in_dim(logits[0], length - 1, 0, keepdims=False)
-                return last, cache
+                return (last, cache, moe) if moe_counted else (last, cache)
 
             @functools.partial(jax.jit, donate_argnums=(1,))
             def _decode_k_paged(params, cache, toks, pos, temps, key, bt):
+                # a live row's first page is never the garbage page 0 (idle
+                # rows decode through all-zero tables): the expert layers
+                # count the live rows' assignments only
+                live = (bt[:, 0] > 0)[:, None] if moe_counted else None
+
                 def body(carry, _):
                     cache, toks, pos, key = carry
-                    logits, cache = paged_decode_step(
-                        cfg_, params, cache, toks, pos, bt,
-                        layer_scales=layer_scales, use_decode_kernel=use_kernel,
+                    logits, cache, moe = paged_forward_counted(
+                        cfg_, params, cache, bt, toks[:, None], pos[:, None],
+                        layer_scales=layer_scales, use_decode_kernel=use_kernel, valid=live,
                     )
                     key, sub = jax.random.split(key)
-                    nxt = _sample_impl(sub, logits, temps)
-                    return (cache, nxt, pos + 1, key), nxt
+                    nxt = _sample_impl(sub, logits[:, 0], temps)
+                    return (cache, nxt, pos + 1, key), (nxt, moe)
 
-                (cache, _, _, key), toks_k = jax.lax.scan(
+                (cache, _, _, key), (toks_k, moe) = jax.lax.scan(
                     body, (cache, toks, pos, key), None, length=K_chunk
                 )
-                return jnp.swapaxes(toks_k, 0, 1), cache, key  # [B, K]
+                out = (jnp.swapaxes(toks_k, 0, 1), cache, key)  # [B, K]
+                if moe_counted:
+                    out += (jax.tree.map(lambda a: a.sum(0), moe),)  # over the K steps
+                return out
 
             # copy-on-write primitive (models/generation.copy_paged_page):
             # donated so XLA copies the page in place in the pool buffers
@@ -855,7 +879,25 @@ class LLMEngine:
                 "prefix_tokens_reused": self._prefix_tokens_reused,
                 "prefix_evictions": self._prefix.evictions if self._prefix is not None else 0,
                 "cow_copies": self._cow_count,
+                "decode_steps": self._decode_step_count,
+                "kv_read_share": self.kv_read_share(),
+                **self._moe_stats_locked(),
             }
+
+    def _moe_stats_locked(self) -> Dict[str, Any]:
+        """The expert layers' running totals (absent for a config without
+        dropless expert layers): (token, choice) pairs routed, per expert and
+        in all; (layer, expert) pairs that got at least one token, over all
+        program runs and over the decode steps alone."""
+        if not self._moe_counted:
+            return {}
+        return {
+            "moe_assignments": int(self._moe_expert_assignments.sum()),
+            "moe_expert_assignments": self._moe_expert_assignments.tolist(),
+            "moe_experts_hit": self._moe_experts_hit,
+            "moe_experts_hit_decode": self._moe_experts_hit_decode,
+            "moe_expert_layers": self.cfg.expert_layers,
+        }
 
     def lowered_decode_text(self) -> str:
         """StableHLO text of the decode program as the loop runs it (same
@@ -915,6 +957,9 @@ class LLMEngine:
                 "prefix_hit_rate": (useful / probes) if probes else 0.0,
                 "prefix_tokens_reused": self._prefix_tokens_reused,
                 "prefix_evictions": self._prefix.evictions if self._prefix is not None else 0,
+                "decode_steps": self._decode_step_count,
+                "kv_read_share": self.kv_read_share(),
+                **self._moe_stats_locked(),
                 # SLO percentiles from the engine-side latency sketches
                 # (ttft / inter_token / queue_wait / e2e, seconds)
                 "latency": {
@@ -1600,11 +1645,12 @@ class LLMEngine:
             # must still never land on refcount > 1 pages
             self._cow_shared_writes(req.slot, start, n)
             bt = jnp.asarray(self._block_tables[req.slot : req.slot + 1])
-            logits, self._cache = self._prefill_chunk(
+            logits, self._cache, *moe = self._prefill_chunk(
                 self.params, self._cache, jnp.asarray(toks), bt,
                 jnp.int32(start), jnp.int32(n),
             )
             jax.block_until_ready(logits)
+            self._note_moe(moe, decode=False)
         except BaseException as exc:  # noqa: BLE001
             with self._lock:
                 self._prefilling.pop(0)
@@ -1630,6 +1676,36 @@ class LLMEngine:
         except BaseException as exc:  # noqa: BLE001
             self._fail_admit(req, exc)
         return True
+
+    def _note_moe(self, moe, *, decode: bool) -> None:
+        """Add one program run's expert-layer counts (``moe``: empty unless
+        the config has dropless expert layers) to the running totals. Called
+        right after the run's tokens or logits were read, so the small
+        arrays are on the host already: no further sync."""
+        if not moe:
+            return
+        counts = np.asarray(moe[0]["assignments"])
+        hit = int(moe[0]["pairs_hit"])
+        self._moe_expert_assignments += counts
+        self._moe_experts_hit += hit
+        if decode:
+            self._moe_experts_hit_decode += hit
+        metric_defs.LLM_MOE_ASSIGNMENTS.inc(int(counts.sum()))
+        metric_defs.LLM_MOE_EXPERTS_HIT.inc(hit)
+
+    def kv_read_share(self) -> float:
+        """Of the cached tokens of the live sequences, the share a decode
+        step must read: a sliding layer sees the last ``min(len, window)`` of
+        a sequence, a full layer all of it; summed over layers and live
+        sequences over layers x the sum of lengths. 1.0 without windows (and
+        with nothing live). The pool still holds every page: pages behind a
+        window are not freed (one block table serves all layers)."""
+        windows = self.cfg.layer_windows
+        lens = self._pos[self._active].astype(np.int64)
+        if windows is None or not lens.sum():
+            return 1.0
+        must = sum(int(np.minimum(lens, w).sum()) if w else int(lens.sum()) for w in windows)
+        return must / (len(windows) * int(lens.sum()))
 
     def _maybe_finish(self, req: GenRequest, tok: int) -> bool:
         done = len(req.generated) >= req.max_tokens or (
@@ -1666,16 +1742,19 @@ class LLMEngine:
             # inactive rows decode through all-zero tables -> garbage page 0,
             # so freed pages are never written after release
             bt = jnp.asarray(self._block_tables * self._active[:, None].astype(np.int32))
-            out, self._cache, self._key = self._decode_k_paged(
+            out, self._cache, self._key, *moe = self._decode_k_paged(
                 self.params, self._cache, toks, pos,
                 jnp.asarray(self._temps), self._key, bt,
             )
         else:
+            moe = ()
             out, self._cache, self._key = self._decode_k(
                 self.params, self._cache, toks, pos,
                 jnp.asarray(self._temps), self._key,
             )
         sampled = np.asarray(out)  # [B, K]
+        self._decode_step_count += sampled.shape[1]
+        self._note_moe(moe, decode=True)
         for k in range(sampled.shape[1]):
             for i in range(self.B):
                 # rt-lint: disable=lock-discipline -- engine-thread-owned:

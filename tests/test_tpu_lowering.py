@@ -228,6 +228,43 @@ def test_engine_paged_programs_update_the_pool_in_place(as_chip, v5e, program):
     assert _pool_sized_ops(compiled.as_text(), {layer_elems, cfg.n_layers * layer_elems}) == []
 
 
+def test_a_windowed_expert_config_compiles_one_in_place_decode_program(as_chip, v5e):
+    """Layer kinds riding the scan, the paged kernel with its window scalar,
+    two layer stacks and the dropless expert layer's grouped products, at the
+    served widths of the second family (GQA 32/4, head 128, experts 2048 x
+    1024) and a small vocabulary: the chip's compiler takes the decode
+    program, both pools are still donated through, and nothing pool-sized is
+    copied."""
+    import dataclasses
+
+    from ray_tpu.models import TransformerConfig, init_params
+    from ray_tpu.models.generation import init_paged_cache, paged_forward_counted
+
+    cfg = TransformerConfig(
+        vocab_size=4096, d_model=2048, n_layers=5, n_heads=32, n_kv_heads=4, head_dim=128, d_ff=6144,
+        max_seq_len=8192, dtype=BF16, param_dtype=BF16, norm_eps=1e-5, tie_embeddings=False, qk_norm=True,
+        attn_gate=True, post_norms=True, layer_types=("sliding",) * 3 + ("full", "sliding"), sliding_window=2048,
+        rope_full_layers=False, num_experts=16, expert_top_k=8, num_dense_layers=1, expert_d_ff=1024,
+        num_shared_experts=1, router_score="sigmoid", route_scale=2.826, router_bias=True)
+    one = SingleDeviceSharding(v5e[0])
+    B, bs, M, N = 48, 16, 512, 8192
+    params = _abstract_tree(lambda: init_params(cfg, jax.random.key(0)), one)
+    cache = _abstract_tree(lambda: init_paged_cache(cfg, N, bs), one)
+    layer_elems = int(np.prod(cache["k"].shape[1:]))
+
+    def fn(params, cache, toks, pos, bt):
+        return paged_forward_counted(cfg, params, cache, bt, toks[:, None], pos[:, None], valid=(bt[:, 0] > 0)[:, None])
+
+    args = _abstract([((B,), I32), ((B,), I32), ((B, M), I32)], one)
+    lowered = jax.jit(fn, donate_argnums=(1,)).trace(params, cache, *args).lower(lowering_platforms=("tpu",))
+    assert "tpu_custom_call" in lowered.as_text()
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * cfg.n_layers * layer_elems * 2
+    assert _pool_sized_ops(compiled.as_text(), {layer_elems, cfg.n_layers * layer_elems}) == []
+    assert dataclasses.replace(cfg, scan_layers=False).layer_windows == (2048, 2048, 2048, 0, 2048)
+
+
 # --------------------------------------------------------------------------
 # make_train_step, AOT
 # --------------------------------------------------------------------------
