@@ -21,10 +21,12 @@ Kwon et al. 2023): K/V live in a shared, layer-stacked pool of fixed-size
 pages ``[L, num_blocks, block_size, Hkv*D]`` (a page row holds all KV heads
 side by side on the lanes, so the pool's natural device layout is unpadded
 row-major pages) and each sequence names its pages in an
-``int32[B, max_blocks]`` block table. The table and the layer index ride
-Pallas scalar prefetch (``PrefetchScalarGridSpec``) so the BlockSpec index
-maps gather pages straight out of the stacked pool in HBM — no per-layer
-slice, no materialized per-sequence cache copy.
+``int32[B, max_blocks]`` block table. The grid is one step a sequence; the
+table, the lengths and the layer index ride Pallas scalar prefetch
+(``PrefetchScalarGridSpec``), the pools stay in HBM, and the body copies the
+pages a sequence can see, a group of 128 tokens at a time, straight out of
+the stacked pool — no per-layer slice, no materialized per-sequence cache
+copy, and no work for a page or a slot that holds nothing.
 ``use_kernel=False`` is the plain-XLA reference (a ``jnp.take`` gather that
 reduces to the dense math) the kernel is checked against.
 
@@ -176,85 +178,115 @@ def _paged_decode_kernel(
     tables_ref, lengths_ref, layer_ref,  # scalar-prefetch: [B, M] page ids, [B], [1]
     *refs, sm_scale: float, block_size: int, pack: int, windowed: bool,
 ):
-    """Grid (B, M): M innermost walks the sequence's logical blocks.
+    """Grid (B,): one grid step a sequence; its visible pages are walked by a
+    loop inside the body, so a slot that holds nothing costs one grid step
+    and a page no query may see costs nothing at all.
 
-    One grid step holds one whole physical page of one layer: k_ref/v_ref
-    are ``[block_size, Hkv*D]`` (the layer and page axes squeezed by the
-    BlockSpec, whose index map reads ``layer_ref`` and ``tables_ref`` to
-    pick them). The KV heads are walked inside the kernel in static lane
-    tiles of ``W = pack*D`` lanes, ``pack`` neighbouring heads to a tile, so
-    a 64-wide head still loads whole 128-lane tiles: q_ref is
-    ``[Hkv, rep_p, W]`` with head ``g``'s query in its own ``D`` lanes of its
-    tile and zeros in its neighbours' (their K lanes drop out of the scores
-    as exact zeros), and o_ref/acc carry ``W`` lanes of which the caller
-    keeps head ``g``'s own. Per head the state machine is
-    :func:`_decode_kernel`'s; validity is derived in-kernel from
-    ``lengths_ref`` instead of a bias input, and logical blocks wholly past
-    the valid prefix skip their FLOPs. ``windowed``: a fourth scalar-prefetch
-    ref ``[1]`` carries this call's sliding window (0: none); blocks wholly
-    before ``length - window`` skip their FLOPs too (and their DMA: the index
-    map holds them on the first visible page), the first visible block is
-    masked inside.
+    k_hbm/v_hbm are the whole stacked pools ``[L, N, block_size, Hkv*D]``
+    where they lie in HBM. The body loops, with a traced trip count, over
+    the row's visible span in groups of ``G`` pages (``k_buf``/``v_buf``:
+    ``[2, G*block_size, Hkv*D]`` VMEM, one whole 128-token lane tile of
+    scores where the page size divides 128), from the group that holds the
+    window's first position (0 without one) to the one that holds position
+    ``length - 1``. Each page of a group comes by its own copy out of
+    ``pool[layer, table[b, j]]`` into its rows of the group's buffer; the
+    next group's copies start before this group's products (two buffers).
+    Pages of a group past the last live one, or before the window's first,
+    are not fetched: their rows keep what an earlier group left there and
+    are masked by position (scores by ``where``, so stale K cannot reach a
+    sum, and a probability of exactly 0 meets stale V). The buffers are
+    zeroed at the first grid step, so they only ever hold zeros or live
+    pages of the pool.
+
+    The KV heads are walked in static lane tiles of ``W = pack*D`` lanes,
+    ``pack`` neighbouring heads to a tile, so a 64-wide head still loads
+    whole 128-lane tiles: q_ref is ``[Hkv, rep_p, W]`` with head ``g``'s
+    query in its own ``D`` lanes of its tile and zeros in its neighbours'
+    (their K lanes drop out of the scores as exact zeros), and o_ref/acc
+    carry ``W`` lanes of which the caller keeps head ``g``'s own. Per head
+    the state machine is :func:`_decode_kernel`'s, a group at a time.
+    ``windowed``: a fourth scalar-prefetch ref ``[1]`` carries this call's
+    sliding window (0: none).
     """
     if windowed:
-        window_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr = refs
-    else:
-        q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr = refs
+        window_ref, *refs = refs
+    q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, m_scr, l_scr, acc_scr = refs
     bi = pl.program_id(0)
-    si = pl.program_id(1)
-    num_s = pl.num_programs(1)
     length = lengths_ref[bi]
+    layer = layer_ref[0]
     n_kv, _, w = q_ref.shape
+    span = k_buf.shape[1]  # tokens a group
+    group = span // block_size
+    first = window_start(length, window_ref[0]) if windowed else 0
+    # a length past the table's capacity (a finished row's overshoot) walks
+    # the table and no further
+    held = jnp.minimum(length, tables_ref.shape[1] * block_size)
+    page_lo, page_hi = first // block_size, pl.cdiv(held, block_size)
+    group_lo, group_hi = first // span, pl.cdiv(held, span)
 
-    @pl.when(si == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+    @pl.when(bi == 0)
+    def _clean_buffers():
+        k_buf[...] = jnp.zeros_like(k_buf)
+        v_buf[...] = jnp.zeros_like(v_buf)
 
-    # computed below ``_init``, not above it: where the predicate sits changes
-    # the lowered body and with it the cost of every grid step (3-7% of a
-    # SmolLM2 decode step on the v5e), so moving it is a measured change
-    live = si * block_size < length
-    if windowed:
-        first = window_start(length, window_ref[0])
-        live = jnp.logical_and(live, (si + 1) * block_size > first)
+    m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    @pl.when(live)
-    def _accum():
-        pos = si * block_size + jax.lax.broadcasted_iota(jnp.int32, (1, block_size), 1)
-        visible = pos < length
-        if windowed:
-            visible = jnp.logical_and(visible, pos >= first)
-        bias = jnp.where(visible, 0.0, NEG_INF)  # [1, block_size]
-        for g in range(n_kv):
-            if g % pack == 0:  # a new lane tile: widened once for its heads
-                k = k_ref[:, g // pack * w:(g // pack + 1) * w].astype(jnp.float32)
-                v = v_ref[:, g // pack * w:(g // pack + 1) * w].astype(jnp.float32)
-            q = q_ref[g].astype(jnp.float32) * sm_scale
+    def page_copies(g, slot, act):
+        """``act`` on the K and V copy of each visible page of group ``g``."""
+
+        def one_page(page, carry):
+            phys = tables_ref[bi, page]
+            rows = pl.ds(pl.multiple_of((page - g * group) * block_size, block_size), block_size)
+            act(pltpu.make_async_copy(k_hbm.at[layer, phys], k_buf.at[slot, rows], sems.at[0, slot]))
+            act(pltpu.make_async_copy(v_hbm.at[layer, phys], v_buf.at[slot, rows], sems.at[1, slot]))
+            return carry
+
+        jax.lax.fori_loop(jnp.maximum(page_lo, g * group), jnp.minimum(page_hi, (g + 1) * group), one_page, None)
+
+    page_copies(group_lo, group_lo % 2, lambda copy: copy.start())
+
+    def one_group(g, carry):
+        slot = g % 2
+
+        @pl.when(g + 1 < group_hi)
+        def _():
+            page_copies(g + 1, 1 - slot, lambda copy: copy.start())
+
+        page_copies(g, slot, lambda copy: copy.wait())
+        pos = g * span + jax.lax.broadcasted_iota(jnp.int32, (1, span), 1)
+        visible = jnp.logical_and(pos >= first, pos < held)  # [1, span]
+        for h in range(n_kv):
+            if h % pack == 0:  # a new lane tile: widened once for its heads
+                lanes = slice(h // pack * w, (h // pack + 1) * w)
+                k = k_buf[slot, :, lanes].astype(jnp.float32)
+                v = v_buf[slot, :, lanes].astype(jnp.float32)
+            q = q_ref[h].astype(jnp.float32) * sm_scale
             s = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
             )
-            s = s + bias  # [rep_p, block_size]
+            s = jnp.where(visible, s, NEG_INF)  # [rep_p, span]
 
-            m_prev = m_scr[g, :, :1]
-            l_prev = l_scr[g, :, :1]
+            m_prev = m_scr[h, :, :1]
+            l_prev = l_scr[h, :, :1]
             m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
             alpha = jnp.exp(jnp.where(m_prev == NEG_INF, NEG_INF, m_prev - m_new))
             p = jnp.exp(s - m_new)
             l_new = l_prev * alpha + p.sum(axis=-1, keepdims=True)
-            acc_scr[g] = acc_scr[g] * alpha + jax.lax.dot_general(
+            acc_scr[h] = acc_scr[h] * alpha + jax.lax.dot_general(
                 p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
             )
-            m_scr[g] = jnp.broadcast_to(m_new, m_scr.shape[1:])
-            l_scr[g] = jnp.broadcast_to(l_new, l_scr.shape[1:])
+            m_scr[h] = jnp.broadcast_to(m_new, m_scr.shape[1:])
+            l_scr[h] = jnp.broadcast_to(l_new, l_scr.shape[1:])
+        return carry
 
-    @pl.when(si == num_s - 1)
-    def _final():
-        l = l_scr[:, :, :1]
-        empty = m_scr[:, :, :1] <= NEG_INF * 0.5  # lengths[b] == 0: emit zeros
-        out = jnp.where(empty, 0.0, acc_scr[...] / jnp.where(l == 0, 1.0, l))
-        o_ref[...] = out.astype(o_ref.dtype)
+    jax.lax.fori_loop(group_lo, group_hi, one_group, None)
+
+    l = l_scr[:, :, :1]
+    empty = m_scr[:, :, :1] <= NEG_INF * 0.5  # lengths[b] == 0: emit zeros
+    out = jnp.where(empty, 0.0, acc_scr[...] / jnp.where(l == 0, 1.0, l))
+    o_ref[...] = out.astype(o_ref.dtype)
 
 
 def _paged_decode_xla(qg, k_pool, v_pool, block_tables, lengths, layer, scale, window=None):
@@ -305,9 +337,14 @@ def paged_decode_attention(
     The pool is read where it lies: ``layer`` only steers the page DMAs, so
     a caller looping over layers hands in the same stacked buffer each time.
     ``Hkv`` is what the pool's row width and ``D`` (from ``q``) imply.
-    Table entries past ``ceil(lengths[b] / block_size)`` may point anywhere
-    valid (the engine points them at the reserved garbage page 0) — they are
-    masked out, never normalized in. ``use_kernel=False`` is the plain-XLA
+    Table entries past ``ceil(lengths[b] / block_size)``, and behind the
+    window, may point anywhere valid (the engine points the former at the
+    reserved garbage page 0): a page no query may see is never fetched.
+    Page 0 is the pool's garbage page (``init_paged_cache``; the allocator
+    never hands it out): a row whose table starts there holds no sequence —
+    the engine's idle slots decode through all-zero tables at whatever
+    length their last tenant left — and reads as ``lengths[b] == 0``: a
+    zero row and no page visited. ``use_kernel=False`` is the plain-XLA
     gather reference. There is no auto-select here: callers that choose by
     platform (``models/generation.py``) ask ``ops.backend.on_tpu()`` once
     and pass the answer; off the chip the kernel runs in interpret mode,
@@ -318,9 +355,9 @@ def paged_decode_attention(
     B, H, D = q.shape
     _, _, bs, row = k_pool.shape
     Hkv = row // D
-    M = block_tables.shape[1]
     n_rep = H // Hkv
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(D)
+    lengths = jnp.where(block_tables[:, 0] > 0, lengths, 0)  # an idle row visits nothing
 
     qg = q.reshape(B, Hkv, n_rep, D)
     if not use_kernel:
@@ -345,25 +382,22 @@ def paged_decode_attention(
     if windowed:
         scalars.append(jnp.asarray(window, jnp.int32).reshape(1))
 
-    def page(b, s, bt, ln, ly, *wn):
-        # the paged gather: logical block s of sequence b streams from
-        # physical page bt[b, s] of layer ly[0] — one DMA per page, no copy.
-        # Blocks behind the window name the first visible page instead: a
-        # block index that repeats is not fetched again
-        if wn:
-            s = jnp.maximum(s, window_start(ln[b], wn[0][0]) // bs)
-        return (ly[0], bt[b, s], 0, 0)
-
+    # a group of pages is one lane tile of scores: 128 tokens where the page
+    # size divides it
+    span = max(1, _LANES // bs) * bs
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=len(scalars),  # block_tables, lengths, layer(, window) — usable in index maps
-        grid=(B, M),
+        num_scalar_prefetch=len(scalars),  # block_tables, lengths, layer(, window)
+        grid=(B,),
         in_specs=[
-            pl.BlockSpec((None, Hkv, rep_p, W), lambda b, s, *_: (b, 0, 0, 0)),
-            pl.BlockSpec((None, None, bs, row), page),
-            pl.BlockSpec((None, None, bs, row), page),
+            pl.BlockSpec((None, Hkv, rep_p, W), lambda b, *_: (b, 0, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),  # the pools stay in HBM: the body
+            pl.BlockSpec(memory_space=pl.ANY),  # copies the pages it needs
         ],
-        out_specs=pl.BlockSpec((None, Hkv, rep_p, W), lambda b, s, *_: (b, 0, 0, 0)),
+        out_specs=pl.BlockSpec((None, Hkv, rep_p, W), lambda b, *_: (b, 0, 0, 0)),
         scratch_shapes=[
+            pltpu.VMEM((2, span, row), k_pool.dtype),
+            pltpu.VMEM((2, span, row), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),  # [K | V, buffer]
             pltpu.VMEM((Hkv, rep_p, _LANES), jnp.float32),
             pltpu.VMEM((Hkv, rep_p, _LANES), jnp.float32),
             pltpu.VMEM((Hkv, rep_p, W), jnp.float32),
@@ -373,10 +407,10 @@ def paged_decode_attention(
         functools.partial(_paged_decode_kernel, sm_scale=scale, block_size=bs, pack=pack, windowed=windowed),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, rep_p, W), q.dtype),
         grid_spec=grid_spec,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
-        ),
+        # in order: the group buffers are cleaned at the first grid step
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=_use_interpret(),
+        name="paged_decode",
     )(*scalars, qg, k_pool, v_pool)
     out = out[:, :, :n_rep]
     if pack > 1:  # keep each head's own lanes of its tile

@@ -881,6 +881,7 @@ class LLMEngine:
                 "cow_copies": self._cow_count,
                 "decode_steps": self._decode_step_count,
                 "kv_read_share": self.kv_read_share(),
+                "kv_live_pages": self.kv_live_pages(),
                 **self._moe_stats_locked(),
             }
 
@@ -1706,6 +1707,22 @@ class LLMEngine:
             return 1.0
         must = sum(int(np.minimum(lens, w).sum()) if w else int(lens.sum()) for w in windows)
         return must / (len(windows) * int(lens.sum()))
+
+    def kv_live_pages(self) -> float:
+        """Summed over the live sequences, the pages the next decode step's
+        attention visits in a layer, averaged over the layers: a full layer
+        walks all ``cdiv(len, block)`` pages of a sequence (``len``: its
+        cached tokens and the one the step writes), a sliding layer those
+        from the page of its window's first position on. What the paged
+        decode kernel's work follows; 0.0 for the dense cache."""
+        if self.cache_kind != "paged":
+            return 0.0
+        bs = self.kv_block_size
+        lens = self._pos[self._active].astype(np.int64) + 1
+        last = -(-lens // bs)
+        windows = self.cfg.layer_windows or (0,)
+        visited = sum(int((last - np.maximum(lens - w, 0) // bs).sum()) if w else int(last.sum()) for w in windows)
+        return visited / len(windows)
 
     def _maybe_finish(self, req: GenRequest, tok: int) -> bool:
         done = len(req.generated) >= req.max_tokens or (

@@ -243,7 +243,7 @@ def test_the_grouped_products_take_t_times_k_rows(world, monkeypatch):
 
 
 # --- (d) the window in the paged decode kernel ----------------------------------------------
-@pytest.mark.parametrize("window", [0, 5, 16, 23])
+@pytest.mark.parametrize("window", [0, 5, 16, 23, 40, 47])
 def test_paged_decode_kernel_window_matches_its_xla_reference(window):
     from ray_tpu.ops.decode_attention import paged_decode_attention
 

@@ -40,6 +40,32 @@ def test_matches_reference(n_rep, block_s):
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5)
 
 
+@pytest.mark.parametrize("n_rep", [1, 4])
+@pytest.mark.parametrize("block_size", [16, 128])
+def test_paged_kernel_matches_reference_on_the_same_cache(n_rep, block_size):
+    """The dense cache cut into shuffled pages (groups of 8 pages and of
+    one): the paged kernel gives this file's reference and the dense
+    kernel's answer."""
+    from ray_tpu.ops.decode_attention import paged_decode_attention
+
+    rng = np.random.default_rng(2)
+    B, Hkv, S, D = 3, 2, 256, 32
+    M = S // block_size
+    q = jnp.asarray(rng.standard_normal((B, Hkv * n_rep, D)), jnp.float32)
+    kc, vc = (jnp.asarray(rng.standard_normal((B, Hkv, S, D)), jnp.float32) for _ in range(2))
+    lengths = jnp.asarray([1, 129, 256], jnp.int32)
+    bt = rng.permutation(np.arange(1, B * M + 1)).reshape(B, M).astype(np.int32)
+
+    def pool(cache):  # [1, pages, block_size, Hkv*D], page 0 the garbage page
+        pages = jnp.transpose(cache, (0, 2, 1, 3)).reshape(B * M, block_size, Hkv * D)
+        return jnp.zeros((1, B * M + 1, block_size, Hkv * D), jnp.float32).at[0, bt.reshape(-1)].set(pages)
+
+    out = paged_decode_attention(q, pool(kc), pool(vc), jnp.asarray(bt), lengths, jnp.int32(0))
+    np.testing.assert_allclose(np.asarray(out), np.asarray(_reference(q, kc, vc, lengths)), rtol=2e-5, atol=2e-5)
+    dense = decode_attention(q, kc, vc, lengths)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(dense), rtol=2e-5, atol=2e-5)
+
+
 def test_bf16_cache():
     rng = np.random.default_rng(1)
     B, Hkv, S, D = 2, 2, 64, 16

@@ -193,6 +193,106 @@ def test_paged_decode_attention_reads_the_named_layer(layer, use_kernel):
         assert np.abs(out - np.asarray(refs[other]))[:3].max() > 1e-2
 
 
+# the kernel walks a row's visible pages in groups of 128 tokens: lengths at
+# every edge of a page and of a group, the full table, and an empty row
+EDGE_LENGTHS = (0, 1, 15, 16, 17, 127, 128, 129, 256)
+EDGE_SHAPES = {"mha_d64": (4, 4, 64), "gqa32_4_d128": (32, 4, 128)}
+edge_shapes = pytest.mark.parametrize("shape", list(EDGE_SHAPES.values()), ids=list(EDGE_SHAPES))
+
+
+def _edge_case(shape, window, seed, *, poison=False):
+    """A row a length of ``EDGE_LENGTHS`` over 16 pages of 16 tokens; the
+    last two rows' first 8 pages are the row before theirs' (a shared
+    prefix). ``poison``: every table entry a query at that length and
+    window may not see names a page of NaN, where the clean table keeps the
+    row's own page."""
+    from ray_tpu.ops.decode_attention import window_start
+
+    H, Hkv, D = shape
+    bs, M, B = 16, 16, len(EDGE_LENGTHS)
+    rng = np.random.default_rng(seed)
+    N = B * M + 2
+    k_pool, v_pool = (rng.normal(size=(2, N, bs, Hkv * D)).astype(np.float32) for _ in range(2))
+    q = jnp.asarray(rng.normal(size=(B, H, D)), jnp.float32)
+    bt = rng.permutation(np.arange(1, N - 1))[: B * M].reshape(B, M).astype(np.int32)
+    bt[-2:, :8] = bt[-3, :8]
+    lengths = np.asarray(EDGE_LENGTHS, np.int32)
+    if poison:
+        k_pool[:, N - 1] = v_pool[:, N - 1] = np.nan
+        first = np.asarray(window_start(lengths, window or 0)) // bs
+        page = np.arange(M)[None]
+        bt = np.where((page < first[:, None]) | (page >= -(-lengths // bs)[:, None]), N - 1, bt).astype(np.int32)
+    return q, jnp.asarray(k_pool), jnp.asarray(v_pool), jnp.asarray(bt), jnp.asarray(lengths)
+
+
+# no window argument; none (0); one inside the first group, inside a page, for
+# the long rows (129 -> position 29, 256 -> 156) and longer than the short ones;
+# one on a page's edge; one longer than every row
+@pytest.mark.parametrize("window", [None, 0, 100, 112, 1000])
+@edge_shapes
+def test_paged_decode_kernel_at_page_and_group_edges(shape, window):
+    from ray_tpu.ops.decode_attention import paged_decode_attention
+
+    q, kp, vp, bt, lengths = _edge_case(shape, window, seed=11)
+    kw = {} if window is None else {"window": jnp.int32(window)}
+    got = paged_decode_attention(q, kp, vp, bt, lengths, jnp.int32(1), use_kernel=True, **kw)
+    want = paged_decode_attention(q, kp, vp, bt, lengths, jnp.int32(1), use_kernel=False, **kw)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5)
+    assert not np.asarray(got[0]).any()  # lengths == 0
+    if window == 100:
+        full = paged_decode_attention(q, kp, vp, bt, lengths, jnp.int32(1), use_kernel=True)
+        assert np.abs(np.asarray(full) - np.asarray(got))[5:].max() > 1e-3  # the long rows lost keys
+        np.testing.assert_array_equal(np.asarray(full)[:5], np.asarray(got)[:5])  # the short rows none
+
+
+@pytest.mark.parametrize("window", [None, 100])
+@edge_shapes
+def test_paged_decode_kernel_never_computes_on_a_page_no_query_may_see(shape, window):
+    """Every table entry past the row's last page and behind its window
+    names a page of NaN: the kernel's answer is finite and is the
+    reference's on the clean table, so those pages are neither fetched nor
+    multiplied by a zero weight."""
+    from ray_tpu.ops.decode_attention import paged_decode_attention
+
+    kw = {} if window is None else {"window": jnp.int32(window)}
+    q, kp, vp, poisoned, lengths = _edge_case(shape, window, seed=13, poison=True)
+    clean = _edge_case(shape, window, seed=13)[3]
+    assert (np.asarray(poisoned) != np.asarray(clean)).sum() > 60
+    got = np.asarray(paged_decode_attention(q, kp, vp, poisoned, lengths, jnp.int32(0), use_kernel=True, **kw))
+    assert np.isfinite(got).all()
+    want = paged_decode_attention(q, kp, vp, clean, lengths, jnp.int32(0), use_kernel=False, **kw)
+    np.testing.assert_allclose(got, np.asarray(want), rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("use_kernel", [True, False], ids=["kernel", "xla"])
+def test_a_row_whose_table_starts_at_the_garbage_page_reads_as_empty(use_kernel):
+    """The engine's idle slots decode through all-zero tables at whatever
+    length their last tenant left: such a row gives zeros and leaves its
+    neighbours' answers alone."""
+    from ray_tpu.ops.decode_attention import paged_decode_attention
+
+    q, kp, vp, bt, lengths, refs = _paged_op_case(OP_SHAPES["mha_d64"], seed=7)
+    idle = bt.at[1].set(0)  # the full row: 64 stale tokens on page 0
+    out = np.asarray(paged_decode_attention(q, kp, vp, idle, lengths, 1, use_kernel=use_kernel))
+    assert not out[1].any()
+    np.testing.assert_allclose(out[[0, 2, 3]], np.asarray(refs[1])[[0, 2, 3]], atol=1e-5)
+
+
+def test_a_length_past_the_tables_capacity_walks_the_table_and_no_further():
+    """A finished row's position may run past its table (the engine's
+    post-finish overshoot): the kernel reads the table's pages, as the
+    reference does, and no entry beyond the row."""
+    from ray_tpu.ops.decode_attention import paged_decode_attention
+
+    q, kp, vp, bt, lengths, _ = _paged_op_case(OP_SHAPES["gqa8_2_d64"], seed=9)
+    over = lengths.at[1].set(64 + 300).at[3].set(64 + 1)  # capacity: 4 pages of 16
+    got = paged_decode_attention(q, kp, vp, bt, over, 2, use_kernel=True)
+    want = paged_decode_attention(q, kp, vp, bt, over, 2, use_kernel=False)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
+    at_capacity = paged_decode_attention(q, kp, vp, bt, over.at[1].set(64).at[3].set(64), 2, use_kernel=True)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(at_capacity))
+
+
 # --------------------------------------------------------------------------
 # the model runner: paged pool == dense cache, in logits
 # --------------------------------------------------------------------------

@@ -170,6 +170,25 @@ def test_engine_paged_decode_program_compiles_for_v5e(as_chip, v5e):
     _lower_for_tpu(_engine_decode_step(cfg), args).compile()
 
 
+def _pallas_grids(jaxpr):
+    """The grid of every ``pallas_call`` in ``jaxpr``, scans and calls walked."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield tuple(eqn.params["grid_mapping"].grid)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _pallas_grids(sub)
+
+
+def test_the_decode_programs_paged_kernel_has_one_grid_step_a_slot(as_chip):
+    """The pages axis stays out of the grid: a slot that holds nothing costs
+    one grid step a layer, and the kernel's work follows the live pages
+    (with ``(slots, max_blocks)`` 90-98% of the benchmark's grid steps held
+    nothing and were most of a decode step)."""
+    cfg, args = _engine_decode_args()
+    grids = list(_pallas_grids(jax.make_jaxpr(_engine_decode_step(cfg))(*args).jaxpr))
+    assert grids == [(FULL["serve"]["slots"],)]
+
+
 def _pool_sized_ops(hlo, sizes):
     """The optimized HLO's ``copy`` / ``dynamic-slice`` / ``dynamic-update-slice``
     instructions (fused or not) whose result has one of ``sizes`` elements."""
