@@ -188,14 +188,12 @@ ROW_GROUPS = [
     # growing — value is goodput/capacity (~1.0 = graceful degradation).
     # Own fresh-runtime group — it deploys a serve app.
     ["overload_goodput"],
-    # paged KV cache + chunked prefill (ISSUE 14): concurrent streams at a
-    # fixed KV HBM budget paged vs dense (block-granular sharing packs
-    # short requests 4x deeper than whole-sequence slots), and the p99
-    # inter-token stall a running decode stream sees while long prompts
-    # prefill behind it (chunked prefill interleaves decode steps between
-    # fixed-width chunks).  Own fresh-runtime group — the rows spin up
-    # several engines with background decode threads.
-    ["llm_paged_capacity_x", "llm_chunked_prefill_stall_p99"],
+    # chunked prefill (ISSUE 14): the p99 inter-token stall a running decode
+    # stream sees while long prompts prefill behind it (chunked prefill
+    # interleaves decode steps between fixed-width chunks).  Own
+    # fresh-runtime group — the row spins up several engines with
+    # background decode threads.
+    ["llm_chunked_prefill_stall_p99"],
     # elastic gang-scheduled training (ISSUE 17): step time of the same
     # global batch split across a 1- then 2- then 4-member StageGroup gang
     # (value = gang-1/gang-4 step time), with the in-row train-while-serve
@@ -265,7 +263,6 @@ def main() -> None:
         "hedged_tail_latency_p99",
         "overload_goodput",
         "train_step_scaling",
-        "llm_paged_capacity_x",
         "llm_chunked_prefill_stall_p99",
         "llm_concurrent_streams_x",
         "llm_prefix_cache_ttft_x",

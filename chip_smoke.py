@@ -321,7 +321,7 @@ def leg_serving(sz, on_chip):
         assert all(0 <= t < cfg.vocab_size for t in toks), "token id out of range"
     assert again == first, "the prefix-cached repeat wave is not token-identical"
     assert overlap > 2, f"requests did not overlap in the engine (overlap {overlap:.2f})"
-    assert stats["cache_kind"] == "paged", stats["cache_kind"]
+    assert stats["kv_block_pool_size"] > 0, stats
     assert stats["prefix_cache_hits"] > 0, "the repeat wave never hit the prefix cache"
     assert stats["active_slots"] == stats["queued"] == stats["prefilling"] == 0, stats
     # quiesced engine: every page still out of the pool is a prefix-cache page
@@ -468,18 +468,30 @@ def leg_four_chip(sz, on_chip):
         mesh=Mesh(np.array(devices), ("tp",)),
     )
     try:
-        futures = [
-            engine.submit([(5 * i + j) % span + 1 for j in range(s["prompt"])], max_tokens=s["new"])
-            for i in range(sz["tp_requests"])
-        ]
+        prompts = [[(5 * i + j) % span + 1 for j in range(s["prompt"])] for i in range(sz["tp_requests"])]
+        futures = [engine.submit(p, max_tokens=s["new"]) for p in prompts]
         outs = [f.result(timeout=600) for f in futures]
+        # the same prompts again: served from the pool's cached pages
+        again = [engine.submit(p, max_tokens=s["new"]).result(timeout=600) for p in prompts]
         emb = engine.params["embed"]
         assert len(emb.sharding.device_set) == 4, f"embed on {len(emb.sharding.device_set)} device(s)"
+        stats = engine.stats()
+        # after prefills, decode steps and copy-on-write: each device still
+        # holds every page, of its own KV heads (all heads where 4 does not
+        # divide them)
+        pool = engine._cache["k"]
+        shard = tuple(pool.addressable_shards[0].data.shape)
     finally:
         engine.shutdown()
     for toks in outs:
         assert len(toks) == s["new"] and all(0 <= t < scfg.vocab_size for t in toks), toks
-    return {"ring_train": ring, "tp_engine": {"requests": len(outs), "cache_kind": engine.cache_kind}}
+    assert again == outs, "the prefix-cached repeat is not token-identical under the mesh"
+    assert stats["prefix_cache_hits"] > 0 and stats["cow_copies"] > 0, stats
+    heads = scfg.kv_heads // 4 if scfg.kv_heads % 4 == 0 else scfg.kv_heads
+    assert shard == pool.shape[:3] + (heads * scfg.head_dim,), f"pool {pool.shape} lies as {shard} a device"
+    return {"ring_train": ring, "tp_engine": {
+        "requests": len(outs) + len(again), "kv_pool_shard": list(shard),
+        "prefix_cache_hits": stats["prefix_cache_hits"]}}
 
 
 def verdict(summary: dict) -> dict:

@@ -287,39 +287,8 @@ class Config:
     # returns.
     router_queue_wait_timeout_s: float = 30.0
 
-    # ---- LLM serving engine: paged KV + chunked prefill -------------------
-    # KV cache layout of serve.llm.LLMEngine: "paged" (default) allocates
-    # fixed-size HBM pages per request through a block table — capacity
-    # proportional to tokens actually cached; "dense" preallocates one
-    # [L, B, Hkv, max_len, Dh] buffer (one full-length row per slot).
-    # Engines under a mesh auto-fall back to dense (GSPMD paged scatter is
-    # not wired yet).  See docs/tpu_design.md "Paged KV + chunked prefill".
-    llm_cache_kind: str = "paged"
-    # Tokens per KV page.  Smaller = finer-grained allocation (less slack
-    # per request), larger = fewer pages to stream per decode step.  On
-    # real TPUs keep it a multiple of the sublane tile (8 for f32, 16 for
-    # bf16) so Pallas page blocks stay tileable.
-    kv_block_size: int = 16
-    # Total pages in the pool (one is reserved as the garbage page).
-    # 0 = auto: max_batch_size * ceil(max_seq_len / kv_block_size) + 1,
-    # i.e. dense-equivalent capacity; set it LOWER than auto to serve more
-    # slots than dense could back at the same HBM budget.
-    kv_num_blocks: int = 0
-    # Chunked prefill (Sarathi-style bounded per-iteration prefill budget):
-    # prompts longer than this prefill in fixed-size chunks interleaved
-    # between decode steps, so running decodes never stall more than one
-    # chunk's forward.  0 = one-shot (whole prompt, power-of-2 bucketed).
-    prefill_chunk_tokens: int = 0
-    # Prefix-aware KV reuse (paged engines only): finished requests publish
-    # the full blocks of prompt+completion into a radix prefix cache
-    # (serve/prefix_cache.py) and new requests share() the longest cached
-    # prefix straight into their block table — zero prefill compute for the
-    # hit region, refcounted pages, copy-on-write on divergence.
-    llm_prefix_cache: bool = True
-    # Max full blocks the prefix cache may pin (0 = bounded only by the
-    # pool).  When the pool runs short, unreferenced cached leaves are
-    # LRU-evicted before admission holds or sheds either way.
-    prefix_cache_max_blocks: int = 0
+    # ---- LLM serving: disaggregated prefill/decode -----------------------
+    # (an engine itself is sized by serve.llm.LLMEngine's constructor)
     # Disaggregated prefill/decode serving (serve/disagg.py): attempts per
     # request on the migration fallback ladder.  Attempt 1 migrates the
     # prefill replica's KV blocks to a decode replica (device pull, then

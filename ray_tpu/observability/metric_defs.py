@@ -370,7 +370,7 @@ LLM_SLOTS_EVICTED = _reg.counter(
 LLM_KV_BLOCK_POOL_SIZE = _reg.gauge(
     "llm_kv_block_pool_size",
     "Usable pages in the LLM engine's paged KV block pool (excludes the "
-    "reserved garbage page; 0 = dense cache).",
+    "reserved garbage page; 0 = the engine was shut down).",
     "blocks",
 )
 LLM_KV_BLOCKS_IN_USE = _reg.gauge(

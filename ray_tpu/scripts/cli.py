@@ -672,8 +672,8 @@ def cmd_overload(args) -> int:
 
 
 def cmd_llm(args) -> int:
-    """``rt llm``: LLM serving engines at a glance — cache kind, KV block
-    pool occupancy, chunked-prefill progress, queue/slot pressure. One line
+    """``rt llm``: LLM serving engines at a glance — KV block pool
+    occupancy, chunked-prefill progress, queue/slot pressure. One line
     block per registered engine (admission source layer == "engine")."""
     address = _read_address(args.address)
     data = _get(address, "/api/overload")
@@ -685,11 +685,10 @@ def cmd_llm(args) -> int:
         print("no llm engines registered")
         return 0
     for i, src in enumerate(engines):
-        kind = src.get("cache_kind", "dense")
         role = src.get("role") or ""
         role_txt = f" role={role}," if role else ""
         print(
-            f"engine {i}: cache={kind},{role_txt} "
+            f"engine {i}:{role_txt} "
             f"{src.get('active_slots', 0)}/{src.get('slots', 0)} slots, "
             f"{src.get('queued', 0)} queued (bound {src.get('queue_bound', 0)}), "
             f"{src.get('shed', 0)} shed, {src.get('slots_evicted', 0)} evicted"
@@ -700,29 +699,28 @@ def cmd_llm(args) -> int:
                 f"{src.get('migrations_in', 0)} in, "
                 f"{src.get('staged_migrations', 0)} staged"
             )
-        if kind == "paged":
+        print(
+            f"  kv pool: {src.get('kv_blocks_in_use', 0)}/"
+            f"{src.get('kv_block_pool_size', 0)} blocks in use "
+            f"({100.0 * src.get('kv_block_occupancy', 0.0):.0f}%), "
+            f"block size {src.get('kv_block_size', 0)} tokens"
+        )
+        print(
+            f"  prefill: {src.get('prefilling', 0)} in flight, "
+            f"{src.get('prefill_chunks', 0)} chunks total, "
+            f"{src.get('waiting_for_blocks', 0)} head-of-line waiting for blocks"
+        )
+        if src.get("prefix_cache_enabled"):
             print(
-                f"  kv pool: {src.get('kv_blocks_in_use', 0)}/"
-                f"{src.get('kv_block_pool_size', 0)} blocks in use "
-                f"({100.0 * src.get('kv_block_occupancy', 0.0):.0f}%), "
-                f"block size {src.get('kv_block_size', 0)} tokens"
+                f"  prefix cache: {src.get('prefix_cache_blocks', 0)} blocks "
+                f"cached, {100.0 * src.get('prefix_hit_rate', 0.0):.0f}% hit "
+                f"rate, {src.get('kv_blocks_shared', 0)} pages shared, "
+                f"{src.get('prefix_tokens_reused', 0)} prompt tokens reused, "
+                f"{src.get('prefix_evictions', 0)} evictions"
             )
-            print(
-                f"  prefill: {src.get('prefilling', 0)} in flight, "
-                f"{src.get('prefill_chunks', 0)} chunks total, "
-                f"{src.get('waiting_for_blocks', 0)} head-of-line waiting for blocks"
-            )
-            if src.get("prefix_cache_enabled"):
-                print(
-                    f"  prefix cache: {src.get('prefix_cache_blocks', 0)} blocks "
-                    f"cached, {100.0 * src.get('prefix_hit_rate', 0.0):.0f}% hit "
-                    f"rate, {src.get('kv_blocks_shared', 0)} pages shared, "
-                    f"{src.get('prefix_tokens_reused', 0)} prompt tokens reused, "
-                    f"{src.get('prefix_evictions', 0)} evictions"
-                )
-            else:
-                print("  prefix cache: off")
-            _print_llm_model_lines(src)
+        else:
+            print("  prefix cache: off")
+        _print_llm_model_lines(src)
         lat = src.get("latency", {})
         parts = []
         for name in ("ttft", "inter_token", "queue_wait", "e2e"):

@@ -1055,8 +1055,7 @@ def run_suite(
 
     # ---- paged KV + chunked prefill (ISSUE 14) ---------------------------
     if (
-        wanted("llm_paged_capacity_x")
-        or wanted("llm_chunked_prefill_stall_p99")
+        wanted("llm_chunked_prefill_stall_p99")
         or wanted("llm_concurrent_streams_x")
         or wanted("llm_prefix_cache_ttft_x")
         or wanted("llm_disagg_intertoken_p99")
@@ -1073,70 +1072,6 @@ def run_suite(
         )
         llm_params = init_params(llm_cfg, jax.random.key(0))
 
-    if wanted("llm_paged_capacity_x"):
-        # Concurrent streams at a FIXED KV HBM budget, paged vs dense.  The
-        # budget is 4 max-length rows (4 x 256 positions).  Dense must cut
-        # it into 4 whole-sequence slots, so 4 streams run no matter how
-        # short the requests are; the paged pool shares the same positions
-        # at 16-token block granularity, so 64-position requests pack 16
-        # deep.  Row value = measured peak concurrent paged streams /
-        # measured peak dense (x).  In-row guards: every stream completes,
-        # all pool blocks return, and the ratio meets the >= 2x acceptance.
-        import threading as _th
-
-        S_CAP, BS = 256, 16
-        BUDGET_BLOCKS = 4 * (S_CAP // BS)  # the dense engine's footprint
-        PROMPT_N, MAX_T = 40, 24  # 64 positions = 4 blocks per stream
-        STREAMS = 16
-
-        def _peak_streams(kind, batch, num_blocks=None):
-            # prefix_cache off: this row measures block-granular packing at
-            # a fixed HBM budget; cached prefixes would hold pool pages and
-            # trip the all-blocks-return guard
-            eng = LLMEngine(
-                llm_cfg, llm_params, max_batch_size=batch, max_seq_len=S_CAP,
-                cache_kind=kind, kv_block_size=BS, kv_num_blocks=num_blocks,
-                prefix_cache=False,
-            )
-            try:
-                eng.generate([1] * PROMPT_N, max_tokens=2)  # warm compiles
-                peak = [0]
-                stop = _th.Event()
-
-                def watch():
-                    while not stop.is_set():
-                        peak[0] = max(peak[0], eng.stats()["active_slots"])
-                        time.sleep(0.002)
-
-                w = _th.Thread(target=watch, daemon=True)
-                w.start()
-                futs = [
-                    eng.submit([2 + (i % 96)] * PROMPT_N, max_tokens=MAX_T)
-                    for i in range(STREAMS)
-                ]
-                outs = [f.result(timeout=300) for f in futs]
-                stop.set()
-                w.join()
-                if not all(len(o) == MAX_T for o in outs):
-                    raise AssertionError("capacity row: a stream stopped early")
-                if kind == "paged" and eng.stats()["kv_blocks_in_use"] != 0:
-                    raise AssertionError("capacity row leaked KV blocks")
-                return peak[0]
-            finally:
-                eng.shutdown()
-
-        dense_peak = _peak_streams("dense", batch=4)
-        paged_peak = _peak_streams(
-            "paged", batch=STREAMS, num_blocks=BUDGET_BLOCKS + 1
-        )
-        ratio = paged_peak / max(1, dense_peak)
-        if ratio < 2.0:
-            raise AssertionError(
-                f"paged capacity {paged_peak} vs dense {dense_peak} = "
-                f"{ratio:.2f}x, below the 2x acceptance floor"
-            )
-        record("llm_paged_capacity_x", ratio, "x")
-
     if wanted("llm_chunked_prefill_stall_p99"):
         # Client-observed p99 inter-token gap of a RUNNING decode stream
         # while three long prompts are admitted behind it.  One-shot
@@ -1150,7 +1085,7 @@ def run_suite(
         def _gap_p99(chunk_tokens):
             eng = LLMEngine(
                 llm_cfg, llm_params, max_batch_size=4, max_seq_len=512,
-                cache_kind="paged", prefill_chunk_tokens=chunk_tokens,
+                prefill_chunk_tokens=chunk_tokens,
             )
             try:
                 # warm the prefill/decode compiles out of the measurement
@@ -1223,9 +1158,8 @@ def run_suite(
             # very compute under test
             kw.setdefault("max_batch_size", 4)
             kw.setdefault("max_seq_len", 512)
-            return LLMEngine(llm_cfg, llm_params, cache_kind="paged",
-                             prefill_chunk_tokens=CHUNK, prefix_cache=False,
-                             **kw)
+            return LLMEngine(llm_cfg, llm_params, prefill_chunk_tokens=CHUNK,
+                             prefix_cache=False, **kw)
 
         def _victim_gaps(eng, inject):
             stream = eng.submit_stream([5, 6, 7], max_tokens=VICTIM_T)
@@ -1392,7 +1326,7 @@ def run_suite(
         N_STREAMS, GEN_T, PROMPT_L = 8, 32, 24
         eng = LLMEngine(
             llm_cfg, llm_params, max_batch_size=N_STREAMS, max_seq_len=256,
-            cache_kind="paged", prefix_cache=False,
+            prefix_cache=False,
         )
         try:
             prompts = [
@@ -1436,7 +1370,7 @@ def run_suite(
         PREFIX_L, GEN_T = 192, 8
         eng = LLMEngine(
             llm_cfg, llm_params, max_batch_size=2, max_seq_len=256,
-            cache_kind="paged", kv_block_size=16,
+            kv_block_size=16,
         )
         try:
             # warm BOTH code paths (full prefill and hit + COW) on an

@@ -72,13 +72,13 @@ class ServeControllerActor:
 
     # ----------------------------------------------------------- deploys
     def deploy(self, deployment: Deployment, init_args: tuple, init_kwargs: dict) -> None:
-        # deploy-time role validation: zero-replica pools or a dense KV
-        # cache fail HERE with a typed ValueError, not at the first
+        # deploy-time role validation: an unknown role or a zero-replica
+        # pool fails HERE with a typed ValueError, not at the first
         # migration (serve/disagg.py)
         if deployment.roles is not None:
             from ray_tpu.serve.disagg import validate_roles
 
-            validate_roles(deployment.roles, init_kwargs)
+            validate_roles(deployment.roles)
         with self._lock:
             old = self._deployments.get(deployment.name)
             state = _DeploymentState(deployment, init_args, init_kwargs)

@@ -84,16 +84,13 @@ def migration_uuid(mig_id: str, block_idx: int) -> int:
     return ((hi << 32) | (block_idx & 0xFFFFFFFF)) or 1
 
 
-def validate_roles(roles: Optional[Dict[str, int]],
-                   init_kwargs: Optional[dict] = None) -> None:
+def validate_roles(roles: Optional[Dict[str, int]]) -> None:
     """Deploy-time validation of a disaggregated deployment (fails fast
     with a typed ValueError instead of wedging at the first migration):
 
     - only the ``prefill`` / ``decode`` roles exist;
     - both pools need at least one replica (zero decode replicas would
-      accept prefills that can never decode);
-    - ``llm_cache_kind="dense"`` has no block table to migrate — roles
-      require the paged KV cache.
+      accept prefills that can never decode).
     """
     if roles is None:
         return
@@ -111,14 +108,6 @@ def validate_roles(roles: Optional[Dict[str, int]],
                 "decodes on the decode pool — an empty pool wedges every "
                 "request at its first migration"
             )
-    kind = (init_kwargs or {}).get("cache_kind")
-    if kind is None:
-        kind = get_config().llm_cache_kind
-    if kind == "dense":
-        raise ValueError(
-            "roles= requires the paged KV cache (llm_cache_kind='paged'): "
-            "a dense cache has no block table to migrate between replicas"
-        )
 
 
 def make_ticket(

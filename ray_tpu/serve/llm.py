@@ -9,19 +9,19 @@ dictated by XLA's static-shape compilation model:
   jitted program over all B slots (inactive slots compute masked garbage —
   the static-shape price, paid in exchange for zero recompiles at any
   admission pattern).
-- **Paged KV cache (default).** K/V live in a shared HBM pool of
-  fixed-size pages ``[L, num_blocks, block_size, Hkv*Dh]``; each slot
-  names its pages in a static-shape ``int32[B, max_blocks_per_slot]`` block
-  table (PagedAttention, Kwon et al. 2023). Admission is block-aware — a
-  request is admitted when enough PAGES are free, so HBM capacity is
-  proportional to tokens actually reserved, not ``B * max_len``. The
-  ``"dense"`` cache kind keeps the classic one-row-per-slot
-  ``[L, B, Hkv, S, Dh]`` buffer.
+- **Paged KV cache.** K/V live in a shared HBM pool of fixed-size pages
+  ``[L, num_blocks, block_size, Hkv*Dh]``; each slot names its pages in a
+  static-shape ``int32[B, max_blocks_per_slot]`` block table
+  (PagedAttention, Kwon et al. 2023). Admission is block-aware — a request
+  is admitted when enough PAGES are free, so HBM capacity is proportional
+  to tokens actually reserved, not ``B * max_len``. Under a mesh the pool
+  shards over its KV heads (``models/generation.paged_cache_spec``) and the
+  same admission, prefill and decode programs run, partitioned by GSPMD.
 - **Chunked prefill.** Prompts prefill in fixed-size chunks interleaved
   between decode steps (Sarathi-style bounded per-iteration budget,
   ``prefill_chunk_tokens``; 0 = one-shot with power-of-2 bucketing), so a
   long prompt stalls running decodes by at most one chunk's forward.
-- **Prefix-aware KV reuse (paged engines, on by default).** Finished
+- **Prefix-aware KV reuse (on by default).** Finished
   requests publish the full blocks of prompt+completion into a radix
   prefix cache (``serve/prefix_cache.py``); admission matches the longest
   cached prefix and ``share()``s those pages straight into the new block
@@ -54,23 +54,19 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ray_tpu.core.config import get_config
 from ray_tpu.exceptions import DeadlineExceededError
 from ray_tpu.models.generation import (
     copy_paged_page,
-    decode_step,
     export_paged_page,
     filter_top_k_top_p,
-    forward_with_cache,
-    init_cache,
     init_paged_cache,
+    paged_cache_spec,
     paged_forward_counted,
     write_paged_pages,
 )
 from ray_tpu.models.transformer import TransformerConfig
 from ray_tpu.observability import metric_defs
 from ray_tpu.observability.sketch import LatencySketch
-from ray_tpu.ops import backend
 from ray_tpu.runtime import admission
 from ray_tpu.runtime.context import (
     current_deadline_ts,
@@ -192,6 +188,16 @@ class LLMEngine:
     Thread model: callers enqueue via :meth:`submit` (thread-safe); one
     background loop admits requests and steps the batch. All jitted callables
     are built once in __init__ so the loop never traces.
+
+    The KV pool is sized here and nowhere else. ``kv_block_size``: tokens a
+    page (a multiple of the sublane tile, 8 for f32 and 16 for bf16, keeps
+    the decode kernel's page reads aligned). ``kv_num_blocks``: pages in the
+    pool, one the garbage page; 0 = every slot can hold ``max_seq_len``.
+    ``prefill_chunk_tokens``: prompts enter the cache this many tokens at a
+    time, a decode step between chunks; 0 = the uncached suffix in one
+    power-of-2 bucketed call. ``prefix_cache``: finished requests' full
+    blocks stay cached and are shared into later requests, at most
+    ``prefix_cache_max_blocks`` of them (0 = what the pool can spare).
     """
 
     def __init__(
@@ -211,12 +217,11 @@ class LLMEngine:
         max_queued_requests: int = 256,
         max_queued_prefill_tokens: int = 0,
         tenant_weights: Optional[Dict[str, float]] = None,
-        cache_kind: Optional[str] = None,
-        kv_block_size: Optional[int] = None,
-        kv_num_blocks: Optional[int] = None,
-        prefill_chunk_tokens: Optional[int] = None,
-        prefix_cache: Optional[bool] = None,
-        prefix_cache_max_blocks: Optional[int] = None,
+        kv_block_size: int = 16,
+        kv_num_blocks: int = 0,
+        prefill_chunk_tokens: int = 0,
+        prefix_cache: bool = True,
+        prefix_cache_max_blocks: int = 0,
         role: Optional[str] = None,
     ):
         self.cfg = cfg
@@ -228,52 +233,23 @@ class LLMEngine:
         if role not in (None, "", "prefill", "decode"):
             raise ValueError(f"role must be 'prefill' or 'decode', got {role!r}")
         self.role = role or ""
-        # KV layout: "paged" (block pool + per-slot block tables) is the
-        # default via Config.llm_cache_kind; explicit args override the
-        # config knobs. Engines under a mesh auto-fall back to dense — the
-        # GSPMD sharding of the paged scatter/gather is not wired yet.
-        rc = get_config()
-        kind = cache_kind if cache_kind is not None else rc.llm_cache_kind
-        if kind == "paged" and mesh is not None:
-            if cache_kind is not None:
-                raise ValueError("cache_kind='paged' with a mesh is not supported yet")
-            kind = "dense"
-        if kind not in ("dense", "paged"):
-            raise ValueError(f"cache_kind must be 'dense' or 'paged', got {kind!r}")
-        if self.role and kind != "paged":
-            raise ValueError(
-                f"role={self.role!r} requires the paged KV cache: a dense "
-                "cache has no block table to migrate between replicas"
-            )
-        self.cache_kind = kind
-        self.kv_block_size = int(
-            kv_block_size if kv_block_size is not None else rc.kv_block_size
-        )
+        self.kv_block_size = int(kv_block_size)
         if self.kv_block_size < 1:
             raise ValueError(f"kv_block_size must be >= 1, got {self.kv_block_size}")
         # static block-table width: enough logical blocks for a max-length
         # sequence — the table shape never depends on the allocation pattern
         self.max_blocks_per_slot = -(-self.S // self.kv_block_size)
-        nb = int(kv_num_blocks if kv_num_blocks is not None else rc.kv_num_blocks)
+        nb = int(kv_num_blocks)
         if nb <= 0:
-            # auto: dense-equivalent capacity (+1 for the garbage page)
+            # auto: every slot can hold a max-length sequence (+1 for the
+            # garbage page)
             nb = self.B * self.max_blocks_per_slot + 1
         self.kv_num_blocks = nb
-        self.prefill_chunk_tokens = int(
-            prefill_chunk_tokens if prefill_chunk_tokens is not None
-            else rc.prefill_chunk_tokens
-        )
-        self._allocator = BlockAllocator(nb) if kind == "paged" else None
-        # prefix-aware KV reuse is a paged-pool feature (dense engines have
-        # no pages to share); on by default via Config.llm_prefix_cache
-        use_prefix = prefix_cache if prefix_cache is not None else rc.llm_prefix_cache
-        pcb = int(
-            prefix_cache_max_blocks if prefix_cache_max_blocks is not None
-            else rc.prefix_cache_max_blocks
-        )
+        self.prefill_chunk_tokens = int(prefill_chunk_tokens)
+        self._allocator = BlockAllocator(nb)
         self._prefix = (
-            PrefixCache(self.kv_block_size, pcb)
-            if (kind == "paged" and use_prefix)
+            PrefixCache(self.kv_block_size, int(prefix_cache_max_blocks))
+            if prefix_cache
             else None
         )
         # prefix-cache outcome counts per admitted request, tokens whose
@@ -299,25 +275,31 @@ class LLMEngine:
         self.top_k = top_k
         self.top_p = top_p
         self.quantized = quantize
-        self.mesh = mesh
-        self._kv_spec = None
+        self._kv_sharding = None
         if mesh is not None:
             # tensor-parallel serving: params shard per the Megatron layout
-            # (ray_tpu.models.transformer.param_specs), the KV cache's head
-            # axis over tp when divisible; GSPMD partitions the einsum
-            # attention, so decode collectives ride ICI. The Pallas decode
-            # kernel is bypassed (GSPMD cannot partition a Mosaic kernel).
-            from jax.sharding import NamedSharding, PartitionSpec as P
+            # (ray_tpu.models.transformer.param_specs), the KV pool over its
+            # heads when tp divides them (each device then holds whole pages
+            # of its own heads); GSPMD partitions the einsum attention, so
+            # decode collectives ride ICI. The Pallas decode kernel is
+            # bypassed (GSPMD cannot partition a Mosaic kernel).
+            from jax.sharding import NamedSharding
 
             from ray_tpu.models.transformer import _kv_tp_ok, shard_params
 
             if quantize:
                 raise ValueError("quantize=True with mesh is not supported yet")
+            if self.role:
+                raise ValueError(
+                    f"role={self.role!r} with mesh is not supported yet: migrated "
+                    "blocks are exported from and landed in an unsharded pool"
+                )
             if tp not in mesh.axis_names:
                 raise ValueError(f"mesh has no {tp!r} axis: {mesh.axis_names}")
             params = shard_params(params, mesh, cfg, tp=tp, ep=tp)
-            kv_ax = tp if _kv_tp_ok(cfg, mesh, tp) else None
-            self._kv_spec = NamedSharding(mesh, P(None, None, kv_ax, None, None))
+            self._kv_sharding = NamedSharding(
+                mesh, paged_cache_spec(tp if _kv_tp_ok(cfg, mesh, tp) else None)
+            )
         if quantize and (cfg.dense_stack or cfg.dropless):
             # no silent path: the int8 scales ride ONE stack of layers whose
             # every weight is an xs leaf of the layer scan
@@ -405,10 +387,7 @@ class LLMEngine:
         self._staged: Dict[str, dict] = {}
         self.num_migrations_out = 0
         self.num_migrations_in = 0
-        metric_defs.LLM_KV_BLOCK_POOL_SIZE.set(
-            self._allocator.capacity if self._allocator is not None else 0,
-            self._depth_tags,
-        )
+        metric_defs.LLM_KV_BLOCK_POOL_SIZE.set(self._allocator.capacity, self._depth_tags)
         metric_defs.LLM_KV_BLOCKS_IN_USE.set(0, self._depth_tags)
         metric_defs.LLM_KV_BLOCKS_SHARED.set(0, self._depth_tags)
         metric_defs.LLM_PREFIX_CACHE_BLOCKS.set(0, self._depth_tags)
@@ -419,36 +398,18 @@ class LLMEngine:
         cfg_ = cfg
         moe_counted = self._moe_counted
         layer_scales = self._layer_scales
-        kv_spec = self._kv_spec
         # under a mesh the einsum path partitions via GSPMD; the Pallas
-        # kernel paths stay for the single-device engine
+        # decode kernel stays for the single-device engine
         use_kernel = None if mesh is None else False
-        prefill_kernel = mesh is None and backend.on_tpu()
+        # under a mesh a program that returns the pool returns it as it was
+        # placed: the donated buffers are updated where they lie and the next
+        # call finds the sharding it was compiled for (left to itself GSPMD
+        # re-shards a replicated pool). None, jit's default, on one device
+        kv_sharding = self._kv_sharding
 
-        @jax.jit
-        def _prefill_one(params, tokens, length):
-            """tokens [1, Tb] (bucket-padded); length is traced so all
-            prompts in a bucket share ONE compile. Returns (logits [V],
-            cache row)."""
-            row = init_cache(cfg_, 1, self.S)
-            if kv_spec is not None:
-                row = {k: jax.lax.with_sharding_constraint(v, kv_spec) for k, v in row.items()}
-            positions = jnp.arange(tokens.shape[1])[None, :]
-            logits, row = forward_with_cache(
-                cfg_, params, row, tokens, positions,
-                layer_scales=layer_scales, use_decode_kernel=use_kernel,
-                use_prefill_kernel=prefill_kernel,  # positions start at 0 here
-            )
-            return jax.lax.dynamic_index_in_dim(logits[0], length - 1, 0, keepdims=False), row
-
-        @functools.partial(jax.jit, donate_argnums=(0,))
-        def _insert(cache, row, slot):
-            out = {}
-            for kk in ("k", "v"):
-                out[kk] = jax.vmap(
-                    lambda c, r: jax.lax.dynamic_update_slice(c, r, (slot, 0, 0, 0))
-                )(cache[kk], row[kk])
-            return out
+        def pool_among(n_outputs: int):  # the expert counts, where returned, come last
+            rest = (None,) * (n_outputs - 2 + moe_counted)
+            return None if kv_sharding is None else (None, kv_sharding) + rest
 
         top_k_, top_p_ = self.top_k, self.top_p
 
@@ -461,98 +422,74 @@ class LLMEngine:
             sampled = jax.vmap(jax.random.categorical)(keys, scaled)
             return jnp.where(greedy, jnp.argmax(logits, -1), sampled).astype(jnp.int32)
 
-        _sample = jax.jit(_sample_impl)
+        self._sample = jax.jit(_sample_impl)
 
         # the decode program: K sequential decode+sample steps inside ONE
         # jitted lax.scan (K = decode_chunk; 1 = classic per-token
         # stepping), so the host pays one dispatch/readback round trip per
         # K tokens. One key split per generated token.  The cache is
         # donated: the engine holds the only reference and reassigns, so
-        # XLA updates the [L,B,Hkv,S,Dh] buffers in place.
+        # XLA updates the pool's buffers in place.
         K_chunk = self.decode_chunk
 
-        @functools.partial(jax.jit, donate_argnums=(1,))
-        def _decode_k(params, cache, toks, pos, temps, key):
+        @functools.partial(jax.jit, donate_argnums=(1,), out_shardings=pool_among(2))
+        def _prefill_chunk(params, cache, toks, bt, start, length):
+            """toks [1, C] chunk-padded; bt [1, M]; start/length traced,
+            so every chunk of every prompt at width C shares ONE
+            compile. Writes K/V for the chunk's ``length`` real tokens
+            through the block table and returns the last real token's
+            logits [V] (only the final chunk's are consumed)."""
+            C = toks.shape[1]
+            positions = start + jnp.arange(C)[None, :]
+            valid = (jnp.arange(C) < length)[None, :]
+            logits, cache, moe = paged_forward_counted(
+                cfg_, params, cache, bt, toks, positions,
+                valid=valid, layer_scales=layer_scales, use_decode_kernel=False,
+            )
+            last = jax.lax.dynamic_index_in_dim(logits[0], length - 1, 0, keepdims=False)
+            return (last, cache, moe) if moe_counted else (last, cache)
+
+        @functools.partial(jax.jit, donate_argnums=(1,), out_shardings=pool_among(3))
+        def _decode_k_paged(params, cache, toks, pos, temps, key, bt):
+            # a live row's first page is never the garbage page 0 (idle
+            # rows decode through all-zero tables): the expert layers
+            # count the live rows' assignments only
+            live = (bt[:, 0] > 0)[:, None] if moe_counted else None
+
             def body(carry, _):
                 cache, toks, pos, key = carry
-                logits, cache = decode_step(
-                    cfg_, params, cache, toks, pos,
-                    layer_scales=layer_scales, use_decode_kernel=use_kernel,
+                logits, cache, moe = paged_forward_counted(
+                    cfg_, params, cache, bt, toks[:, None], pos[:, None],
+                    layer_scales=layer_scales, use_decode_kernel=use_kernel, valid=live,
                 )
                 key, sub = jax.random.split(key)
-                nxt = _sample_impl(sub, logits, temps)
-                return (cache, nxt, pos + 1, key), nxt
+                nxt = _sample_impl(sub, logits[:, 0], temps)
+                return (cache, nxt, pos + 1, key), (nxt, moe)
 
-            (cache, _, _, key), toks_k = jax.lax.scan(
+            (cache, _, _, key), (toks_k, moe) = jax.lax.scan(
                 body, (cache, toks, pos, key), None, length=K_chunk
             )
-            return jnp.swapaxes(toks_k, 0, 1), cache, key  # [B, K]
+            out = (jnp.swapaxes(toks_k, 0, 1), cache, key)  # [B, K]
+            if moe_counted:
+                out += (jax.tree.map(lambda a: a.sum(0), moe),)  # over the K steps
+            return out
 
-        self._decode_k = _decode_k
-        self._prefill_one = _prefill_one
-        self._insert = _insert
-        self._sample = _sample
+        # copy-on-write primitive (models/generation.copy_paged_page):
+        # donated so XLA copies the page in place in the pool buffers
+        self._copy_page = jax.jit(copy_paged_page, donate_argnums=(0,), out_shardings=kv_sharding)
 
-        if self.cache_kind == "paged":
-
-            @functools.partial(jax.jit, donate_argnums=(1,))
-            def _prefill_chunk(params, cache, toks, bt, start, length):
-                """toks [1, C] chunk-padded; bt [1, M]; start/length traced,
-                so every chunk of every prompt at width C shares ONE
-                compile. Writes K/V for the chunk's ``length`` real tokens
-                through the block table and returns the last real token's
-                logits [V] (only the final chunk's are consumed)."""
-                C = toks.shape[1]
-                positions = start + jnp.arange(C)[None, :]
-                valid = (jnp.arange(C) < length)[None, :]
-                logits, cache, moe = paged_forward_counted(
-                    cfg_, params, cache, bt, toks, positions,
-                    valid=valid, layer_scales=layer_scales, use_decode_kernel=False,
-                )
-                last = jax.lax.dynamic_index_in_dim(logits[0], length - 1, 0, keepdims=False)
-                return (last, cache, moe) if moe_counted else (last, cache)
-
-            @functools.partial(jax.jit, donate_argnums=(1,))
-            def _decode_k_paged(params, cache, toks, pos, temps, key, bt):
-                # a live row's first page is never the garbage page 0 (idle
-                # rows decode through all-zero tables): the expert layers
-                # count the live rows' assignments only
-                live = (bt[:, 0] > 0)[:, None] if moe_counted else None
-
-                def body(carry, _):
-                    cache, toks, pos, key = carry
-                    logits, cache, moe = paged_forward_counted(
-                        cfg_, params, cache, bt, toks[:, None], pos[:, None],
-                        layer_scales=layer_scales, use_decode_kernel=use_kernel, valid=live,
-                    )
-                    key, sub = jax.random.split(key)
-                    nxt = _sample_impl(sub, logits[:, 0], temps)
-                    return (cache, nxt, pos + 1, key), (nxt, moe)
-
-                (cache, _, _, key), (toks_k, moe) = jax.lax.scan(
-                    body, (cache, toks, pos, key), None, length=K_chunk
-                )
-                out = (jnp.swapaxes(toks_k, 0, 1), cache, key)  # [B, K]
-                if moe_counted:
-                    out += (jax.tree.map(lambda a: a.sum(0), moe),)  # over the K steps
-                return out
-
-            # copy-on-write primitive (models/generation.copy_paged_page):
-            # donated so XLA copies the page in place in the pool buffers
-            self._copy_page = jax.jit(copy_paged_page, donate_argnums=(0,))
-
-            # Land a migrated block set ``[N, 2, L, block_size, Hkv, Dh]`` into
-            # the pool in ONE donated scatter: per-block writes cost a
-            # dispatch each — 24 blocks of a long prompt stall the engine
-            # loop ~10ms on the bench box. Callers bucket-pad N by repeating
-            # the last (block, page) pair (the duplicate scatter indices stay
-            # idempotent), keeping the compile count at O(log blocks), not
-            # one per block count.
-            self._write_blocks = jax.jit(write_paged_pages, donate_argnums=(0,))
-            # the page index is traced: every exported block shares one compile
-            self._export_page = jax.jit(functools.partial(export_paged_page, cfg_))
-            self._prefill_chunk = _prefill_chunk
-            self._decode_k_paged = _decode_k_paged
+        # Land a migrated block set ``[N, 2, L, block_size, Hkv, Dh]`` into
+        # the pool in ONE donated scatter: per-block writes cost a
+        # dispatch each — 24 blocks of a long prompt stall the engine
+        # loop ~10ms on the bench box. Callers bucket-pad N by repeating
+        # the last (block, page) pair (the duplicate scatter indices stay
+        # idempotent), keeping the compile count at O(log blocks), not
+        # one per block count.
+        self._write_blocks = jax.jit(write_paged_pages, donate_argnums=(0,), out_shardings=kv_sharding)
+        # the page index is traced: every exported block shares one compile
+        self._export_page = jax.jit(functools.partial(export_paged_page, cfg_))
+        self._prefill_chunk = _prefill_chunk
+        self._decode_k_paged = _decode_k_paged
 
         self._thread = threading.Thread(target=self._loop, daemon=True, name="llm-engine")
         self._thread.start()
@@ -611,18 +548,17 @@ class LLMEngine:
                 f"prompt ({len(prompt)}) + max_tokens ({max_tokens}) exceeds "
                 f"engine max_seq_len {self.S}"
             )
-        if self._allocator is not None:
-            # never-fits contract (same as max_queued_prefill_tokens below):
-            # a request needing more pages than the POOL holds can never be
-            # admitted — that is a config/input error at submit, not a
-            # retry-after-able overload and not a failure deep in prefill
-            needed = -(-(len(prompt) + max_tokens - 1) // self.kv_block_size)
-            if needed > self._allocator.capacity:
-                raise ValueError(
-                    f"prompt ({len(prompt)}) + max_tokens ({max_tokens}) needs "
-                    f"{needed} KV blocks but the pool only holds "
-                    f"{self._allocator.capacity} and would never be admitted"
-                )
+        # never-fits contract (same as max_queued_prefill_tokens below):
+        # a request needing more pages than the POOL holds can never be
+        # admitted — that is a config/input error at submit, not a
+        # retry-after-able overload and not a failure deep in prefill
+        needed = -(-(len(prompt) + max_tokens - 1) // self.kv_block_size)
+        if needed > self._allocator.capacity:
+            raise ValueError(
+                f"prompt ({len(prompt)}) + max_tokens ({max_tokens}) needs "
+                f"{needed} KV blocks but the pool only holds "
+                f"{self._allocator.capacity} and would never be admitted"
+            )
         if self._max_queued_tokens and len(prompt) > self._max_queued_tokens:
             # a prompt that ALONE exceeds the budget can never be admitted:
             # that is a config/input error, not a retry-after-able overload
@@ -765,8 +701,6 @@ class LLMEngine:
         ``mig_id`` and resolve the future with the migration ticket
         (header-only — zero KV payload bytes).  The request reserves only
         the prompt's pages (``max_tokens=1``): decode never runs here."""
-        if self.cache_kind != "paged":
-            raise ValueError("prefill_export requires the paged KV cache")
         return self._submit_req(
             prompt, max_tokens=1, temperature=temperature, eos_id=eos_id,
             tenant=tenant, deadline_ts=deadline_ts, _export_mig_id=mig_id,
@@ -791,8 +725,6 @@ class LLMEngine:
         indices already covered by this replica's prefix cache may be
         omitted.  Admission, block budget, COW and prefix-cache semantics
         are the normal paged path; only prefill compute is skipped."""
-        if self.cache_kind != "paged":
-            raise ValueError("adopt_migration requires the paged KV cache")
         return self._submit_req(
             list(ticket["prompt"]), max_tokens=max_tokens,
             temperature=temperature, eos_id=eos_id, tenant=tenant,
@@ -815,11 +747,8 @@ class LLMEngine:
 
     def kv_free_blocks(self) -> int:
         """Free pages right now — the decode-pool routing signal."""
-        alloc = self._allocator
-        if alloc is None:
-            return 0
         with self._lock:
-            return alloc.free_blocks
+            return self._allocator.free_blocks
 
     def release_migration(self, mig_id: str) -> bool:
         """Drop a staged export: forget the arrays and unregister the
@@ -864,11 +793,10 @@ class LLMEngine:
                 "prefill_forwards": self._prefill_count,
                 "slots_evicted": self.num_slots_evicted,
                 "shed": self.num_shed,
-                "cache_kind": self.cache_kind,
-                "kv_block_size": self.kv_block_size if alloc is not None else 0,
-                "kv_block_pool_size": alloc.capacity if alloc is not None else 0,
-                "kv_blocks_in_use": alloc.used_blocks if alloc is not None else 0,
-                "kv_blocks_shared": alloc.shared_blocks if alloc is not None else 0,
+                "kv_block_size": self.kv_block_size,
+                "kv_block_pool_size": alloc.capacity,
+                "kv_blocks_in_use": alloc.used_blocks,
+                "kv_blocks_shared": alloc.shared_blocks,
                 "prefilling": len(self._prefilling),
                 "prefill_chunks": self._prefill_chunk_count,
                 "prefix_cache_enabled": self._prefix is not None,
@@ -912,21 +840,15 @@ class LLMEngine:
         params, cache = jax.tree.map(abstract, (self.params, self._cache))
         toks = jax.ShapeDtypeStruct((self.B,), jnp.int32)
         temps = jax.ShapeDtypeStruct((self.B,), jnp.float32)
-        if self.cache_kind == "paged":
-            bt = jax.ShapeDtypeStruct(self._block_tables.shape, jnp.int32)
-            lowered = self._decode_k_paged.lower(
-                params, cache, toks, toks, temps, self._key, bt
-            )
-        else:
-            lowered = self._decode_k.lower(params, cache, toks, toks, temps, self._key)
-        return lowered.as_text()
+        bt = jax.ShapeDtypeStruct(self._block_tables.shape, jnp.int32)
+        return self._decode_k_paged.lower(params, cache, toks, toks, temps, self._key, bt).as_text()
 
     def admission_snapshot(self) -> Dict[str, Any]:
         """Bounds + depths for GET /api/overload (admission source)."""
         with self._lock:
             alloc = self._allocator
-            pool = alloc.capacity if alloc is not None else 0
-            in_use = alloc.used_blocks if alloc is not None else 0
+            pool = alloc.capacity
+            in_use = alloc.used_blocks
             probes = sum(self._prefix_results.values())
             useful = self._prefix_results["hit"] + self._prefix_results["partial"]
             return {
@@ -944,12 +866,11 @@ class LLMEngine:
                 "by_tenant": self._queue.depth_by_tenant(),
                 "slots_evicted": self.num_slots_evicted,
                 "shed": self.num_shed,
-                "cache_kind": self.cache_kind,
-                "kv_block_size": self.kv_block_size if alloc is not None else 0,
+                "kv_block_size": self.kv_block_size,
                 "kv_block_pool_size": pool,
                 "kv_blocks_in_use": in_use,
-                "kv_blocks_shared": alloc.shared_blocks if alloc is not None else 0,
-                "kv_block_occupancy": (in_use / pool) if pool else 0.0,
+                "kv_blocks_shared": alloc.shared_blocks,
+                "kv_block_occupancy": in_use / pool,
                 "prefilling": len(self._prefilling),
                 "prefill_chunks": self._prefill_chunk_count,
                 "waiting_for_blocks": 1 if self._held_req is not None else 0,
@@ -976,11 +897,10 @@ class LLMEngine:
         # zero this engine's gauge series; the freed token (and thus the
         # series label) is reused by the next engine
         metric_defs.ADMISSION_QUEUE_DEPTH.set(0, self._depth_tags)
-        if self._allocator is not None:
-            metric_defs.LLM_KV_BLOCKS_IN_USE.set(0, self._depth_tags)
-            metric_defs.LLM_KV_BLOCK_POOL_SIZE.set(0, self._depth_tags)
-            metric_defs.LLM_KV_BLOCKS_SHARED.set(0, self._depth_tags)
-            metric_defs.LLM_PREFIX_CACHE_BLOCKS.set(0, self._depth_tags)
+        metric_defs.LLM_KV_BLOCKS_IN_USE.set(0, self._depth_tags)
+        metric_defs.LLM_KV_BLOCK_POOL_SIZE.set(0, self._depth_tags)
+        metric_defs.LLM_KV_BLOCKS_SHARED.set(0, self._depth_tags)
+        metric_defs.LLM_PREFIX_CACHE_BLOCKS.set(0, self._depth_tags)
         with self._lock:
             pending = [r for r in self._queue.items() if not r.future.done()]
             pending += [r for r in self._slots if r is not None and not r.future.done()]
@@ -1029,10 +949,9 @@ class LLMEngine:
 
     def _pool_gauges_locked(self):
         """(in_use, shared, cache_blocks) snapshot; caller holds the lock."""
-        alloc = self._allocator
         return (
-            alloc.used_blocks if alloc is not None else 0,
-            alloc.shared_blocks if alloc is not None else 0,
+            self._allocator.used_blocks,
+            self._allocator.shared_blocks,
             len(self._prefix) if self._prefix is not None else 0,
         )
 
@@ -1099,17 +1018,11 @@ class LLMEngine:
         })
 
     # -- engine loop --------------------------------------------------------
-    def _admit(self) -> None:
-        if self.cache_kind == "paged":
-            self._admit_paged()
-        else:
-            self._admit_dense()
-
     def _pop_admissible(self, *, need_free_slot: bool = True):
         """Shared admit-loop head: pop (or resume) the next runnable request.
 
         Returns ``(req, free_slots)`` with shed-on-pop filtering applied, or
-        ``None`` when there is nothing admissible right now. A paged engine's
+        ``None`` when there is nothing admissible right now. The
         head-of-line request waiting for blocks lives in ``self._held_req``
         and is resumed here (never re-pushed: re-pushing would re-bill its
         stride and let later arrivals overtake the weighted-fair order).
@@ -1171,58 +1084,7 @@ class LLMEngine:
                     req.trace.mark("wfq_pop")
             return req, free
 
-    def _admit_dense(self) -> None:
-        while True:
-            popped = self._pop_admissible()
-            if popped is None:
-                return
-            req, free = popped
-            slot = free[0]
-            if req.trace is not None:
-                # dense admission is immediate: no kv_block_wait phase
-                req.trace.mark("admitted")
-            try:
-                tp = len(req.prompt)
-                bucket = _bucket(tp, cap=self.S)
-                toks = np.zeros((1, bucket), np.int32)
-                toks[0, :tp] = req.prompt
-                stalled = bool(self._active.any())
-                t0 = time.perf_counter()
-                logits, row = self._prefill_one(self.params, jnp.asarray(toks), jnp.int32(tp))
-                jax.block_until_ready(logits)
-                if stalled:
-                    # decode slots sat idle for this whole one-shot prefill
-                    metric_defs.LLM_DECODE_STALL.observe(time.perf_counter() - t0)
-                    self._note_stall()
-                with self._lock:  # stats() reads this under the lock
-                    self._prefill_count += 1
-                self._cache = self._insert(self._cache, row, slot)
-                # first output token comes straight from the prefill logits
-                self._key, sub = jax.random.split(self._key)
-                tok0 = int(
-                    self._sample(
-                        sub, logits[None, :], jnp.asarray([req.temperature], jnp.float32)
-                    )[0]
-                )
-            except BaseException as exc:  # noqa: BLE001
-                # the popped request is in neither queue nor slots — fail it
-                # HERE or its caller hangs forever
-                self._fail_admit(req, exc)
-                continue
-            req.slot = slot
-            req.generated = [tok0]
-            self._note_first_token(req)
-            req.emit(tok0)
-            with self._lock:
-                self._slots[slot] = req
-                self._active[slot] = True
-                self._last_tok[slot] = tok0
-                self._pos[slot] = tp
-                self._temps[slot] = req.temperature
-            if self._maybe_finish(req, tok0):
-                continue
-
-    def _admit_paged(self) -> None:
+    def _admit(self) -> None:
         """Block-aware admission: reserve the request's whole page budget up
         front (``ceil((prompt + max_tokens - 1) / block_size)`` — the last
         written position is ``prompt + max_tokens - 2``), so an admitted
@@ -1519,13 +1381,13 @@ class LLMEngine:
             req.future.set_exception(RuntimeError(f"prefill failed: {exc!r}"))
         if req.stream_queue is not None:
             req.stream_queue.put(_STREAM_END)
-        if self._allocator is not None and req.slot >= 0:
+        if req.slot >= 0:
             with self._lock:
                 self._release_blocks_locked(req.slot)
                 gauges = self._pool_gauges_locked()
             self._publish_pool_gauges(*gauges)
         if self._cache["k"].is_deleted():
-            # a donated insert/chunk consumed the cache then failed: the
+            # a donated chunk or page write consumed the cache then failed: the
             # shared cache is gone, taking every in-flight slot with it
             self._fail_inflight(RuntimeError(f"cache lost in failed prefill: {exc!r}"))
             self._reset_cache()
@@ -1575,7 +1437,7 @@ class LLMEngine:
         block-table entry swapped, so shared pages are only ever READ.
         By construction the admission path never maps a to-be-written block
         to a shared page, so this is an invariant net, not a hot path."""
-        if n < 1 or self._allocator is None:
+        if n < 1:
             return
         bs = self.kv_block_size
         lo = max(0, start // bs)
@@ -1714,9 +1576,7 @@ class LLMEngine:
         walks all ``cdiv(len, block)`` pages of a sequence (``len``: its
         cached tokens and the one the step writes), a sliding layer those
         from the page of its window's first position on. What the paged
-        decode kernel's work follows; 0.0 for the dense cache."""
-        if self.cache_kind != "paged":
-            return 0.0
+        decode kernel's work follows."""
         bs = self.kv_block_size
         lens = self._pos[self._active].astype(np.int64) + 1
         last = -(-lens // bs)
@@ -1729,17 +1589,14 @@ class LLMEngine:
             req.eos_id is not None and tok == req.eos_id
         )
         if done:
-            evicted_n = 0
             with self._lock:
                 self._active[req.slot] = False
                 self._slots[req.slot] = None
-                if self._allocator is not None:
-                    evicted_n = self._retire_blocks_locked(req)
-                    gauges = self._pool_gauges_locked()
-            if self._allocator is not None:
-                if evicted_n:
-                    metric_defs.LLM_PREFIX_EVICTIONS.inc(evicted_n)
-                self._publish_pool_gauges(*gauges)
+                evicted_n = self._retire_blocks_locked(req)
+                gauges = self._pool_gauges_locked()
+            if evicted_n:
+                metric_defs.LLM_PREFIX_EVICTIONS.inc(evicted_n)
+            self._publish_pool_gauges(*gauges)
             self._record_done(req, "finish")
             req.future.set_result(req.generated)
             if req.stream_queue is not None:
@@ -1749,26 +1606,19 @@ class LLMEngine:
     def _step(self) -> None:
         toks = jnp.asarray(self._last_tok)
         pos = jnp.asarray(self._pos)
-        if self.cache_kind == "paged":
-            # copy-on-write net: a decode chunk writes positions
-            # [pos, pos + K) — if any of those blocks still maps to a
-            # shared page, give the slot its own copy before stepping
-            for i in range(self.B):
-                if self._active[i]:
-                    self._cow_shared_writes(i, int(self._pos[i]), self.decode_chunk)
-            # inactive rows decode through all-zero tables -> garbage page 0,
-            # so freed pages are never written after release
-            bt = jnp.asarray(self._block_tables * self._active[:, None].astype(np.int32))
-            out, self._cache, self._key, *moe = self._decode_k_paged(
-                self.params, self._cache, toks, pos,
-                jnp.asarray(self._temps), self._key, bt,
-            )
-        else:
-            moe = ()
-            out, self._cache, self._key = self._decode_k(
-                self.params, self._cache, toks, pos,
-                jnp.asarray(self._temps), self._key,
-            )
+        # copy-on-write net: a decode chunk writes positions
+        # [pos, pos + K) — if any of those blocks still maps to a
+        # shared page, give the slot its own copy before stepping
+        for i in range(self.B):
+            if self._active[i]:
+                self._cow_shared_writes(i, int(self._pos[i]), self.decode_chunk)
+        # inactive rows decode through all-zero tables -> garbage page 0,
+        # so freed pages are never written after release
+        bt = jnp.asarray(self._block_tables * self._active[:, None].astype(np.int32))
+        out, self._cache, self._key, *moe = self._decode_k_paged(
+            self.params, self._cache, toks, pos,
+            jnp.asarray(self._temps), self._key, bt,
+        )
         sampled = np.asarray(out)  # [B, K]
         self._decode_step_count += sampled.shape[1]
         self._note_moe(moe, decode=True)
@@ -1792,13 +1642,11 @@ class LLMEngine:
     def _reset_cache(self) -> None:
         """(Re)allocate the decode cache — also the recovery path after a
         failed donated step leaves the old buffers deleted."""
-        if self.cache_kind == "paged":
-            self._cache = init_paged_cache(self.cfg, self.kv_num_blocks, self.kv_block_size)
-            return
-        cache = init_cache(self.cfg, self.B, self.S)
-        if self._kv_spec is not None:
-            cache = {k: jax.device_put(v, self._kv_spec) for k, v in cache.items()}
-        self._cache = cache
+        init = functools.partial(init_paged_cache, self.cfg, self.kv_num_blocks, self.kv_block_size)
+        if self._kv_sharding is not None:
+            # each device zeroes its own shard: the whole pool never lies on one
+            init = jax.jit(init, out_shardings=self._kv_sharding)
+        self._cache = init()
 
     def _fail_inflight(self, error: BaseException) -> None:
         """Fail every queued, prefilling, and in-slot request (loop-crash
@@ -1814,19 +1662,17 @@ class LLMEngine:
             self._queued_tokens = 0
             self._slots = [None] * self.B
             self._active[:] = False
-            if self._allocator is not None:
-                for i in range(self.B):
-                    self._release_blocks_locked(i)
-                if self._prefix is not None:
-                    # the device pool is about to be re-initialized; cached
-                    # page CONTENTS die with it, so the index must too —
-                    # drop every node and its reference unconditionally
-                    stale = self._prefix.drain()
-                    if stale:
-                        self._allocator.free(stale)
+            for i in range(self.B):
+                self._release_blocks_locked(i)
+            if self._prefix is not None:
+                # the device pool is about to be re-initialized; cached
+                # page CONTENTS die with it, so the index must too —
+                # drop every node and its reference unconditionally
+                stale = self._prefix.drain()
+                if stale:
+                    self._allocator.free(stale)
         metric_defs.ADMISSION_QUEUE_DEPTH.set(0, self._depth_tags)
-        if self._allocator is not None:
-            self._publish_pool_gauges(0, 0, 0)
+        self._publish_pool_gauges(0, 0, 0)
         for r in victims:
             self._record_done(r, "crash", str(error))
             if not r.future.done():
@@ -1847,10 +1693,9 @@ class LLMEngine:
             for i, _ in victims:
                 self._slots[i] = None
                 self._active[i] = False
-                if self._allocator is not None:
-                    self._release_blocks_locked(i)
+                self._release_blocks_locked(i)
             gauges = self._pool_gauges_locked()
-        if victims and self._allocator is not None:
+        if victims:
             self._publish_pool_gauges(*gauges)
         for _, r in victims:
             self.num_slots_evicted += 1
@@ -1866,9 +1711,7 @@ class LLMEngine:
             try:
                 self._evict_cancelled()
                 self._admit()
-                progressed = False
-                if self.cache_kind == "paged":
-                    progressed = self._prefill_tick()
+                progressed = self._prefill_tick()
                 if self._active.any():
                     self._step()
                 elif not progressed:
@@ -1923,12 +1766,11 @@ class LLMServer:
         max_queued_requests: int = 256,
         max_queued_prefill_tokens: int = 0,
         tenant_weights: Optional[Dict[str, float]] = None,
-        cache_kind: Optional[str] = None,
-        kv_block_size: Optional[int] = None,
-        kv_num_blocks: Optional[int] = None,
-        prefill_chunk_tokens: Optional[int] = None,
-        prefix_cache: Optional[bool] = None,
-        prefix_cache_max_blocks: Optional[int] = None,
+        kv_block_size: int = 16,
+        kv_num_blocks: int = 0,
+        prefill_chunk_tokens: int = 0,
+        prefix_cache: bool = True,
+        prefix_cache_max_blocks: int = 0,
         role: Optional[str] = None,
     ):
         made = model_factory()
@@ -1949,7 +1791,6 @@ class LLMServer:
             max_queued_requests=max_queued_requests,
             max_queued_prefill_tokens=max_queued_prefill_tokens,
             tenant_weights=tenant_weights,
-            cache_kind=cache_kind,
             kv_block_size=kv_block_size,
             kv_num_blocks=kv_num_blocks,
             prefill_chunk_tokens=prefill_chunk_tokens,

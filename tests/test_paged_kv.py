@@ -8,13 +8,14 @@ Five contracts:
   interpret-mode Pallas kernel; MHA, GQA, head_dim 64 and 128)
 - runner: the paged pool carried through the layer loop gives the dense
   cache's logits bit for bit on the CPU
-- engine identity: the paged engine is token-identical to the dense engine
+- engine identity: the engine is token-identical to one-shot ``generate()``
   under greedy decoding, and chunked prefill is token-identical to one-shot
   for every chunk width
 - leak checks: every release path (finish, eos, deadline shed, disconnect
   evict, prefill crash, loop crash) returns ALL blocks to the pool
 """
 
+import functools
 import time
 
 import jax
@@ -22,6 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from llm_reference import greedy_reference
 from ray_tpu.exceptions import DeadlineExceededError, OverloadedError
 from ray_tpu.models import TransformerConfig, init_params
 from ray_tpu.serve.kv_blocks import BlockAllocator
@@ -31,6 +33,7 @@ CFG = TransformerConfig(
     vocab_size=89, d_model=32, n_layers=2, n_heads=4, n_kv_heads=2, d_ff=64,
     attention="dense", dtype=jnp.float32,
 )
+_reference = functools.partial(greedy_reference, CFG)
 
 
 @pytest.fixture(scope="module")
@@ -41,7 +44,7 @@ def params():
 def _paged(params, **kw):
     kw.setdefault("max_batch_size", 4)
     kw.setdefault("max_seq_len", 64)
-    return LLMEngine(CFG, params, cache_kind="paged", **kw)
+    return LLMEngine(CFG, params, **kw)
 
 
 def _wait(pred, timeout=60):
@@ -446,23 +449,20 @@ def test_paged_cache_spec_shards_heads_not_page_tokens():
 
 
 # --------------------------------------------------------------------------
-# engine identity: paged == dense, chunked == one-shot
+# engine identity: engine == generate(), chunked == one-shot
 # --------------------------------------------------------------------------
 PROMPTS = [[3, 5, 7, 11, 13], [2] * 17, list(range(1, 31)), [8, 9]]
 
 
-def test_paged_engine_token_identical_to_dense(params):
-    dense = LLMEngine(CFG, params, max_batch_size=4, max_seq_len=64, cache_kind="dense")
+def test_paged_engine_token_identical_to_generate(params):
     paged = _paged(params)
     try:
-        ref = [f.result(timeout=120) for f in
-               [dense.submit(p, max_tokens=8) for p in PROMPTS]]
+        ref = [_reference(params, p, 8) for p in PROMPTS]
         got = [f.result(timeout=120) for f in
                [paged.submit(p, max_tokens=8) for p in PROMPTS]]
         assert got == ref
         _assert_no_leak(paged)
     finally:
-        dense.shutdown()
         paged.shutdown()
 
 
@@ -641,7 +641,6 @@ def test_paged_snapshot_and_metrics_registered(params):
     try:
         snap = [s for s in admission.sources_snapshot()
                 if s.get("layer") == "engine"][-1]
-        assert snap["cache_kind"] == "paged"
         assert snap["kv_block_pool_size"] == eng._allocator.capacity
         assert snap["kv_blocks_in_use"] == 0
         assert snap["kv_block_occupancy"] == 0.0

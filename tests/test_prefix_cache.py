@@ -10,13 +10,14 @@ Four contracts:
   free page or the garbage page) raises instead of corrupting the pool
 - engine identity: warm runs (full hit + COW, partial hit, chunked prefill
   resuming mid-prompt, divergent suffixes off a shared prefix) are
-  token-identical to the dense reference under greedy decoding
+  token-identical to one-shot ``generate()`` under greedy decoding
 - leak + determinism: every release path under ACTIVE sharing returns the
   request's references (pool == cache after quiesce, flush drains both),
   loop crash invalidates the whole cache, and the same workload on a
   bounded cache evicts the same pages in the same order
 """
 
+import functools
 import threading
 import time
 
@@ -25,6 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from llm_reference import greedy_reference
 from ray_tpu.models import TransformerConfig, init_params
 from ray_tpu.serve.kv_blocks import BlockAllocator
 from ray_tpu.serve.llm import LLMEngine
@@ -41,16 +43,13 @@ def params():
     return init_params(CFG, jax.random.key(11))
 
 
+_reference = functools.partial(greedy_reference, CFG)
+
+
 def _paged(params, **kw):
     kw.setdefault("max_batch_size", 4)
     kw.setdefault("max_seq_len", 64)
-    return LLMEngine(CFG, params, cache_kind="paged", **kw)
-
-
-def _dense(params, **kw):
-    kw.setdefault("max_batch_size", 4)
-    kw.setdefault("max_seq_len", 64)
-    return LLMEngine(CFG, params, cache_kind="dense", **kw)
+    return LLMEngine(CFG, params, **kw)
 
 
 def _wait(pred, timeout=60):
@@ -214,13 +213,12 @@ def test_allocator_share_misuse_raises_and_is_atomic():
 # --------------------------------------------------------------------------
 # engine: warm-path token identity
 # --------------------------------------------------------------------------
-def test_full_hit_cow_token_identical_to_dense(params):
+def test_full_hit_cow_token_identical_to_generate(params):
     eng = _paged(params, kv_block_size=8)
-    ref = _dense(params)
     try:
         p = list(range(1, 25))  # 24 tokens = 3 full blocks
-        want6 = ref.generate(p, max_tokens=6)
-        want10 = ref.generate(p, max_tokens=10)
+        want6 = _reference(params, p, 6)
+        want10 = _reference(params, p, 10)
         assert eng.generate(p, max_tokens=6) == want6  # cold
         # warm, different generation length: full hit + COW on the tail block
         assert eng.generate(p, max_tokens=10) == want10
@@ -230,17 +228,15 @@ def test_full_hit_cow_token_identical_to_dense(params):
         _assert_no_leak(eng)
     finally:
         eng.shutdown()
-        ref.shutdown()
 
 
-def test_divergent_suffixes_share_prefix_blocks(params):
+def test_divergent_suffixes_share_prefix_blocks_to_generate(params):
     eng = _paged(params, kv_block_size=8)
-    ref = _dense(params)
     try:
         base = list(range(30, 46))  # 16 tokens = 2 full blocks
         p1, p2 = base + [5, 6, 7], base + [8, 9]
-        assert eng.generate(p1, max_tokens=5) == ref.generate(p1, max_tokens=5)
-        assert eng.generate(p2, max_tokens=5) == ref.generate(p2, max_tokens=5)
+        assert eng.generate(p1, max_tokens=5) == _reference(params, p1, 5)
+        assert eng.generate(p2, max_tokens=5) == _reference(params, p2, 5)
         st = eng.stats()
         # p2 reused base's two blocks without COW (its suffix diverges)
         assert st["prefix_cache_hits"] + st["prefix_cache_partial"] >= 1
@@ -248,18 +244,16 @@ def test_divergent_suffixes_share_prefix_blocks(params):
         _assert_no_leak(eng)
     finally:
         eng.shutdown()
-        ref.shutdown()
 
 
 @pytest.mark.parametrize("chunk", [7, 8, 16])
-def test_chunked_prefill_resumes_at_first_uncached_token(params, chunk):
+def test_chunked_prefill_resumes_at_first_uncached_token_to_generate(params, chunk):
     """Chunked prefill x cache hit: the warm run starts prefill mid-prompt
-    (at the first uncached token) and still produces the dense tokens."""
+    (at the first uncached token) and still produces generate()'s tokens."""
     eng = _paged(params, kv_block_size=8, prefill_chunk_tokens=chunk)
-    ref = _dense(params)
     try:
         p = list(range(1, 31))  # 30 tokens
-        want = ref.generate(p, max_tokens=5)
+        want = _reference(params, p, 5)
         assert eng.generate(p, max_tokens=5) == want
         chunks_cold = eng.stats()["prefill_chunks"]
         assert eng.generate(p, max_tokens=5) == want
@@ -270,12 +264,11 @@ def test_chunked_prefill_resumes_at_first_uncached_token(params, chunk):
         # an EXTENDED prompt diverges inside the cached completion's block:
         # a PARTIAL hit that resumes after the shared full blocks
         p2 = p + [60, 61, 62]
-        assert eng.generate(p2, max_tokens=5) == ref.generate(p2, max_tokens=5)
+        assert eng.generate(p2, max_tokens=5) == _reference(params, p2, 5)
         assert eng.stats()["prefix_cache_partial"] >= 1
         _assert_no_leak(eng)
     finally:
         eng.shutdown()
-        ref.shutdown()
 
 
 def test_shared_pages_visible_while_request_live(params):

@@ -2,6 +2,7 @@
 continuous admission (mid-flight joins), slot reuse, eos/max_tokens stops,
 and the Serve deployment wrapper."""
 
+import functools
 import json
 import threading
 import time
@@ -11,7 +12,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.models import TransformerConfig, generate, init_params
+from llm_reference import greedy_reference
+from ray_tpu.models import TransformerConfig, init_params
 from ray_tpu.serve.llm import LLMEngine, LLMServer, _bucket
 
 CFG = TransformerConfig(
@@ -32,11 +34,7 @@ def engine(params):
     eng.shutdown()
 
 
-def _reference(params, prompt, n):
-    """Greedy reference continuation via the one-shot generate()."""
-    p = jnp.asarray([prompt], jnp.int32)
-    out, lens = generate(CFG, params, p, max_new_tokens=n, temperature=0)
-    return np.asarray(out[0, len(prompt): int(lens[0])]).tolist()
+_reference = functools.partial(greedy_reference, CFG)
 
 
 def test_single_request_matches_generate(engine, params):
@@ -291,28 +289,86 @@ def test_http_sse_invalid_request_gets_error_response(params):
         ray_tpu.shutdown()
 
 
-def test_mesh_sharded_engine(params):
-    """Tensor-parallel engine over the virtual device mesh: params shard
-    per the Megatron layout, cache heads over tp, outputs match the
-    single-device engine."""
-    if len(jax.devices()) < 2:
+def _tp_mesh(n):
+    if len(jax.devices()) < n:
         pytest.skip("needs virtual devices")
     from jax.sharding import Mesh
 
-    mesh = Mesh(np.array(jax.devices()[:2]), ("tp",))  # kv_heads=2 -> tp=2 shards kv
-    eng_m = LLMEngine(CFG, params, max_batch_size=2, max_seq_len=64, mesh=mesh)
-    eng_s = LLMEngine(CFG, params, max_batch_size=2, max_seq_len=64)
+    return Mesh(np.array(jax.devices()[:n]), ("tp",))
+
+
+@pytest.mark.parametrize("chunk", [0, 8])
+def test_mesh_sharded_engine(params, chunk):
+    """Tensor-parallel engine over the virtual device mesh: params shard
+    per the Megatron layout, the pool's heads over tp, and the same prefill
+    program runs, one-shot or in chunks shorter than the prompt: outputs
+    match the single-device engine and ``generate()``."""
+    mesh = _tp_mesh(2)  # kv_heads=2 -> tp=2 shards kv
+    kw = dict(max_batch_size=2, max_seq_len=64, prefill_chunk_tokens=chunk)
+    eng_m = LLMEngine(CFG, params, mesh=mesh, **kw)
+    eng_s = LLMEngine(CFG, params, **kw)
     try:
         # params really sharded over tp
         wq_sh = eng_m.params["layers"]["wq"].sharding
         assert wq_sh.spec[2] == "tp"
-        prompts = [[3, 14, 15], [7, 8]]
+        prompts = [[3, 14, 15], [7, 8], list(range(1, 20))]
         m_out = [eng_m.generate(p, max_tokens=6) for p in prompts]
         s_out = [eng_s.generate(p, max_tokens=6) for p in prompts]
-        assert m_out == s_out
+        assert m_out == s_out == [_reference(params, p, 6) for p in prompts]
+        # 19 tokens: one bucketed call, or 8 + 8 + 3
+        assert eng_m.stats()["prefill_chunks"] == eng_s.stats()["prefill_chunks"] == (5 if chunk else 3)
     finally:
         eng_m.shutdown()
         eng_s.shutdown()
+
+
+def test_mesh_engine_serves_a_repeated_prompt_from_the_prefix_cache(params):
+    """Under a mesh the second serve of a prompt shares the first one's
+    pages: no chunk of the prompt is prefilled again (one chunk recomputes
+    its last token, whose logits seed sampling, into a copied page), and the
+    tokens are the first serve's and ``generate()``'s."""
+    eng = LLMEngine(CFG, params, max_batch_size=2, max_seq_len=64, mesh=_tp_mesh(2), prefill_chunk_tokens=8)
+    try:
+        prompt = list(range(1, 33))  # two full pages, four chunks
+        first = eng.generate(prompt, max_tokens=6)
+        cold = eng.stats()
+        assert (cold["prefill_chunks"], cold["prefix_cache_hits"]) == (4, 0)
+        assert eng.generate(prompt, max_tokens=6) == first == _reference(params, prompt, 6)
+        warm = eng.stats()
+        assert warm["prefix_cache_hits"] == 1 and warm["cow_copies"] == 1
+        assert warm["prefill_chunks"] == cold["prefill_chunks"] + 1
+        assert warm["prefix_tokens_reused"] == len(prompt) - 1
+    finally:
+        eng.shutdown()
+
+
+@pytest.mark.parametrize("tp,heads", [(2, 1), (4, 2)])
+def test_mesh_engine_pool_keeps_whole_pages_of_its_heads_on_each_device(params, tp, heads):
+    """tp=2 splits the pool's two KV heads, tp=4 does not divide them and
+    leaves it replicated; either way a device holds every page, and the
+    programs that return the pool (prefill, decode step, copy-on-write)
+    return it laid out as it was."""
+    eng = LLMEngine(CFG, params, max_batch_size=2, max_seq_len=64, mesh=_tp_mesh(tp))
+    try:
+        def shards():
+            return {tuple(sh.data.shape) for kk in ("k", "v") for sh in eng._cache[kk].addressable_shards}
+
+        want = {(CFG.n_layers, eng.kv_num_blocks, eng.kv_block_size, heads * CFG.head_dim)}
+        assert shards() == want
+        placed = eng._cache["k"].sharding
+        prompt = list(range(1, 33))
+        eng.generate(prompt, max_tokens=4)
+        eng.generate(prompt, max_tokens=4)  # full hit: the tail page is copied
+        st = eng.stats()
+        assert st["decode_steps"] >= 6 and st["cow_copies"] == 1
+        assert shards() == want and eng._cache["k"].sharding == placed
+    finally:
+        eng.shutdown()
+
+
+def test_mesh_role_rejected(params):
+    with pytest.raises(ValueError, match="role='decode' with mesh"):
+        LLMEngine(CFG, params, mesh=_tp_mesh(2), role="decode")
 
 
 def test_mesh_engine_kv_replicated_when_indivisible(params):
@@ -355,6 +411,30 @@ def test_mesh_moe_engine(params):
     try:
         out = eng.generate([1, 2, 3], max_tokens=3)
         assert len(out) == 3
+    finally:
+        eng.shutdown()
+
+
+def test_mesh_dropless_expert_engine_returns_counts_beside_the_pool():
+    """A windowed config with a dropless routed layer under a mesh: the
+    programs return the expert counts as one more output behind the pinned
+    pool, and the tokens are ``generate()``'s, cold and from cached pages."""
+    cfg = TransformerConfig(
+        vocab_size=128, d_model=64, n_layers=3, n_heads=4, n_kv_heads=2, head_dim=16, d_ff=128,
+        dtype=jnp.float32, tie_embeddings=False, layer_types=("sliding", "full", "sliding"), sliding_window=16,
+        num_experts=8, expert_top_k=2, num_dense_layers=1, expert_d_ff=32, num_shared_experts=1,
+        router_score="sigmoid", route_scale=2.0, router_bias=True)
+    assert cfg.dropless
+    moe_params = init_params(cfg, jax.random.key(0))
+    eng = LLMEngine(cfg, moe_params, max_batch_size=2, max_seq_len=64, mesh=_tp_mesh(2), prefill_chunk_tokens=8)
+    try:
+        prompt = list(range(1, 33))
+        want = greedy_reference(cfg, moe_params, prompt, 6)
+        assert eng.generate(prompt, max_tokens=6) == want
+        assert eng.generate(prompt, max_tokens=6) == want
+        st = eng.stats()
+        assert st["moe_assignments"] > 0 and st["prefix_cache_hits"] == 1
+        assert eng._cache["k"].sharding.spec[3] == "tp"
     finally:
         eng.shutdown()
 
@@ -730,17 +810,11 @@ def test_prefix_cache_on_by_default_and_disable_knob(params):
 
 def test_tp_engine_with_chunked_decode(params):
     """decode_chunk composes with tensor-parallel serving: the sharded scan
-    program produces the single-device engine's tokens (mesh engines run
-    the dense cache, so prefix reuse does not apply there)."""
-    if len(jax.devices()) < 2:
-        pytest.skip("needs virtual devices")
-    from jax.sharding import Mesh
-
-    mesh = Mesh(np.array(jax.devices()[:2]), ("tp",))
+    program produces ``generate()``'s tokens, cold and from cached pages."""
     eng = LLMEngine(CFG, params, max_batch_size=2, max_seq_len=64,
-                    mesh=mesh, decode_chunk=3)
+                    mesh=_tp_mesh(2), decode_chunk=3)
     try:
-        assert eng.stats()["prefix_cache_enabled"] is False  # dense fallback
+        assert eng.stats()["prefix_cache_enabled"] is True
         prompt = [3, 14, 15, 9, 2]
         want = _reference(params, prompt, 7)
         assert eng.generate(prompt, max_tokens=7) == want

@@ -200,37 +200,54 @@ def _pool_sized_ops(hlo, sizes):
     return found
 
 
+@pytest.mark.parametrize("chips", [1, 4])
 @pytest.mark.parametrize("program", ["decode", "prefill_chunk"])
-def test_engine_paged_programs_update_the_pool_in_place(as_chip, v5e, program):
+def test_engine_paged_programs_update_the_pool_in_place(as_chip, v5e, program, chips):
     """The serve programs with the cache donated, at a pool whose one layer
     is 64 MiB: the compiler needs less scratch than one layer's slice of one
     pool, and no instruction copies, slices out or writes back a layer's or
     a whole pool's worth of elements. (The pool used to be re-laid-out per
     layer and stacked into a second pool: 5.4 GiB of temporaries at the
-    benchmark's size, and more device time than the attention kernel.)"""
+    benchmark's size, and more device time than the attention kernel.)
+
+    On four chips, as ``LLMEngine(mesh=...)`` builds the programs: params per
+    ``shard_params``, the pool split over its KV heads and pinned so on the
+    way out, no Mosaic kernel. Each device then holds a quarter of every
+    layer, and the same holds of its quarter."""
     import dataclasses
 
     from ray_tpu.models import init_params
     from ray_tpu.models.generation import (
-        init_paged_cache, paged_decode_step, paged_forward_with_cache)
+        init_paged_cache, paged_cache_spec, paged_decode_step, paged_forward_with_cache)
+    from ray_tpu.models.transformer import fit_spec, param_specs
 
     # a small vocabulary: what is left among the temporaries is the weights the
     # compiler prefetches into on-chip memory (``S(1)``), ~20 MiB here
     cfg = dataclasses.replace(serving_config(), n_layers=4, vocab_size=4096)
-    one = SingleDeviceSharding(v5e[0])
     B, bs, M, N, C = 8, 16, 64, 4096, 256
-
-    params = _abstract_tree(lambda: init_params(cfg, jax.random.key(0)), one)
-    cache = _abstract_tree(lambda: init_paged_cache(cfg, N, bs), one)
-    layer_elems = int(np.prod(cache["k"].shape[1:]))
+    if chips == 1:
+        rest = pool = SingleDeviceSharding(v5e[0])
+        params = _abstract_tree(lambda: init_params(cfg, jax.random.key(0)), rest)
+        pinned, kernel = None, None
+    else:
+        mesh = Mesh(np.array(v5e), ("tp",))
+        rest, pool = NamedSharding(mesh, P()), NamedSharding(mesh, paged_cache_spec("tp"))
+        params = jax.tree.map(
+            lambda x, spec: jax.ShapeDtypeStruct(
+                x.shape, x.dtype, sharding=NamedSharding(mesh, fit_spec(x.shape, spec, mesh))),
+            jax.eval_shape(lambda: init_params(cfg, jax.random.key(0))), param_specs(cfg, ep="tp"),
+            is_leaf=lambda x: isinstance(x, P))
+        pinned, kernel = (None, pool), False
+    cache = _abstract_tree(lambda: init_paged_cache(cfg, N, bs), pool)
+    layer_elems = int(np.prod(cache["k"].shape[1:])) // chips
     layer_bytes = layer_elems * cache["k"].dtype.itemsize
-    assert layer_bytes == 64 * 2**20
+    assert layer_bytes == 64 * 2**20 // chips
 
     if program == "decode":
         def fn(params, cache, toks, pos, bt):
-            return paged_decode_step(cfg, params, cache, toks, pos, bt)
+            return paged_decode_step(cfg, params, cache, toks, pos, bt, use_decode_kernel=kernel)
 
-        args = _abstract([((B,), I32), ((B,), I32), ((B, M), I32)], one)
+        args = _abstract([((B,), I32), ((B,), I32), ((B, M), I32)], rest)
     else:
         def fn(params, cache, toks, bt, start, length):
             positions = start + jnp.arange(C)[None, :]
@@ -238,10 +255,10 @@ def test_engine_paged_programs_update_the_pool_in_place(as_chip, v5e, program):
             return paged_forward_with_cache(
                 cfg, params, cache, bt, toks, positions, valid=valid, use_decode_kernel=False)
 
-        args = _abstract([((1, C), I32), ((1, M), I32), ((), I32), ((), I32)], one)
-    compiled = jax.jit(fn, donate_argnums=(1,)).trace(params, cache, *args).lower(
+        args = _abstract([((1, C), I32), ((1, M), I32), ((), I32), ((), I32)], rest)
+    compiled = jax.jit(fn, donate_argnums=(1,), out_shardings=pinned).trace(params, cache, *args).lower(
         lowering_platforms=("tpu",)).compile()
-    mem = compiled.memory_analysis()
+    mem = compiled.memory_analysis()  # of one device
     assert mem.alias_size_in_bytes >= 2 * cfg.n_layers * layer_bytes  # both pools donated through
     assert mem.temp_size_in_bytes < layer_bytes, f"{mem.temp_size_in_bytes / 2**20:.1f} MiB of temporaries"
     assert _pool_sized_ops(compiled.as_text(), {layer_elems, cfg.n_layers * layer_elems}) == []
