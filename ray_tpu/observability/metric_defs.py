@@ -435,6 +435,19 @@ LLM_MOE_EXPERTS_HIT = _reg.counter(
     "runs x expert layers x experts it is the share of expert weights "
     "touched per step.",
 )
+LLM_DECODE_STEPS_OVERLAPPED = _reg.counter(
+    "llm_decode_steps_overlapped_total",
+    "Decode steps the LLM engine dispatched while the step before was still "
+    "unread (same unit as stats()'s decode_steps): the host's work between "
+    "two steps then ran beside the device, not between its programs.",
+)
+LLM_DECODE_ROW_STEPS_DISCARDED = _reg.counter(
+    "llm_decode_row_steps_discarded_total",
+    "Row-steps the LLM engine computed and dropped unread: a step is "
+    "dispatched before the one before it is read, so a row that hit EOS or "
+    "whose stream was cancelled is decoded once more (max_tokens finishes "
+    "are known by count and waste nothing).",
+)
 
 # Serving SLO families (request-scope observability): ms-scale boundaries
 # matching observability/sketch.py SERVING_LATENCY_BOUNDS — the coarse
@@ -585,6 +598,8 @@ ALL_METRICS = [
     LLM_PREFIX_EVICTIONS,
     LLM_MOE_ASSIGNMENTS,
     LLM_MOE_EXPERTS_HIT,
+    LLM_DECODE_STEPS_OVERLAPPED,
+    LLM_DECODE_ROW_STEPS_DISCARDED,
     LLM_TTFT,
     LLM_INTER_TOKEN,
     SERVE_REQUEST_PHASE,

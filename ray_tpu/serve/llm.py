@@ -34,6 +34,12 @@ dictated by XLA's static-shape compilation model:
   (vLLM-style iteration-level scheduling); finished ones free their slot
   and pages immediately. Per-request ``max_tokens`` and ``temperature``
   ride as device arrays, so mixed sampling configs share one compiled step.
+- **One decode step in flight.** The loop dispatches step N+1 before it
+  reads step N: the rows' last tokens stay on the device (the program hands
+  them to its own next run), positions, tables and ``max_tokens`` counts the
+  host knows ahead, so everything the host does in an iteration runs beside
+  the device. An EOS or a cancel is seen one step late and costs one
+  dropped row-step (``docs/tpu_design.md``, "Paged KV + chunked prefill").
 
 ``LLMServer`` is the Serve-facing wrapper: a deployment class whose
 replicas each own an engine; requests arrive via handle/HTTP and block on a
@@ -48,7 +54,7 @@ import time
 from collections import deque
 from concurrent.futures import Future
 from dataclasses import dataclass, field
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -107,6 +113,9 @@ class GenRequest:
     # filled by the engine
     slot: int = -1
     generated: List[int] = field(default_factory=list)
+    # decode tokens dispatched for this request, read back or not: a
+    # ``max_tokens`` finish is known by this count, ahead of the device
+    dispatched: int = 0
     # chunked prefill progress: prompt tokens already cached (paged engine)
     prefill_pos: int = 0
     # request-scope observability: the lifecycle trace born at the proxy
@@ -167,6 +176,18 @@ class _TokenStream:
             self.close()
         except Exception:  # noqa: BLE001 — GC teardown must never raise
             pass
+
+
+@dataclass
+class _Flight:
+    """A decode step dispatched and not yet read. ``rows`` are the (slot,
+    request) pairs it decodes for, as they stood at dispatch: by the time
+    the tokens are read a slot may be another request's."""
+
+    out: Any  # device int32[B, K]
+    moe: list  # the expert layers' counts, where the program returns them
+    rows: List[Tuple[int, GenRequest]]
+    overlapped: bool  # dispatched while the step before was still unread
 
 
 def _bucket(n: int, lo: int = 16, cap: Optional[int] = None) -> int:
@@ -354,7 +375,13 @@ class LLMEngine:
 
         # slot state (host-side mirrors of the device arrays)
         self._slots: List[Optional[GenRequest]] = [None] * self.B
-        self._last_tok = np.zeros(self.B, np.int32)
+        # a row's last sampled token lives on the device (``_dev_toks``, what
+        # the last dispatched step returned). ``_join_tok`` carries the tokens
+        # the HOST sampled since the last dispatch (a sequence fresh from
+        # prefill or migration), -1 elsewhere: the decode program takes a
+        # row's token from here where it is >= 0. ``_pos`` is the position
+        # the NEXT dispatch writes: it advances at dispatch, not at readback
+        self._join_tok = np.full(self.B, -1, np.int32)
         self._pos = np.zeros(self.B, np.int32)
         self._temps = np.zeros(self.B, np.float32)
         self._active = np.zeros(self.B, bool)
@@ -372,6 +399,12 @@ class LLMEngine:
         self._held_req: Optional[GenRequest] = None
         self._prefill_chunk_count = 0
         self._decode_step_count = 0
+        # the decode step dispatched and not yet read (``_dispatch`` /
+        # ``_collect``), steps dispatched while the one before was unread,
+        # and rows computed past an EOS or a cancel and dropped unread
+        self._flight: Optional[_Flight] = None
+        self._decode_steps_overlapped = 0
+        self._decode_row_steps_discarded = 0
         # the dropless expert layers' own counters (models/generation.py,
         # ``paged_forward_counted``): the prefill and decode programs return
         # them beside the tokens and the loop adds them up when it reads the
@@ -429,7 +462,9 @@ class LLMEngine:
         # stepping), so the host pays one dispatch/readback round trip per
         # K tokens. One key split per generated token.  The cache is
         # donated: the engine holds the only reference and reassigns, so
-        # XLA updates the pool's buffers in place.
+        # XLA updates the pool's buffers in place.  It also hands back every
+        # row's last token as a device array, which the next run takes as
+        # it is: the loop dispatches that run before it reads this one's.
         K_chunk = self.decode_chunk
 
         @functools.partial(jax.jit, donate_argnums=(1,), out_shardings=pool_among(2))
@@ -449,8 +484,12 @@ class LLMEngine:
             last = jax.lax.dynamic_index_in_dim(logits[0], length - 1, 0, keepdims=False)
             return (last, cache, moe) if moe_counted else (last, cache)
 
-        @functools.partial(jax.jit, donate_argnums=(1,), out_shardings=pool_among(3))
-        def _decode_k_paged(params, cache, toks, pos, temps, key, bt):
+        @functools.partial(jax.jit, donate_argnums=(1,), out_shardings=pool_among(4))
+        def _decode_k_paged(params, cache, toks, join, pos, temps, key, bt):
+            # ``toks``: what the last run of this program returned, never
+            # read by the host in between; ``join`` >= 0 where the host
+            # sampled a row's token itself since then (its first)
+            toks = jnp.where(join >= 0, join, toks)
             # a live row's first page is never the garbage page 0 (idle
             # rows decode through all-zero tables): the expert layers
             # count the live rows' assignments only
@@ -466,10 +505,10 @@ class LLMEngine:
                 nxt = _sample_impl(sub, logits[:, 0], temps)
                 return (cache, nxt, pos + 1, key), (nxt, moe)
 
-            (cache, _, _, key), (toks_k, moe) = jax.lax.scan(
+            (cache, last, _, key), (toks_k, moe) = jax.lax.scan(
                 body, (cache, toks, pos, key), None, length=K_chunk
             )
-            out = (jnp.swapaxes(toks_k, 0, 1), cache, key)  # [B, K]
+            out = (jnp.swapaxes(toks_k, 0, 1), cache, key, last)  # [B, K] ... [B]
             if moe_counted:
                 out += (jax.tree.map(lambda a: a.sum(0), moe),)  # over the K steps
             return out
@@ -808,6 +847,8 @@ class LLMEngine:
                 "prefix_evictions": self._prefix.evictions if self._prefix is not None else 0,
                 "cow_copies": self._cow_count,
                 "decode_steps": self._decode_step_count,
+                "decode_steps_overlapped": self._decode_steps_overlapped,
+                "decode_row_steps_discarded": self._decode_row_steps_discarded,
                 "kv_read_share": self.kv_read_share(),
                 "kv_live_pages": self.kv_live_pages(),
                 **self._moe_stats_locked(),
@@ -841,7 +882,7 @@ class LLMEngine:
         toks = jax.ShapeDtypeStruct((self.B,), jnp.int32)
         temps = jax.ShapeDtypeStruct((self.B,), jnp.float32)
         bt = jax.ShapeDtypeStruct(self._block_tables.shape, jnp.int32)
-        return self._decode_k_paged.lower(params, cache, toks, toks, temps, self._key, bt).as_text()
+        return self._decode_k_paged.lower(params, cache, toks, toks, toks, temps, self._key, bt).as_text()
 
     def admission_snapshot(self) -> Dict[str, Any]:
         """Bounds + depths for GET /api/overload (admission source)."""
@@ -987,7 +1028,7 @@ class LLMEngine:
         the stall on each stalled request's trace (the decoding requests
         experience the bubble, not the prefilling one)."""
         # rt-lint: disable=lock-discipline -- engine-thread-owned: _slots
-        # mutations all run on this same engine loop thread (see _step)
+        # mutations all run on this same engine loop thread (see _dispatch)
         for r in self._slots:
             if r is not None and r.trace is not None:
                 r.trace.note_stall()
@@ -1090,7 +1131,7 @@ class LLMEngine:
         written position is ``prompt + max_tokens - 2``), so an admitted
         request can never hit a mid-decode pool OOM and nothing is ever
         preempted. Prefill itself runs later, chunk by chunk, from
-        ``_prefill_tick`` so decode steps interleave with long prompts.
+        ``_prefill_enqueue`` so decode steps interleave with long prompts.
 
         With the prefix cache, the longest cached prefix of the prompt is
         ``share()``d straight into the block table (zero prefill compute for
@@ -1223,7 +1264,7 @@ class LLMEngine:
             self._slots[slot] = req
             self._active[slot] = True
             self._reserved[slot] = False
-            self._last_tok[slot] = tok0
+            self._join_tok[slot] = tok0
             self._pos[slot] = tp
             self._temps[slot] = req.temperature
         self._maybe_finish(req, tok0)
@@ -1295,7 +1336,7 @@ class LLMEngine:
             self._slots[slot] = req
             self._active[slot] = True
             self._reserved[slot] = False
-            self._last_tok[slot] = tok0
+            self._join_tok[slot] = tok0
             self._pos[slot] = tp
             self._temps[slot] = req.temperature
             self.num_migrations_in += 1
@@ -1395,7 +1436,17 @@ class LLMEngine:
     def _release_blocks_locked(self, slot: int) -> None:
         """Drop a slot's page references (a request holds exactly ONE per
         block-table entry, shared or not, so every release path — finish,
-        shed, evict, crash — is this same free). Caller holds ``self._lock``."""
+        shed, evict, crash — is this same free). Caller holds ``self._lock``.
+
+        A decode step may still be in flight for this slot (an EOS is read
+        one step late, a cancel whenever it comes). Freeing under it is
+        sound: that step writes the row's K/V at positions >= ``pos``, in
+        pages only this request could write (``_cow_shared_writes``) and
+        that ``_retire_blocks_locked`` never publishes; whoever is given the
+        pages next enqueues its writes later, the device runs programs in
+        the order they were enqueued, and no one reads a position of its
+        page before writing it. The row's tokens are dropped at
+        ``_collect`` by the request's identity, never through the slot."""
         blocks = self._slot_blocks[slot]
         self._slot_blocks[slot] = []
         self._block_tables[slot, :] = 0
@@ -1420,7 +1471,9 @@ class LLMEngine:
             self._allocator.free(blocks)
             return 0
         # the last sampled token was never written back to the KV cache;
-        # every token before it was — cache exactly those full blocks
+        # every token before it was — cache exactly those full blocks. (A
+        # step in flight past an EOS writes position len(cached) and up:
+        # in no full block of ``cached``, so never in a published page)
         cached = req.prompt + req.generated[:-1]
         adopted, evicted = self._prefix.insert(cached, blocks, self._evictable)
         if evicted:
@@ -1463,9 +1516,10 @@ class LLMEngine:
                 self._allocator.free([old])
                 self._cow_count += 1
 
-    def _prefill_tick(self) -> bool:
-        """Advance the head prefilling request by one chunk. Returns True if
-        any device work ran (the loop then skips its idle wait).
+    def _prefill_enqueue(self):
+        """Enqueue one chunk of the head prefilling request and return what
+        ``_prefill_finish`` needs, without waiting for it; None if nothing
+        is prefilling (or the chunk failed: the request is failed here).
 
         With ``prefill_chunk_tokens > 0`` every chunk is the same fixed
         width, so a single compiled program serves all prompts and a decode
@@ -1487,7 +1541,7 @@ class LLMEngine:
                 if req.stream_queue is not None:
                     req.stream_queue.put(_STREAM_END)
             if not self._prefilling:
-                return False
+                return None
             req = self._prefilling[0]
             gauges = self._pool_gauges_locked()
         self._publish_pool_gauges(*gauges)
@@ -1507,18 +1561,32 @@ class LLMEngine:
             # shared page (the full-hit tail is COW'd eagerly), but writes
             # must still never land on refcount > 1 pages
             self._cow_shared_writes(req.slot, start, n)
-            bt = jnp.asarray(self._block_tables[req.slot : req.slot + 1])
+            # a copy of the row: the transfer may read (or alias) the host
+            # buffer after the call returns, and the mirror is rewritten at
+            # will before the chunk has been waited for
+            bt = jnp.asarray(self._block_tables[req.slot : req.slot + 1].copy())
             logits, self._cache, *moe = self._prefill_chunk(
                 self.params, self._cache, jnp.asarray(toks), bt,
                 jnp.int32(start), jnp.int32(n),
             )
+        except BaseException as exc:  # noqa: BLE001
+            with self._lock:
+                self._prefilling.pop(0)
+            self._fail_admit(req, exc)
+            return None
+        return req, n, logits, moe, stalled, t0
+
+    def _prefill_finish(self, req: GenRequest, n: int, logits, moe, stalled: bool, t0: float) -> None:
+        """Wait for the chunk ``_prefill_enqueue`` started and, if it was the
+        prompt's last, sample the first token and join the decode batch."""
+        try:
             jax.block_until_ready(logits)
             self._note_moe(moe, decode=False)
         except BaseException as exc:  # noqa: BLE001
             with self._lock:
                 self._prefilling.pop(0)
             self._fail_admit(req, exc)
-            return True
+            return
         if stalled:
             # decode slots sat idle while this chunk ran; chunking bounds it
             metric_defs.LLM_DECODE_STALL.observe(time.perf_counter() - t0)
@@ -1528,9 +1596,9 @@ class LLMEngine:
             req.trace.note_prefill_chunk()
         with self._lock:
             self._prefill_chunk_count += 1
-        req.prefill_pos = start + n
-        if req.prefill_pos < tp:
-            return True
+        req.prefill_pos += n
+        if req.prefill_pos < len(req.prompt):
+            return
         with self._lock:
             self._prefilling.pop(0)
             self._prefill_count += 1
@@ -1538,7 +1606,6 @@ class LLMEngine:
             self._finish_prefill(req, logits)
         except BaseException as exc:  # noqa: BLE001
             self._fail_admit(req, exc)
-        return True
 
     def _note_moe(self, moe, *, decode: bool) -> None:
         """Add one program run's expert-layer counts (``moe``: empty unless
@@ -1603,40 +1670,75 @@ class LLMEngine:
                 req.stream_queue.put(_STREAM_END)
         return done
 
-    def _step(self) -> None:
-        toks = jnp.asarray(self._last_tok)
-        pos = jnp.asarray(self._pos)
-        # copy-on-write net: a decode chunk writes positions
-        # [pos, pos + K) — if any of those blocks still maps to a
-        # shared page, give the slot its own copy before stepping
-        for i in range(self.B):
-            if self._active[i]:
-                self._cow_shared_writes(i, int(self._pos[i]), self.decode_chunk)
-        # inactive rows decode through all-zero tables -> garbage page 0,
-        # so freed pages are never written after release
-        bt = jnp.asarray(self._block_tables * self._active[:, None].astype(np.int32))
-        out, self._cache, self._key, *moe = self._decode_k_paged(
-            self.params, self._cache, toks, pos,
-            jnp.asarray(self._temps), self._key, bt,
+    def _dispatch(self) -> Optional[_Flight]:
+        """Enqueue one decode step (``decode_chunk`` tokens a row) for every
+        row still owed a token and return its handle unread; None if there is
+        no such row. The host knows everything the step needs ahead of the
+        device but the rows' last tokens, and those the program takes from
+        its own previous run (``_dev_toks``) or, for a row that joined since,
+        from ``_join_tok``. Positions and the ``max_tokens`` count advance
+        here, so the next step can be dispatched before this one is read."""
+        K = self.decode_chunk
+        rows: List[Tuple[int, GenRequest]] = []
+        live = np.zeros(self.B, bool)
+        # rt-lint: disable=lock-discipline -- engine-thread-owned: every
+        # _slots mutation (admit/finish/evict/fail_inflight) runs on this
+        # same engine loop thread; _lock exists for cross-thread READERS
+        # (stats, abandon flags), not for us
+        for i, req in enumerate(self._slots):
+            if req is None or req.dispatched >= req.max_tokens - 1:
+                continue  # free, or its last tokens are in flight: known by count
+            # copy-on-write net: the step writes positions [pos, pos + K) —
+            # if any of those blocks still maps to a shared page, give the
+            # slot its own copy before stepping
+            self._cow_shared_writes(i, int(self._pos[i]), K)
+            rows.append((i, req))
+            live[i] = True
+        if not rows:
+            return None
+        # rows not in this step decode through all-zero tables -> garbage
+        # page 0, so freed pages are never written after release. The device
+        # gets copies of the mirrors: a transfer may read (or alias) its host
+        # buffer after the call returns, and the mirrors change right below
+        bt = jnp.asarray(self._block_tables * live[:, None].astype(np.int32))
+        out, self._cache, self._key, self._dev_toks, *moe = self._decode_k_paged(
+            self.params, self._cache, self._dev_toks, jnp.asarray(self._join_tok.copy()),
+            jnp.asarray(self._pos.copy()), jnp.asarray(self._temps.copy()), self._key, bt,
         )
-        sampled = np.asarray(out)  # [B, K]
-        self._decode_step_count += sampled.shape[1]
-        self._note_moe(moe, decode=True)
-        for k in range(sampled.shape[1]):
-            for i in range(self.B):
-                # rt-lint: disable=lock-discipline -- engine-thread-owned:
-                # every _slots mutation (admit/finish/evict/fail_inflight)
-                # runs on this same engine loop thread; _lock exists for
-                # cross-thread READERS (stats, abandon flags), not for us
-                req = self._slots[i]
-                if req is None:
-                    continue  # free, or finished earlier in this chunk
+        self._join_tok[:] = -1
+        for i, req in rows:
+            req.dispatched += K
+            self._pos[i] += K
+        return _Flight(out, moe, rows, overlapped=self._flight is not None)
+
+    def _collect(self, flight: _Flight) -> None:
+        """Read a dispatched step's tokens (this waits for that step only,
+        not for one dispatched after it) and emit them. A row whose request
+        left its slot since the dispatch (an EOS read one step late, a
+        cancelled stream evicted) is dropped whole, by identity: the slot
+        may be another request's by now."""
+        sampled = np.asarray(flight.out)  # [B, K]
+        K = sampled.shape[1]
+        self._decode_step_count += K
+        if flight.overlapped:
+            self._decode_steps_overlapped += K
+            metric_defs.LLM_DECODE_STEPS_OVERLAPPED.inc(K)
+        self._note_moe(flight.moe, decode=True)
+        # rt-lint: disable=lock-discipline -- engine-thread-owned (see _dispatch)
+        rows = [(i, req) for i, req in flight.rows if self._slots[i] is req and not req.cancelled]
+        dropped = len(flight.rows) - len(rows)
+        if dropped:
+            self._decode_row_steps_discarded += dropped * K
+            metric_defs.LLM_DECODE_ROW_STEPS_DISCARDED.inc(dropped * K)
+        for k in range(K):
+            for i, req in rows:
+                # rt-lint: disable=lock-discipline -- engine-thread-owned (see _dispatch)
+                if self._slots[i] is not req:
+                    continue  # finished earlier in this chunk
                 tok = int(sampled[i, k])
                 req.generated.append(tok)
                 self._note_next_token(req)
                 req.emit(tok)
-                self._pos[i] += 1
-                self._last_tok[i] = tok
                 self._maybe_finish(req, tok)
 
     def _reset_cache(self) -> None:
@@ -1647,6 +1749,10 @@ class LLMEngine:
             # each device zeroes its own shard: the whole pool never lies on one
             init = jax.jit(init, out_shardings=self._kv_sharding)
         self._cache = init()
+        # the rows' last tokens as the decode program last returned them:
+        # part of the same device state (a step that failed in flight leaves
+        # its outputs poisoned). No row reads it before joining from the host
+        self._dev_toks = jnp.zeros(self.B, jnp.int32)
 
     def _fail_inflight(self, error: BaseException) -> None:
         """Fail every queued, prefilling, and in-slot request (loop-crash
@@ -1671,6 +1777,10 @@ class LLMEngine:
                 stale = self._prefix.drain()
                 if stale:
                     self._allocator.free(stale)
+        # the step in flight goes with them: its rows' requests are victims
+        # (engine-thread state, like the loop that dispatched it)
+        self._flight = None
+        self._join_tok[:] = -1
         metric_defs.ADMISSION_QUEUE_DEPTH.set(0, self._depth_tags)
         self._publish_pool_gauges(0, 0, 0)
         for r in victims:
@@ -1709,12 +1819,20 @@ class LLMEngine:
     def _loop(self) -> None:
         while not self._stop:
             try:
+                # one decode step stays in flight: the next is dispatched
+                # before the last one's tokens are read, so everything the
+                # host does in an iteration runs beside the device. A chunk
+                # goes into the device's queue ahead of that next step, and
+                # is waited for only after the step in flight was read
                 self._evict_cancelled()
                 self._admit()
-                progressed = self._prefill_tick()
-                if self._active.any():
-                    self._step()
-                elif not progressed:
+                chunk = self._prefill_enqueue()
+                prev, self._flight = self._flight, self._dispatch()
+                if prev is not None:
+                    self._collect(prev)
+                if chunk is not None:
+                    self._prefill_finish(*chunk)
+                elif prev is None and self._flight is None:
                     self._wake.wait(timeout=0.05)
                     self._wake.clear()
             except BaseException as exc:  # noqa: BLE001 — a dead loop hangs every caller

@@ -588,20 +588,101 @@ def test_blocks_released_on_prefill_crash(params):
         eng.shutdown()
 
 
-def test_blocks_released_on_loop_crash(params):
+class _Poisoned:
+    """A step's tokens that fail when the host reads them."""
+
+    def __array__(self, *a, **k):
+        raise RuntimeError("injected decode fault")
+
+
+@pytest.mark.parametrize("fault", ["dispatch", "dispatch_with_a_step_in_flight", "collect"])
+def test_blocks_released_on_loop_crash(params, fault):
+    """A decode step that fails where it is dispatched (the first, or one
+    dispatched while the step before is unread) or where it is read: the
+    engine fails every request it holds, drops the step in flight with them,
+    resets the pool and serves the next request."""
     eng = _paged(params)
     try:
-        real = eng._decode_k_paged
-        eng._decode_k_paged = lambda *a, **k: (_ for _ in ()).throw(
-            RuntimeError("injected decode fault")
-        )
-        fut = eng.submit([1, 2, 3], max_tokens=8)
-        with pytest.raises(RuntimeError):
-            fut.result(timeout=120)
+        real, calls = eng._decode_k_paged, []
+
+        def program(*a, **k):
+            calls.append(1)
+            if fault == "dispatch" or (fault == "dispatch_with_a_step_in_flight" and len(calls) >= 2):
+                raise RuntimeError("injected decode fault")
+            out = real(*a, **k)
+            return (_Poisoned(), *out[1:]) if fault == "collect" else out
+
+        eng._decode_k_paged = program
+        futs = [eng.submit([1, 2, 3], max_tokens=8), eng.submit([9, 8, 7, 6], max_tokens=8)]
+        for fut in futs:
+            with pytest.raises(RuntimeError):
+                fut.result(timeout=120)
         _wait(lambda: eng.stats()["kv_blocks_in_use"] == 0)
+        assert eng._flight is None and eng.stats()["active_slots"] == 0
         eng._decode_k_paged = real
         # _fail_inflight + _reset_cache recovered the engine
-        assert len(eng.generate([1, 2, 3], max_tokens=3)) == 3
+        assert eng.generate([1, 2, 3], max_tokens=6) == _reference(params, [1, 2, 3], 6)
+        _assert_no_leak(eng)
+    finally:
+        eng.shutdown()
+
+
+@pytest.mark.parametrize("K", [1, 4])
+def test_eos_read_one_step_late_drops_the_row_and_frees_its_pages(params, K):
+    """The step after an EOS is on the device before the host reads the
+    EOS: its row is dropped whole and counted, exactly the tokens up to the
+    EOS are returned, the pool goes back to its baseline, and both a later
+    request over the finished one's prefix and one that is given its freed
+    pages (the pool holds no others) get the reference's tokens."""
+    from llm_reference import late_eos_case
+
+    prompt, out, j = late_eos_case(CFG, params)
+    # 3 + 40 - 1 positions in pages of 4: 11 pages, and the pool has exactly 11
+    eng = _paged(params, max_batch_size=2, decode_chunk=K, kv_block_size=4, kv_num_blocks=12)
+    try:
+        assert eng.kv_free_blocks() == 11
+        assert eng.generate(prompt, max_tokens=40, eos_id=out[j]) == out[: j + 1]
+        # the future resolves where the EOS is read; the step behind it is read next
+        _wait(lambda: eng._flight is None and eng.stats()["decode_row_steps_discarded"] > 0)
+        st = eng.stats()
+        assert st["decode_row_steps_discarded"] == K  # one dispatch past the EOS, none past that
+        assert st["active_slots"] == 0 and st["kv_blocks_shared"] == 0
+        # what is held is the cache's: the full pages of prompt + generated[:-1], once each
+        assert st["kv_blocks_in_use"] == st["prefix_cache_blocks"] == (len(prompt) + j) // 4
+        longer = prompt + out[:j] + [11, 12, 13]
+        assert eng.generate(longer, max_tokens=6) == _reference(params, longer, 6)
+        assert eng.stats()["prefix_tokens_reused"] >= 4 * ((len(prompt) + j) // 4)
+        # a request that needs every page of the pool, the dropped step's targets among them
+        other = [31, 32, 33]
+        assert eng.generate(other, max_tokens=40) == _reference(params, other, 40)
+        _assert_no_leak(eng)
+        assert eng.kv_free_blocks() == 11
+    finally:
+        eng.shutdown()
+
+
+def test_stream_cancelled_with_a_step_in_flight(params):
+    """A consumer that goes away mid-decode: the step in flight for its row
+    is dropped by the request's identity (the slot may be another's by
+    then), nothing more is emitted, the pages return, and the slot's next
+    owner gets the reference's tokens."""
+    eng = _paged(params, max_batch_size=1, max_seq_len=1024)
+    try:
+        want, nxt = _reference(params, [4, 2], 3), [9, 3, 7]
+        want_nxt = _reference(params, nxt, 6)
+        stream = eng.submit_stream([4, 2], max_tokens=1000)  # far from done when the consumer leaves
+        assert [next(stream) for _ in range(3)] == want
+        req = stream._req
+        stream.close()
+        _wait(lambda: eng.stats()["active_slots"] == 0)
+        _wait(lambda: eng.stats()["kv_blocks_in_use"] == 0)
+        _wait(lambda: eng._flight is None)
+        st = eng.stats()
+        assert st["decode_row_steps_discarded"] >= 1 and st["slots_evicted"] == 1
+        emitted = len(req.generated)
+        assert 3 <= emitted < 1000
+        assert eng.generate(nxt, max_tokens=6) == want_nxt
+        assert len(req.generated) == emitted and not req.stream_queue.qsize() > emitted
         _assert_no_leak(eng)
     finally:
         eng.shutdown()

@@ -294,6 +294,57 @@ def test_shared_pages_visible_while_request_live(params):
         eng.shutdown()
 
 
+@pytest.mark.parametrize("K", [1, 4])
+def test_full_hit_tail_page_copied_while_a_step_is_in_flight(params, K):
+    """A full-prompt hit copies its tail page at admission. With another
+    request decoding, that copy is enqueued behind a step that has not been
+    read yet: the device runs it in order, and both requests get the
+    reference's tokens."""
+    eng = _paged(params, kv_block_size=8, max_seq_len=512, decode_chunk=K)
+    try:
+        p, long = list(range(1, 25)), [70, 71, 72]  # p: 3 full blocks
+        want4, want10, want_long = _reference(params, p, 4), _reference(params, p, 10), _reference(params, long, 400)
+        assert eng.generate(p, max_tokens=4) == want4  # cold: p's blocks are cached
+        fut = eng.submit(long, max_tokens=400)
+        _wait(lambda: eng.stats()["decode_steps_overlapped"] > 0)
+        cow0 = eng.stats()["cow_copies"]
+        assert eng.generate(p, max_tokens=10) == want10  # full hit, tail block copied on write
+        assert not fut.done(), "the long request was to be decoding meanwhile"
+        assert eng.stats()["cow_copies"] == cow0 + 1
+        assert fut.result(timeout=120) == want_long
+        assert eng.stats()["decode_row_steps_discarded"] == 0
+        _assert_no_leak(eng)
+    finally:
+        eng.shutdown()
+
+
+def test_decode_write_to_a_page_shared_mid_flight_is_copied_first(params):
+    """The copy-on-write net under the loop's new order: pages a live row
+    has yet to write become shared while its step is in flight. That step
+    (dispatched when the page was the row's own) writes the old page; the
+    next dispatch copies it, the write included, before it writes on: the
+    tokens are the reference's and the shared pages are never written."""
+    eng = _paged(params, kv_block_size=8, max_seq_len=512)
+    try:
+        prompt = [70, 71, 72]
+        want = _reference(params, prompt, 300)
+        fut = eng.submit(prompt, max_tokens=300)
+        _wait(lambda: eng.stats()["decode_steps_overlapped"] > 2)
+        with eng._lock:  # another reader appears for every page the row holds
+            slot = next(i for i, r in enumerate(eng._slots) if r is not None)
+            pages = [int(b) for b in eng._block_tables[slot] if b > 0]
+            eng._allocator.share(pages)
+        assert fut.result(timeout=120) == want
+        st = eng.stats()
+        # the tail page of that moment and every page after it were copied; full ones are only read
+        assert 1 <= st["cow_copies"] <= len(pages)
+        with eng._lock:
+            eng._allocator.free(pages)  # the other reader goes
+        _assert_no_leak(eng)
+    finally:
+        eng.shutdown()
+
+
 # --------------------------------------------------------------------------
 # engine: release paths under active sharing
 # --------------------------------------------------------------------------

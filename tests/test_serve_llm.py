@@ -875,3 +875,89 @@ def test_openai_top_p_allowed_when_engine_configured(params):
             srv({"model": "m", "prompt": [1, 2], "max_tokens": 2, "top_p": 0.2})
     finally:
         srv.engine.shutdown()
+
+
+# --------------------------------------------------------------------------
+# one decode step in flight: step N+1 is dispatched before step N is read
+# --------------------------------------------------------------------------
+@pytest.mark.parametrize("K", [1, 4])
+def test_staggered_joins_and_finishes_match_reference(params, K):
+    """Seven requests through three slots, joining while steps are in flight
+    and finishing at different ``max_tokens`` (one at its first token, some
+    mid-chunk): every one gets the reference's tokens, and since a
+    ``max_tokens`` finish is known by count no row-step is computed in vain."""
+    eng = LLMEngine(CFG, params, max_batch_size=3, max_seq_len=64, decode_chunk=K)
+    try:
+        script = [([5, 6], 19, 0.0), ([7, 8, 9, 10, 11], 3, 0.02), ([1] * 17, 12, 0.0), ([42], 1, 0.03),
+                  ([13, 12, 11], 7, 0.0), ([9, 9, 2], 2, 0.02), ([3, 14, 15], 25, 0.0)]
+        futs = []
+        for prompt, n, pause in script:
+            time.sleep(pause)
+            futs.append(eng.submit(prompt, max_tokens=n))
+        for (prompt, n, _), fut in zip(script, futs):
+            assert fut.result(timeout=120) == _reference(params, prompt, n)
+        st = eng.stats()
+        assert st["decode_row_steps_discarded"] == 0
+        assert 0 < st["decode_steps_overlapped"] <= st["decode_steps"]
+        assert st["active_slots"] == 0
+    finally:
+        eng.shutdown()
+
+
+@pytest.mark.parametrize("K", [1, 4])
+def test_overlap_counters_count_steps_dispatched_before_the_last_was_read(params, K):
+    eng = LLMEngine(CFG, params, max_batch_size=4, max_seq_len=128, decode_chunk=K)
+    try:
+        def counters():
+            st = eng.stats()
+            return st["decode_steps"], st["decode_steps_overlapped"], st["decode_row_steps_discarded"]
+
+        assert eng.generate([5, 6], max_tokens=1) == _reference(params, [5, 6], 1)
+        assert counters() == (0, 0, 0)  # a one-token request never decodes
+        assert eng.generate([5, 6], max_tokens=2) == _reference(params, [5, 6], 2)
+        assert counters() == (K, 0, 0)  # one dispatch, into an empty device, and known to be the last
+        prompts = [[2, 3, 4], [7, 8, 9, 10, 11], [40, 41]]
+        futs = [eng.submit(p, max_tokens=100) for p in prompts]
+        for p, fut in zip(prompts, futs):
+            assert fut.result(timeout=120) == _reference(params, p, 100)
+        steps, overlapped, discarded = counters()
+        assert steps >= K + 99 and discarded == 0
+        assert overlapped / steps > 0.9  # a long steady batch: all but the first step of it
+    finally:
+        eng.shutdown()
+
+
+def test_an_eos_is_read_one_step_late_and_nothing_follows_it(params):
+    """Streaming: the tokens up to the EOS arrive, then the end mark; the
+    row-step computed past it is counted and dropped, not emitted."""
+    from llm_reference import late_eos_case
+
+    prompt, out, j = late_eos_case(CFG, params)
+    eng = LLMEngine(CFG, params, max_batch_size=2, max_seq_len=64)
+    try:
+        assert list(eng.submit_stream(prompt, max_tokens=40, eos_id=out[j])) == out[: j + 1]
+        # the stream ends where the EOS is read; the step behind it is read next
+        deadline = time.time() + 60
+        while eng.stats()["decode_row_steps_discarded"] == 0 and time.time() < deadline:
+            time.sleep(0.005)
+        st = eng.stats()
+        assert st["decode_row_steps_discarded"] == 1 and st["active_slots"] == 0
+        assert st["decode_steps"] == j + 1  # j tokens read and one step dropped
+        # the freed slot's next owner is not handed the dropped row
+        assert eng.generate(prompt, max_tokens=j + 3) == out[: j + 3]
+    finally:
+        eng.shutdown()
+
+
+def test_lowered_decode_text_is_the_program_the_loop_dispatches(engine):
+    """What ``chip_smoke.py`` reads the attention path from: the decode
+    program with its eight arguments, returning the ``[B, K]`` tokens for the
+    host and the ``[B]`` last tokens that stay on the device."""
+    import re
+
+    text = engine.lowered_decode_text()
+    main = re.search(r"func\.func public @main\((.*?)\) -> \((.*?)\) \{", text, re.S)
+    assert main is not None
+    assert "tensor<4x1xi32>" in main.group(2) and "tensor<4xi32>" in main.group(2)
+    # toks, join, pos are three int32[B] arguments; temps a float32[B]
+    assert main.group(1).count("tensor<4xi32>") == 3 and main.group(1).count("tensor<4xf32>") == 1

@@ -170,6 +170,54 @@ def test_engine_paged_decode_program_compiles_for_v5e(as_chip, v5e):
     _lower_for_tpu(_engine_decode_step(cfg), args).compile()
 
 
+def _engine_program_args(eng, sharding=None):
+    """Abstract arguments of ``LLMEngine._decode_k_paged`` as the loop passes
+    them: params, the pool, the rows' last tokens as the program's previous
+    run left them on the device, the tokens the host sampled since (-1: none),
+    positions, temperatures, the key and the masked block tables."""
+    def abstract(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
+
+    params, cache, key = jax.tree.map(abstract, (eng.params, eng._cache, eng._key))
+    toks, temps, bt = _abstract([((eng.B,), I32), ((eng.B,), F32), (eng._block_tables.shape, I32)], sharding)
+    return params, cache, toks, toks, toks, temps, key, bt
+
+
+@pytest.fixture
+def small_engine(as_chip):
+    import dataclasses
+
+    from ray_tpu.models import init_params
+    from ray_tpu.serve.llm import LLMEngine
+
+    cfg = dataclasses.replace(serving_config(), n_layers=2, vocab_size=512)
+    eng = LLMEngine(cfg, init_params(cfg, jax.random.key(0)), max_batch_size=8, max_seq_len=256, decode_chunk=2)
+    yield eng
+    eng.shutdown()
+
+
+def test_the_engines_own_decode_program_keeps_its_tokens_on_the_device(small_engine):
+    """The program the loop dispatches, not a stand-in: it takes its own
+    previous tokens and the host's joins, and hands back ``[B, K]`` tokens
+    for the host beside the pool, the key and the ``[B]`` last tokens that
+    the next run takes unread. Lowered for the chip, the kernel is in it."""
+    eng = small_engine
+    traced = eng._decode_k_paged.trace(*_engine_program_args(eng))
+    out = traced.out_info
+    assert [(o.shape, o.dtype) for o in (out[0], out[2], out[3])] == [
+        ((8, 2), I32), ((), eng._key.dtype), ((8,), I32)]
+    assert jax.tree.structure(out[1]) == jax.tree.structure(eng._cache) and len(out) == 4
+    assert "tpu_custom_call" in traced.lower(lowering_platforms=("tpu",)).as_text()
+
+
+def test_the_engines_own_decode_program_compiles_for_v5e(small_engine, v5e):
+    eng = small_engine
+    args = _engine_program_args(eng, SingleDeviceSharding(v5e[0]))
+    compiled = eng._decode_k_paged.trace(*args).lower(lowering_platforms=("tpu",)).compile()
+    pool_bytes = sum(int(np.prod(x.shape)) * x.dtype.itemsize for x in jax.tree.leaves(eng._cache))
+    assert compiled.memory_analysis().alias_size_in_bytes >= pool_bytes  # donated through, as before
+
+
 def _pallas_grids(jaxpr):
     """The grid of every ``pallas_call`` in ``jaxpr``, scans and calls walked."""
     for eqn in jaxpr.eqns:
