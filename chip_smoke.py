@@ -36,6 +36,7 @@ from __future__ import annotations
 
 import argparse
 import faulthandler
+import functools
 import gc
 import importlib.metadata
 import json
@@ -58,6 +59,14 @@ FULL = dict(
     prefill=dict(H=16, D=64, widths=(16, 32, 64, 128, 256, 512, 1024)),
     decode=dict(B=8, H=16, Hkv=8, D=64, S=1024),
     paged=dict(B=8, H=16, Hkv=8, D=64, M=64, bs=16),
+    # (name, H, Hkv, D, chunk width, start, real tokens, window, table pages): the
+    # two served families' head shapes at their cells' chunk and capacity
+    paged_prefill=(
+        ("smollm2", 32, 32, 64, 512, 1536, 512, None, 256),
+        ("smollm2_tail", 32, 32, 64, 512, 3584, 77, None, 256),
+        ("trinity_full", 32, 4, 128, 512, 3000, 512, 0, 512),
+        ("trinity_sliding", 32, 4, 128, 512, 3000, 500, 2048, 512),
+    ),
     serve=dict(slots=8, seq=1024, requests=16, prompt=64, new=32),
     train=dict(steps=4),
     ring=dict(batch=4, steps=3),
@@ -68,6 +77,7 @@ REHEARSAL = dict(
     prefill=dict(H=2, D=64, widths=(16, 32)),
     decode=dict(B=2, H=4, Hkv=2, D=64, S=128),
     paged=dict(B=2, H=4, Hkv=2, D=64, M=4, bs=16),
+    paged_prefill=(("mha", 2, 2, 64, 32, 48, 20, None, 8), ("gqa_sliding", 8, 1, 128, 32, 80, 32, 40, 8)),
     serve=dict(slots=4, seq=128, requests=8, prompt=32, new=8),
     train=dict(steps=4),
     ring=dict(batch=2, steps=3),
@@ -162,6 +172,7 @@ def leg_kernels(sz, on_chip):
         _paged_decode_xla,
         decode_attention,
         paged_decode_attention,
+        paged_prefill_attention,
     )
 
     errs = {}
@@ -251,6 +262,30 @@ def leg_kernels(sz, on_chip):
     ref = ref_decode(q, kp, vp, bt, lengths, layer)
     _check("decode_paged", out[1:], ref[1:], FWD_REL_TOL, errs)
     assert not bool(jnp.any(out[0])), "decode_paged: an empty row must be zeros"
+
+    # paged prefill kernel: a chunk of queries at a start over a shuffled
+    # table, against the XLA lines on the same pool. At the serving model's
+    # head shape every bucket width the engine produces, in mid-sequence with
+    # a padded tail; then the served families' shapes at their chunk width
+    def prefill(use_kernel, q, kp, vp, bt, start, length, layer, window):
+        return paged_prefill_attention(q, kp, vp, bt, start, length, layer, window=window, use_kernel=use_kernel)
+
+    def prefill_ref(*args):
+        with jax.default_matmul_precision("highest"):
+            return prefill(False, *args)
+
+    prefill_kernel, prefill_xla = jax.jit(functools.partial(prefill, True)), jax.jit(prefill_ref)
+    cases = [(f"T{T}", H, Hkv, D, T, 3 * bs + T // 2, max(1, T - 3), None, M * B) for T in p["widths"]
+             if 3 * bs + T // 2 + T <= M * B * bs]
+    for name, H, Hkv, D, T, start, length, window, M in cases + list(sz["paged_prefill"]):
+        N = M + 2
+        q = rand(40, (1, T, H, D))
+        kp, vp = rand(41, (2, N, bs, Hkv * D)), rand(42, (2, N, bs, Hkv * D))
+        bt = jnp.asarray(np.random.default_rng(1).permutation(np.arange(1, N))[None, :M].astype(np.int32))
+        args = (q, kp, vp, bt, jnp.asarray([start], jnp.int32), jnp.asarray([length], jnp.int32), jnp.int32(1),
+                None if window is None else jnp.int32(window))
+        out = _compile(prefill_kernel, *args, on_chip=on_chip)(*args)
+        _check(f"prefill_paged_{name}", out[:, :length], prefill_xla(*args)[:, :length], FWD_REL_TOL, errs)
     return {"rel_err": errs, "tolerance": {"fwd": FWD_REL_TOL, "bwd": BWD_REL_TOL}}
 
 

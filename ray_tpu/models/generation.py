@@ -290,13 +290,15 @@ def paged_forward_counted(
     (logits, cache, the expert layers' counts).
 
     Writes this call's K/V into the pool through the block tables and
-    attends over every cached position up to ``positions``. Single-token
-    calls route through the Pallas paged decode kernel when
+    attends over every cached position up to ``positions``. With
     ``use_decode_kernel`` (default: ``ops.backend.on_tpu()`` — the ONE
-    select for this kernel; the op itself has none) — the block table rides
-    scalar prefetch, pages stream from HBM with no gather copy. Otherwise
-    the pool is gathered to a dense view and the attention lines are
-    IDENTICAL to the dense path's, which is what makes paged serving
+    select for the paged kernels; the ops themselves have none) attention
+    goes through the Pallas paged kernels, the decode kernel for
+    single-token calls and the prefill kernel for chunks: the block table
+    rides scalar prefetch and the pages a query can see stream from HBM
+    where they lie, with no gather copy and no capacity-wide scores.
+    Otherwise the pool is gathered to a dense view and the attention lines
+    are IDENTICAL to the dense path's, which is what makes paged serving
     byte-equal to the dense cache under ``JAX_PLATFORMS=cpu``.
 
     ``valid`` masks bucket-padded tail tokens out of the cache write (their
@@ -330,7 +332,9 @@ def paged_forward_counted(
     vis = kv_pos[None, None, None, :] <= positions[:, None, :, None]  # [B,1,T,cap]
     if use_decode_kernel is None:
         use_decode_kernel = backend.on_tpu()
-    decode_kernel = use_decode_kernel and T == 1
+    # a chunk's real tokens: the padded tail (``valid`` False) wrote to the
+    # garbage page and is no key of the prefill kernel's
+    lengths = T if valid is None else jnp.broadcast_to(valid, (B, T)).sum(-1)
     _refuse_scales_on_two_stacks(cfg, layer_scales)
 
     phys, off = _paged_write_index(block_tables, positions, valid, bs)
@@ -356,13 +360,19 @@ def paged_forward_counted(
         q, k, v = block_qkv(cfg, layer, h, positions, kind)
         kc = kc.at[l, phys, off].set(k.reshape(B * T, -1).astype(kc.dtype))
         vc = vc.at[l, phys, off].set(v.reshape(B * T, -1).astype(vc.dtype))
-        if decode_kernel:
+        if use_decode_kernel and T == 1:
             from ray_tpu.ops.decode_attention import paged_decode_attention
 
             o = paged_decode_attention(
                 q[:, 0], kc, vc, block_tables, starts + 1, l, sm_scale=scale, window=window
             )[:, None]
             o = o.astype(x.dtype)
+        elif use_decode_kernel:
+            from ray_tpu.ops.decode_attention import paged_prefill_attention
+
+            o = paged_prefill_attention(
+                q, kc, vc, block_tables, starts, lengths, l, sm_scale=scale, window=window
+            ).astype(x.dtype)
         else:
             # masked positions contribute exactly-0.0 weight, so page-0
             # garbage never reaches the output

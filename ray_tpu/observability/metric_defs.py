@@ -386,6 +386,18 @@ LLM_PREFILL_CHUNKS = _reg.counter(
     "prefill: one prompt = ceil(len/prefill_chunk_tokens) chunks "
     "interleaved between decode steps).",
 )
+LLM_PREFILL_KV_VISITED = _reg.counter(
+    "llm_prefill_kv_tokens_visited_total",
+    "Cached tokens the attention of the LLM engine's prefill chunks had to "
+    "visit, averaged over the layers: a chunk's start + its new tokens, less "
+    "what a sliding layer's window hides below the chunk's first query.",
+)
+LLM_PREFILL_KV_CAPACITY = _reg.counter(
+    "llm_prefill_kv_tokens_capacity_total",
+    "Tokens a sequence's block table can hold, once a prefill chunk: what a "
+    "chunk's attention read when it gathered the whole capacity. "
+    "llm_prefill_kv_tokens_visited_total over this is the share that is left.",
+)
 LLM_DECODE_STALL = _reg.histogram(
     "llm_decode_stall_seconds",
     "Time running decodes stalled waiting on prefill work admitted between "
@@ -591,6 +603,8 @@ ALL_METRICS = [
     LLM_KV_BLOCK_POOL_SIZE,
     LLM_KV_BLOCKS_IN_USE,
     LLM_PREFILL_CHUNKS,
+    LLM_PREFILL_KV_VISITED,
+    LLM_PREFILL_KV_CAPACITY,
     LLM_DECODE_STALL,
     LLM_PREFIX_CACHE_HITS,
     LLM_PREFIX_CACHE_BLOCKS,

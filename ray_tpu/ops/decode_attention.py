@@ -1,4 +1,5 @@
-"""Single-token (decode) attention over a KV cache as a Pallas TPU kernel.
+"""Attention over a KV cache for serving, as Pallas TPU kernels: one query a
+sequence (decode), dense or paged, and a chunk of queries over a paged pool.
 
 Decode attention is the per-token hot op of serving: one query row per
 sequence attends over the whole cache. It is purely HBM-bandwidth-bound —
@@ -29,6 +30,13 @@ the stacked pool — no per-layer slice, no materialized per-sequence cache
 copy, and no work for a page or a slot that holds nothing.
 ``use_kernel=False`` is the plain-XLA reference (a ``jnp.take`` gather that
 reduces to the dense math) the kernel is checked against.
+
+:func:`paged_prefill_attention` is the same page walk (:func:`_walk_pages`)
+for a chunk of ``T > 1`` consecutive queries of a sequence: a grid step a
+tile of 128 queries, the keys walked from the first position the tile's
+first query still sees to its last query's own, causal and windowed by
+position, so a prefill chunk reads the pages it can see and no
+``[T, capacity]`` score tensor exists.
 
 No backward pass: decode is inference-only. Non-TPU backends run in
 interpret mode (tests exercise the same code path on CPU).
@@ -174,70 +182,33 @@ def window_start(lengths, window):
     return jnp.where(window > 0, jnp.maximum(lengths - window, 0), 0)
 
 
-def _paged_decode_kernel(
-    tables_ref, lengths_ref, layer_ref,  # scalar-prefetch: [B, M] page ids, [B], [1]
-    *refs, sm_scale: float, block_size: int, pack: int, windowed: bool,
-):
-    """Grid (B,): one grid step a sequence; its visible pages are walked by a
-    loop inside the body, so a slot that holds nothing costs one grid step
-    and a page no query may see costs nothing at all.
+def _walk_pages(tables_ref, row, layer, k_hbm, v_hbm, k_buf, v_buf, sems, block_size, first, held, on_group):
+    """The page walk of both paged kernels: positions ``[first, held)`` of the
+    sequence in table row ``row``, a group of ``span`` tokens at a time.
 
     k_hbm/v_hbm are the whole stacked pools ``[L, N, block_size, Hkv*D]``
-    where they lie in HBM. The body loops, with a traced trip count, over
-    the row's visible span in groups of ``G`` pages (``k_buf``/``v_buf``:
-    ``[2, G*block_size, Hkv*D]`` VMEM, one whole 128-token lane tile of
-    scores where the page size divides 128), from the group that holds the
-    window's first position (0 without one) to the one that holds position
-    ``length - 1``. Each page of a group comes by its own copy out of
-    ``pool[layer, table[b, j]]`` into its rows of the group's buffer; the
-    next group's copies start before this group's products (two buffers).
-    Pages of a group past the last live one, or before the window's first,
-    are not fetched: their rows keep what an earlier group left there and
-    are masked by position (scores by ``where``, so stale K cannot reach a
-    sum, and a probability of exactly 0 meets stale V). The buffers are
-    zeroed at the first grid step, so they only ever hold zeros or live
-    pages of the pool.
-
-    The KV heads are walked in static lane tiles of ``W = pack*D`` lanes,
-    ``pack`` neighbouring heads to a tile, so a 64-wide head still loads
-    whole 128-lane tiles: q_ref is ``[Hkv, rep_p, W]`` with head ``g``'s
-    query in its own ``D`` lanes of its tile and zeros in its neighbours'
-    (their K lanes drop out of the scores as exact zeros), and o_ref/acc
-    carry ``W`` lanes of which the caller keeps head ``g``'s own. Per head
-    the state machine is :func:`_decode_kernel`'s, a group at a time.
-    ``windowed``: a fourth scalar-prefetch ref ``[1]`` carries this call's
-    sliding window (0: none).
-    """
-    if windowed:
-        window_ref, *refs = refs
-    q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, m_scr, l_scr, acc_scr = refs
-    bi = pl.program_id(0)
-    length = lengths_ref[bi]
-    layer = layer_ref[0]
-    n_kv, _, w = q_ref.shape
+    where they lie in HBM; ``k_buf``/``v_buf`` are ``[2, span, Hkv*D]`` VMEM
+    (``span``: a group of ``G`` pages, one whole 128-token lane tile of
+    scores where the page size divides 128). The loop runs, with a traced
+    trip count, from the group that holds ``first`` to the one that holds
+    ``held - 1``. Each page of a group comes by its own copy out of
+    ``pool[layer, table[row, j]]`` into its rows of the group's buffer; the
+    next group's copies start before ``on_group(g, slot)`` does this
+    group's products on ``k_buf[slot]``/``v_buf[slot]`` (two buffers). Pages
+    of a group past the last visible one, or before the first, are not
+    fetched: their rows keep what an earlier group left there, and the
+    caller masks them by position (scores by ``where``, so stale K cannot
+    reach a sum, and a probability of exactly 0 meets stale V)."""
     span = k_buf.shape[1]  # tokens a group
     group = span // block_size
-    first = window_start(length, window_ref[0]) if windowed else 0
-    # a length past the table's capacity (a finished row's overshoot) walks
-    # the table and no further
-    held = jnp.minimum(length, tables_ref.shape[1] * block_size)
     page_lo, page_hi = first // block_size, pl.cdiv(held, block_size)
     group_lo, group_hi = first // span, pl.cdiv(held, span)
-
-    @pl.when(bi == 0)
-    def _clean_buffers():
-        k_buf[...] = jnp.zeros_like(k_buf)
-        v_buf[...] = jnp.zeros_like(v_buf)
-
-    m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-    l_scr[...] = jnp.zeros_like(l_scr)
-    acc_scr[...] = jnp.zeros_like(acc_scr)
 
     def page_copies(g, slot, act):
         """``act`` on the K and V copy of each visible page of group ``g``."""
 
         def one_page(page, carry):
-            phys = tables_ref[bi, page]
+            phys = tables_ref[row, page]
             rows = pl.ds(pl.multiple_of((page - g * group) * block_size, block_size), block_size)
             act(pltpu.make_async_copy(k_hbm.at[layer, phys], k_buf.at[slot, rows], sems.at[0, slot]))
             act(pltpu.make_async_copy(v_hbm.at[layer, phys], v_buf.at[slot, rows], sems.at[1, slot]))
@@ -255,6 +226,56 @@ def _paged_decode_kernel(
             page_copies(g + 1, 1 - slot, lambda copy: copy.start())
 
         page_copies(g, slot, lambda copy: copy.wait())
+        on_group(g, slot)
+        return carry
+
+    jax.lax.fori_loop(group_lo, group_hi, one_group, None)
+
+
+def _paged_decode_kernel(
+    tables_ref, lengths_ref, layer_ref,  # scalar-prefetch: [B, M] page ids, [B], [1]
+    *refs, sm_scale: float, block_size: int, pack: int, windowed: bool,
+):
+    """Grid (B,): one grid step a sequence; its visible pages are walked by a
+    loop inside the body (:func:`_walk_pages`), so a slot that holds nothing
+    costs one grid step and a page no query may see costs nothing at all:
+    the walk runs from the group that holds the window's first position (0
+    without one) to the one that holds position ``length - 1``. The buffers
+    are zeroed at the first grid step, so they only ever hold zeros or live
+    pages of the pool.
+
+    The KV heads are walked in static lane tiles of ``W = pack*D`` lanes,
+    ``pack`` neighbouring heads to a tile, so a 64-wide head still loads
+    whole 128-lane tiles: q_ref is ``[Hkv, rep_p, W]`` with head ``g``'s
+    query in its own ``D`` lanes of its tile and zeros in its neighbours'
+    (their K lanes drop out of the scores as exact zeros), and o_ref/acc
+    carry ``W`` lanes of which the caller keeps head ``g``'s own. Per head
+    the state machine is :func:`_decode_kernel`'s, a group at a time.
+    ``windowed``: a fourth scalar-prefetch ref ``[1]`` carries this call's
+    sliding window (0: none).
+    """
+    if windowed:
+        window_ref, *refs = refs
+    q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, m_scr, l_scr, acc_scr = refs
+    bi = pl.program_id(0)
+    length = lengths_ref[bi]
+    n_kv, _, w = q_ref.shape
+    span = k_buf.shape[1]
+    first = window_start(length, window_ref[0]) if windowed else 0
+    # a length past the table's capacity (a finished row's overshoot) walks
+    # the table and no further
+    held = jnp.minimum(length, tables_ref.shape[1] * block_size)
+
+    @pl.when(bi == 0)
+    def _clean_buffers():
+        k_buf[...] = jnp.zeros_like(k_buf)
+        v_buf[...] = jnp.zeros_like(v_buf)
+
+    m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    def one_group(g, slot):
         pos = g * span + jax.lax.broadcasted_iota(jnp.int32, (1, span), 1)
         visible = jnp.logical_and(pos >= first, pos < held)  # [1, span]
         for h in range(n_kv):
@@ -266,27 +287,82 @@ def _paged_decode_kernel(
             s = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
             )
-            s = jnp.where(visible, s, NEG_INF)  # [rep_p, span]
+            _softmax_step(jnp.where(visible, s, NEG_INF), v, h, m_scr, l_scr, acc_scr)
 
-            m_prev = m_scr[h, :, :1]
-            l_prev = l_scr[h, :, :1]
-            m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-            alpha = jnp.exp(jnp.where(m_prev == NEG_INF, NEG_INF, m_prev - m_new))
-            p = jnp.exp(s - m_new)
-            l_new = l_prev * alpha + p.sum(axis=-1, keepdims=True)
-            acc_scr[h] = acc_scr[h] * alpha + jax.lax.dot_general(
-                p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-            )
-            m_scr[h] = jnp.broadcast_to(m_new, m_scr.shape[1:])
-            l_scr[h] = jnp.broadcast_to(l_new, l_scr.shape[1:])
-        return carry
+    _walk_pages(tables_ref, bi, layer_ref[0], k_hbm, v_hbm, k_buf, v_buf, sems, block_size, first, held, one_group)
+    _emit(o_ref, m_scr, l_scr, acc_scr)
 
-    jax.lax.fori_loop(group_lo, group_hi, one_group, None)
 
+def _dot_qk(q, k):
+    """``q [rows, W] . k [span, W]^T`` in float32: bf16 operands go to the MXU
+    as they are stored (the product of two bf16 values is exact in float32,
+    so one pass accumulated in float32 is the float32 product of the dense
+    lines); anything else as float32 at full precision."""
+    dims = (((1,), (1,)), ((), ()))
+    if q.dtype == k.dtype == jnp.bfloat16:
+        return jax.lax.dot_general(q, k, dims, preferred_element_type=jnp.float32)
+    return jax.lax.dot_general(
+        q.astype(jnp.float32), k.astype(jnp.float32), dims, preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST)
+
+
+def _dot_pv(p, v):
+    """``p [rows, span]`` float32 times ``v [span, W]`` with nothing rounded:
+    a float32 is three bf16 terms exactly (24 bits of mantissa in three
+    eights) and each term's products with a bf16 ``v`` are exact in float32,
+    so three passes of the MXU, accumulated in float32, are the float32
+    product. (Left to itself the MXU takes float32 operands in one pass of
+    rounded ones: 1.4x this kernel's error against the float32 lines on the
+    chip, and the full-precision product of two float32 operands takes six
+    passes.) A pool that is not bf16 takes the six."""
+    dims = (((1,), (0,)), ((), ()))
+    if v.dtype != jnp.bfloat16:
+        return jax.lax.dot_general(
+            p, v.astype(jnp.float32), dims, preferred_element_type=jnp.float32, precision=jax.lax.Precision.HIGHEST)
+    out = None
+    for _ in range(3):
+        term = p.astype(jnp.bfloat16)
+        p = p - term.astype(jnp.float32)
+        part = jax.lax.dot_general(term, v, dims, preferred_element_type=jnp.float32)
+        out = part if out is None else out + part
+    return out
+
+
+def _dot_pv_as_given(p, v):
+    """The decode kernel's ``P.V``: float32 operands as the MXU takes them."""
+    return jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+
+
+def _softmax_step(s, v, h, m_scr, l_scr, acc_scr, dot_pv=_dot_pv_as_given):
+    """One group's masked float32 scores ``s [rows, span]`` and values
+    ``v [span, W]`` into head ``h``'s online-softmax state (``m``/``l``
+    lane-broadcast ``[Hkv, rows, LANES]``, ``acc [Hkv, rows, W]``).
+    ``dot_pv``: the ``P.V`` product."""
+    m_prev = m_scr[h, :, :1]
+    l_prev = l_scr[h, :, :1]
+    m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+    alpha = jnp.exp(jnp.where(m_prev == NEG_INF, NEG_INF, m_prev - m_new))
+    p = jnp.exp(s - m_new)
+    l_new = l_prev * alpha + p.sum(axis=-1, keepdims=True)
+    acc_scr[h] = acc_scr[h] * alpha + dot_pv(p, v)
+    m_scr[h] = jnp.broadcast_to(m_new, m_scr.shape[1:])
+    l_scr[h] = jnp.broadcast_to(l_new, l_scr.shape[1:])
+
+
+def _emit(o_ref, m_scr, l_scr, acc_scr):
+    """The finished state as the output block; a row that saw no key (its
+    running max never left the floor) emits zeros, not garbage-V means."""
     l = l_scr[:, :, :1]
-    empty = m_scr[:, :, :1] <= NEG_INF * 0.5  # lengths[b] == 0: emit zeros
+    empty = m_scr[:, :, :1] <= NEG_INF * 0.5
     out = jnp.where(empty, 0.0, acc_scr[...] / jnp.where(l == 0, 1.0, l))
     o_ref[...] = out.astype(o_ref.dtype)
+
+
+def _gathered(pool, layer, block_tables, Hkv, D):
+    """Each sequence's pages of ``layer`` as a dense [B, Hkv, M*bs, D] view."""
+    B, M = block_tables.shape
+    g = pool[layer, block_tables]  # [B, M, bs, Hkv*D]
+    return jnp.transpose(g.reshape(B, -1, Hkv, D), (0, 2, 1, 3))
 
 
 def _paged_decode_xla(qg, k_pool, v_pool, block_tables, lengths, layer, scale, window=None):
@@ -296,12 +372,7 @@ def _paged_decode_xla(qg, k_pool, v_pool, block_tables, lengths, layer, scale, w
     in interpret mode, ``chip_smoke.py`` compiled)."""
     B, Hkv, _, D = qg.shape
     M, bs = block_tables.shape[1], k_pool.shape[2]
-
-    def dense(pool):
-        g = pool[layer, block_tables]  # [B, M, bs, Hkv*D]
-        return jnp.transpose(g.reshape(B, M * bs, Hkv, D), (0, 2, 1, 3))
-
-    k, v = dense(k_pool), dense(v_pool)
+    k, v = (_gathered(pool, layer, block_tables, Hkv, D) for pool in (k_pool, v_pool))
     s = jnp.einsum(
         "bgrk,bgsk->bgrs", qg.astype(jnp.float32), k.astype(jnp.float32)
     ) * scale  # [B, Hkv, n_rep, S]
@@ -314,6 +385,75 @@ def _paged_decode_xla(qg, k_pool, v_pool, block_tables, lengths, layer, scale, w
     # a fully-masked row softmaxes to uniform garbage; zero it like the kernel
     p = jnp.where((lengths > 0)[:, None, None, None], p, 0.0)
     return jnp.einsum("bgrs,bgsk->bgrk", p, v.astype(jnp.float32))
+
+
+def _heads_a_lane_tile(Hkv: int, D: int) -> int:
+    """Heads narrower than a lane tile share one: ``pack`` neighbours to a
+    tile of ``pack * D`` lanes, so a paged kernel never slices inside a tile
+    (a 64-lane slice at a 64-lane offset cost twice the aligned load per page
+    on the v5e)."""
+    import math
+
+    return math.gcd(Hkv, _LANES // D) if _LANES % D == 0 else 1
+
+
+def _into_own_lanes(qg: jax.Array, pack: int) -> jax.Array:
+    """``qg [B, Hkv, rows, D]`` -> ``[B, Hkv, rows, pack*D]``: head ``g``'s
+    query in its own ``D`` lanes of its tile, exact zeros in its neighbours'."""
+    if pack == 1:
+        return qg
+    B, Hkv, rows, D = qg.shape
+    own_lanes = jax.nn.one_hot(jnp.arange(Hkv) % pack, pack, dtype=qg.dtype)  # [Hkv, pack]
+    return (qg[:, :, :, None, :] * own_lanes[None, :, None, :, None]).reshape(B, Hkv, rows, pack * D)
+
+
+def _own_lanes(out: jax.Array, pack: int) -> jax.Array:
+    """``out [B, Hkv, rows, pack*D]`` -> ``[B, Hkv, rows, D]``: each head's
+    own lanes of its tile."""
+    if pack == 1:
+        return out
+    B, Hkv, rows, W = out.shape
+    own = jnp.arange(Hkv) % pack
+    return jnp.take_along_axis(
+        out.reshape(B, Hkv, rows, pack, W // pack), own[None, :, None, None, None], axis=3
+    ).reshape(B, Hkv, rows, W // pack)
+
+
+def _paged_call(kernel, name, scalars, qg, k_pool, v_pool, *, grid, rows, q_index, **compiler_params):
+    """The ``pallas_call`` of both paged kernels: ``scalars`` ride scalar
+    prefetch, ``qg [B, Hkv, R, W]`` and the output of its shape come in
+    blocks of ``rows`` at ``q_index``, the pools stay in HBM (the body copies
+    the pages it needs), and the scratch is the two-slot group buffers of K
+    and V (a group of pages is one lane tile of scores: 128 tokens where the
+    page size divides it), their copy semaphores and the online-softmax
+    state of every KV head."""
+    _, Hkv, _, W = qg.shape
+    bs, row = k_pool.shape[2:]
+    span = max(1, _LANES // bs) * bs
+    block = pl.BlockSpec((None, Hkv, rows, W), q_index)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(scalars),
+        grid=grid,
+        in_specs=[block, pl.BlockSpec(memory_space=pl.ANY), pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=block,
+        scratch_shapes=[
+            pltpu.VMEM((2, span, row), k_pool.dtype),
+            pltpu.VMEM((2, span, row), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),  # [K | V, buffer]
+            pltpu.VMEM((Hkv, rows, _LANES), jnp.float32),
+            pltpu.VMEM((Hkv, rows, _LANES), jnp.float32),
+            pltpu.VMEM((Hkv, rows, W), jnp.float32),
+        ],
+    )
+    return pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct(qg.shape, qg.dtype),
+        grid_spec=grid_spec,
+        # in order: the group buffers are cleaned at the first grid step
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",) * len(grid), **compiler_params),
+        interpret=_use_interpret(),
+        name=name,
+    )(*scalars, qg, k_pool, v_pool)
 
 
 def paged_decode_attention(
@@ -367,54 +507,168 @@ def paged_decode_attention(
     rep_p = -(-n_rep // _MIN_REP) * _MIN_REP
     if rep_p != n_rep:
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, rep_p - n_rep), (0, 0)))
-    # heads narrower than a lane tile share one: `pack` neighbours to a tile
-    # of W lanes, so the kernel never slices inside a tile (a 64-lane slice
-    # at a 64-lane offset cost twice the aligned load per page on the v5e)
-    pack = math.gcd(Hkv, _LANES // D) if _LANES % D == 0 else 1
-    W = pack * D
-    own = jnp.arange(Hkv) % pack  # which D lanes of its tile are head g's own
-    if pack > 1:
-        # q into its own lanes, exact zeros in the neighbours'
-        own_lanes = jax.nn.one_hot(own, pack, dtype=qg.dtype)  # [Hkv, pack]
-        qg = (qg[:, :, :, None, :] * own_lanes[None, :, None, :, None]).reshape(B, Hkv, rep_p, W)
+    pack = _heads_a_lane_tile(Hkv, D)
+    qg = _into_own_lanes(qg, pack)
     windowed = window is not None
     scalars = [block_tables.astype(jnp.int32), lengths.astype(jnp.int32), jnp.asarray(layer, jnp.int32).reshape(1)]
     if windowed:
         scalars.append(jnp.asarray(window, jnp.int32).reshape(1))
 
-    # a group of pages is one lane tile of scores: 128 tokens where the page
-    # size divides it
-    span = max(1, _LANES // bs) * bs
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=len(scalars),  # block_tables, lengths, layer(, window)
-        grid=(B,),
-        in_specs=[
-            pl.BlockSpec((None, Hkv, rep_p, W), lambda b, *_: (b, 0, 0, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),  # the pools stay in HBM: the body
-            pl.BlockSpec(memory_space=pl.ANY),  # copies the pages it needs
-        ],
-        out_specs=pl.BlockSpec((None, Hkv, rep_p, W), lambda b, *_: (b, 0, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((2, span, row), k_pool.dtype),
-            pltpu.VMEM((2, span, row), v_pool.dtype),
-            pltpu.SemaphoreType.DMA((2, 2)),  # [K | V, buffer]
-            pltpu.VMEM((Hkv, rep_p, _LANES), jnp.float32),
-            pltpu.VMEM((Hkv, rep_p, _LANES), jnp.float32),
-            pltpu.VMEM((Hkv, rep_p, W), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
+    out = _paged_call(
         functools.partial(_paged_decode_kernel, sm_scale=scale, block_size=bs, pack=pack, windowed=windowed),
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, rep_p, W), q.dtype),
-        grid_spec=grid_spec,
-        # in order: the group buffers are cleaned at the first grid step
-        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
-        interpret=_use_interpret(),
-        name="paged_decode",
-    )(*scalars, qg, k_pool, v_pool)
-    out = out[:, :, :n_rep]
-    if pack > 1:  # keep each head's own lanes of its tile
-        out = jnp.take_along_axis(
-            out.reshape(B, Hkv, n_rep, pack, D), own[None, :, None, None, None], axis=3
-        )
-    return out.reshape(B, H, D)
+        "paged_decode", scalars, qg, k_pool, v_pool, grid=(B,), rows=rep_p, q_index=lambda b, *_: (b, 0, 0, 0))
+    return _own_lanes(out[:, :, :n_rep], pack).reshape(B, H, D)
+
+
+_Q_TILE = 128  # queries a grid step of the prefill kernel: one group of keys on the diagonal
+# q and o blocks twice, the state and the group buffers: ~12 MiB at both served
+# shapes (32 heads x 128 rows, 4 x 1024), past the compiler's default for a kernel
+_PREFILL_VMEM_BYTES = 48 * 1024 * 1024
+
+
+def _paged_prefill_kernel(
+    tables_ref, starts_ref, lengths_ref, layer_ref,  # scalar-prefetch: [B, M], [B], [B], [1]
+    *refs, sm_scale: float, block_size: int, pack: int, n_rep: int, windowed: bool,
+):
+    """Grid (B, T / tq): one grid step a tile of ``tq`` consecutive queries of
+    a chunk, all heads. The tile's queries stand at ``start + qi*tq ..``; its
+    keys are walked where they lie in the pool (:func:`_walk_pages`) from the
+    group that holds the first position its first query still sees (0
+    without a window) to the one that holds its last query's own position,
+    or the chunk's last real token's if that comes first: nothing past
+    ``start + length - 1`` is fetched, and a tile wholly past it walks
+    nothing and emits zeros. Visibility is positional and per query: key
+    ``j`` is seen by the query at ``p`` iff ``j <= p`` and, with a window,
+    ``j > p - window``.
+
+    q_ref/o_ref are ``[Hkv, tq*n_rep, W]``: row ``t*n_rep + r`` is query
+    ``t``'s head ``r`` of the KV group, so the ``n_rep`` heads of a group
+    share one read of its K/V and one mask serves every group; lanes as in
+    :func:`_paged_decode_kernel`. Scores, softmax state and ``P.V`` are
+    float32 with nothing rounded on the way (:func:`_dot_qk`, :func:`_dot_pv`).
+    """
+    if windowed:
+        window_ref, *refs = refs
+    q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, m_scr, l_scr, acc_scr = refs
+    bi, qi = pl.program_id(0), pl.program_id(1)
+    n_kv, rows, w = q_ref.shape
+    span, tq = k_buf.shape[1], rows // n_rep
+    p0 = starts_ref[bi] + qi * tq  # the tile's first query's position
+    held = jnp.minimum(starts_ref[bi] + lengths_ref[bi], tables_ref.shape[1] * block_size)
+    last = jnp.where(p0 < held, jnp.minimum(held, p0 + tq), 0)
+    first = window_start(p0 + 1, window_ref[0]) if windowed else 0
+
+    @pl.when(jnp.logical_and(bi == 0, qi == 0))
+    def _clean_buffers():
+        k_buf[...] = jnp.zeros_like(k_buf)
+        v_buf[...] = jnp.zeros_like(v_buf)
+
+    m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+    q_pos = p0 + jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0) // n_rep
+    q_first = window_start(q_pos + 1, window_ref[0]) if windowed else None  # [rows, 1]
+
+    def one_group(g, slot):
+        pos = g * span + jax.lax.broadcasted_iota(jnp.int32, (1, span), 1)
+        visible = jnp.logical_and(pos <= q_pos, pos < last)  # [rows, span]
+        if windowed:
+            visible = jnp.logical_and(visible, pos >= q_first)
+        for h in range(n_kv):
+            if h % pack == 0:  # a new lane tile, shared by its heads
+                lanes = slice(h // pack * w, (h // pack + 1) * w)
+                k = k_buf[slot, :, lanes]
+                v = v_buf[slot, :, lanes]
+            s = _dot_qk(q_ref[h], k) * sm_scale
+            _softmax_step(jnp.where(visible, s, NEG_INF), v, h, m_scr, l_scr, acc_scr, dot_pv=_dot_pv)
+
+    _walk_pages(tables_ref, bi, layer_ref[0], k_hbm, v_hbm, k_buf, v_buf, sems, block_size, first, last, one_group)
+    _emit(o_ref, m_scr, l_scr, acc_scr)
+
+
+def _paged_prefill_xla(qg, k_pool, v_pool, block_tables, starts, lengths, layer, scale, n_rep, window=None):
+    """Plain-XLA reference: the dense lines of
+    ``models/generation.py::paged_forward_counted`` on a gathered
+    [B, Hkv, M*bs, D] view; the kernel is compared against it."""
+    B, Hkv, rows, D = qg.shape
+    M, bs = block_tables.shape[1], k_pool.shape[2]
+    k, v = (_gathered(pool, layer, block_tables, Hkv, D) for pool in (k_pool, v_pool))
+    s = jnp.einsum("bgtk,bgsk->bgts", qg.astype(jnp.float32), k.astype(jnp.float32)) * scale
+    pos = jnp.arange(M * bs)[None, None, :]
+    q_pos = (starts[:, None] + jnp.arange(rows)[None, :] // n_rep)[:, :, None]
+    vis = (pos <= q_pos) & (pos < (starts + lengths)[:, None, None])
+    if window is not None:
+        vis = vis & (pos >= window_start(q_pos + 1, window))
+    s = jnp.where(vis[:, None], s, NEG_INF)
+    p = jnp.where(vis.any(-1)[:, None, :, None], jax.nn.softmax(s, axis=-1), 0.0)
+    return jnp.einsum("bgts,bgsk->bgtk", p, v.astype(jnp.float32))
+
+
+def paged_prefill_attention(
+    q: jax.Array,             # [B, T, H, D] a chunk of consecutive queries a sequence
+    k_pool: jax.Array,        # [L, num_blocks, block_size, Hkv*D] shared pool
+    v_pool: jax.Array,        # [L, num_blocks, block_size, Hkv*D]
+    block_tables: jax.Array,  # [B, M] int32 physical page per logical block
+    starts: jax.Array,        # [B] int32: the position of each chunk's first query
+    lengths: jax.Array,       # [B] int32: real tokens of each chunk (the rest is padding)
+    layer: jax.Array,         # int32 scalar: which layer of the pool to read
+    *,
+    sm_scale: Optional[float] = None,
+    use_kernel: bool = True,
+    window=None,
+) -> jax.Array:
+    """Chunked-prefill attention over one layer of a paged KV pool; returns
+    [B, T, H, D]. The chunk's own K/V are in the pool already.
+
+    Query ``t`` of row ``b`` stands at position ``starts[b] + t`` and sees
+    the keys at or before it (within ``window`` of it: an int or a traced
+    scalar; 0: none; None: the kernel is built without it). The pool is read
+    where it lies, a group of 128 tokens at a time, for the pages some query
+    of the chunk may see and for no other: table entries past the page of
+    ``starts + lengths - 1``, and behind the window of the chunk's first
+    query, may point anywhere and are never fetched; no dense view is
+    gathered and no ``[T, capacity]`` score tensor exists. Rows past
+    ``lengths`` are padding (their K/V went to the garbage page): they see no
+    key past the last real one and their outputs mean nothing. The queries
+    are tiled by 128 over the grid, so a tile re-reads the K/V before it:
+    with ``c`` visible tokens a chunk of ``T`` reads about
+    ``(T/128) * (c - T/2)`` tokens of K and V a layer.
+    ``use_kernel=False`` is the plain-XLA gather reference; the platform
+    select is the caller's, as for :func:`paged_decode_attention`.
+    """
+    import math
+
+    B, T, H, D = q.shape
+    _, _, bs, row = k_pool.shape
+    Hkv = row // D
+    n_rep = H // Hkv
+    scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(D)
+    starts, lengths = starts.astype(jnp.int32), jnp.broadcast_to(jnp.asarray(lengths, jnp.int32), (B,))
+
+    def grouped(a):  # [B, T', H, D] -> [B, Hkv, T'*n_rep, D], a query's n_rep heads on neighbouring rows
+        return jnp.transpose(a.reshape(B, -1, Hkv, n_rep, D), (0, 2, 1, 3, 4)).reshape(B, Hkv, -1, D)
+
+    def ungrouped(a):
+        return jnp.transpose(a.reshape(B, Hkv, -1, n_rep, D), (0, 2, 1, 3, 4)).reshape(B, -1, H, D)
+
+    if not use_kernel:
+        out = _paged_prefill_xla(grouped(q), k_pool, v_pool, block_tables, starts, lengths, layer, scale, n_rep, window)
+        return ungrouped(out.astype(q.dtype))
+
+    tq = min(_Q_TILE, -(-T // 16) * 16)  # whole sublane tiles of a 16-bit query
+    pad_t = (-T) % tq
+    if pad_t:
+        q = jnp.pad(q, ((0, 0), (0, pad_t), (0, 0), (0, 0)))
+    pack = _heads_a_lane_tile(Hkv, D)
+    qg = _into_own_lanes(grouped(q), pack)
+    windowed = window is not None
+    scalars = [block_tables.astype(jnp.int32), starts, lengths, jnp.asarray(layer, jnp.int32).reshape(1)]
+    if windowed:
+        scalars.append(jnp.asarray(window, jnp.int32).reshape(1))
+
+    out = _paged_call(
+        functools.partial(
+            _paged_prefill_kernel, sm_scale=scale, block_size=bs, pack=pack, n_rep=n_rep, windowed=windowed),
+        "paged_prefill", scalars, qg, k_pool, v_pool, grid=(B, (T + pad_t) // tq), rows=tq * n_rep,
+        q_index=lambda b, i, *_: (b, 0, i, 0), vmem_limit_bytes=_PREFILL_VMEM_BYTES)
+    return ungrouped(_own_lanes(out, pack))[:, :T]

@@ -51,7 +51,7 @@ from __future__ import annotations
 import functools
 import threading
 import time
-from collections import deque
+from collections import Counter, deque
 from concurrent.futures import Future
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Tuple
@@ -398,6 +398,13 @@ class LLMEngine:
         # until release paths free enough blocks
         self._held_req: Optional[GenRequest] = None
         self._prefill_chunk_count = 0
+        # tokens of K/V the chunks' attention had to visit, averaged over the
+        # layers (``_chunk_kv_visited``), and the capacity a chunk's table
+        # spans: what the prefill kernel reads of what the dense lines read
+        self._prefill_kv_visited = 0.0
+        self._prefill_kv_capacity = 0
+        # (window, layers that have it); 0: a full layer
+        self._layers_by_window = sorted(Counter(cfg.layer_windows or (0,) * cfg.n_layers).items())
         self._decode_step_count = 0
         # the decode step dispatched and not yet read (``_dispatch`` /
         # ``_collect``), steps dispatched while the one before was unread,
@@ -432,7 +439,7 @@ class LLMEngine:
         moe_counted = self._moe_counted
         layer_scales = self._layer_scales
         # under a mesh the einsum path partitions via GSPMD; the Pallas
-        # decode kernel stays for the single-device engine
+        # paged kernels (decode and prefill) stay for the single-device engine
         use_kernel = None if mesh is None else False
         # under a mesh a program that returns the pool returns it as it was
         # placed: the donated buffers are updated where they lie and the next
@@ -479,7 +486,7 @@ class LLMEngine:
             valid = (jnp.arange(C) < length)[None, :]
             logits, cache, moe = paged_forward_counted(
                 cfg_, params, cache, bt, toks, positions,
-                valid=valid, layer_scales=layer_scales, use_decode_kernel=False,
+                valid=valid, layer_scales=layer_scales, use_decode_kernel=use_kernel,
             )
             last = jax.lax.dynamic_index_in_dim(logits[0], length - 1, 0, keepdims=False)
             return (last, cache, moe) if moe_counted else (last, cache)
@@ -838,6 +845,8 @@ class LLMEngine:
                 "kv_blocks_shared": alloc.shared_blocks,
                 "prefilling": len(self._prefilling),
                 "prefill_chunks": self._prefill_chunk_count,
+                "prefill_kv_tokens_visited": self._prefill_kv_visited,
+                "prefill_kv_tokens_capacity": self._prefill_kv_capacity,
                 "prefix_cache_enabled": self._prefix is not None,
                 "prefix_cache_blocks": len(self._prefix) if self._prefix is not None else 0,
                 "prefix_cache_hits": self._prefix_results["hit"],
@@ -1594,8 +1603,14 @@ class LLMEngine:
         metric_defs.LLM_PREFILL_CHUNKS.inc()
         if req.trace is not None:
             req.trace.note_prefill_chunk()
+        visited = self._chunk_kv_visited(req.prefill_pos, n)
+        capacity = self.max_blocks_per_slot * self.kv_block_size
+        metric_defs.LLM_PREFILL_KV_VISITED.inc(visited)
+        metric_defs.LLM_PREFILL_KV_CAPACITY.inc(capacity)
         with self._lock:
             self._prefill_chunk_count += 1
+            self._prefill_kv_visited += visited
+            self._prefill_kv_capacity += capacity
         req.prefill_pos += n
         if req.prefill_pos < len(req.prompt):
             return
@@ -1622,6 +1637,14 @@ class LLMEngine:
             self._moe_experts_hit_decode += hit
         metric_defs.LLM_MOE_ASSIGNMENTS.inc(int(counts.sum()))
         metric_defs.LLM_MOE_EXPERTS_HIT.inc(hit)
+
+    def _chunk_kv_visited(self, start: int, n: int) -> float:
+        """Cached tokens the attention of a chunk of ``n`` tokens at
+        ``start`` has to visit in a layer, averaged over the layers: all
+        ``start + n`` in a full layer, less those below its first query's
+        window in a sliding one."""
+        seen = sum(count * (start + n - (max(0, start - w + 1) if w else 0)) for w, count in self._layers_by_window)
+        return seen / self.cfg.n_layers
 
     def kv_read_share(self) -> float:
         """Of the cached tokens of the live sequences, the share a decode

@@ -8,6 +8,7 @@ toy size, in float32, on seeded weights with every norm gain perturbed."""
 from __future__ import annotations
 
 import dataclasses
+import functools
 import os
 import sys
 
@@ -99,8 +100,9 @@ def via_dense_cache(cfg, params, tokens, split=29):
     return jnp.concatenate(out)
 
 
-def via_paged(cfg, params, tokens, split=37, chunk=16, page=4):
-    """Chunked prefill at a traced start (the engine's ``_prefill_chunk``),
+def via_paged(cfg, params, tokens, split=37, chunk=16, page=4, prefill_kernel=False):
+    """Chunked prefill at a traced start (the engine's ``_prefill_chunk``;
+    ``prefill_kernel``: through the paged prefill kernel, as on the chip),
     then decode through the paged kernel in interpret mode; chunks, pages and
     the window all end at different places."""
     M = 64 // page
@@ -111,7 +113,7 @@ def via_paged(cfg, params, tokens, split=37, chunk=16, page=4):
     def prefill_chunk(cache, toks, start, length):
         valid = (jnp.arange(chunk) < length)[None]
         return paged_forward_with_cache(cfg, params, cache, bt, toks, start + jnp.arange(chunk)[None],
-                                        valid=valid, use_decode_kernel=False)
+                                        valid=valid, use_decode_kernel=prefill_kernel)
 
     out = []
     for start in range(0, split, chunk):
@@ -127,7 +129,8 @@ def via_paged(cfg, params, tokens, split=37, chunk=16, page=4):
     return jnp.concatenate(out)
 
 
-PATHS = {"forward": via_forward, "forward_with_cache": via_dense_cache, "paged_forward_with_cache": via_paged}
+PATHS = {"forward": via_forward, "forward_with_cache": via_dense_cache, "paged_forward_with_cache": via_paged,
+         "paged_prefill_kernel": functools.partial(via_paged, prefill_kernel=True)}
 
 
 @pytest.mark.parametrize("path", sorted(PATHS))
@@ -177,6 +180,8 @@ FAULTS = {
 CASES = [(f, "forward") for f in FAULTS] + [
     ("no window on sliding layers", "paged_forward_with_cache"),
     ("no window on sliding layers", "forward_with_cache"),
+    ("no window on sliding layers", "paged_prefill_kernel"),
+    ("RoPE on a full layer", "paged_prefill_kernel"),
     ("RoPE on a full layer", "paged_forward_with_cache"),
     ("no output gate", "paged_forward_with_cache"),
     ("the bias added to the weights", "paged_forward_with_cache"),

@@ -375,6 +375,82 @@ def test_paged_runner_decode_kernel_matches_dense_cache(shape):
         np.testing.assert_allclose(np.asarray(d), np.asarray(p), atol=2e-4, rtol=2e-4)
 
 
+def _chunked_prefill(cfg, params, *, use_decode_kernel, layer_scales=None):
+    """A 27-token prompt in 8-wide chunks (the last padded, masked by
+    ``valid``) at traced starts through one jitted program, over a shuffled
+    pool of 4-token pages: every chunk's logits and the pool they leave."""
+    from ray_tpu.models.generation import init_paged_cache, paged_forward_with_cache
+
+    rng = np.random.default_rng(4)
+    chunk, bs, M = 8, 4, 8
+    prompt = rng.integers(1, cfg.vocab_size, 27)
+    pool = init_paged_cache(cfg, 2 * M + 1, bs)
+    bt = jnp.asarray(rng.permutation(np.arange(1, 2 * M + 1))[None, :M].astype(np.int32))
+
+    @jax.jit
+    def prefill(pool, toks, start, length):
+        return paged_forward_with_cache(
+            cfg, params, pool, bt, toks, start + jnp.arange(chunk)[None], valid=(jnp.arange(chunk) < length)[None],
+            use_decode_kernel=use_decode_kernel, layer_scales=layer_scales)
+
+    out = []
+    for start in range(0, len(prompt), chunk):
+        piece = prompt[start:start + chunk]
+        toks = np.zeros((1, chunk), np.int32)
+        toks[0, : len(piece)] = piece
+        logits, pool = prefill(pool, jnp.asarray(toks), jnp.int32(start), jnp.int32(len(piece)))
+        out.append(logits[0, : len(piece)])
+    return jnp.concatenate(out), pool, prefill
+
+
+@op_shapes
+def test_paged_runner_prefill_kernel_matches_the_dense_lines(shape):
+    """``use_decode_kernel=True`` sends a chunk (T > 1) through the
+    (interpret-mode) paged prefill kernel: the dense lines' logits, and the
+    pool it leaves holds the first layer's K/V bit for bit (they do not pass
+    through attention) and the deeper layers' to rounding."""
+    cfg = _runner_cfg(shape)
+    params = init_params(cfg, jax.random.key(2))
+    want, dense_pool, _ = _chunked_prefill(cfg, params, use_decode_kernel=False)
+    got, kernel_pool, _ = _chunked_prefill(cfg, params, use_decode_kernel=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-4, rtol=2e-4)
+    for name in ("k", "v"):
+        np.testing.assert_array_equal(np.asarray(kernel_pool[name][0]), np.asarray(dense_pool[name][0]))
+        # but for the garbage page: the padded tail's writes went there, and what a padded row attends over means nothing
+        np.testing.assert_allclose(np.asarray(kernel_pool[name][:, 1:]), np.asarray(dense_pool[name][:, 1:]), atol=2e-4, rtol=2e-4)
+        assert np.asarray(kernel_pool[name][:, 0]).any()
+
+
+def test_paged_runner_prefill_kernel_with_int8_layer_scales():
+    """The int8 path's attention branch is the same: scales ride the layer
+    loop's xs, the kernel reads the carried pool."""
+    from ray_tpu.ops.quantization import quantize_layers
+
+    cfg = _runner_cfg(OP_SHAPES["gqa8_2_d64"])
+    params = init_params(cfg, jax.random.key(2))
+    layers_q, scales = quantize_layers(params["layers"])
+    qparams = {**params, "layers": layers_q}
+    want, _, _ = _chunked_prefill(cfg, qparams, use_decode_kernel=False, layer_scales=scales)
+    got, _, _ = _chunked_prefill(cfg, qparams, use_decode_kernel=True, layer_scales=scales)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-4, rtol=2e-4)
+
+
+@pytest.mark.parametrize("use", [False, True, None], ids=["False", "True", "default_off_the_chip"])
+def test_use_decode_kernel_is_the_one_select_for_a_chunk_too(use):
+    """``False`` keeps the dense lines for T > 1 (the benchmark's own calls
+    pass it by keyword; the engine under a mesh does), ``True`` puts one
+    Pallas call a layer stack into the chunk's program and no
+    capacity-wide score tensor; the default asks ``on_tpu()``: off here."""
+    cfg = _runner_cfg(OP_SHAPES["mha_d64"])
+    _, _, prefill = _chunked_prefill(cfg, init_params(cfg, jax.random.key(2)), use_decode_kernel=use)
+    from ray_tpu.models.generation import init_paged_cache
+
+    text = str(jax.make_jaxpr(prefill)(init_paged_cache(cfg, 17, 4), jnp.zeros((1, 8), jnp.int32), jnp.int32(8), jnp.int32(8)))
+    capacity_wide_scores = "f32[1,4,1,8,32]"  # [B, Hkv, n_rep, T, M * block_size]
+    assert ("pallas_call" in text) == bool(use)
+    assert (capacity_wide_scores in text) == (not use)
+
+
 def test_paged_runner_int8_layer_scales_bit_identical_to_dense_cache():
     """The int8 path's scales ride the layer loop's xs beside the layers and
     the layer index; the pool still rides the carry."""
@@ -715,6 +791,8 @@ def test_paged_snapshot_and_metrics_registered(params):
         "llm_kv_block_pool_size",
         "llm_kv_blocks_in_use",
         "llm_prefill_chunks_total",
+        "llm_prefill_kv_tokens_visited_total",
+        "llm_prefill_kv_tokens_capacity_total",
         "llm_decode_stall_seconds",
     ):
         assert family in names

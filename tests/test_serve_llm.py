@@ -961,3 +961,54 @@ def test_lowered_decode_text_is_the_program_the_loop_dispatches(engine):
     assert "tensor<4x1xi32>" in main.group(2) and "tensor<4xi32>" in main.group(2)
     # toks, join, pos are three int32[B] arguments; temps a float32[B]
     assert main.group(1).count("tensor<4xi32>") == 3 and main.group(1).count("tensor<4xf32>") == 1
+
+
+def _prefill_kv(eng):
+    st = eng.stats()
+    return st["prefill_chunks"], st["prefill_kv_tokens_visited"], st["prefill_kv_tokens_capacity"]
+
+
+def test_prefill_kv_counters_follow_chunks_and_prefix_hits(params):
+    """``prefill_kv_tokens_visited``: what a chunk's attention has to visit
+    (its start + its new tokens); ``prefill_kv_tokens_capacity``: what the
+    table spans, once a chunk. By hand for a three-chunk prompt, a partial
+    prefix hit and a full one (one token recomputed)."""
+    eng = LLMEngine(CFG, params, max_batch_size=2, max_seq_len=64, kv_block_size=8, prefill_chunk_tokens=8)
+    try:
+        assert _prefill_kv(eng) == (0, 0, 0)
+        prompt = list(range(1, 21))  # 20 tokens: chunks at 0, 8 and 16 of 8, 8 and 4
+        assert eng.generate(prompt, max_tokens=2) == _reference(params, prompt, 2)
+        assert _prefill_kv(eng) == (3, 8 + 16 + 20, 3 * 64)
+        # the same again: two full pages come from the prefix cache, one chunk at 16
+        assert eng.generate(prompt, max_tokens=2) == _reference(params, prompt, 2)
+        assert eng.stats()["prefix_tokens_reused"] == 16
+        assert _prefill_kv(eng) == (4, 44 + 20, 4 * 64)
+        # its first two pages alone: a full hit recomputes the last token, at 15
+        assert eng.generate(prompt[:16], max_tokens=2) == _reference(params, prompt[:16], 2)
+        assert _prefill_kv(eng) == (5, 64 + 16, 5 * 64)
+    finally:
+        eng.shutdown()
+
+
+def test_prefill_kv_counters_leave_out_what_a_window_hides():
+    """A sliding layer's chunk visits ``start + n`` less the positions below
+    its first query's window; the count is the mean over the layers."""
+    cfg = TransformerConfig(
+        vocab_size=89, d_model=32, n_layers=2, n_heads=4, n_kv_heads=2, d_ff=64, attention="dense",
+        dtype=jnp.float32, layer_types=("sliding", "full"), sliding_window=8)
+    assert cfg.layer_windows == (8, 0)
+    eng = LLMEngine(cfg, init_params(cfg, jax.random.key(3)), max_batch_size=2, max_seq_len=64, kv_block_size=8,
+                    prefill_chunk_tokens=8)
+    try:
+        assert len(eng.generate(list(range(1, 21)), max_tokens=2)) == 2
+        # sliding: 8 - 0, 16 - 1, 20 - 9 (the first query at 16 sees from 9 on); full: 8, 16, 20
+        assert _prefill_kv(eng) == (3, ((8 + 15 + 11) + (8 + 16 + 20)) / 2, 3 * 64)
+        # one bucketed call a prompt (prefill_chunk_tokens 0) counts the same way
+        one_shot = LLMEngine(cfg, eng.params, max_batch_size=2, max_seq_len=64, kv_block_size=8)
+        try:
+            assert len(one_shot.generate(list(range(1, 21)), max_tokens=2)) == 2
+            assert _prefill_kv(one_shot) == (1, 20, 64)
+        finally:
+            one_shot.shutdown()
+    finally:
+        eng.shutdown()

@@ -31,7 +31,7 @@ import chip_smoke
 from bench import TRAIN_BATCH, train_config
 from ray_tpu.ops import backend
 from ray_tpu.ops.attention import flash_attention
-from ray_tpu.ops.decode_attention import decode_attention, paged_decode_attention
+from ray_tpu.ops.decode_attention import decode_attention, paged_decode_attention, paged_prefill_attention
 from ray_tpu.ops.quantization import int8_matmul
 from ray_tpu.scripts.llm_bench import serving_config
 
@@ -103,6 +103,21 @@ def _kernel_cases():
     # (two heads to a 128-lane tile), and one 128-wide head (its own tile) under GQA
     yield "decode_paged_mha32_d64", paged_decode_attention, paged(32, 32, 64, g["bs"], BF16)
     yield "decode_paged_gqa4_1_d128", paged_decode_attention, paged(4, 1, 128, g["bs"], BF16)
+    # the paged prefill kernel at the two served families' chunk, capacity and
+    # head shapes (32 x 64 wide heads, two to a lane tile; 32 over 4 of 128 with
+    # the window as a traced scalar), and at widths below and across its query tile
+    def chunk(T, H, Hkv, D, M, dt=BF16, window=False):
+        pool = ((2, M + 1, g["bs"], Hkv * D), dt)
+        return [((1, T, H, D), dt), pool, pool, ((1, M), I32), ((1,), I32), ((1,), I32), ((), I32)] + [((), I32)] * window
+
+    def windowed(q, kp, vp, bt, start, length, layer, window):
+        return paged_prefill_attention(q, kp, vp, bt, start, length, layer, window=window)
+
+    yield "prefill_paged_smollm2", paged_prefill_attention, chunk(512, 32, 32, 64, 256)
+    yield "prefill_paged_trinity", windowed, chunk(512, 32, 4, 128, 512, window=True)
+    for T in (16, 200, 1024):
+        yield f"prefill_paged_T{T}", paged_prefill_attention, chunk(T, H, Hkv, D, g["M"])
+    yield "prefill_paged_float32", paged_prefill_attention, chunk(64, H, Hkv, D, g["M"], F32)
     yield "int8_matmul", int8_matmul, [((512, 1024), BF16), ((1024, 1024), jnp.int8), ((1024,), F32)]
 
 
@@ -301,15 +316,21 @@ def test_engine_paged_programs_update_the_pool_in_place(as_chip, v5e, program, c
             positions = start + jnp.arange(C)[None, :]
             valid = (jnp.arange(C) < length)[None, :]
             return paged_forward_with_cache(
-                cfg, params, cache, bt, toks, positions, valid=valid, use_decode_kernel=False)
+                cfg, params, cache, bt, toks, positions, valid=valid, use_decode_kernel=kernel)
 
         args = _abstract([((1, C), I32), ((1, M), I32), ((), I32), ((), I32)], rest)
-    compiled = jax.jit(fn, donate_argnums=(1,), out_shardings=pinned).trace(params, cache, *args).lower(
-        lowering_platforms=("tpu",)).compile()
+    lowered = jax.jit(fn, donate_argnums=(1,), out_shardings=pinned).trace(params, cache, *args).lower(
+        lowering_platforms=("tpu",))
+    # on one chip both programs attend through a Mosaic kernel; under a mesh neither does
+    assert ("tpu_custom_call" in lowered.as_text()) == (chips == 1)
+    compiled = lowered.compile()
     mem = compiled.memory_analysis()  # of one device
     assert mem.alias_size_in_bytes >= 2 * cfg.n_layers * layer_bytes  # both pools donated through
     assert mem.temp_size_in_bytes < layer_bytes, f"{mem.temp_size_in_bytes / 2**20:.1f} MiB of temporaries"
     assert _pool_sized_ops(compiled.as_text(), {layer_elems, cfg.n_layers * layer_elems}) == []
+    if program == "prefill_chunk":
+        # the float32 scores of a chunk over the table's whole capacity: gone with the kernel
+        assert (re.search(rf"f32\[(?:\d+,){{2,}}{C},{M * bs}\]", compiled.as_text()) is None) == (chips == 1)
 
 
 def test_a_windowed_expert_config_compiles_one_in_place_decode_program(as_chip, v5e):
