@@ -67,6 +67,10 @@ FULL = dict(
         ("trinity_full", 32, 4, 128, 512, 3000, 512, 0, 512),
         ("trinity_sliding", 32, 4, 128, 512, 3000, 500, 2048, 512),
     ),
+    # a decode step by diffusion over blocks at the SDAR cell's shape: 48 rows
+    # x 4 positions, 32 query heads over 4 KV heads of 128, ~950 cached tokens
+    # a row in a table of 256 pages
+    block_step=dict(B=48, H=32, Hkv=4, D=128, Bk=4, M=256, tokens=950, reps=64),
     serve=dict(slots=8, seq=1024, requests=16, prompt=64, new=32),
     train=dict(steps=4),
     ring=dict(batch=4, steps=3),
@@ -78,6 +82,7 @@ REHEARSAL = dict(
     decode=dict(B=2, H=4, Hkv=2, D=64, S=128),
     paged=dict(B=2, H=4, Hkv=2, D=64, M=4, bs=16),
     paged_prefill=(("mha", 2, 2, 64, 32, 48, 20, None, 8), ("gqa_sliding", 8, 1, 128, 32, 80, 32, 40, 8)),
+    block_step=dict(B=3, H=8, Hkv=2, D=64, Bk=4, M=8, tokens=70, reps=2),
     serve=dict(slots=4, seq=128, requests=8, prompt=32, new=8),
     train=dict(steps=4),
     ring=dict(batch=2, steps=3),
@@ -286,7 +291,57 @@ def leg_kernels(sz, on_chip):
                 None if window is None else jnp.int32(window))
         out = _compile(prefill_kernel, *args, on_chip=on_chip)(*args)
         _check(f"prefill_paged_{name}", out[:, :length], prefill_xla(*args)[:, :length], FWD_REL_TOL, errs)
-    return {"rel_err": errs, "tolerance": {"fwd": FWD_REL_TOL, "bwd": BWD_REL_TOL}}
+
+    # a block step's attention (generation by diffusion over blocks): every
+    # row's block of Bk queries over its cached tokens and the block itself,
+    # block-causal, through the prefill kernel; one idle row. Checked against
+    # the XLA lines, then timed alone (``reps`` calls chained in one program)
+    # at the query tile it takes (Bk queries: 32 rows a KV head) and at the
+    # prefill kernel's own 16, beside the XLA lines at default precision
+    b = sz["block_step"]
+    B, H, Hkv, D, Bk, M = (b[k] for k in ("B", "H", "Hkv", "D", "Bk", "M"))
+    rng = np.random.default_rng(2)
+    starts = (rng.integers(b["tokens"] // 2, b["tokens"] * 3 // 2, size=B) // Bk * Bk).astype(np.int32)
+    used = int(-(-(starts.max() + Bk) // bs))
+    N = B * used + 1
+    tables = np.zeros((B, M), np.int32)
+    tables[:, :used] = rng.permutation(np.arange(1, N)).reshape(B, used)
+    tables[-1] = 0  # an idle row: its table is the garbage page and it holds nothing
+    lengths = np.full(B, Bk, np.int32)
+    lengths[-1] = 0
+    q = rand(50, (B, Bk, H, D))
+    kp, vp = rand(51, (2, N, bs, Hkv * D)), rand(52, (2, N, bs, Hkv * D))
+    args = (q, kp, vp, jnp.asarray(tables), jnp.asarray(starts), jnp.asarray(lengths), jnp.int32(1))
+
+    def block_attn(use_kernel, q_tile, q, kp, vp, bt, starts, lengths, layer):
+        return paged_prefill_attention(q, kp, vp, bt, starts, lengths, layer, block=Bk, use_kernel=use_kernel,
+                                       q_tile=q_tile)
+
+    def block_ref(*args):
+        with jax.default_matmul_precision("highest"):
+            return block_attn(False, None, *args)
+
+    out = _compile(jax.jit(functools.partial(block_attn, True, None)), *args, on_chip=on_chip)(*args)
+    _check("block_step", out[:-1], jax.jit(block_ref)(*args)[:-1], FWD_REL_TOL, errs)
+
+    def chained(fn):
+        def many(q, *rest):
+            return jax.lax.fori_loop(0, b["reps"], lambda _, q: q + 0 * fn(q, *rest).astype(q.dtype), q)
+
+        return jax.jit(many)
+
+    times = {}
+    for name, fn in (("kernel_tile_of_block", functools.partial(block_attn, True, None)),
+                     ("kernel_tile_16", functools.partial(block_attn, True, 16)),
+                     ("xla_lines", functools.partial(block_attn, False, None))):
+        many = chained(fn)
+        jax.block_until_ready(many(*args))
+        t0 = time.perf_counter()
+        jax.block_until_ready(many(*args))
+        times[name] = round(1e6 * (time.perf_counter() - t0) / b["reps"], 1)
+    visible = int(((starts[:-1] + Bk + bs - 1) // bs * bs).sum())
+    return {"rel_err": errs, "tolerance": {"fwd": FWD_REL_TOL, "bwd": BWD_REL_TOL},
+            "block_step_us_a_call": times, "block_step_kv_bytes": visible * 2 * Hkv * D * 2}
 
 
 # ---------------------------------------------------------------------------

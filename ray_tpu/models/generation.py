@@ -45,6 +45,7 @@ from ray_tpu.models.transformer import (
     block_attn_out,
     block_ffn,
     block_qkv,
+    block_last,
     embed_tokens,
     flash_by_kind,
     in_window,
@@ -285,6 +286,7 @@ def paged_forward_counted(
     valid: Optional[jax.Array] = None,  # [B, T] bool: False = pad, don't cache
     use_decode_kernel: Optional[bool] = None,
     layer_scales: Optional[Dict[str, jax.Array]] = None,
+    with_logits: bool = True,
 ) -> Tuple[jax.Array, KVCache, Dict[str, jax.Array]]:
     """:func:`forward_with_cache` over a paged pool instead of dense rows:
     (logits, cache, the expert layers' counts).
@@ -311,6 +313,15 @@ def paged_forward_counted(
     K/V rows into ``pool[l]`` in place and attention reads them from there,
     so a donated cache is never sliced, re-laid-out or copied.
 
+    A block-causal config (``cfg.block_length`` > 1) sees, per query, the keys
+    to the end of the query's block as far as the call's real tokens reach
+    (:func:`~ray_tpu.ops.decode_attention.block_last`): a chunk's tokens, or
+    the ``block_length`` positions of a block step (:func:`paged_block_step`),
+    are written first and then attended through the pool like any others, and
+    every such call goes through the prefill kernel (or the dense lines).
+    ``with_logits=False`` skips the final norm and the head (logits: None): no
+    token comes from such a config's prefill.
+
     The counts are what the dropless expert layers did with this call's
     ``valid`` tokens (all, where ``valid`` is None): ``{"assignments":
     int32[E], "pairs_hit": int32}``, the (token, choice) pairs each expert
@@ -329,12 +340,15 @@ def paged_forward_counted(
     x = embed_tokens(cfg, params, tokens)
     starts = positions[:, 0]
     kv_pos = jnp.arange(cap)
-    vis = kv_pos[None, None, None, :] <= positions[:, None, :, None]  # [B,1,T,cap]
+    vis = kv_pos[None, None, None, :] <= block_last(positions, cfg.block)[:, None, :, None]  # [B,1,T,cap]
     if use_decode_kernel is None:
         use_decode_kernel = backend.on_tpu()
     # a chunk's real tokens: the padded tail (``valid`` False) wrote to the
     # garbage page and is no key of the prefill kernel's
     lengths = T if valid is None else jnp.broadcast_to(valid, (B, T)).sum(-1)
+    if cfg.block > 1:
+        # a query sees past itself inside its block, but nothing past the call's last real token
+        vis = vis & (kv_pos < jnp.broadcast_to(starts + lengths, (B,))[:, None])[:, None, None, :]
     _refuse_scales_on_two_stacks(cfg, layer_scales)
 
     phys, off = _paged_write_index(block_tables, positions, valid, bs)
@@ -360,7 +374,7 @@ def paged_forward_counted(
         q, k, v = block_qkv(cfg, layer, h, positions, kind)
         kc = kc.at[l, phys, off].set(k.reshape(B * T, -1).astype(kc.dtype))
         vc = vc.at[l, phys, off].set(v.reshape(B * T, -1).astype(vc.dtype))
-        if use_decode_kernel and T == 1:
+        if use_decode_kernel and T == 1 and cfg.block <= 1:
             from ray_tpu.ops.decode_attention import paged_decode_attention
 
             o = paged_decode_attention(
@@ -371,7 +385,7 @@ def paged_forward_counted(
             from ray_tpu.ops.decode_attention import paged_prefill_attention
 
             o = paged_prefill_attention(
-                q, kc, vc, block_tables, starts, lengths, l, sm_scale=scale, window=window
+                q, kc, vc, block_tables, starts, lengths, l, sm_scale=scale, window=window, block=cfg.block
             ).astype(x.dtype)
         else:
             # masked positions contribute exactly-0.0 weight, so page-0
@@ -403,7 +417,8 @@ def paged_forward_counted(
             assignments = assignments + counts.sum(0)
             pairs_hit = pairs_hit + jnp.sum(counts > 0).astype(jnp.int32)
     x, ks, vs = carry
-    return unembed(cfg, params, x), {"k": ks, "v": vs}, {"assignments": assignments, "pairs_hit": pairs_hit}
+    logits = unembed(cfg, params, x) if with_logits else None
+    return logits, {"k": ks, "v": vs}, {"assignments": assignments, "pairs_hit": pairs_hit}
 
 
 def paged_forward_with_cache(*args, **kwargs) -> Tuple[jax.Array, KVCache]:
@@ -425,6 +440,104 @@ def paged_decode_step(
         cfg, params, cache, block_tables, tokens[:, None], positions[:, None], **fw_kwargs
     )
     return logits[:, 0], cache
+
+
+BlockState = Dict[str, jax.Array]
+
+
+def open_blocks(cfg: TransformerConfig, steps: jax.Array, known: Optional[jax.Array] = None,
+                known_tokens: Optional[jax.Array] = None) -> BlockState:
+    """The state a row's block starts in: the first ``known[b]`` positions
+    hold ``known_tokens[b]`` (the tail of a prompt whose length is no
+    multiple of the block), the rest ``cfg.mask_token_id`` and are masked;
+    ``steps[b]`` denoising steps lie ahead. Which positions are masked is
+    state of its own, never a comparison of ids: a prompt may hold the mask
+    id as an ordinary token.
+
+    ``{"toks": int32[B, Bk], "masked": bool[B, Bk], "unmasked_at":
+    int32[B, Bk] (the denoising step, from 1, at which a position took its
+    token; 0: known from the start), "steps": int32[B], "steps_left":
+    int32[B]}``."""
+    B, Bk = steps.shape[0], cfg.block
+    masked = jnp.ones((B, Bk), bool) if known is None else jnp.arange(Bk)[None, :] >= known[:, None]
+    toks = jnp.full((B, Bk), cfg.mask_token_id, jnp.int32)
+    if known_tokens is not None:
+        toks = jnp.where(masked, toks, known_tokens.astype(jnp.int32))
+    steps = steps.astype(jnp.int32)
+    return {"toks": toks, "masked": masked, "unmasked_at": jnp.zeros((B, Bk), jnp.int32),
+            "steps": steps, "steps_left": steps}
+
+
+def select_rows(rows: jax.Array, new: BlockState, old: BlockState) -> BlockState:
+    """``new``'s state in the rows ``rows`` [B] marks, ``old``'s elsewhere."""
+    return {k: jnp.where(rows.reshape((-1,) + (1,) * (old[k].ndim - 1)), new[k], old[k]) for k in old}
+
+
+def unmask_step(cfg: TransformerConfig, logits: jax.Array, state: BlockState, sample=None) -> BlockState:
+    """One denoising step's unmasking (``low_confidence_static``): at each
+    masked position the candidate is ``sample(logits [B*Bk, V])`` (default:
+    the arg-max) over the vocabulary without the mask id, its confidence the
+    softmax probability of the candidate; the ``ceil(masked left / steps
+    left)`` masked positions of highest confidence (ties: the lowest
+    position) take their candidates and never change again. A row with no
+    mask left is left as it is."""
+    B, Bk, V = logits.shape
+    logits = logits.at[..., cfg.mask_token_id].set(-1e30)  # a candidate is never a mask
+    flat = logits.reshape(B * Bk, V)
+    cand = (jnp.argmax(flat, -1) if sample is None else sample(flat)).astype(jnp.int32).reshape(B, Bk)
+    picked = jnp.take_along_axis(logits, cand[..., None], axis=-1)[..., 0]
+    conf = jnp.exp(picked - jax.nn.logsumexp(logits, axis=-1))
+    masked = state["masked"]
+    left = masked.sum(-1).astype(jnp.int32)
+    n = -(-left // jnp.maximum(state["steps_left"], 1))
+    # rank 0: the most confident masked position, the lowest of equals (a stable sort)
+    order = jnp.argsort(-jnp.where(masked, conf, -1.0), axis=-1, stable=True)
+    rank = jnp.argsort(order, axis=-1)
+    take = masked & (rank < n[:, None])
+    step_no = state["steps"] - state["steps_left"] + 1
+    return {
+        "toks": jnp.where(take, cand, state["toks"]),
+        "masked": masked & ~take,
+        "unmasked_at": jnp.where(take, step_no[:, None], state["unmasked_at"]),
+        "steps": state["steps"],
+        "steps_left": jnp.maximum(state["steps_left"] - (left > 0), 0),
+    }
+
+
+def paged_block_step(
+    cfg: TransformerConfig,
+    params: Dict[str, Any],
+    cache: KVCache,
+    block_tables: jax.Array,  # [B, M]
+    state: BlockState,        # :func:`open_blocks`
+    positions: jax.Array,     # [B] the position of each row's block's first token
+    *,
+    live: Optional[jax.Array] = None,  # [B] bool: False = an idle row, nothing written or counted
+    sample=None,
+    **fw_kwargs,
+) -> Tuple[jax.Array, KVCache, BlockState, Dict[str, jax.Array], Dict[str, jax.Array]]:
+    """One decode step of generation by diffusion over blocks: a forward of
+    every row's ``cfg.block_length`` positions as they stand (mask ids among
+    them) over everything committed before them plus the block itself, then
+    :func:`unmask_step`. Returns (logits [B, Bk, V], cache, the state after
+    the step, what the step finished, the expert layers' counts).
+
+    The step writes the block's K/V through the table before it attends, as a
+    prefill chunk does. While a mask is left those rows are tentative: the
+    next step of the same block overwrites them. A row that came in with no
+    mask left runs the **commit**: the same forward on the finished block,
+    whose K/V are therefore final; its logits decide nothing, its tokens are
+    handed back (``{"committed": bool[B], "toks": int32[B, Bk], "unmasked_at":
+    int32[B, Bk]}``) and its state reopens all masked for the block that
+    follows. Nothing before a block's first position is ever written."""
+    B, Bk = state["toks"].shape
+    commit = ~state["masked"].any(-1)
+    pos = positions[:, None] + jnp.arange(Bk, dtype=positions.dtype)[None, :]
+    valid = None if live is None else jnp.broadcast_to(live[:, None], (B, Bk))
+    logits, cache, moe = paged_forward_counted(cfg, params, cache, block_tables, state["toks"], pos, valid=valid, **fw_kwargs)
+    done = {"committed": commit, "toks": state["toks"], "unmasked_at": state["unmasked_at"]}
+    nxt = select_rows(commit, open_blocks(cfg, state["steps"]), unmask_step(cfg, logits, state, sample))
+    return logits, cache, nxt, done, moe
 
 
 def _single_device_params(params) -> bool:

@@ -43,6 +43,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ray_tpu.ops import backend
 from ray_tpu.ops.attention import NEG_INF, flash_attention_with_lse, mha
+from ray_tpu.ops.decode_attention import block_last
 
 
 @dataclasses.dataclass(frozen=True)
@@ -101,8 +102,22 @@ class TransformerConfig:
     route_norm: bool = True             # selected weights / their sum
     route_scale: float = 1.0
     router_bias: bool = False           # per-expert bias added for SELECTION only (params: "router_bias")
+    # generation by diffusion over blocks (SDAR): key j is visible to query i
+    # iff j // block_length <= i // block_length (causal across blocks, full
+    # inside one); 0 or 1 => causal. The serving engine then decodes a block
+    # of block_length positions a step, masked positions holding
+    # mask_token_id until they are unmasked (serve/llm.py)
+    block_length: int = 0
+    mask_token_id: int = 0
 
     def __post_init__(self):
+        if self.block_length > 1:
+            if self.layer_types is not None and "sliding" in self.layer_types:
+                raise ValueError("block_length > 1 has no sliding layers: a window would cut a block")
+            if self.attention == "ring":
+                raise ValueError('block_length > 1 has no attention="ring": the ring kernel is causal')
+            if not 0 <= self.mask_token_id < self.vocab_size:
+                raise ValueError(f"mask_token_id {self.mask_token_id} is not a row of the {self.vocab_size}-row tables")
         if self.head_dim is None:
             if self.d_model % self.n_heads:
                 raise ValueError(f"d_model {self.d_model} not divisible by n_heads {self.n_heads}")
@@ -139,6 +154,11 @@ class TransformerConfig:
     @property
     def kv_heads(self) -> int:
         return self.n_kv_heads or self.n_heads
+
+    @property
+    def block(self) -> int:
+        """Positions a block of the attention mask (and of a decode step) holds; 1: causal."""
+        return max(1, self.block_length)
 
     @property
     def dense_stack(self) -> int:
@@ -396,12 +416,14 @@ def _repeat_kv(x, n_rep: int):
     return jnp.broadcast_to(x[:, :, :, None, :], (B, T, Hkv, n_rep, Dh)).reshape(B, T, Hkv * n_rep, Dh)
 
 
-def _gqa_mha(qt, k, v, *, causal: bool, sm_scale: float, window=None):
+def _gqa_mha(qt, k, v, *, causal: bool, sm_scale: float, window=None, block: int = 1):
     """Grouped-query attention, K/V kept at kv-head width (no materialized
     repeat — decode/train HBM traffic stays 1/n_rep of the MHA layout).
 
     qt: [B, H, T, Dh]; k, v: [B, T, Hkv, Dh]. ``window`` (an int or a traced
-    scalar; 0 or None: none) hides keys at or before ``i - window``."""
+    scalar; 0 or None: none) hides keys at or before ``i - window``.
+    ``block`` > 1: a query also sees the keys after it in its own block of
+    ``block`` positions (:func:`block_last`)."""
     B, H, T, Dh = qt.shape
     Hkv = k.shape[2]
     n_rep = H // Hkv
@@ -411,7 +433,7 @@ def _gqa_mha(qt, k, v, *, causal: bool, sm_scale: float, window=None):
     s = jnp.einsum("bgrtd,bgsd->bgrts", qg, kt, preferred_element_type=jnp.float32) * sm_scale
     if causal:
         S = s.shape[-1]
-        mask = jnp.arange(S)[None, :] <= jnp.arange(T)[:, None]
+        mask = jnp.arange(S)[None, :] <= block_last(jnp.arange(T)[:, None], block)
         if window is not None:
             mask = mask & in_window(jnp.arange(S)[None, :], jnp.arange(T)[:, None], window)
         s = jnp.where(mask[None, None, None], s, NEG_INF)
@@ -434,7 +456,7 @@ def _attention(cfg: TransformerConfig, q, k, v, use_flash: bool, mesh=None, sp_a
     qt = jnp.transpose(q, (0, 2, 1, 3))
     if not use_flash and cfg.attention != "ring":
         # grouped einsum path: K/V never widen to n_heads
-        o = _gqa_mha(qt, k, v, causal=True, sm_scale=1.0 / math.sqrt(cfg.head_dim), window=window)
+        o = _gqa_mha(qt, k, v, causal=True, sm_scale=1.0 / math.sqrt(cfg.head_dim), window=window, block=cfg.block)
         return jnp.transpose(o, (0, 2, 1, 3))
     if window is not None and cfg.attention == "ring":
         raise ValueError('attention="ring" has no sliding window: use "auto", "flash" or "dense" with layer_types')
@@ -694,6 +716,10 @@ def forward(
     use_flash = cfg.attention == "flash" or (
         cfg.attention == "auto" and backend.on_tpu() and act_spec is None
     )
+    if cfg.block > 1:
+        if cfg.attention == "flash":
+            raise ValueError('block_length > 1 has no attention="flash": the flash kernel is causal')
+        use_flash = False  # the grouped einsum carries the block-causal mask
     B, T = tokens.shape
     x = embed_tokens(cfg, params, tokens)
     positions = jnp.broadcast_to(jnp.arange(T)[None, :], (B, T))

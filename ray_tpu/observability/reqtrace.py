@@ -73,6 +73,9 @@ _SEGMENT_FOR_MARK = {
     "engine_submit": "replica",
     "wfq_pop": "engine_queue",
     "admitted": "kv_block_wait",
+    # a diffusion config's first tokens come with its first block's commit,
+    # which is marked just before them: prefill and the block's denoising
+    "first_block_committed": "prefill",
     "first_token": "prefill",
     "kv_migrate": "kv_migrate",
     "finished": "decode",
@@ -112,7 +115,7 @@ class RequestTrace:
     __slots__ = (
         "request_id", "tenant", "deployment", "route", "born_wall", "t0",
         "marks", "outcome", "detail", "tokens", "prefill_chunks", "stalls",
-        "gap_count", "gap_sum", "gap_max", "e2e_s", "done",
+        "gap_count", "gap_sum", "gap_max", "e2e_s", "done", "blocks_committed",
     )
 
     def __init__(self, route: str = "", deployment: str = "",
@@ -134,6 +137,7 @@ class RequestTrace:
         self.gap_max = 0.0
         self.e2e_s = 0.0
         self.done = False
+        self.blocks_committed = 0  # generation by diffusion over blocks: blocks this request committed
 
     # ------------------------------------------------------------ stamps
     def mark(self, name: str) -> None:
@@ -158,6 +162,12 @@ class RequestTrace:
 
     def note_prefill_chunk(self) -> None:
         self.prefill_chunks += 1
+
+    def note_block(self) -> None:
+        """A block of a diffusion config committed (its tokens were noted
+        one by one, with the block's one stamp)."""
+        self.blocks_committed += 1
+        self.mark("first_block_committed")
 
     def note_stall(self) -> None:
         self.stalls += 1
@@ -208,6 +218,7 @@ class RequestTrace:
             "ttft_s": round(ttft, 6) if ttft is not None else None,
             "tokens": self.tokens,
             "prefill_chunks": self.prefill_chunks,
+            "blocks_committed": self.blocks_committed,
             "stalls": self.stalls,
             "inter_token": {
                 "count": self.gap_count,

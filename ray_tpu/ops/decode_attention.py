@@ -54,6 +54,15 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ray_tpu.ops.attention import NEG_INF, _LANES, _use_interpret
 
+
+def block_last(q_pos, block: int):
+    """The last key position the query at ``q_pos`` sees: its own under a
+    causal mask (``block`` <= 1: ``q_pos`` as it is), the last of its block
+    of ``block`` positions under a block-causal one
+    (``TransformerConfig.block_length``)."""
+    return q_pos if block <= 1 else (q_pos // block + 1) * block - 1
+
+
 _MIN_REP = 8  # sublane multiple: pad the n_rep query rows up to one tile
 
 
@@ -528,7 +537,7 @@ _PREFILL_VMEM_BYTES = 48 * 1024 * 1024
 
 def _paged_prefill_kernel(
     tables_ref, starts_ref, lengths_ref, layer_ref,  # scalar-prefetch: [B, M], [B], [B], [1]
-    *refs, sm_scale: float, block_size: int, pack: int, n_rep: int, windowed: bool,
+    *refs, sm_scale: float, block_size: int, pack: int, n_rep: int, windowed: bool, block: int = 1,
 ):
     """Grid (B, T / tq): one grid step a tile of ``tq`` consecutive queries of
     a chunk, all heads. The tile's queries stand at ``start + qi*tq ..``; its
@@ -539,7 +548,9 @@ def _paged_prefill_kernel(
     ``start + length - 1`` is fetched, and a tile wholly past it walks
     nothing and emits zeros. Visibility is positional and per query: key
     ``j`` is seen by the query at ``p`` iff ``j <= p`` and, with a window,
-    ``j > p - window``.
+    ``j > p - window``. ``block`` > 1 (a block-causal config): iff ``j`` is at
+    or before the last position of ``p``'s block of ``block`` positions, and
+    the walk runs to the end of the tile's last query's block.
 
     q_ref/o_ref are ``[Hkv, tq*n_rep, W]``: row ``t*n_rep + r`` is query
     ``t``'s head ``r`` of the KV group, so the ``n_rep`` heads of a group
@@ -555,7 +566,7 @@ def _paged_prefill_kernel(
     span, tq = k_buf.shape[1], rows // n_rep
     p0 = starts_ref[bi] + qi * tq  # the tile's first query's position
     held = jnp.minimum(starts_ref[bi] + lengths_ref[bi], tables_ref.shape[1] * block_size)
-    last = jnp.where(p0 < held, jnp.minimum(held, p0 + tq), 0)
+    last = jnp.where(p0 < held, jnp.minimum(held, block_last(p0 + tq - 1, block) + 1 if block > 1 else p0 + tq), 0)
     first = window_start(p0 + 1, window_ref[0]) if windowed else 0
 
     @pl.when(jnp.logical_and(bi == 0, qi == 0))
@@ -571,7 +582,7 @@ def _paged_prefill_kernel(
 
     def one_group(g, slot):
         pos = g * span + jax.lax.broadcasted_iota(jnp.int32, (1, span), 1)
-        visible = jnp.logical_and(pos <= q_pos, pos < last)  # [rows, span]
+        visible = jnp.logical_and(pos <= block_last(q_pos, block), pos < last)  # [rows, span]
         if windowed:
             visible = jnp.logical_and(visible, pos >= q_first)
         for h in range(n_kv):
@@ -586,7 +597,7 @@ def _paged_prefill_kernel(
     _emit(o_ref, m_scr, l_scr, acc_scr)
 
 
-def _paged_prefill_xla(qg, k_pool, v_pool, block_tables, starts, lengths, layer, scale, n_rep, window=None):
+def _paged_prefill_xla(qg, k_pool, v_pool, block_tables, starts, lengths, layer, scale, n_rep, window=None, block=1):
     """Plain-XLA reference: the dense lines of
     ``models/generation.py::paged_forward_counted`` on a gathered
     [B, Hkv, M*bs, D] view; the kernel is compared against it."""
@@ -596,7 +607,7 @@ def _paged_prefill_xla(qg, k_pool, v_pool, block_tables, starts, lengths, layer,
     s = jnp.einsum("bgtk,bgsk->bgts", qg.astype(jnp.float32), k.astype(jnp.float32)) * scale
     pos = jnp.arange(M * bs)[None, None, :]
     q_pos = (starts[:, None] + jnp.arange(rows)[None, :] // n_rep)[:, :, None]
-    vis = (pos <= q_pos) & (pos < (starts + lengths)[:, None, None])
+    vis = (pos <= block_last(q_pos, block)) & (pos < (starts + lengths)[:, None, None])
     if window is not None:
         vis = vis & (pos >= window_start(q_pos + 1, window))
     s = jnp.where(vis[:, None], s, NEG_INF)
@@ -616,13 +627,20 @@ def paged_prefill_attention(
     sm_scale: Optional[float] = None,
     use_kernel: bool = True,
     window=None,
+    block: int = 1,
+    q_tile: Optional[int] = None,
 ) -> jax.Array:
     """Chunked-prefill attention over one layer of a paged KV pool; returns
     [B, T, H, D]. The chunk's own K/V are in the pool already.
 
     Query ``t`` of row ``b`` stands at position ``starts[b] + t`` and sees
     the keys at or before it (within ``window`` of it: an int or a traced
-    scalar; 0: none; None: the kernel is built without it). The pool is read
+    scalar; 0: none; None: the kernel is built without it). ``block`` > 1:
+    it also sees the keys after it in its own block of ``block`` positions
+    (:func:`block_last`), as far as the chunk's real tokens reach; a decode
+    step by diffusion over blocks is this call with ``T = block``, and its
+    query tile is then the fewest queries whose heads fill whole sublane
+    tiles (``q_tile`` overrides it, for the timing that chose it). The pool is read
     where it lies, a group of 128 tokens at a time, for the pages some query
     of the chunk may see and for no other: table entries past the page of
     ``starts + lengths - 1``, and behind the window of the chunk's first
@@ -652,10 +670,15 @@ def paged_prefill_attention(
         return jnp.transpose(a.reshape(B, Hkv, -1, n_rep, D), (0, 2, 1, 3, 4)).reshape(B, -1, H, D)
 
     if not use_kernel:
-        out = _paged_prefill_xla(grouped(q), k_pool, v_pool, block_tables, starts, lengths, layer, scale, n_rep, window)
+        out = _paged_prefill_xla(
+            grouped(q), k_pool, v_pool, block_tables, starts, lengths, layer, scale, n_rep, window, block)
         return ungrouped(out.astype(q.dtype))
 
-    tq = min(_Q_TILE, -(-T // 16) * 16)  # whole sublane tiles of a 16-bit query
+    # whole sublane tiles of a 16-bit query: 16 queries, or under a block mask
+    # (a decode step of ``block`` positions) the fewest whose n_rep heads a
+    # KV group make a multiple of 16 rows
+    unit = 16 if block <= 1 else 16 // math.gcd(16, n_rep)
+    tq = q_tile or min(_Q_TILE, -(-T // unit) * unit)
     pad_t = (-T) % tq
     if pad_t:
         q = jnp.pad(q, ((0, 0), (0, pad_t), (0, 0), (0, 0)))
@@ -668,7 +691,8 @@ def paged_prefill_attention(
 
     out = _paged_call(
         functools.partial(
-            _paged_prefill_kernel, sm_scale=scale, block_size=bs, pack=pack, n_rep=n_rep, windowed=windowed),
+            _paged_prefill_kernel, sm_scale=scale, block_size=bs, pack=pack, n_rep=n_rep, windowed=windowed,
+            block=block),
         "paged_prefill", scalars, qg, k_pool, v_pool, grid=(B, (T + pad_t) // tq), rows=tq * n_rep,
         q_index=lambda b, i, *_: (b, 0, i, 0), vmem_limit_bytes=_PREFILL_VMEM_BYTES)
     return ungrouped(_own_lanes(out, pack))[:, :T]

@@ -41,6 +41,17 @@ dictated by XLA's static-shape compilation model:
   the device. An EOS or a cancel is seen one step late and costs one
   dropped row-step (``docs/tpu_design.md``, "Paged KV + chunked prefill").
 
+- **Generation by diffusion over blocks.** A config with ``block_length`` > 1
+  (SDAR) switches the decode program to block steps: every row carries its
+  block of ``block_length`` positions (mask ids among them), which positions
+  are masked and its step counters as device-resident state, a step is one
+  forward of the block over everything committed plus the block itself and
+  unmasks the most confident positions inside the program, and a row whose
+  block holds no mask runs the commit forward, whose K/V are final, and
+  yields the block's tokens: 0 tokens a row on a denoise step, up to
+  ``block_length`` on a commit, rows of one batch in different phases. The
+  host knows each row's schedule by count, so the one step in flight stays.
+
 ``LLMServer`` is the Serve-facing wrapper: a deployment class whose
 replicas each own an engine; requests arrive via handle/HTTP and block on a
 per-request Future.
@@ -54,7 +65,7 @@ import time
 from collections import Counter, deque
 from concurrent.futures import Future
 from dataclasses import dataclass, field
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -66,8 +77,11 @@ from ray_tpu.models.generation import (
     export_paged_page,
     filter_top_k_top_p,
     init_paged_cache,
+    open_blocks,
+    paged_block_step,
     paged_cache_spec,
     paged_forward_counted,
+    select_rows,
     write_paged_pages,
 )
 from ray_tpu.models.transformer import TransformerConfig
@@ -83,6 +97,16 @@ from ray_tpu.serve.kv_blocks import BlockAllocator
 from ray_tpu.serve.prefix_cache import PrefixCache
 
 _STREAM_END = object()
+
+
+class TokenBlock(NamedTuple):
+    """What a diffusion config's stream delivers: one committed block's new
+    tokens as one event; ``unmasked_at[i]`` is the denoising step (from 1) at
+    which ``tokens[i]`` took its value."""
+
+    tokens: List[int]
+    unmasked_at: List[int]
+
 
 # prebuilt tag dicts for the per-request admission hot path
 _EVICT_DISCONNECT_TAGS = {"reason": "disconnect"}
@@ -138,10 +162,21 @@ class GenRequest:
     export_mig_id: Optional[str] = None
     import_ticket: Optional[dict] = None
     import_arrays: Optional[Dict[int, Any]] = None
+    # generation by diffusion over blocks: the steps a block is denoised in,
+    # and the host's count of the block under way, kept ahead of the device:
+    # positions of it the prompt's tail made known, and forwards it still
+    # needs (its denoise steps, then the commit)
+    denoising_steps: int = 0
+    block_known: int = 0
+    forwards_left: int = 0
 
     def emit(self, tok: int) -> None:
         if self.stream_queue is not None:
             self.stream_queue.put(tok)
+
+    def emit_block(self, toks: List[int], unmasked_at: List[int]) -> None:
+        if self.stream_queue is not None:
+            self.stream_queue.put(TokenBlock(toks, unmasked_at))
 
 
 class _TokenStream:
@@ -184,10 +219,12 @@ class _Flight:
     request) pairs it decodes for, as they stood at dispatch: by the time
     the tokens are read a slot may be another request's."""
 
-    out: Any  # device int32[B, K]
+    out: Any  # device int32[B, K]; a block step: what it finished (``paged_block_step``)
     moe: list  # the expert layers' counts, where the program returns them
     rows: List[Tuple[int, GenRequest]]
     overlapped: bool  # dispatched while the step before was still unread
+    # a block step: slot -> known positions of the block this step commits
+    commits: Dict[int, int] = field(default_factory=dict)
 
 
 def _bucket(n: int, lo: int = 16, cap: Optional[int] = None) -> int:
@@ -293,6 +330,22 @@ class LLMEngine:
         # emission happen at chunk granularity, and a request finishing
         # mid-chunk discards the tail tokens (identical outputs either way)
         self.decode_chunk = max(1, int(decode_chunk))
+        # positions a decode step carries a row: 1, or a diffusion config's block
+        self._bk = cfg.block
+        if self._bk > 1:
+            # no silent path: what block steps cannot honour yet is refused by name
+            refused = {
+                "decode_chunk > 1 (one block step a program)": self.decode_chunk > 1,
+                "quantize=True": bool(quantize),
+                "mesh (the block step runs the single-device paged kernels)": mesh is not None,
+                f"kv_block_size {self.kv_block_size} (a page holds whole blocks of {self._bk})":
+                    self.kv_block_size % self._bk != 0,
+                f"max_seq_len {self.S} (whole blocks of {self._bk})": self.S % self._bk != 0,
+            }
+            bad = [k for k, v in refused.items() if v]
+            if bad:
+                raise ValueError(f"a config with block_length {self._bk} (generation by diffusion over blocks) "
+                                 f"cannot be served with " + "; ".join(bad))
         self.top_k = top_k
         self.top_p = top_p
         self.quantized = quantize
@@ -382,6 +435,14 @@ class LLMEngine:
         # row's token from here where it is >= 0. ``_pos`` is the position
         # the NEXT dispatch writes: it advances at dispatch, not at readback
         self._join_tok = np.full(self.B, -1, np.int32)
+        # a diffusion config's rows join with their first block instead: the
+        # prompt's tail as known positions, and the request's steps a block
+        self._join = {
+            "row": np.zeros(self.B, bool),
+            "known": np.zeros(self.B, np.int32),
+            "toks": np.zeros((self.B, self._bk), np.int32),
+            "steps": np.ones(self.B, np.int32),
+        }
         self._pos = np.zeros(self.B, np.int32)
         self._temps = np.zeros(self.B, np.float32)
         self._active = np.zeros(self.B, bool)
@@ -412,6 +473,14 @@ class LLMEngine:
         self._flight: Optional[_Flight] = None
         self._decode_steps_overlapped = 0
         self._decode_row_steps_discarded = 0
+        # block steps (a diffusion config): forwards of live rows (denoise and
+        # commit), blocks committed, tokens they emitted and positions they
+        # unmasked, and blocks a cancelled row left uncommitted
+        self._block_row_forwards = 0
+        self._block_commits = 0
+        self._tokens_emitted = 0
+        self._tokens_unmasked = 0
+        self._blocks_dropped = 0
         # the dropless expert layers' own counters (models/generation.py,
         # ``paged_forward_counted``): the prefill and decode programs return
         # them beside the tokens and the loop adds them up when it reads the
@@ -473,6 +542,7 @@ class LLMEngine:
         # row's last token as a device array, which the next run takes as
         # it is: the loop dispatches that run before it reads this one's.
         K_chunk = self.decode_chunk
+        block = self._bk
 
         @functools.partial(jax.jit, donate_argnums=(1,), out_shardings=pool_among(2))
         def _prefill_chunk(params, cache, toks, bt, start, length):
@@ -487,12 +557,37 @@ class LLMEngine:
             logits, cache, moe = paged_forward_counted(
                 cfg_, params, cache, bt, toks, positions,
                 valid=valid, layer_scales=layer_scales, use_decode_kernel=use_kernel,
+                with_logits=block == 1,
             )
-            last = jax.lax.dynamic_index_in_dim(logits[0], length - 1, 0, keepdims=False)
+            if logits is None:
+                # no token comes from a diffusion config's prefill: the head is
+                # not run, and what is waited for is a word of the written pool
+                last = cache["k"][0, 0, 0, :1].astype(jnp.float32)
+            else:
+                last = jax.lax.dynamic_index_in_dim(logits[0], length - 1, 0, keepdims=False)
             return (last, cache, moe) if moe_counted else (last, cache)
+
+        def _block_step(params, cache, state, join, pos, temps, key, bt):
+            """The decode program of a diffusion config: one block step
+            (``models/generation.paged_block_step``). ``state``: the rows'
+            blocks as the last run left them, never read by the host in
+            between; ``join["row"]`` where the host opened a row's first
+            block since then. Hands back what the step finished (which rows
+            committed, and their tokens), the pool, the key and the state."""
+            state = select_rows(join["row"], open_blocks(cfg_, join["steps"], join["known"], join["toks"]), state)
+            key, sub = jax.random.split(key)
+            _, cache, state, done, moe = paged_block_step(
+                cfg_, params, cache, bt, state, pos, live=bt[:, 0] > 0,
+                sample=lambda flat: _sample_impl(sub, flat, jnp.repeat(temps, block)),
+                use_decode_kernel=use_kernel,
+            )
+            out = (done, cache, key, state)
+            return out + (moe,) if moe_counted else out
 
         @functools.partial(jax.jit, donate_argnums=(1,), out_shardings=pool_among(4))
         def _decode_k_paged(params, cache, toks, join, pos, temps, key, bt):
+            if block > 1:
+                return _block_step(params, cache, toks, join, pos, temps, key, bt)
             # ``toks``: what the last run of this program returned, never
             # read by the host in between; ``join`` >= 0 where the host
             # sampled a row's token itself since then (its first)
@@ -550,9 +645,14 @@ class LLMEngine:
         eos_id: Optional[int] = None,
         tenant: Optional[str] = None,
         deadline_ts: Optional[float] = None,
+        denoising_steps: Optional[int] = None,
         _stream_queue=None,
     ) -> Future:
         """Enqueue one request; resolves to the generated token-id list.
+
+        ``denoising_steps`` (a diffusion config only; default: its
+        ``block_length``): the steps a block is denoised in, 1 to
+        ``block_length``; fewer steps, fewer forwards a token.
 
         ``tenant`` (default: the request-context tenant id set by the
         ingress) keys weighted fair queuing; ``deadline_ts`` (default: the
@@ -566,6 +666,7 @@ class LLMEngine:
             eos_id=eos_id,
             tenant=tenant,
             deadline_ts=deadline_ts,
+            denoising_steps=denoising_steps,
             _stream_queue=_stream_queue,
         ).future
 
@@ -578,6 +679,7 @@ class LLMEngine:
         eos_id: Optional[int] = None,
         tenant: Optional[str] = None,
         deadline_ts: Optional[float] = None,
+        denoising_steps: Optional[int] = None,
         _stream_queue=None,
         _export_mig_id: Optional[str] = None,
         _import_ticket: Optional[dict] = None,
@@ -594,11 +696,24 @@ class LLMEngine:
                 f"prompt ({len(prompt)}) + max_tokens ({max_tokens}) exceeds "
                 f"engine max_seq_len {self.S}"
             )
+        if self._bk == 1:
+            if denoising_steps is not None:
+                raise ValueError("denoising_steps belongs to a config that generates by diffusion over blocks "
+                                 "(block_length > 1); this engine's config is autoregressive")
+        else:
+            if _export_mig_id is not None or _import_ticket is not None:
+                raise ValueError("prefill_export / adopt_migration are not supported for a config that generates "
+                                 "by diffusion over blocks: an exported prefill carries no first token")
+            if denoising_steps is None:
+                denoising_steps = self._bk
+            if not 1 <= int(denoising_steps) <= self._bk:
+                raise ValueError(f"denoising_steps must be 1 to the config's block_length {self._bk}, "
+                                 f"got {denoising_steps}")
         # never-fits contract (same as max_queued_prefill_tokens below):
         # a request needing more pages than the POOL holds can never be
         # admitted — that is a config/input error at submit, not a
         # retry-after-able overload and not a failure deep in prefill
-        needed = -(-(len(prompt) + max_tokens - 1) // self.kv_block_size)
+        needed = self._pages_needed(len(prompt), max_tokens)
         if needed > self._allocator.capacity:
             raise ValueError(
                 f"prompt ({len(prompt)}) + max_tokens ({max_tokens}) needs "
@@ -660,6 +775,7 @@ class LLMEngine:
                 stream_queue=_stream_queue, tenant=tenant,
                 deadline_ts=deadline_ts, trace=trace,
             )
+            req.denoising_steps = int(denoising_steps or 0)
             req.export_mig_id = _export_mig_id
             req.import_ticket = _import_ticket
             req.import_arrays = _import_arrays
@@ -672,23 +788,40 @@ class LLMEngine:
         self._wake.set()
         return req
 
+    def _pages_needed(self, prompt_len: int, max_tokens: int) -> int:
+        """Pages a request's whole budget takes. The last written position
+        is ``prompt + max_tokens - 2`` (the last sampled token is never
+        written); a diffusion config commits every emitted token and writes
+        its last block whole, to the end of the block that holds position
+        ``prompt + max_tokens - 1`` (a page holds whole blocks)."""
+        last = prompt_len + max_tokens - (2 if self._bk == 1 else 1)
+        return last // self.kv_block_size + 1
+
+    def _fill_len(self, req: GenRequest) -> int:
+        """Prompt tokens prefill has to cache: all of them, or for a
+        diffusion config the prompt's whole blocks (the rest opens the
+        first block as known positions)."""
+        return len(req.prompt) - len(req.prompt) % self._bk
+
     def generate(self, prompt: List[int], **kw) -> List[int]:
         return self.submit(prompt, **kw).result()
 
-    def submit_stream(self, prompt: List[int], *, token_timeout_s: float = 120.0, **kw):
+    def submit_stream(self, prompt: List[int], *, token_timeout_s: float = 120.0, blocks: bool = False, **kw):
         """Per-token streaming: returns an iterator yielding token ids as
         they are sampled (the continuous-batching analog of the runtime's
         ObjectRefGenerator). Validation errors raise HERE, not mid-stream.
         The iterator ends at eos/max_tokens; engine errors re-raise at the
         end of iteration; a stalled engine raises after ``token_timeout_s``
-        without a token (so consumers never block forever)."""
+        without a token (so consumers never block forever). A diffusion
+        config delivers a committed block a time: its tokens one after the
+        other, or with ``blocks`` each block as one :class:`TokenBlock`."""
         import queue as _queue
 
         q: "_queue.Queue" = _queue.Queue()
         req = self._submit_req(prompt, _stream_queue=q, **kw)
-        return _TokenStream(self._stream_iter(req, q, token_timeout_s), req, self)
+        return _TokenStream(self._stream_iter(req, q, token_timeout_s, blocks), req, self)
 
-    def _stream_iter(self, req: GenRequest, q, token_timeout_s: float = 120.0):
+    def _stream_iter(self, req: GenRequest, q, token_timeout_s: float = 120.0, blocks: bool = False):
         """Generator draining ``req``'s stream queue until ``_STREAM_END``
         (shared by submit_stream and the disagg adopt-stream path)."""
         import queue as _queue
@@ -706,7 +839,10 @@ class LLMEngine:
                 if exc is not None:
                     raise exc
                 return
-            yield tok
+            if isinstance(tok, TokenBlock) and not blocks:
+                yield from tok.tokens
+            else:
+                yield tok
 
     def _abandon_stream(self, req: GenRequest) -> None:
         """Consumer gone: if the request is still WAITING, drop it from the
@@ -861,7 +997,25 @@ class LLMEngine:
                 "kv_read_share": self.kv_read_share(),
                 "kv_live_pages": self.kv_live_pages(),
                 **self._moe_stats_locked(),
+                **self._block_stats_locked(),
             }
+
+    def _block_stats_locked(self) -> Dict[str, Any]:
+        """A diffusion config's own counters (absent otherwise): block steps
+        (its decode steps), forwards of live rows in them (denoise and
+        commit), blocks committed, the tokens they emitted and the positions
+        they unmasked, and blocks a cancelled row left uncommitted."""
+        if self._bk == 1:
+            return {}
+        return {
+            "block_length": self._bk,
+            "block_steps": self._decode_step_count,
+            "block_row_forwards": self._block_row_forwards,
+            "block_commits": self._block_commits,
+            "tokens_emitted": self._tokens_emitted,
+            "tokens_unmasked": self._tokens_unmasked,
+            "blocks_dropped": self._blocks_dropped,
+        }
 
     def _moe_stats_locked(self) -> Dict[str, Any]:
         """The expert layers' running totals (absent for a config without
@@ -891,7 +1045,8 @@ class LLMEngine:
         toks = jax.ShapeDtypeStruct((self.B,), jnp.int32)
         temps = jax.ShapeDtypeStruct((self.B,), jnp.float32)
         bt = jax.ShapeDtypeStruct(self._block_tables.shape, jnp.int32)
-        return self._decode_k_paged.lower(params, cache, toks, toks, toks, temps, self._key, bt).as_text()
+        state, join = (toks, toks) if self._bk == 1 else jax.tree.map(abstract, (self._dev_toks, self._join_arrays()))
+        return self._decode_k_paged.lower(params, cache, state, join, toks, temps, self._key, bt).as_text()
 
     def admission_snapshot(self) -> Dict[str, Any]:
         """Bounds + depths for GET /api/overload (admission source)."""
@@ -1032,6 +1187,26 @@ class LLMEngine:
         if req.trace is not None:
             req.trace.note_token(gap)
 
+    def _note_block(self, req: GenRequest, n: int) -> None:
+        """A committed block's ``n`` tokens leave the engine at one instant:
+        the sketches take them with one stamp (the first carries the gap
+        since the block before, or the first-token time; the rest gaps of 0)."""
+        first = not req.t_first
+        if req.trace is not None:
+            req.trace.note_block()  # marks first_block_committed, ahead of first_token
+        if first:
+            self._note_first_token(req)
+        now = time.perf_counter()
+        gap = now - req.t_last_tok
+        req.t_last_tok = now
+        inter = self._sketches["inter_token"]
+        for k in range(1 if first else 0, n):
+            g = gap if k == 0 else 0.0
+            inter.observe(g)
+            metric_defs.LLM_INTER_TOKEN.observe(g, self._depth_tags)
+            if req.trace is not None:
+                req.trace.note_token(g)
+
     def _note_stall(self) -> None:
         """A prefill forward just stalled every running decode slot: count
         the stall on each stalled request's trace (the decoding requests
@@ -1157,15 +1332,17 @@ class LLMEngine:
                 return
             req, free = popped
             tp = len(req.prompt)
-            total = -(-(tp + req.max_tokens - 1) // bs)
+            total = self._pages_needed(tp, req.max_tokens)
             with self._lock:
                 pages: List[int] = []
                 matched = 0
                 if self._prefix is not None:
                     pages, matched = self._prefix.match(req.prompt)
                 cow_src = -1
-                if matched == tp:
-                    # full-prompt hit: the tail block must be writable
+                if matched == tp and self._bk == 1:
+                    # full-prompt hit: the tail block must be writable (a
+                    # diffusion config recomputes nothing: no token comes
+                    # from its prefill, and its first block opens a page)
                     cow_src = pages.pop()
                     matched -= bs
                 # pin the hit region (and the COW source) FIRST: the
@@ -1248,6 +1425,13 @@ class LLMEngine:
                 # for every running stream (the loop re-admits next tick)
                 self._adopt_admitted(req, had_cow=cow_src >= 0)
                 return
+            if req.prefill_pos >= self._fill_len(req):
+                # a diffusion config's prompt of whole cached pages, or one
+                # shorter than a block: nothing to prefill
+                with self._lock:
+                    self._prefill_count += 1
+                self._finish_prefill(req, None)
+                continue
             with self._lock:
                 self._prefilling.append(req)
 
@@ -1255,6 +1439,9 @@ class LLMEngine:
         """Prompt is fully in the paged cache: sample the first token and
         hand the slot to the decode batch (or, for an export request,
         stage the block set for migration instead)."""
+        if self._bk > 1:
+            self._open_first_block(req)
+            return
         tp = len(req.prompt)
         self._key, sub = jax.random.split(self._key)
         tok0 = int(
@@ -1277,6 +1464,32 @@ class LLMEngine:
             self._pos[slot] = tp
             self._temps[slot] = req.temperature
         self._maybe_finish(req, tok0)
+
+    def _open_first_block(self, req: GenRequest) -> None:
+        """A diffusion config's prompt is in the paged cache as far as its
+        whole blocks go: hand the slot to the decode batch with the first
+        block opened, the prompt's tail as its known positions. No token
+        comes from prefill; the first ones come with the block's commit."""
+        fill = self._fill_len(req)
+        known = len(req.prompt) - fill
+        req.block_known = known
+        req.forwards_left = min(self._bk - known, req.denoising_steps) + 1
+        with self._lock:
+            slot = req.slot
+            self._slots[slot] = req
+            self._active[slot] = True
+            self._reserved[slot] = False
+            self._join["row"][slot] = True
+            self._join["known"][slot] = known
+            self._join["toks"][slot, :known] = req.prompt[fill:]
+            self._join["steps"][slot] = req.denoising_steps
+            self._pos[slot] = fill
+            self._temps[slot] = req.temperature
+
+    def _join_arrays(self):
+        """Copies of the join mirrors for the device (a transfer may read its
+        host buffer after the call returns, and the mirrors change at once)."""
+        return {k: jnp.asarray(v.copy()) for k, v in self._join.items()}
 
     def _adopt_admitted(self, req: GenRequest, *, had_cow: bool) -> None:
         """Activate an admitted IMPORT request: write the pulled block
@@ -1483,7 +1696,9 @@ class LLMEngine:
         # every token before it was — cache exactly those full blocks. (A
         # step in flight past an EOS writes position len(cached) and up:
         # in no full block of ``cached``, so never in a published page)
-        cached = req.prompt + req.generated[:-1]
+        # A diffusion config committed every token it emitted; what its last
+        # block holds past them was dropped, so that block's page is not full
+        cached = req.prompt + (req.generated if self._bk > 1 else req.generated[:-1])
         adopted, evicted = self._prefix.insert(cached, blocks, self._evictable)
         if evicted:
             self._allocator.free(evicted)
@@ -1554,7 +1769,7 @@ class LLMEngine:
             req = self._prefilling[0]
             gauges = self._pool_gauges_locked()
         self._publish_pool_gauges(*gauges)
-        tp = len(req.prompt)
+        tp = self._fill_len(req)
         start = req.prefill_pos
         chunk = self.prefill_chunk_tokens
         # one-shot width buckets the UNCACHED suffix, not the whole prompt:
@@ -1612,7 +1827,7 @@ class LLMEngine:
             self._prefill_kv_visited += visited
             self._prefill_kv_capacity += capacity
         req.prefill_pos += n
-        if req.prefill_pos < len(req.prompt):
+        if req.prefill_pos < self._fill_len(req):
             return
         with self._lock:
             self._prefilling.pop(0)
@@ -1668,7 +1883,7 @@ class LLMEngine:
         from the page of its window's first position on. What the paged
         decode kernel's work follows."""
         bs = self.kv_block_size
-        lens = self._pos[self._active].astype(np.int64) + 1
+        lens = self._pos[self._active].astype(np.int64) + self._bk  # to the end of the step's writes
         last = -(-lens // bs)
         windows = self.cfg.layer_windows or (0,)
         visited = sum(int((last - np.maximum(lens - w, 0) // bs).sum()) if w else int(last.sum()) for w in windows)
@@ -1701,6 +1916,8 @@ class LLMEngine:
         its own previous run (``_dev_toks``) or, for a row that joined since,
         from ``_join_tok``. Positions and the ``max_tokens`` count advance
         here, so the next step can be dispatched before this one is read."""
+        if self._bk > 1:
+            return self._dispatch_blocks()
         K = self.decode_chunk
         rows: List[Tuple[int, GenRequest]] = []
         live = np.zeros(self.B, bool)
@@ -1734,12 +1951,88 @@ class LLMEngine:
             self._pos[i] += K
         return _Flight(out, moe, rows, overlapped=self._flight is not None)
 
+    def _dispatch_blocks(self) -> Optional[_Flight]:
+        """:meth:`_dispatch` for a diffusion config: enqueue one block step
+        for every row that still owes a token. A row's schedule is known by
+        count (a block of ``m`` masked positions takes ``min(m, steps)``
+        denoise forwards, then the commit), so positions and the
+        ``max_tokens`` count advance here, at the commit's dispatch, ahead of
+        the device; which rows committed is read back with the tokens."""
+        Bk = self._bk
+        rows: List[Tuple[int, GenRequest]] = []
+        live = np.zeros(self.B, bool)
+        # rt-lint: disable=lock-discipline -- engine-thread-owned (see _dispatch)
+        for i, req in enumerate(self._slots):
+            if req is None or req.dispatched >= req.max_tokens:
+                continue  # free, or its last commit is in flight: known by count
+            # the step writes the block's positions, tentative or final, into
+            # pages only this row holds: copy-on-write before the first write
+            self._cow_shared_writes(i, int(self._pos[i]), Bk)
+            rows.append((i, req))
+            live[i] = True
+        if not rows:
+            return None
+        bt = jnp.asarray(self._block_tables * live[:, None].astype(np.int32))
+        done, self._cache, self._key, self._dev_toks, *moe = self._decode_k_paged(
+            self.params, self._cache, self._dev_toks, self._join_arrays(),
+            jnp.asarray(self._pos.copy()), jnp.asarray(self._temps.copy()), self._key, bt,
+        )
+        self._join["row"][:] = False
+        commits: Dict[int, int] = {}
+        for i, req in rows:
+            req.forwards_left -= 1
+            if req.forwards_left == 0:  # this step commits the row's block; the next opens all masked
+                commits[i] = req.block_known
+                req.dispatched += Bk - req.block_known
+                self._pos[i] += Bk
+                req.block_known = 0
+                req.forwards_left = min(Bk, req.denoising_steps) + 1
+        return _Flight(done, moe, rows, overlapped=self._flight is not None, commits=commits)
+
+    def _collect_blocks(self, flight: _Flight) -> None:
+        """:meth:`_collect` for a diffusion config: a row yields nothing on a
+        denoise step and its block's new tokens, as one stream event, on a
+        commit (cut at ``max_tokens`` or after an EOS: what the block holds
+        beyond is dropped)."""
+        done = jax.device_get(flight.out)
+        self._decode_step_count += 1
+        if flight.overlapped:
+            self._decode_steps_overlapped += 1
+            metric_defs.LLM_DECODE_STEPS_OVERLAPPED.inc(1)
+        self._note_moe(flight.moe, decode=True)
+        # rt-lint: disable=lock-discipline -- engine-thread-owned (see _dispatch)
+        rows = [(i, req) for i, req in flight.rows if self._slots[i] is req and not req.cancelled]
+        dropped = len(flight.rows) - len(rows)
+        if dropped:
+            self._decode_row_steps_discarded += dropped
+            metric_defs.LLM_DECODE_ROW_STEPS_DISCARDED.inc(dropped)
+        self._block_row_forwards += len(rows)
+        for i, req in rows:
+            known = flight.commits.get(i)
+            if bool(done["committed"][i]) != (known is not None):
+                raise RuntimeError(f"block step out of step with its schedule in slot {i}: the device "
+                                   f"{'committed' if known is None else 'did not commit'} a block")
+            if known is None:
+                continue
+            toks = done["toks"][i, known:].tolist()[: req.max_tokens - len(req.generated)]
+            if req.eos_id is not None and req.eos_id in toks:
+                toks = toks[: toks.index(req.eos_id) + 1]
+            req.generated.extend(toks)
+            self._block_commits += 1
+            self._tokens_emitted += len(toks)
+            self._tokens_unmasked += self._bk - known
+            self._note_block(req, len(toks))
+            req.emit_block(toks, done["unmasked_at"][i, known : known + len(toks)].tolist())
+            self._maybe_finish(req, toks[-1])
+
     def _collect(self, flight: _Flight) -> None:
         """Read a dispatched step's tokens (this waits for that step only,
         not for one dispatched after it) and emit them. A row whose request
         left its slot since the dispatch (an EOS read one step late, a
         cancelled stream evicted) is dropped whole, by identity: the slot
         may be another request's by now."""
+        if self._bk > 1:
+            return self._collect_blocks(flight)
         sampled = np.asarray(flight.out)  # [B, K]
         K = sampled.shape[1]
         self._decode_step_count += K
@@ -1776,6 +2069,8 @@ class LLMEngine:
         # part of the same device state (a step that failed in flight leaves
         # its outputs poisoned). No row reads it before joining from the host
         self._dev_toks = jnp.zeros(self.B, jnp.int32)
+        if self._bk > 1:  # a diffusion config: the rows' blocks (models/generation.open_blocks)
+            self._dev_toks = open_blocks(self.cfg, jnp.ones(self.B, jnp.int32))
 
     def _fail_inflight(self, error: BaseException) -> None:
         """Fail every queued, prefilling, and in-slot request (loop-crash
@@ -1804,6 +2099,7 @@ class LLMEngine:
         # (engine-thread state, like the loop that dispatched it)
         self._flight = None
         self._join_tok[:] = -1
+        self._join["row"][:] = False
         metric_defs.ADMISSION_QUEUE_DEPTH.set(0, self._depth_tags)
         self._publish_pool_gauges(0, 0, 0)
         for r in victims:
@@ -1827,6 +2123,10 @@ class LLMEngine:
                 self._slots[i] = None
                 self._active[i] = False
                 self._release_blocks_locked(i)
+            if self._bk > 1:
+                # a row of a diffusion config always has a block under way:
+                # its tentative K/V go with its pages, which nothing shared
+                self._blocks_dropped += len(victims)
             gauges = self._pool_gauges_locked()
         if victims:
             self._publish_pool_gauges(*gauges)
@@ -1890,6 +2190,13 @@ class LLMServer:
         handle = serve.run(app)
         handle.remote({"prompt": [1,2,3], "max_tokens": 16}).result()
         handle.remote({"text": "once upon", "max_tokens": 16}).result()
+
+    A config with ``block_length`` > 1 generates by diffusion over blocks: a
+    request may pass ``denoising_steps`` (1 to ``block_length``, default
+    ``block_length``), ``max_tokens`` need be no multiple of the block (what
+    the last block holds beyond it is dropped), and a stream delivers a
+    committed block a time, as one event ``{"tokens": [...], "unmasked_at":
+    [...]}`` (the denoising step at which each token took its value).
     """
 
     def __init__(
@@ -1956,16 +2263,22 @@ class LLMServer:
             temperature=float(request.get("temperature", 0.0)),
             eos_id=request.get("eos_id"),
         )
+        if request.get("denoising_steps") is not None:
+            kw["denoising_steps"] = int(request["denoising_steps"])
         if request.get("stream"):
             # submit EAGERLY so validation errors surface as a normal error
             # response, not mid-stream corruption after a 200 was sent;
             # the returned generator of per-token events reaches the proxy
             # by reference (in-proc replicas) and renders as SSE
-            stream = self.engine.submit_stream(prompt, **kw)
+            stream = self.engine.submit_stream(prompt, blocks=True, **kw)
 
             def events():
                 n = 0
                 for tok in stream:
+                    if isinstance(tok, TokenBlock):  # a diffusion config: a committed block a time
+                        n += len(tok.tokens)
+                        yield {"tokens": tok.tokens, "unmasked_at": tok.unmasked_at}
+                        continue
                     n += 1
                     yield {"token": tok}
                 yield {"done": True, "num_generated": n}
@@ -2261,6 +2574,15 @@ class OpenAICompatLLMServer(LLMServer):
                 bad.append(k)
         if body.get("echo"):
             bad.append("echo")
+        if self.engine.cfg.block > 1:
+            # generation by diffusion over blocks: a token comes from a
+            # confidence schedule over several forwards of its block, not from
+            # one next-token distribution, so nothing that rests on that
+            # distribution can be honoured, now or by a later sampler: say so
+            why = (f" (the model generates by diffusion over blocks of {self.engine.cfg.block}: "
+                   "no next-token distribution a position)")
+            bad = [b + why if b in ("logprobs", "n > 1", "best_of > 1") else b for b in bad]
+            bad += [k + why for k in ("top_logprobs", "logit_bias") if body.get(k)]
         if bad:
             raise ValueError(
                 "unsupported OpenAI parameter(s): " + ", ".join(bad)

@@ -118,6 +118,16 @@ def _kernel_cases():
     for T in (16, 200, 1024):
         yield f"prefill_paged_T{T}", paged_prefill_attention, chunk(T, H, Hkv, D, g["M"])
     yield "prefill_paged_float32", paged_prefill_attention, chunk(64, H, Hkv, D, g["M"], F32)
+    # generation by diffusion over blocks at the SDAR cell's shapes (32 query
+    # heads over 4 KV heads of 128, a table of 256 pages): the prefill chunk
+    # under the block-causal mask, and the block step, 48 rows x 4 positions
+    def block_causal(q, kp, vp, bt, start, length, layer):
+        return paged_prefill_attention(q, kp, vp, bt, start, length, layer, block=4)
+
+    yield "prefill_paged_sdar_chunk", block_causal, chunk(512, 32, 4, 128, 256)
+    pool = ((2, 48 * 64 + 1, g["bs"], 4 * 128), BF16)
+    yield "block_step_sdar", block_causal, [((48, 4, 32, 128), BF16), pool, pool, ((48, 256), I32), ((48,), I32),
+                                            ((48,), I32), ((), I32)]
     yield "int8_matmul", int8_matmul, [((512, 1024), BF16), ((1024, 1024), jnp.int8), ((1024,), F32)]
 
 
@@ -368,6 +378,48 @@ def test_a_windowed_expert_config_compiles_one_in_place_decode_program(as_chip, 
     assert mem.alias_size_in_bytes >= 2 * cfg.n_layers * layer_elems * 2
     assert _pool_sized_ops(compiled.as_text(), {layer_elems, cfg.n_layers * layer_elems}) == []
     assert dataclasses.replace(cfg, scan_layers=False).layer_windows == (2048, 2048, 2048, 0, 2048)
+
+
+def test_the_sdar_cells_block_step_and_prefill_chunk_compile_for_v5e_and_fit(as_chip, v5e):
+    """The benchmark's diffusion configuration as its file states it (6 layers
+    of 128 experts at published widths, 48 slots, 12 288 pages): the engine's
+    own decode program, a block step of 48 rows x 4 positions with its state
+    on the device, and its prefill chunk, which runs no head, compile for a
+    v5e with the Mosaic kernel in them, update the donated pool in place and
+    fit the chip's 16 GB."""
+    from benchmark import system
+    from ray_tpu.models import init_params
+    from ray_tpu.models.generation import init_paged_cache, open_blocks, paged_block_step, paged_forward_counted
+
+    config = system.load_json("benchmark/configs/sdar-30b-a3b-serve-l6.json")
+    run = config["run"]
+    cfg = system.model_module(config).program_config(
+        config, max_seq_len=run["max_seq_len"], dtype=run["dtype"], param_dtype=run["param_dtype"])
+    one = SingleDeviceSharding(v5e[0])
+    B, bs, C = run["max_batch_size"], run["kv_block_size"], run["prefill_chunk_tokens"]
+    M = run["max_seq_len"] // bs
+    params = _abstract_tree(lambda: init_params(cfg, jax.random.key(0)), one)
+    cache = _abstract_tree(lambda: init_paged_cache(cfg, run["kv_num_blocks"], bs), one)
+    state = _abstract_tree(lambda: open_blocks(cfg, jnp.ones(B, I32)), one)
+    pos, bt, toks, row, scalar = _abstract([((B,), I32), ((B, M), I32), ((1, C), I32), ((1, M), I32), ((), I32)], one)
+    pool_bytes = sum(int(np.prod(x.shape)) * x.dtype.itemsize for x in jax.tree.leaves(cache))
+
+    def step(params, cache, state, pos, bt):
+        _, cache, state, done, moe = paged_block_step(cfg, params, cache, bt, state, pos, live=bt[:, 0] > 0)
+        return done, cache, state, moe
+
+    def chunk(params, cache, toks, bt, start, length):
+        valid = (jnp.arange(C) < length)[None, :]
+        _, cache, moe = paged_forward_counted(cfg, params, cache, bt, toks, start + jnp.arange(C)[None, :],
+                                              valid=valid, with_logits=False)
+        return cache, moe
+
+    for fn, args in ((step, (params, cache, state, pos, bt)), (chunk, (params, cache, toks, row, scalar, scalar))):
+        lowered = jax.jit(fn, donate_argnums=(1,)).trace(*args).lower(lowering_platforms=("tpu",))
+        assert "tpu_custom_call" in lowered.as_text()
+        m = lowered.compile().memory_analysis()
+        assert m.alias_size_in_bytes >= pool_bytes
+        assert m.argument_size_in_bytes + m.temp_size_in_bytes + m.output_size_in_bytes - m.alias_size_in_bytes < 15.5e9
 
 
 # --------------------------------------------------------------------------
