@@ -71,6 +71,16 @@ FULL = dict(
     # x 4 positions, 32 query heads over 4 KV heads of 128, ~950 cached tokens
     # a row in a table of 256 pages
     block_step=dict(B=48, H=32, Hkv=4, D=128, Bk=4, M=256, tokens=950, reps=64),
+    # (name, tokens, a, b, layers, experts, k, live tokens): the expert layer's
+    # up projection at the served MoE cells' shapes: a block step's 48 x 4
+    # tokens and a prefill chunk's 512 over all 128 experts of one layer of
+    # the stack, and a decode step's 48 rows when two sequences are live
+    # (idle rows follow the first's experts)
+    grouped=dict(reps=100, shapes=(
+        ("sdar_block_step", 192, 2048, 768, 6, 128, 8, 192),
+        ("trinity_chunk", 512, 2048, 1024, 4, 128, 8, 512),
+        ("trinity_decode", 48, 2048, 1024, 4, 128, 8, 2),
+    )),
     serve=dict(slots=8, seq=1024, requests=16, prompt=64, new=32),
     train=dict(steps=4),
     ring=dict(batch=4, steps=3),
@@ -83,6 +93,7 @@ REHEARSAL = dict(
     paged=dict(B=2, H=4, Hkv=2, D=64, M=4, bs=16),
     paged_prefill=(("mha", 2, 2, 64, 32, 48, 20, None, 8), ("gqa_sliding", 8, 1, 128, 32, 80, 32, 40, 8)),
     block_step=dict(B=3, H=8, Hkv=2, D=64, Bk=4, M=8, tokens=70, reps=2),
+    grouped=dict(reps=2, shapes=(("all_live", 24, 128, 128, 2, 8, 2, 24), ("two_live", 12, 128, 256, 3, 8, 2, 2))),
     serve=dict(slots=4, seq=128, requests=8, prompt=32, new=8),
     train=dict(steps=4),
     ring=dict(batch=2, steps=3),
@@ -340,8 +351,51 @@ def leg_kernels(sz, on_chip):
         jax.block_until_ready(many(*args))
         times[name] = round(1e6 * (time.perf_counter() - t0) / b["reps"], 1)
     visible = int(((starts[:-1] + Bk + bs - 1) // bs * bs).sum())
+
+    # the expert layer's grouped product alone: the kernel against the
+    # float32 product of the live layer, then kernel and ``ragged_dot`` timed
+    # (``reps`` calls in one program, the group sizes riding the loop so that
+    # nothing is hoisted) and read as GB/s of the live groups' weights
+    from ray_tpu.ops.grouped_matmul import grouped_matmul
+
+    def many_products(fn):
+        def many(rows, w, sizes):
+            def step(_, carry):
+                out = fn(rows, w, carry[0])
+                return carry[0] + jnp.isnan(out[0, 0]).astype(jnp.int32), out
+
+            return jax.lax.fori_loop(0, sz["grouped"]["reps"], step, (sizes, jnp.zeros((rows.shape[0], w.shape[2]), bf16)))
+
+        return jax.jit(many)
+
+    grouped = {}
+    for name, tokens, a, b, L, E, k, live_tokens in sz["grouped"]["shapes"]:
+        rng = np.random.default_rng(41)
+        p = rng.dirichlet(np.full(E, 20.0))
+        picks = np.stack([rng.choice(E, size=k, replace=False, p=p) for _ in range(tokens)])
+        picks[live_tokens:] = picks[0]
+        sizes = np.bincount(picks.reshape(-1), minlength=E).astype(np.int32)
+        layer = L // 2
+        rows = rand(60, (tokens * k, a))
+        w = (jax.random.normal(jax.random.key(61), (L * E, a, b), jnp.float32) * a ** -0.5).astype(bf16)
+        in_stack = jnp.zeros((L, E), jnp.int32).at[layer].set(jnp.asarray(sizes)).reshape(L * E)
+        out = _compile(jax.jit(grouped_matmul), rows, w, in_stack, on_chip=on_chip)(rows, w, in_stack)
+        ref = jax.jit(lambda r, w, s: jax.lax.ragged_dot(r.astype(jnp.float32), w.astype(jnp.float32), s, precision="highest"))(
+            rows, w[layer * E:(layer + 1) * E], jnp.asarray(sizes))
+        _check(f"grouped_{name}", out, ref, FWD_REL_TOL, errs)
+        live_bytes = int((sizes > 0).sum()) * a * b * 2
+        grouped[name] = {"live_groups": int((sizes > 0).sum()), "rows_a_group_max": int(sizes.max()), "live_weight_bytes": live_bytes}
+        for label, fn in (("kernel", grouped_matmul), ("ragged_dot", jax.lax.ragged_dot)):
+            many = many_products(fn)
+            jax.block_until_ready(many(rows, w, in_stack))
+            t0 = time.perf_counter()
+            jax.block_until_ready(many(rows, w, in_stack))
+            us = 1e6 * (time.perf_counter() - t0) / sz["grouped"]["reps"]
+            grouped[name][label] = {"us_a_call": round(us, 1), "live_GB_per_s": round(live_bytes / us / 1e3, 1)}
+        del rows, w, out, ref
     return {"rel_err": errs, "tolerance": {"fwd": FWD_REL_TOL, "bwd": BWD_REL_TOL},
-            "block_step_us_a_call": times, "block_step_kv_bytes": visible * 2 * Hkv * D * 2}
+            "block_step_us_a_call": times, "block_step_kv_bytes": visible * 2 * Hkv * D * 2,
+            "grouped_product": grouped}
 
 
 # ---------------------------------------------------------------------------

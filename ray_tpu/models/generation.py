@@ -245,7 +245,7 @@ def forward_with_cache(
             o = jnp.einsum("bgrts,bgsk->btgrk", p, vc.astype(jnp.float32))
             o = o.reshape(B, T, h_heads, cfg.head_dim).astype(x.dtype)
         x = block_attn_out(cfg, layer, x, h, o)
-        x, _ = block_ffn(cfg, layer, x, stack=stack, index=index)
+        x, _ = block_ffn(cfg, layer, x, stack=stack, index=index, kernel=use_decode_kernel)
         return x, (kc, vc)
 
     stacks = layer_stacks(cfg, params)
@@ -298,7 +298,8 @@ def paged_forward_counted(
     goes through the Pallas paged kernels, the decode kernel for
     single-token calls and the prefill kernel for chunks: the block table
     rides scalar prefetch and the pages a query can see stream from HBM
-    where they lie, with no gather copy and no capacity-wide scores.
+    where they lie, with no gather copy and no capacity-wide scores, and
+    an expert layer's grouped products go through ``ops/grouped_matmul.py``.
     Otherwise the pool is gathered to a dense view and the attention lines
     are IDENTICAL to the dense path's, which is what makes paged serving
     byte-equal to the dense cache under ``JAX_PLATFORMS=cpu``.
@@ -402,7 +403,7 @@ def paged_forward_counted(
             o = jnp.einsum("bgrts,bgsk->btgrk", p, vd.astype(jnp.float32))
             o = o.reshape(B, T, h_heads, cfg.head_dim).astype(x.dtype)
         x = block_attn_out(cfg, layer, x, h, o)
-        x, counts = block_ffn(cfg, layer, x, valid, stack=stack, index=l - first)
+        x, counts = block_ffn(cfg, layer, x, valid, stack=stack, index=l - first, kernel=use_decode_kernel)
         return (x, kc, vc), counts
 
     carry = (x, cache["k"], cache["v"])

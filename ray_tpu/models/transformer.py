@@ -44,6 +44,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ray_tpu.ops import backend
 from ray_tpu.ops.attention import NEG_INF, flash_attention_with_lse, mha
 from ray_tpu.ops.decode_attention import block_last
+from ray_tpu.ops.grouped_matmul import grouped_matmul as grouped_matmul_kernel
 
 
 @dataclasses.dataclass(frozen=True)
@@ -564,8 +565,16 @@ def route(cfg: TransformerConfig, layer, x2):
 def grouped_matmul(rows, weights, group_sizes):
     """``rows[start_e : start_e + group_sizes[e]] @ weights[e]`` for every
     group ``e``: rows [R, a] sorted by group, weights [E, a, b] -> [R, b].
-    ``jax.lax.ragged_dot``: on the TPU one native grouped kernel whose FLOPs
-    follow R and which reads only the groups that hold rows."""
+    On the chip the Pallas kernel of ``ops/grouped_matmul.py``: each group
+    that holds rows has its weights copied out of HBM once, at a product as
+    tall as its rows, and a group without rows costs nothing. Elsewhere
+    ``jax.lax.ragged_dot``, which is also the kernel's gradient."""
+    if backend.on_tpu():
+        return grouped_matmul_kernel(rows, weights, group_sizes)
+    return _ragged_dot(rows, weights, group_sizes)
+
+
+def _ragged_dot(rows, weights, group_sizes):
     return jax.lax.ragged_dot(rows, weights.astype(rows.dtype), group_sizes)
 
 
@@ -577,13 +586,15 @@ def scanned_leaves(cfg: TransformerConfig, stack):
     dropless expert layer's three weight stacks. A scan hands its body a
     slice of each xs leaf, and a slice that feeds a kernel is a copy: 0.5 GB
     a projection a layer a step at 128 experts of 2048 x 1024. The grouped
-    products read the whole stack where it lies instead (``moe_ffn_dropless``)."""
+    products take the whole stack where it lies instead, as ``L x E`` groups
+    (``moe_ffn_dropless``): on the chip ``ops/grouped_matmul.py`` copies out
+    of it the weights of the groups that hold rows and of no other."""
     if cfg.dropless and "we1" in stack:
         return {k: v for k, v in stack.items() if k not in EXPERT_WEIGHTS}
     return stack
 
 
-def moe_ffn_dropless(cfg: TransformerConfig, layer, x, valid=None, *, stack=None, index=0):
+def moe_ffn_dropless(cfg: TransformerConfig, layer, x, valid=None, *, stack=None, index=0, kernel=True):
     """The dropless routed + shared expert layer: route, sort the N x k
     assignments by expert, one grouped product a projection over exactly
     those N x k rows, unsort, weigh and add; the shared experts see every
@@ -593,6 +604,8 @@ def moe_ffn_dropless(cfg: TransformerConfig, layer, x, valid=None, *, stack=None
     layer loop, from ``stack`` (the whole ``[L, E, ..]`` leaves) at layer
     ``index``, which may be traced: the stack is read as ``L x E`` groups of
     which only this layer's hold rows, so nothing is sliced out of it.
+    ``kernel=False`` keeps the products XLA's own on the chip too: under a
+    mesh, where GSPMD partitions ``ragged_dot`` and refuses a Mosaic call.
 
     Returns (out [B, T, d], assignments int32[E]): how many (token, choice)
     pairs each expert got, counting only tokens ``valid`` [B, T] marks. Bucket
@@ -614,8 +627,10 @@ def moe_ffn_dropless(cfg: TransformerConfig, layer, x, valid=None, *, stack=None
     L = w["we1"].shape[0]
     in_stack = jnp.zeros((L, E), jnp.int32).at[index].set(group_sizes).reshape(L * E)
 
+    multiply = grouped_matmul if kernel else _ragged_dot
+
     def product(a, name):
-        return grouped_matmul(a, w[name].reshape(L * E, *w[name].shape[2:]), in_stack)
+        return multiply(a, w[name].reshape(L * E, *w[name].shape[2:]), in_stack)
 
     out = product(jax.nn.silu(product(rows, "we3")) * product(rows, "we1"), "we2")  # [N*k, d]
     back = jnp.argsort(order)                       # where each (token, choice) landed
@@ -678,11 +693,12 @@ def block_attn_out(cfg: TransformerConfig, layer, x, h, o):
     return x + a
 
 
-def block_ffn(cfg: TransformerConfig, layer, x, valid=None, *, stack=None, index=0):
+def block_ffn(cfg: TransformerConfig, layer, x, valid=None, *, stack=None, index=0, kernel=True):
     """The feed-forward branch: dense or expert layer by the layer's own
     leaves (``stack``, ``index``: where a layer loop keeps the dropless
-    experts' weights, see :func:`scanned_leaves`). Returns (x, the dropless
-    layer's assignment counts or None)."""
+    experts' weights, see :func:`scanned_leaves`; ``kernel``: whether its
+    grouped products may be a Mosaic call, see :func:`moe_ffn_dropless`).
+    Returns (x, the dropless layer's assignment counts or None)."""
     h = _rms_norm(x, layer["ffn_norm"], cfg.norm_eps)
     counts = None
     if "router" not in layer:
@@ -690,7 +706,7 @@ def block_ffn(cfg: TransformerConfig, layer, x, valid=None, *, stack=None, index
     elif cfg.moe_capacity_factor > 0:
         ffn = _moe_ffn_capacity(cfg, layer, h)
     else:
-        ffn, counts = moe_ffn_dropless(cfg, layer, h, valid, stack=stack, index=index)
+        ffn, counts = moe_ffn_dropless(cfg, layer, h, valid, stack=stack, index=index, kernel=kernel)
     if cfg.post_norms:
         ffn = _rms_norm(ffn, layer["post_ffn_norm"], cfg.norm_eps)
     return x + ffn, counts
@@ -731,7 +747,7 @@ def forward(
         o = _attention(cfg, q, k, v, use_flash, mesh=mesh, sp_axis=sp_axis,
                        window=None if kind is None else kind["window"])
         x = block_attn_out(cfg, layer, x, h, o)
-        x, _ = block_ffn(cfg, layer, x, stack=stack, index=index)
+        x, _ = block_ffn(cfg, layer, x, stack=stack, index=index, kernel=act_spec is None)
         if act_spec is not None:
             x = jax.lax.with_sharding_constraint(x, act_spec)
         return x, None

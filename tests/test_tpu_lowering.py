@@ -422,6 +422,54 @@ def test_the_sdar_cells_block_step_and_prefill_chunk_compile_for_v5e_and_fit(as_
         assert m.argument_size_in_bytes + m.temp_size_in_bytes + m.output_size_in_bytes - m.alias_size_in_bytes < 15.5e9
 
 
+def _cell_programs(name):
+    """A serve cell's decode (or block) step and prefill chunk at the
+    benchmark's own configuration, abstract arguments and all, not placed."""
+    from benchmark import system
+    from ray_tpu.models import init_params
+    from ray_tpu.models.generation import init_paged_cache, open_blocks, paged_block_step, paged_forward_counted
+
+    config = system.load_json(f"benchmark/configs/{name}.json")
+    run = config["run"]
+    cfg = system.model_module(config).program_config(
+        config, max_seq_len=run["max_seq_len"], dtype=run["dtype"], param_dtype=run["param_dtype"])
+    B, bs, C = run["max_batch_size"], run["kv_block_size"], run["prefill_chunk_tokens"]
+    M = run["max_seq_len"] // bs
+    params = _abstract_tree(lambda: init_params(cfg, jax.random.key(0)))
+    cache = _abstract_tree(lambda: init_paged_cache(cfg, run["kv_num_blocks"], bs))
+    pos, bt, toks, row, scalar = _abstract([((B,), I32), ((B, M), I32), ((1, C), I32), ((1, M), I32), ((), I32)])
+
+    def chunk(params, cache, toks, bt, start, length):
+        valid = (jnp.arange(C) < length)[None, :]
+        return paged_forward_counted(cfg, params, cache, bt, toks, start + jnp.arange(C)[None, :], valid=valid,
+                                     with_logits=not cfg.block)
+
+    if cfg.block:
+        state = _abstract_tree(lambda: open_blocks(cfg, jnp.ones(B, I32)))
+        step = lambda params, cache, state, pos, bt: paged_block_step(cfg, params, cache, bt, state, pos, live=bt[:, 0] > 0)
+        step_args = (params, cache, state, pos, bt)
+    else:
+        step = lambda params, cache, toks, pos, bt: paged_forward_counted(
+            cfg, params, cache, bt, toks[:, None], pos[:, None], valid=(bt[:, 0] > 0)[:, None])
+        step_args = (params, cache, pos, pos, bt)
+    return {"step": (step, step_args), "chunk": (chunk, (params, cache, toks, row, scalar, scalar))}
+
+
+@pytest.mark.parametrize("cell,program,products", [
+    ("trinity-mini-serve-l5", "step", 3), ("trinity-mini-serve-l5", "chunk", 3),
+    ("sdar-30b-a3b-serve-l6", "step", 3), ("sdar-30b-a3b-serve-l6", "chunk", 3),
+    ("smollm2-1.7b-serve", "step", 0), ("smollm2-1.7b-serve", "chunk", 0)])
+def test_the_expert_layers_grouped_products_are_the_named_kernel(as_chip, cell, program, products):
+    """Lowered for the chip, an expert layer's three projections are three
+    Mosaic calls named ``grouped_matmul`` (the layer scan's body holds them
+    once) and no ``ragged_dot`` is left; a dense model's programs hold none."""
+    fn, args = _cell_programs(cell)[program]
+    text = jax.jit(fn).trace(*args).lower(lowering_platforms=("tpu",)).as_text()
+    assert text.count('kernel_name = "grouped_matmul"') == products
+    assert "ragged_dot" not in text
+    assert "tpu_custom_call" in text  # the paged attention kernels, in every program
+
+
 # --------------------------------------------------------------------------
 # make_train_step, AOT
 # --------------------------------------------------------------------------
@@ -485,6 +533,31 @@ def test_train_step_compiles_under_a_four_chip_mesh(as_chip, v5e, attention):
     tokens = jax.ShapeDtypeStruct((2, cfg.max_seq_len), I32, sharding=NamedSharding(mesh, P("dp", None)))
     lowered = train_step.lower(state, tokens)
     assert ("tpu_custom_call" in lowered.as_text()) == (attention == "ring")
+    lowered.compile()
+
+
+def test_an_expert_layer_under_a_mesh_keeps_xlas_grouped_product(as_chip, v5e):
+    """GSPMD partitions ``ragged_dot`` over the sharded experts and refuses a
+    Mosaic call ("cannot be automatically partitioned"): under a mesh the
+    train step's expert layers hold no ``grouped_matmul`` kernel, and the
+    step compiles for four chips as it did before the kernel came."""
+    from ray_tpu.models import TransformerConfig
+    from ray_tpu.models.transformer import make_train_step
+
+    cfg = TransformerConfig(
+        vocab_size=4096, d_model=1024, n_layers=2, n_heads=8, n_kv_heads=4, head_dim=128, d_ff=2048, max_seq_len=1024,
+        dtype=BF16, param_dtype=F32, num_experts=8, expert_top_k=2, expert_d_ff=512, attention="dense")
+    mesh = Mesh(np.array(v5e).reshape(2, 1, 2), ("dp", "sp", "tp"))
+    _, train_step = make_train_step(cfg, mesh=mesh)
+    tokens = jax.ShapeDtypeStruct((4, cfg.max_seq_len), I32, sharding=NamedSharding(mesh, P("dp", None)))
+    lowered = train_step.lower(_abstract_train_state(cfg, mesh=mesh), tokens)
+    assert "ragged_dot" in lowered.as_text() and "grouped_matmul" not in lowered.as_text()
+    lowered.compile()
+    _, alone = make_train_step(cfg)  # one chip: the kernel, and ragged_dot only in its gradient
+    state = _abstract_train_state(cfg, device=v5e[0])
+    lowered = alone.trace(state, jax.ShapeDtypeStruct((4, cfg.max_seq_len), I32, sharding=SingleDeviceSharding(v5e[0]))).lower(
+        lowering_platforms=("tpu",))
+    assert 'kernel_name = "grouped_matmul"' in lowered.as_text() and "ragged_dot" in lowered.as_text()
     lowered.compile()
 
 
