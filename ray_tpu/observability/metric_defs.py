@@ -400,9 +400,11 @@ LLM_PREFILL_KV_CAPACITY = _reg.counter(
 )
 LLM_DECODE_STALL = _reg.histogram(
     "llm_decode_stall_seconds",
-    "Time running decodes stalled waiting on prefill work admitted between "
-    "decode steps. Chunked prefill bounds each observation to one chunk's "
-    "forward instead of a whole prompt's.",
+    "Time the LLM engine's loop waited for a prefill chunk that ran while "
+    "decode rows were live, after it had read their step in flight (the "
+    "loop's prefill_wait phase): what the chunk added to those rows' next "
+    "token. Chunked prefill bounds each observation to one chunk's forward "
+    "instead of a whole prompt's.",
     "s",
     boundaries=_LATENCY_BOUNDS,
 )
@@ -434,31 +436,24 @@ LLM_PREFIX_EVICTIONS = _reg.counter(
     "tie-break) to return pages to a short pool or to respect "
     "prefix_cache_max_blocks.",
 )
-LLM_MOE_ASSIGNMENTS = _reg.counter(
-    "llm_moe_assignments_total",
-    "(token, choice) pairs the dropless expert layers routed, summed over "
-    "expert layers and program runs (valid tokens only: bucket padding and "
-    "idle decode rows are not counted). Tokens x top_k x expert layers.",
+LLM_LOOP_PHASE_SECONDS = _reg.counter(
+    "llm_loop_phase_seconds_total",
+    "Wall time of the LLM engine's loop thread by phase (serve/llm.py "
+    "LOOP_PHASES; the phases add up to the thread's time). collect_wait and "
+    "prefill_wait are blocked on the device and idle on an empty batch; the "
+    "rate of the other nine is the share of a core the host path takes. "
+    "Published from the loop about once a second.",
+    "s",
 )
-LLM_MOE_EXPERTS_HIT = _reg.counter(
-    "llm_moe_experts_hit_total",
-    "(layer, expert) pairs that received at least one token, summed over "
-    "program runs: the expert weight matrices a run had to read. Over "
-    "runs x expert layers x experts it is the share of expert weights "
-    "touched per step.",
-)
-LLM_DECODE_STEPS_OVERLAPPED = _reg.counter(
-    "llm_decode_steps_overlapped_total",
-    "Decode steps the LLM engine dispatched while the step before was still "
-    "unread (same unit as stats()'s decode_steps): the host's work between "
-    "two steps then ran beside the device, not between its programs.",
-)
-LLM_DECODE_ROW_STEPS_DISCARDED = _reg.counter(
-    "llm_decode_row_steps_discarded_total",
-    "Row-steps the LLM engine computed and dropped unread: a step is "
-    "dispatched before the one before it is read, so a row that hit EOS or "
-    "whose stream was cancelled is decoded once more (max_tokens finishes "
-    "are known by count and waste nothing).",
+LLM_DECODE_DISPATCHES = _reg.counter(
+    "llm_decode_dispatches_total",
+    "Decode steps the LLM engine enqueued, by what the device held then: "
+    "queued (work still ahead of it), dry (the step in flight had finished "
+    "and no prefill chunk went ahead: the device idled until this call) or "
+    "cold (no step in flight: the batch had emptied). dry / (dry + queued) "
+    "is a lower bound of the steps the device waited for (the host learns "
+    "that a step has finished some way behind the device): to watch a "
+    "replica against its own past, not to alert on a level.",
 )
 
 # Serving SLO families (request-scope observability): ms-scale boundaries
@@ -610,10 +605,8 @@ ALL_METRICS = [
     LLM_PREFIX_CACHE_BLOCKS,
     LLM_KV_BLOCKS_SHARED,
     LLM_PREFIX_EVICTIONS,
-    LLM_MOE_ASSIGNMENTS,
-    LLM_MOE_EXPERTS_HIT,
-    LLM_DECODE_STEPS_OVERLAPPED,
-    LLM_DECODE_ROW_STEPS_DISCARDED,
+    LLM_LOOP_PHASE_SECONDS,
+    LLM_DECODE_DISPATCHES,
     LLM_TTFT,
     LLM_INTER_TOKEN,
     SERVE_REQUEST_PHASE,

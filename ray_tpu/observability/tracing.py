@@ -25,6 +25,11 @@ the task spec) rebuilt without an OpenTelemetry dependency:
 The driver installs the control service's span store as the sink at
 ``init()`` (``api.init`` → :func:`set_span_sink`); processes without a sink
 (pool workers) buffer locally and are drained into result payloads.
+
+:class:`LoopClock` is the other kind of span: the phases of one hot
+single-threaded loop (the LLM engine's), summed into seconds and, while a
+``jax.profiler`` session is on, written into that profile's host plane. They
+never reach the collector above.
 """
 
 from __future__ import annotations
@@ -285,3 +290,79 @@ def emit_span(
     if attrs:
         ev["attrs"] = dict(attrs)
     record_span_event(ev)
+
+
+# --------------------------------------------------------------------------
+# loop phases: the profiler's clock, not the collector's
+# --------------------------------------------------------------------------
+class LoopClock:
+    """The time of a single-threaded loop, divided among named phases that
+    follow each other without a hole: :meth:`lap` ends the open phase and
+    opens the next, so the phases' seconds add up to the loop's wall time.
+    ``phases`` in the order an iteration meets them; the last is where the
+    loop rests, and the clock opens in it.
+
+    One ``perf_counter`` a boundary. While a ``jax.profiler`` session is on
+    (and only then: no object is built otherwise) each phase is also a
+    ``TraceAnnotation`` ``llm::<phase>`` on the loop's thread, in the
+    profile's host plane and on the profiler's clock, the one the device
+    planes use: XProf and Perfetto show it beside the device's programs.
+    The phases are flat, so a phase's time is its self time. They do not go
+    through :class:`span` and the collector: a dozen spans an iteration at
+    hundreds of iterations a second would swamp the control service's span
+    store, and ``ray_tpu.timeline()`` is for tasks and requests.
+
+    Owned by the loop's thread: no lock. Another thread may copy
+    ``seconds`` (the keys never change; the open phase's time is added when
+    it ends)."""
+
+    __slots__ = ("seconds", "_names", "_annotation", "_open", "_phase", "_t", "_at_iteration")
+
+    def __init__(self, phases: Tuple[str, ...]):
+        from jax.profiler import TraceAnnotation
+
+        self.seconds: Dict[str, float] = dict.fromkeys(phases, 0.0)
+        self._names = {p: f"llm::{p}" for p in phases}
+        self._annotation = TraceAnnotation
+        self._open = None  # the open phase's TraceAnnotation, while a profiler session is on
+        self._phase = phases[-1]
+        self._t = time.perf_counter()
+        self._at_iteration = dict(self.seconds)
+
+    def lap(self, phase: str) -> float:
+        """End the open phase, open ``phase``; returns the boundary's time."""
+        now = time.perf_counter()
+        self.seconds[self._phase] += now - self._t
+        self._t = now
+        self._phase = phase
+        if self._annotation.is_enabled():
+            if self._open is not None:
+                self._open.__exit__(None, None, None)
+            self._open = self._annotation(self._names[phase])
+            self._open.__enter__()
+        elif self._open is not None:  # the session ended inside the last phase
+            self._open.__exit__(None, None, None)
+            self._open = None
+        return now
+
+    def iteration(self, phase: str) -> float:
+        """:meth:`lap` into an iteration's first phase."""
+        now = self.lap(phase)
+        self._at_iteration = dict(self.seconds)
+        return now
+
+    def iteration_ms(self) -> Dict[str, float]:
+        """The phases' time since the current iteration began, in ms (the
+        open phase up to now): where a loop that stood still was."""
+        out = {p: 1e3 * (s - self._at_iteration[p]) for p, s in self.seconds.items()}
+        out[self._phase] += 1e3 * (time.perf_counter() - self._t)
+        return out
+
+    def close(self) -> None:
+        """The loop is over: end the open phase."""
+        now = time.perf_counter()
+        self.seconds[self._phase] += now - self._t
+        self._t = now
+        if self._open is not None:
+            self._open.__exit__(None, None, None)
+            self._open = None

@@ -87,6 +87,7 @@ from ray_tpu.models.generation import (
 from ray_tpu.models.transformer import TransformerConfig
 from ray_tpu.observability import metric_defs
 from ray_tpu.observability.sketch import LatencySketch
+from ray_tpu.observability.tracing import LoopClock
 from ray_tpu.runtime import admission
 from ray_tpu.runtime.context import (
     current_deadline_ts,
@@ -108,8 +109,24 @@ class TokenBlock(NamedTuple):
     unmasked_at: List[int]
 
 
+#: The engine loop's phases (``llm::<phase>`` in a profiler trace, the keys
+#: of ``stats()["loop_phase_s"]``), in the order an iteration meets them.
+#: ``collect_wait``, ``prefill_wait`` and ``idle`` wait (for the device, for
+#: a request); the other nine are the host path. The loop rests in the last.
+LOOP_PHASES = (
+    "evict", "admit", "prefill_enqueue", "dispatch_rows", "dispatch_enqueue", "collect_wait",
+    "collect_counts", "emit", "prefill_wait", "prefill_counts", "first_token", "idle",
+)
+#: What a decode step found when it was enqueued: ``queued`` behind work
+#: the device still had, ``dry`` (the step in flight had finished and no
+#: chunk went ahead: the device idled until this call) or ``cold`` (no
+#: step in flight: the batch had emptied or is starting).
+_DISPATCH_KINDS = ("queued", "dry", "cold")
+
 # prebuilt tag dicts for the per-request admission hot path
 _EVICT_DISCONNECT_TAGS = {"reason": "disconnect"}
+_LOOP_PHASE_TAGS = {p: {"phase": p} for p in LOOP_PHASES}
+_DISPATCH_TAGS = {k: {"device": k} for k in _DISPATCH_KINDS}
 _PREFIX_RESULT_TAGS = {
     "hit": {"result": "hit"},
     "partial": {"result": "partial"},
@@ -222,7 +239,6 @@ class _Flight:
     out: Any  # device int32[B, K]; a block step: what it finished (``paged_block_step``)
     moe: list  # the expert layers' counts, where the program returns them
     rows: List[Tuple[int, GenRequest]]
-    overlapped: bool  # dispatched while the step before was still unread
     # a block step: slot -> known positions of the block this step commits
     commits: Dict[int, int] = field(default_factory=dict)
 
@@ -471,8 +487,11 @@ class LLMEngine:
         # ``_collect``), steps dispatched while the one before was unread,
         # and rows computed past an EOS or a cancel and dropped unread
         self._flight: Optional[_Flight] = None
-        self._decode_steps_overlapped = 0
         self._decode_row_steps_discarded = 0
+        # where the loop's time goes and what each step found on the device:
+        # engine-thread-owned like ``_slots``, copied by ``stats()``
+        self._clock = LoopClock(LOOP_PHASES)
+        self._dispatches = dict.fromkeys(_DISPATCH_KINDS, 0)
         # block steps (a diffusion config): forwards of live rows (denoise and
         # commit), blocks committed, tokens they emitted and positions they
         # unmasked, and blocks a cancelled row left uncommitted
@@ -992,8 +1011,11 @@ class LLMEngine:
                 "prefix_evictions": self._prefix.evictions if self._prefix is not None else 0,
                 "cow_copies": self._cow_count,
                 "decode_steps": self._decode_step_count,
-                "decode_steps_overlapped": self._decode_steps_overlapped,
+                # dispatched while the step before was still unread: all but the cold ones
+                "decode_steps_overlapped": self.decode_chunk * (self._dispatches["queued"] + self._dispatches["dry"]),
                 "decode_row_steps_discarded": self._decode_row_steps_discarded,
+                "decode_dispatches": dict(self._dispatches),
+                "loop_phase_s": dict(self._clock.seconds),
                 "kv_read_share": self.kv_read_share(),
                 "kv_live_pages": self.kv_live_pages(),
                 **self._moe_stats_locked(),
@@ -1779,7 +1801,6 @@ class LLMEngine:
         toks = np.zeros((1, width), np.int32)
         toks[0, :n] = req.prompt[start : start + n]
         stalled = bool(self._active.any())
-        t0 = time.perf_counter()
         try:
             # invariant net: admission never maps a to-be-written block to a
             # shared page (the full-hit tail is COW'd eagerly), but writes
@@ -1798,13 +1819,16 @@ class LLMEngine:
                 self._prefilling.pop(0)
             self._fail_admit(req, exc)
             return None
-        return req, n, logits, moe, stalled, t0
+        return req, n, logits, moe, stalled
 
-    def _prefill_finish(self, req: GenRequest, n: int, logits, moe, stalled: bool, t0: float) -> None:
+    def _prefill_finish(self, req: GenRequest, n: int, logits, moe, stalled: bool) -> None:
         """Wait for the chunk ``_prefill_enqueue`` started and, if it was the
         prompt's last, sample the first token and join the decode batch."""
+        lap = self._clock.lap
+        t_wait = lap("prefill_wait")
         try:
             jax.block_until_ready(logits)
+            t_done = lap("prefill_counts")
             self._note_moe(moe, decode=False)
         except BaseException as exc:  # noqa: BLE001
             with self._lock:
@@ -1812,8 +1836,10 @@ class LLMEngine:
             self._fail_admit(req, exc)
             return
         if stalled:
-            # decode slots sat idle while this chunk ran; chunking bounds it
-            metric_defs.LLM_DECODE_STALL.observe(time.perf_counter() - t0)
+            # rows were live while this chunk ran: the loop has read their
+            # step already, so what it waited here is what the chunk added
+            # to their next token; chunking bounds it
+            metric_defs.LLM_DECODE_STALL.observe(t_done - t_wait)
             self._note_stall()
         metric_defs.LLM_PREFILL_CHUNKS.inc()
         if req.trace is not None:
@@ -1832,6 +1858,7 @@ class LLMEngine:
         with self._lock:
             self._prefilling.pop(0)
             self._prefill_count += 1
+        lap("first_token")
         try:
             self._finish_prefill(req, logits)
         except BaseException as exc:  # noqa: BLE001
@@ -1850,8 +1877,6 @@ class LLMEngine:
         self._moe_experts_hit += hit
         if decode:
             self._moe_experts_hit_decode += hit
-        metric_defs.LLM_MOE_ASSIGNMENTS.inc(int(counts.sum()))
-        metric_defs.LLM_MOE_EXPERTS_HIT.inc(hit)
 
     def _chunk_kv_visited(self, start: int, n: int) -> float:
         """Cached tokens the attention of a chunk of ``n`` tokens at
@@ -1908,16 +1933,31 @@ class LLMEngine:
                 req.stream_queue.put(_STREAM_END)
         return done
 
-    def _dispatch(self) -> Optional[_Flight]:
+    def _note_dispatch(self, behind_chunk: bool) -> None:
+        """Count what the step about to be enqueued finds on the device
+        (``_DISPATCH_KINDS``), its uploads made and the jit call next: one
+        ``is_ready()``, no wait."""
+        prev = self._flight
+        if prev is None:
+            kind = "cold"
+        elif not behind_chunk and jax.tree.leaves(prev.out)[0].is_ready():
+            kind = "dry"
+        else:
+            kind = "queued"
+        self._dispatches[kind] += 1
+
+    def _dispatch(self, behind_chunk: bool) -> Optional[_Flight]:
         """Enqueue one decode step (``decode_chunk`` tokens a row) for every
         row still owed a token and return its handle unread; None if there is
         no such row. The host knows everything the step needs ahead of the
         device but the rows' last tokens, and those the program takes from
         its own previous run (``_dev_toks``) or, for a row that joined since,
         from ``_join_tok``. Positions and the ``max_tokens`` count advance
-        here, so the next step can be dispatched before this one is read."""
+        here, so the next step can be dispatched before this one is read.
+        ``behind_chunk``: this iteration enqueued a prefill chunk ahead."""
+        self._clock.lap("dispatch_rows")
         if self._bk > 1:
-            return self._dispatch_blocks()
+            return self._dispatch_blocks(behind_chunk)
         K = self.decode_chunk
         rows: List[Tuple[int, GenRequest]] = []
         live = np.zeros(self.B, bool)
@@ -1936,22 +1976,24 @@ class LLMEngine:
             live[i] = True
         if not rows:
             return None
+        self._clock.lap("dispatch_enqueue")
         # rows not in this step decode through all-zero tables -> garbage
         # page 0, so freed pages are never written after release. The device
         # gets copies of the mirrors: a transfer may read (or alias) its host
         # buffer after the call returns, and the mirrors change right below
         bt = jnp.asarray(self._block_tables * live[:, None].astype(np.int32))
+        join, pos, temps = jnp.asarray(self._join_tok.copy()), jnp.asarray(self._pos.copy()), jnp.asarray(self._temps.copy())
+        self._note_dispatch(behind_chunk)
         out, self._cache, self._key, self._dev_toks, *moe = self._decode_k_paged(
-            self.params, self._cache, self._dev_toks, jnp.asarray(self._join_tok.copy()),
-            jnp.asarray(self._pos.copy()), jnp.asarray(self._temps.copy()), self._key, bt,
+            self.params, self._cache, self._dev_toks, join, pos, temps, self._key, bt,
         )
         self._join_tok[:] = -1
         for i, req in rows:
             req.dispatched += K
             self._pos[i] += K
-        return _Flight(out, moe, rows, overlapped=self._flight is not None)
+        return _Flight(out, moe, rows)
 
-    def _dispatch_blocks(self) -> Optional[_Flight]:
+    def _dispatch_blocks(self, behind_chunk: bool) -> Optional[_Flight]:
         """:meth:`_dispatch` for a diffusion config: enqueue one block step
         for every row that still owes a token. A row's schedule is known by
         count (a block of ``m`` masked positions takes ``min(m, steps)``
@@ -1972,10 +2014,12 @@ class LLMEngine:
             live[i] = True
         if not rows:
             return None
+        self._clock.lap("dispatch_enqueue")
         bt = jnp.asarray(self._block_tables * live[:, None].astype(np.int32))
+        join, pos, temps = self._join_arrays(), jnp.asarray(self._pos.copy()), jnp.asarray(self._temps.copy())
+        self._note_dispatch(behind_chunk)
         done, self._cache, self._key, self._dev_toks, *moe = self._decode_k_paged(
-            self.params, self._cache, self._dev_toks, self._join_arrays(),
-            jnp.asarray(self._pos.copy()), jnp.asarray(self._temps.copy()), self._key, bt,
+            self.params, self._cache, self._dev_toks, join, pos, temps, self._key, bt,
         )
         self._join["row"][:] = False
         commits: Dict[int, int] = {}
@@ -1987,7 +2031,7 @@ class LLMEngine:
                 self._pos[i] += Bk
                 req.block_known = 0
                 req.forwards_left = min(Bk, req.denoising_steps) + 1
-        return _Flight(done, moe, rows, overlapped=self._flight is not None, commits=commits)
+        return _Flight(done, moe, rows, commits=commits)
 
     def _collect_blocks(self, flight: _Flight) -> None:
         """:meth:`_collect` for a diffusion config: a row yields nothing on a
@@ -1995,18 +2039,14 @@ class LLMEngine:
         commit (cut at ``max_tokens`` or after an EOS: what the block holds
         beyond is dropped)."""
         done = jax.device_get(flight.out)
+        self._clock.lap("collect_counts")
         self._decode_step_count += 1
-        if flight.overlapped:
-            self._decode_steps_overlapped += 1
-            metric_defs.LLM_DECODE_STEPS_OVERLAPPED.inc(1)
         self._note_moe(flight.moe, decode=True)
         # rt-lint: disable=lock-discipline -- engine-thread-owned (see _dispatch)
         rows = [(i, req) for i, req in flight.rows if self._slots[i] is req and not req.cancelled]
-        dropped = len(flight.rows) - len(rows)
-        if dropped:
-            self._decode_row_steps_discarded += dropped
-            metric_defs.LLM_DECODE_ROW_STEPS_DISCARDED.inc(dropped)
+        self._decode_row_steps_discarded += len(flight.rows) - len(rows)
         self._block_row_forwards += len(rows)
+        self._clock.lap("emit")
         for i, req in rows:
             known = flight.commits.get(i)
             if bool(done["committed"][i]) != (known is not None):
@@ -2031,21 +2071,18 @@ class LLMEngine:
         left its slot since the dispatch (an EOS read one step late, a
         cancelled stream evicted) is dropped whole, by identity: the slot
         may be another request's by now."""
+        self._clock.lap("collect_wait")
         if self._bk > 1:
             return self._collect_blocks(flight)
         sampled = np.asarray(flight.out)  # [B, K]
+        self._clock.lap("collect_counts")
         K = sampled.shape[1]
         self._decode_step_count += K
-        if flight.overlapped:
-            self._decode_steps_overlapped += K
-            metric_defs.LLM_DECODE_STEPS_OVERLAPPED.inc(K)
         self._note_moe(flight.moe, decode=True)
         # rt-lint: disable=lock-discipline -- engine-thread-owned (see _dispatch)
         rows = [(i, req) for i, req in flight.rows if self._slots[i] is req and not req.cancelled]
-        dropped = len(flight.rows) - len(rows)
-        if dropped:
-            self._decode_row_steps_discarded += dropped * K
-            metric_defs.LLM_DECODE_ROW_STEPS_DISCARDED.inc(dropped * K)
+        self._decode_row_steps_discarded += (len(flight.rows) - len(rows)) * K
+        self._clock.lap("emit")
         for k in range(K):
             for i, req in rows:
                 # rt-lint: disable=lock-discipline -- engine-thread-owned (see _dispatch)
@@ -2139,23 +2176,49 @@ class LLMEngine:
                     RuntimeError("stream consumer disconnected; decode slot evicted")
                 )
 
+    def _publish_loop_totals(self, published: Dict[str, float]) -> None:
+        """Bring the two loop families up to the engine-owned totals
+        (``published``: what they hold already). From the loop, at most once
+        a second: never a locked increment a phase."""
+        for family, totals, tags in (
+            (metric_defs.LLM_LOOP_PHASE_SECONDS, self._clock.seconds, _LOOP_PHASE_TAGS),
+            (metric_defs.LLM_DECODE_DISPATCHES, self._dispatches, _DISPATCH_TAGS),
+        ):
+            for name, total in totals.items():
+                delta = total - published.get(name, 0)
+                if delta > 0:
+                    family.inc(delta, tags[name])
+                    published[name] = total
+
     def _loop(self) -> None:
+        clock = self._clock
+        published: Dict[str, float] = {}
+        publish_at = 0.0
         while not self._stop:
             try:
                 # one decode step stays in flight: the next is dispatched
                 # before the last one's tokens are read, so everything the
                 # host does in an iteration runs beside the device. A chunk
                 # goes into the device's queue ahead of that next step, and
-                # is waited for only after the step in flight was read
+                # is waited for only after the step in flight was read.
+                # Every instant belongs to one of LOOP_PHASES: a call below
+                # opens its own phases where it holds more than one
+                now = clock.iteration("evict")
+                if now >= publish_at:
+                    self._publish_loop_totals(published)
+                    publish_at = now + 1.0
                 self._evict_cancelled()
+                clock.lap("admit")
                 self._admit()
+                clock.lap("prefill_enqueue")
                 chunk = self._prefill_enqueue()
-                prev, self._flight = self._flight, self._dispatch()
+                prev, self._flight = self._flight, self._dispatch(behind_chunk=chunk is not None)
                 if prev is not None:
                     self._collect(prev)
                 if chunk is not None:
                     self._prefill_finish(*chunk)
                 elif prev is None and self._flight is None:
+                    clock.lap("idle")
                     self._wake.wait(timeout=0.05)
                     self._wake.clear()
             except BaseException as exc:  # noqa: BLE001 — a dead loop hangs every caller
@@ -2167,7 +2230,8 @@ class LLMEngine:
                     "engine_crash",
                     f"LLMEngine loop crashed: {exc!r}",
                     severity="ERROR",
-                    state=self.admission_snapshot(),
+                    # ... and where the iteration that crashed had spent its time
+                    state={**self.admission_snapshot(), "loop_phase_ms": clock.iteration_ms()},
                     requests=list(self._finished_ring)[-8:],
                     engine=str(self._admission_token),
                 )
@@ -2175,6 +2239,8 @@ class LLMEngine:
                 # a failed donated step leaves self._cache pointing at
                 # deleted buffers; reallocate so the engine keeps serving
                 self._reset_cache()
+        clock.close()
+        self._publish_loop_totals(published)
 
 
 class LLMServer:
