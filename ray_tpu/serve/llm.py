@@ -1009,6 +1009,7 @@ class LLMEngine:
                 "prefix_cache_misses": self._prefix_results["miss"],
                 "prefix_tokens_reused": self._prefix_tokens_reused,
                 "prefix_evictions": self._prefix.evictions if self._prefix is not None else 0,
+                "prefix_evict_scanned": self._prefix.scanned if self._prefix is not None else 0,
                 "cow_copies": self._cow_count,
                 "decode_steps": self._decode_step_count,
                 # dispatched while the step before was still unread: all but the cold ones
@@ -1106,6 +1107,7 @@ class LLMEngine:
                 "prefix_hit_rate": (useful / probes) if probes else 0.0,
                 "prefix_tokens_reused": self._prefix_tokens_reused,
                 "prefix_evictions": self._prefix.evictions if self._prefix is not None else 0,
+                "prefix_evict_scanned": self._prefix.scanned if self._prefix is not None else 0,
                 "decode_steps": self._decode_step_count,
                 "kv_read_share": self.kv_read_share(),
                 **self._moe_stats_locked(),

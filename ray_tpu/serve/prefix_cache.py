@@ -27,6 +27,13 @@ matched pages into the requesting block table, so a page's refcount is
 deterministic ``(last_used, seq)`` tie-break (``seq`` is insertion order),
 so the same workload always evicts the same pages.
 
+One ``evict`` call is one pass over the nodes plus a heap: the pass gathers
+the leaves that may go, the heap orders them by ``(last_used, seq)``, and a
+parent whose last child was just taken joins the heap. That is
+``O(nodes + want log leaves)`` a call, whatever ``want`` is: an admission
+that needs 110 pages from a pool of 6 000 cached ones examines ~6 100
+nodes, not 110 x 6 000. ``scanned`` counts the nodes examined.
+
 The cache never touches device memory and never calls the allocator: the
 engine owns the allocator lock and frees/shares pages around these calls.
 Not thread-safe on its own; the engine serializes access under its
@@ -36,6 +43,7 @@ admission lock.
 from __future__ import annotations
 
 import hashlib
+import heapq
 from dataclasses import dataclass
 from typing import Callable, Dict, List, Optional, Sequence, Set, Tuple
 
@@ -82,6 +90,7 @@ class PrefixCache:
         self._tick = 0  # LRU clock: one bump per touch/insert
         self._seq = 0  # insertion counter (never reused)
         self.evictions = 0  # cumulative, for the evictions counter metric
+        self.scanned = 0  # cumulative nodes examined by evict(), added once a call
 
     def __len__(self) -> int:
         return len(self._nodes)
@@ -186,28 +195,38 @@ class PrefixCache:
         — same workload, same eviction order. Evicting a leaf can expose
         its parent as the next leaf, so the sweep cascades up cold chains.
         Interior nodes and pages still shared into live requests are never
-        taken."""
+        taken.
+
+        One pass over the nodes gathers this call's candidates into a heap;
+        after that only a victim's parent is examined, when its last child
+        goes. ``evictable`` is asked once per candidate: the caller holds
+        the allocator's lock and frees the returned pages after the call, so
+        no answer changes during it. Cost ``O(nodes + want log leaves)``."""
+        if want <= 0:
+            return []
+        nodes = self._nodes
+        protect = protect or ()
+        heap = [
+            (nd.last_used, nd.seq, nd)
+            for nd in nodes.values()
+            if not nd.children and nd.key not in protect and evictable(nd.page)
+        ]
+        heapq.heapify(heap)  # seq is unique: a comparison never reaches the node
+        scanned = len(nodes)
         freed: List[int] = []
-        while len(freed) < want:
-            victim: Optional[_Node] = None
-            for nd in self._nodes.values():
-                if nd.children:
-                    continue
-                if protect is not None and nd.key in protect:
-                    continue
-                if not evictable(nd.page):
-                    continue
-                if victim is None or (nd.last_used, nd.seq) < (victim.last_used, victim.seq):
-                    victim = nd
-            if victim is None:
-                break
-            del self._nodes[victim.key]
-            if victim.parent is not None:
-                parent = self._nodes.get(victim.parent)
-                if parent is not None:
-                    parent.children -= 1
+        while heap and len(freed) < want:
+            victim = heapq.heappop(heap)[2]
+            del nodes[victim.key]
             freed.append(victim.page)
-            self.evictions += 1
+            parent = nodes.get(victim.parent)  # None for a depth-0 victim
+            if parent is None:
+                continue
+            parent.children -= 1
+            scanned += 1
+            if not parent.children and parent.key not in protect and evictable(parent.page):
+                heapq.heappush(heap, (parent.last_used, parent.seq, parent))
+        self.evictions += len(freed)
+        self.scanned += scanned
         return freed
 
     def drain(self) -> List[int]:

@@ -4,7 +4,9 @@ Four contracts:
 - PrefixCache unit: hash-chain keys are process-stable and unambiguous,
   match/insert/evict round-trip pages, eviction is LRU over unreferenced
   leaves with a deterministic (last_used, seq) order, max_blocks is honored
-  without ever evicting the chain being inserted
+  without ever evicting the chain being inserted; the heap sweep takes the
+  victims of the plain sweep it replaced, in its order, and examines each
+  node once a call (``scanned``)
 - allocator refcounts: share() pins pages, free() releases one reference,
   pages return to the pool only at zero, and misuse (double free, sharing a
   free page or the garbage page) raises instead of corrupting the pool
@@ -17,7 +19,9 @@ Four contracts:
   bounded cache evicts the same pages in the same order
 """
 
+import copy
 import functools
+import random
 import threading
 import time
 
@@ -57,6 +61,12 @@ def _wait(pred, timeout=60):
     while not pred() and time.time() < deadline:
         time.sleep(0.005)
     assert pred()
+
+
+def _engine_sources():
+    from ray_tpu.runtime import admission
+
+    return [s for s in admission.sources_snapshot() if s.get("layer") == "engine"]
 
 
 def _assert_no_leak(eng):
@@ -174,6 +184,140 @@ def test_cache_eviction_deterministic_across_instances():
         return order, sorted(pc.keys())
 
     assert run() == run()
+
+
+# --------------------------------------------------------------------------
+# the heap sweep against the plain sweep it replaced
+# --------------------------------------------------------------------------
+def _evict_reference(nodes, want, evictable, protect=None):
+    """The sweep as it stood before the heap, kept as the independent
+    reference: for each page it frees it walks every node for the least
+    recently used droppable leaf. Mutates ``nodes``; returns the pages."""
+    freed = []
+    while len(freed) < want:
+        victim = None
+        for nd in nodes.values():
+            if nd.children:
+                continue
+            if protect is not None and nd.key in protect:
+                continue
+            if not evictable(nd.page):
+                continue
+            if victim is None or (nd.last_used, nd.seq) < (victim.last_used, victim.seq):
+                victim = nd
+        if victim is None:
+            break
+        del nodes[victim.key]
+        if victim.parent is not None:
+            parent = nodes.get(victim.parent)
+            if parent is not None:
+                parent.children -= 1
+        freed.append(victim.page)
+    return freed
+
+
+def _chain_keys(tokens):
+    """The node keys of a prompt's chain at block size 1."""
+    keys, parent = [], _ROOT
+    for t in tokens:
+        parent = chain_key(parent, [t])
+        keys.append(parent)
+    return keys
+
+
+def _sweep_case(shape, seed):
+    """A cache of a given shape with its LRU order shuffled by match walks,
+    and the arguments of one ``evict`` call: (cache, want, pinned pages,
+    protected keys)."""
+    rng = random.Random(seed)
+    pc = PrefixCache(block_size=1)
+    page = iter(range(1, 1 << 20))
+    prompts = []
+
+    def grow(stem, n):
+        toks = stem + [rng.randrange(1 << 30) for _ in range(n)]
+        pc.insert(toks, [next(page) for _ in toks], lambda p: True)
+        prompts.append(toks)
+        return toks
+
+    if shape == "forks":
+        stem = grow([], 12)
+        for _ in range(5):  # branches off the stem, and twigs off each branch
+            branch = grow(stem[: rng.randrange(4, 13)], rng.randrange(5, 16))
+            for _ in range(rng.randrange(0, 3)):
+                grow(branch[: rng.randrange(len(stem) // 2, len(branch))], rng.randrange(1, 8))
+    else:
+        for _ in range(8):
+            grow([], rng.randrange(20, 41))
+        for _ in range(4):  # a few forks among the chains too
+            src = rng.choice(prompts)
+            grow(src[: rng.randrange(1, len(src))], rng.randrange(1, 10))
+    for _ in range(12):  # walks of whole prompts and of stems alone: a parent newer than its leaf
+        toks = rng.choice(prompts)
+        pc.match(toks[: rng.randrange(1, len(toks) + 1)])
+    pinned, protect = set(), None
+    n = len(pc)
+    want = n // 3
+    if shape in ("pinned", "want_exceeds"):
+        # live requests name a prompt's pages from its root down; a stray page besides
+        for toks in rng.sample(prompts, 3):
+            pinned.update(pc.match(toks[: rng.randrange(1, len(toks) + 1)])[0])
+        pinned.update(rng.sample(range(1, n + 1), n // 10))
+    if shape == "want_exceeds":
+        want = 2 * n
+    if shape == "want_len":
+        want = n
+    if shape == "protect":
+        protect = set(_chain_keys(rng.choice(prompts)))
+        want = n  # everything but the protected chain goes
+    return pc, want, pinned, protect
+
+
+@pytest.mark.parametrize("seed", [3, 17, 2147483659])
+@pytest.mark.parametrize("shape", ["chains", "forks", "pinned", "protect", "want_exceeds", "want_len"])
+def test_evict_takes_the_plain_sweeps_victims_in_its_order(shape, seed):
+    pc, want, pinned, protect = _sweep_case(shape, seed)
+    evictable = lambda p: p not in pinned  # noqa: E731
+    ref_nodes = copy.deepcopy(pc._nodes)
+    n = len(pc)
+    want_pages = _evict_reference(ref_nodes, want, evictable, protect)
+    got = pc.evict(want, evictable, protect=protect)
+    assert got == want_pages  # the same pages in the same order
+    assert pc.keys() == set(ref_nodes)
+    assert {k: nd.children for k, nd in pc._nodes.items()} == {k: nd.children for k, nd in ref_nodes.items()}
+    assert pc.evictions == len(want_pages)
+    assert n <= pc.scanned <= n + len(got)  # one pass, then a parent for each victim at most
+    if shape in ("chains", "forks"):
+        assert len(got) == want
+    elif shape == "want_len":
+        assert len(got) == n and len(pc) == 0
+    elif shape == "protect":
+        assert pc.keys() == protect
+    else:
+        assert not pinned & set(got)
+        if shape == "want_exceeds":
+            assert 0 < len(got) < n
+    # a second call goes on where the first stopped, as the plain sweep does
+    assert pc.evict(5, evictable, protect=protect) == _evict_reference(ref_nodes, 5, evictable, protect)
+    assert pc.keys() == set(ref_nodes)
+
+
+def test_evict_examines_each_node_once_a_call():
+    """The work bound, with no clock in it: freeing 110 pages from 4 000
+    cached ones examines the nodes once and then one parent a victim, where
+    the plain sweep walked all of them for every page."""
+    pc = PrefixCache(block_size=1)
+    for c in range(40):
+        pc.insert([c * 1000 + i for i in range(100)], [c * 100 + i + 1 for i in range(100)], lambda p: True)
+    n = len(pc)
+    assert n == 4000
+    ref_nodes = copy.deepcopy(pc._nodes)
+    assert pc.evict(0, lambda p: True) == [] and pc.scanned == 0  # nothing asked, nothing examined
+    got = pc.evict(110, lambda p: True)
+    assert got == _evict_reference(ref_nodes, 110, lambda p: True)
+    assert got[:100] == list(range(100, 0, -1))  # the coldest chain from its tail, then the next
+    assert n <= pc.scanned <= n + 2 * 110
+    assert pc.evictions == 110 and len(pc) == n - 110
 
 
 # --------------------------------------------------------------------------
@@ -446,6 +590,29 @@ def test_pool_short_admission_evicts_cache_before_holding(params):
         eng.shutdown()
 
 
+def test_evict_scanned_moves_with_an_evicting_admission_and_not_with_decode_steps(params):
+    eng = _paged(params, max_batch_size=1, kv_num_blocks=5)  # 4 usable
+    try:
+        assert len(eng.generate([1] * 40, max_tokens=20)) == 20
+        st0 = eng.stats()
+        assert st0["prefix_cache_blocks"] == 3
+        assert st0["prefix_evict_scanned"] == 0 and st0["prefix_evictions"] == 0  # a roomy pool never sweeps
+        stream = eng.submit_stream([2] * 40, max_tokens=20)
+        next(stream)  # admitted: 3 pages short, so the chain of 3 went, tail first
+        st1 = eng.stats()
+        assert st1["prefix_evictions"] == 3
+        assert st1["prefix_evict_scanned"] == 3 + 2  # one pass over 3 nodes, then the 2 parents
+        assert len(list(stream)) == 19
+        _wait(lambda: eng.stats()["active_slots"] == 0)
+        st2 = eng.stats()
+        assert st2["decode_steps"] - st0["decode_steps"] >= 19
+        assert st2["prefix_evict_scanned"] == st1["prefix_evict_scanned"]  # no step, emit or retire moved it
+        assert _engine_sources()[-1]["prefix_evict_scanned"] == 5
+        _assert_no_leak(eng)
+    finally:
+        eng.shutdown()
+
+
 def test_never_fitting_prompt_rejected_with_cache_populated(params):
     eng = _paged(params, kv_num_blocks=4)  # 3 usable blocks
     try:
@@ -495,7 +662,6 @@ def test_engine_eviction_deterministic_across_runs(params):
 
 def test_prefix_metric_families_registered(params):
     from ray_tpu.observability import metric_defs
-    from ray_tpu.runtime import admission
 
     names = {m.name for m in metric_defs.ALL_METRICS}
     for family in (
@@ -510,8 +676,7 @@ def test_prefix_metric_families_registered(params):
         p = list(range(1, 18))
         eng.generate(p, max_tokens=3)
         eng.generate(p, max_tokens=3)
-        snap = [s for s in admission.sources_snapshot()
-                if s.get("layer") == "engine"][-1]
+        snap = _engine_sources()[-1]
         assert snap["prefix_cache_enabled"] is True
         assert snap["prefix_cache_blocks"] >= 2
         assert 0.0 < snap["prefix_hit_rate"] <= 1.0
