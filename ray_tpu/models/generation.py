@@ -41,35 +41,65 @@ import jax.numpy as jnp
 
 from ray_tpu.models.transformer import (
     TransformerConfig,
-    _rms_norm,
     block_attn_out,
     block_ffn,
     block_qkv,
     block_last,
     embed_tokens,
     flash_by_kind,
+    full_kind,
+    hybrid_scan,
     in_window,
     layer_kinds,
     layer_stacks,
+    linear_inputs,
+    linear_out,
+    pre_norm,
     scanned_leaves,
     unembed,
 )
 from ray_tpu.ops import backend
+from ray_tpu.ops.gated_delta import (
+    gated_delta_chunked,
+    gated_delta_decode,
+    lane_group,
+    pack_state,
+    unpack_state,
+)
 
 KVCache = Dict[str, jax.Array]
 
 
+def _refuse_ring_for_hybrid(cfg: TransformerConfig, what: str) -> None:
+    if cfg.hybrid:
+        raise ValueError(f'{what} keeps keys and values only: a config with "linear" layers (recurrent state a '
+                         "sequence) is served through the paged path, init_paged_cache(..., slots=) and "
+                         "paged_forward_with_cache(..., slots=)")
+
+
 def init_cache(cfg: TransformerConfig, batch: int, max_len: int, dtype=None) -> KVCache:
     """Preallocated KV cache: {"k","v"}: [L, B, Hkv, max_len, Dh]."""
+    _refuse_ring_for_hybrid(cfg, "init_cache (the ring cache of forward_with_cache and generate)")
     dt = dtype or cfg.dtype
     shape = (cfg.n_layers, batch, cfg.kv_heads, max_len, cfg.head_dim)
     return {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
 
 
 def init_paged_cache(
-    cfg: TransformerConfig, num_blocks: int, block_size: int, dtype=None
+    cfg: TransformerConfig, num_blocks: int, block_size: int, dtype=None, slots: int = 0
 ) -> KVCache:
     """Paged KV pool: {"k","v"}: [L, num_blocks, block_size, Hkv*Dh].
+
+    A config with "linear" layers keeps pages for its full layers only (``L``
+    is ``cfg.kv_layers``) and, beside them, what its linear layers carry a
+    sequence, for ``slots`` sequences: ``"state"`` ``[L_lin, slots, H / g,
+    dk, g * dv]`` float32 (the recurrent state, ``g`` heads side by side on
+    the minor axis: ``ops/gated_delta.py``) and ``"conv"`` ``[L_lin, slots,
+    (width - 1) * channels]`` (the convolution's last ``width - 1`` inputs,
+    oldest first, side by side: with an axis of 3 of its own XLA lays the
+    array out with that axis on the 128 lanes inside the layer loop, 1.13 GB
+    for 28 MB at the benchmark's sizes). A sequence names its pages by its
+    block table and its state by its slot.
 
     Unlike :func:`init_cache` there is no batch axis — sequences own sets
     of pages named by an ``int32[B, max_blocks]`` block table, so HBM is
@@ -85,8 +115,46 @@ def init_paged_cache(
     instead — every layer then pays a layout conversion of its whole slice.)
     """
     dt = dtype or cfg.dtype
-    shape = (cfg.n_layers, num_blocks, block_size, cfg.kv_heads * cfg.head_dim)
-    return {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
+    shape = (cfg.kv_layers, num_blocks, block_size, cfg.kv_heads * cfg.head_dim)
+    cache = {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
+    if cfg.hybrid:
+        if slots < 1:
+            raise ValueError('a config with "linear" layers keeps a recurrent state a sequence: '
+                             "init_paged_cache needs slots >= 1")
+        cache.update(init_sequence_state(cfg, slots, dt))
+    return cache
+
+
+def init_sequence_state(cfg: TransformerConfig, slots: int, dtype=None) -> KVCache:
+    """``slots`` sequences' recurrent state and convolution tails, zero: the
+    ``"state"`` and ``"conv"`` of :func:`init_paged_cache`, and the layout of
+    a pool of snapshots of them (``serve/llm.py``)."""
+    H, dk, dv = cfg.linear_heads, cfg.linear_key_dim, cfg.linear_value_dim
+    g = lane_group(H, dv)
+    return {"state": jnp.zeros((cfg.linear_layers, slots, H // g, dk, g * dv), jnp.float32),
+            "conv": jnp.zeros((cfg.linear_layers, slots, (cfg.linear_conv_width - 1) * cfg.linear_channels),
+                              dtype or cfg.dtype)}
+
+
+def copy_sequence_state(dst: KVCache, src: KVCache, dst_slot, src_slot) -> KVCache:
+    """``dst``'s state and convolution tail of sequence ``dst_slot`` <- ``src``'s
+    of ``src_slot`` (every linear layer; the slots may be traced): a snapshot
+    taken (``dst`` the snapshot pool) or restored (``dst`` the cache). Other
+    keys of ``dst`` are passed through."""
+    out = dict(dst)
+    for name in ("state", "conv"):
+        row = jax.lax.dynamic_slice_in_dim(src[name], src_slot, 1, axis=1)
+        out[name] = jax.lax.dynamic_update_slice_in_dim(dst[name], row.astype(dst[name].dtype), dst_slot, axis=1)
+    return out
+
+
+def zero_sequence_state(cache: KVCache, slot) -> KVCache:
+    """Sequence ``slot`` starts: its state and convolution tail are zero."""
+    out = dict(cache)
+    for name in ("state", "conv"):
+        zero = jnp.zeros_like(jax.lax.dynamic_slice_in_dim(cache[name], 0, 1, axis=1))
+        out[name] = jax.lax.dynamic_update_slice_in_dim(cache[name], zero, slot, axis=1)
+    return out
 
 
 def paged_cache_spec(heads_axis: Optional[str]):
@@ -191,6 +259,7 @@ def forward_with_cache(
     layer dequantizes IN the scan body — only one layer's weights ever
     exist at full precision, instead of a whole-tree f32 copy per step.
     Unquantized leaves carry broadcast-ones scales."""
+    _refuse_ring_for_hybrid(cfg, "forward_with_cache")
     B, T = tokens.shape
     S = cache["k"].shape[3]
     h_heads, hkv = cfg.n_heads, cfg.kv_heads
@@ -215,7 +284,7 @@ def forward_with_cache(
         else:
             layer, kind, index, kc, vc = layer_xs
         window = None if kind is None else kind["window"]
-        h = _rms_norm(x, layer["attn_norm"], cfg.norm_eps)
+        h = pre_norm(cfg, layer, "attn_norm", x)
         q, k, v = block_qkv(cfg, layer, h, positions, kind)
         kc = _write_kv(kc, k, starts)
         vc = _write_kv(vc, v, starts)
@@ -287,9 +356,21 @@ def paged_forward_counted(
     use_decode_kernel: Optional[bool] = None,
     layer_scales: Optional[Dict[str, jax.Array]] = None,
     with_logits: bool = True,
+    slots: Optional[jax.Array] = None,  # [B] int32: each row's sequence slot (a config with linear layers)
 ) -> Tuple[jax.Array, KVCache, Dict[str, jax.Array]]:
     """:func:`forward_with_cache` over a paged pool instead of dense rows:
     (logits, cache, the expert layers' counts).
+
+    A config with "linear" layers (``cfg.hybrid``) takes, with the block
+    tables, the rows' ``slots``: its full layers write and read pages as
+    below (the pool's layer axis counts them alone), its linear layers read
+    and write row ``b``'s recurrent state and convolution tail at
+    ``cache["state"][:, slots[b]]`` / ``cache["conv"][:, slots[b]]``: a
+    single-token call through :func:`~ray_tpu.ops.gated_delta.gated_delta_decode`
+    (on the chip the kernel, the state updated where it lies), a chunk
+    through :func:`~ray_tpu.ops.gated_delta.gated_delta_chunked` from the
+    state the slot holds. Positions ``valid`` marks False advance nothing: a
+    row without a valid token (an idle decode row) keeps its state.
 
     Writes this call's K/V into the pool through the block tables and
     attends over every cached position up to ``positions``. With
@@ -371,7 +452,14 @@ def paged_forward_counted(
         else:
             layer, kind, l = layer_xs
         window = None if kind is None else kind["window"]
-        h = _rms_norm(x, layer["attn_norm"], cfg.norm_eps)
+        x, kc, vc = paged_attention_block(x, kc, vc, layer, kind, l, window)
+        x, counts = block_ffn(cfg, layer, x, valid, stack=stack, index=l - first, kernel=use_decode_kernel)
+        return (x, kc, vc), counts
+
+    def paged_attention_block(x, kc, vc, layer, kind, l, window):
+        """One attention branch against the pool: write this call's K/V into
+        layer ``l`` of it, attend, output gate, ``wo``, residual."""
+        h = pre_norm(cfg, layer, "attn_norm", x)
         q, k, v = block_qkv(cfg, layer, h, positions, kind)
         kc = kc.at[l, phys, off].set(k.reshape(B * T, -1).astype(kc.dtype))
         vc = vc.at[l, phys, off].set(v.reshape(B * T, -1).astype(vc.dtype))
@@ -402,9 +490,44 @@ def paged_forward_counted(
             p = jax.nn.softmax(s_, axis=-1)
             o = jnp.einsum("bgrts,bgsk->btgrk", p, vd.astype(jnp.float32))
             o = o.reshape(B, T, h_heads, cfg.head_dim).astype(x.dtype)
-        x = block_attn_out(cfg, layer, x, h, o)
-        x, counts = block_ffn(cfg, layer, x, valid, stack=stack, index=l - first, kernel=use_decode_kernel)
-        return (x, kc, vc), counts
+        return block_attn_out(cfg, layer, x, h, o), kc, vc
+
+    if cfg.hybrid:
+        if slots is None:
+            raise ValueError('a config with "linear" layers keeps a state a sequence: pass the rows\' slots')
+        if layer_scales is not None:
+            raise ValueError('layer_scales (int8 weight-only serving) do not cover a config with "linear" layers')
+        G = lane_group(cfg.linear_heads, cfg.linear_value_dim)
+        real = None if valid is None else jnp.broadcast_to(valid, (B, T))
+        live = jnp.ones((B,), bool) if valid is None else lengths > 0
+
+        def linear_fn(carry, x, layer, li):
+            kc, vc, st, cv = carry
+            tail = cv[li, slots].reshape(B, cfg.linear_conv_width - 1, cfg.linear_channels)
+            h = pre_norm(cfg, layer, "attn_norm", x)
+            # a row without real tokens reads its own tail back: nothing of it moves
+            q, k, v, g, beta, tail = linear_inputs(cfg, layer, h, tail, None if valid is None else lengths)
+            if T == 1:
+                o, st = gated_delta_decode(st, li, slots, live, q[:, 0], k[:, 0], v[:, 0], jnp.exp(g[:, 0]),
+                                           beta[:, 0], kernel=use_decode_kernel)
+                o = o[:, None]
+            else:
+                o, S = gated_delta_chunked(unpack_state(st[li, slots], G), q, k, v, g, beta, real)
+                st = st.at[li, slots].set(pack_state(S, G))
+            cv = cv.at[li, slots].set(tail.reshape(B, -1).astype(cv.dtype))
+            x = linear_out(cfg, layer, x, h, o)
+            return (kc, vc, st, cv), block_ffn(cfg, layer, x)[0]
+
+        def full_fn(carry, x, layer, fi):
+            kc, vc, st, cv = carry
+            x, kc, vc = paged_attention_block(x, kc, vc, layer, full_kind(cfg), fi, None)
+            return (kc, vc, st, cv), block_ffn(cfg, layer, x)[0]
+
+        carry = (cache["k"], cache["v"], cache["state"], cache["conv"])
+        (ks, vs, st, cv), x = hybrid_scan(cfg, params, carry, x, linear_fn, full_fn)
+        logits = unembed(cfg, params, x) if with_logits else None
+        zeros = {"assignments": jnp.zeros((1,), jnp.int32), "pairs_hit": jnp.zeros((), jnp.int32)}
+        return logits, {"k": ks, "v": vs, "state": st, "conv": cv}, zeros
 
     carry = (x, cache["k"], cache["v"])
     assignments = jnp.zeros((max(cfg.num_experts, 1),), jnp.int32)

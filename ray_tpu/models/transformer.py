@@ -20,6 +20,14 @@ nets are the closest analog, ``rllib/core/rl_module/rl_module.py``):
   per-layer values, so one traced body serves all of them. Leading dense
   layers and expert layers are two stacks, scanned one after the other.
   ``docs/models.md`` shows how a published config maps onto the fields.
+- A third layer kind, ``"linear"`` (Gated DeltaNet: a recurrent state a head
+  in place of keys and values, ``ops/gated_delta.py``), has a parameter tree
+  of its own, so it cannot ride the scan as a per-layer value: a config that
+  names it repeats a period of ``k`` linear layers and one full layer, the
+  linear layers are stacks of their own (``params["linear_layers"]``: a list
+  of ``k`` trees, the ``j``-th the stack ``[periods, ...]`` of every period's
+  ``j``-th linear layer) beside the full layers' ``[periods, ...]``, and the
+  scan's body is a period (:func:`hybrid_scan`).
 - Attention: Pallas flash kernel (``ray_tpu.ops.attention``) on a single
   chip (no mesh); XLA einsum attention under any mesh; or
   ``attention="ring"`` — sequence-parallel ring attention
@@ -44,6 +52,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ray_tpu.ops import backend
 from ray_tpu.ops.attention import NEG_INF, flash_attention_with_lse, mha
 from ray_tpu.ops.decode_attention import block_last
+from ray_tpu.ops.gated_delta import gated_delta_chunked
 from ray_tpu.ops.grouped_matmul import grouped_matmul as grouped_matmul_kernel
 
 
@@ -110,6 +119,19 @@ class TransformerConfig:
     # mask_token_id until they are unmasked (serve/llm.py)
     block_length: int = 0
     mask_token_id: int = 0
+    # "linear" layers (Gated DeltaNet, ops/gated_delta.py): linear_heads key
+    # heads of linear_key_dim and as many value heads of linear_value_dim, a
+    # causal depthwise convolution of linear_conv_width over time on q, k, v,
+    # beta = sigmoid (x 2 with linear_allow_neg_eigval). layer_types must then
+    # repeat (k x "linear", "full"): the period is the layer scan's body
+    linear_heads: int = 0
+    linear_key_dim: int = 0
+    linear_value_dim: int = 0
+    linear_conv_width: int = 4
+    linear_allow_neg_eigval: bool = False
+    # OLMo 2/3's norm placement is post_norms without pre_norms: x + norm(branch(x))
+    pre_norms: bool = True              # False => no RMSNorm on a branch's input (needs post_norms)
+    qk_norm_whole: bool = False         # qk_norm's gains span the whole projection (H*Dh, Hkv*Dh), not head_dim
 
     def __post_init__(self):
         if self.block_length > 1:
@@ -125,14 +147,20 @@ class TransformerConfig:
             object.__setattr__(self, "head_dim", self.d_model // self.n_heads)
         if self.layer_types is not None:
             object.__setattr__(self, "layer_types", tuple(self.layer_types))
-            bad = set(self.layer_types) - {"sliding", "full"}
+            bad = set(self.layer_types) - {"sliding", "full", "linear"}
             if bad or len(self.layer_types) != self.n_layers:
                 raise ValueError(
-                    f'layer_types must name "sliding" or "full" for each of the {self.n_layers} layers; '
+                    f'layer_types must name "sliding", "full" or "linear" for each of the {self.n_layers} layers; '
                     f"got {self.layer_types!r}"
                 )
             if "sliding" in self.layer_types and self.sliding_window < 1:
                 raise ValueError("a sliding layer needs sliding_window >= 1")
+            if "linear" in self.layer_types:
+                self._check_hybrid()
+        if not self.pre_norms and not self.post_norms:
+            raise ValueError("pre_norms=False leaves a branch without any norm: it goes with post_norms=True")
+        if self.qk_norm_whole and not self.qk_norm:
+            raise ValueError("qk_norm_whole places qk_norm's gains: set qk_norm=True")
         if self.router_score not in ("softmax", "sigmoid"):
             raise ValueError(f'router_score must be "softmax" or "sigmoid"; got {self.router_score!r}')
         if self.num_experts > 0 and not 0 <= self.num_dense_layers < self.n_layers:
@@ -152,9 +180,53 @@ class TransformerConfig:
                 f"n_kv_heads {kv} must be a positive divisor of n_heads {self.n_heads}"
             )
 
+    def _check_hybrid(self) -> None:
+        """A config with "linear" layers: sizes given, and a whole number of
+        periods of ``k`` linear layers and one full layer."""
+        if min(self.linear_heads, self.linear_key_dim, self.linear_value_dim) < 1 or self.linear_conv_width < 2:
+            raise ValueError('a "linear" layer needs linear_heads, linear_key_dim and linear_value_dim >= 1 '
+                             "and linear_conv_width >= 2")
+        k = self.layer_types.index("full") if "full" in self.layer_types else 0
+        period = ("linear",) * k + ("full",)
+        if k < 1 or self.n_layers % len(period) or self.layer_types != period * (self.n_layers // len(period)):
+            raise ValueError('layer_types with "linear" layers must repeat one period of k >= 1 "linear" layers '
+                             f'followed by one "full" layer; got {self.layer_types!r}')
+        refused = {"num_experts > 0": self.num_experts > 0, "block_length > 1": self.block_length > 1,
+                   'attention="ring"': self.attention == "ring"}
+        bad = [name for name, hit in refused.items() if hit]
+        if bad:
+            raise ValueError('"linear" layers do not go with ' + ", ".join(bad))
+
     @property
     def kv_heads(self) -> int:
         return self.n_kv_heads or self.n_heads
+
+    @property
+    def hybrid(self) -> bool:
+        """Whether some layers are "linear" (recurrent state, no K/V)."""
+        return self.layer_types is not None and "linear" in self.layer_types
+
+    @property
+    def linear_per_period(self) -> int:
+        return self.layer_types.index("full") if self.hybrid else 0
+
+    @property
+    def periods(self) -> int:
+        return self.n_layers // (self.linear_per_period + 1) if self.hybrid else 0
+
+    @property
+    def linear_layers(self) -> int:
+        return self.periods * self.linear_per_period
+
+    @property
+    def kv_layers(self) -> int:
+        """Layers that keep keys and values: the paged pool's layer axis."""
+        return self.n_layers - self.linear_layers
+
+    @property
+    def linear_channels(self) -> int:
+        """Channels of a linear layer's convolution: q, k and v side by side."""
+        return self.linear_heads * (2 * self.linear_key_dim + self.linear_value_dim)
 
     @property
     def block(self) -> int:
@@ -213,18 +285,18 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
     def one_layer(k, experts: bool):
         ks = jax.random.split(k, 8)
         layer = {
-            "attn_norm": jnp.ones((d,), pd),
             "wq": dense_init(ks[0], (d, h, dh), d),
             "wk": dense_init(ks[1], (d, hkv, dh), d),
             "wv": dense_init(ks[2], (d, hkv, dh), d),
             "wo": dense_init(ks[3], (h, dh, d), h * dh),
-            "ffn_norm": jnp.ones((d,), pd),
         }
+        if cfg.pre_norms:
+            layer.update(attn_norm=jnp.ones((d,), pd), ffn_norm=jnp.ones((d,), pd))
         # leaves of the newer switches draw from keys folded off the layer's
         # own, so the eight splits above stay what they were
         if cfg.qk_norm:
-            layer["q_norm"] = jnp.ones((dh,), pd)
-            layer["k_norm"] = jnp.ones((dh,), pd)
+            layer["q_norm"] = jnp.ones((h * dh if cfg.qk_norm_whole else dh,), pd)
+            layer["k_norm"] = jnp.ones((hkv * dh if cfg.qk_norm_whole else dh,), pd)
         if cfg.attn_gate:
             layer["wg"] = dense_init(jax.random.fold_in(k, 8), (d, h, dh), d)
         if cfg.post_norms:
@@ -252,9 +324,59 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
             layer["w2"] = dense_init(ks[6], (ff, d), ff)
         return layer
 
-    def stack(keys, experts: bool):
+    def linear_layer(k):
+        """A Gated DeltaNet layer: its mixer's leaves (``lin_*``, the gates'
+        ``A_log`` and ``dt_bias``, the convolution, the output norm) and the
+        dense FFN's. ``A_log`` and ``dt_bias`` are float32 buffers drawn as the
+        published layer draws them (A uniform in (0, 16); dt log-uniform in
+        [0.001, 0.1] through the inverse softplus), so that with seeded
+        weights the decay ``alpha = exp(-A softplus(.))`` spreads over (0, 1)."""
+        ks = jax.random.split(k, 12)
+        H, dk, dv, K = cfg.linear_heads, cfg.linear_key_dim, cfg.linear_value_dim, cfg.linear_conv_width
+        dt = jnp.exp(jax.random.uniform(ks[8], (H,)) * (math.log(0.1) - math.log(0.001)) + math.log(0.001))
+        layer = {
+            "lin_wq": dense_init(ks[0], (d, H, dk), d),
+            "lin_wk": dense_init(ks[1], (d, H, dk), d),
+            "lin_wv": dense_init(ks[2], (d, H, dv), d),
+            "lin_wg": dense_init(ks[3], (d, H, dv), d),
+            "lin_wo": dense_init(ks[4], (H, dv, d), H * dv),
+            "lin_wa": dense_init(ks[5], (d, H), d),
+            "lin_wb": dense_init(ks[6], (d, H), d),
+            "A_log": jnp.log(jax.random.uniform(ks[7], (H,), minval=1e-3, maxval=16.0)),
+            "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+            # [width, channels], channels = q, k, v side by side; tap j weighs the input 3 - j steps back
+            "conv_w": jax.random.uniform(ks[9], (K, cfg.linear_channels), minval=-1.0, maxval=1.0).astype(pd) / math.sqrt(K),
+            "o_norm": jnp.ones((dv,), pd),
+            "w1": dense_init(jax.random.fold_in(k, 4), (d, ff), d),
+            "w3": dense_init(jax.random.fold_in(k, 5), (d, ff), d),
+            "w2": dense_init(jax.random.fold_in(k, 6), (ff, d), ff),
+        }
+        if cfg.pre_norms:
+            layer.update(attn_norm=jnp.ones((d,), pd), ffn_norm=jnp.ones((d,), pd))
+        if cfg.post_norms:
+            layer.update(post_attn_norm=jnp.ones((d,), pd), post_ffn_norm=jnp.ones((d,), pd))
+        return layer
+
+    def stack(keys, experts: bool, make=None):
         # stacked layers: leaves get a leading [layers] dim, scanned in forward.
-        return jax.tree.map(lambda *xs: jnp.stack(xs), *[one_layer(k, experts) for k in keys])
+        make = make or (lambda k: one_layer(k, experts))
+        return jax.tree.map(lambda *xs: jnp.stack(xs), *[make(k) for k in keys])
+
+    if cfg.hybrid:
+        # a period of k linear layers and one full layer is the scan's body: the
+        # j-th linear layers of all periods are one stack [periods, ...] (so that
+        # the scan's slice of it is one layer's weights, read where they lie),
+        # the full layers another
+        k_lin, per = cfg.linear_per_period, cfg.linear_per_period + 1
+        params = {
+            "embed": dense_init(k_embed, (cfg.vocab_size, d), d),
+            "linear_layers": [stack(layer_keys[j::per], False, linear_layer) for j in range(k_lin)],
+            "layers": stack(layer_keys[k_lin::per], False),
+            "final_norm": jnp.ones((d,), pd),
+        }
+        if not cfg.tie_embeddings:
+            params["head"] = dense_init(k_head, (cfg.vocab_size, d), d)
+        return params
 
     nd = cfg.dense_stack
     params = {
@@ -296,13 +418,13 @@ def param_specs(
 
     def layer_specs(experts: bool):
         specs = {
-            "attn_norm": P(None, None),
             "wq": P(None, None, tp, None),
             "wk": P(None, None, kv, None),
             "wv": P(None, None, kv, None),
             "wo": P(None, tp, None, None),
-            "ffn_norm": P(None, None),
         }
+        if cfg.pre_norms:
+            specs.update(attn_norm=P(None, None), ffn_norm=P(None, None))
         if cfg.qk_norm:
             specs.update(q_norm=P(None, None), k_norm=P(None, None))
         if cfg.attn_gate:
@@ -325,6 +447,12 @@ def param_specs(
         return specs
 
     specs = {"embed": P(tp, None), "layers": layer_specs(cfg.num_experts > 0), "final_norm": P(None)}
+    if cfg.hybrid:
+        # replicated: the serving engine refuses a mesh for a config with linear layers (ROADMAP R6)
+        names = ["lin_wq", "lin_wk", "lin_wv", "lin_wg", "lin_wo", "lin_wa", "lin_wb", "A_log", "dt_bias",
+                 "conv_w", "o_norm", "w1", "w3", "w2"]
+        names += ["attn_norm", "ffn_norm"] * cfg.pre_norms + ["post_attn_norm", "post_ffn_norm"] * cfg.post_norms
+        specs["linear_layers"] = [{name: P() for name in names} for _ in range(cfg.linear_per_period)]
     if cfg.dense_stack:
         specs["dense_layers"] = layer_specs(False)
     if not cfg.tie_embeddings:
@@ -675,8 +803,14 @@ def block_qkv(cfg: TransformerConfig, layer, h, positions, kind=None):
     q = jnp.einsum("btd,dhk->bthk", h, layer["wq"].astype(h.dtype))
     k = jnp.einsum("btd,dhk->bthk", h, layer["wk"].astype(h.dtype))
     v = jnp.einsum("btd,dhk->bthk", h, layer["wv"].astype(h.dtype))
-    if cfg.qk_norm:
+    if cfg.qk_norm_whole:
+        # one gain vector over all heads' outputs, the mean square over the whole projection
+        q = _rms_norm(q.reshape(*q.shape[:2], -1), layer["q_norm"], cfg.norm_eps).reshape(q.shape)
+        k = _rms_norm(k.reshape(*k.shape[:2], -1), layer["k_norm"], cfg.norm_eps).reshape(k.shape)
+    elif cfg.qk_norm:
         q, k = _rms_norm(q, layer["q_norm"], cfg.norm_eps), _rms_norm(k, layer["k_norm"], cfg.norm_eps)
+    if kind is not None and kind["rope"] is False:  # known while tracing: a period's full layer
+        return q, k, v
     rq, rk = _rope(q, positions, cfg.rope_theta), _rope(k, positions, cfg.rope_theta)
     if kind is None:
         return rq, rk, v
@@ -699,7 +833,7 @@ def block_ffn(cfg: TransformerConfig, layer, x, valid=None, *, stack=None, index
     experts' weights, see :func:`scanned_leaves`; ``kernel``: whether its
     grouped products may be a Mosaic call, see :func:`moe_ffn_dropless`).
     Returns (x, the dropless layer's assignment counts or None)."""
-    h = _rms_norm(x, layer["ffn_norm"], cfg.norm_eps)
+    h = pre_norm(cfg, layer, "ffn_norm", x)
     counts = None
     if "router" not in layer:
         ffn = _dense_ffn(layer, h)
@@ -710,6 +844,120 @@ def block_ffn(cfg: TransformerConfig, layer, x, valid=None, *, stack=None, index
     if cfg.post_norms:
         ffn = _rms_norm(ffn, layer["post_ffn_norm"], cfg.norm_eps)
     return x + ffn, counts
+
+
+def pre_norm(cfg: TransformerConfig, layer, name: str, x):
+    """A branch's input: ``RMSNorm(x)`` by the gain ``name``, or ``x`` itself without pre_norms."""
+    return _rms_norm(x, layer[name], cfg.norm_eps) if cfg.pre_norms else x
+
+
+# ---------------------------------------------------------------------------
+# the "linear" layer (Gated DeltaNet) and the period scan of a hybrid config
+# ---------------------------------------------------------------------------
+def linear_inputs(cfg: TransformerConfig, layer, h, tail=None, lengths=None):
+    """What the recurrence of a linear layer takes, from the layer's input
+    ``h`` [B, T, d]: q, k [B, T, H, dk] (unit norm, q over sqrt(dk)), v
+    [B, T, H, dv], ``g = log alpha`` and ``beta`` [B, T, H], all float32, and
+    the convolution's tail after the call.
+
+    q, k and v pass a causal depthwise convolution of ``linear_conv_width``
+    over time and SiLU. ``tail`` [B, width - 1, channels] holds the inputs
+    before this call (None: zeros, the sequence starts here); the tail
+    returned holds the last ``width - 1`` inputs up to ``lengths`` [B] real
+    tokens of this call (None: all ``T``)."""
+    B, T, _ = h.shape
+    H, dk, dv, K = cfg.linear_heads, cfg.linear_key_dim, cfg.linear_value_dim, cfg.linear_conv_width
+    f32 = jnp.float32
+    u = jnp.concatenate([jnp.einsum("btd,dhk->bthk", h, layer[name].astype(h.dtype)).reshape(B, T, -1)
+                         for name in ("lin_wq", "lin_wk", "lin_wv")], axis=-1)          # [B, T, channels]
+    if tail is None:
+        tail = jnp.zeros((B, K - 1, u.shape[-1]), u.dtype)
+    seq = jnp.concatenate([tail.astype(u.dtype), u], axis=1)                             # [B, K - 1 + T, channels]
+    w = layer["conv_w"].astype(f32)
+    c = jax.nn.silu(sum(seq[:, j : j + T].astype(f32) * w[j] for j in range(K)))
+    if lengths is None:
+        new_tail = seq[:, T:]
+    else:
+        new_tail = jax.vmap(lambda s, n: jax.lax.dynamic_slice_in_dim(s, n, K - 1, axis=0))(seq, lengths)
+    q, k, v = jnp.split(c, [H * dk, 2 * H * dk], axis=-1)
+    q, k, v = q.reshape(B, T, H, dk), k.reshape(B, T, H, dk), v.reshape(B, T, H, dv)
+
+    def unit(x):
+        return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
+
+    # the gates in float32 in fact: the chip's default precision would round h and the weights' product
+    a = jnp.einsum("btd,dh->bth", h.astype(f32), layer["lin_wa"].astype(f32), precision="highest")
+    b = jnp.einsum("btd,dh->bth", h.astype(f32), layer["lin_wb"].astype(f32), precision="highest")
+    g = -jnp.exp(layer["A_log"].astype(f32)) * jax.nn.softplus(a + layer["dt_bias"].astype(f32))
+    beta = jax.nn.sigmoid(b) * (2.0 if cfg.linear_allow_neg_eigval else 1.0)
+    return unit(q) / math.sqrt(dk), unit(k), v, g, beta, new_tail
+
+
+def linear_out(cfg: TransformerConfig, layer, x, h, o):
+    """The linear branch's tail: ``RMSNorm_dv(o) * silu(h Wg)``, ``Wo``, post-norm, residual. o: [B,T,H,dv] f32."""
+    gate = jnp.einsum("btd,dhv->bthv", h, layer["lin_wg"].astype(h.dtype))
+    y = (_rms_norm(o, layer["o_norm"].astype(jnp.float32), cfg.norm_eps) * jax.nn.silu(gate.astype(jnp.float32))).astype(h.dtype)
+    a = jnp.einsum("bthv,hvd->btd", y, layer["lin_wo"].astype(y.dtype))
+    if cfg.post_norms:
+        a = _rms_norm(a, layer["post_attn_norm"], cfg.norm_eps)
+    return x + a
+
+
+def hybrid_scan(cfg: TransformerConfig, params, carry, x, linear_fn, full_fn):
+    """The layer loop of a config with linear layers: a ``lax.scan`` over its
+    periods whose body runs the period's ``k`` linear layers and then its
+    full layer, so what is traced and compiled is one period, whatever the
+    depth. ``linear_fn(carry, x, layer, i)`` and ``full_fn(carry, x, layer,
+    i)`` return ``(carry, x)``; ``i`` counts the layers of their kind from 0
+    (traced); ``carry`` is whatever the caller keeps beside ``x`` (the
+    caches, updated in place). Returns ``(carry, x)``.
+
+    The ``k`` linear layers are the body's own lines, each with its own
+    stack among the scan's xs: the scan's slice of a stack is then one
+    layer's weights, which the products read where they lie. (An inner scan
+    over a ``[periods, k, ...]`` stack takes the period's slice as a loop
+    invariant, and a static index into such a slice fares no better: XLA
+    copies the slice out of the stack every period. The chipless compile at
+    the benchmark's sizes: 1.3 GB of temporaries, which a decode step would
+    write and read besides the weights themselves.)"""
+    k = cfg.linear_per_period
+
+    def period(state, xs):
+        lin, full, p = xs
+        for j in range(k):
+            state = linear_fn(*state, lin[j], p * k + j)
+        return full_fn(*state, full, p), None
+
+    if cfg.remat:
+        period = jax.checkpoint(period, policy=jax.checkpoint_policies.dots_saveable if cfg.remat == "dots" else None)
+    (carry, x), _ = jax.lax.scan(period, (carry, x),
+                                 (tuple(params["linear_layers"]), params["layers"], jnp.arange(cfg.periods, dtype=jnp.int32)))
+    return carry, x
+
+
+def full_kind(cfg: TransformerConfig):
+    """The ``kind`` of a hybrid config's full layers: no window; RoPE or none is known while tracing."""
+    return {"window": None, "rope": bool(cfg.rope_full_layers)}
+
+
+def _hybrid_forward(cfg: TransformerConfig, params, x, positions, use_flash: bool):
+    B = x.shape[0]
+    S0 = jnp.zeros((B, cfg.linear_heads, cfg.linear_key_dim, cfg.linear_value_dim), jnp.float32)
+
+    def linear_fn(carry, x, layer, _):
+        h = pre_norm(cfg, layer, "attn_norm", x)
+        q, k, v, g, beta, _ = linear_inputs(cfg, layer, h)
+        o, _ = gated_delta_chunked(S0, q, k, v, g, beta)
+        x = linear_out(cfg, layer, x, h, o)
+        return carry, block_ffn(cfg, layer, x)[0]
+
+    def full_fn(carry, x, layer, _):
+        h = pre_norm(cfg, layer, "attn_norm", x)
+        q, k, v = block_qkv(cfg, layer, h, positions, full_kind(cfg))
+        x = block_attn_out(cfg, layer, x, h, _attention(cfg, q, k, v, use_flash))
+        return carry, block_ffn(cfg, layer, x)[0]
+
+    return hybrid_scan(cfg, params, (), x, linear_fn, full_fn)[1]
 
 
 def unembed(cfg: TransformerConfig, params, x):
@@ -740,9 +988,14 @@ def forward(
     x = embed_tokens(cfg, params, tokens)
     positions = jnp.broadcast_to(jnp.arange(T)[None, :], (B, T))
 
+    if cfg.hybrid:
+        if act_spec is not None:
+            raise ValueError('a config with "linear" layers runs on one device: its forward takes no mesh')
+        return unembed(cfg, params, _hybrid_forward(cfg, params, x, positions, use_flash))
+
     def layer_fn(stack, x, layer_xs):
         layer, kind, index = layer_xs
-        h = _rms_norm(x, layer["attn_norm"], cfg.norm_eps)
+        h = pre_norm(cfg, layer, "attn_norm", x)
         q, k, v = block_qkv(cfg, layer, h, positions, kind)
         o = _attention(cfg, q, k, v, use_flash, mesh=mesh, sp_axis=sp_axis,
                        window=None if kind is None else kind["window"])

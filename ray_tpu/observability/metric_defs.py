@@ -436,6 +436,41 @@ LLM_PREFIX_EVICTIONS = _reg.counter(
     "tie-break) to return pages to a short pool or to respect "
     "prefix_cache_max_blocks.",
 )
+LLM_STATE_SNAPSHOT_POOL_SIZE = _reg.gauge(
+    "llm_state_snapshot_pool_size",
+    "Entries of the LLM engine's state-snapshot pool (a config with linear "
+    "layers: one entry holds every recurrent layer's state and convolution "
+    "tail after some cached prefix; 0 = none, or the engine was shut down).",
+    "snapshots",
+)
+LLM_STATE_SNAPSHOTS_IN_USE = _reg.gauge(
+    "llm_state_snapshots_in_use",
+    "State-snapshot pool entries held: by live requests (taken, not yet "
+    "published) and by prefix-cache nodes. At pool_size the next snapshot "
+    "detaches the least recently used one from its node.",
+    "snapshots",
+)
+LLM_STATE_SNAPSHOTS_TAKEN = _reg.counter(
+    "llm_state_snapshots_taken_total",
+    "Recurrent-state snapshots copied on the device: after a prompt's last "
+    "whole page during prefill, and after decode steps that end a page.",
+)
+LLM_STATE_SNAPSHOTS_EVICTED = _reg.counter(
+    "llm_state_snapshots_evicted_total",
+    "State snapshots detached from their prefix-cache node because the "
+    "snapshot pool was full (LRU; the node's page stays, so a later request "
+    "matches the page and re-prefills from the deepest snapshot left).",
+)
+LLM_STATE_RESTORES = _reg.counter(
+    "llm_state_restores_total",
+    "Admissions whose decode slot started from a state snapshot's copy "
+    "(prefill skipped up to the snapshot's node).",
+)
+LLM_STATE_ZEROED = _reg.counter(
+    "llm_state_zeroed_total",
+    "Admissions whose decode slot started from a zero recurrent state (no "
+    "snapshot on the matched path: the whole prompt is prefilled).",
+)
 LLM_LOOP_PHASE_SECONDS = _reg.counter(
     "llm_loop_phase_seconds_total",
     "Wall time of the LLM engine's loop thread by phase (serve/llm.py "
@@ -605,6 +640,12 @@ ALL_METRICS = [
     LLM_PREFIX_CACHE_BLOCKS,
     LLM_KV_BLOCKS_SHARED,
     LLM_PREFIX_EVICTIONS,
+    LLM_STATE_SNAPSHOT_POOL_SIZE,
+    LLM_STATE_SNAPSHOTS_IN_USE,
+    LLM_STATE_SNAPSHOTS_TAKEN,
+    LLM_STATE_SNAPSHOTS_EVICTED,
+    LLM_STATE_RESTORES,
+    LLM_STATE_ZEROED,
     LLM_LOOP_PHASE_SECONDS,
     LLM_DECODE_DISPATCHES,
     LLM_TTFT,

@@ -25,6 +25,13 @@ per block-table entry) or the prefix cache (one reference per cached
 node), so every existing release path stays a plain ``free`` of the slot's
 pages.
 
+A configuration with recurrent layers keeps a second pool beside the pages:
+:class:`SnapshotPool`, the free list over the entries of a device array of
+state snapshots (one entry: every recurrent layer's state and convolution
+tail after some prefix). An entry has one owner at a time: a live request
+(a snapshot taken for it and not yet published) or a node of the prefix
+cache; there is nothing to share, so there are no reference counts.
+
 Not thread-safe on its own: the engine serializes every alloc/share/free
 under its admission lock, same as the WeightedFairQueue.
 """
@@ -120,3 +127,32 @@ class BlockAllocator:
             if self._refs[b] == 0:
                 del self._refs[b]
                 self._free.append(b)
+
+
+class SnapshotPool:
+    """Free list over ``size`` state-snapshot entries. ``alloc`` gives -1
+    when none is free (the caller evicts one from the prefix cache or goes
+    without: a snapshot is an optimisation, never a request's need); a
+    double free or a foreign entry raises, as for pages."""
+
+    def __init__(self, size: int):
+        self.size = max(0, int(size))
+        self._free: List[int] = list(range(self.size - 1, -1, -1))
+        self._held = set()
+
+    @property
+    def in_use(self) -> int:
+        return len(self._held)
+
+    def alloc(self) -> int:
+        if not self._free:
+            return -1
+        idx = self._free.pop()
+        self._held.add(idx)
+        return idx
+
+    def free(self, idx: int) -> None:
+        if idx not in self._held:
+            raise ValueError(f"freeing state snapshot {idx} that is not held")
+        self._held.remove(idx)
+        self._free.append(idx)

@@ -52,6 +52,31 @@ dictated by XLA's static-shape compilation model:
   ``block_length`` on a commit, rows of one batch in different phases. The
   host knows each row's schedule by count, so the one step in flight stays.
 
+- **Recurrent state beside the pages.** A config with "linear" layers
+  (Gated DeltaNet: ``cfg.hybrid``) keeps keys and values in its full layers
+  only; its linear layers carry a recurrent state and a convolution tail a
+  sequence, which live in the cache at the sequence's decode slot. A slot's
+  state is zeroed or restored from a snapshot on the device at admission, in
+  order with the step in flight. A page match alone is no prefix hit there:
+  a request skips prefill only as far as the deepest matched radix node that
+  carries a *state snapshot* (``serve/prefix_cache.py``), an entry of a
+  second device pool (``state_snapshots`` entries, ``serve/kv_blocks.py``
+  ``SnapshotPool``) holding the state after exactly that node's tokens.
+  Snapshots are taken on the device right behind the program that produced
+  the state: after the chunk that ends a prompt's last whole page (when no
+  later one is certain to come) and after a decode step that ends a page
+  (every such step of a row with an EOS to wait for, else the last one of
+  the reply, known by count). One taken with a decode step is tentative
+  until that step's tokens are read and kept: a row-step discarded because
+  an EOS or a cancel was seen a step late has advanced the slot's state,
+  and its snapshot is dropped with it. A finished request's snapshot goes
+  to the radix node of its depth when its pages are published. Both pools
+  evict the least recently used, and requests are admitted in order of
+  arrival: a waiting session keeps its pages and its snapshot only while
+  the pools' turnover (the unreferenced pages over the rate new ones are
+  asked for) outlasts its wait; past that every returning turn prefills its
+  history again (``docs/tpu_design.md``, "State snapshots").
+
 ``LLMServer`` is the Serve-facing wrapper: a deployment class whose
 replicas each own an engine; requests arrive via handle/HTTP and block on a
 per-request Future.
@@ -74,27 +99,31 @@ import numpy as np
 from ray_tpu.exceptions import DeadlineExceededError
 from ray_tpu.models.generation import (
     copy_paged_page,
+    copy_sequence_state,
     export_paged_page,
     filter_top_k_top_p,
     init_paged_cache,
+    init_sequence_state,
     open_blocks,
     paged_block_step,
     paged_cache_spec,
     paged_forward_counted,
     select_rows,
     write_paged_pages,
+    zero_sequence_state,
 )
 from ray_tpu.models.transformer import TransformerConfig
 from ray_tpu.observability import metric_defs
 from ray_tpu.observability.sketch import LatencySketch
 from ray_tpu.observability.tracing import LoopClock
+from ray_tpu.ops.gated_delta import lane_group, unpack_state
 from ray_tpu.runtime import admission
 from ray_tpu.runtime.context import (
     current_deadline_ts,
     current_request_trace,
     current_tenant,
 )
-from ray_tpu.serve.kv_blocks import BlockAllocator
+from ray_tpu.serve.kv_blocks import BlockAllocator, SnapshotPool
 from ray_tpu.serve.prefix_cache import PrefixCache
 
 _STREAM_END = object()
@@ -186,6 +215,9 @@ class GenRequest:
     denoising_steps: int = 0
     block_known: int = 0
     forwards_left: int = 0
+    # a config with linear layers: the state snapshot taken for this request
+    # and not yet published, (snapshot pool entry, tokens it covers), or None
+    snap: Optional[Tuple[int, int]] = None
 
     def emit(self, tok: int) -> None:
         if self.stream_queue is not None:
@@ -241,6 +273,9 @@ class _Flight:
     rows: List[Tuple[int, GenRequest]]
     # a block step: slot -> known positions of the block this step commits
     commits: Dict[int, int] = field(default_factory=dict)
+    # state snapshots taken right behind this step, tentative until its tokens
+    # are read and kept: (request, snapshot pool entry, tokens covered)
+    snaps: List[Tuple[GenRequest, int, int]] = field(default_factory=list)
 
 
 def _bucket(n: int, lo: int = 16, cap: Optional[int] = None) -> int:
@@ -272,6 +307,10 @@ class LLMEngine:
     power-of-2 bucketed call. ``prefix_cache``: finished requests' full
     blocks stay cached and are shared into later requests, at most
     ``prefix_cache_max_blocks`` of them (0 = what the pool can spare).
+    ``state_snapshots``: entries of the state-snapshot pool of a config with
+    linear layers (None = twice ``max_batch_size``; 0 = none: every request
+    prefills its whole prompt); any other config has no such pool and takes
+    only None or 0.
     """
 
     def __init__(
@@ -297,6 +336,7 @@ class LLMEngine:
         prefix_cache: bool = True,
         prefix_cache_max_blocks: int = 0,
         role: Optional[str] = None,
+        state_snapshots: Optional[int] = None,
     ):
         self.cfg = cfg
         self.B = max_batch_size
@@ -362,6 +402,31 @@ class LLMEngine:
             if bad:
                 raise ValueError(f"a config with block_length {self._bk} (generation by diffusion over blocks) "
                                  f"cannot be served with " + "; ".join(bad))
+        # a config with linear layers keeps a recurrent state a sequence at
+        # its slot, and a pool of snapshots of it beside the page pool
+        self._hybrid = cfg.hybrid
+        if self._hybrid:
+            # no silent path: what the slots' state cannot follow yet is refused by name
+            refused = {
+                "decode_chunk > 1 (a snapshot is taken behind one step's state)": self.decode_chunk > 1,
+                "quantize=True (the int8 scales ride one stack of layers)": bool(quantize),
+                "mesh (the state and the snapshot pool are not sharded)": mesh is not None,
+            }
+            bad = [k for k, v in refused.items() if v]
+            if bad:
+                raise ValueError('a config with "linear" layers (recurrent state a sequence) cannot be served '
+                                 "with " + "; ".join(bad))
+        elif state_snapshots:
+            raise ValueError(f"state_snapshots={state_snapshots} belongs to a config with \"linear\" layers; "
+                             "this one keeps no recurrent state")
+        n_snapshots = (2 * self.B if state_snapshots is None else max(0, int(state_snapshots))) if self._hybrid else 0
+        self._n_snapshots = n_snapshots  # the pool's size: fixed, read without the lock
+        self._snap_pool = SnapshotPool(n_snapshots)
+        self._snaps = None  # the device arrays of the pool (``_reset_cache``)
+        self._state_snapshots_taken = 0
+        self._state_restores = 0
+        self._state_zeroed = 0
+        self._prefix_tokens_matched = 0
         self.top_k = top_k
         self.top_p = top_p
         self.quantized = quantize
@@ -481,7 +546,9 @@ class LLMEngine:
         self._prefill_kv_visited = 0.0
         self._prefill_kv_capacity = 0
         # (window, layers that have it); 0: a full layer
-        self._layers_by_window = sorted(Counter(cfg.layer_windows or (0,) * cfg.n_layers).items())
+        # (a linear layer has no K/V: ``_chunk_kv_visited`` still averages over every layer)
+        self._layers_by_window = sorted(Counter(
+            (0,) * cfg.kv_layers if self._hybrid else cfg.layer_windows or (0,) * cfg.n_layers).items())
         self._decode_step_count = 0
         # the decode step dispatched and not yet read (``_dispatch`` /
         # ``_collect``), steps dispatched while the one before was unread,
@@ -519,6 +586,9 @@ class LLMEngine:
         metric_defs.LLM_KV_BLOCKS_IN_USE.set(0, self._depth_tags)
         metric_defs.LLM_KV_BLOCKS_SHARED.set(0, self._depth_tags)
         metric_defs.LLM_PREFIX_CACHE_BLOCKS.set(0, self._depth_tags)
+        if self._hybrid:
+            metric_defs.LLM_STATE_SNAPSHOT_POOL_SIZE.set(self._n_snapshots, self._depth_tags)
+            metric_defs.LLM_STATE_SNAPSHOTS_IN_USE.set(0, self._depth_tags)
 
         self._reset_cache()
         self._key = jax.random.key(np.random.randint(0, 2**31 - 1))
@@ -562,21 +632,23 @@ class LLMEngine:
         # it is: the loop dispatches that run before it reads this one's.
         K_chunk = self.decode_chunk
         block = self._bk
+        hybrid = self._hybrid
 
         @functools.partial(jax.jit, donate_argnums=(1,), out_shardings=pool_among(2))
-        def _prefill_chunk(params, cache, toks, bt, start, length):
+        def _prefill_chunk(params, cache, toks, bt, start, length, slot=None):
             """toks [1, C] chunk-padded; bt [1, M]; start/length traced,
             so every chunk of every prompt at width C shares ONE
             compile. Writes K/V for the chunk's ``length`` real tokens
             through the block table and returns the last real token's
-            logits [V] (only the final chunk's are consumed)."""
+            logits [V] (only the final chunk's are consumed). ``slot`` [1]
+            (a config with linear layers): where the sequence's state lives."""
             C = toks.shape[1]
             positions = start + jnp.arange(C)[None, :]
             valid = (jnp.arange(C) < length)[None, :]
             logits, cache, moe = paged_forward_counted(
                 cfg_, params, cache, bt, toks, positions,
                 valid=valid, layer_scales=layer_scales, use_decode_kernel=use_kernel,
-                with_logits=block == 1,
+                with_logits=block == 1, slots=slot,
             )
             if logits is None:
                 # no token comes from a diffusion config's prefill: the head is
@@ -614,13 +686,15 @@ class LLMEngine:
             # a live row's first page is never the garbage page 0 (idle
             # rows decode through all-zero tables): the expert layers
             # count the live rows' assignments only
-            live = (bt[:, 0] > 0)[:, None] if moe_counted else None
+            # (and an idle row's recurrent state stays as it is)
+            live = (bt[:, 0] > 0)[:, None] if moe_counted or hybrid else None
+            slots = jnp.arange(bt.shape[0], dtype=jnp.int32) if hybrid else None
 
             def body(carry, _):
                 cache, toks, pos, key = carry
                 logits, cache, moe = paged_forward_counted(
                     cfg_, params, cache, bt, toks[:, None], pos[:, None],
-                    layer_scales=layer_scales, use_decode_kernel=use_kernel, valid=live,
+                    layer_scales=layer_scales, use_decode_kernel=use_kernel, valid=live, slots=slots,
                 )
                 key, sub = jax.random.split(key)
                 nxt = _sample_impl(sub, logits[:, 0], temps)
@@ -650,6 +724,18 @@ class LLMEngine:
         self._export_page = jax.jit(functools.partial(export_paged_page, cfg_))
         self._prefill_chunk = _prefill_chunk
         self._decode_k_paged = _decode_k_paged
+        if hybrid:
+            # a slot's state at admission: zero, or a snapshot's copy; and the
+            # snapshots of one dispatch, one program (``n`` of the ``B`` pairs
+            # are real). Slots and entries are traced: one compile each
+            self._zero_state = jax.jit(zero_sequence_state, donate_argnums=(0,))
+            self._restore_state = jax.jit(copy_sequence_state, donate_argnums=(0,))
+
+            def _snapshot_rows(snaps, cache, slots, entries, n):
+                return jax.lax.fori_loop(
+                    0, n, lambda i, snaps: copy_sequence_state(snaps, cache, entries[i], slots[i]), snaps)
+
+            self._snapshot_state = jax.jit(_snapshot_rows, donate_argnums=(0,))
 
         self._thread = threading.Thread(target=self._loop, daemon=True, name="llm-engine")
         self._thread.start()
@@ -715,6 +801,9 @@ class LLMEngine:
                 f"prompt ({len(prompt)}) + max_tokens ({max_tokens}) exceeds "
                 f"engine max_seq_len {self.S}"
             )
+        if self._hybrid and (_export_mig_id is not None or _import_ticket is not None):
+            raise ValueError('prefill_export / adopt_migration are not supported for a config with "linear" layers: '
+                             "a migrated block set carries pages, not the sequence's recurrent state")
         if self._bk == 1:
             if denoising_steps is not None:
                 raise ValueError("denoising_steps belongs to a config that generates by diffusion over blocks "
@@ -1021,7 +1110,29 @@ class LLMEngine:
                 "kv_live_pages": self.kv_live_pages(),
                 **self._moe_stats_locked(),
                 **self._block_stats_locked(),
+                **self._state_stats_locked(),
             }
+
+    def _state_stats_locked(self) -> Dict[str, Any]:
+        """The recurrent state's own counters (absent for a config without
+        linear layers): the snapshot pool's size and entries held (by live
+        requests and by radix nodes), snapshots taken, snapshots detached
+        because the pool was full, slots restored from a snapshot and slots
+        zeroed at admission, the prompt tokens a page match offered
+        (``prefix_tokens_reused`` beside it: those a snapshot let the engine
+        skip), and the bytes a slot's state and convolution tail take."""
+        if not self._hybrid:
+            return {}
+        return {
+            "state_snapshot_pool_size": self._snap_pool.size,
+            "state_snapshots_in_use": self._snap_pool.in_use,
+            "state_snapshots_taken": self._state_snapshots_taken,
+            "state_snapshots_evicted": self._prefix.snapshot_evictions if self._prefix is not None else 0,
+            "state_restores": self._state_restores,
+            "state_zeroed": self._state_zeroed,
+            "prefix_tokens_matched": self._prefix_tokens_matched,
+            "state_bytes_per_slot": self._state_bytes_per_slot,
+        }
 
     def _block_stats_locked(self) -> Dict[str, Any]:
         """A diffusion config's own counters (absent otherwise): block steps
@@ -1130,6 +1241,9 @@ class LLMEngine:
         metric_defs.LLM_KV_BLOCK_POOL_SIZE.set(0, self._depth_tags)
         metric_defs.LLM_KV_BLOCKS_SHARED.set(0, self._depth_tags)
         metric_defs.LLM_PREFIX_CACHE_BLOCKS.set(0, self._depth_tags)
+        if self._hybrid:
+            metric_defs.LLM_STATE_SNAPSHOT_POOL_SIZE.set(0, self._depth_tags)
+            metric_defs.LLM_STATE_SNAPSHOTS_IN_USE.set(0, self._depth_tags)
         with self._lock:
             pending = [r for r in self._queue.items() if not r.future.done()]
             pending += [r for r in self._slots if r is not None and not r.future.done()]
@@ -1161,7 +1275,7 @@ class LLMEngine:
         if self._prefix is None:
             return 0
         with self._lock:
-            pages = self._prefix.evict(len(self._prefix), self._evictable)
+            pages = self._evict_pages_locked(len(self._prefix))
             if pages:
                 self._allocator.free(pages)
             gauges = self._pool_gauges_locked()
@@ -1170,11 +1284,61 @@ class LLMEngine:
         self._publish_pool_gauges(*gauges)
         return len(pages)
 
+    def state_snapshot(self, tokens: List[int]) -> Optional[Dict[str, Any]]:
+        """Read-out for a check (a config with linear layers, an engine at
+        rest): the deepest state snapshot the prefix cache holds on the path
+        of ``tokens``, as ``{"tokens": how many of them it covers, "state":
+        the recurrent state after exactly those, float32 [linear layers,
+        heads, key dim, value dim]}``, or None if no node on the path carries
+        one. No clock of either pool moves. The engine thread replaces the
+        pool's arrays whenever it takes a snapshot, so call this while
+        nothing decodes."""
+        if self._prefix is None or self._snaps is None:
+            return None
+        with self._lock:
+            entry, covered = self._prefix.snapshot_at(tokens)
+            snaps = self._snaps
+        if entry < 0:
+            return None
+        group = lane_group(self.cfg.linear_heads, self.cfg.linear_value_dim)
+        return {"tokens": covered, "state": np.asarray(unpack_state(snaps["state"][:, entry], group))}
+
     def _evictable(self, page: int) -> bool:
         """An eviction may only take pages whose sole reference is the
         cache's own — refcount 1 means no live block table names the page.
         Caller holds ``self._lock``."""
         return self._allocator.refcount(page) == 1
+
+    def _evict_pages_locked(self, want: int) -> List[int]:
+        """LRU-evict up to ``want`` unreferenced cached leaves and return
+        their pages for the caller to free; the state snapshots of the nodes
+        that went return to their pool here. Caller holds ``self._lock``."""
+        pages = self._prefix.evict(want, self._evictable)
+        self._reclaim_snapshots_locked()
+        return pages
+
+    def _reclaim_snapshots_locked(self) -> None:
+        """Return to the snapshot pool the entries of radix nodes that went."""
+        for entry in self._prefix.take_freed_snapshots():
+            self._snap_pool.free(entry)
+
+    def _drop_snapshot_locked(self, req: GenRequest) -> None:
+        """A request leaves without publishing its pages: its snapshot goes too."""
+        if req.snap is not None:
+            self._snap_pool.free(req.snap[0])
+            req.snap = None
+
+    def _alloc_snapshot_locked(self) -> int:
+        """An entry of the snapshot pool: a free one, else the least recently
+        used one a radix node carries (its pages stay), else -1: the caller
+        goes without. Snapshot exhaustion fails no request."""
+        entry = self._snap_pool.alloc()
+        if entry < 0 and self._prefix is not None:
+            freed = self._prefix.evict_snapshot()
+            if freed >= 0:
+                self._snap_pool.free(freed)
+                entry = self._snap_pool.alloc()
+        return entry
 
     def _pool_gauges_locked(self):
         """(in_use, shared, cache_blocks) snapshot; caller holds the lock."""
@@ -1360,8 +1524,17 @@ class LLMEngine:
             with self._lock:
                 pages: List[int] = []
                 matched = 0
-                if self._prefix is not None:
+                snapshot = -1
+                if self._prefix is not None and not self._hybrid:
                     pages, matched = self._prefix.match(req.prompt)
+                elif self._prefix is not None:
+                    # the state after the matched pages has to exist too: skip
+                    # as far as the deepest matched node with a snapshot, short
+                    # of the last token (its logits seed sampling, and a state
+                    # cannot be stepped back), and share no page beyond it
+                    pages, offered, snapshot, matched = self._prefix.match_snapshot(req.prompt, tp - 1)
+                    self._prefix_tokens_matched += min(offered, (tp - 1) // bs * bs)
+                    pages = pages[: matched // bs]
                 cow_src = -1
                 if matched == tp and self._bk == 1:
                     # full-prompt hit: the tail block must be writable (a
@@ -1380,7 +1553,7 @@ class LLMEngine:
                 if short > 0 and self._prefix is not None:
                     # pool short: LRU-sweep unreferenced cached leaves
                     # before holding (and long before admission sheds)
-                    evicted = self._prefix.evict(short, self._evictable)
+                    evicted = self._evict_pages_locked(short)
                     if evicted:
                         self._allocator.free(evicted)
                         evicted_n = len(evicted)
@@ -1425,6 +1598,8 @@ class LLMEngine:
             # chunked prefill resumes at the first token whose KV is not
             # already in the table (tp - 1 for a full hit: one recompute)
             req.prefill_pos = matched
+            if self._hybrid and not self._place_state(req, snapshot):
+                continue
             if cow_src >= 0:
                 try:
                     dst = blocks[len(pages)]  # the fresh page for the tail block
@@ -1458,6 +1633,80 @@ class LLMEngine:
                 continue
             with self._lock:
                 self._prefilling.append(req)
+
+    def _place_state(self, req: GenRequest, snapshot: int) -> bool:
+        """The admitted request's slot starts from the state its prefill
+        resumes from: the snapshot's copy (the entry may go to another owner
+        right after: the device runs the copy first) or zero. Enqueued behind
+        whatever the slot's last occupant still had in flight, so nothing of
+        it survives. False: the copy failed and the request with it."""
+        try:
+            with jax.profiler.TraceAnnotation("llm::state_restore"):
+                if snapshot >= 0:
+                    self._cache = self._restore_state(self._cache, self._snaps, jnp.int32(req.slot), jnp.int32(snapshot))
+                else:
+                    self._cache = self._zero_state(self._cache, jnp.int32(req.slot))
+        except BaseException as exc:  # noqa: BLE001
+            self._fail_admit(req, exc)
+            return False
+        with self._lock:
+            if snapshot >= 0:
+                self._state_restores += 1
+            else:
+                self._state_zeroed += 1
+        (metric_defs.LLM_STATE_RESTORES if snapshot >= 0 else metric_defs.LLM_STATE_ZEROED).inc()
+        return True
+
+    def _snapshot_after_prompt(self, req: GenRequest) -> int:
+        """Tokens of ``req``'s prompt a snapshot is to be taken after during
+        prefill: its whole pages, or 0 for none: no snapshot pool or prefix
+        cache, a prompt shorter than a page, or a reply that is certain (no
+        EOS to end it early) to reach a later page boundary while decoding,
+        whose snapshot would replace this one."""
+        bs = self.kv_block_size
+        tp = len(req.prompt)
+        whole = tp // bs * bs
+        if not self._n_snapshots or self._prefix is None or not whole:
+            return 0
+        later = req.eos_id is None and tp + req.max_tokens - 2 >= whole + bs - 1
+        return 0 if later else whole
+
+    def _take_snapshots(self, rows: List[Tuple[GenRequest, int]]) -> List[Tuple[GenRequest, int, int]]:
+        """Enqueue, behind the program that produced them, the copies of the
+        states of ``rows`` ((request, tokens its state then covers)) into
+        entries of the snapshot pool: one program for all of them. Returns
+        (request, entry, tokens) of those that got an entry."""
+        taken: List[Tuple[GenRequest, int, int]] = []
+        if not rows:  # (always, without a prefix cache to publish them to)
+            return taken
+        with self._lock:
+            detached = self._prefix.snapshot_evictions
+            for req, tokens in rows:
+                entry = self._alloc_snapshot_locked()
+                if entry >= 0:
+                    taken.append((req, entry, tokens))
+            in_use = self._snap_pool.in_use
+            self._state_snapshots_taken += len(taken)
+            detached = self._prefix.snapshot_evictions - detached
+        if detached:
+            metric_defs.LLM_STATE_SNAPSHOTS_EVICTED.inc(detached)
+        if not taken:
+            return taken
+        slots, entries = np.zeros(self.B, np.int32), np.zeros(self.B, np.int32)
+        for j, (req, entry, _) in enumerate(taken):
+            slots[j], entries[j] = req.slot, entry
+        with jax.profiler.TraceAnnotation("llm::state_snapshot"):
+            self._snaps = self._snapshot_state(self._snaps, self._cache, jnp.asarray(slots), jnp.asarray(entries),
+                                               jnp.int32(len(taken)))
+        metric_defs.LLM_STATE_SNAPSHOTS_TAKEN.inc(len(taken))
+        metric_defs.LLM_STATE_SNAPSHOTS_IN_USE.set(in_use, self._depth_tags)
+        return taken
+
+    def _keep_snapshot(self, req: GenRequest, entry: int, tokens: int) -> None:
+        """``req``'s newest snapshot replaces the one it held."""
+        with self._lock:
+            self._drop_snapshot_locked(req)
+            req.snap = (entry, tokens)
 
     def _finish_prefill(self, req: GenRequest, logits) -> None:
         """Prompt is fully in the paged cache: sample the first token and
@@ -1671,6 +1920,7 @@ class LLMEngine:
         if req.slot >= 0:
             with self._lock:
                 self._release_blocks_locked(req.slot)
+                self._drop_snapshot_locked(req)
                 gauges = self._pool_gauges_locked()
             self._publish_pool_gauges(*gauges)
         if self._cache["k"].is_deleted():
@@ -1711,10 +1961,10 @@ class LLMEngine:
         self._slot_blocks[slot] = []
         self._block_tables[slot, :] = 0
         self._reserved[slot] = False
-        if not blocks:
-            return 0
-        if self._prefix is None:
-            self._allocator.free(blocks)
+        if not blocks or self._prefix is None:
+            self._drop_snapshot_locked(req)
+            if blocks:
+                self._allocator.free(blocks)
             return 0
         # the last sampled token was never written back to the KV cache;
         # every token before it was — cache exactly those full blocks. (A
@@ -1724,6 +1974,14 @@ class LLMEngine:
         # block holds past them was dropped, so that block's page is not full
         cached = req.prompt + (req.generated if self._bk > 1 else req.generated[:-1])
         adopted, evicted = self._prefix.insert(cached, blocks, self._evictable)
+        if req.snap is not None:
+            # the state after exactly ``tokens`` of ``cached`` goes to the node that ends them;
+            # where that node is not cached, or has one already, the entry is free again
+            entry, tokens = req.snap
+            if tokens <= len(cached) and self._prefix.attach_snapshot(cached, tokens, entry):
+                req.snap = None
+            self._drop_snapshot_locked(req)
+        self._reclaim_snapshots_locked()
         if evicted:
             self._allocator.free(evicted)
         rest = [b for b in blocks if b not in adopted]
@@ -1750,7 +2008,7 @@ class LLMEngine:
                 if old == 0 or self._allocator.refcount(old) <= 1:
                     continue
                 if self._allocator.free_blocks < 1 and self._prefix is not None:
-                    evicted = self._prefix.evict(1, self._evictable)
+                    evicted = self._evict_pages_locked(1)
                     if evicted:
                         self._allocator.free(evicted)
                 new = self._allocator.alloc(1)[0]  # typed shed if truly none
@@ -1777,6 +2035,7 @@ class LLMEngine:
             while self._prefilling and self._prefilling[0].cancelled:
                 req = self._prefilling.pop(0)
                 self._release_blocks_locked(req.slot)
+                self._drop_snapshot_locked(req)
                 self.num_shed += 1
                 admission.record_shed("engine", "disconnect")
                 self._record_done(
@@ -1800,6 +2059,12 @@ class LLMEngine:
         # a warm request's TTFT is proportional to what it actually computes
         width = min(chunk, self.S) if chunk > 0 else _bucket(tp - start, cap=self.S)
         n = min(width, tp - start)
+        # a config with linear layers: a snapshot after the prompt's whole pages
+        # needs a chunk that ends there (what is left of the prompt, under a
+        # page, is then a chunk of its own)
+        snap_at = self._snapshot_after_prompt(req) if self._hybrid else 0
+        if start < snap_at < start + n:
+            n = snap_at - start
         toks = np.zeros((1, width), np.int32)
         toks[0, :n] = req.prompt[start : start + n]
         stalled = bool(self._active.any())
@@ -1812,10 +2077,14 @@ class LLMEngine:
             # buffer after the call returns, and the mirror is rewritten at
             # will before the chunk has been waited for
             bt = jnp.asarray(self._block_tables[req.slot : req.slot + 1].copy())
+            slot = (jnp.asarray([req.slot], jnp.int32),) if self._hybrid else ()
             logits, self._cache, *moe = self._prefill_chunk(
                 self.params, self._cache, jnp.asarray(toks), bt,
-                jnp.int32(start), jnp.int32(n),
+                jnp.int32(start), jnp.int32(n), *slot,
             )
+            if snap_at and start + n == snap_at:
+                for taken in self._take_snapshots([(req, snap_at)]):
+                    self._keep_snapshot(*taken)
         except BaseException as exc:  # noqa: BLE001
             with self._lock:
                 self._prefilling.pop(0)
@@ -1912,6 +2181,8 @@ class LLMEngine:
         bs = self.kv_block_size
         lens = self._pos[self._active].astype(np.int64) + self._bk  # to the end of the step's writes
         last = -(-lens // bs)
+        if self._hybrid:  # the full layers walk every page, the linear layers none
+            return self.cfg.kv_layers * int(last.sum()) / self.cfg.n_layers
         windows = self.cfg.layer_windows or (0,)
         visited = sum(int((last - np.maximum(lens - w, 0) // bs).sum()) if w else int(last.sum()) for w in windows)
         return visited / len(windows)
@@ -1990,10 +2261,31 @@ class LLMEngine:
             self.params, self._cache, self._dev_toks, join, pos, temps, self._key, bt,
         )
         self._join_tok[:] = -1
+        snaps = self._take_snapshots(self._rows_ending_a_page(rows)) if self._hybrid else []
         for i, req in rows:
             req.dispatched += K
             self._pos[i] += K
-        return _Flight(out, moe, rows)
+        return _Flight(out, moe, rows, snaps=snaps)
+
+    def _rows_ending_a_page(self, rows: List[Tuple[int, GenRequest]]) -> List[Tuple[GenRequest, int]]:
+        """Of the rows of the decode step just enqueued (``_pos`` not yet
+        advanced), those whose state after it is to be snapshotted, each
+        with the tokens that state covers: the step writes position ``pos``
+        and that ends a page; a row with an EOS to wait for at every such
+        step, any other at the last one of its reply (the last position it
+        writes is known by count)."""
+        bs = self.kv_block_size
+        if not self._n_snapshots or self._prefix is None:
+            return []
+        out = []
+        for i, req in rows:
+            covered = int(self._pos[i]) + 1
+            if covered % bs:
+                continue
+            last_written = len(req.prompt) + req.max_tokens - 2
+            if req.eos_id is not None or covered + bs > last_written + 1:
+                out.append((req, covered))
+        return out
 
     def _dispatch_blocks(self, behind_chunk: bool) -> Optional[_Flight]:
         """:meth:`_dispatch` for a diffusion config: enqueue one block step
@@ -2084,6 +2376,15 @@ class LLMEngine:
         # rt-lint: disable=lock-discipline -- engine-thread-owned (see _dispatch)
         rows = [(i, req) for i, req in flight.rows if self._slots[i] is req and not req.cancelled]
         self._decode_row_steps_discarded += (len(flight.rows) - len(rows)) * K
+        for req, entry, tokens in flight.snaps:
+            # taken behind this step: kept if the row's step is (before its request may finish
+            # below and publish it); a discarded row-step's token is in its state, so it goes
+            # rt-lint: disable=lock-discipline -- engine-thread-owned (see _dispatch)
+            if self._slots[req.slot] is req and not req.cancelled:
+                self._keep_snapshot(req, entry, tokens)
+            else:
+                with self._lock:
+                    self._snap_pool.free(entry)
         self._clock.lap("emit")
         for k in range(K):
             for i, req in rows:
@@ -2099,7 +2400,16 @@ class LLMEngine:
     def _reset_cache(self) -> None:
         """(Re)allocate the decode cache — also the recovery path after a
         failed donated step leaves the old buffers deleted."""
-        init = functools.partial(init_paged_cache, self.cfg, self.kv_num_blocks, self.kv_block_size)
+        init = functools.partial(init_paged_cache, self.cfg, self.kv_num_blocks, self.kv_block_size,
+                                 **({"slots": self.B} if self._hybrid else {}))
+        if self._hybrid:
+            # the slots' states went with the cache: so do the snapshots of them
+            size = self._n_snapshots
+            with self._lock:
+                self._snap_pool = SnapshotPool(size)
+            self._snaps = init_sequence_state(self.cfg, size) if size else None
+            one = init_sequence_state(self.cfg, 1)
+            self._state_bytes_per_slot = int(sum(a.size * a.dtype.itemsize for a in one.values()))
         if self._kv_sharding is not None:
             # each device zeroes its own shard: the whole pool never lies on one
             init = jax.jit(init, out_shardings=self._kv_sharding)
@@ -2134,6 +2444,9 @@ class LLMEngine:
                 stale = self._prefix.drain()
                 if stale:
                     self._allocator.free(stale)
+                self._prefix.take_freed_snapshots()  # ``_reset_cache`` makes the snapshot pool anew
+            for r in victims:
+                r.snap = None
         # the step in flight goes with them: its rows' requests are victims
         # (engine-thread state, like the loop that dispatched it)
         self._flight = None
@@ -2158,10 +2471,11 @@ class LLMEngine:
                 (i, r) for i, r in enumerate(self._slots)
                 if r is not None and r.cancelled
             ]
-            for i, _ in victims:
+            for i, r in victims:
                 self._slots[i] = None
                 self._active[i] = False
                 self._release_blocks_locked(i)
+                self._drop_snapshot_locked(r)
             if self._bk > 1:
                 # a row of a diffusion config always has a block under way:
                 # its tentative K/V go with its pages, which nothing shared
@@ -2288,6 +2602,7 @@ class LLMServer:
         prefix_cache: bool = True,
         prefix_cache_max_blocks: int = 0,
         role: Optional[str] = None,
+        state_snapshots: Optional[int] = None,
     ):
         made = model_factory()
         cfg, params = made[0], made[1]
@@ -2313,6 +2628,7 @@ class LLMServer:
             prefix_cache=prefix_cache,
             prefix_cache_max_blocks=prefix_cache_max_blocks,
             role=role,
+            state_snapshots=state_snapshots,
         )
 
     def _encode(self, request: Dict[str, Any]) -> List[int]:
@@ -2365,6 +2681,9 @@ class LLMServer:
 
     def stats(self) -> Dict[str, Any]:
         return self.engine.stats()
+
+    def state_snapshot(self, tokens: List[int]) -> Optional[Dict[str, Any]]:
+        return self.engine.state_snapshot(tokens)
 
     def lowered_decode_text(self) -> str:
         return self.engine.lowered_decode_text()

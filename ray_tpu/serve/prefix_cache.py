@@ -34,6 +34,21 @@ parent whose last child was just taken joins the heap. That is
 that needs 110 pages from a pool of 6 000 cached ones examines ~6 100
 nodes, not 110 x 6 000. ``scanned`` counts the nodes examined.
 
+**State snapshots** (a configuration with recurrent layers). There a page
+match alone is no hit: the recurrent state after the matched tokens has to
+exist too. A node may carry a *snapshot*: the index of an entry of the
+engine's snapshot pool that holds the state after exactly the tokens of the
+chain down to that node. ``match_snapshot`` returns, with the pages, the
+deepest node on the path that carries one; the engine shares pages and skips
+prefill up to there and no further. A node's snapshot goes with the node
+(``evict``, ``drain``: the index lands in ``freed_snapshots`` for the engine
+to return to its pool); when the snapshot pool itself is full,
+``evict_snapshot`` detaches the least recently used one (used: attached, or
+restored from) and the node's page stays. A snapshot on a node with one
+child lies on the only path to the deeper snapshot just attached below it,
+so attaching ages it at once: no request can reach it without passing a
+better one. ``snapshot_at`` looks one up without touching any clock.
+
 The cache never touches device memory and never calls the allocator: the
 engine owns the allocator lock and frees/shares pages around these calls.
 Not thread-safe on its own; the engine serializes access under its
@@ -71,6 +86,8 @@ class _Node:
     seq: int  # insertion order — the deterministic LRU tie-break
     last_used: int  # monotonic touch counter (bumped on every match walk)
     children: int = 0  # live child count; leaf iff 0
+    snapshot: int = -1  # entry of the engine's state-snapshot pool; -1: none
+    snap_used: int = 0  # LRU clock of the snapshot (attached, restored from)
 
 
 class PrefixCache:
@@ -91,6 +108,11 @@ class PrefixCache:
         self._seq = 0  # insertion counter (never reused)
         self.evictions = 0  # cumulative, for the evictions counter metric
         self.scanned = 0  # cumulative nodes examined by evict(), added once a call
+        # state snapshots: the nodes that carry one, by pool entry, and the
+        # entries of nodes that went (the engine returns them to its pool)
+        self._snap_nodes: Dict[int, _Node] = {}
+        self.freed_snapshots: List[int] = []
+        self.snapshot_evictions = 0  # detached by evict_snapshot(): the pool was full
 
     def __len__(self) -> int:
         return len(self._nodes)
@@ -125,6 +147,93 @@ class PrefixCache:
             pages.append(node.page)
             parent = key
         return pages, len(pages) * bs
+
+    # -- state snapshots -----------------------------------------------------
+    @property
+    def snapshots(self) -> int:
+        return len(self._snap_nodes)
+
+    def _chain(self, tokens: Sequence[int], blocks: int) -> List[_Node]:
+        """The nodes of the first ``blocks`` blocks of ``tokens``, as far as cached."""
+        bs = self.block_size
+        out: List[_Node] = []
+        parent = _ROOT
+        for i in range(blocks):
+            parent = chain_key(parent, tokens[i * bs : (i + 1) * bs])
+            node = self._nodes.get(parent)
+            if node is None:
+                break
+            out.append(node)
+        return out
+
+    def match_snapshot(self, tokens: Sequence[int], limit: int) -> Tuple[List[int], int, int, int]:
+        """:meth:`match`, and the deepest node on the matched path that
+        carries a snapshot of at most ``limit`` tokens: ``(pages, matched
+        tokens, snapshot, snapshot tokens)``, the last two ``(-1, 0)``
+        where no node on the path has one. The snapshot's own LRU clock is
+        bumped: the caller restores from it."""
+        bs = self.block_size
+        path = self._chain(tokens, len(tokens) // bs)
+        best = -1
+        for i, node in enumerate(path):
+            self._touch(node)
+            if node.snapshot >= 0 and (i + 1) * bs <= limit:
+                best = i
+        if best < 0:
+            return [nd.page for nd in path], len(path) * bs, -1, 0
+        self._tick += 1
+        path[best].snap_used = self._tick
+        return [nd.page for nd in path], len(path) * bs, path[best].snapshot, (best + 1) * bs
+
+    def snapshot_at(self, tokens: Sequence[int]) -> Tuple[int, int]:
+        """The deepest snapshot on the cached path of ``tokens`` and the
+        tokens it covers, ``(-1, 0)`` if there is none; no clock moves (a
+        read-out, not a use)."""
+        bs = self.block_size
+        best = (-1, 0)
+        for i, node in enumerate(self._chain(tokens, len(tokens) // bs)):
+            if node.snapshot >= 0:
+                best = (node.snapshot, (i + 1) * bs)
+        return best
+
+    def attach_snapshot(self, tokens: Sequence[int], n_tokens: int, snapshot: int) -> bool:
+        """Give the node that ends the first ``n_tokens`` (whole blocks) of
+        ``tokens`` the snapshot ``snapshot``. False (the caller keeps the
+        entry, and frees it) if that node is not cached or carries one
+        already. Snapshots above it on single-child nodes age at once."""
+        bs = self.block_size
+        blocks = n_tokens // bs
+        path = self._chain(tokens, blocks) if blocks and n_tokens % bs == 0 else []
+        if len(path) != blocks or not path or path[-1].snapshot >= 0:
+            return False
+        self._tick += 1
+        path[-1].snapshot, path[-1].snap_used = int(snapshot), self._tick
+        self._snap_nodes[int(snapshot)] = path[-1]
+        for node in path[:-1]:
+            if node.snapshot >= 0 and node.children == 1:
+                node.snap_used = 0
+        return True
+
+    def evict_snapshot(self) -> int:
+        """Detach the least recently used snapshot (ties: the older node)
+        and return its pool entry, -1 if no node carries one. The node and
+        its page stay: a later request re-prefills from the deepest snapshot
+        left."""
+        if not self._snap_nodes:
+            return -1
+        node = min(self._snap_nodes.values(), key=lambda nd: (nd.snap_used, nd.seq))
+        self.snapshot_evictions += 1
+        return self._detach(node)
+
+    def _detach(self, node: _Node) -> int:
+        idx, node.snapshot = node.snapshot, -1
+        del self._snap_nodes[idx]
+        return idx
+
+    def take_freed_snapshots(self) -> List[int]:
+        """The pool entries of nodes that went since the last call."""
+        out, self.freed_snapshots = self.freed_snapshots, []
+        return out
 
     # -- insertion -----------------------------------------------------------
     def insert(
@@ -218,6 +327,8 @@ class PrefixCache:
             victim = heapq.heappop(heap)[2]
             del nodes[victim.key]
             freed.append(victim.page)
+            if victim.snapshot >= 0:
+                self.freed_snapshots.append(self._detach(victim))
             parent = nodes.get(victim.parent)  # None for a depth-0 victim
             if parent is None:
                 continue
@@ -236,4 +347,6 @@ class PrefixCache:
         index must not survive them."""
         pages = [nd.page for nd in self._nodes.values()]
         self._nodes.clear()
+        self.freed_snapshots.extend(self._snap_nodes)
+        self._snap_nodes.clear()
         return pages

@@ -422,6 +422,71 @@ def test_the_sdar_cells_block_step_and_prefill_chunk_compile_for_v5e_and_fit(as_
         assert m.argument_size_in_bytes + m.temp_size_in_bytes + m.output_size_in_bytes - m.alias_size_in_bytes < 15.5e9
 
 
+def test_the_olmo_hybrid_cells_decode_step_and_prefill_chunk_compile_for_v5e_and_fit(as_chip, v5e):
+    """The benchmark's hybrid configuration as its file states it (16 layers at
+    published widths: 12 Gated DeltaNet, 4 full attention; 32 slots, 3072
+    pages for the full layers only, 64 state snapshots): a decode step of 32
+    rows and a prefill chunk of 512 tokens compile for a v5e with both Mosaic
+    kernels in the decode step (the paged attention and the state update),
+    update the donated pages, states and convolution tails in place, and fit
+    the chip's 16 GB beside the snapshot pool. The memory analysis is what the
+    configuration file's ``sizing`` quotes."""
+    from benchmark import system
+    from ray_tpu.models import init_params
+    from ray_tpu.models.generation import (copy_sequence_state, init_paged_cache, init_sequence_state,
+                                           paged_forward_counted)
+
+    config = system.load_json("benchmark/configs/olmo-hybrid-7b-serve-l16.json")
+    run = config["run"]
+    cfg = system.model_module(config).program_config(
+        config, max_seq_len=run["max_seq_len"], dtype=run["dtype"], param_dtype=run["param_dtype"])
+    one = SingleDeviceSharding(v5e[0])
+    B, bs, C = run["max_batch_size"], run["kv_block_size"], run["prefill_chunk_tokens"]
+    M = run["max_seq_len"] // bs
+    params = _abstract_tree(lambda: init_params(cfg, jax.random.key(0)), one)
+    cache = _abstract_tree(lambda: init_paged_cache(cfg, run["kv_num_blocks"], bs, slots=B), one)
+    snaps = _abstract_tree(lambda: init_sequence_state(cfg, run["state_snapshots"]), one)
+    assert cache["k"].shape == (4, 3072, 16, 3840) and cache["state"].shape == (12, 32, 15, 96, 384)
+    assert cache["conv"].shape == (12, 32, 3 * 11520) and snaps["state"].shape == (12, 64, 15, 96, 384)
+    toks, pos, bt, chunk, row, slot, scalar = _abstract(
+        [((B,), I32), ((B,), I32), ((B, M), I32), ((1, C), I32), ((1, M), I32), ((1,), I32), ((), I32)], one)
+    nbytes = lambda tree: sum(int(np.prod(x.shape)) * x.dtype.itemsize for x in jax.tree.leaves(tree))  # noqa: E731
+    assert sum(int(np.prod(x.shape)) for x in jax.tree.leaves(params)) == system.model_module(config).n_params(config)
+
+    def step(params, cache, toks, pos, bt):
+        live = (bt[:, 0] > 0)[:, None]
+        logits, cache, _ = paged_forward_counted(cfg, params, cache, bt, toks[:, None], pos[:, None], valid=live,
+                                                 slots=jnp.arange(B, dtype=I32))
+        return jnp.argmax(logits[:, 0], -1), cache
+
+    def prefill(params, cache, toks, bt, start, length, slot):
+        valid = (jnp.arange(C) < length)[None, :]
+        logits, cache, _ = paged_forward_counted(cfg, params, cache, bt, toks, start + jnp.arange(C)[None, :],
+                                                 valid=valid, slots=slot)
+        return jax.lax.dynamic_index_in_dim(logits[0], length - 1, 0, keepdims=False), cache
+
+    report = {}
+    for name, fn, args, kernels in (("decode", step, (params, cache, toks, pos, bt), 2),
+                                    ("prefill_chunk", prefill, (params, cache, chunk, row, scalar, scalar, slot), 1)):
+        lowered = jax.jit(fn, donate_argnums=(1,)).trace(*args).lower(lowering_platforms=("tpu",))
+        text = lowered.as_text()
+        assert "tpu_custom_call" in text
+        if kernels == 2:
+            assert "gated_delta_decode" in text
+        m = lowered.compile().memory_analysis()
+        assert m.alias_size_in_bytes >= nbytes(cache)  # pages, states and tails are updated where they lie
+        need = m.argument_size_in_bytes + m.temp_size_in_bytes + m.output_size_in_bytes - m.alias_size_in_bytes
+        report[name] = {"arguments_gb": round(m.argument_size_in_bytes / 1e9, 2), "temporaries_gb": round(m.temp_size_in_bytes / 1e9, 2)}
+        assert need + nbytes(snaps) < 15.5e9, (name, report)
+    # a snapshot's copy moves one sequence's state and nothing else
+    lowered = jax.jit(copy_sequence_state, donate_argnums=(0,)).trace(snaps, cache, scalar, scalar).lower(
+        lowering_platforms=("tpu",))
+    m = lowered.compile().memory_analysis()
+    one_slot = nbytes({k: cache[k] for k in ("state", "conv")}) // B
+    assert m.temp_size_in_bytes < 2 * one_slot and one_slot == system.model_module(config).state_bytes_per_sequence(config)
+    print("olmo hybrid sizing:", report, "snapshots_gb", round(nbytes(snaps) / 1e9, 2), "cache_gb", round(nbytes(cache) / 1e9, 2))
+
+
 def _cell_programs(name):
     """A serve cell's decode (or block) step and prefill chunk at the
     benchmark's own configuration, abstract arguments and all, not placed."""
