@@ -59,6 +59,10 @@ FULL = dict(
     prefill=dict(H=16, D=64, widths=(16, 32, 64, 128, 256, 512, 1024)),
     decode=dict(B=8, H=16, Hkv=8, D=64, S=1024),
     paged=dict(B=8, H=16, Hkv=8, D=64, M=64, bs=16),
+    # (name, B, H, Hkv, D): the paged decode kernel at the three served families'
+    # batch and head shapes (eight 64-wide heads a unit, a query row each; a
+    # 128-wide head a unit with eight query rows; six of thirty such heads, a row each)
+    paged_decode=(("smollm2", 40, 32, 32, 64), ("trinity", 48, 32, 4, 128), ("olmo_hybrid", 32, 30, 30, 128)),
     # (name, H, Hkv, D, chunk width, start, real tokens, window, table pages): the
     # two served families' head shapes at their cells' chunk and capacity
     paged_prefill=(
@@ -91,6 +95,7 @@ REHEARSAL = dict(
     prefill=dict(H=2, D=64, widths=(16, 32)),
     decode=dict(B=2, H=4, Hkv=2, D=64, S=128),
     paged=dict(B=2, H=4, Hkv=2, D=64, M=4, bs=16),
+    paged_decode=(("packed", 2, 4, 4, 64), ("grouped", 3, 16, 2, 128)),
     paged_prefill=(("mha", 2, 2, 64, 32, 48, 20, None, 8), ("gqa_sliding", 8, 1, 128, 32, 80, 32, 40, 8)),
     block_step=dict(B=3, H=8, Hkv=2, D=64, Bk=4, M=8, tokens=70, reps=2),
     grouped=dict(reps=2, shapes=(("all_live", 24, 128, 128, 2, 8, 2, 24), ("two_live", 12, 128, 256, 3, 8, 2, 2))),
@@ -262,22 +267,25 @@ def leg_kernels(sz, on_chip):
     _check("decode_dense", out, ref, FWD_REL_TOL, errs)
 
     # paged decode kernel over the engine's pool layout (the second of three
-    # layers), shuffled tables, ragged lengths (one empty row, one full)
+    # layers), shuffled tables, ragged lengths (one empty row, one full), at the
+    # shape it has had here and at the three served families'
     g = sz["paged"]
-    B, H, Hkv, D, M, bs = g["B"], g["H"], g["Hkv"], g["D"], g["M"], g["bs"]
-    N = B * M + 1
-    q = rand(30, (B, H, D))
-    kp, vp = rand(31, (3, N, bs, Hkv * D)), rand(32, (3, N, bs, Hkv * D))
-    rng = np.random.default_rng(0)
-    bt = jnp.asarray(rng.permutation(np.arange(1, N)).reshape(B, M).astype(np.int32))
-    lengths = jnp.asarray(np.linspace(0, M * bs, B).astype(np.int32))
-    layer = jnp.int32(1)
-    out = _compile(jax.jit(paged_decode_attention), q, kp, vp, bt, lengths, layer, on_chip=on_chip)(
-        q, kp, vp, bt, lengths, layer
-    )
-    ref = ref_decode(q, kp, vp, bt, lengths, layer)
-    _check("decode_paged", out[1:], ref[1:], FWD_REL_TOL, errs)
-    assert not bool(jnp.any(out[0])), "decode_paged: an empty row must be zeros"
+    M, bs = g["M"], g["bs"]
+    for name, B, H, Hkv, D in [("", g["B"], g["H"], g["Hkv"], g["D"])] + [("_" + c[0], *c[1:]) for c in sz["paged_decode"]]:
+        N = B * M + 1
+        q = rand(30, (B, H, D))
+        kp, vp = rand(31, (3, N, bs, Hkv * D)), rand(32, (3, N, bs, Hkv * D))
+        rng = np.random.default_rng(0)
+        bt = jnp.asarray(rng.permutation(np.arange(1, N)).reshape(B, M).astype(np.int32))
+        lengths = jnp.asarray(np.linspace(0, M * bs, B).astype(np.int32))
+        layer = jnp.int32(1)
+        out = _compile(jax.jit(paged_decode_attention), q, kp, vp, bt, lengths, layer, on_chip=on_chip)(
+            q, kp, vp, bt, lengths, layer
+        )
+        ref = ref_decode(q, kp, vp, bt, lengths, layer)
+        _check(f"decode_paged{name}", out[1:], ref[1:], FWD_REL_TOL, errs)
+        assert not bool(jnp.any(out[0])), f"decode_paged{name}: an empty row must be zeros"
+        del q, kp, vp, out, ref
 
     # paged prefill kernel: a chunk of queries at a start over a shuffled
     # table, against the XLA lines on the same pool. At the serving model's

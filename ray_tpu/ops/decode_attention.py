@@ -2,9 +2,12 @@
 sequence (decode), dense or paged, and a chunk of queries over a paged pool.
 
 Decode attention is the per-token hot op of serving: one query row per
-sequence attends over the whole cache. It is purely HBM-bandwidth-bound —
-the FLOPs are trivial; what matters is streaming K/V exactly once at full
-bandwidth. The kernel:
+sequence attends over the whole cache. Its bytes set its floor (K and V are
+read once, nothing is reused), but its time is the bytes' only if the work
+around each block of keys hides under their copy: a block of scores is a few
+rows tall whatever the kernel does, so the products' fixed cost and the
+dependent chain max -> exp -> sum -> product are paid a block, not a FLOP. The
+dense kernel:
 
 - grids over (batch, kv_head, cache blocks) and streams K/V blocks through
   VMEM with online-softmax state in scratch (same revisited-output pattern
@@ -27,7 +30,18 @@ table, the lengths and the layer index ride Pallas scalar prefetch
 (``PrefetchScalarGridSpec``), the pools stay in HBM, and the body copies the
 pages a sequence can see, a group of 128 tokens at a time, straight out of
 the stacked pool — no per-layer slice, no materialized per-sequence cache
-copy, and no work for a page or a slot that holds nothing.
+copy, and no work for a page or a slot that holds nothing. The body's unit
+of work for a group of keys is a run of whole lane tiles of the page row,
+the neighbouring KV heads whose queries fill one sublane tile of rows
+(:func:`_heads_a_unit`): a row a head and query, each in its own head's
+lanes with exact zeros in the others', so one ``QK^T``, one softmax step and
+one ``P.V`` serve all of them. The operands go to the MXU in bf16 as the pool
+stores them, ``P`` rounded to bf16 in the open; scores and softmax state are
+float32. Kernel alone on a v5e (PERF.md section 5, PR 45) the page copies
+cost 1.1x their bytes' time at 128-240 KiB a page and a layer and the whole
+kernel 1.2-1.4x where eight or six heads share a unit; where a unit is one
+GQA group (four units a group of keys, 32 KiB a page) the copies cost 1.6x
+and the kernel 3.5x, the softmax chain of so few units standing in the open.
 ``use_kernel=False`` is the plain-XLA reference (a ``jnp.take`` gather that
 reduces to the dense math) the kernel is checked against.
 
@@ -243,7 +257,7 @@ def _walk_pages(tables_ref, row, layer, k_hbm, v_hbm, k_buf, v_buf, sems, block_
 
 def _paged_decode_kernel(
     tables_ref, lengths_ref, layer_ref,  # scalar-prefetch: [B, M] page ids, [B], [1]
-    *refs, sm_scale: float, block_size: int, pack: int, windowed: bool,
+    *refs, sm_scale: float, block_size: int, share: int, n_rep: int, windowed: bool,
 ):
     """Grid (B,): one grid step a sequence; its visible pages are walked by a
     loop inside the body (:func:`_walk_pages`), so a slot that holds nothing
@@ -253,13 +267,21 @@ def _paged_decode_kernel(
     are zeroed at the first grid step, so they only ever hold zeros or live
     pages of the pool.
 
-    The KV heads are walked in static lane tiles of ``W = pack*D`` lanes,
-    ``pack`` neighbouring heads to a tile, so a 64-wide head still loads
-    whole 128-lane tiles: q_ref is ``[Hkv, rep_p, W]`` with head ``g``'s
-    query in its own ``D`` lanes of its tile and zeros in its neighbours'
-    (their K lanes drop out of the scores as exact zeros), and o_ref/acc
-    carry ``W`` lanes of which the caller keeps head ``g``'s own. Per head
-    the state machine is :func:`_decode_kernel`'s, a group at a time.
+    The unit of work for a group of keys is ``W = share*D`` lanes of the page
+    row, whole lane tiles that hold ``share`` neighbouring KV heads
+    (:func:`_heads_a_unit`: as many as fill one sublane tile with their
+    queries). q_ref is ``[units, rows_p, W]``: row ``j*n_rep + r`` of a unit
+    is its head ``j``'s query ``r`` in that head's own ``D`` lanes and exact
+    zeros in its neighbours', whose K lanes so drop out of the row's scores
+    as exact zeros. One ``QK^T`` (contracting all ``W`` lanes), one softmax
+    step and one ``P.V`` then serve every head of the unit: the rows are
+    independent through the softmax, and a row's accumulator holds its own
+    head's output in that head's lanes (the other lanes are a neighbour's V
+    under this head's weights, and are dropped when o_ref ``[Hkv, n_rep, D]``
+    is written). K, V and q go to the MXU as they are stored
+    (:func:`_dot_qk`), the scale multiplies the float32 scores, and ``P`` is
+    rounded to the pool's bf16 for the one pass of ``P.V``
+    (:func:`_dot_pv`); scores, max, sum and accumulator are float32.
     ``windowed``: a fourth scalar-prefetch ref ``[1]`` carries this call's
     sliding window (0: none).
     """
@@ -268,7 +290,7 @@ def _paged_decode_kernel(
     q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, m_scr, l_scr, acc_scr = refs
     bi = pl.program_id(0)
     length = lengths_ref[bi]
-    n_kv, _, w = q_ref.shape
+    units, _, w = q_ref.shape
     span = k_buf.shape[1]
     first = window_start(length, window_ref[0]) if windowed else 0
     # a length past the table's capacity (a finished row's overshoot) walks
@@ -284,22 +306,22 @@ def _paged_decode_kernel(
     l_scr[...] = jnp.zeros_like(l_scr)
     acc_scr[...] = jnp.zeros_like(acc_scr)
 
+    one_pass = functools.partial(_dot_pv, terms=1)
+
     def one_group(g, slot):
         pos = g * span + jax.lax.broadcasted_iota(jnp.int32, (1, span), 1)
         visible = jnp.logical_and(pos >= first, pos < held)  # [1, span]
-        for h in range(n_kv):
-            if h % pack == 0:  # a new lane tile: widened once for its heads
-                lanes = slice(h // pack * w, (h // pack + 1) * w)
-                k = k_buf[slot, :, lanes].astype(jnp.float32)
-                v = v_buf[slot, :, lanes].astype(jnp.float32)
-            q = q_ref[h].astype(jnp.float32) * sm_scale
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-            )
-            _softmax_step(jnp.where(visible, s, NEG_INF), v, h, m_scr, l_scr, acc_scr)
+        for u in range(units):
+            lanes = slice(u * w, (u + 1) * w)
+            s = _dot_qk(q_ref[u], k_buf[slot, :, lanes]) * sm_scale
+            _softmax_step(jnp.where(visible, s, NEG_INF), v_buf[slot, :, lanes], u, m_scr, l_scr, acc_scr, dot_pv=one_pass)
 
     _walk_pages(tables_ref, bi, layer_ref[0], k_hbm, v_hbm, k_buf, v_buf, sems, block_size, first, held, one_group)
-    _emit(o_ref, m_scr, l_scr, acc_scr)
+    d = w // share
+    for u in range(units):
+        out = _finished(m_scr, l_scr, acc_scr, u)
+        for j in range(share):  # head u*share + j: its rows, its lanes
+            o_ref[u * share + j] = out[j * n_rep:(j + 1) * n_rep, j * d:(j + 1) * d].astype(o_ref.dtype)
 
 
 def _dot_qk(q, k):
@@ -315,21 +337,22 @@ def _dot_qk(q, k):
         precision=jax.lax.Precision.HIGHEST)
 
 
-def _dot_pv(p, v):
-    """``p [rows, span]`` float32 times ``v [span, W]`` with nothing rounded:
-    a float32 is three bf16 terms exactly (24 bits of mantissa in three
-    eights) and each term's products with a bf16 ``v`` are exact in float32,
-    so three passes of the MXU, accumulated in float32, are the float32
-    product. (Left to itself the MXU takes float32 operands in one pass of
-    rounded ones: 1.4x this kernel's error against the float32 lines on the
-    chip, and the full-precision product of two float32 operands takes six
-    passes.) A pool that is not bf16 takes the six."""
+def _dot_pv(p, v, terms: int = 3):
+    """``p [rows, span]`` float32 times ``v [span, W]``. ``terms`` = 3,
+    nothing rounded: a float32 is three bf16 terms exactly (24 bits of
+    mantissa in three eights) and each term's products with a bf16 ``v`` are
+    exact in float32, so three passes of the MXU, accumulated in float32, are
+    the float32 product. ``terms`` = 1, the decode kernel's: one pass on ``p``
+    rounded to bf16 in the open, which is what the MXU makes of a float32
+    operand left to itself (1.4x the three-term error against the float32
+    lines on the chip; the full-precision product of two float32 operands
+    takes six passes). A pool that is not bf16 takes the six."""
     dims = (((1,), (0,)), ((), ()))
     if v.dtype != jnp.bfloat16:
         return jax.lax.dot_general(
             p, v.astype(jnp.float32), dims, preferred_element_type=jnp.float32, precision=jax.lax.Precision.HIGHEST)
     out = None
-    for _ in range(3):
+    for _ in range(terms):
         term = p.astype(jnp.bfloat16)
         p = p - term.astype(jnp.float32)
         part = jax.lax.dot_general(term, v, dims, preferred_element_type=jnp.float32)
@@ -337,16 +360,12 @@ def _dot_pv(p, v):
     return out
 
 
-def _dot_pv_as_given(p, v):
-    """The decode kernel's ``P.V``: float32 operands as the MXU takes them."""
-    return jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-
-
-def _softmax_step(s, v, h, m_scr, l_scr, acc_scr, dot_pv=_dot_pv_as_given):
+def _softmax_step(s, v, h, m_scr, l_scr, acc_scr, dot_pv=_dot_pv):
     """One group's masked float32 scores ``s [rows, span]`` and values
-    ``v [span, W]`` into head ``h``'s online-softmax state (``m``/``l``
-    lane-broadcast ``[Hkv, rows, LANES]``, ``acc [Hkv, rows, W]``).
-    ``dot_pv``: the ``P.V`` product."""
+    ``v [span, W]`` into unit ``h``'s online-softmax state (``m``/``l``
+    lane-broadcast ``[units, rows, LANES]``, ``acc [units, rows, W]``; a unit
+    is a KV head of the prefill kernel, a run of heads of the decode kernel:
+    :func:`_heads_a_unit`). ``dot_pv``: the ``P.V`` product."""
     m_prev = m_scr[h, :, :1]
     l_prev = l_scr[h, :, :1]
     m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
@@ -358,13 +377,13 @@ def _softmax_step(s, v, h, m_scr, l_scr, acc_scr, dot_pv=_dot_pv_as_given):
     l_scr[h] = jnp.broadcast_to(l_new, l_scr.shape[1:])
 
 
-def _emit(o_ref, m_scr, l_scr, acc_scr):
-    """The finished state as the output block; a row that saw no key (its
-    running max never left the floor) emits zeros, not garbage-V means."""
-    l = l_scr[:, :, :1]
-    empty = m_scr[:, :, :1] <= NEG_INF * 0.5
-    out = jnp.where(empty, 0.0, acc_scr[...] / jnp.where(l == 0, 1.0, l))
-    o_ref[...] = out.astype(o_ref.dtype)
+def _finished(m_scr, l_scr, acc_scr, unit=slice(None)):
+    """The finished state of ``unit`` (all of them: ``[units, rows, W]``) as
+    float32 outputs; a row that saw no key (its running max never left the
+    floor) gives zeros, not garbage-V means."""
+    l = l_scr[unit, :, :1]
+    empty = m_scr[unit, :, :1] <= NEG_INF * 0.5
+    return jnp.where(empty, 0.0, acc_scr[unit] / jnp.where(l == 0, 1.0, l))
 
 
 def _gathered(pool, layer, block_tables, Hkv, D):
@@ -406,6 +425,20 @@ def _heads_a_lane_tile(Hkv: int, D: int) -> int:
     return math.gcd(Hkv, _LANES // D) if _LANES % D == 0 else 1
 
 
+def _heads_a_unit(Hkv: int, D: int, n_rep: int) -> int:
+    """Heads that share a unit of the decode kernel: whole lane tiles of
+    neighbours, as many as divide ``Hkv`` and keep their ``n_rep`` queries
+    each within one sublane tile of rows (eight 64-wide MHA heads, six of
+    thirty 128-wide ones, one GQA group of eight queries); a lane tile's
+    heads at least. The cost of a unit hardly follows its lanes (the softmax
+    chain on one block of rows and the products' fixed cost set it), so fewer,
+    wider units are what the kernel's time follows (PERF.md section 5)."""
+    pack = _heads_a_lane_tile(Hkv, D)
+    if pack * D % _LANES:
+        return pack
+    return max((s for s in range(pack, Hkv + 1, pack) if Hkv % s == 0 and s * n_rep <= _MIN_REP), default=pack)
+
+
 def _into_own_lanes(qg: jax.Array, pack: int) -> jax.Array:
     """``qg [B, Hkv, rows, D]`` -> ``[B, Hkv, rows, pack*D]``: head ``g``'s
     query in its own ``D`` lanes of its tile, exact zeros in its neighbours'."""
@@ -428,35 +461,50 @@ def _own_lanes(out: jax.Array, pack: int) -> jax.Array:
     ).reshape(B, Hkv, rows, W // pack)
 
 
-def _paged_call(kernel, name, scalars, qg, k_pool, v_pool, *, grid, rows, q_index, **compiler_params):
+def _heads_share_rows(qg: jax.Array, share: int) -> jax.Array:
+    """``qg [B, Hkv, n_rep, D]`` -> ``[B, Hkv/share, rows_p, share*D]``, the
+    decode kernel's queries: the ``share`` heads of a unit share one block of
+    rows, row ``j*n_rep + r`` head ``j``'s query ``r`` in its own ``D`` lanes
+    and exact zeros in its neighbours'; the ``share*n_rep`` rows are padded
+    with zero rows up to a sublane multiple."""
+    B, Hkv, n_rep, D = qg.shape
+    q = qg.reshape(B, Hkv // share, share, n_rep, 1, D)
+    if share > 1:
+        q = q * jnp.eye(share, dtype=qg.dtype)[:, None, :, None]  # [.., j, r, j', D]
+    q = q.reshape(B, Hkv // share, share * n_rep, share * D)
+    return jnp.pad(q, ((0, 0), (0, 0), (0, (-share * n_rep) % _MIN_REP), (0, 0)))
+
+
+def _paged_call(kernel, name, scalars, qg, k_pool, v_pool, *, grid, rows, q_index, out_block=None, **compiler_params):
     """The ``pallas_call`` of both paged kernels: ``scalars`` ride scalar
-    prefetch, ``qg [B, Hkv, R, W]`` and the output of its shape come in
-    blocks of ``rows`` at ``q_index``, the pools stay in HBM (the body copies
-    the pages it needs), and the scratch is the two-slot group buffers of K
-    and V (a group of pages is one lane tile of scores: 128 tokens where the
-    page size divides it), their copy semaphores and the online-softmax
-    state of every KV head."""
-    _, Hkv, _, W = qg.shape
+    prefetch, ``qg [B, units, R, W]`` comes in blocks of ``rows`` at
+    ``q_index`` and so does the output, of ``qg``'s shape or, given
+    ``out_block``, ``[B, *out_block]`` a whole block a grid step; the pools
+    stay in HBM (the body copies the pages it needs), and the scratch is the
+    two-slot group buffers of K and V (a group of pages is one lane tile of
+    scores: 128 tokens where the page size divides it), their copy semaphores
+    and the online-softmax state of every unit."""
+    B, units, _, W = qg.shape
     bs, row = k_pool.shape[2:]
     span = max(1, _LANES // bs) * bs
-    block = pl.BlockSpec((None, Hkv, rows, W), q_index)
+    block = pl.BlockSpec((None, units, rows, W), q_index)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalars),
         grid=grid,
         in_specs=[block, pl.BlockSpec(memory_space=pl.ANY), pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=block,
+        out_specs=block if out_block is None else pl.BlockSpec((None, *out_block), q_index),
         scratch_shapes=[
             pltpu.VMEM((2, span, row), k_pool.dtype),
             pltpu.VMEM((2, span, row), v_pool.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),  # [K | V, buffer]
-            pltpu.VMEM((Hkv, rows, _LANES), jnp.float32),
-            pltpu.VMEM((Hkv, rows, _LANES), jnp.float32),
-            pltpu.VMEM((Hkv, rows, W), jnp.float32),
+            pltpu.VMEM((units, rows, _LANES), jnp.float32),
+            pltpu.VMEM((units, rows, _LANES), jnp.float32),
+            pltpu.VMEM((units, rows, W), jnp.float32),
         ],
     )
     return pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct(qg.shape, qg.dtype),
+        out_shape=jax.ShapeDtypeStruct(qg.shape if out_block is None else (B, *out_block), qg.dtype),
         grid_spec=grid_spec,
         # in order: the group buffers are cleaned at the first grid step
         compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",) * len(grid), **compiler_params),
@@ -513,20 +561,19 @@ def paged_decode_attention(
         out = _paged_decode_xla(qg, k_pool, v_pool, block_tables, lengths, layer, scale, window)
         return out.astype(q.dtype).reshape(B, H, D)
 
-    rep_p = -(-n_rep // _MIN_REP) * _MIN_REP
-    if rep_p != n_rep:
-        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, rep_p - n_rep), (0, 0)))
-    pack = _heads_a_lane_tile(Hkv, D)
-    qg = _into_own_lanes(qg, pack)
+    share = _heads_a_unit(Hkv, D, n_rep)
+    qt = _heads_share_rows(qg, share)
     windowed = window is not None
     scalars = [block_tables.astype(jnp.int32), lengths.astype(jnp.int32), jnp.asarray(layer, jnp.int32).reshape(1)]
     if windowed:
         scalars.append(jnp.asarray(window, jnp.int32).reshape(1))
 
     out = _paged_call(
-        functools.partial(_paged_decode_kernel, sm_scale=scale, block_size=bs, pack=pack, windowed=windowed),
-        "paged_decode", scalars, qg, k_pool, v_pool, grid=(B,), rows=rep_p, q_index=lambda b, *_: (b, 0, 0, 0))
-    return _own_lanes(out[:, :, :n_rep], pack).reshape(B, H, D)
+        functools.partial(
+            _paged_decode_kernel, sm_scale=scale, block_size=bs, share=share, n_rep=n_rep, windowed=windowed),
+        "paged_decode", scalars, qt, k_pool, v_pool, grid=(B,), rows=qt.shape[2], q_index=lambda b, *_: (b, 0, 0, 0),
+        out_block=(Hkv, n_rep, D))
+    return out.reshape(B, H, D)
 
 
 _Q_TILE = 128  # queries a grid step of the prefill kernel: one group of keys on the diagonal
@@ -594,7 +641,7 @@ def _paged_prefill_kernel(
             _softmax_step(jnp.where(visible, s, NEG_INF), v, h, m_scr, l_scr, acc_scr, dot_pv=_dot_pv)
 
     _walk_pages(tables_ref, bi, layer_ref[0], k_hbm, v_hbm, k_buf, v_buf, sems, block_size, first, last, one_group)
-    _emit(o_ref, m_scr, l_scr, acc_scr)
+    o_ref[...] = _finished(m_scr, l_scr, acc_scr).astype(o_ref.dtype)
 
 
 def _paged_prefill_xla(qg, k_pool, v_pool, block_tables, starts, lengths, layer, scale, n_rep, window=None, block=1):

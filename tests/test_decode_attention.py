@@ -173,3 +173,89 @@ def test_zero_length_rows_yield_zeros():
     assert (out[0] == 0).all() and (out[2] == 0).all()
     ref = _reference(q, kc, vc, lengths)
     np.testing.assert_allclose(out[1], np.asarray(ref[1]), rtol=2e-5, atol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# the paged kernel's unit is a run of heads that share one block of query rows:
+# the served head shapes and the ones that stress the layout, against the
+# plain-XLA lines on the same pool
+# ---------------------------------------------------------------------------
+_PAGE, _TABLE = 16, 10  # 160 tokens a row: two groups of 128 keys, the second part full
+
+
+def _paged_case(Hkv, D, n_rep, seed=0, dtype=jnp.bfloat16):
+    """Three layers of a shuffled pool and six rows: empty, one token, a length
+    that ends mid-page, a full table, two tokens into the second group, and an
+    idle slot (an all-zero table at the length its last tenant left)."""
+    rng = np.random.default_rng(seed)
+    B, N = 6, 6 * _TABLE + 1
+    q = jnp.asarray(rng.standard_normal((B, Hkv * n_rep, D)), dtype)
+    kp, vp = (jnp.asarray(rng.standard_normal((3, N, _PAGE, Hkv * D)), dtype) for _ in range(2))
+    bt = rng.permutation(np.arange(1, N)).reshape(B, _TABLE).astype(np.int32)
+    bt[5] = 0
+    lengths = jnp.asarray([0, 1, 37, _PAGE * _TABLE, 130, 77], jnp.int32)
+    return q, kp, vp, jnp.asarray(bt), lengths
+
+
+def _paged_both(q, kp, vp, bt, lengths, **kw):
+    from ray_tpu.ops.decode_attention import paged_decode_attention
+
+    return [
+        np.asarray(paged_decode_attention(q, kp, vp, bt, lengths, jnp.int32(1), use_kernel=k, **kw), np.float32)
+        for k in (True, False)
+    ]
+
+
+# (Hkv, D, n_rep): SmolLM2 (eight heads a unit, a row each), Trinity-Mini (a
+# head a unit, eight real rows), Olmo-Hybrid (six of thirty heads a unit, a
+# row each), two heads x four queries (eight rows exactly), two heads x eight
+# (sixteen rows: past one sublane tile), and an odd head count at 64 lanes (a
+# head a unit of 64 lanes)
+_HEAD_SHAPES = [(32, 64, 1), (4, 128, 8), (30, 128, 1), (8, 64, 4), (2, 64, 8), (3, 64, 2)]
+
+
+@pytest.mark.parametrize("window", [None, 40], ids=["full", "window40"])
+@pytest.mark.parametrize("Hkv,D,n_rep", _HEAD_SHAPES, ids=[f"kv{h}_d{d}_rep{r}" for h, d, r in _HEAD_SHAPES])
+def test_paged_kernel_matches_xla_at_every_head_layout(Hkv, D, n_rep, window):
+    q, kp, vp, bt, lengths = _paged_case(Hkv, D, n_rep, seed=Hkv + n_rep)
+    got, want = _paged_both(q, kp, vp, bt, lengths, window=window)
+    assert got.shape == (6, Hkv * n_rep, D)
+    assert not got[0].any() and not got[5].any(), "an empty row and an idle slot are zeros"
+    assert np.abs(want[1:5]).max() > 0.5
+    # P meets V in bf16 (one pass of the MXU) and the result is bf16
+    np.testing.assert_allclose(got, want, rtol=1e-2, atol=1e-2)
+
+
+def test_paged_kernel_matches_xla_on_a_float32_pool():
+    """A pool that is not bf16 keeps full-precision products."""
+    q, kp, vp, bt, lengths = _paged_case(8, 64, 4, seed=3, dtype=jnp.float32)
+    got, want = _paged_both(q, kp, vp, bt, lengths)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("Hkv,D,n_rep", [(8, 64, 4), (32, 64, 1), (30, 128, 1)], ids=["two_a_unit", "eight_a_unit", "six_a_unit"])
+def test_a_loud_neighbour_in_the_unit_changes_nothing(Hkv, D, n_rep):
+    """The heads of a unit share lane tiles and a block of query rows. With
+    the odd heads' K and V a thousand times louder, the even heads' outputs
+    are bit-for-bit what they were: their rows hold exact zeros in their
+    neighbours' lanes, and only their own lanes of the accumulator are kept."""
+    from ray_tpu.ops.decode_attention import _heads_a_unit
+
+    assert _heads_a_unit(Hkv, D, n_rep) > 1
+    q, kp, vp, bt, lengths = _paged_case(Hkv, D, n_rep, seed=11)
+    odd = jnp.tile(jnp.repeat(jnp.asarray([1.0, 1e3], kp.dtype), D), Hkv // 2)  # [Hkv*D]: x1 | x1000 a pair of heads
+    quiet, _ = _paged_both(q, kp, vp, bt, lengths)
+    loud, want = _paged_both(q, kp * odd, vp * odd, bt, lengths)
+    heads = lambda a: a.reshape(6, Hkv, n_rep, D)
+    np.testing.assert_array_equal(heads(loud)[:, 0::2], heads(quiet)[:, 0::2])
+    np.testing.assert_allclose(heads(loud)[:, 0::2], heads(want)[:, 0::2], rtol=1e-2, atol=1e-2)
+    # the loud heads are themselves right, at their own scale
+    np.testing.assert_allclose(heads(loud)[:, 1::2] / 1e3, heads(want)[:, 1::2] / 1e3, rtol=1e-2, atol=1e-2)
+
+
+@pytest.mark.parametrize("shape,share", [((32, 64, 1), 8), ((4, 128, 8), 1), ((30, 128, 1), 6), ((8, 64, 4), 2),
+                                         ((2, 64, 8), 2), ((3, 64, 2), 1), ((16, 128, 2), 4), ((5, 96, 1), 1)])
+def test_heads_a_unit_fills_one_sublane_tile_of_rows_with_whole_lane_tiles(shape, share):
+    from ray_tpu.ops.decode_attention import _heads_a_unit
+
+    assert _heads_a_unit(*shape) == share

@@ -99,10 +99,12 @@ def _kernel_cases():
     for bs in (g["bs"], 128):
         for dt in (BF16, F32):
             yield f"decode_paged_bs{bs}_{dt.__name__}", paged_decode_attention, paged(H, Hkv, D, bs, dt)
-    # the heads are lane tiles of the page row: MHA at the cells' 64-wide head
-    # (two heads to a 128-lane tile), and one 128-wide head (its own tile) under GQA
+    # the units are whole lane tiles of the page row: MHA at the cells' 64-wide
+    # head (eight heads a unit, their queries the rows of one block), one
+    # 128-wide head (its own tile) under GQA, and thirty of them under MHA (six a unit)
     yield "decode_paged_mha32_d64", paged_decode_attention, paged(32, 32, 64, g["bs"], BF16)
     yield "decode_paged_gqa4_1_d128", paged_decode_attention, paged(4, 1, 128, g["bs"], BF16)
+    yield "decode_paged_mha30_d128", paged_decode_attention, paged(30, 30, 128, g["bs"], BF16)
     # the paged prefill kernel at the two served families' chunk, capacity and
     # head shapes (32 x 64 wide heads, two to a lane tile; 32 over 4 of 128 with
     # the window as a traced scalar), and at widths below and across its query tile
