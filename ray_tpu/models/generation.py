@@ -50,6 +50,10 @@ from ray_tpu.models.transformer import (
     full_kind,
     hybrid_scan,
     in_window,
+    latent_absorb,
+    latent_out,
+    latent_qkv,
+    latent_scale,
     layer_kinds,
     layer_stacks,
     linear_inputs,
@@ -73,8 +77,8 @@ KVCache = Dict[str, jax.Array]
 def _refuse_ring_for_hybrid(cfg: TransformerConfig, what: str) -> None:
     if cfg.hybrid:
         raise ValueError(f'{what} keeps keys and values only: a config with "linear" layers (recurrent state a '
-                         "sequence) is served through the paged path, init_paged_cache(..., slots=) and "
-                         "paged_forward_with_cache(..., slots=)")
+                         'sequence) or "latent" layers (one latent row a token) is served through the paged path, '
+                         "init_paged_cache(..., slots=) and paged_forward_with_cache(..., slots=)")
 
 
 def init_cache(cfg: TransformerConfig, batch: int, max_len: int, dtype=None) -> KVCache:
@@ -89,6 +93,14 @@ def init_paged_cache(
     cfg: TransformerConfig, num_blocks: int, block_size: int, dtype=None, slots: int = 0
 ) -> KVCache:
     """Paged KV pool: {"k","v"}: [L, num_blocks, block_size, Hkv*Dh].
+
+    A config with "latent" layers keeps **one** pool in their place,
+    ``{"latent": [L_lat, num_blocks, block_size, lanes]}``: a token's row is
+    the normalised latent and the key part every head shares, side by side
+    (``cfg.latent_row`` numbers, zero lanes up to ``cfg.latent_row_lanes``:
+    whole 128-lane tiles, which is what the chip's layout of the minor axis
+    occupies either way), read as keys and, in its first ``latent_rank``
+    lanes, as values (``ops/decode_attention.py``, ``latent_paged_*``).
 
     A config with "linear" layers keeps pages for its full layers only (``L``
     is ``cfg.kv_layers``) and, beside them, what its linear layers carry a
@@ -115,8 +127,12 @@ def init_paged_cache(
     instead — every layer then pays a layout conversion of its whole slice.)
     """
     dt = dtype or cfg.dtype
-    shape = (cfg.kv_layers, num_blocks, block_size, cfg.kv_heads * cfg.head_dim)
-    cache = {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
+    if cfg.latent_layers:
+        # one pool, no K and no V: a token's row is its key for every head and, in its first lanes, its value
+        cache = {"latent": jnp.zeros((cfg.latent_layers, num_blocks, block_size, cfg.latent_row_lanes), dt)}
+    else:
+        shape = (cfg.kv_layers, num_blocks, block_size, cfg.kv_heads * cfg.head_dim)
+        cache = {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
     if cfg.hybrid:
         if slots < 1:
             raise ValueError('a config with "linear" layers keeps a recurrent state a sequence: '
@@ -157,6 +173,14 @@ def zero_sequence_state(cache: KVCache, slot) -> KVCache:
     return out
 
 
+PAGE_POOLS = ("k", "v", "latent")  # a cache's arrays whose axis 1 is the page
+
+
+def page_pools(cache: KVCache):
+    """The paged pools a cache holds: K and V, or the one latent pool."""
+    return [name for name in PAGE_POOLS if name in cache]
+
+
 def paged_cache_spec(heads_axis: Optional[str]):
     """PartitionSpec of a paged pool sharded over KV heads: the heads are
     the leading factor of the minor axis, so an even split of that axis over
@@ -185,8 +209,9 @@ def _paged_write_index(block_tables, positions, valid, block_size):
 
 
 def copy_paged_page(cache: KVCache, src, dst) -> KVCache:
-    """Copy one physical page — every layer's K and V rows — from ``src`` to
-    ``dst`` in a paged pool (:func:`init_paged_cache` layout).
+    """Copy one physical page — every layer's K and V rows, or its latent
+    rows — from ``src`` to ``dst`` in a paged pool (:func:`init_paged_cache`
+    layout); what a cache keeps a sequence, not a page, is passed through.
 
     This is the engine's copy-on-write primitive: a sequence about to write
     into a page it shares with the prefix cache (or another sequence) gets
@@ -195,9 +220,7 @@ def copy_paged_page(cache: KVCache, src, dst) -> KVCache:
     ``jit`` every copy shares one compile. Page 0 must never be a
     destination (the garbage page's contents are sacrificial, but a COW
     into it would alias every masked write)."""
-    return {
-        kk: cache[kk].at[:, dst].set(cache[kk][:, src]) for kk in ("k", "v")
-    }
+    return {**cache, **{kk: cache[kk].at[:, dst].set(cache[kk][:, src]) for kk in page_pools(cache)}}
 
 
 def export_paged_page(cfg: TransformerConfig, cache: KVCache, page) -> jax.Array:
@@ -205,6 +228,8 @@ def export_paged_page(cfg: TransformerConfig, cache: KVCache, page) -> jax.Array
     (k then v): the format replicas exchange names the heads, whatever the
     pool's own row layout is. Indexing materializes NEW buffers, so the block
     survives later donated steps."""
+    if "latent" in cache:  # one pool, no heads: [1, L, block_size, 1, lanes]
+        return cache["latent"][:, page][None, :, :, None, :]
     block = jnp.stack([cache["k"][:, page], cache["v"][:, page]])
     return block.reshape(*block.shape[:3], cfg.kv_heads, cfg.head_dim)
 
@@ -213,9 +238,10 @@ def write_paged_pages(cache: KVCache, blocks, pages) -> KVCache:
     """Land migration blocks ``[N, 2, L, block_size, Hkv, Dh]``
     (:func:`export_paged_page`'s format) in the pool, block ``n`` at physical
     page ``pages[n]``, in one scatter per pool. Duplicate ``(block, page)``
-    pairs are idempotent (identical bytes to the same page)."""
+    pairs are idempotent (identical bytes to the same page). A latent pool's
+    blocks are ``[N, 1, L, block_size, 1, lanes]``."""
     rows = jnp.swapaxes(blocks.reshape(*blocks.shape[:4], -1), 0, 2)  # [L, 2, N, bs, Hkv*Dh]
-    return {kk: cache[kk].at[:, pages].set(rows[:, i]) for i, kk in enumerate(("k", "v"))}
+    return {**cache, **{kk: cache[kk].at[:, pages].set(rows[:, i]) for i, kk in enumerate(page_pools(cache))}}
 
 
 def _write_kv(cache_layer: jax.Array, new: jax.Array, starts: jax.Array) -> jax.Array:
@@ -408,12 +434,14 @@ def paged_forward_counted(
     ``valid`` tokens (all, where ``valid`` is None): ``{"assignments":
     int32[E], "pairs_hit": int32}``, the (token, choice) pairs each expert
     got summed over the expert layers, and how many (layer, expert) pairs got
-    at least one; zeros for any other config. A caller that drops them pays
-    nothing: they fall out of the compiled program.
+    at least one; zeros for any other config. A config that holds a share of
+    its experts (``cfg.experts_held``) counts the experts held, and adds
+    ``"routed"``: the pairs the routers chose over all the experts. A caller
+    that drops them pays nothing: they fall out of the compiled program.
     """
     B, T = tokens.shape
     M = block_tables.shape[1]
-    bs = cache["k"].shape[2]
+    bs = cache["latent" if cfg.latent_layers else "k"].shape[2]
     cap = M * bs
     h_heads, hkv = cfg.n_heads, cfg.kv_heads
     n_rep = h_heads // hkv
@@ -515,19 +543,49 @@ def paged_forward_counted(
                 o, S = gated_delta_chunked(unpack_state(st[li, slots], G), q, k, v, g, beta, real)
                 st = st.at[li, slots].set(pack_state(S, G))
             cv = cv.at[li, slots].set(tail.reshape(B, -1).astype(cv.dtype))
-            x = linear_out(cfg, layer, x, h, o)
-            return (kc, vc, st, cv), block_ffn(cfg, layer, x)[0]
+            return (kc, vc, st, cv), linear_out(cfg, layer, x, h, o)
 
         def full_fn(carry, x, layer, fi):
             kc, vc, st, cv = carry
             x, kc, vc = paged_attention_block(x, kc, vc, layer, full_kind(cfg), fi, None)
-            return (kc, vc, st, cv), block_ffn(cfg, layer, x)[0]
+            return (kc, vc, st, cv), x
 
-        carry = (cache["k"], cache["v"], cache["state"], cache["conv"])
-        (ks, vs, st, cv), x = hybrid_scan(cfg, params, carry, x, linear_fn, full_fn)
+        def latent_fn(carry, x, layer, fi):
+            """A latent layer's attention branch against its one pool (the
+            carry's first place; no V): the call's rows written, the queries
+            absorbed, the walk over the rows they can see."""
+            from ray_tpu.ops.decode_attention import latent_paged_decode, latent_paged_prefill
+
+            lc, _, st, cv = carry
+            h = pre_norm(cfg, layer, "attn_norm", x)
+            q, row = latent_qkv(cfg, layer, h)
+            lanes = lc.shape[-1]
+            row = jnp.pad(row, ((0, 0), (0, 0), (0, lanes - row.shape[-1])))
+            lc = lc.at[fi, phys, off].set(row.reshape(B * T, lanes).astype(lc.dtype))
+            qa = latent_absorb(cfg, layer, q, lanes)
+            if use_decode_kernel and T == 1:
+                o = latent_paged_decode(qa[:, 0], lc, block_tables, starts + 1, fi, rank=cfg.latent_rank,
+                                        sm_scale=latent_scale(cfg))[:, None]
+            else:
+                o = latent_paged_prefill(qa, lc, block_tables, starts, lengths, fi, rank=cfg.latent_rank,
+                                         sm_scale=latent_scale(cfg), use_kernel=bool(use_decode_kernel))
+            return (lc, None, st, cv), latent_out(cfg, layer, x, o.astype(x.dtype))
+
+        latent = cfg.attn_kind == "latent"
+        carry = (cache["latent"], None) if latent else (cache["k"], cache["v"])
+        (ks, vs, st, cv), x, counts = hybrid_scan(cfg, params, carry + (cache["state"], cache["conv"]), x, linear_fn,
+                                                  latent_fn if latent else full_fn, valid=valid, kernel=use_decode_kernel)
         logits = unembed(cfg, params, x) if with_logits else None
-        zeros = {"assignments": jnp.zeros((1,), jnp.int32), "pairs_hit": jnp.zeros((), jnp.int32)}
-        return logits, {"k": ks, "v": vs, "state": st, "conv": cv}, zeros
+        pools = {"latent": ks} if latent else {"k": ks, "v": vs}
+        if counts is None:
+            moe = {"assignments": jnp.zeros((1,), jnp.int32), "pairs_hit": jnp.zeros((), jnp.int32)}
+        else:  # [periods, layers a period, experts held] of the valid tokens
+            moe = {"assignments": counts.sum((0, 1)), "pairs_hit": jnp.sum(counts > 0).astype(jnp.int32)}
+            if cfg.experts_held is not None:
+                # (token, choice) pairs routed over all the experts, here or elsewhere: the counts' denominator
+                tokens_ = B * T if valid is None else jnp.broadcast_to(valid, (B, T)).sum()
+                moe["routed"] = jnp.asarray(tokens_ * cfg.expert_top_k * cfg.expert_layers, jnp.int32)
+        return logits, {**pools, "state": st, "conv": cv}, moe
 
     carry = (x, cache["k"], cache["v"])
     assignments = jnp.zeros((max(cfg.num_experts, 1),), jnp.int32)

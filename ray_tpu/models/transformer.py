@@ -28,6 +28,16 @@ nets are the closest analog, ``rllib/core/rl_module/rl_module.py``):
   of ``k`` trees, the ``j``-th the stack ``[periods, ...]`` of every period's
   ``j``-th linear layer) beside the full layers' ``[periods, ...]``, and the
   scan's body is a period (:func:`hybrid_scan`).
+- A fourth kind, ``"latent"`` (multi-head latent attention), may stand in a
+  period's last place instead of the full layer: its cache is one row a token
+  (the normalised latent and the key part every head shares), and a config
+  that has expert layers beside linear ones keeps **mixers and FFNs in
+  stacks of their own** (``params["dense_ffn"]``, ``params["expert_ffn"]``),
+  each layer naming its index in both (:func:`split_ffn`): the first
+  period's leading layers take the dense FFN and every other an expert FFN
+  while the scan's body stays one period. An expert layer may hold a
+  contiguous share of its experts (``experts_held``): it routes over all of
+  them and computes its own part.
 - Attention: Pallas flash kernel (``ray_tpu.ops.attention``) on a single
   chip (no mesh); XLA einsum attention under any mesh; or
   ``attention="ring"`` — sequence-parallel ring attention
@@ -132,6 +142,30 @@ class TransformerConfig:
     # OLMo 2/3's norm placement is post_norms without pre_norms: x + norm(branch(x))
     pre_norms: bool = True              # False => no RMSNorm on a branch's input (needs post_norms)
     qk_norm_whole: bool = False         # qk_norm's gains span the whole projection (H*Dh, Hkv*Dh), not head_dim
+    # "latent" layers (multi-head latent attention, in a period's last place):
+    # n_heads queries of latent_nope_dim + latent_rope_dim, keys and values
+    # expanded from one latent of latent_rank a token, values of
+    # latent_value_dim; the latent_rope_dim key numbers are shared by every
+    # head and, like the full layers of such a config, carry no rotation
+    # (rope_full_layers must be False). The cache holds latent_rank +
+    # latent_rope_dim numbers a token a layer, read as keys and as values
+    latent_rank: int = 0
+    latent_nope_dim: int = 0
+    latent_rope_dim: int = 0
+    latent_value_dim: int = 0
+    # the linear layer's decay: "head" (one a head: Gated DeltaNet) or "channel"
+    # (one a key channel: Kimi Delta Attention); linear_gate_rank > 0 makes the
+    # output gate's projection low-rank, d -> rank -> heads x size, and is what
+    # a channel decay's projection always is (rank 0 is refused with it);
+    # linear_out_gate is the output gate's activation
+    linear_gate: str = "head"
+    linear_gate_rank: int = 0
+    linear_out_gate: str = "silu"       # silu | sigmoid
+    # the share of the experts this parameter tree holds, [lo, hi) of
+    # num_experts; None: all. The router scores and normalises over all
+    # num_experts; the layer adds the terms of the experts held (and the
+    # shared experts) and leaves the absent ones' out
+    experts_held: Optional[Tuple[int, int]] = None
 
     def __post_init__(self):
         if self.block_length > 1:
@@ -147,16 +181,30 @@ class TransformerConfig:
             object.__setattr__(self, "head_dim", self.d_model // self.n_heads)
         if self.layer_types is not None:
             object.__setattr__(self, "layer_types", tuple(self.layer_types))
-            bad = set(self.layer_types) - {"sliding", "full", "linear"}
+            bad = set(self.layer_types) - {"sliding", "full", "linear", "latent"}
             if bad or len(self.layer_types) != self.n_layers:
                 raise ValueError(
-                    f'layer_types must name "sliding", "full" or "linear" for each of the {self.n_layers} layers; '
-                    f"got {self.layer_types!r}"
+                    f'layer_types must name "sliding", "full", "linear" or "latent" for each of the {self.n_layers} '
+                    f"layers; got {self.layer_types!r}"
                 )
             if "sliding" in self.layer_types and self.sliding_window < 1:
                 raise ValueError("a sliding layer needs sliding_window >= 1")
+            if "latent" in self.layer_types:
+                self._check_latent()
             if "linear" in self.layer_types:
                 self._check_hybrid()
+        if self.experts_held is not None:
+            object.__setattr__(self, "experts_held", tuple(int(e) for e in self.experts_held))
+            lo, hi = self.experts_held
+            if not (self.dropless and 0 <= lo < hi <= self.num_experts):
+                raise ValueError(f"experts_held {self.experts_held!r} must be a range [lo, hi) of the "
+                                 f"{self.num_experts} experts of a dropless expert layer")
+        if self.linear_gate not in ("head", "channel") or self.linear_out_gate not in ("silu", "sigmoid"):
+            raise ValueError(f'linear_gate must be "head" or "channel" and linear_out_gate "silu" or "sigmoid"; '
+                             f"got {self.linear_gate!r}, {self.linear_out_gate!r}")
+        if self.linear_gate == "channel" and self.linear_gate_rank < 1:
+            raise ValueError('linear_gate="channel" projects its decay d -> linear_gate_rank -> heads x key channels: '
+                             "linear_gate_rank must be > 0 (no served configuration has a full-rank channel decay)")
         if not self.pre_norms and not self.post_norms:
             raise ValueError("pre_norms=False leaves a branch without any norm: it goes with post_norms=True")
         if self.qk_norm_whole and not self.qk_norm:
@@ -180,19 +228,37 @@ class TransformerConfig:
                 f"n_kv_heads {kv} must be a positive divisor of n_heads {self.n_heads}"
             )
 
+    def _check_latent(self) -> None:
+        """A config with "latent" layers: sizes given, no rotation, and each
+        the last layer of a period of linear layers."""
+        if min(self.latent_rank, self.latent_nope_dim, self.latent_value_dim) < 1 or self.latent_rope_dim < 0:
+            raise ValueError('a "latent" layer needs latent_rank, latent_nope_dim and latent_value_dim >= 1')
+        refused = {"rope_full_layers=True (a latent layer rotates nothing here: its shared key part is cached as "
+                   "it is projected)": self.rope_full_layers,
+                   'layer_types without "linear" layers (a latent layer is the last of a period of them)':
+                       "linear" not in self.layer_types,
+                   '"full" or "sliding" layers beside it': bool({"full", "sliding"} & set(self.layer_types)),
+                   "qk_norm": self.qk_norm, "attn_gate": self.attn_gate}
+        bad = [name for name, hit in refused.items() if hit]
+        if bad:
+            raise ValueError('"latent" layers do not go with ' + "; ".join(bad))
+
     def _check_hybrid(self) -> None:
         """A config with "linear" layers: sizes given, and a whole number of
-        periods of ``k`` linear layers and one full layer."""
+        periods of ``k`` linear layers and one full (or one latent) layer."""
         if min(self.linear_heads, self.linear_key_dim, self.linear_value_dim) < 1 or self.linear_conv_width < 2:
             raise ValueError('a "linear" layer needs linear_heads, linear_key_dim and linear_value_dim >= 1 '
                              "and linear_conv_width >= 2")
-        k = self.layer_types.index("full") if "full" in self.layer_types else 0
-        period = ("linear",) * k + ("full",)
+        last = self.attn_kind
+        k = self.layer_types.index(last) if last in self.layer_types else 0
+        period = ("linear",) * k + (last,)
         if k < 1 or self.n_layers % len(period) or self.layer_types != period * (self.n_layers // len(period)):
             raise ValueError('layer_types with "linear" layers must repeat one period of k >= 1 "linear" layers '
-                             f'followed by one "full" layer; got {self.layer_types!r}')
-        refused = {"num_experts > 0": self.num_experts > 0, "block_length > 1": self.block_length > 1,
-                   'attention="ring"': self.attention == "ring"}
+                             f'followed by one "full" (or one "latent") layer; got {self.layer_types!r}')
+        refused = {"moe_capacity_factor > 0 (its expert layers are dropless)": self.moe_capacity_factor > 0,
+                   'num_experts > 0 beside "full" layers (the FFN stacks of their own go with a "latent" period)':
+                       self.num_experts > 0 and last == "full",
+                   "block_length > 1": self.block_length > 1, 'attention="ring"': self.attention == "ring"}
         bad = [name for name, hit in refused.items() if hit]
         if bad:
             raise ValueError('"linear" layers do not go with ' + ", ".join(bad))
@@ -207,8 +273,13 @@ class TransformerConfig:
         return self.layer_types is not None and "linear" in self.layer_types
 
     @property
+    def attn_kind(self) -> str:
+        """The kind of a hybrid config's period's last layer: "full" or "latent"."""
+        return "latent" if self.layer_types is not None and "latent" in self.layer_types else "full"
+
+    @property
     def linear_per_period(self) -> int:
-        return self.layer_types.index("full") if self.hybrid else 0
+        return self.layer_types.index(self.attn_kind) if self.hybrid else 0
 
     @property
     def periods(self) -> int:
@@ -219,9 +290,38 @@ class TransformerConfig:
         return self.periods * self.linear_per_period
 
     @property
+    def latent_layers(self) -> int:
+        """Layers that keep one latent row a token: the latent pool's layer axis."""
+        return self.layer_types.count("latent") if self.layer_types is not None else 0
+
+    @property
     def kv_layers(self) -> int:
         """Layers that keep keys and values: the paged pool's layer axis."""
-        return self.n_layers - self.linear_layers
+        return self.n_layers - self.linear_layers - self.latent_layers
+
+    @property
+    def latent_row(self) -> int:
+        """Numbers a token a latent layer caches: the latent and the shared key part."""
+        return self.latent_rank + self.latent_rope_dim
+
+    @property
+    def latent_row_lanes(self) -> int:
+        """``latent_row`` rounded up to whole 128-lane tiles: the latent pool's
+        minor axis. The chip tiles a minor axis of 576 to 640 lanes whatever is
+        asked for, so the pad costs no byte, and with it written down the
+        kernels copy, slice and contract whole tiles."""
+        return -(-self.latent_row // 128) * 128
+
+    @property
+    def split_ffn(self) -> bool:
+        """Whether mixers and FFNs are stacks of their own (:func:`split_ffn`):
+        a config with linear layers and expert layers."""
+        return self.hybrid and self.num_experts > 0
+
+    @property
+    def experts_here(self) -> int:
+        """Experts whose weights this tree holds."""
+        return self.experts_held[1] - self.experts_held[0] if self.experts_held else self.num_experts
 
     @property
     def linear_channels(self) -> int:
@@ -302,26 +402,66 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
         if cfg.post_norms:
             layer["post_attn_norm"] = jnp.ones((d,), pd)
             layer["post_ffn_norm"] = jnp.ones((d,), pd)
-        if experts:
-            e, fe = cfg.num_experts, cfg.expert_width
-            layer["router"] = dense_init(ks[7], (d, e), d)
-            layer["we1"] = dense_init(ks[4], (e, d, fe), d)
-            layer["we3"] = dense_init(ks[5], (e, d, fe), d)
-            layer["we2"] = dense_init(ks[6], (e, fe, d), fe)
-            if cfg.router_bias:
-                # a buffer the router's balance rule moves, not a gradient; zero
-                # when training starts. Drawn small here so that serving code
-                # which dropped it (or put it into the weights) computes another function
-                layer["router_bias"] = 0.01 * jax.random.normal(jax.random.fold_in(k, 9), (e,), jnp.float32)
-            if cfg.num_shared_experts:
-                fs = cfg.num_shared_experts * fe
-                layer["ws1"] = dense_init(jax.random.fold_in(k, 10), (d, fs), d)
-                layer["ws3"] = dense_init(jax.random.fold_in(k, 11), (d, fs), d)
-                layer["ws2"] = dense_init(jax.random.fold_in(k, 12), (fs, d), fs)
-        else:
-            layer["w1"] = dense_init(ks[4], (d, ff), d)
-            layer["w3"] = dense_init(ks[5], (d, ff), d)
-            layer["w2"] = dense_init(ks[6], (ff, d), ff)
+        layer.update(ffn_leaves(k, experts))
+        return layer
+
+    def ffn_leaves(k, experts: bool):
+        """A layer's FFN weights (no norm): the dense SwiGLU's, or the router,
+        the experts held and the shared experts. Drawn from the layer's own
+        key as :func:`one_layer` always drew them."""
+        ks = jax.random.split(k, 8)
+        if not experts:
+            return {"w1": dense_init(ks[4], (d, ff), d), "w3": dense_init(ks[5], (d, ff), d),
+                    "w2": dense_init(ks[6], (ff, d), ff)}
+        e, held, fe = cfg.num_experts, cfg.experts_here, cfg.expert_width
+        layer = {
+            "router": dense_init(ks[7], (d, e), d),
+            "we1": dense_init(ks[4], (held, d, fe), d),
+            "we3": dense_init(ks[5], (held, d, fe), d),
+            "we2": dense_init(ks[6], (held, fe, d), fe),
+        }
+        if cfg.router_bias:
+            # a buffer the router's balance rule moves, not a gradient; zero
+            # when training starts. Drawn small here so that serving code
+            # which dropped it (or put it into the weights) computes another function
+            layer["router_bias"] = 0.01 * jax.random.normal(jax.random.fold_in(k, 9), (e,), jnp.float32)
+        if cfg.num_shared_experts:
+            fs = cfg.num_shared_experts * fe
+            layer["ws1"] = dense_init(jax.random.fold_in(k, 10), (d, fs), d)
+            layer["ws3"] = dense_init(jax.random.fold_in(k, 11), (d, fs), d)
+            layer["ws2"] = dense_init(jax.random.fold_in(k, 12), (fs, d), fs)
+        return layer
+
+    def ffn_layer(k, experts: bool):
+        """One entry of a split config's FFN stacks: the weights and the branch's norms."""
+        layer = ffn_leaves(k, experts)
+        if cfg.pre_norms:
+            layer["ffn_norm"] = jnp.ones((d,), pd)
+        if cfg.post_norms:
+            layer["post_ffn_norm"] = jnp.ones((d,), pd)
+        return layer
+
+    def mixer_norms(with_ffn: bool):
+        names = ["attn_norm"] * cfg.pre_norms + ["post_attn_norm"] * cfg.post_norms
+        if with_ffn:
+            names += ["ffn_norm"] * cfg.pre_norms + ["post_ffn_norm"] * cfg.post_norms
+        return {name: jnp.ones((d,), pd) for name in names}
+
+    def latent_layer(k):
+        """A latent attention layer's mixer (``lat_*``) and, unless the FFNs
+        are stacks of their own, its dense FFN."""
+        r, nope, rope, dv_ = cfg.latent_rank, cfg.latent_nope_dim, cfg.latent_rope_dim, cfg.latent_value_dim
+        ks = jax.random.split(jax.random.fold_in(k, 30), 4)
+        layer = {
+            "lat_wq": dense_init(ks[0], (d, h, nope + rope), d),
+            "lat_wkva": dense_init(ks[1], (d, r + rope), d),
+            "lat_norm": jnp.ones((r,), pd),
+            "lat_wkvb": dense_init(ks[2], (r, h, nope + dv_), r),
+            "lat_wo": dense_init(ks[3], (h, dv_, d), h * dv_),
+            **mixer_norms(not cfg.split_ffn),
+        }
+        if not cfg.split_ffn:
+            layer.update(ffn_leaves(k, False))
         return layer
 
     def linear_layer(k):
@@ -333,28 +473,37 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
         weights the decay ``alpha = exp(-A softplus(.))`` spreads over (0, 1)."""
         ks = jax.random.split(k, 12)
         H, dk, dv, K = cfg.linear_heads, cfg.linear_key_dim, cfg.linear_value_dim, cfg.linear_conv_width
-        dt = jnp.exp(jax.random.uniform(ks[8], (H,)) * (math.log(0.1) - math.log(0.001)) + math.log(0.001))
+        channel, rank = cfg.linear_gate == "channel", cfg.linear_gate_rank
+        gates = (H, dk) if channel else (H,)  # a decay a key channel (its projection low-rank), or a head
+        dt = jnp.exp(jax.random.uniform(ks[8], gates) * (math.log(0.1) - math.log(0.001)) + math.log(0.001))
         layer = {
             "lin_wq": dense_init(ks[0], (d, H, dk), d),
             "lin_wk": dense_init(ks[1], (d, H, dk), d),
             "lin_wv": dense_init(ks[2], (d, H, dv), d),
-            "lin_wg": dense_init(ks[3], (d, H, dv), d),
             "lin_wo": dense_init(ks[4], (H, dv, d), H * dv),
-            "lin_wa": dense_init(ks[5], (d, H), d),
             "lin_wb": dense_init(ks[6], (d, H), d),
-            "A_log": jnp.log(jax.random.uniform(ks[7], (H,), minval=1e-3, maxval=16.0)),
+            # the channel-decay layer draws A in (1, 16), as published: one a head in either kind
+            "A_log": jnp.log(jax.random.uniform(ks[7], (H,), minval=1.0 if channel else 1e-3, maxval=16.0)),
             "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
             # [width, channels], channels = q, k, v side by side; tap j weighs the input 3 - j steps back
             "conv_w": jax.random.uniform(ks[9], (K, cfg.linear_channels), minval=-1.0, maxval=1.0).astype(pd) / math.sqrt(K),
             "o_norm": jnp.ones((dv,), pd),
-            "w1": dense_init(jax.random.fold_in(k, 4), (d, ff), d),
-            "w3": dense_init(jax.random.fold_in(k, 5), (d, ff), d),
-            "w2": dense_init(jax.random.fold_in(k, 6), (ff, d), ff),
         }
-        if cfg.pre_norms:
-            layer.update(attn_norm=jnp.ones((d,), pd), ffn_norm=jnp.ones((d,), pd))
-        if cfg.post_norms:
-            layer.update(post_attn_norm=jnp.ones((d,), pd), post_ffn_norm=jnp.ones((d,), pd))
+        if rank:  # low-rank gates: d -> rank -> heads x size
+            layer["lin_wga"] = dense_init(ks[3], (d, rank), d)
+            layer["lin_wgb"] = dense_init(jax.random.fold_in(k, 20), (rank, H, dv), rank)
+        else:
+            layer["lin_wg"] = dense_init(ks[3], (d, H, dv), d)
+        if channel:
+            layer["lin_wfa"] = dense_init(ks[5], (d, rank), d)
+            layer["lin_wfb"] = dense_init(jax.random.fold_in(k, 21), (rank, H, dk), rank)
+        else:
+            layer["lin_wa"] = dense_init(ks[5], (d, H), d)
+        if not cfg.split_ffn:
+            layer.update(w1=dense_init(jax.random.fold_in(k, 4), (d, ff), d),
+                         w3=dense_init(jax.random.fold_in(k, 5), (d, ff), d),
+                         w2=dense_init(jax.random.fold_in(k, 6), (ff, d), ff))
+        layer.update(mixer_norms(not cfg.split_ffn))
         return layer
 
     def stack(keys, experts: bool, make=None):
@@ -371,9 +520,17 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
         params = {
             "embed": dense_init(k_embed, (cfg.vocab_size, d), d),
             "linear_layers": [stack(layer_keys[j::per], False, linear_layer) for j in range(k_lin)],
-            "layers": stack(layer_keys[k_lin::per], False),
+            "layers": stack(layer_keys[k_lin::per], False, latent_layer if cfg.attn_kind == "latent" else None),
             "final_norm": jnp.ones((d,), pd),
         }
+        if cfg.split_ffn:
+            # mixers above, FFNs here, each kind a stack of its own in layer
+            # order: layer i's FFN is dense_ffn[i] for i < num_dense_layers,
+            # else expert_ffn[i - num_dense_layers] (:func:`split_ffn`)
+            nd_ = cfg.num_dense_layers
+            if nd_:
+                params["dense_ffn"] = stack(layer_keys[:nd_], False, lambda k: ffn_layer(k, False))
+            params["expert_ffn"] = stack(layer_keys[nd_:], True, lambda k: ffn_layer(k, True))
         if not cfg.tie_embeddings:
             params["head"] = dense_init(k_head, (cfg.vocab_size, d), d)
         return params
@@ -413,6 +570,13 @@ def param_specs(
     ``kv_tp=False`` replicates wk/wv across tp — required under GQA when
     ``kv_heads`` isn't divisible by the tp axis size (callers with a mesh,
     e.g. :func:`make_train_step`, decide automatically)."""
+    refused = {'"latent" layers': cfg.latent_layers > 0, "experts_held (a share of the experts)": cfg.experts_held is not None,
+               "expert layers beside linear layers": cfg.split_ffn,
+               'linear_gate="channel" or linear_gate_rank > 0': cfg.linear_gate != "head" or cfg.linear_gate_rank > 0}
+    bad = [name for name, hit in refused.items() if hit]
+    if bad:
+        raise ValueError("param_specs (a mesh) has no layout for a config with " + "; ".join(bad)
+                         + ": it runs on one device")
     ep = ep or dp
     kv = tp if kv_tp else None
 
@@ -735,21 +899,36 @@ def moe_ffn_dropless(cfg: TransformerConfig, layer, x, valid=None, *, stack=None
     ``kernel=False`` keeps the products XLA's own on the chip too: under a
     mesh, where GSPMD partitions ``ragged_dot`` and refuses a Mosaic call.
 
+    A config that holds a share of the experts (``cfg.experts_held``: ``E``
+    below is then the experts held, the router's width stays
+    ``cfg.num_experts``) routes and normalises over all of them and adds the
+    terms of its own.
+
     Returns (out [B, T, d], assignments int32[E]): how many (token, choice)
     pairs each expert got, counting only tokens ``valid`` [B, T] marks. Bucket
     padding and idle decode rows still compute (their rows are there), but
     they follow the first valid token's experts, so they make the products
     read no expert that no real token asked for."""
     B, T, d = x.shape
-    N, E, k = B * T, cfg.num_experts, cfg.expert_top_k
+    N, E, k = B * T, cfg.experts_here, cfg.expert_top_k
     x2 = x.reshape(N, d)
     experts, weights = route(cfg, layer, x2)
     if valid is not None:
         real = valid.reshape(N)
         experts = jnp.where(real[:, None], experts, experts[jnp.argmax(real)][None, :])
-    flat = experts.reshape(N * k)
-    order = jnp.argsort(flat)                       # assignments grouped by expert (stable)
-    group_sizes = jnp.bincount(flat, length=E).astype(jnp.int32)
+    if cfg.experts_held is None:
+        flat = experts.reshape(N * k)
+        order = jnp.argsort(flat)                       # assignments grouped by expert (stable)
+        group_sizes = jnp.bincount(flat, length=E).astype(jnp.int32)
+    else:
+        # a share: the assignments to experts held elsewhere are dropped before
+        # the sort (they sort last, as group E that no size counts), so the
+        # grouped products see rows for the experts held only and leave the
+        # rest zeros, which the weighted sum below then adds as nothing
+        lo, hi = cfg.experts_held
+        flat = jnp.where((experts >= lo) & (experts < hi), experts - lo, E).reshape(N * k)
+        order = jnp.argsort(flat)
+        group_sizes = jnp.zeros((E,), jnp.int32).at[flat].add(1, mode="drop")
     rows = x2[order // k]                           # [N*k, d]: each assignment's token
     w = {name: layer[name][None] for name in EXPERT_WEIGHTS} if stack is None else stack
     L = w["we1"].shape[0]
@@ -770,6 +949,7 @@ def moe_ffn_dropless(cfg: TransformerConfig, layer, x, valid=None, *, stack=None
     if valid is None:
         counted = group_sizes
     else:
+        # (index E, a share's dropped assignment, is out of range: not counted)
         counted = jnp.zeros((E,), jnp.int32).at[flat].add(jnp.repeat(real, k).astype(jnp.int32))
     return y.reshape(B, T, d).astype(x.dtype), counted
 
@@ -857,8 +1037,9 @@ def pre_norm(cfg: TransformerConfig, layer, name: str, x):
 def linear_inputs(cfg: TransformerConfig, layer, h, tail=None, lengths=None):
     """What the recurrence of a linear layer takes, from the layer's input
     ``h`` [B, T, d]: q, k [B, T, H, dk] (unit norm, q over sqrt(dk)), v
-    [B, T, H, dv], ``g = log alpha`` and ``beta`` [B, T, H], all float32, and
-    the convolution's tail after the call.
+    [B, T, H, dv], ``g = log alpha`` and ``beta`` [B, T, H] (``g`` [B, T, H, dk]
+    with ``linear_gate="channel"``), all float32, and the convolution's tail
+    after the call.
 
     q, k and v pass a causal depthwise convolution of ``linear_conv_width``
     over time and SiLU. ``tail`` [B, width - 1, channels] holds the inputs
@@ -886,31 +1067,144 @@ def linear_inputs(cfg: TransformerConfig, layer, h, tail=None, lengths=None):
         return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
 
     # the gates in float32 in fact: the chip's default precision would round h and the weights' product
-    a = jnp.einsum("btd,dh->bth", h.astype(f32), layer["lin_wa"].astype(f32), precision="highest")
+    channel = cfg.linear_gate == "channel"
+    if channel:
+        # a decay a key channel [B, T, H, dk], its projection low-rank
+        a = jnp.einsum("btd,dr->btr", h.astype(f32), layer["lin_wfa"].astype(f32), precision="highest")
+        a = jnp.einsum("btr,rhk->bthk", a, layer["lin_wfb"].astype(f32), precision="highest")
+    else:
+        a = jnp.einsum("btd,dh->bth", h.astype(f32), layer["lin_wa"].astype(f32), precision="highest")
     b = jnp.einsum("btd,dh->bth", h.astype(f32), layer["lin_wb"].astype(f32), precision="highest")
-    g = -jnp.exp(layer["A_log"].astype(f32)) * jax.nn.softplus(a + layer["dt_bias"].astype(f32))
+    A = jnp.exp(layer["A_log"].astype(f32))
+    g = -(A[:, None] if channel else A) * jax.nn.softplus(a + layer["dt_bias"].astype(f32))
     beta = jax.nn.sigmoid(b) * (2.0 if cfg.linear_allow_neg_eigval else 1.0)
     return unit(q) / math.sqrt(dk), unit(k), v, g, beta, new_tail
 
 
 def linear_out(cfg: TransformerConfig, layer, x, h, o):
-    """The linear branch's tail: ``RMSNorm_dv(o) * silu(h Wg)``, ``Wo``, post-norm, residual. o: [B,T,H,dv] f32."""
-    gate = jnp.einsum("btd,dhv->bthv", h, layer["lin_wg"].astype(h.dtype))
-    y = (_rms_norm(o, layer["o_norm"].astype(jnp.float32), cfg.norm_eps) * jax.nn.silu(gate.astype(jnp.float32))).astype(h.dtype)
+    """The linear branch's tail: ``RMSNorm_dv(o) * act(h Wg)`` (``act``:
+    ``linear_out_gate``; ``Wg`` low-rank with ``linear_gate_rank``), ``Wo``,
+    post-norm, residual. o: [B,T,H,dv] f32."""
+    if "lin_wga" in layer:
+        gate = jnp.einsum("btr,rhv->bthv", h @ layer["lin_wga"].astype(h.dtype), layer["lin_wgb"].astype(h.dtype))
+    else:
+        gate = jnp.einsum("btd,dhv->bthv", h, layer["lin_wg"].astype(h.dtype))
+    act = jax.nn.sigmoid if cfg.linear_out_gate == "sigmoid" else jax.nn.silu
+    y = (_rms_norm(o, layer["o_norm"].astype(jnp.float32), cfg.norm_eps) * act(gate.astype(jnp.float32))).astype(h.dtype)
     a = jnp.einsum("bthv,hvd->btd", y, layer["lin_wo"].astype(y.dtype))
     if cfg.post_norms:
         a = _rms_norm(a, layer["post_attn_norm"], cfg.norm_eps)
     return x + a
 
 
-def hybrid_scan(cfg: TransformerConfig, params, carry, x, linear_fn, full_fn):
+def latent_qkv(cfg: TransformerConfig, layer, h):
+    """A latent layer's projections of its input ``h`` [B, T, d]: the queries
+    ``[B, T, H, nope + rope]`` and the row the cache keeps a token,
+    ``[B, T, latent_row]`` = ``(RMSNorm(c~), k_pe)``: the normalised latent
+    every head's keys and values are expanded from, and the key part every
+    head shares, as projected (no rotation)."""
+    r = cfg.latent_rank
+    q = jnp.einsum("btd,dhk->bthk", h, layer["lat_wq"].astype(h.dtype))
+    ckv = h @ layer["lat_wkva"].astype(h.dtype)
+    c = _rms_norm(ckv[..., :r], layer["lat_norm"], cfg.norm_eps)
+    return q, jnp.concatenate([c, ckv[..., r:]], axis=-1)
+
+
+def latent_absorb(cfg: TransformerConfig, layer, q, lanes: Optional[int] = None):
+    """The queries in the absorbed form, ``[B, T, H, latent_row]`` (zeros up
+    to ``lanes`` where given): ``q' = W_kvb[K, head]^T q_nope`` beside
+    ``q_pe``, so that a head's score against a cached row is one dot product,
+    ``q' . c + q_pe . k_pe``, and no key is ever expanded."""
+    nope = cfg.latent_nope_dim
+    wk = layer["lat_wkvb"][..., :nope].astype(q.dtype)                       # [r, H, nope]
+    parts = [jnp.einsum("bthn,rhn->bthr", q[..., :nope], wk), q[..., nope:]]
+    if lanes is not None and lanes > cfg.latent_row:
+        parts.append(jnp.zeros((*q.shape[:-1], lanes - cfg.latent_row), q.dtype))
+    return jnp.concatenate(parts, axis=-1)
+
+
+def latent_out(cfg: TransformerConfig, layer, x, o_lat):
+    """The latent branch's tail from the attention-weighted latents ``o_lat``
+    [B, T, H, r] (``sum_j a_j c_j`` a head): the head's values ``W_kvb[V,
+    head] o_lat``, ``W_o``, post-norm, residual."""
+    wv = layer["lat_wkvb"][..., cfg.latent_nope_dim:].astype(o_lat.dtype)   # [r, H, v]
+    return _latent_wo(cfg, layer, x, jnp.einsum("bthr,rhv->bthv", o_lat, wv))
+
+
+def _latent_wo(cfg: TransformerConfig, layer, x, o):
+    """``W_o`` over the heads' values ``o`` [B, T, H, v], post-norm, residual."""
+    a = jnp.einsum("bthv,hvd->btd", o, layer["lat_wo"].astype(o.dtype))
+    if cfg.post_norms:
+        a = _rms_norm(a, layer["post_attn_norm"], cfg.norm_eps)
+    return x + a
+
+
+def latent_scale(cfg: TransformerConfig) -> float:
+    return 1.0 / math.sqrt(cfg.latent_nope_dim + cfg.latent_rope_dim)
+
+
+def latent_attention_expanded(cfg: TransformerConfig, layer, x, h):
+    """A latent layer's whole attention branch in the published, expanded
+    form, causal over the call's own ``T`` tokens: every head's keys
+    ``[k_nope; k_pe]`` and values expanded from the latents, softmax at
+    ``1 / sqrt(nope + rope)``, ``W_o``, residual. What :func:`forward` runs;
+    the cached paths run the absorbed form (:func:`latent_absorb`,
+    :func:`latent_out`), the same function."""
+    B, T, _ = h.shape
+    r, nope = cfg.latent_rank, cfg.latent_nope_dim
+    q, row = latent_qkv(cfg, layer, h)
+    kv = jnp.einsum("btr,rhk->bthk", row[..., :r], layer["lat_wkvb"].astype(h.dtype))   # [B, T, H, nope + v]
+    k_pe = jnp.broadcast_to(row[:, :, None, r:], (B, T, cfg.n_heads, cfg.latent_rope_dim))
+    k = jnp.concatenate([kv[..., :nope], k_pe], axis=-1)
+    s = jnp.einsum("bthk,bshk->bhts", q.astype(jnp.float32), k.astype(jnp.float32)) * latent_scale(cfg)
+    s = jnp.where(jnp.tril(jnp.ones((T, T), bool))[None, None], s, NEG_INF)
+    o = jnp.einsum("bhts,bshv->bthv", jax.nn.softmax(s, axis=-1), kv[..., nope:].astype(jnp.float32)).astype(h.dtype)
+    return _latent_wo(cfg, layer, x, o)
+
+
+def split_ffn(cfg: TransformerConfig, params, x, i, j: int, valid=None, kernel: bool = True):
+    """The FFN branch of layer ``i`` (traced) of a config whose FFNs are
+    stacks of their own (``cfg.split_ffn``); ``j`` is the layer's place in
+    its period, known while tracing. Layer ``i`` takes
+    ``params["dense_ffn"][i]`` while ``i < num_dense_layers`` and
+    ``params["expert_ffn"][i - num_dense_layers]`` after: a place that is
+    dense in the first period and routed in the others chooses by ``cond``,
+    every other place is routed and traces no branch. The small leaves are
+    read at a traced index, as a scan reads its xs; the experts' weights stay
+    where they lie (:func:`scanned_leaves`). Returns (x, the expert layer's
+    assignment counts int32[experts held]; zeros from a dense layer)."""
+    nd = cfg.num_dense_layers
+
+    def at(stack, index):
+        return jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(a, index, 0, keepdims=False), stack)
+
+    def dense(x):
+        layer = at(params["dense_ffn"], 0 if nd == 1 else jnp.clip(i, 0, nd - 1))
+        return block_ffn(cfg, layer, x)[0], jnp.zeros((cfg.experts_here,), jnp.int32)
+
+    def routed(x):
+        stack = params["expert_ffn"]
+        index = jnp.maximum(i - nd, 0)
+        return block_ffn(cfg, at(scanned_leaves(cfg, stack), index), x, valid, stack=stack, index=index, kernel=kernel)
+
+    if j >= nd:
+        return routed(x)
+    return jax.lax.cond(i < nd, dense, routed, x)
+
+
+def hybrid_scan(cfg: TransformerConfig, params, carry, x, linear_fn, full_fn, valid=None, kernel: bool = True):
     """The layer loop of a config with linear layers: a ``lax.scan`` over its
     periods whose body runs the period's ``k`` linear layers and then its
-    full layer, so what is traced and compiled is one period, whatever the
-    depth. ``linear_fn(carry, x, layer, i)`` and ``full_fn(carry, x, layer,
-    i)`` return ``(carry, x)``; ``i`` counts the layers of their kind from 0
-    (traced); ``carry`` is whatever the caller keeps beside ``x`` (the
-    caches, updated in place). Returns ``(carry, x)``.
+    full (or latent) layer, so what is traced and compiled is one period,
+    whatever the depth. ``linear_fn(carry, x, layer, i)`` and ``full_fn(carry,
+    x, layer, i)`` run a layer's mixer branch and return ``(carry, x)``; ``i``
+    counts the layers of their kind from 0 (traced); ``carry`` is whatever
+    the caller keeps beside ``x`` (the caches, updated in place). The FFN
+    branch follows each here: the layer's own leaves, or for a config whose
+    FFNs are stacks of their own :func:`split_ffn` (``valid``, ``kernel``: as
+    :func:`block_ffn` takes them). Returns ``(carry, x, counts)``: the expert
+    layers' assignment counts ``int32[periods, k + 1, experts held]``, None
+    without expert layers.
 
     The ``k`` linear layers are the body's own lines, each with its own
     stack among the scan's xs: the scan's slice of a stack is then one
@@ -922,17 +1216,27 @@ def hybrid_scan(cfg: TransformerConfig, params, carry, x, linear_fn, full_fn):
     write and read besides the weights themselves.)"""
     k = cfg.linear_per_period
 
+    def ffn(x, layer, j, p):
+        if cfg.split_ffn:
+            return split_ffn(cfg, params, x, p * (k + 1) + j, j, valid, kernel)
+        return block_ffn(cfg, layer, x)
+
     def period(state, xs):
         lin, full, p = xs
-        for j in range(k):
-            state = linear_fn(*state, lin[j], p * k + j)
-        return full_fn(*state, full, p), None
+        counts = []
+        for j in range(k + 1):
+            layer = lin[j] if j < k else full
+            carry, x = linear_fn(*state, layer, p * k + j) if j < k else full_fn(*state, layer, p)
+            x, c = ffn(x, layer, j, p)
+            state = (carry, x)
+            counts.append(c)
+        return state, jnp.stack(counts) if cfg.split_ffn else None
 
     if cfg.remat:
         period = jax.checkpoint(period, policy=jax.checkpoint_policies.dots_saveable if cfg.remat == "dots" else None)
-    (carry, x), _ = jax.lax.scan(period, (carry, x),
-                                 (tuple(params["linear_layers"]), params["layers"], jnp.arange(cfg.periods, dtype=jnp.int32)))
-    return carry, x
+    (carry, x), counts = jax.lax.scan(
+        period, (carry, x), (tuple(params["linear_layers"]), params["layers"], jnp.arange(cfg.periods, dtype=jnp.int32)))
+    return carry, x, counts
 
 
 def full_kind(cfg: TransformerConfig):
@@ -948,14 +1252,14 @@ def _hybrid_forward(cfg: TransformerConfig, params, x, positions, use_flash: boo
         h = pre_norm(cfg, layer, "attn_norm", x)
         q, k, v, g, beta, _ = linear_inputs(cfg, layer, h)
         o, _ = gated_delta_chunked(S0, q, k, v, g, beta)
-        x = linear_out(cfg, layer, x, h, o)
-        return carry, block_ffn(cfg, layer, x)[0]
+        return carry, linear_out(cfg, layer, x, h, o)
 
     def full_fn(carry, x, layer, _):
         h = pre_norm(cfg, layer, "attn_norm", x)
+        if cfg.attn_kind == "latent":
+            return carry, latent_attention_expanded(cfg, layer, x, h)
         q, k, v = block_qkv(cfg, layer, h, positions, full_kind(cfg))
-        x = block_attn_out(cfg, layer, x, h, _attention(cfg, q, k, v, use_flash))
-        return carry, block_ffn(cfg, layer, x)[0]
+        return carry, block_attn_out(cfg, layer, x, h, _attention(cfg, q, k, v, use_flash))
 
     return hybrid_scan(cfg, params, (), x, linear_fn, full_fn)[1]
 

@@ -471,6 +471,36 @@ LLM_STATE_ZEROED = _reg.counter(
     "Admissions whose decode slot started from a zero recurrent state (no "
     "snapshot on the matched path: the whole prompt is prefilled).",
 )
+LLM_LATENT_LAYERS = _reg.gauge(
+    "llm_latent_layers",
+    "Latent attention layers of the served config (one pool of latent rows "
+    "in place of K and V pools; absent for a config without them; 0 = the "
+    "engine was shut down).",
+    "layers",
+)
+LLM_KV_BYTES_PER_TOKEN = _reg.gauge(
+    "llm_kv_bytes_per_token",
+    "Bytes one cached token takes in the paged pools as they were built, "
+    "over all attention layers, pad lanes included (a config with latent "
+    "layers: what the page budget is reckoned in).",
+    "bytes",
+)
+LLM_MOE_EXPERTS_HELD = _reg.gauge(
+    "llm_moe_experts_held",
+    "Routed experts whose weights this engine holds, of the experts its "
+    "routers score (a config that holds a share of its experts).",
+    "experts",
+)
+LLM_MOE_ASSIGNMENTS_ROUTED = _reg.counter(
+    "llm_moe_assignments_routed_total",
+    "(token, choice) pairs the expert layers' routers chose over all the "
+    "experts, held here or elsewhere (a config that holds a share).",
+)
+LLM_MOE_ASSIGNMENTS_LOCAL = _reg.counter(
+    "llm_moe_assignments_local_total",
+    "(token, choice) pairs that landed on the experts this engine holds: "
+    "the rows its grouped products computed.",
+)
 LLM_LOOP_PHASE_SECONDS = _reg.counter(
     "llm_loop_phase_seconds_total",
     "Wall time of the LLM engine's loop thread by phase (serve/llm.py "
@@ -646,6 +676,11 @@ ALL_METRICS = [
     LLM_STATE_SNAPSHOTS_EVICTED,
     LLM_STATE_RESTORES,
     LLM_STATE_ZEROED,
+    LLM_LATENT_LAYERS,
+    LLM_KV_BYTES_PER_TOKEN,
+    LLM_MOE_EXPERTS_HELD,
+    LLM_MOE_ASSIGNMENTS_ROUTED,
+    LLM_MOE_ASSIGNMENTS_LOCAL,
     LLM_LOOP_PHASE_SECONDS,
     LLM_DECODE_DISPATCHES,
     LLM_TTFT,

@@ -52,6 +52,14 @@ first query still sees to its last query's own, causal and windowed by
 position, so a prefill chunk reads the pages it can see and no
 ``[T, capacity]`` score tensor exists.
 
+:func:`latent_paged_decode` and :func:`latent_paged_prefill` are the same
+walk over the **one** pool of a latent attention layer (``[L, num_blocks,
+block_size, lanes]``: a token's normalised latent and the key part every head
+shares, side by side, zero lanes up to whole tiles): a group of cached rows is
+the keys of every head (all its lanes, against queries in the absorbed form)
+and, in its first ``rank`` lanes, the values, so each row is copied once and
+the heads are the rows of one ``QK^T`` and one ``P.V``.
+
 No backward pass: decode is inference-only. Non-TPU backends run in
 interpret mode (tests exercise the same code path on CPU).
 """
@@ -59,6 +67,7 @@ interpret mode (tests exercise the same code path on CPU).
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -221,7 +230,8 @@ def _walk_pages(tables_ref, row, layer, k_hbm, v_hbm, k_buf, v_buf, sems, block_
     of a group past the last visible one, or before the first, are not
     fetched: their rows keep what an earlier group left there, and the
     caller masks them by position (scores by ``where``, so stale K cannot
-    reach a sum, and a probability of exactly 0 meets stale V)."""
+    reach a sum, and a probability of exactly 0 meets stale V). ``v_hbm`` None
+    (a latent pool: one row is key and value): only ``k_hbm`` is walked."""
     span = k_buf.shape[1]  # tokens a group
     group = span // block_size
     page_lo, page_hi = first // block_size, pl.cdiv(held, block_size)
@@ -234,7 +244,8 @@ def _walk_pages(tables_ref, row, layer, k_hbm, v_hbm, k_buf, v_buf, sems, block_
             phys = tables_ref[row, page]
             rows = pl.ds(pl.multiple_of((page - g * group) * block_size, block_size), block_size)
             act(pltpu.make_async_copy(k_hbm.at[layer, phys], k_buf.at[slot, rows], sems.at[0, slot]))
-            act(pltpu.make_async_copy(v_hbm.at[layer, phys], v_buf.at[slot, rows], sems.at[1, slot]))
+            if v_hbm is not None:
+                act(pltpu.make_async_copy(v_hbm.at[layer, phys], v_buf.at[slot, rows], sems.at[1, slot]))
             return carry
 
         jax.lax.fori_loop(jnp.maximum(page_lo, g * group), jnp.minimum(page_hi, (g + 1) * group), one_page, None)
@@ -743,3 +754,200 @@ def paged_prefill_attention(
         "paged_prefill", scalars, qg, k_pool, v_pool, grid=(B, (T + pad_t) // tq), rows=tq * n_rep,
         q_index=lambda b, i, *_: (b, 0, i, 0), vmem_limit_bytes=_PREFILL_VMEM_BYTES)
     return ungrouped(_own_lanes(out, pack))[:, :T]
+
+
+# ---------------------------------------------------------------------------
+# latent attention: one pool, a row is key and value
+# ---------------------------------------------------------------------------
+# cached rows a group of the decode walk: 64 pages of 16, two slots of 1.25 MiB at 640 lanes. The kernel alone on
+# the chip, 64 rows of 17k cached tokens, a layer: 5.22 ms at 256, 4.18 at 512, 3.77 at 1024, 3.59 at 2048 (the
+# rows' bytes at the chip's bandwidth: 1.70 ms): a group's fixed cost (the waits, the softmax step's rescale of a
+# [32, 512] accumulator) is paid half as often at each doubling, and past 1024 little is left of it
+_LATENT_DECODE_SPAN = 1024
+_LATENT_PREFILL_ROWS = 2048  # query rows (queries x heads) a grid step of the prefill kernel
+
+
+def _latent_decode_kernel(tables_ref, lengths_ref, layer_ref, q_ref, pool_hbm, o_ref, buf, sems, m_scr, l_scr, acc_scr,
+                          *, sm_scale: float, block_size: int, rank: int):
+    """Grid (B,): a sequence a grid step, its cached rows walked once
+    (:func:`_walk_pages`). q_ref ``[rows, lanes]``: a head a row, the query in
+    the absorbed form (zero rows pad the heads to a sublane tile); a group of
+    cached rows ``[span, lanes]`` is every head's keys, and its first ``rank``
+    lanes every head's values: one ``QK^T``, one softmax step and one ``P.V``
+    a group serve all heads. Operands go to the MXU as stored, ``P`` rounded
+    to the pool's type for one pass (:func:`_dot_pv`); scores and state are
+    float32. o_ref ``[rows, rank]``: ``sum_j a_j c_j`` a head."""
+    bi = pl.program_id(0)
+    span = buf.shape[1]
+    held = jnp.minimum(lengths_ref[bi], tables_ref.shape[1] * block_size)
+
+    @pl.when(bi == 0)
+    def _clean_buffer():
+        buf[...] = jnp.zeros_like(buf)
+
+    m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+    one_pass = functools.partial(_dot_pv, terms=1)
+
+    def one_group(g, slot):
+        pos = g * span + jax.lax.broadcasted_iota(jnp.int32, (1, span), 1)
+        s = _dot_qk(q_ref[...], buf[slot]) * sm_scale
+        _softmax_step(jnp.where(pos < held, s, NEG_INF), buf[slot, :, :rank], 0, m_scr, l_scr, acc_scr, dot_pv=one_pass)
+
+    _walk_pages(tables_ref, bi, layer_ref[0], pool_hbm, None, buf, None, sems, block_size, 0, held, one_group)
+    o_ref[...] = _finished(m_scr, l_scr, acc_scr, 0).astype(o_ref.dtype)
+
+
+def _latent_prefill_kernel(tables_ref, starts_ref, lengths_ref, layer_ref, q_ref, pool_hbm, o_ref, buf, sems,
+                           m_scr, l_scr, acc_scr, *, sm_scale: float, block_size: int, rank: int, heads: int):
+    """Grid (B, T / tq): a tile of ``tq`` consecutive queries of a chunk a grid
+    step, all heads: q_ref ``[tq * heads, lanes]``, row ``t * heads + h``
+    query ``t``'s head ``h`` in the absorbed form. The tile's keys are walked
+    where they lie from position 0 to its last query's own (or the chunk's
+    last real token's), causal by position, as :func:`_paged_prefill_kernel`
+    walks them; a cached row is key and value as in
+    :func:`_latent_decode_kernel`."""
+    bi, qi = pl.program_id(0), pl.program_id(1)
+    rows = q_ref.shape[0]
+    span, tq = buf.shape[1], rows // heads
+    p0 = starts_ref[bi] + qi * tq
+    held = jnp.minimum(starts_ref[bi] + lengths_ref[bi], tables_ref.shape[1] * block_size)
+    last = jnp.where(p0 < held, jnp.minimum(held, p0 + tq), 0)
+
+    @pl.when(jnp.logical_and(bi == 0, qi == 0))
+    def _clean_buffer():
+        buf[...] = jnp.zeros_like(buf)
+
+    m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+    q_pos = p0 + jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0) // heads
+    one_pass = functools.partial(_dot_pv, terms=1)
+
+    def one_group(g, slot):
+        pos = g * span + jax.lax.broadcasted_iota(jnp.int32, (1, span), 1)
+        visible = jnp.logical_and(pos <= q_pos, pos < last)  # [rows, span]
+        s = _dot_qk(q_ref[...], buf[slot]) * sm_scale
+        _softmax_step(jnp.where(visible, s, NEG_INF), buf[slot, :, :rank], 0, m_scr, l_scr, acc_scr, dot_pv=one_pass)
+
+    _walk_pages(tables_ref, bi, layer_ref[0], pool_hbm, None, buf, None, sems, block_size, 0, last, one_group)
+    o_ref[...] = _finished(m_scr, l_scr, acc_scr, 0).astype(o_ref.dtype)
+
+
+def _latent_call(kernel, name, scalars, q, pool, *, grid, rows, q_index, rank, span, **compiler_params):
+    """The ``pallas_call`` of both latent kernels: q ``[B, R, lanes]`` in
+    blocks of ``rows`` at ``q_index``, the result ``[B, R, rank]`` likewise;
+    the pool stays in HBM; scratch: the two-slot group buffer, its copy
+    semaphores, the online-softmax state."""
+    B, _, W = q.shape
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(scalars),
+        grid=grid,
+        in_specs=[pl.BlockSpec((None, rows, W), q_index), pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((None, rows, rank), q_index),
+        scratch_shapes=[
+            pltpu.VMEM((2, span, W), pool.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.VMEM((1, rows, _LANES), jnp.float32),
+            pltpu.VMEM((1, rows, _LANES), jnp.float32),
+            pltpu.VMEM((1, rows, rank), jnp.float32),
+        ],
+    )
+    return pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((B, q.shape[1], rank), q.dtype),
+        grid_spec=grid_spec,
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",) * len(grid), **compiler_params),
+        interpret=_use_interpret(),
+        name=name,
+    )(*scalars, q, pool)
+
+
+def _latent_xla(q, pool, block_tables, starts, lengths, layer, scale, rank):
+    """Plain-XLA reference of both latent kernels: each sequence's rows of
+    ``layer`` gathered to a dense ``[B, M*bs, lanes]`` view, masked scores
+    of every head against them, their first ``rank`` lanes as values."""
+    B, T = q.shape[:2]
+    rows = pool[layer, block_tables].reshape(B, -1, pool.shape[-1]).astype(jnp.float32)
+    s = jnp.einsum("bthw,bsw->bhts", q.astype(jnp.float32), rows) * scale
+    pos = jnp.arange(rows.shape[1])[None, None, :]
+    q_pos = (starts[:, None] + jnp.arange(T)[None, :])[:, :, None]
+    vis = (pos <= q_pos) & (pos < (starts + lengths)[:, None, None])       # [B, T, S]
+    p = jnp.where(vis.any(-1)[:, None, :, None], jax.nn.softmax(jnp.where(vis[:, None], s, NEG_INF), axis=-1), 0.0)
+    return jnp.einsum("bhts,bsr->bthr", p, rows[..., :rank])
+
+
+def latent_paged_decode(
+    q: jax.Array,             # [B, H, lanes] one absorbed query a head a sequence
+    pool: jax.Array,          # [L, num_blocks, block_size, lanes] the latent pool
+    block_tables: jax.Array,  # [B, M]
+    lengths: jax.Array,       # [B] cached rows of each sequence
+    layer: jax.Array,         # int32 scalar: the pool's layer
+    *,
+    rank: int,
+    sm_scale: float,
+    use_kernel: bool = True,
+    span: Optional[int] = None,
+) -> jax.Array:
+    """Decode attention of a latent layer over its one pool: returns
+    ``[B, H, rank]``, ``sum_j a_j c_j`` a head (the caller expands it to the
+    head's values). ``q`` is in the absorbed form
+    (``models/transformer.latent_absorb``), zero in the pool's pad lanes; a
+    cached row's ``lanes`` are its key for every head, its first ``rank``
+    its value. Idle rows (table starting at the garbage page 0) read as
+    length 0, as in :func:`paged_decode_attention`. ``span``: cached rows a
+    group of the walk (a multiple of the page and of 128)."""
+    B, H, W = q.shape
+    bs = pool.shape[2]
+    lengths = jnp.where(block_tables[:, 0] > 0, lengths, 0).astype(jnp.int32)
+    if not use_kernel:
+        out = _latent_xla(q[:, None], pool, block_tables, jnp.maximum(lengths - 1, 0), jnp.minimum(lengths, 1),
+                          layer, sm_scale, rank)
+        return out[:, 0].astype(q.dtype)
+    pad = (-H) % 16
+    qp = jnp.pad(q, ((0, 0), (0, pad), (0, 0))) if pad else q
+    scalars = [block_tables.astype(jnp.int32), lengths, jnp.asarray(layer, jnp.int32).reshape(1)]
+    out = _latent_call(
+        functools.partial(_latent_decode_kernel, sm_scale=sm_scale, block_size=bs, rank=rank),
+        "latent_paged_decode", scalars, qp, pool, grid=(B,), rows=H + pad, q_index=lambda b, *_: (b, 0, 0),
+        rank=rank, span=span or max(1, _LATENT_DECODE_SPAN // bs) * bs)
+    return out[:, :H]
+
+
+def latent_paged_prefill(
+    q: jax.Array,             # [B, T, H, lanes] a chunk of consecutive queries a sequence, absorbed
+    pool: jax.Array,          # [L, num_blocks, block_size, lanes]
+    block_tables: jax.Array,  # [B, M]
+    starts: jax.Array,        # [B] the position of each chunk's first query
+    lengths: jax.Array,       # [B] real tokens of each chunk
+    layer: jax.Array,
+    *,
+    rank: int,
+    sm_scale: float,
+    use_kernel: bool = True,
+) -> jax.Array:
+    """Chunked-prefill attention of a latent layer over its one pool; returns
+    ``[B, T, H, rank]``. The chunk's own rows are in the pool already; query
+    ``t`` of row ``b`` stands at ``starts[b] + t`` and sees the rows at or
+    before it, as far as the chunk's real tokens reach, as
+    :func:`paged_prefill_attention` has it. A tile of queries re-reads the
+    rows before it: 1280 B a token, a twelfth of what K and V of 32 heads of
+    128 cost, so the walk is bound by its products, not its copies."""
+    B, T, H, W = q.shape
+    bs = pool.shape[2]
+    starts, lengths = starts.astype(jnp.int32), jnp.broadcast_to(jnp.asarray(lengths, jnp.int32), (B,))
+    if not use_kernel:
+        return _latent_xla(q, pool, block_tables, starts, lengths, layer, sm_scale, rank).astype(q.dtype)
+    unit = 16 // math.gcd(16, H)  # the fewest queries whose heads fill whole sublane tiles of a 16-bit query
+    tq = max(unit, min(_LATENT_PREFILL_ROWS // H // unit * unit, -(-T // unit) * unit))
+    pad_t = (-T) % tq
+    if pad_t:
+        q = jnp.pad(q, ((0, 0), (0, pad_t), (0, 0), (0, 0)))
+    scalars = [block_tables.astype(jnp.int32), starts, lengths, jnp.asarray(layer, jnp.int32).reshape(1)]
+    out = _latent_call(
+        functools.partial(_latent_prefill_kernel, sm_scale=sm_scale, block_size=bs, rank=rank, heads=H),
+        "latent_paged_prefill", scalars, q.reshape(B, -1, W), pool, grid=(B, (T + pad_t) // tq), rows=tq * H,
+        q_index=lambda b, i, *_: (b, i, 0), rank=rank, span=max(1, _LANES // bs) * bs,
+        vmem_limit_bytes=_PREFILL_VMEM_BYTES)
+    return out.reshape(B, T + pad_t, H, rank)[:, :T]

@@ -14,6 +14,16 @@ writing strength ``beta`` (in (0, 2) when negative eigenvalues are allowed):
   inverted exactly by halving: it is unit lower triangular), between chunks
   the state is carried by a ``lax.scan``. A ``valid`` mask turns padded
   positions into no-ops (``beta`` 0, ``alpha`` 1), so any length runs.
+  With a decay **a key channel** (``g`` ``[.., H, dk]``: Kimi Delta Attention,
+  ``S' = Diag(alpha_t) S_{t-1}``) no scalar ``Gamma[i, j]`` comes out of
+  ``K K^T``: the products are ``(k_i e^{gc_i}) . (k_j e^{-gc_j})`` a channel,
+  and ``e^{-gc_j}`` overflows inside a chunk once a channel forgets fast. The
+  chunk is therefore cut into sub-chunks of ``SUB`` positions
+  (:func:`_decayed_gram`): a block of rows against the columns before its
+  sub-chunk takes both decays relative to the sub-chunk's start, where both
+  exponents are <= 0 (two bounded factors, one product); the block on the
+  diagonal takes ``e^{gc_i - gc_j}`` a pair and channel, masked before the
+  ``exp``. No exponent is ever positive, so the form holds for any ``g <= 0``.
 * :func:`gated_delta_decode` — one token a row for a batch of rows whose
   states live in a stacked per-slot array: on the chip a Pallas kernel that,
   a row a grid step, reads the row's state of layer ``l``, applies the update
@@ -43,6 +53,7 @@ from jax.experimental.pallas import tpu as pltpu
 from ray_tpu.ops.attention import _use_interpret
 
 CHUNK = 64
+SUB = 16  # positions a sub-chunk of the channel-decay form (the published kernels' too)
 _HI = jax.lax.Precision.HIGHEST
 
 
@@ -71,11 +82,14 @@ def unpack_state(Sp: jax.Array, g: int) -> jax.Array:
 
 def gated_delta_step(S, q, k, v, alpha, beta):
     """One token. S ``[..., H, dk, dv]`` f32; q, k ``[..., H, dk]``; v
-    ``[..., H, dv]``; alpha, beta ``[..., H]``. Returns (o ``[..., H, dv]``,
-    the state after). Products and sums on the vector unit, in float32: a
-    matrix product would round its operands on the chip."""
+    ``[..., H, dv]``; alpha ``[..., H]`` (a decay a head) or ``[..., H, dk]``
+    (a decay a key channel: a row of the state is scaled by its own
+    channel's); beta ``[..., H]``. Returns (o ``[..., H, dv]``, the state
+    after). Products and sums on the vector unit, in float32: a matrix
+    product would round its operands on the chip."""
     S, q, k, v = (x.astype(jnp.float32) for x in (S, q, k, v))
-    Sd = S * alpha.astype(jnp.float32)[..., None, None]
+    alpha = alpha.astype(jnp.float32)
+    Sd = S * (alpha[..., None] if alpha.ndim == k.ndim else alpha[..., None, None])
     u = beta.astype(jnp.float32)[..., None] * (v - (Sd * k[..., None]).sum(-2))
     Sn = Sd + k[..., None] * u[..., None, :]
     return (Sn * q[..., None]).sum(-2), Sn
@@ -99,11 +113,14 @@ def unit_lower_inverse(M: jax.Array) -> jax.Array:
 
 def gated_delta_chunked(S0, q, k, v, g, beta, valid=None, chunk: int = CHUNK):
     """``T`` tokens. S0 ``[B, H, dk, dv]``; q, k ``[B, T, H, dk]``; v
-    ``[B, T, H, dv]``; g (``log alpha``, <= 0) and beta ``[B, T, H]``; valid
-    ``[B, T]`` bool or None. Returns (o ``[B, T, H, dv]`` f32, the state after
+    ``[B, T, H, dv]``; g (``log alpha``, <= 0) ``[B, T, H]`` or, a decay a key
+    channel, ``[B, T, H, dk]``; beta ``[B, T, H]``; valid ``[B, T]`` bool or
+    None. Returns (o ``[B, T, H, dv]`` f32, the state after
     the last valid token). Float32 at the highest matmul precision; named
     ``gated_delta_chunked`` in a profile (``jax.named_scope``)."""
     with jax.named_scope("gated_delta_chunked"):
+        if g.ndim == q.ndim:
+            return _chunked_channel(S0, q, k, v, g, beta, valid, chunk)
         return _chunked(S0, q, k, v, g, beta, valid, chunk)
 
 
@@ -154,16 +171,88 @@ def _chunked(S0, q, k, v, g, beta, valid, C):
     return o[:, :T], S
 
 
+def _decayed_gram(x, k, g, gc, sub):
+    """``G[i, j] = sum_c x_i[c] k_j[c] exp(gc_i[c] - gc_j[c])`` for ``j <= i``
+    (0 above the diagonal) of one chunk: x, k, g, gc ``[..., C, dk]``, ``gc``
+    the running sum of ``g`` from the chunk's start. Sub-chunks of ``sub``
+    positions: rows of sub-chunk ``a`` against the columns before it as one
+    product of ``x_i e^{gc_i - r_a}`` and ``k_j e^{r_a - gc_j}`` (``r_a``: the
+    sum up to ``a``'s start; both exponents <= 0), against its own columns
+    pair by pair. ``x`` may carry a leading axis of its own (several left
+    operands share the exponentials)."""
+    *lead, C, dk = k.shape
+    n = C // sub
+    cut = lambda a: a.reshape(*a.shape[:-2], n, sub, dk)  # noqa: E731
+    xs, ks, gcs, gs = cut(x), cut(k), cut(gc), cut(g)
+    r = gcs[..., :1, :] - gs[..., :1, :]                          # [..., n, 1, dk]: the sum before a's first position
+    left = xs * jnp.exp(gcs - r)
+    before = (jnp.arange(C)[None, :] < (jnp.arange(n) * sub)[:, None])[..., None]          # [n, C, 1]: j before a's start
+    right = k[..., None, :, :] * jnp.exp(jnp.where(before, r - gc[..., None, :, :], -jnp.inf))  # [..., n, C, dk]
+    off = jnp.einsum("...atc,...ajc->...atj", left, right, precision=_HI)            # [..., n, sub, C]
+    own = jnp.tril(jnp.ones((sub, sub), bool))[..., None]
+    pair = ks[..., None, :, :] * jnp.exp(jnp.where(own, gcs[..., :, None, :] - gcs[..., None, :, :], -jnp.inf))
+    diag = jnp.einsum("...atc,...atjc->...atj", xs, pair, precision=_HI)             # [..., n, sub, sub]
+    diag = diag[..., None, :] * jnp.eye(n, dtype=diag.dtype)[:, None, :, None]          # [..., a, t, b, j]
+    return (off + diag.reshape(*diag.shape[:-2], C)).reshape(*off.shape[:-3], C, C)
+
+
+def _chunked_channel(S0, q, k, v, g, beta, valid, C, sub=SUB):
+    """:func:`_chunked` with ``g`` ``[B, T, H, dk]``: the same WY form, the
+    scalar ``Gamma`` replaced by :func:`_decayed_gram`."""
+    B, T, H, dk = q.shape
+    dv = v.shape[-1]
+    f32 = jnp.float32
+    q, k, v, g, beta = (x.astype(f32) for x in (q, k, v, g, beta))
+    if valid is not None:
+        g = jnp.where(valid[..., None, None], g, 0.0)
+        beta = jnp.where(valid[..., None], beta, 0.0)
+    pad = (-T) % C
+    if pad:
+        q, k, v, g, beta = (jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2)) for x in (q, k, v, g, beta))
+    N = (T + pad) // C
+
+    def chunks(x):  # [B, N*C, H, ...] -> [N, B, H, C, ...]
+        x = x.reshape(B, N, C, H, *x.shape[3:])
+        return jnp.moveaxis(jnp.moveaxis(x, 3, 2), 1, 0)
+
+    q, k, v, g, beta = (chunks(x) for x in (q, k, v, g, beta))
+    gc = jnp.cumsum(g, axis=-2)                                   # [N,B,H,C,dk] log decay from the chunk's start
+    decay = jnp.exp(gc)
+    kb, vb = k * beta[..., None], v * beta[..., None]
+    gram = _decayed_gram(jnp.stack([kb, q]), k, g, gc, sub)       # [2,N,B,H,C,C], lower, diagonal included
+    A = jnp.where(jnp.tril(jnp.ones((C, C), bool), -1), gram[0], 0.0)
+    attn = gram[1]
+    Tm = unit_lower_inverse(A + jnp.eye(C, dtype=f32))
+    u = jnp.einsum("...ij,...jv->...iv", Tm, vb, precision=_HI)             # [N,B,H,C,dv]
+    w = jnp.einsum("...ij,...jk->...ik", Tm, kb * decay, precision=_HI)     # [N,B,H,C,dk]
+    qd = q * decay
+    k_out = k * jnp.exp(gc[..., -1:, :] - gc)                     # each key decayed to the chunk's end, a channel
+    last = decay[..., -1, :]                                      # [N,B,H,dk]
+
+    def body(S, xs):
+        u_n, w_n, attn_n, qd_n, k_n, last_n = xs
+        v_new = u_n - jnp.einsum("...ck,...kv->...cv", w_n, S, precision=_HI)
+        o = jnp.einsum("...ck,...kv->...cv", qd_n, S, precision=_HI) + \
+            jnp.einsum("...ij,...jv->...iv", attn_n, v_new, precision=_HI)
+        S = S * last_n[..., None] + jnp.einsum("...ck,...cv->...kv", k_n, v_new, precision=_HI)
+        return S, o
+
+    S, o = jax.lax.scan(body, S0.astype(f32), (u, w, attn, qd, k_out, last))
+    o = jnp.moveaxis(jnp.moveaxis(o, 0, 1), 2, 3).reshape(B, N * C, H, dv)
+    return o[:, :T], S
+
+
 # ---------------------------------------------------------------------------
 # the decode step over a stacked per-slot state
 # ---------------------------------------------------------------------------
 def _decode_kernel(l_ref, slots_ref, live_ref, kT_ref, qT_ref, v_ref, a_ref, b_ref, s_ref, o_ref, s_out_ref,
-                   *, groups, g, dv):
+                   *, groups, g, dv, channel=False):
     """One row: every lane group of the row's state. ``kT``/``qT`` ``[dk, H]``
     (a head a lane), ``v``/``a``/``b`` ``[groups, g * dv]`` (a head's scalar
     repeated over its ``dv`` lanes). A key's column, broadcast over the lanes
     of its head, stands in for the head axis: no lane is ever sliced off the
-    state."""
+    state. ``channel``: ``a`` is ``[dk, H]`` like the key, a decay a key
+    channel, and reaches a row of the state as the key's column does."""
     del l_ref, slots_ref
     live = live_ref[pl.program_id(0)] > 0
     dk = s_ref.shape[-2]
@@ -179,7 +268,7 @@ def _decode_kernel(l_ref, slots_ref, live_ref, kT_ref, qT_ref, v_ref, a_ref, b_r
     for p in range(groups):
         S = s_ref[0, 0, p]
         kx, qx = over_lanes(kT, p), over_lanes(qT, p)
-        Sd = S * a_ref[0, p : p + 1, :]
+        Sd = S * (over_lanes(a_ref[0], p) if channel else a_ref[0, p : p + 1, :])
         u = b_ref[0, p : p + 1, :] * (v_ref[0, p : p + 1, :] - jnp.sum(Sd * kx, axis=0, keepdims=True))
         Sn = Sd + kx * u
         o_ref[0, p : p + 1, :] = jnp.sum(Sn * qx, axis=0, keepdims=True)
@@ -190,8 +279,8 @@ def gated_delta_decode(state, layer, slots, live, q, k, v, alpha, beta, *, kerne
     """One token a row. state ``[L, slots, H / g, dk, g * dv]`` f32, updated
     at ``[layer, slots[r]]`` for the rows ``live`` marks and left as it is for
     the others; ``layer`` a traced scalar; slots ``[B]`` int32 (distinct);
-    live ``[B]`` bool; q, k ``[B, H, dk]``; v ``[B, H, dv]``; alpha, beta
-    ``[B, H]``. Returns (o ``[B, H, dv]`` f32, state). ``kernel``: the Pallas
+    live ``[B]`` bool; q, k ``[B, H, dk]``; v ``[B, H, dv]``; alpha ``[B, H]``
+    or ``[B, H, dk]`` (a decay a key channel); beta ``[B, H]``. Returns (o ``[B, H, dv]`` f32, state). ``kernel``: the Pallas
     kernel (the caller asks ``ops.backend.on_tpu()`` once, as for the paged
     attention kernels); else :func:`gated_delta_step` on the gathered rows."""
     B, H, dk = q.shape
@@ -209,17 +298,19 @@ def gated_delta_decode(state, layer, slots, live, q, k, v, alpha, beta, *, kerne
         return jnp.repeat(x.astype(f32), dv, axis=-1).reshape(B, Hg, gdv)
 
     kT, qT = (jnp.swapaxes(x.astype(f32), 1, 2) for x in (k, q))  # [B, dk, H]
+    channel = alpha.ndim == 3
     rows = lambda r, *_: (r, 0, 0)  # noqa: E731
     at = lambda r, l, slots, live: (l[0], slots[r], 0, 0, 0)  # noqa: E731
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3, grid=(B,),
         in_specs=[pl.BlockSpec((1, dk, H), rows), pl.BlockSpec((1, dk, H), rows),
-                  pl.BlockSpec((1, Hg, gdv), rows), pl.BlockSpec((1, Hg, gdv), rows), pl.BlockSpec((1, Hg, gdv), rows),
+                  pl.BlockSpec((1, Hg, gdv), rows),
+                  pl.BlockSpec((1, dk, H) if channel else (1, Hg, gdv), rows), pl.BlockSpec((1, Hg, gdv), rows),
                   pl.BlockSpec((1, 1, Hg, dk, gdv), at)],
         out_specs=[pl.BlockSpec((1, Hg, gdv), rows), pl.BlockSpec((1, 1, Hg, dk, gdv), at)],
     )
     o, state = pl.pallas_call(
-        functools.partial(_decode_kernel, groups=Hg, g=g, dv=dv),
+        functools.partial(_decode_kernel, groups=Hg, g=g, dv=dv, **({"channel": True} if channel else {})),
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((B, Hg, gdv), f32), jax.ShapeDtypeStruct(state.shape, state.dtype)],
         input_output_aliases={8: 1},  # the state, counted with the three prefetched scalars
@@ -227,5 +318,6 @@ def gated_delta_decode(state, layer, slots, live, q, k, v, alpha, beta, *, kerne
         interpret=_use_interpret(),
         name="gated_delta_decode",
     )(jnp.reshape(layer, (1,)).astype(jnp.int32), slots.astype(jnp.int32), live.astype(jnp.int32),
-      kT, qT, v.astype(f32).reshape(B, Hg, gdv), lanes(alpha), lanes(beta), state)
+      kT, qT, v.astype(f32).reshape(B, Hg, gdv), jnp.swapaxes(alpha.astype(f32), 1, 2) if channel else lanes(alpha),
+      lanes(beta), state)
     return o.reshape(B, H, dv), state

@@ -70,7 +70,12 @@ dictated by XLA's static-shape compilation model:
   until that step's tokens are read and kept: a row-step discarded because
   an EOS or a cancel was seen a step late has advanced the slot's state,
   and its snapshot is dropped with it. A finished request's snapshot goes
-  to the radix node of its depth when its pages are published. Both pools
+  to the radix node of its depth when its pages are published. A request
+  that has to prefill two chunks or more over pages the cache holds (no
+  snapshot was ever taken at the end of what it shares, or that one aged
+  out) cuts a chunk where its tokens part from another request's and
+  leaves a snapshot on that node at once, for the requests after it
+  (``_branch_snapshot_at``). Both pools
   evict the least recently used, and requests are admitted in order of
   arrival: a waiting session keeps its pages and its snapshot only while
   the pools' turnover (the unreferenced pages over the rate new ones are
@@ -90,7 +95,7 @@ import time
 from collections import Counter, deque
 from concurrent.futures import Future
 from dataclasses import dataclass, field
-from typing import Any, Callable, Dict, List, NamedTuple, Optional, Tuple
+from typing import Any, Callable, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -105,6 +110,7 @@ from ray_tpu.models.generation import (
     init_paged_cache,
     init_sequence_state,
     open_blocks,
+    page_pools,
     paged_block_step,
     paged_cache_spec,
     paged_forward_counted,
@@ -124,7 +130,7 @@ from ray_tpu.runtime.context import (
     current_tenant,
 )
 from ray_tpu.serve.kv_blocks import BlockAllocator, SnapshotPool
-from ray_tpu.serve.prefix_cache import PrefixCache
+from ray_tpu.serve.prefix_cache import PrefixCache, chain_keys
 
 _STREAM_END = object()
 
@@ -218,6 +224,13 @@ class GenRequest:
     # a config with linear layers: the state snapshot taken for this request
     # and not yet published, (snapshot pool entry, tokens it covers), or None
     snap: Optional[Tuple[int, int]] = None
+    # with a prefix cache: the digests of the prompt's whole blocks
+    # (``prefix_cache.chain_keys``), computed once by ``submit`` on its
+    # caller's thread; and, for a config with linear layers, the tokens of
+    # the prompt after which prefill leaves a snapshot for the requests that
+    # share them (``PrefixCache.branch_point``), 0 for none
+    block_keys: Sequence[bytes] = ()
+    branch_at: int = 0
 
     def emit(self, tok: int) -> None:
         if self.stream_queue is not None:
@@ -405,6 +418,8 @@ class LLMEngine:
         # a config with linear layers keeps a recurrent state a sequence at
         # its slot, and a pool of snapshots of it beside the page pool
         self._hybrid = cfg.hybrid
+        # layers that walk pages: K and V, or a latent layer's one row a token
+        self._attn_layers = cfg.kv_layers + cfg.latent_layers
         if self._hybrid:
             # no silent path: what the slots' state cannot follow yet is refused by name
             refused = {
@@ -548,7 +563,7 @@ class LLMEngine:
         # (window, layers that have it); 0: a full layer
         # (a linear layer has no K/V: ``_chunk_kv_visited`` still averages over every layer)
         self._layers_by_window = sorted(Counter(
-            (0,) * cfg.kv_layers if self._hybrid else cfg.layer_windows or (0,) * cfg.n_layers).items())
+            (0,) * self._attn_layers if self._hybrid else cfg.layer_windows or (0,) * cfg.n_layers).items())
         self._decode_step_count = 0
         # the decode step dispatched and not yet read (``_dispatch`` /
         # ``_collect``), steps dispatched while the one before was unread,
@@ -572,7 +587,10 @@ class LLMEngine:
         # them beside the tokens and the loop adds them up when it reads the
         # step's tokens. For any other config the programs drop them
         self._moe_counted = cfg.dropless
-        self._moe_expert_assignments = np.zeros(max(cfg.num_experts, 1), np.int64)
+        # (a config that holds a share of its experts counts those it holds,
+        # and beside them the pairs its routers chose over all the experts)
+        self._moe_expert_assignments = np.zeros(max(cfg.experts_here, 1), np.int64)
+        self._moe_routed = 0
         self._moe_experts_hit = 0
         self._moe_experts_hit_decode = 0
         # disaggregated serving: staged exports parked by migration id
@@ -589,8 +607,13 @@ class LLMEngine:
         if self._hybrid:
             metric_defs.LLM_STATE_SNAPSHOT_POOL_SIZE.set(self._n_snapshots, self._depth_tags)
             metric_defs.LLM_STATE_SNAPSHOTS_IN_USE.set(0, self._depth_tags)
+        if cfg.experts_held is not None:
+            metric_defs.LLM_MOE_EXPERTS_HELD.set(cfg.experts_here, self._depth_tags)
 
         self._reset_cache()
+        if cfg.latent_layers:
+            metric_defs.LLM_LATENT_LAYERS.set(cfg.latent_layers, self._depth_tags)
+            metric_defs.LLM_KV_BYTES_PER_TOKEN.set(self._kv_bytes_per_token, self._depth_tags)
         self._key = jax.random.key(np.random.randint(0, 2**31 - 1))
 
         cfg_ = cfg
@@ -854,6 +877,10 @@ class LLMEngine:
                 self.num_shed += 1
             admission.record_shed("engine", "deadline_expired")
             raise DeadlineExceededError("llm_request", "engine_admission", 0.0)
+        # a long prompt's chain is a millisecond or two of hashing: here, on
+        # the caller's thread, not at admission between two decode steps
+        bs = self.kv_block_size
+        block_keys = tuple(chain_keys(prompt, len(prompt) // bs, bs)) if self._prefix is not None else ()
         with self._lock:
             depth = len(self._queue)
             if self._max_queued and depth >= self._max_queued:
@@ -884,6 +911,7 @@ class LLMEngine:
                 deadline_ts=deadline_ts, trace=trace,
             )
             req.denoising_steps = int(denoising_steps or 0)
+            req.block_keys = block_keys
             req.export_mig_id = _export_mig_id
             req.import_ticket = _import_ticket
             req.import_arrays = _import_arrays
@@ -1132,7 +1160,16 @@ class LLMEngine:
             "state_zeroed": self._state_zeroed,
             "prefix_tokens_matched": self._prefix_tokens_matched,
             "state_bytes_per_slot": self._state_bytes_per_slot,
+            **self._latent_stats(),
         }
+
+    def _latent_stats(self) -> Dict[str, Any]:
+        """A config with latent layers: how many, and the bytes a cached token
+        takes in all the pools as they were built (every attention layer, the
+        pad lanes included)."""
+        if not self.cfg.latent_layers:
+            return {}
+        return {"latent_layers": self.cfg.latent_layers, "kv_bytes_per_token": self._kv_bytes_per_token}
 
     def _block_stats_locked(self) -> Dict[str, Any]:
         """A diffusion config's own counters (absent otherwise): block steps
@@ -1155,16 +1192,26 @@ class LLMEngine:
         """The expert layers' running totals (absent for a config without
         dropless expert layers): (token, choice) pairs routed, per expert and
         in all; (layer, expert) pairs that got at least one token, over all
-        program runs and over the decode steps alone."""
+        program runs and over the decode steps alone. A config that holds a
+        share of its experts: ``moe_experts_held``, ``moe_assignments`` the
+        pairs routed over all the experts and ``moe_assignments_local`` those
+        that landed on the experts held."""
         if not self._moe_counted:
             return {}
-        return {
-            "moe_assignments": int(self._moe_expert_assignments.sum()),
+        local = int(self._moe_expert_assignments.sum())
+        out = {
+            "moe_assignments": local,
             "moe_expert_assignments": self._moe_expert_assignments.tolist(),
             "moe_experts_hit": self._moe_experts_hit,
             "moe_experts_hit_decode": self._moe_experts_hit_decode,
             "moe_expert_layers": self.cfg.expert_layers,
         }
+        if self.cfg.experts_held is not None:
+            # a share: the per-expert and hit counters are over the experts
+            # held; routed counts every choice, those that land elsewhere too
+            out.update(moe_assignments=self._moe_routed, moe_assignments_local=local,
+                       moe_experts_held=self.cfg.experts_here)
+        return out
 
     def lowered_decode_text(self) -> str:
         """StableHLO text of the decode program as the loop runs it (same
@@ -1244,6 +1291,11 @@ class LLMEngine:
         if self._hybrid:
             metric_defs.LLM_STATE_SNAPSHOT_POOL_SIZE.set(0, self._depth_tags)
             metric_defs.LLM_STATE_SNAPSHOTS_IN_USE.set(0, self._depth_tags)
+        if self.cfg.latent_layers:
+            metric_defs.LLM_LATENT_LAYERS.set(0, self._depth_tags)
+            metric_defs.LLM_KV_BYTES_PER_TOKEN.set(0, self._depth_tags)
+        if self.cfg.experts_held is not None:
+            metric_defs.LLM_MOE_EXPERTS_HELD.set(0, self._depth_tags)
         with self._lock:
             pending = [r for r in self._queue.items() if not r.future.done()]
             pending += [r for r in self._slots if r is not None and not r.future.done()]
@@ -1526,15 +1578,16 @@ class LLMEngine:
                 matched = 0
                 snapshot = -1
                 if self._prefix is not None and not self._hybrid:
-                    pages, matched = self._prefix.match(req.prompt)
+                    pages, matched = self._prefix.match(req.prompt, req.block_keys)
                 elif self._prefix is not None:
                     # the state after the matched pages has to exist too: skip
                     # as far as the deepest matched node with a snapshot, short
                     # of the last token (its logits seed sampling, and a state
                     # cannot be stepped back), and share no page beyond it
-                    pages, offered, snapshot, matched = self._prefix.match_snapshot(req.prompt, tp - 1)
+                    pages, offered, snapshot, matched = self._prefix.match_snapshot(req.prompt, tp - 1, req.block_keys)
                     self._prefix_tokens_matched += min(offered, (tp - 1) // bs * bs)
                     pages = pages[: matched // bs]
+                    req.branch_at = self._branch_snapshot_at(req, offered, matched)
                 cow_src = -1
                 if matched == tp and self._bk == 1:
                     # full-prompt hit: the tail block must be writable (a
@@ -1568,6 +1621,8 @@ class LLMEngine:
                         metric_defs.LLM_PREFIX_EVICTIONS.inc(evicted_n)
                     return
                 blocks = pages + self._allocator.alloc(needed)
+                if snapshot >= 0:
+                    self._prefix.restored(snapshot)
                 slot = free[0]
                 self._reserved[slot] = True
                 self._slot_blocks[slot] = blocks
@@ -1670,6 +1725,32 @@ class LLMEngine:
             return 0
         later = req.eos_id is None and tp + req.max_tokens - 2 >= whole + bs - 1
         return 0 if later else whole
+
+    def _branch_snapshot_at(self, req: GenRequest, offered: int, matched: int) -> int:
+        """Tokens of ``req``'s prompt after which its prefill leaves a
+        snapshot on the cached node that ends them, 0 for none. Pages are
+        cached ``offered`` tokens deep and the state only ``matched``: the
+        request prefills what lies between again, over tokens other requests
+        share, and so will every request after it (a document whose first
+        reader's prompt ran on past it never had a snapshot at its end; one
+        whose snapshot aged out of a full pool never gets another from a
+        prompt's end). Where those tokens are two chunks or more and part
+        from another request's at a node (``PrefixCache.branch_point``), the
+        chunk is cut there and the state kept. Caller holds the lock."""
+        gap = 2 * (self.prefill_chunk_tokens or self.S)
+        if not self._n_snapshots or offered - matched < gap:
+            return 0
+        at = self._prefix.branch_point(req.prompt, len(req.prompt) - 1, req.block_keys)
+        return at if at - matched >= gap else 0
+
+    def _snapshot_branch(self, req: GenRequest) -> None:
+        """The chunk just enqueued ends at ``req.branch_at``: the state behind
+        it goes to the cached node there, unless another request got there
+        first (the entry is free again)."""
+        for _, entry, tokens in self._take_snapshots([(req, req.branch_at)]):
+            with self._lock:
+                if not self._prefix.attach_snapshot(req.prompt, tokens, entry, req.block_keys):
+                    self._snap_pool.free(entry)
 
     def _take_snapshots(self, rows: List[Tuple[GenRequest, int]]) -> List[Tuple[GenRequest, int, int]]:
         """Enqueue, behind the program that produced them, the copies of the
@@ -1923,7 +2004,7 @@ class LLMEngine:
                 self._drop_snapshot_locked(req)
                 gauges = self._pool_gauges_locked()
             self._publish_pool_gauges(*gauges)
-        if self._cache["k"].is_deleted():
+        if next(iter(self._cache.values())).is_deleted():
             # a donated chunk or page write consumed the cache then failed: the
             # shared cache is gone, taking every in-flight slot with it
             self._fail_inflight(RuntimeError(f"cache lost in failed prefill: {exc!r}"))
@@ -1973,12 +2054,14 @@ class LLMEngine:
         # A diffusion config committed every token it emitted; what its last
         # block holds past them was dropped, so that block's page is not full
         cached = req.prompt + (req.generated if self._bk > 1 else req.generated[:-1])
-        adopted, evicted = self._prefix.insert(cached, blocks, self._evictable)
+        bs = self.kv_block_size
+        keys = tuple(chain_keys(cached, len(cached) // bs, bs, req.block_keys))  # the reply's blocks behind the prompt's
+        adopted, evicted = self._prefix.insert(cached, blocks, self._evictable, keys)
         if req.snap is not None:
             # the state after exactly ``tokens`` of ``cached`` goes to the node that ends them;
             # where that node is not cached, or has one already, the entry is free again
             entry, tokens = req.snap
-            if tokens <= len(cached) and self._prefix.attach_snapshot(cached, tokens, entry):
+            if tokens <= len(cached) and self._prefix.attach_snapshot(cached, tokens, entry, keys):
                 req.snap = None
             self._drop_snapshot_locked(req)
         self._reclaim_snapshots_locked()
@@ -2065,6 +2148,8 @@ class LLMEngine:
         snap_at = self._snapshot_after_prompt(req) if self._hybrid else 0
         if start < snap_at < start + n:
             n = snap_at - start
+        if start < req.branch_at < start + n:
+            n = req.branch_at - start
         toks = np.zeros((1, width), np.int32)
         toks[0, :n] = req.prompt[start : start + n]
         stalled = bool(self._active.any())
@@ -2085,6 +2170,8 @@ class LLMEngine:
             if snap_at and start + n == snap_at:
                 for taken in self._take_snapshots([(req, snap_at)]):
                     self._keep_snapshot(*taken)
+            elif req.branch_at and start + n == req.branch_at:
+                self._snapshot_branch(req)
         except BaseException as exc:  # noqa: BLE001
             with self._lock:
                 self._prefilling.pop(0)
@@ -2146,6 +2233,11 @@ class LLMEngine:
         hit = int(moe[0]["pairs_hit"])
         self._moe_expert_assignments += counts
         self._moe_experts_hit += hit
+        if "routed" in moe[0]:
+            routed = int(moe[0]["routed"])
+            self._moe_routed += routed
+            metric_defs.LLM_MOE_ASSIGNMENTS_ROUTED.inc(routed)
+            metric_defs.LLM_MOE_ASSIGNMENTS_LOCAL.inc(int(counts.sum()))
         if decode:
             self._moe_experts_hit_decode += hit
 
@@ -2181,8 +2273,8 @@ class LLMEngine:
         bs = self.kv_block_size
         lens = self._pos[self._active].astype(np.int64) + self._bk  # to the end of the step's writes
         last = -(-lens // bs)
-        if self._hybrid:  # the full layers walk every page, the linear layers none
-            return self.cfg.kv_layers * int(last.sum()) / self.cfg.n_layers
+        if self._hybrid:  # the full (or latent) layers walk every page, the linear layers none
+            return self._attn_layers * int(last.sum()) / self.cfg.n_layers
         windows = self.cfg.layer_windows or (0,)
         visited = sum(int((last - np.maximum(lens - w, 0) // bs).sum()) if w else int(last.sum()) for w in windows)
         return visited / len(windows)
@@ -2414,6 +2506,9 @@ class LLMEngine:
             # each device zeroes its own shard: the whole pool never lies on one
             init = jax.jit(init, out_shardings=self._kv_sharding)
         self._cache = init()
+        # bytes a cached token takes in the pools as built, all attention layers
+        pools = [self._cache[name] for name in page_pools(self._cache)]
+        self._kv_bytes_per_token = int(sum(a.shape[0] * a.shape[-1] * a.dtype.itemsize for a in pools))
         # the rows' last tokens as the decode program last returned them:
         # part of the same device state (a step that failed in flight leaves
         # its outputs poisoned). No row reads it before joining from the host

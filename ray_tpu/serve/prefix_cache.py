@@ -46,8 +46,20 @@ to return to its pool); when the snapshot pool itself is full,
 ``evict_snapshot`` detaches the least recently used one (used: attached, or
 restored from) and the node's page stays. A snapshot on a node with one
 child lies on the only path to the deeper snapshot just attached below it,
-so attaching ages it at once: no request can reach it without passing a
-better one. ``snapshot_at`` looks one up without touching any clock.
+so attaching ages it at once: a turn of one conversation, which no request
+reaches without passing a better one. A node that more than one request
+restored from is a shared prefix (a document, a system prompt) and is not
+aged, however few children it has at the moment. The rule still guesses
+where a shared prefix has had ONE user so far, and at a full pool that
+snapshot goes; ``branch_point`` is how it comes back: the deepest node on a
+prompt's cached path where another request's tokens part from it, which is
+where the engine takes a snapshot when it has to prefill again over pages
+the cache already holds. ``snapshot_at`` looks one up without touching any
+clock.
+
+Every method that walks a prompt's chain takes ``keys``, the chain's first
+digests where the caller has them (``chain_keys``; the engine computes a
+prompt's once, in ``submit``, off its loop).
 
 The cache never touches device memory and never calls the allocator: the
 engine owns the allocator lock and frees/shares pages around these calls.
@@ -62,20 +74,43 @@ import heapq
 from dataclasses import dataclass
 from typing import Callable, Dict, List, Optional, Sequence, Set, Tuple
 
+import numpy as np
+
 # digest of the chain root (depth -1); any constant works, but make it
 # content-distinct from real node keys
 _ROOT = hashlib.blake2b(b"ray_tpu.prefix_cache.root", digest_size=16).digest()
 
 
-def chain_key(parent: bytes, tokens: Sequence[int]) -> bytes:
-    """Running digest of one block's tokens chained onto ``parent``.
+def chain_keys(tokens: Sequence[int], blocks: int, block_size: int,
+               known: Sequence[bytes] = (), parent: bytes = _ROOT):
+    """The running digests of the first ``blocks`` blocks of ``tokens`` in
+    turn, each chained onto the one before (the first onto ``parent``).
     Deterministic across processes (no Python ``hash``); token ids are
     encoded as fixed-width little-endian int64 so there is no ambiguity
-    between e.g. [1, 23] and [12, 3]."""
-    h = hashlib.blake2b(parent, digest_size=16)
-    for t in tokens:
-        h.update(int(t).to_bytes(8, "little", signed=True))
-    return h.digest()
+    between e.g. [1, 23] and [12, 3].
+
+    ONE conversion of the tokens and one update a block: a 16k-token prompt
+    is ~1000 blocks, chained at every match, insert and snapshot, and a call
+    a token stood ~15 ms of host time in front of every admission of such a
+    prompt. ``known``: the chain's first keys where the caller has them (a
+    request carries its prompt's from ``submit`` on): they are yielded as
+    they are and only what lies behind them is converted and hashed."""
+    known = known[:blocks]
+    yield from known
+    if known:
+        parent = known[-1]
+    step = 8 * block_size
+    buf = memoryview(np.asarray(tokens[len(known) * block_size : blocks * block_size], dtype="<i8").tobytes())
+    for i in range(blocks - len(known)):
+        h = hashlib.blake2b(parent, digest_size=16)
+        h.update(buf[i * step : (i + 1) * step])
+        parent = h.digest()
+        yield parent
+
+
+def chain_key(parent: bytes, tokens: Sequence[int]) -> bytes:
+    """One block's key: :func:`chain_keys` of ``tokens`` as a single block on ``parent``."""
+    return next(chain_keys(tokens, 1, len(tokens), parent=parent))
 
 
 @dataclass
@@ -88,6 +123,7 @@ class _Node:
     children: int = 0  # live child count; leaf iff 0
     snapshot: int = -1  # entry of the engine's state-snapshot pool; -1: none
     snap_used: int = 0  # LRU clock of the snapshot (attached, restored from)
+    snap_hits: int = 0  # requests that restored from the snapshot
 
 
 class PrefixCache:
@@ -127,7 +163,7 @@ class PrefixCache:
         node.last_used = self._tick
 
     # -- lookup --------------------------------------------------------------
-    def match(self, tokens: Sequence[int]) -> Tuple[List[int], int]:
+    def match(self, tokens: Sequence[int], keys: Sequence[bytes] = ()) -> Tuple[List[int], int]:
         """Longest cached prefix of ``tokens`` at full-block granularity.
 
         Returns ``(pages, matched_token_count)`` — ``pages[i]`` holds the
@@ -137,15 +173,12 @@ class PrefixCache:
         admits."""
         bs = self.block_size
         pages: List[int] = []
-        parent = _ROOT
-        for i in range(len(tokens) // bs):
-            key = chain_key(parent, tokens[i * bs : (i + 1) * bs])
+        for key in chain_keys(tokens, len(tokens) // bs, bs, keys):
             node = self._nodes.get(key)
             if node is None:
                 break
             self._touch(node)
             pages.append(node.page)
-            parent = key
         return pages, len(pages) * bs
 
     # -- state snapshots -----------------------------------------------------
@@ -153,27 +186,28 @@ class PrefixCache:
     def snapshots(self) -> int:
         return len(self._snap_nodes)
 
-    def _chain(self, tokens: Sequence[int], blocks: int) -> List[_Node]:
+    def _chain(self, tokens: Sequence[int], blocks: int, keys: Sequence[bytes] = ()) -> List[_Node]:
         """The nodes of the first ``blocks`` blocks of ``tokens``, as far as cached."""
         bs = self.block_size
         out: List[_Node] = []
-        parent = _ROOT
-        for i in range(blocks):
-            parent = chain_key(parent, tokens[i * bs : (i + 1) * bs])
-            node = self._nodes.get(parent)
+        for key in chain_keys(tokens, blocks, bs, keys):
+            node = self._nodes.get(key)
             if node is None:
                 break
             out.append(node)
         return out
 
-    def match_snapshot(self, tokens: Sequence[int], limit: int) -> Tuple[List[int], int, int, int]:
+    def match_snapshot(self, tokens: Sequence[int], limit: int,
+                       keys: Sequence[bytes] = ()) -> Tuple[List[int], int, int, int]:
         """:meth:`match`, and the deepest node on the matched path that
         carries a snapshot of at most ``limit`` tokens: ``(pages, matched
         tokens, snapshot, snapshot tokens)``, the last two ``(-1, 0)``
         where no node on the path has one. The snapshot's own LRU clock is
-        bumped: the caller restores from it."""
+        bumped: the caller restores from it, and says so with
+        :meth:`restored` once the request is admitted (a request held for
+        want of pages probes again at every wake)."""
         bs = self.block_size
-        path = self._chain(tokens, len(tokens) // bs)
+        path = self._chain(tokens, len(tokens) // bs, keys)
         best = -1
         for i, node in enumerate(path):
             self._touch(node)
@@ -184,6 +218,30 @@ class PrefixCache:
         self._tick += 1
         path[best].snap_used = self._tick
         return [nd.page for nd in path], len(path) * bs, path[best].snapshot, (best + 1) * bs
+
+    def restored(self, snapshot: int) -> None:
+        """A request was admitted on the copy of ``snapshot``: one more
+        request restored from its node (what keeps a shared prefix's
+        snapshot from ageing, ``attach_snapshot``)."""
+        node = self._snap_nodes.get(snapshot)
+        if node is not None:
+            node.snap_hits += 1
+
+    def branch_point(self, tokens: Sequence[int], limit: int, keys: Sequence[bytes] = ()) -> int:
+        """Tokens down to the deepest node on the cached path of ``tokens``
+        (at most ``limit``) where another request's tokens part from these:
+        a node with a child off this path, which for the last cached node
+        is any child. 0 if there is none. A request that prefills past such
+        a node again (its snapshot went, or none was ever taken there: the
+        first request's prompt ran on beyond it) leaves a snapshot there for
+        the next. No clock moves."""
+        bs = self.block_size
+        path = self._chain(tokens, len(tokens) // bs, keys)
+        for i in range(min(len(path), limit // bs) - 1, -1, -1):
+            on_path = 1 if i + 1 < len(path) else 0  # the child that continues these tokens
+            if path[i].children > on_path:
+                return (i + 1) * bs
+        return 0
 
     def snapshot_at(self, tokens: Sequence[int]) -> Tuple[int, int]:
         """The deepest snapshot on the cached path of ``tokens`` and the
@@ -196,21 +254,23 @@ class PrefixCache:
                 best = (node.snapshot, (i + 1) * bs)
         return best
 
-    def attach_snapshot(self, tokens: Sequence[int], n_tokens: int, snapshot: int) -> bool:
+    def attach_snapshot(self, tokens: Sequence[int], n_tokens: int, snapshot: int,
+                        keys: Sequence[bytes] = ()) -> bool:
         """Give the node that ends the first ``n_tokens`` (whole blocks) of
         ``tokens`` the snapshot ``snapshot``. False (the caller keeps the
         entry, and frees it) if that node is not cached or carries one
-        already. Snapshots above it on single-child nodes age at once."""
+        already. Snapshots above it on single-child nodes that at most one
+        request restored from age at once."""
         bs = self.block_size
         blocks = n_tokens // bs
-        path = self._chain(tokens, blocks) if blocks and n_tokens % bs == 0 else []
+        path = self._chain(tokens, blocks, keys) if blocks and n_tokens % bs == 0 else []
         if len(path) != blocks or not path or path[-1].snapshot >= 0:
             return False
         self._tick += 1
-        path[-1].snapshot, path[-1].snap_used = int(snapshot), self._tick
+        path[-1].snapshot, path[-1].snap_used, path[-1].snap_hits = int(snapshot), self._tick, 0
         self._snap_nodes[int(snapshot)] = path[-1]
         for node in path[:-1]:
-            if node.snapshot >= 0 and node.children == 1:
+            if node.snapshot >= 0 and node.children == 1 and node.snap_hits <= 1:
                 node.snap_used = 0
         return True
 
@@ -241,6 +301,7 @@ class PrefixCache:
         tokens: Sequence[int],
         pages: Sequence[int],
         evictable: Callable[[int], bool],
+        keys: Sequence[bytes] = (),
     ) -> Tuple[Set[int], List[int]]:
         """Adopt the full blocks of ``tokens`` (``pages[i]`` is the caller's
         page for block ``i``) into the cache.
@@ -258,8 +319,7 @@ class PrefixCache:
         parent = _ROOT
         parent_node: Optional[_Node] = None
         protect: Set[bytes] = set()  # the chain being built: never evict it
-        for i in range(min(len(tokens) // bs, len(pages))):
-            key = chain_key(parent, tokens[i * bs : (i + 1) * bs])
+        for i, key in enumerate(chain_keys(tokens, min(len(tokens) // bs, len(pages)), bs, keys)):
             node = self._nodes.get(key)
             if node is None:
                 if self.max_blocks and len(self._nodes) >= self.max_blocks:

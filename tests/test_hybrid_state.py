@@ -308,6 +308,63 @@ def test_a_page_match_deeper_than_its_snapshot_skips_only_to_the_snapshot(params
         eng.shutdown()
 
 
+def test_a_shared_prefix_whose_lone_first_session_aged_its_snapshot_out_of_a_full_pool_gets_it_back(params, cold):
+    """A document is sent once, then ONE session holds two turns on it while
+    the snapshot pool is full: the document's snapshot, above a single child
+    and restored from once, ages and goes. The next reader prefills the
+    document again, cuts a chunk at its end and leaves a snapshot there; the
+    reader after that restores."""
+    eng = engine_of(params, state_snapshots=3)
+    try:
+        doc = prompt_of(96)  # six pages, three chunks
+        eng.generate(doc, max_tokens=1)
+        turn1 = doc + prompt_of(21, 1)
+        r1 = eng.generate(turn1, max_tokens=20)
+        turn2 = turn1 + r1 + prompt_of(9, 2)
+        r2 = eng.generate(turn2, max_tokens=12)
+        assert (r1, r2) == (cold.generate(turn1, max_tokens=20), cold.generate(turn2, max_tokens=12))
+        assert settle(eng)
+        # three entries, all held by nodes: the document's (aged), turn 1's (aged) and turn 2's
+        assert eng._prefix.snapshot_at(doc) == (eng._prefix.snapshot_at(doc)[0], 96) and eng._prefix.snapshots == 3
+        other = prompt_of(40, 7)  # its snapshot needs an entry: the oldest goes, which is the document's
+        eng.generate(other, max_tokens=1)
+        assert settle(eng) and eng._prefix.snapshot_at(doc) == (-1, 0)
+        before = eng.stats()
+        a = doc + prompt_of(30, 3)
+        assert eng.generate(a, max_tokens=20) == cold.generate(a, max_tokens=20)
+        s = eng.stats()
+        assert s["state_restores"] == before["state_restores"] and s["state_zeroed"] - before["state_zeroed"] == 1
+        assert s["prefill_chunks"] - before["prefill_chunks"] == 4  # 32 + 32 + 32 to the document's end, then the rest
+        assert settle(eng) and eng._prefix.snapshot_at(doc)[1] == 96  # left at the branch, while the prompt was prefilled
+        before = eng.stats()
+        b = doc + prompt_of(30, 4)
+        assert eng.generate(b, max_tokens=20) == cold.generate(b, max_tokens=20)
+        s = eng.stats()
+        assert s["state_restores"] - before["state_restores"] == 1
+        assert s["prefix_tokens_reused"] - before["prefix_tokens_reused"] == 96
+        assert s["prefill_chunks"] - before["prefill_chunks"] == 1
+        assert settle(eng)
+    finally:
+        eng.shutdown()
+
+
+def test_a_prefix_whose_first_reader_ran_on_past_it_gets_a_snapshot_from_the_second(params, cold):
+    """Nobody sent the document alone: the first request is document + question, so its snapshots lie
+    past the document's end. The second reader finds pages and no state, and leaves one at the branch."""
+    eng = engine_of(params)
+    try:
+        doc = prompt_of(96)
+        for seed, restores in ((1, 0), (2, 0), (3, 1)):
+            q = doc + prompt_of(25, seed)
+            before = eng.stats()["state_restores"]
+            assert eng.generate(q, max_tokens=8) == cold.generate(q, max_tokens=8)
+            assert eng.stats()["state_restores"] - before == restores
+            assert settle(eng)
+        assert eng._prefix.snapshot_at(doc)[1] == 96
+    finally:
+        eng.shutdown()
+
+
 def test_a_slot_reused_after_a_foreign_occupant_gives_the_fresh_result(params, cold):
     eng = engine_of(params, max_batch_size=1, prefix_cache=False)
     try:
@@ -606,6 +663,39 @@ def test_a_snapshot_goes_with_its_node_and_the_least_recently_used_goes_when_the
     assert cache.evict(1, lambda p: True) == [4] and cache.take_freed_snapshots() == [3]          # a's leaf, and its snapshot
     cache.drain()
     assert cache.take_freed_snapshots() == [4] and cache.snapshots == 0 and cache.take_freed_snapshots() == []
+
+
+def test_a_snapshot_more_than_one_request_restored_from_does_not_age_and_keys_stand_for_tokens():
+    from ray_tpu.serve.prefix_cache import chain_keys
+
+    cache = PrefixCache(4)
+    doc, s1 = list(range(8)), list(range(8)) + [50] * 8
+    keys = tuple(chain_keys(s1, 4, 4))
+    cache.insert(s1, [1, 2, 3, 4], lambda p: True, keys)
+    assert cache.attach_snapshot(doc, 8, 0, keys) and cache.attach_snapshot(s1, 12, 1, keys)  # 0 above one child: aged
+    assert cache.match_snapshot(s1, 15, keys) == ([1, 2, 3, 4], 16, 1, 12)
+    assert cache.match_snapshot(doc + [60] * 8, 15)[2:] == (0, 8)
+    cache.restored(0)
+    cache.restored(0)                                        # two sessions were admitted on the document's
+    assert cache.attach_snapshot(s1, 16, 2, keys)            # 1 ages (one child, never restored from); 0 does not
+    assert [cache.evict_snapshot() for _ in range(3)] == [1, 0, 2]
+    assert cache.attach_snapshot(doc, 8, 0)
+    cache.restored(0)
+    assert cache.attach_snapshot(s1, 16, 2)                  # one restore is a conversation's next turn: 0 ages
+    assert cache.evict_snapshot() == 0
+
+
+def test_the_branch_point_is_the_deepest_node_where_another_requests_tokens_part():
+    cache = PrefixCache(4)
+    doc = list(range(16))
+    s1, s2 = doc + [50] * 8, doc + [60] * 8
+    cache.insert(s1, list(range(1, 7)), lambda p: True)
+    assert cache.branch_point(s2, 23) == 16                  # the document's last node has a child that is not s2's
+    assert cache.branch_point(s1, 23) == 0                   # s1's own chain: every child is on its path
+    assert cache.branch_point(s1[:20] + [9] * 4, 23) == 20   # parts from s1 inside its question
+    assert cache.branch_point(s2, 15) == 0                   # the limit: short of the prompt's last token
+    cache.insert(s2, [1, 2, 3, 4, 7, 8], lambda p: True)
+    assert cache.branch_point(s1, 23) == 16 and cache.branch_point([7] * 8, 7) == 0
 
 
 def test_attaching_below_ages_the_snapshots_above_on_single_child_nodes():

@@ -21,6 +21,7 @@ Four contracts:
 
 import copy
 import functools
+import hashlib
 import random
 import threading
 import time
@@ -90,6 +91,57 @@ def test_chain_key_stable_and_unambiguous():
     assert chain_key(k1, [3, 4]) != chain_key(_ROOT, [3, 4])
     # negative token ids encode without error
     assert chain_key(_ROOT, [-1]) != chain_key(_ROOT, [1])
+    # the digest is the one of a token's eight little-endian bytes after another (what other processes computed)
+    h = hashlib.blake2b(_ROOT, digest_size=16)
+    for t in (7, -8, 2**40):
+        h.update(t.to_bytes(8, "little", signed=True))
+    assert chain_key(_ROOT, [7, -8, 2**40]) == h.digest()
+
+
+@pytest.mark.parametrize("as_array", [False, True])
+@pytest.mark.parametrize("blocks", [0, 1, 5, 64])
+def test_chain_keys_of_a_whole_prompt_are_the_keys_block_by_block(blocks, as_array):
+    from ray_tpu.serve.prefix_cache import chain_keys
+
+    tokens = np.random.default_rng(blocks).integers(-5, 200_000, size=64 * 16 + 7)
+    want, parent = [], _ROOT
+    for i in range(blocks):
+        parent = chain_key(parent, tokens[i * 16 : (i + 1) * 16].tolist())
+        want.append(parent)
+    assert list(chain_keys(tokens if as_array else tokens.tolist(), blocks, 16)) == want
+
+
+@pytest.mark.parametrize("known", [0, 1, 5, 9, 12])
+def test_chain_keys_resume_behind_the_keys_the_caller_has(known):
+    from ray_tpu.serve.prefix_cache import chain_keys
+
+    tokens = np.random.default_rng(3).integers(0, 200_000, size=9 * 16 + 5).tolist()
+    want = list(chain_keys(tokens, 9, 16))
+    have = tuple(want) + (b"x" * 16,) * 3  # keys past ``blocks`` are not the caller's to give: never yielded
+    assert list(chain_keys(tokens, 9, 16, have[:known])) == want
+    assert list(chain_keys(tokens + [7] * 40, 11, 16, want[:known]))[:9] == want  # a reply's blocks behind the prompt's
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_the_allocators_shared_count_is_the_recount_after_any_shares_and_frees(seed):
+    rng = random.Random(seed)
+    a = BlockAllocator(40)
+    held = []  # one entry a reference
+    for _ in range(400):
+        roll = rng.random()
+        if roll < 0.3 and a.free_blocks >= 3:
+            held += a.alloc(3)
+        elif roll < 0.6 and held:
+            pick = rng.sample(held, min(len(held), 4))
+            a.share(list(dict.fromkeys(pick)))
+            held += list(dict.fromkeys(pick))
+        elif held:
+            for b in rng.sample(held, min(len(held), 5)):
+                held.remove(b)
+                a.free([b])
+        assert a.shared_blocks == sum(1 for b in set(held) if held.count(b) > 1)
+    a.free(held)
+    assert a.shared_blocks == 0 and a.free_blocks == a.capacity
 
 
 # --------------------------------------------------------------------------

@@ -489,6 +489,68 @@ def test_the_olmo_hybrid_cells_decode_step_and_prefill_chunk_compile_for_v5e_and
     print("olmo hybrid sizing:", report, "snapshots_gb", round(nbytes(snaps) / 1e9, 2), "cache_gb", round(nbytes(cache) / 1e9, 2))
 
 
+def test_the_kimi_linear_cells_decode_step_and_prefill_chunk_compile_for_v5e_and_fit(as_chip, v5e):
+    """The benchmark's Kimi Linear configuration as its file states it (8
+    layers at published widths: 6 KDA, 2 latent attention; layer 1's FFN
+    dense, seven expert layers holding 64 of 256 experts; 64 slots, 32 768
+    pages of ONE latent pool, 192 state snapshots): a decode step of 64 rows
+    and a prefill chunk of 512 tokens compile for a v5e with the three Mosaic
+    kernels by name (the latent page walk, the state update, the grouped
+    products), update the donated pool, states and tails in place, build no K
+    and no V pool, and fit the chip's 16 GB beside the snapshot pool. The
+    memory analysis is what the configuration file's ``sizing`` quotes."""
+    from benchmark import system
+    from ray_tpu.models import init_params
+    from ray_tpu.models.generation import init_paged_cache, init_sequence_state, paged_forward_counted
+
+    config = system.load_json("benchmark/configs/kimi-linear-48b-a3b-serve-l8.json")
+    run = config["run"]
+    cfg = system.model_module(config).program_config(
+        config, max_seq_len=run["max_seq_len"], dtype=run["dtype"], param_dtype=run["param_dtype"])
+    one = SingleDeviceSharding(v5e[0])
+    B, bs, C = run["max_batch_size"], run["kv_block_size"], run["prefill_chunk_tokens"]
+    M = run["max_seq_len"] // bs
+    params = _abstract_tree(lambda: init_params(cfg, jax.random.key(0)), one)
+    cache = _abstract_tree(lambda: init_paged_cache(cfg, run["kv_num_blocks"], bs, slots=B), one)
+    snaps = _abstract_tree(lambda: init_sequence_state(cfg, run["state_snapshots"]), one)
+    assert set(cache) == {"latent", "state", "conv"} and cache["latent"].shape == (2, 32768, 16, 640)
+    assert cache["state"].shape == (6, 64, 32, 128, 128) and cache["conv"].shape == (6, 64, 3 * 12288)
+    assert params["expert_ffn"]["we1"].shape == (7, 64, 2304, 1024) and params["expert_ffn"]["router"].shape == (7, 2304, 256)
+    toks, pos, bt, chunk, row, slot, scalar = _abstract(
+        [((B,), I32), ((B,), I32), ((B, M), I32), ((1, C), I32), ((1, M), I32), ((1,), I32), ((), I32)], one)
+    nbytes = lambda tree: sum(int(np.prod(x.shape)) * x.dtype.itemsize for x in jax.tree.leaves(tree))  # noqa: E731
+    assert sum(int(np.prod(x.shape)) for x in jax.tree.leaves(params)) == system.model_module(config).n_params(config)
+
+    def step(params, cache, toks, pos, bt):
+        live = (bt[:, 0] > 0)[:, None]
+        logits, cache, moe = paged_forward_counted(cfg, params, cache, bt, toks[:, None], pos[:, None], valid=live,
+                                                   slots=jnp.arange(B, dtype=I32))
+        return jnp.argmax(logits[:, 0], -1), cache, moe
+
+    def prefill(params, cache, toks, bt, start, length, slot):
+        valid = (jnp.arange(C) < length)[None, :]
+        logits, cache, moe = paged_forward_counted(cfg, params, cache, bt, toks, start + jnp.arange(C)[None, :],
+                                                   valid=valid, slots=slot)
+        return jax.lax.dynamic_index_in_dim(logits[0], length - 1, 0, keepdims=False), cache, moe
+
+    report = {}
+    for name, fn, args, kernels in (
+            ("decode", step, (params, cache, toks, pos, bt), ("latent_paged_decode", "gated_delta_decode")),
+            ("prefill_chunk", prefill, (params, cache, chunk, row, scalar, scalar, slot), ("latent_paged_prefill",))):
+        lowered = jax.jit(fn, donate_argnums=(1,)).trace(*args).lower(lowering_platforms=("tpu",))
+        text = lowered.as_text()
+        for kernel in kernels:
+            assert kernel in text, (name, kernel)
+        assert "paged_decode\"" not in text.replace("latent_paged_decode", "")  # no K/V walk: there is no such pool
+        m = lowered.compile().memory_analysis()
+        assert m.alias_size_in_bytes >= nbytes(cache)  # the pool, the states and the tails are updated where they lie
+        need = m.argument_size_in_bytes + m.temp_size_in_bytes + m.output_size_in_bytes - m.alias_size_in_bytes
+        report[name] = {"arguments_gb": round(m.argument_size_in_bytes / 1e9, 2), "temporaries_gb": round(m.temp_size_in_bytes / 1e9, 2)}
+        assert need + nbytes(snaps) < 15.5e9, (name, report)
+    print("kimi linear sizing:", report, "params_gb", round(nbytes(params) / 1e9, 2), "snapshots_gb", round(nbytes(snaps) / 1e9, 2),
+          "cache_gb", round(nbytes(cache) / 1e9, 2), "latent_pool_gb", round(nbytes(cache["latent"]) / 1e9, 2))
+
+
 def _cell_programs(name):
     """A serve cell's decode (or block) step and prefill chunk at the
     benchmark's own configuration, abstract arguments and all, not placed."""
