@@ -33,11 +33,16 @@ def unpatchify(patches: jax.Array, image_size: int, patch_size: int, channels: i
 class JittedStep:
     """Callable train step carrying its batch-placement helper (jit wrappers
     don't accept attribute assignment). Shared by the decoder and ViT train
-    steps so sharding/donation fixes land in one place."""
+    steps so sharding/donation fixes land in one place.
+
+    ``ring_layout``: how the step's newest trace placed the sequence for
+    ``attention="ring"`` (``"zigzag"`` or ``"contiguous"``,
+    ``parallel/ring.py``); None before a trace and without a ring."""
 
     def __init__(self, fn, shard_batch):
         self._fn = fn
         self.shard_batch = shard_batch
+        self.ring_layout = None
 
     def __call__(self, *args):
         return self._fn(*args)

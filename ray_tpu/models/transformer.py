@@ -42,7 +42,10 @@ nets are the closest analog, ``rllib/core/rl_module/rl_module.py``):
   chip (no mesh); XLA einsum attention under any mesh; or
   ``attention="ring"`` — sequence-parallel ring attention
   (``ray_tpu.parallel.ring``: ppermute K/V rotation + per-step flash
-  kernel) sharded over (dp, tp, sp), the long-context mode.
+  kernel) sharded over (dp, tp, sp), the long-context mode. The ring's
+  causal work is balanced by placing the sequence zigzag over ``sp``
+  (:func:`ring_placement`: ids, targets and mask gathered once a step,
+  RoPE given the permutation as positions).
 
 Mesh axes: ``dp`` (batch), ``sp`` (sequence), ``tp`` (hidden/heads),
 ``ep`` (experts; may be folded into ``dp`` on small meshes).
@@ -57,6 +60,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ray_tpu.ops import backend
@@ -767,7 +771,10 @@ def _attention(cfg: TransformerConfig, q, k, v, use_flash: bool, mesh=None, sp_a
         pad = (-T) % n_sp
         if pad:
             # tail-pad to an even sp split; causal masking keeps padded
-            # KEYS invisible to real queries, padded QUERY rows are sliced
+            # KEYS invisible to real queries, padded QUERY rows are sliced.
+            # The tokens lie contiguous (ring_placement), which the ring
+            # reads off a shard of odd length: pad to one
+            pad += n_sp * ((T + pad) % (2 * n_sp) == 0)
             widths = ((0, 0), (0, 0), (0, pad), (0, 0))
             qt, kt, vt = (jnp.pad(x, widths) for x in (qt, kt, vt))
         axes = set(mesh.axis_names)
@@ -1271,6 +1278,19 @@ def unembed(cfg: TransformerConfig, params, x):
     return jnp.einsum("btd,vd->btv", x, table.astype(x.dtype)).astype(jnp.float32)
 
 
+def ring_placement(cfg: TransformerConfig, mesh, sp_axis, T: int):
+    """Where ``attention="ring"`` runs over ``sp_axis`` and a ``T``-long
+    sequence cuts into two pieces a rank, the ring's zigzag placement
+    (``parallel/ring.py::ring_order``, a permutation of ``arange(T)``); else
+    None: the tokens stay as they lie."""
+    if cfg.attention != "ring" or mesh is None or sp_axis is None:
+        return None
+    from ray_tpu.parallel.ring import ring_layout, ring_order
+
+    n = mesh.shape[sp_axis]
+    return ring_order(T, n) if ring_layout(T, n, causal=True) == "zigzag" else None
+
+
 def forward(
     cfg: TransformerConfig,
     params: Dict[str, Any],
@@ -1279,8 +1299,18 @@ def forward(
     act_spec: Optional[P] = None,
     mesh: Optional[Mesh] = None,
     sp_axis: Optional[str] = None,
+    positions=None,
 ) -> jax.Array:
-    """Returns logits [B, T, V]."""
+    """Returns logits [B, T, V].
+
+    ``positions`` ([T] ints; default ``arange(T)``) are the sequence
+    positions of ``tokens`` as they lie; the logits lie the same way. The
+    ring wants its own placement (:func:`ring_placement`): ``loss_fn`` places
+    the ids, targets and mask once and passes the placement here. Called
+    without ``positions``, ``forward`` takes tokens and returns logits in
+    natural order whatever the attention: under a zigzag ring it places the
+    ids itself and restores the order on the final hidden states (one
+    gather a call, before the head)."""
     use_flash = cfg.attention == "flash" or (
         cfg.attention == "auto" and backend.on_tpu() and act_spec is None
     )
@@ -1289,8 +1319,13 @@ def forward(
             raise ValueError('block_length > 1 has no attention="flash": the flash kernel is causal')
         use_flash = False  # the grouped einsum carries the block-causal mask
     B, T = tokens.shape
+    restore = None
+    if positions is None:
+        positions = ring_placement(cfg, mesh, sp_axis, T)
+        if positions is not None:
+            tokens, restore = tokens[:, positions], np.argsort(positions)
     x = embed_tokens(cfg, params, tokens)
-    positions = jnp.broadcast_to(jnp.arange(T)[None, :], (B, T))
+    positions = jnp.broadcast_to((jnp.arange(T) if positions is None else jnp.asarray(positions))[None, :], (B, T))
 
     if cfg.hybrid:
         if act_spec is not None:
@@ -1328,6 +1363,8 @@ def forward(
             kind = None if cfg.layer_types is None else {
                 "window": cfg.layer_windows[i], "rope": cfg.layer_rope[i]}
             x, _ = step(x, (layer_i, kind, i - first))
+    if restore is not None:
+        x = x[:, restore]
     return unembed(cfg, params, x)
 
 
@@ -1354,11 +1391,20 @@ def loss_fn(cfg: TransformerConfig, params, tokens, *, act_spec=None, mesh=None,
     from ray_tpu.parallel._compat import spmd_roll
 
     B, T = tokens.shape
-    logits = forward(cfg, params, tokens, act_spec=act_spec, mesh=mesh, sp_axis=sp_axis)
+    # the ring's placement, once a step and on integers: ids, targets and
+    # mask are gathered; every layer but attention is position-wise and the
+    # loss is a mean, so nothing wide is ever re-laid
+    order = ring_placement(cfg, mesh, sp_axis, T)
+    placed = tokens if order is None else tokens[:, order]
+    logits = forward(cfg, params, placed, act_spec=act_spec, mesh=mesh, sp_axis=sp_axis, positions=order)
     targets = spmd_roll(tokens, -1, axis=1)  # [:, T-1] rolls around: masked
+    if order is not None:
+        targets = targets[:, order]
     logp = jax.nn.log_softmax(logits, axis=-1)
     nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
     mask = (jnp.arange(T) < T - 1).astype(nll.dtype)[None, :]
+    if order is not None:
+        mask = mask[:, order]
     return jnp.sum(nll * mask) / (B * (T - 1))
 
 
@@ -1416,6 +1462,10 @@ def make_train_step(
             ring_mesh = mesh
 
     def train_step(state, tokens):
+        if ring_mesh is not None:  # while tracing: the placement is a fact of the compiled step
+            from ray_tpu.parallel.ring import ring_layout
+
+            step.ring_layout = ring_layout(tokens.shape[1], mesh.shape[sp_ax], causal=True)
         loss, grads = jax.value_and_grad(
             lambda p: loss_fn(cfg, p, tokens, act_spec=act_spec, mesh=ring_mesh, sp_axis=sp_ax)
         )(state["params"])
@@ -1441,4 +1491,5 @@ def make_train_step(
 
     from ray_tpu.models.common import JittedStep
 
-    return sharded_init, JittedStep(jax.jit(train_step, donate_argnums=(0,)), shard_batch)
+    step = JittedStep(jax.jit(train_step, donate_argnums=(0,)), shard_batch)
+    return sharded_init, step

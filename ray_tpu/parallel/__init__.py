@@ -35,6 +35,8 @@ from ray_tpu.parallel.pipeline import pipeline_apply, pipeline_sharded
 from ray_tpu.parallel.ring import (
     ring_attention,
     ring_attention_sharded,
+    ring_layout,
+    ring_order,
     ulysses_attention,
     ulysses_attention_sharded,
 )
@@ -45,6 +47,6 @@ __all__ = [
     "all_to_all", "barrier", "broadcast", "init_collective_group",
     "distributed_initialize", "multihost_mesh", "rendezvous_via_cluster",
     "ppermute", "reducescatter", "send_recv", "pipeline_apply",
-    "pipeline_sharded", "ring_attention", "ring_attention_sharded",
+    "pipeline_sharded", "ring_attention", "ring_attention_sharded", "ring_layout", "ring_order",
     "ulysses_attention", "ulysses_attention_sharded",
 ]

@@ -7,7 +7,14 @@ collectives).  Two strategies over a mesh axis holding sequence shards:
 * **Ring attention** (Liu et al.): K/V blocks rotate around the ICI ring via
   ``ppermute`` while each device accumulates blockwise attention with the
   online-softmax (log-sum-exp) recurrence, so peak memory stays
-  O(T_local^2-free) and the sequence scales with the ring size.
+  O(T_local^2-free) and the sequence scales with the ring size.  Under the
+  causal mask the sequence is placed **zigzag** (:func:`ring_order`): cut into
+  ``2n`` pieces, rank ``i`` holds the early piece ``c_i`` and the late piece
+  ``c_{2n-1-i}``.  On contiguous shards the last rank attends to ``n`` blocks
+  and the first to one, and every ring step ends with the early ranks
+  waiting in a ``collective-permute`` for the late ones; zigzag gives every
+  rank the same tiles at every step.  The caller places the sequence (once,
+  on token ids: ``models/transformer.py``); the ring's block masks follow.
 * **Ulysses**: ``all_to_all`` swaps the sharding between sequence and heads,
   runs dense per-head attention locally, and swaps back — cheaper when
   head_count >= ring size and sequence blocks are small.
@@ -24,6 +31,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
@@ -35,71 +43,96 @@ def _to_varying(x, axis_name: str):
     return lax.pcast(x, (axis_name,), to="varying")
 
 
+def ring_layout(T: int, n: int, causal: bool) -> str:
+    """The placement the ring expects of a ``T``-long sequence over ``n``
+    ranks: ``"zigzag"`` (:func:`ring_order`) when the mask is causal and the
+    sequence cuts into ``2n`` equal pieces, else ``"contiguous"`` (rank ``i``
+    holds block ``i``; a ring without a mask is balanced as it lies).
+    :func:`ring_attention` reads the same rule off its shard: a causal ring
+    over shards of even length is zigzag."""
+    return "zigzag" if causal and T % (2 * n) == 0 else "contiguous"
+
+
+def ring_order(T: int, n: int) -> np.ndarray:
+    """The zigzag placement as a permutation: ``ring_order(T, n)[p]`` is the
+    sequence position held at index ``p`` of the placed sequence, whose
+    contiguous ``n``-way split gives rank ``i`` the pieces ``(c_i, c_{2n-1-i})``
+    of ``2n``. Place with ``x[order]``, restore with ``y[np.argsort(order)]``."""
+    pieces = np.arange(T).reshape(2 * n, T // (2 * n))
+    return np.concatenate([pieces[[i, 2 * n - 1 - i]].reshape(-1) for i in range(n)])
+
+
 def ring_attention(
     q, k, v, axis_name: str, *, causal: bool = True, sm_scale: Optional[float] = None,
     block_q: Optional[int] = None, block_k: Optional[int] = None,
 ):
     """Blockwise ring attention over sequence shards (call inside shard_map).
 
-    q, k, v: [B, H, T_local, D] — the local sequence shard.
-    Returns [B, H, T_local, D] in q.dtype.
+    q, k, v: [B, H, T_local, D] — the local shard of a sequence placed as
+    :func:`ring_layout` says (causal and ``T_local`` even: zigzag).
+    Returns [B, H, T_local, D] in q.dtype, placed like ``q``.
 
     Each ring step runs the Pallas flash kernel on the local Q against the
     currently-held K/V shard (``flash_attention_with_lse``) and merges the
     normalized partial outputs with lse-softmax weights — so per-step
     compute rides the MXU kernel and per-device memory stays linear in the
-    shard length. For a causal mask the shard either attends fully
-    (earlier shard), causally (the diagonal shard), or not at all (later
-    shard) — picked per step with ``lax.switch``.
+    shard length. K/V make ``n - 1`` hops, each issued before the products
+    of the step that does not need it. The first step is the rank's own
+    shard under the causal mask. After it the K/V held come from an earlier
+    or a later rank, picked per step with ``lax.switch``: zigzag, all of Q
+    against their early half or the late half of Q against all of them (the
+    same tiles either way); contiguous, all of them or nothing.
     """
     n = lax.axis_size(axis_name)
-    my_block = lax.axis_index(axis_name)
+    rank = lax.axis_index(axis_name)
     B, H, Tq, D = q.shape
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(D)
+    zigzag = ring_layout(n * Tq, n, causal) == "zigzag"
+    half = Tq // 2
 
     from ray_tpu.ops.attention import flash_attention_with_lse
 
-    o0 = jnp.zeros((B, H, Tq, D), jnp.float32)   # unnormalized accumulator
-    m0 = jnp.full((B, H, Tq), NEG_INF, jnp.float32)  # running max of lse_i
-    w0 = jnp.zeros((B, H, Tq), jnp.float32)      # sum of exp(lse_i - m)
-    o0, m0, w0 = (_to_varying(x, axis_name) for x in (o0, m0, w0))
-
-    def local_full(k_cur, v_cur):
-        out, lse = flash_attention_with_lse(q, k_cur, v_cur, scale, False, block_q, block_k)
+    def flash(q, k, v, masked=False):
+        out, lse = flash_attention_with_lse(q, k, v, scale, masked, block_q, block_k)
         return out.astype(jnp.float32), lse
 
-    def local_diag(k_cur, v_cur):
-        out, lse = flash_attention_with_lse(q, k_cur, v_cur, scale, True, block_q, block_k)
-        return out.astype(jnp.float32), lse
+    def unseen(rows):  # what queries that see none of these keys add to the merge
+        return jnp.zeros((B, H, rows, D), jnp.float32), jnp.full((B, H, rows), NEG_INF, jnp.float32)
 
-    def local_empty(k_cur, v_cur):
-        return jnp.zeros((B, H, Tq, D), jnp.float32), jnp.full((B, H, Tq), NEG_INF, jnp.float32)
+    def from_earlier(k_cur, v_cur):
+        if zigzag:  # their early piece lies before both of ours, their late piece after both
+            return flash(q, k_cur[:, :, :half], v_cur[:, :, :half])
+        return flash(q, k_cur, v_cur)
 
-    def body(step, carry):
-        k_cur, v_cur, o_acc, m_run, w_sum = carry
-        src_block = (my_block - step) % n  # sequence block k_cur holds now
-        if causal:
-            # 0: src < my (full), 1: src == my (diagonal), 2: src > my (skip)
-            idx = jnp.where(src_block == my_block, 1, jnp.where(src_block < my_block, 0, 2))
-            o_i, lse_i = lax.switch(idx, (local_full, local_diag, local_empty), k_cur, v_cur)
+    def from_later(k_cur, v_cur):
+        if zigzag:  # both their pieces lie between ours: the late piece of q sees them whole
+            seen = flash(q[:, :, half:], k_cur, v_cur)
+            return tuple(jnp.concatenate(pair, axis=2) for pair in zip(unseen(half), seen))
+        return unseen(Tq)
+
+    perm = [(i, (i + 1) % n) for i in range(n)]
+    held = (k, v)
+    for step in range(n):
+        # the hop goes out before the products that do not need it; none after the last
+        arriving = tuple(lax.ppermute(x, axis_name, perm) for x in held) if step < n - 1 else None
+        if step == 0:
+            # unnormalized accumulator, running max of the lse, sum of exp(lse - max):
+            # one divide after the loop replaces a full-tensor renormalize per step
+            o_acc, m_run = flash(q, *held, masked=causal)
+            w_sum = jnp.ones_like(m_run)
         else:
-            o_i, lse_i = local_full(k_cur, v_cur)
-        # accumulate UNNORMALIZED against the running max: one divide after
-        # the loop replaces a full-tensor renormalize per step
-        m_new = jnp.maximum(m_run, lse_i)
-        alpha = jnp.exp(m_run - m_new)
-        beta = jnp.exp(lse_i - m_new)
-        o_acc = o_acc * alpha[..., None] + o_i * beta[..., None]
-        w_sum = w_sum * alpha + beta
-        # rotate K/V to the next rank on the ICI ring
-        perm = [(i, (i + 1) % n) for i in range(n)]
-        k_nxt = lax.ppermute(k_cur, axis_name, perm)
-        v_nxt = lax.ppermute(v_cur, axis_name, perm)
-        return k_nxt, v_nxt, o_acc, m_new, w_sum
-
-    _, _, o, _m, w = lax.fori_loop(0, n, body, (k, v, o0, m0, w0))
-    w_safe = jnp.where(w == 0, 1.0, w)
-    return (o / w_safe[..., None]).astype(q.dtype)
+            if causal:  # K/V of rank (rank - step) % n: an earlier rank iff rank >= step
+                o_i, lse_i = lax.switch((rank < step).astype(jnp.int32), (from_earlier, from_later), *held)
+            else:
+                o_i, lse_i = flash(q, *held)
+            m_new = jnp.maximum(m_run, lse_i)
+            alpha = jnp.exp(m_run - m_new)
+            beta = jnp.exp(lse_i - m_new)
+            o_acc = o_acc * alpha[..., None] + o_i * beta[..., None]
+            w_sum = w_sum * alpha + beta
+            m_run = m_new
+        held = arriving
+    return (o_acc / w_sum[..., None]).astype(q.dtype)
 
 
 def ring_attention_sharded(

@@ -171,6 +171,74 @@ def test_ring_attention_mode_matches_dense():
 
 
 # --------------------------------------------------------------------------
+# attention="ring": the sequence's placement (parallel/ring.py)
+# --------------------------------------------------------------------------
+def _ring_tiny(attention):
+    return TransformerConfig(
+        vocab_size=128, d_model=32, n_layers=2, n_heads=2, d_ff=64, max_seq_len=64,
+        attention=attention, remat=False, dtype=jnp.float32)
+
+
+@pytest.mark.parametrize("mesh_shape, T, layout", [
+    ((1, 2, 2), 16, "zigzag"),        # dp1 x sp2 x tp2, as the four-chip training cell
+    ((1, 2, 2), 18, "contiguous"),    # a shard of 9 does not cut in two
+    ((1, 2, 2), 15, "contiguous"),    # nor does one padded for an odd length
+    ((1, 4, 1), 16, "zigzag"),
+    ((1, 4, 1), 20, "contiguous"),
+], ids=lambda x: "x".join(map(str, x)) if isinstance(x, tuple) else str(x))
+def test_ring_mode_equals_dense_in_either_placement(mesh_shape, T, layout):
+    """Loss and gradients of ``attention="ring"`` under a mesh equal the
+    dense path's whichever placement the length gives; the train step names
+    the placement it traced; ``forward`` from outside takes and returns
+    natural order."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    mesh = Mesh(np.array(jax.devices()[:4]).reshape(mesh_shape), ("dp", "sp", "tp"))
+    tokens = jnp.asarray(np.random.default_rng(0).integers(0, 128, (2, T)), jnp.int32)
+    dense, ring = _ring_tiny("dense"), _ring_tiny("ring")
+    params = init_params(dense, jax.random.key(0))
+    act = NamedSharding(mesh, P("dp", "sp", None))
+    want = jax.jit(jax.value_and_grad(lambda p: loss_fn(dense, p, tokens)))(params)
+    got = jax.jit(jax.value_and_grad(lambda p: loss_fn(ring, p, tokens, act_spec=act, mesh=mesh, sp_axis="sp")))(params)
+    assert float(got[0]) == pytest.approx(float(want[0]), rel=1e-5)
+    for a, b in zip(jax.tree.leaves(got[1]), jax.tree.leaves(want[1])):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5)
+    logits = jax.jit(lambda p, t: forward(ring, p, t, act_spec=act, mesh=mesh, sp_axis="sp"))(params, tokens)
+    np.testing.assert_allclose(np.asarray(logits), np.asarray(jax.jit(lambda p, t: forward(dense, p, t))(params, tokens)), atol=2e-4)
+
+    for cfg, traced in ((ring, layout), (dense, None)):
+        init_state, step = make_train_step(cfg, mesh=mesh)
+        assert step.ring_layout is None
+        step.lower(jax.eval_shape(init_state, jax.random.key(0)), jax.ShapeDtypeStruct(tokens.shape, tokens.dtype))
+        assert step.ring_layout == traced
+
+
+def test_without_a_mesh_the_loss_is_the_program_it_was():
+    """The one-chip training path (``smollm2-1.7b-train-l8``) places nothing:
+    with no mesh ``loss_fn`` lowers to the same text as the loss written
+    without the placement (the parent's body), ``attention="ring"`` included."""
+    from ray_tpu.models.transformer import ring_placement
+    from ray_tpu.parallel._compat import spmd_roll
+
+    def natural_loss(cfg, params, tokens):
+        B, T = tokens.shape
+        logits = forward(cfg, params, tokens)
+        targets = spmd_roll(tokens, -1, axis=1)
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
+        mask = (jnp.arange(T) < T - 1).astype(nll.dtype)[None, :]
+        return jnp.sum(nll * mask) / (B * (T - 1))
+
+    tokens = jnp.zeros((2, 16), jnp.int32)
+    for cfg in (TINY, _ring_tiny("ring")):
+        assert ring_placement(cfg, None, None, 16) is None
+        params = init_params(cfg, jax.random.key(0))
+        texts = [jax.jit(jax.value_and_grad(lambda p, f=f: f(cfg, p, tokens))).lower(params).as_text()
+                 for f in (loss_fn, natural_loss)]
+        assert texts[0] == texts[1]
+
+
+# --------------------------------------------------------------------------
 # ViT (image model family)
 # --------------------------------------------------------------------------
 def _vit_tiny():

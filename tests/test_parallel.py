@@ -5,6 +5,8 @@ Ulysses attention numerics vs dense reference, the GPipe pipeline, and the
 Pallas flash-attention kernel (interpret mode on CPU).
 """
 
+import functools
+import math
 import threading
 
 import jax
@@ -19,6 +21,8 @@ from ray_tpu.parallel import (
     collective,
     pipeline_sharded,
     ring_attention_sharded,
+    ring_layout,
+    ring_order,
     shard_array,
     ulysses_attention_sharded,
 )
@@ -143,32 +147,106 @@ def _qkv(B=2, H=8, T=128, D=32, dtype=jnp.float32):
     return tuple(jax.random.normal(k, (B, H, T, D), dtype) for k in keys)
 
 
+def _ring_mesh(n):
+    return MeshManager().create_mesh({"sp": n}, devices=jax.devices()[:n])
+
+
 @pytest.mark.parametrize("causal", [True, False])
-def test_ring_attention_matches_dense(mesh_sp, causal):
-    q, k, v = _qkv()
-    ref = mha(q, k, v, causal=causal)
-    spec = (None, None, "sp", None)
-    qs, ks, vs = (shard_array(x, mesh_sp, *spec) for x in (q, k, v))
-    out = ring_attention_sharded(qs, ks, vs, mesh_sp, "sp", causal=causal)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+@pytest.mark.parametrize("n", [2, 4, 8])
+@pytest.mark.parametrize("layout", ["contiguous", "zigzag"])
+def test_ring_attention_matches_dense(layout, n, causal):
+    """Forward and gradients of the ring, over a sequence placed as
+    ``ring_layout`` says, against ``mha`` on the un-permuted sequence. A
+    causal ring is zigzag where a rank's shard cuts in two (T = 8n) and
+    contiguous where it does not (T = 9n); without a mask the ring takes
+    any placement, zigzag too."""
+    T = (8 if layout == "zigzag" else 9) * n
+    assert ring_layout(T, n, causal) == (layout if causal else "contiguous")
+    order = ring_order(T, n) if layout == "zigzag" else np.arange(T)
+    inverse = np.argsort(order)
+    mesh = _ring_mesh(n)
+    q, k, v = _qkv(B=1, H=2, T=T, D=16)
 
+    def ring(q, k, v):
+        placed = (x[:, :, order] for x in (q, k, v))
+        return ring_attention_sharded(*placed, mesh, "sp", causal=causal)[:, :, inverse]
 
-def test_ring_attention_grads_match_dense(mesh_sp):
-    """The lse-combined ring gradient must match dense attention's."""
-    q, k, v = _qkv()
-    spec = (None, None, "sp", None)
-    qs, ks, vs = (shard_array(x, mesh_sp, *spec) for x in (q, k, v))
+    def out_and_grads(attend):
+        def loss(q, k, v):
+            out = attend(q, k, v)
+            return jnp.sum(out.astype(jnp.float32) ** 2), out
 
-    def loss_ring(q, k, v):
-        return jnp.sum(ring_attention_sharded(q, k, v, mesh_sp, "sp", causal=True).astype(jnp.float32) ** 2)
+        (_, out), grads = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True))(q, k, v)
+        return out, grads
 
-    def loss_ref(q, k, v):
-        return jnp.sum(mha(q, k, v, causal=True).astype(jnp.float32) ** 2)
-
-    g1 = jax.grad(loss_ring, argnums=(0, 1, 2))(qs, ks, vs)
-    g2 = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-    for a, b in zip(g1, g2):
+    got, got_grads = out_and_grads(ring)
+    want, want_grads = out_and_grads(functools.partial(mha, causal=causal))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+    for a, b in zip(got_grads, want_grads):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-4)
+
+
+def _eqns(jaxpr, name):
+    """Every equation of primitive ``name`` in a jaxpr, its sub-jaxprs included."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == name:
+            found.append(eqn)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _eqns(sub, name)
+    return found
+
+
+def _ring_jaxprs(n, T, causal=True, **blocks):
+    """Jaxprs of an n-way ring over T positions: forward, and forward with its pullback."""
+    mesh = _ring_mesh(n)
+    q, k, v = _qkv(B=1, H=2, T=T, D=16)
+
+    def ring(q, k, v):
+        return ring_attention_sharded(q, k, v, mesh, "sp", causal=causal, **blocks)
+
+    def both(q, k, v, ct):
+        return jax.vjp(ring, q, k, v)[1](ct)
+
+    return jax.make_jaxpr(ring)(q, k, v).jaxpr, jax.make_jaxpr(both)(q, k, v, q).jaxpr
+
+
+@pytest.mark.parametrize("n", [2, 4, 8])
+@pytest.mark.parametrize("T_local", [8, 9])
+def test_ring_kv_make_n_minus_1_hops(n, T_local):
+    """K and V each hop n-1 times forward, and dK and dV n-1 times in the
+    gradient: no rotation back to the start, no hop of zero cotangents, and
+    no loop around them (zigzag and contiguous alike)."""
+    forward, with_pullback = _ring_jaxprs(n, T_local * n)
+    assert len(_eqns(forward, "ppermute")) == 2 * (n - 1)
+    assert len(_eqns(with_pullback, "ppermute")) == 2 * (n - 1) + 2 * (n - 1)
+    for jaxpr in (forward, with_pullback):
+        assert not _eqns(jaxpr, "while") and not _eqns(jaxpr, "scan")
+
+
+def _branch_tiles(jaxpr):
+    """Per ``cond`` of a ring's jaxpr that picks between flash calls (the
+    kernels' own ``pl.when`` bodies hold none): the tiles in each branch."""
+    def tiles(branch):
+        return sum(math.prod(e.params["grid_mapping"].grid) for e in _eqns(branch.jaxpr, "pallas_call"))
+
+    per_cond = [[tiles(b) for b in eqn.params["branches"]] for eqn in _eqns(jaxpr, "cond")]
+    return [t for t in per_cond if any(t)]
+
+
+@pytest.mark.parametrize("n", [2, 4])
+def test_zigzag_steps_have_no_empty_branch_and_equal_tiles(n):
+    """Zigzag: at every step after the first, K/V from an earlier rank and
+    from a later one cost the same tiles and neither costs none; contiguous
+    (a shard that does not cut into the blocks' halves): one of them is the
+    empty branch, which is the imbalance."""
+    blocks = dict(block_q=8, block_k=16)
+    zigzag = _branch_tiles(_ring_jaxprs(n, 32 * n, **blocks)[0])
+    assert len(zigzag) == n - 1
+    for earlier, later in zigzag:
+        assert earlier == later == 2 * 4  # heads x (4 x 1 tiles of all of q, or 2 x 2 of its late half)
+    contiguous = _branch_tiles(_ring_jaxprs(n, 33 * n, **blocks)[0])
+    assert len(contiguous) == n - 1 and all(later == 0 < earlier for earlier, later in contiguous)
 
 
 def test_ulysses_matches_dense(mesh_sp):

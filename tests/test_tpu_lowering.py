@@ -662,6 +662,7 @@ def test_train_step_compiles_under_a_four_chip_mesh(as_chip, v5e, attention):
     tokens = jax.ShapeDtypeStruct((2, cfg.max_seq_len), I32, sharding=NamedSharding(mesh, P("dp", None)))
     lowered = train_step.lower(state, tokens)
     assert ("tpu_custom_call" in lowered.as_text()) == (attention == "ring")
+    assert train_step.ring_layout == ("zigzag" if attention == "ring" else None)  # 2048 tokens over sp2 cut in four
     lowered.compile()
 
 
