@@ -625,13 +625,13 @@ def leg_four_chip(sz, on_chip):
         outs = [f.result(timeout=600) for f in futures]
         # the same prompts again: served from the pool's cached pages
         again = [engine.submit(p, max_tokens=s["new"]).result(timeout=600) for p in prompts]
-        emb = engine.params["embed"]
+        emb = engine.runner.params["embed"]
         assert len(emb.sharding.device_set) == 4, f"embed on {len(emb.sharding.device_set)} device(s)"
         stats = engine.stats()
         # after prefills, decode steps and copy-on-write: each device still
         # holds every page, of its own KV heads (all heads where 4 does not
         # divide them)
-        pool = engine._cache["k"]
+        pool = engine.runner.cache["k"]
         shard = tuple(pool.addressable_shards[0].data.shape)
     finally:
         engine.shutdown()

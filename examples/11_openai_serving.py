@@ -18,7 +18,7 @@ import jax.numpy as jnp
 import ray_tpu as rt
 from ray_tpu import serve
 from ray_tpu.models import TransformerConfig, init_params
-from ray_tpu.serve.llm import OpenAICompatLLMServer
+from ray_tpu.serve.openai_compat import OpenAICompatLLMServer
 
 
 class CharTokenizer:

@@ -1224,7 +1224,7 @@ def run_suite(
             d_eng.adopt_migration(
                 warm_ticket, warm_arrays, max_tokens=2
             ).future.result(timeout=300)
-            p_eng.release_migration("bench/warm")
+            p_eng.store.release_migration("bench/warm")
             def _mover(round_tickets):
                 # off the stream-consumer thread: the handoff must not
                 # starve the victim's token reads.  Pulls run sequentially
@@ -1238,7 +1238,7 @@ def run_suite(
                     }
                     adopted.append(
                         d_eng.adopt_migration(ticket, arrays, max_tokens=2))
-                    p_eng.release_migration(ticket["mig_id"])
+                    p_eng.store.release_migration(ticket["mig_id"])
 
             disagg_gaps: list = []
             for r in range(3):
@@ -1292,7 +1292,7 @@ def run_suite(
                 req = d_eng.adopt_migration(ticket, arrays, max_tokens=2)
                 quiet_migs.append(time.perf_counter() - t0)
                 req.future.result(timeout=300)
-                p_eng.release_migration(ticket["mig_id"])
+                p_eng.store.release_migration(ticket["mig_id"])
             chunk_lats = []
             for _ in range(3):
                 t0 = time.perf_counter()
@@ -1378,7 +1378,7 @@ def run_suite(
             warmup = [7] * PREFIX_L
             eng.generate(warmup, max_tokens=2)
             eng.generate(warmup, max_tokens=2)
-            eng.flush_prefix_cache()
+            eng.store.flush_prefix_cache()
 
             def ttft(p):
                 t0 = time.perf_counter()

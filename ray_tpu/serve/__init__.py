@@ -24,7 +24,8 @@ from ray_tpu.serve.api import (
 )
 from ray_tpu.serve.batching import batch
 from ray_tpu.serve.deployment import Application, AutoscalingConfig, Deployment, deployment
-from ray_tpu.serve.llm import LLMEngine, LLMServer, OpenAICompatLLMServer
+from ray_tpu.serve.llm import LLMEngine, LLMServer
+from ray_tpu.serve.openai_compat import OpenAICompatLLMServer
 from ray_tpu.serve.multiplex import get_multiplexed_model_id, multiplexed
 from ray_tpu.serve.replica import ReplicaContext, get_replica_context
 from ray_tpu.serve.router import DeploymentHandle, DeploymentResponse

@@ -2,34 +2,24 @@
 
 The reference's Serve ships no inference engine (its LLM guides delegate to
 vLLM on GPU). On TPU the engine IS the framework's job, and the design is
-dictated by XLA's static-shape compilation model:
+dictated by XLA's static-shape compilation model. The engine is three boxes
+and the arrows point one way: this file is the scheduler; it asks
+``serve/sequence_store.py`` what state a sequence keeps and
+``serve/model_runner.py`` (which the store calls too, and which calls neither)
+for the device state, every program and what a decode step yields.
 
 - **Fixed decode slots.** B = ``max_batch_size`` decode slots; a request
   occupies a slot from admission to completion and every decode step is ONE
   jitted program over all B slots (inactive slots compute masked garbage —
   the static-shape price, paid in exchange for zero recompiles at any
   admission pattern).
-- **Paged KV cache.** K/V live in a shared HBM pool of fixed-size pages
-  ``[L, num_blocks, block_size, Hkv*Dh]``; each slot names its pages in a
-  static-shape ``int32[B, max_blocks_per_slot]`` block table
-  (PagedAttention, Kwon et al. 2023). Admission is block-aware — a request
-  is admitted when enough PAGES are free, so HBM capacity is proportional
-  to tokens actually reserved, not ``B * max_len``. Under a mesh the pool
-  shards over its KV heads (``models/generation.paged_cache_spec``) and the
-  same admission, prefill and decode programs run, partitioned by GSPMD.
+- **Block-aware admission.** A request is admitted when the store can
+  reserve its whole page budget (paged KV, prefix reuse, a config with linear
+  layers' recurrent state and its snapshots: ``serve/sequence_store.py``).
 - **Chunked prefill.** Prompts prefill in fixed-size chunks interleaved
   between decode steps (Sarathi-style bounded per-iteration budget,
   ``prefill_chunk_tokens``; 0 = one-shot with power-of-2 bucketing), so a
   long prompt stalls running decodes by at most one chunk's forward.
-- **Prefix-aware KV reuse (on by default).** Finished
-  requests publish the full blocks of prompt+completion into a radix
-  prefix cache (``serve/prefix_cache.py``); admission matches the longest
-  cached prefix and ``share()``s those pages straight into the new block
-  table, so prefill starts at the first UNCACHED token and reserves pool
-  budget only for the suffix. Pages are refcounted; a write that would
-  land in a shared page goes through copy-on-write; when the pool runs
-  short, unreferenced cached leaves are LRU-evicted before admission holds
-  or sheds (vLLM PagedAttention / SGLang RadixAttention idiom).
 - **Continuous batching.** New requests join between decode steps
   (vLLM-style iteration-level scheduling); finished ones free their slot
   and pages immediately. Per-request ``max_tokens`` and ``temperature``
@@ -40,47 +30,8 @@ dictated by XLA's static-shape compilation model:
   host knows ahead, so everything the host does in an iteration runs beside
   the device. An EOS or a cancel is seen one step late and costs one
   dropped row-step (``docs/tpu_design.md``, "Paged KV + chunked prefill").
-
-- **Generation by diffusion over blocks.** A config with ``block_length`` > 1
-  (SDAR) switches the decode program to block steps: every row carries its
-  block of ``block_length`` positions (mask ids among them), which positions
-  are masked and its step counters as device-resident state, a step is one
-  forward of the block over everything committed plus the block itself and
-  unmasks the most confident positions inside the program, and a row whose
-  block holds no mask runs the commit forward, whose K/V are final, and
-  yields the block's tokens: 0 tokens a row on a denoise step, up to
-  ``block_length`` on a commit, rows of one batch in different phases. The
-  host knows each row's schedule by count, so the one step in flight stays.
-
-- **Recurrent state beside the pages.** A config with "linear" layers
-  (Gated DeltaNet: ``cfg.hybrid``) keeps keys and values in its full layers
-  only; its linear layers carry a recurrent state and a convolution tail a
-  sequence, which live in the cache at the sequence's decode slot. A slot's
-  state is zeroed or restored from a snapshot on the device at admission, in
-  order with the step in flight. A page match alone is no prefix hit there:
-  a request skips prefill only as far as the deepest matched radix node that
-  carries a *state snapshot* (``serve/prefix_cache.py``), an entry of a
-  second device pool (``state_snapshots`` entries, ``serve/kv_blocks.py``
-  ``SnapshotPool``) holding the state after exactly that node's tokens.
-  Snapshots are taken on the device right behind the program that produced
-  the state: after the chunk that ends a prompt's last whole page (when no
-  later one is certain to come) and after a decode step that ends a page
-  (every such step of a row with an EOS to wait for, else the last one of
-  the reply, known by count). One taken with a decode step is tentative
-  until that step's tokens are read and kept: a row-step discarded because
-  an EOS or a cancel was seen a step late has advanced the slot's state,
-  and its snapshot is dropped with it. A finished request's snapshot goes
-  to the radix node of its depth when its pages are published. A request
-  that has to prefill two chunks or more over pages the cache holds (no
-  snapshot was ever taken at the end of what it shares, or that one aged
-  out) cuts a chunk where its tokens part from another request's and
-  leaves a snapshot on that node at once, for the requests after it
-  (``_branch_snapshot_at``). Both pools
-  evict the least recently used, and requests are admitted in order of
-  arrival: a waiting session keeps its pages and its snapshot only while
-  the pools' turnover (the unreferenced pages over the rate new ones are
-  asked for) outlasts its wait; past that every returning turn prefills its
-  history again (``docs/tpu_design.md``, "State snapshots").
+  What a step is — a token a row, or a block step of a config that generates
+  by diffusion over blocks — is the runner's (``runner.steps``).
 
 ``LLMServer`` is the Serve-facing wrapper: a deployment class whose
 replicas each own an engine; requests arrive via handle/HTTP and block on a
@@ -89,10 +40,9 @@ per-request Future.
 
 from __future__ import annotations
 
-import functools
 import threading
 import time
-from collections import Counter, deque
+from collections import deque
 from concurrent.futures import Future
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, NamedTuple, Optional, Sequence, Tuple
@@ -102,35 +52,19 @@ import jax.numpy as jnp
 import numpy as np
 
 from ray_tpu.exceptions import DeadlineExceededError
-from ray_tpu.models.generation import (
-    copy_paged_page,
-    copy_sequence_state,
-    export_paged_page,
-    filter_top_k_top_p,
-    init_paged_cache,
-    init_sequence_state,
-    open_blocks,
-    page_pools,
-    paged_block_step,
-    paged_cache_spec,
-    paged_forward_counted,
-    select_rows,
-    write_paged_pages,
-    zero_sequence_state,
-)
 from ray_tpu.models.transformer import TransformerConfig
 from ray_tpu.observability import metric_defs
 from ray_tpu.observability.sketch import LatencySketch
 from ray_tpu.observability.tracing import LoopClock
-from ray_tpu.ops.gated_delta import lane_group, unpack_state
 from ray_tpu.runtime import admission
 from ray_tpu.runtime.context import (
     current_deadline_ts,
     current_request_trace,
     current_tenant,
 )
-from ray_tpu.serve.kv_blocks import BlockAllocator, SnapshotPool
-from ray_tpu.serve.prefix_cache import PrefixCache, chain_keys
+from ray_tpu.serve.model_runner import ModelRunner
+from ray_tpu.serve.prefix_cache import chain_keys
+from ray_tpu.serve.sequence_store import SequenceStore
 
 _STREAM_END = object()
 
@@ -162,11 +96,65 @@ _DISPATCH_KINDS = ("queued", "dry", "cold")
 _EVICT_DISCONNECT_TAGS = {"reason": "disconnect"}
 _LOOP_PHASE_TAGS = {p: {"phase": p} for p in LOOP_PHASES}
 _DISPATCH_TAGS = {k: {"device": k} for k in _DISPATCH_KINDS}
-_PREFIX_RESULT_TAGS = {
-    "hit": {"result": "hit"},
-    "partial": {"result": "partial"},
-    "miss": {"result": "miss"},
-}
+
+# What combines with what. A row: a property of the model's configuration, the
+# sentence its refusals open with ("" where each is a sentence of its own: the
+# first one met is raised) and, by what a constructor call or a submit asks
+# for, why the two do not go together. A pair without an entry is served.
+_REFUSED = (
+    ("block", "a config with block_length {block} (generation by diffusion over blocks) cannot be served with ", {
+        "decode_chunk": "decode_chunk > 1 (one block step a program)",
+        "quantize": "quantize=True",
+        "mesh": "mesh (the block step runs the single-device paged kernels)",
+        "page": "kv_block_size {kv_block_size} (a page holds whole blocks of {block})",
+        "length": "max_seq_len {max_seq_len} (whole blocks of {block})",
+    }),
+    ("linear", 'a config with "linear" layers (recurrent state a sequence) cannot be served with ', {
+        "decode_chunk": "decode_chunk > 1 (a snapshot is taken behind one step's state)",
+        "quantize": "quantize=True (the int8 scales ride one stack of layers)",
+        "mesh": "mesh (the state and the snapshot pool are not sharded)",
+    }),
+    ("no linear", "", {
+        "state_snapshots": 'state_snapshots={state_snapshots} belongs to a config with "linear" layers; '
+                           "this one keeps no recurrent state",
+    }),
+    ("mesh", "", {
+        "quantize": "quantize=True with mesh is not supported yet",
+        "role": "role={role!r} with mesh is not supported yet: migrated "
+                "blocks are exported from and landed in an unsharded pool",
+        "axis": "mesh has no {tp!r} axis: {axes}",
+    }),
+    # the int8 scales ride ONE stack of layers whose every weight is an xs leaf of the layer scan
+    ("dense_stack or dropless", "", {
+        "quantize": "quantize=True does not cover a config with num_dense_layers > 0 or dropless expert layers "
+                    "(two layer stacks; expert weights read where they lie): serve it unquantized",
+    }),
+    # at submit
+    ("linear", "", {
+        "migration": 'prefill_export / adopt_migration are not supported for a config with "linear" layers: '
+                     "a migrated block set carries pages, not the sequence's recurrent state",
+    }),
+    ("autoregressive", "", {
+        "denoising_steps": "denoising_steps belongs to a config that generates by diffusion over blocks "
+                           "(block_length > 1); this engine's config is autoregressive",
+    }),
+    ("block", "", {
+        "migration": "prefill_export / adopt_migration are not supported for a config that generates "
+                     "by diffusion over blocks: an exported prefill carries no first token",
+        "steps_range": "denoising_steps must be 1 to the config's block_length {block}, got {denoising_steps}",
+    }),
+)
+
+
+def _check_combination(cfg: TransformerConfig, mesh: Any, asked: Dict[str, Any], **named) -> None:
+    """No silent path: raise what ``_REFUSED`` holds against a call that
+    ``asked`` for these (what is asked for by name -> whether it was)."""
+    held = {"block": cfg.block > 1, "autoregressive": cfg.block == 1, "linear": cfg.hybrid, "no linear": not cfg.hybrid,
+            "mesh": mesh is not None, "dense_stack or dropless": bool(cfg.dense_stack or cfg.dropless)}
+    for row, head, cells in _REFUSED:
+        bad = [why.format(block=cfg.block, **named) for what, why in cells.items() if held[row] and asked.get(what)]
+        if bad:
+            raise ValueError(head.format(block=cfg.block) + "; ".join(bad) if head else bad[0])
 
 
 @dataclass
@@ -285,7 +273,7 @@ class _Flight:
     moe: list  # the expert layers' counts, where the program returns them
     rows: List[Tuple[int, GenRequest]]
     # a block step: slot -> known positions of the block this step commits
-    commits: Dict[int, int] = field(default_factory=dict)
+    commits: Optional[Dict[int, int]] = None
     # state snapshots taken right behind this step, tentative until its tokens
     # are read and kept: (request, snapshot pool entry, tokens covered)
     snaps: List[Tuple[GenRequest, int, int]] = field(default_factory=list)
@@ -309,7 +297,7 @@ class LLMEngine:
 
     Thread model: callers enqueue via :meth:`submit` (thread-safe); one
     background loop admits requests and steps the batch. All jitted callables
-    are built once in __init__ so the loop never traces.
+    are built once (``serve/model_runner.py``) so the loop never traces.
 
     The KV pool is sized here and nowhere else. ``kv_block_size``: tokens a
     page (a multiple of the sublane tile, 8 for f32 and 16 for bf16, keeps
@@ -363,27 +351,13 @@ class LLMEngine:
         self.kv_block_size = int(kv_block_size)
         if self.kv_block_size < 1:
             raise ValueError(f"kv_block_size must be >= 1, got {self.kv_block_size}")
-        # static block-table width: enough logical blocks for a max-length
-        # sequence — the table shape never depends on the allocation pattern
-        self.max_blocks_per_slot = -(-self.S // self.kv_block_size)
         nb = int(kv_num_blocks)
         if nb <= 0:
             # auto: every slot can hold a max-length sequence (+1 for the
             # garbage page)
-            nb = self.B * self.max_blocks_per_slot + 1
+            nb = self.B * -(-self.S // self.kv_block_size) + 1
         self.kv_num_blocks = nb
         self.prefill_chunk_tokens = int(prefill_chunk_tokens)
-        self._allocator = BlockAllocator(nb)
-        self._prefix = (
-            PrefixCache(self.kv_block_size, int(prefix_cache_max_blocks))
-            if prefix_cache
-            else None
-        )
-        # prefix-cache outcome counts per admitted request, tokens whose
-        # prefill compute was skipped, and copy-on-write page copies
-        self._prefix_results = {"hit": 0, "partial": 0, "miss": 0}
-        self._prefix_tokens_reused = 0
-        self._cow_count = 0
         # bounded waiting queue (overload survival, ISSUE 9): past the
         # request-count bound, or the prefill-token budget (0 = unbounded),
         # submit() sheds with a typed OverloadedError instead of growing
@@ -399,98 +373,15 @@ class LLMEngine:
         # emission happen at chunk granularity, and a request finishing
         # mid-chunk discards the tail tokens (identical outputs either way)
         self.decode_chunk = max(1, int(decode_chunk))
-        # positions a decode step carries a row: 1, or a diffusion config's block
-        self._bk = cfg.block
-        if self._bk > 1:
-            # no silent path: what block steps cannot honour yet is refused by name
-            refused = {
-                "decode_chunk > 1 (one block step a program)": self.decode_chunk > 1,
-                "quantize=True": bool(quantize),
-                "mesh (the block step runs the single-device paged kernels)": mesh is not None,
-                f"kv_block_size {self.kv_block_size} (a page holds whole blocks of {self._bk})":
-                    self.kv_block_size % self._bk != 0,
-                f"max_seq_len {self.S} (whole blocks of {self._bk})": self.S % self._bk != 0,
-            }
-            bad = [k for k, v in refused.items() if v]
-            if bad:
-                raise ValueError(f"a config with block_length {self._bk} (generation by diffusion over blocks) "
-                                 f"cannot be served with " + "; ".join(bad))
-        # a config with linear layers keeps a recurrent state a sequence at
-        # its slot, and a pool of snapshots of it beside the page pool
-        self._hybrid = cfg.hybrid
-        # layers that walk pages: K and V, or a latent layer's one row a token
-        self._attn_layers = cfg.kv_layers + cfg.latent_layers
-        if self._hybrid:
-            # no silent path: what the slots' state cannot follow yet is refused by name
-            refused = {
-                "decode_chunk > 1 (a snapshot is taken behind one step's state)": self.decode_chunk > 1,
-                "quantize=True (the int8 scales ride one stack of layers)": bool(quantize),
-                "mesh (the state and the snapshot pool are not sharded)": mesh is not None,
-            }
-            bad = [k for k, v in refused.items() if v]
-            if bad:
-                raise ValueError('a config with "linear" layers (recurrent state a sequence) cannot be served '
-                                 "with " + "; ".join(bad))
-        elif state_snapshots:
-            raise ValueError(f"state_snapshots={state_snapshots} belongs to a config with \"linear\" layers; "
-                             "this one keeps no recurrent state")
-        n_snapshots = (2 * self.B if state_snapshots is None else max(0, int(state_snapshots))) if self._hybrid else 0
-        self._n_snapshots = n_snapshots  # the pool's size: fixed, read without the lock
-        self._snap_pool = SnapshotPool(n_snapshots)
-        self._snaps = None  # the device arrays of the pool (``_reset_cache``)
-        self._state_snapshots_taken = 0
-        self._state_restores = 0
-        self._state_zeroed = 0
-        self._prefix_tokens_matched = 0
-        self.top_k = top_k
-        self.top_p = top_p
-        self.quantized = quantize
-        self._kv_sharding = None
-        if mesh is not None:
-            # tensor-parallel serving: params shard per the Megatron layout
-            # (ray_tpu.models.transformer.param_specs), the KV pool over its
-            # heads when tp divides them (each device then holds whole pages
-            # of its own heads); GSPMD partitions the einsum attention, so
-            # decode collectives ride ICI. The Pallas decode kernel is
-            # bypassed (GSPMD cannot partition a Mosaic kernel).
-            from jax.sharding import NamedSharding
-
-            from ray_tpu.models.transformer import _kv_tp_ok, shard_params
-
-            if quantize:
-                raise ValueError("quantize=True with mesh is not supported yet")
-            if self.role:
-                raise ValueError(
-                    f"role={self.role!r} with mesh is not supported yet: migrated "
-                    "blocks are exported from and landed in an unsharded pool"
-                )
-            if tp not in mesh.axis_names:
-                raise ValueError(f"mesh has no {tp!r} axis: {mesh.axis_names}")
-            params = shard_params(params, mesh, cfg, tp=tp, ep=tp)
-            self._kv_sharding = NamedSharding(
-                mesh, paged_cache_spec(tp if _kv_tp_ok(cfg, mesh, tp) else None)
-            )
-        if quantize and (cfg.dense_stack or cfg.dropless):
-            # no silent path: the int8 scales ride ONE stack of layers whose
-            # every weight is an xs leaf of the layer scan
-            raise ValueError(
-                "quantize=True does not cover a config with num_dense_layers > 0 or dropless expert layers "
-                "(two layer stacks; expert weights read where they lie): serve it unquantized"
-            )
-        if quantize:
-            # weight-only int8 on the stacked layer LINEAR weights (norm
-            # gains and the embedding stay full precision). Scales ride the
-            # layer scan as xs, so dequant happens per layer IN the scan
-            # body — only one layer is ever wide, never a whole-tree copy.
-            from ray_tpu.ops.quantization import quantize_layers
-
-            q_layers, self._layer_scales = quantize_layers(
-                params["layers"], min_size=quantize_min_size
-            )
-            self.params = {**params, "layers": q_layers}
-        else:
-            self._layer_scales = None
-            self.params = params
+        _check_combination(
+            cfg, mesh,
+            {"decode_chunk": self.decode_chunk > 1, "quantize": quantize, "mesh": mesh is not None,
+             "page": self.kv_block_size % cfg.block, "length": self.S % cfg.block, "state_snapshots": state_snapshots,
+             "role": self.role, "axis": mesh is not None and tp not in mesh.axis_names},
+            kv_block_size=self.kv_block_size, max_seq_len=self.S, state_snapshots=state_snapshots, role=self.role,
+            tp=tp, axes=getattr(mesh, "axis_names", None))
+        n_snapshots = (2 * self.B if state_snapshots is None else max(0, int(state_snapshots))) if cfg.hybrid else 0
+        self.top_k, self.top_p = top_k, top_p
 
         # tenant-keyed weighted fair queue: pops interleave proportionally
         # to tenant_weights (default weight 1), so one hot tenant saturating
@@ -522,32 +413,28 @@ class LLMEngine:
         # flight recorder's raw material when the loop crashes
         self._finished_ring: deque = deque(maxlen=64)
 
-        # slot state (host-side mirrors of the device arrays)
+        # the device state and every program over it, and what a decode step yields
+        sizes = dict(B=self.B, S=self.S, kv_block_size=self.kv_block_size, kv_num_blocks=nb, n_snapshots=n_snapshots)
+        self.runner = ModelRunner(cfg, params, top_k=top_k, top_p=top_p, quantize=quantize, mesh=mesh, tp=tp,
+                                  quantize_min_size=quantize_min_size, decode_chunk=self.decode_chunk, **sizes)
+        self._steps = self.runner.steps
+        # ``benchmark/tools/state_precision_control.py`` swaps ``_place_state`` on the engine
+        # and, inside it, these two: aliases of the runner's programs (None without linear
+        # layers) until that tool swaps at the runner (ROADMAP D12)
+        self._zero_state = getattr(self.runner, "_zero_state", None)
+        self._restore_state = getattr(self.runner, "_restore_state", None)
+        # what state a sequence keeps: pages, cached prefixes, state snapshots, staged migrations
+        self.store = SequenceStore(
+            cfg, self.runner, self._lock, prefix_cache=prefix_cache, max_blocks=int(prefix_cache_max_blocks),
+            gap=2 * (self.prefill_chunk_tokens or self.S), tags=self._depth_tags, **sizes)
+        # slot state (host-side mirrors of the device arrays). ``_pos`` is the
+        # position the NEXT dispatch writes: it advances at dispatch, not at
+        # readback. ``_reserved``: slots of a request whose chunked prefill is
+        # still in flight (the slot is taken but must not receive decode tokens yet)
         self._slots: List[Optional[GenRequest]] = [None] * self.B
-        # a row's last sampled token lives on the device (``_dev_toks``, what
-        # the last dispatched step returned). ``_join_tok`` carries the tokens
-        # the HOST sampled since the last dispatch (a sequence fresh from
-        # prefill or migration), -1 elsewhere: the decode program takes a
-        # row's token from here where it is >= 0. ``_pos`` is the position
-        # the NEXT dispatch writes: it advances at dispatch, not at readback
-        self._join_tok = np.full(self.B, -1, np.int32)
-        # a diffusion config's rows join with their first block instead: the
-        # prompt's tail as known positions, and the request's steps a block
-        self._join = {
-            "row": np.zeros(self.B, bool),
-            "known": np.zeros(self.B, np.int32),
-            "toks": np.zeros((self.B, self._bk), np.int32),
-            "steps": np.ones(self.B, np.int32),
-        }
         self._pos = np.zeros(self.B, np.int32)
         self._temps = np.zeros(self.B, np.float32)
         self._active = np.zeros(self.B, bool)
-        # paged state: per-slot block tables (host mirror of the device
-        # int32[B, M] array), pages held per slot, and slots reserved by a
-        # request whose chunked prefill is still in flight (the slot is
-        # taken but must not receive decode tokens yet)
-        self._block_tables = np.zeros((self.B, self.max_blocks_per_slot), np.int32)
-        self._slot_blocks: List[List[int]] = [[] for _ in range(self.B)]
         self._reserved = np.zeros(self.B, bool)
         self._prefilling: List[GenRequest] = []
         # head-of-line request popped from the fair queue but waiting for
@@ -556,14 +443,10 @@ class LLMEngine:
         self._held_req: Optional[GenRequest] = None
         self._prefill_chunk_count = 0
         # tokens of K/V the chunks' attention had to visit, averaged over the
-        # layers (``_chunk_kv_visited``), and the capacity a chunk's table
+        # layers (``store.chunk_kv_visited``), and the capacity a chunk's table
         # spans: what the prefill kernel reads of what the dense lines read
         self._prefill_kv_visited = 0.0
         self._prefill_kv_capacity = 0
-        # (window, layers that have it); 0: a full layer
-        # (a linear layer has no K/V: ``_chunk_kv_visited`` still averages over every layer)
-        self._layers_by_window = sorted(Counter(
-            (0,) * self._attn_layers if self._hybrid else cfg.layer_windows or (0,) * cfg.n_layers).items())
         self._decode_step_count = 0
         # the decode step dispatched and not yet read (``_dispatch`` /
         # ``_collect``), steps dispatched while the one before was unread,
@@ -574,14 +457,6 @@ class LLMEngine:
         # engine-thread-owned like ``_slots``, copied by ``stats()``
         self._clock = LoopClock(LOOP_PHASES)
         self._dispatches = dict.fromkeys(_DISPATCH_KINDS, 0)
-        # block steps (a diffusion config): forwards of live rows (denoise and
-        # commit), blocks committed, tokens they emitted and positions they
-        # unmasked, and blocks a cancelled row left uncommitted
-        self._block_row_forwards = 0
-        self._block_commits = 0
-        self._tokens_emitted = 0
-        self._tokens_unmasked = 0
-        self._blocks_dropped = 0
         # the dropless expert layers' own counters (models/generation.py,
         # ``paged_forward_counted``): the prefill and decode programs return
         # them beside the tokens and the loop adds them up when it reads the
@@ -593,173 +468,10 @@ class LLMEngine:
         self._moe_routed = 0
         self._moe_experts_hit = 0
         self._moe_experts_hit_decode = 0
-        # disaggregated serving: staged exports parked by migration id
-        # (the extracted block arrays outlive the prefill request's pool
-        # pages — those retire into the prefix cache at export) and the
-        # in/out migration counters surfaced by stats()/rt llm
-        self._staged: Dict[str, dict] = {}
+        # disaggregated serving: the in/out migration counters surfaced by stats()/rt llm
         self.num_migrations_out = 0
         self.num_migrations_in = 0
-        metric_defs.LLM_KV_BLOCK_POOL_SIZE.set(self._allocator.capacity, self._depth_tags)
-        metric_defs.LLM_KV_BLOCKS_IN_USE.set(0, self._depth_tags)
-        metric_defs.LLM_KV_BLOCKS_SHARED.set(0, self._depth_tags)
-        metric_defs.LLM_PREFIX_CACHE_BLOCKS.set(0, self._depth_tags)
-        if self._hybrid:
-            metric_defs.LLM_STATE_SNAPSHOT_POOL_SIZE.set(self._n_snapshots, self._depth_tags)
-            metric_defs.LLM_STATE_SNAPSHOTS_IN_USE.set(0, self._depth_tags)
-        if cfg.experts_held is not None:
-            metric_defs.LLM_MOE_EXPERTS_HELD.set(cfg.experts_here, self._depth_tags)
-
-        self._reset_cache()
-        if cfg.latent_layers:
-            metric_defs.LLM_LATENT_LAYERS.set(cfg.latent_layers, self._depth_tags)
-            metric_defs.LLM_KV_BYTES_PER_TOKEN.set(self._kv_bytes_per_token, self._depth_tags)
-        self._key = jax.random.key(np.random.randint(0, 2**31 - 1))
-
-        cfg_ = cfg
-        moe_counted = self._moe_counted
-        layer_scales = self._layer_scales
-        # under a mesh the einsum path partitions via GSPMD; the Pallas
-        # paged kernels (decode and prefill) stay for the single-device engine
-        use_kernel = None if mesh is None else False
-        # under a mesh a program that returns the pool returns it as it was
-        # placed: the donated buffers are updated where they lie and the next
-        # call finds the sharding it was compiled for (left to itself GSPMD
-        # re-shards a replicated pool). None, jit's default, on one device
-        kv_sharding = self._kv_sharding
-
-        def pool_among(n_outputs: int):  # the expert counts, where returned, come last
-            rest = (None,) * (n_outputs - 2 + moe_counted)
-            return None if kv_sharding is None else (None, kv_sharding) + rest
-
-        top_k_, top_p_ = self.top_k, self.top_p
-
-        def _sample_impl(key, logits, temps):
-            """Per-slot temperature; temp <= 0 means greedy."""
-            greedy = temps <= 0.0
-            t = jnp.where(greedy, 1.0, temps)
-            scaled = filter_top_k_top_p(logits / t[:, None], top_k_, top_p_)
-            keys = jax.random.split(key, logits.shape[0])
-            sampled = jax.vmap(jax.random.categorical)(keys, scaled)
-            return jnp.where(greedy, jnp.argmax(logits, -1), sampled).astype(jnp.int32)
-
-        self._sample = jax.jit(_sample_impl)
-
-        # the decode program: K sequential decode+sample steps inside ONE
-        # jitted lax.scan (K = decode_chunk; 1 = classic per-token
-        # stepping), so the host pays one dispatch/readback round trip per
-        # K tokens. One key split per generated token.  The cache is
-        # donated: the engine holds the only reference and reassigns, so
-        # XLA updates the pool's buffers in place.  It also hands back every
-        # row's last token as a device array, which the next run takes as
-        # it is: the loop dispatches that run before it reads this one's.
-        K_chunk = self.decode_chunk
-        block = self._bk
-        hybrid = self._hybrid
-
-        @functools.partial(jax.jit, donate_argnums=(1,), out_shardings=pool_among(2))
-        def _prefill_chunk(params, cache, toks, bt, start, length, slot=None):
-            """toks [1, C] chunk-padded; bt [1, M]; start/length traced,
-            so every chunk of every prompt at width C shares ONE
-            compile. Writes K/V for the chunk's ``length`` real tokens
-            through the block table and returns the last real token's
-            logits [V] (only the final chunk's are consumed). ``slot`` [1]
-            (a config with linear layers): where the sequence's state lives."""
-            C = toks.shape[1]
-            positions = start + jnp.arange(C)[None, :]
-            valid = (jnp.arange(C) < length)[None, :]
-            logits, cache, moe = paged_forward_counted(
-                cfg_, params, cache, bt, toks, positions,
-                valid=valid, layer_scales=layer_scales, use_decode_kernel=use_kernel,
-                with_logits=block == 1, slots=slot,
-            )
-            if logits is None:
-                # no token comes from a diffusion config's prefill: the head is
-                # not run, and what is waited for is a word of the written pool
-                last = cache["k"][0, 0, 0, :1].astype(jnp.float32)
-            else:
-                last = jax.lax.dynamic_index_in_dim(logits[0], length - 1, 0, keepdims=False)
-            return (last, cache, moe) if moe_counted else (last, cache)
-
-        def _block_step(params, cache, state, join, pos, temps, key, bt):
-            """The decode program of a diffusion config: one block step
-            (``models/generation.paged_block_step``). ``state``: the rows'
-            blocks as the last run left them, never read by the host in
-            between; ``join["row"]`` where the host opened a row's first
-            block since then. Hands back what the step finished (which rows
-            committed, and their tokens), the pool, the key and the state."""
-            state = select_rows(join["row"], open_blocks(cfg_, join["steps"], join["known"], join["toks"]), state)
-            key, sub = jax.random.split(key)
-            _, cache, state, done, moe = paged_block_step(
-                cfg_, params, cache, bt, state, pos, live=bt[:, 0] > 0,
-                sample=lambda flat: _sample_impl(sub, flat, jnp.repeat(temps, block)),
-                use_decode_kernel=use_kernel,
-            )
-            out = (done, cache, key, state)
-            return out + (moe,) if moe_counted else out
-
-        @functools.partial(jax.jit, donate_argnums=(1,), out_shardings=pool_among(4))
-        def _decode_k_paged(params, cache, toks, join, pos, temps, key, bt):
-            if block > 1:
-                return _block_step(params, cache, toks, join, pos, temps, key, bt)
-            # ``toks``: what the last run of this program returned, never
-            # read by the host in between; ``join`` >= 0 where the host
-            # sampled a row's token itself since then (its first)
-            toks = jnp.where(join >= 0, join, toks)
-            # a live row's first page is never the garbage page 0 (idle
-            # rows decode through all-zero tables): the expert layers
-            # count the live rows' assignments only
-            # (and an idle row's recurrent state stays as it is)
-            live = (bt[:, 0] > 0)[:, None] if moe_counted or hybrid else None
-            slots = jnp.arange(bt.shape[0], dtype=jnp.int32) if hybrid else None
-
-            def body(carry, _):
-                cache, toks, pos, key = carry
-                logits, cache, moe = paged_forward_counted(
-                    cfg_, params, cache, bt, toks[:, None], pos[:, None],
-                    layer_scales=layer_scales, use_decode_kernel=use_kernel, valid=live, slots=slots,
-                )
-                key, sub = jax.random.split(key)
-                nxt = _sample_impl(sub, logits[:, 0], temps)
-                return (cache, nxt, pos + 1, key), (nxt, moe)
-
-            (cache, last, _, key), (toks_k, moe) = jax.lax.scan(
-                body, (cache, toks, pos, key), None, length=K_chunk
-            )
-            out = (jnp.swapaxes(toks_k, 0, 1), cache, key, last)  # [B, K] ... [B]
-            if moe_counted:
-                out += (jax.tree.map(lambda a: a.sum(0), moe),)  # over the K steps
-            return out
-
-        # copy-on-write primitive (models/generation.copy_paged_page):
-        # donated so XLA copies the page in place in the pool buffers
-        self._copy_page = jax.jit(copy_paged_page, donate_argnums=(0,), out_shardings=kv_sharding)
-
-        # Land a migrated block set ``[N, 2, L, block_size, Hkv, Dh]`` into
-        # the pool in ONE donated scatter: per-block writes cost a
-        # dispatch each — 24 blocks of a long prompt stall the engine
-        # loop ~10ms on the bench box. Callers bucket-pad N by repeating
-        # the last (block, page) pair (the duplicate scatter indices stay
-        # idempotent), keeping the compile count at O(log blocks), not
-        # one per block count.
-        self._write_blocks = jax.jit(write_paged_pages, donate_argnums=(0,), out_shardings=kv_sharding)
-        # the page index is traced: every exported block shares one compile
-        self._export_page = jax.jit(functools.partial(export_paged_page, cfg_))
-        self._prefill_chunk = _prefill_chunk
-        self._decode_k_paged = _decode_k_paged
-        if hybrid:
-            # a slot's state at admission: zero, or a snapshot's copy; and the
-            # snapshots of one dispatch, one program (``n`` of the ``B`` pairs
-            # are real). Slots and entries are traced: one compile each
-            self._zero_state = jax.jit(zero_sequence_state, donate_argnums=(0,))
-            self._restore_state = jax.jit(copy_sequence_state, donate_argnums=(0,))
-
-            def _snapshot_rows(snaps, cache, slots, entries, n):
-                return jax.lax.fori_loop(
-                    0, n, lambda i, snaps: copy_sequence_state(snaps, cache, entries[i], slots[i]), snaps)
-
-            self._snapshot_state = jax.jit(_snapshot_rows, donate_argnums=(0,))
-
+        self._model_gauges(1)
         self._thread = threading.Thread(target=self._loop, daemon=True, name="llm-engine")
         self._thread.start()
 
@@ -824,32 +536,25 @@ class LLMEngine:
                 f"prompt ({len(prompt)}) + max_tokens ({max_tokens}) exceeds "
                 f"engine max_seq_len {self.S}"
             )
-        if self._hybrid and (_export_mig_id is not None or _import_ticket is not None):
-            raise ValueError('prefill_export / adopt_migration are not supported for a config with "linear" layers: '
-                             "a migrated block set carries pages, not the sequence's recurrent state")
-        if self._bk == 1:
-            if denoising_steps is not None:
-                raise ValueError("denoising_steps belongs to a config that generates by diffusion over blocks "
-                                 "(block_length > 1); this engine's config is autoregressive")
-        else:
-            if _export_mig_id is not None or _import_ticket is not None:
-                raise ValueError("prefill_export / adopt_migration are not supported for a config that generates "
-                                 "by diffusion over blocks: an exported prefill carries no first token")
-            if denoising_steps is None:
-                denoising_steps = self._bk
-            if not 1 <= int(denoising_steps) <= self._bk:
-                raise ValueError(f"denoising_steps must be 1 to the config's block_length {self._bk}, "
-                                 f"got {denoising_steps}")
+        # a diffusion config's default: as many steps as a block has positions
+        block = self.cfg.block
+        n_steps = block if denoising_steps is None else int(denoising_steps)
+        _check_combination(
+            self.cfg, None,
+            {"migration": _export_mig_id is not None or _import_ticket is not None,
+             "denoising_steps": denoising_steps is not None, "steps_range": not 1 <= n_steps <= block},
+            denoising_steps=denoising_steps)
         # never-fits contract (same as max_queued_prefill_tokens below):
         # a request needing more pages than the POOL holds can never be
         # admitted — that is a config/input error at submit, not a
         # retry-after-able overload and not a failure deep in prefill
-        needed = self._pages_needed(len(prompt), max_tokens)
-        if needed > self._allocator.capacity:
+        needed = self.store.pages_needed(len(prompt), max_tokens)
+        capacity = self.store.allocator.capacity
+        if needed > capacity:
             raise ValueError(
                 f"prompt ({len(prompt)}) + max_tokens ({max_tokens}) needs "
                 f"{needed} KV blocks but the pool only holds "
-                f"{self._allocator.capacity} and would never be admitted"
+                f"{capacity} and would never be admitted"
             )
         if self._max_queued_tokens and len(prompt) > self._max_queued_tokens:
             # a prompt that ALONE exceeds the budget can never be admitted:
@@ -880,7 +585,7 @@ class LLMEngine:
         # a long prompt's chain is a millisecond or two of hashing: here, on
         # the caller's thread, not at admission between two decode steps
         bs = self.kv_block_size
-        block_keys = tuple(chain_keys(prompt, len(prompt) // bs, bs)) if self._prefix is not None else ()
+        block_keys = tuple(chain_keys(prompt, len(prompt) // bs, bs)) if self.store.prefix is not None else ()
         with self._lock:
             depth = len(self._queue)
             if self._max_queued and depth >= self._max_queued:
@@ -910,7 +615,7 @@ class LLMEngine:
                 stream_queue=_stream_queue, tenant=tenant,
                 deadline_ts=deadline_ts, trace=trace,
             )
-            req.denoising_steps = int(denoising_steps or 0)
+            req.denoising_steps = n_steps if block > 1 else 0
             req.block_keys = block_keys
             req.export_mig_id = _export_mig_id
             req.import_ticket = _import_ticket
@@ -924,20 +629,11 @@ class LLMEngine:
         self._wake.set()
         return req
 
-    def _pages_needed(self, prompt_len: int, max_tokens: int) -> int:
-        """Pages a request's whole budget takes. The last written position
-        is ``prompt + max_tokens - 2`` (the last sampled token is never
-        written); a diffusion config commits every emitted token and writes
-        its last block whole, to the end of the block that holds position
-        ``prompt + max_tokens - 1`` (a page holds whole blocks)."""
-        last = prompt_len + max_tokens - (2 if self._bk == 1 else 1)
-        return last // self.kv_block_size + 1
-
     def _fill_len(self, req: GenRequest) -> int:
         """Prompt tokens prefill has to cache: all of them, or for a
         diffusion config the prompt's whole blocks (the rest opens the
         first block as known positions)."""
-        return len(req.prompt) - len(req.prompt) % self._bk
+        return len(req.prompt) - len(req.prompt) % self._steps.block
 
     def generate(self, prompt: List[int], **kw) -> List[int]:
         return self.submit(prompt, **kw).result()
@@ -1051,59 +747,14 @@ class LLMEngine:
             _import_arrays=dict(arrays),
         )
 
-    def peek_prefix_match(self, prompt: List[int]) -> int:
-        """Longest cached prefix (tokens) of ``prompt`` in THIS replica's
-        prefix cache — the decode side probes before pulling so a warm
-        prefix short-circuits re-migration of shared-prefix blocks.
-        Advisory: admission re-matches, and a shrink in between surfaces
-        as a typed migration error (the ladder re-prefills)."""
-        if self._prefix is None:
-            return 0
-        with self._lock:
-            _, matched = self._prefix.match(prompt)
-        return matched
-
-    def kv_free_blocks(self) -> int:
-        """Free pages right now — the decode-pool routing signal."""
-        with self._lock:
-            return self._allocator.free_blocks
-
-    def release_migration(self, mig_id: str) -> bool:
-        """Drop a staged export: forget the arrays and unregister the
-        host-fallback source.  Device-plane offers have no cancel API —
-        unpulled ones expire via the transfer server's staging TTL (a
-        documented device_plane caveat).  Idempotent; True if the staging
-        existed.  The prefill-side POOL pages were already retired into
-        the prefix cache at export, so this never touches the pool —
-        exactly-once freeing is the export path's invariant."""
-        with self._lock:
-            entry = self._staged.pop(mig_id, None)
-        if entry is None:
-            return False
-        from ray_tpu.runtime import data_plane
-
-        data_plane.unregister_kv_block_source(mig_id)
-        return True
-
-    def fetch_staged_block(self, mig_id: str, block_idx: int):
-        """One staged block.  Returns the staged device array as-is: the
-        in-process rung adopts it without a host round-trip, and the
-        data-plane ``kv_pull`` op host-converts it only when actually
-        serving a remote pull."""
-        with self._lock:
-            entry = self._staged.get(mig_id)
-        if entry is None:
-            raise KeyError(f"no staged migration {mig_id!r}")
-        return entry["arrays"][block_idx]
-
     def stats(self) -> Dict[str, Any]:
+        """The scheduler's counters, the store's (pages, prefix cache, state
+        snapshots) and what the runner's steps count (a diffusion config)."""
         with self._lock:
-            alloc = self._allocator
             return {
                 "role": self.role,
                 "migrations_out": self.num_migrations_out,
                 "migrations_in": self.num_migrations_in,
-                "staged_migrations": len(self._staged),
                 "active_slots": int(self._active.sum()),
                 "max_batch_size": self.B,
                 "queued": len(self._queue),
@@ -1111,23 +762,10 @@ class LLMEngine:
                 "prefill_forwards": self._prefill_count,
                 "slots_evicted": self.num_slots_evicted,
                 "shed": self.num_shed,
-                "kv_block_size": self.kv_block_size,
-                "kv_block_pool_size": alloc.capacity,
-                "kv_blocks_in_use": alloc.used_blocks,
-                "kv_blocks_shared": alloc.shared_blocks,
                 "prefilling": len(self._prefilling),
                 "prefill_chunks": self._prefill_chunk_count,
                 "prefill_kv_tokens_visited": self._prefill_kv_visited,
                 "prefill_kv_tokens_capacity": self._prefill_kv_capacity,
-                "prefix_cache_enabled": self._prefix is not None,
-                "prefix_cache_blocks": len(self._prefix) if self._prefix is not None else 0,
-                "prefix_cache_hits": self._prefix_results["hit"],
-                "prefix_cache_partial": self._prefix_results["partial"],
-                "prefix_cache_misses": self._prefix_results["miss"],
-                "prefix_tokens_reused": self._prefix_tokens_reused,
-                "prefix_evictions": self._prefix.evictions if self._prefix is not None else 0,
-                "prefix_evict_scanned": self._prefix.scanned if self._prefix is not None else 0,
-                "cow_copies": self._cow_count,
                 "decode_steps": self._decode_step_count,
                 # dispatched while the step before was still unread: all but the cold ones
                 "decode_steps_overlapped": self.decode_chunk * (self._dispatches["queued"] + self._dispatches["dry"]),
@@ -1136,57 +774,11 @@ class LLMEngine:
                 "loop_phase_s": dict(self._clock.seconds),
                 "kv_read_share": self.kv_read_share(),
                 "kv_live_pages": self.kv_live_pages(),
+                **self.store.stats_locked(),
                 **self._moe_stats_locked(),
-                **self._block_stats_locked(),
-                **self._state_stats_locked(),
+                **self._steps.stats(self._decode_step_count),
+                **self.store.state_stats_locked(),
             }
-
-    def _state_stats_locked(self) -> Dict[str, Any]:
-        """The recurrent state's own counters (absent for a config without
-        linear layers): the snapshot pool's size and entries held (by live
-        requests and by radix nodes), snapshots taken, snapshots detached
-        because the pool was full, slots restored from a snapshot and slots
-        zeroed at admission, the prompt tokens a page match offered
-        (``prefix_tokens_reused`` beside it: those a snapshot let the engine
-        skip), and the bytes a slot's state and convolution tail take."""
-        if not self._hybrid:
-            return {}
-        return {
-            "state_snapshot_pool_size": self._snap_pool.size,
-            "state_snapshots_in_use": self._snap_pool.in_use,
-            "state_snapshots_taken": self._state_snapshots_taken,
-            "state_snapshots_evicted": self._prefix.snapshot_evictions if self._prefix is not None else 0,
-            "state_restores": self._state_restores,
-            "state_zeroed": self._state_zeroed,
-            "prefix_tokens_matched": self._prefix_tokens_matched,
-            "state_bytes_per_slot": self._state_bytes_per_slot,
-            **self._latent_stats(),
-        }
-
-    def _latent_stats(self) -> Dict[str, Any]:
-        """A config with latent layers: how many, and the bytes a cached token
-        takes in all the pools as they were built (every attention layer, the
-        pad lanes included)."""
-        if not self.cfg.latent_layers:
-            return {}
-        return {"latent_layers": self.cfg.latent_layers, "kv_bytes_per_token": self._kv_bytes_per_token}
-
-    def _block_stats_locked(self) -> Dict[str, Any]:
-        """A diffusion config's own counters (absent otherwise): block steps
-        (its decode steps), forwards of live rows in them (denoise and
-        commit), blocks committed, the tokens they emitted and the positions
-        they unmasked, and blocks a cancelled row left uncommitted."""
-        if self._bk == 1:
-            return {}
-        return {
-            "block_length": self._bk,
-            "block_steps": self._decode_step_count,
-            "block_row_forwards": self._block_row_forwards,
-            "block_commits": self._block_commits,
-            "tokens_emitted": self._tokens_emitted,
-            "tokens_unmasked": self._tokens_unmasked,
-            "blocks_dropped": self._blocks_dropped,
-        }
 
     def _moe_stats_locked(self) -> Dict[str, Any]:
         """The expert layers' running totals (absent for a config without
@@ -1213,36 +805,17 @@ class LLMEngine:
                        moe_experts_held=self.cfg.experts_here)
         return out
 
-    def lowered_decode_text(self) -> str:
-        """StableHLO text of the decode program as the loop runs it (same
-        params, cache and slot-array shapes). ``chip_smoke.py`` looks for
-        ``tpu_custom_call`` in it: which attention path the engine compiled
-        is read from the program, not assumed from a flag."""
-
-        def abstract(x):
-            return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=x.sharding)
-
-        params, cache = jax.tree.map(abstract, (self.params, self._cache))
-        toks = jax.ShapeDtypeStruct((self.B,), jnp.int32)
-        temps = jax.ShapeDtypeStruct((self.B,), jnp.float32)
-        bt = jax.ShapeDtypeStruct(self._block_tables.shape, jnp.int32)
-        state, join = (toks, toks) if self._bk == 1 else jax.tree.map(abstract, (self._dev_toks, self._join_arrays()))
-        return self._decode_k_paged.lower(params, cache, state, join, toks, temps, self._key, bt).as_text()
-
     def admission_snapshot(self) -> Dict[str, Any]:
         """Bounds + depths for GET /api/overload (admission source)."""
         with self._lock:
-            alloc = self._allocator
-            pool = alloc.capacity
-            in_use = alloc.used_blocks
-            probes = sum(self._prefix_results.values())
-            useful = self._prefix_results["hit"] + self._prefix_results["partial"]
+            pool = self.store.stats_locked()
+            useful = pool["prefix_cache_hits"] + pool["prefix_cache_partial"]
+            probes = useful + pool["prefix_cache_misses"]
             return {
                 "layer": "engine",
                 "role": self.role,
                 "migrations_out": self.num_migrations_out,
                 "migrations_in": self.num_migrations_in,
-                "staged_migrations": len(self._staged),
                 "queued": len(self._queue),
                 "queue_bound": self._max_queued,
                 "queued_prefill_tokens": self._queued_tokens,
@@ -1252,20 +825,15 @@ class LLMEngine:
                 "by_tenant": self._queue.depth_by_tenant(),
                 "slots_evicted": self.num_slots_evicted,
                 "shed": self.num_shed,
-                "kv_block_size": self.kv_block_size,
-                "kv_block_pool_size": pool,
-                "kv_blocks_in_use": in_use,
-                "kv_blocks_shared": alloc.shared_blocks,
-                "kv_block_occupancy": in_use / pool,
+                **{k: pool[k] for k in (
+                    "staged_migrations", "kv_block_size", "kv_block_pool_size", "kv_blocks_in_use", "kv_blocks_shared",
+                    "prefix_cache_enabled", "prefix_cache_blocks", "prefix_tokens_reused", "prefix_evictions",
+                    "prefix_evict_scanned")},
+                "kv_block_occupancy": pool["kv_blocks_in_use"] / pool["kv_block_pool_size"],
                 "prefilling": len(self._prefilling),
                 "prefill_chunks": self._prefill_chunk_count,
                 "waiting_for_blocks": 1 if self._held_req is not None else 0,
-                "prefix_cache_enabled": self._prefix is not None,
-                "prefix_cache_blocks": len(self._prefix) if self._prefix is not None else 0,
                 "prefix_hit_rate": (useful / probes) if probes else 0.0,
-                "prefix_tokens_reused": self._prefix_tokens_reused,
-                "prefix_evictions": self._prefix.evictions if self._prefix is not None else 0,
-                "prefix_evict_scanned": self._prefix.scanned if self._prefix is not None else 0,
                 "decode_steps": self._decode_step_count,
                 "kv_read_share": self.kv_read_share(),
                 **self._moe_stats_locked(),
@@ -1276,6 +844,16 @@ class LLMEngine:
                 },
             }
 
+    def _model_gauges(self, on: int) -> None:
+        """This engine's series of what its model holds (zeroed at shutdown:
+        the series label is reused by the next engine)."""
+        cfg = self.cfg
+        if cfg.latent_layers:
+            metric_defs.LLM_LATENT_LAYERS.set(on * cfg.latent_layers, self._depth_tags)
+            metric_defs.LLM_KV_BYTES_PER_TOKEN.set(on * self.runner.kv_bytes_per_token, self._depth_tags)
+        if cfg.experts_held is not None:
+            metric_defs.LLM_MOE_EXPERTS_HELD.set(on * cfg.experts_here, self._depth_tags)
+
     def shutdown(self) -> None:
         self._stop = True
         self._wake.set()
@@ -1284,18 +862,8 @@ class LLMEngine:
         # zero this engine's gauge series; the freed token (and thus the
         # series label) is reused by the next engine
         metric_defs.ADMISSION_QUEUE_DEPTH.set(0, self._depth_tags)
-        metric_defs.LLM_KV_BLOCKS_IN_USE.set(0, self._depth_tags)
-        metric_defs.LLM_KV_BLOCK_POOL_SIZE.set(0, self._depth_tags)
-        metric_defs.LLM_KV_BLOCKS_SHARED.set(0, self._depth_tags)
-        metric_defs.LLM_PREFIX_CACHE_BLOCKS.set(0, self._depth_tags)
-        if self._hybrid:
-            metric_defs.LLM_STATE_SNAPSHOT_POOL_SIZE.set(0, self._depth_tags)
-            metric_defs.LLM_STATE_SNAPSHOTS_IN_USE.set(0, self._depth_tags)
-        if self.cfg.latent_layers:
-            metric_defs.LLM_LATENT_LAYERS.set(0, self._depth_tags)
-            metric_defs.LLM_KV_BYTES_PER_TOKEN.set(0, self._depth_tags)
-        if self.cfg.experts_held is not None:
-            metric_defs.LLM_MOE_EXPERTS_HELD.set(0, self._depth_tags)
+        self.store.gauges(0)
+        self._model_gauges(0)
         with self._lock:
             pending = [r for r in self._queue.items() if not r.future.done()]
             pending += [r for r in self._slots if r is not None and not r.future.done()]
@@ -1307,103 +875,13 @@ class LLMEngine:
                 self._held_req = None
             self._queue.drain()
             self._queued_tokens = 0
-            staged = list(self._staged)
-            self._staged.clear()
-        if staged:
-            from ray_tpu.runtime import data_plane
-
-            for mig_id in staged:
-                data_plane.unregister_kv_block_source(mig_id)
+            staged = list(self.store.staged)
+            self.store.staged.clear()
+        self.store.unregister(staged)
         for r in pending:
             r.future.set_exception(RuntimeError("LLMEngine shut down"))
             if r.stream_queue is not None:
                 r.stream_queue.put(_STREAM_END)
-
-    def flush_prefix_cache(self) -> int:
-        """Evict every prefix-cache entry not currently shared into a live
-        request and return the number of pages freed.  Ops hook — also the
-        leak-check primitive: on a quiesced engine, ``kv_blocks_in_use``
-        equals ``prefix_cache_blocks`` and a flush takes both to zero."""
-        if self._prefix is None:
-            return 0
-        with self._lock:
-            pages = self._evict_pages_locked(len(self._prefix))
-            if pages:
-                self._allocator.free(pages)
-            gauges = self._pool_gauges_locked()
-        if pages:
-            metric_defs.LLM_PREFIX_EVICTIONS.inc(len(pages))
-        self._publish_pool_gauges(*gauges)
-        return len(pages)
-
-    def state_snapshot(self, tokens: List[int]) -> Optional[Dict[str, Any]]:
-        """Read-out for a check (a config with linear layers, an engine at
-        rest): the deepest state snapshot the prefix cache holds on the path
-        of ``tokens``, as ``{"tokens": how many of them it covers, "state":
-        the recurrent state after exactly those, float32 [linear layers,
-        heads, key dim, value dim]}``, or None if no node on the path carries
-        one. No clock of either pool moves. The engine thread replaces the
-        pool's arrays whenever it takes a snapshot, so call this while
-        nothing decodes."""
-        if self._prefix is None or self._snaps is None:
-            return None
-        with self._lock:
-            entry, covered = self._prefix.snapshot_at(tokens)
-            snaps = self._snaps
-        if entry < 0:
-            return None
-        group = lane_group(self.cfg.linear_heads, self.cfg.linear_value_dim)
-        return {"tokens": covered, "state": np.asarray(unpack_state(snaps["state"][:, entry], group))}
-
-    def _evictable(self, page: int) -> bool:
-        """An eviction may only take pages whose sole reference is the
-        cache's own — refcount 1 means no live block table names the page.
-        Caller holds ``self._lock``."""
-        return self._allocator.refcount(page) == 1
-
-    def _evict_pages_locked(self, want: int) -> List[int]:
-        """LRU-evict up to ``want`` unreferenced cached leaves and return
-        their pages for the caller to free; the state snapshots of the nodes
-        that went return to their pool here. Caller holds ``self._lock``."""
-        pages = self._prefix.evict(want, self._evictable)
-        self._reclaim_snapshots_locked()
-        return pages
-
-    def _reclaim_snapshots_locked(self) -> None:
-        """Return to the snapshot pool the entries of radix nodes that went."""
-        for entry in self._prefix.take_freed_snapshots():
-            self._snap_pool.free(entry)
-
-    def _drop_snapshot_locked(self, req: GenRequest) -> None:
-        """A request leaves without publishing its pages: its snapshot goes too."""
-        if req.snap is not None:
-            self._snap_pool.free(req.snap[0])
-            req.snap = None
-
-    def _alloc_snapshot_locked(self) -> int:
-        """An entry of the snapshot pool: a free one, else the least recently
-        used one a radix node carries (its pages stay), else -1: the caller
-        goes without. Snapshot exhaustion fails no request."""
-        entry = self._snap_pool.alloc()
-        if entry < 0 and self._prefix is not None:
-            freed = self._prefix.evict_snapshot()
-            if freed >= 0:
-                self._snap_pool.free(freed)
-                entry = self._snap_pool.alloc()
-        return entry
-
-    def _pool_gauges_locked(self):
-        """(in_use, shared, cache_blocks) snapshot; caller holds the lock."""
-        return (
-            self._allocator.used_blocks,
-            self._allocator.shared_blocks,
-            len(self._prefix) if self._prefix is not None else 0,
-        )
-
-    def _publish_pool_gauges(self, in_use: int, shared: int, cache_blocks: int) -> None:
-        metric_defs.LLM_KV_BLOCKS_IN_USE.set(in_use, self._depth_tags)
-        metric_defs.LLM_KV_BLOCKS_SHARED.set(shared, self._depth_tags)
-        metric_defs.LLM_PREFIX_CACHE_BLOCKS.set(cache_blocks, self._depth_tags)
 
     # -- request-scope latency bookkeeping ----------------------------------
     def _note_first_token(self, req: GenRequest) -> None:
@@ -1483,7 +961,7 @@ class LLMEngine:
         })
 
     # -- engine loop --------------------------------------------------------
-    def _pop_admissible(self, *, need_free_slot: bool = True):
+    def _pop_admissible(self):
         """Shared admit-loop head: pop (or resume) the next runnable request.
 
         Returns ``(req, free_slots)`` with shed-on-pop filtering applied, or
@@ -1498,7 +976,7 @@ class LLMEngine:
                     i for i in range(self.B)
                     if not self._active[i] and not self._reserved[i]
                 ]
-                if need_free_slot and not free:
+                if not free:
                     return None
                 if self._held_req is not None:
                     req = self._held_req
@@ -1550,126 +1028,42 @@ class LLMEngine:
             return req, free
 
     def _admit(self) -> None:
-        """Block-aware admission: reserve the request's whole page budget up
-        front (``ceil((prompt + max_tokens - 1) / block_size)`` — the last
-        written position is ``prompt + max_tokens - 2``), so an admitted
-        request can never hit a mid-decode pool OOM and nothing is ever
-        preempted. Prefill itself runs later, chunk by chunk, from
-        ``_prefill_enqueue`` so decode steps interleave with long prompts.
-
-        With the prefix cache, the longest cached prefix of the prompt is
-        ``share()``d straight into the block table (zero prefill compute for
-        the hit region — chunked prefill starts at the first uncached token)
-        and only the uncached suffix reserves fresh pages. A full-prompt hit
-        still recomputes the LAST prompt token (its logits seed sampling),
-        and that write would land in the final matched block — a shared
-        page — so that block is copy-on-write: the request gets a fresh
-        page populated by a device page copy instead of a share."""
-        bs = self.kv_block_size
+        """Admission: pop the next request in fair order, reserve its whole
+        page budget from the store (``SequenceStore.reserve_locked``: the
+        prefix match, the pins, the eviction sweep; a request the pool cannot
+        hold yet waits at the head of the line), place its slot's state, and
+        queue it for prefill. Prefill itself runs later, chunk by chunk, from
+        ``_prefill_enqueue`` so decode steps interleave with long prompts."""
+        store = self.store
         while True:
             popped = self._pop_admissible()
             if popped is None:
                 return
             req, free = popped
-            tp = len(req.prompt)
-            total = self._pages_needed(tp, req.max_tokens)
+            slot = free[0]
             with self._lock:
-                pages: List[int] = []
-                matched = 0
-                snapshot = -1
-                if self._prefix is not None and not self._hybrid:
-                    pages, matched = self._prefix.match(req.prompt, req.block_keys)
-                elif self._prefix is not None:
-                    # the state after the matched pages has to exist too: skip
-                    # as far as the deepest matched node with a snapshot, short
-                    # of the last token (its logits seed sampling, and a state
-                    # cannot be stepped back), and share no page beyond it
-                    pages, offered, snapshot, matched = self._prefix.match_snapshot(req.prompt, tp - 1, req.block_keys)
-                    self._prefix_tokens_matched += min(offered, (tp - 1) // bs * bs)
-                    pages = pages[: matched // bs]
-                    req.branch_at = self._branch_snapshot_at(req, offered, matched)
-                cow_src = -1
-                if matched == tp and self._bk == 1:
-                    # full-prompt hit: the tail block must be writable (a
-                    # diffusion config recomputes nothing: no token comes
-                    # from its prefill, and its first block opens a page)
-                    cow_src = pages.pop()
-                    matched -= bs
-                # pin the hit region (and the COW source) FIRST: the
-                # eviction sweep below must never free a page we matched
-                pins = pages + ([cow_src] if cow_src >= 0 else [])
-                if pins:
-                    self._allocator.share(pins)
-                needed = total - len(pages)
-                short = needed - self._allocator.free_blocks
-                evicted_n = 0
-                if short > 0 and self._prefix is not None:
-                    # pool short: LRU-sweep unreferenced cached leaves
-                    # before holding (and long before admission sheds)
-                    evicted = self._evict_pages_locked(short)
-                    if evicted:
-                        self._allocator.free(evicted)
-                        evicted_n = len(evicted)
-                if needed > self._allocator.free_blocks:
-                    # head-of-line waits for release paths to return pages;
-                    # skipping it would starve big requests behind small
-                    # ones. Drop the pins — it re-probes the cache on wake.
-                    if pins:
-                        self._allocator.free(pins)
+                got = store.reserve_locked(req, slot)
+                if got is None:
                     self._held_req = req
-                    if evicted_n:
-                        metric_defs.LLM_PREFIX_EVICTIONS.inc(evicted_n)
                     return
-                blocks = pages + self._allocator.alloc(needed)
-                if snapshot >= 0:
-                    self._prefix.restored(snapshot)
-                slot = free[0]
                 self._reserved[slot] = True
-                self._slot_blocks[slot] = blocks
-                self._block_tables[slot, :] = 0
-                self._block_tables[slot, : len(blocks)] = blocks
-                hit_tokens = matched + (bs if cow_src >= 0 else 0)
-                if self._prefix is not None:
-                    fb = (tp // bs) * bs  # the matchable (full-block) region
-                    result = (
-                        ("hit" if hit_tokens == fb else "partial")
-                        if hit_tokens > 0
-                        else "miss"
-                    )
-                    self._prefix_results[result] += 1
-                    self._prefix_tokens_reused += (
-                        tp - 1 if cow_src >= 0 else matched
-                    )
-                gauges = self._pool_gauges_locked()
-            if evicted_n:
-                metric_defs.LLM_PREFIX_EVICTIONS.inc(evicted_n)
-            self._publish_pool_gauges(*gauges)
-            if self._prefix is not None:
-                metric_defs.LLM_PREFIX_CACHE_HITS.inc(tags=_PREFIX_RESULT_TAGS[result])
+            store.publish_reserved(got)
             req.slot = slot
             if req.trace is not None:
                 # pages reserved: kv_block_wait (wfq_pop -> here) is over
                 req.trace.mark("admitted")
             # chunked prefill resumes at the first token whose KV is not
             # already in the table (tp - 1 for a full hit: one recompute)
-            req.prefill_pos = matched
-            if self._hybrid and not self._place_state(req, snapshot):
+            req.prefill_pos = got.matched
+            if store.keeps_state and not self._place_state(req, got.snapshot):
                 continue
-            if cow_src >= 0:
+            if got.cow_src >= 0:
                 try:
-                    dst = blocks[len(pages)]  # the fresh page for the tail block
-                    self._cache = self._copy_page(
-                        self._cache, jnp.int32(cow_src), jnp.int32(dst)
-                    )
-                    with self._lock:
-                        self._allocator.free([cow_src])  # drop the copy pin
-                        self._cow_count += 1
+                    store.copy_tail(got)
                 except BaseException as exc:  # noqa: BLE001
-                    with self._lock:
-                        self._allocator.free([cow_src])
                     self._fail_admit(req, exc)
                     continue
-                req.prefill_pos = tp - 1
+                req.prefill_pos = len(req.prompt) - 1
             if req.import_arrays is not None:
                 # migrated request: blocks land from the producer's staged
                 # arrays (or this replica's own prefix cache) — no prefill.
@@ -1677,7 +1071,7 @@ class LLMEngine:
                 # the heaviest admission step, and a migration burst
                 # draining in a single pass would stall the decode cadence
                 # for every running stream (the loop re-admits next tick)
-                self._adopt_admitted(req, had_cow=cow_src >= 0)
+                self._adopt_admitted(req, had_cow=got.cow_src >= 0)
                 return
             if req.prefill_pos >= self._fill_len(req):
                 # a diffusion config's prompt of whole cached pages, or one
@@ -1695,155 +1089,49 @@ class LLMEngine:
         right after: the device runs the copy first) or zero. Enqueued behind
         whatever the slot's last occupant still had in flight, so nothing of
         it survives. False: the copy failed and the request with it."""
+        # (the tool named at ``_restore_state`` swaps this method and the two programs it passes)
         try:
-            with jax.profiler.TraceAnnotation("llm::state_restore"):
-                if snapshot >= 0:
-                    self._cache = self._restore_state(self._cache, self._snaps, jnp.int32(req.slot), jnp.int32(snapshot))
-                else:
-                    self._cache = self._zero_state(self._cache, jnp.int32(req.slot))
+            if snapshot >= 0:
+                self.runner.restore_state(req.slot, snapshot, self._restore_state)
+            else:
+                self.runner.zero_state(req.slot, self._zero_state)
         except BaseException as exc:  # noqa: BLE001
             self._fail_admit(req, exc)
             return False
         with self._lock:
             if snapshot >= 0:
-                self._state_restores += 1
+                self.store.state_restores += 1
             else:
-                self._state_zeroed += 1
+                self.store.state_zeroed += 1
         (metric_defs.LLM_STATE_RESTORES if snapshot >= 0 else metric_defs.LLM_STATE_ZEROED).inc()
         return True
 
-    def _snapshot_after_prompt(self, req: GenRequest) -> int:
-        """Tokens of ``req``'s prompt a snapshot is to be taken after during
-        prefill: its whole pages, or 0 for none: no snapshot pool or prefix
-        cache, a prompt shorter than a page, or a reply that is certain (no
-        EOS to end it early) to reach a later page boundary while decoding,
-        whose snapshot would replace this one."""
-        bs = self.kv_block_size
-        tp = len(req.prompt)
-        whole = tp // bs * bs
-        if not self._n_snapshots or self._prefix is None or not whole:
-            return 0
-        later = req.eos_id is None and tp + req.max_tokens - 2 >= whole + bs - 1
-        return 0 if later else whole
-
-    def _branch_snapshot_at(self, req: GenRequest, offered: int, matched: int) -> int:
-        """Tokens of ``req``'s prompt after which its prefill leaves a
-        snapshot on the cached node that ends them, 0 for none. Pages are
-        cached ``offered`` tokens deep and the state only ``matched``: the
-        request prefills what lies between again, over tokens other requests
-        share, and so will every request after it (a document whose first
-        reader's prompt ran on past it never had a snapshot at its end; one
-        whose snapshot aged out of a full pool never gets another from a
-        prompt's end). Where those tokens are two chunks or more and part
-        from another request's at a node (``PrefixCache.branch_point``), the
-        chunk is cut there and the state kept. Caller holds the lock."""
-        gap = 2 * (self.prefill_chunk_tokens or self.S)
-        if not self._n_snapshots or offered - matched < gap:
-            return 0
-        at = self._prefix.branch_point(req.prompt, len(req.prompt) - 1, req.block_keys)
-        return at if at - matched >= gap else 0
-
-    def _snapshot_branch(self, req: GenRequest) -> None:
-        """The chunk just enqueued ends at ``req.branch_at``: the state behind
-        it goes to the cached node there, unless another request got there
-        first (the entry is free again)."""
-        for _, entry, tokens in self._take_snapshots([(req, req.branch_at)]):
-            with self._lock:
-                if not self._prefix.attach_snapshot(req.prompt, tokens, entry, req.block_keys):
-                    self._snap_pool.free(entry)
-
-    def _take_snapshots(self, rows: List[Tuple[GenRequest, int]]) -> List[Tuple[GenRequest, int, int]]:
-        """Enqueue, behind the program that produced them, the copies of the
-        states of ``rows`` ((request, tokens its state then covers)) into
-        entries of the snapshot pool: one program for all of them. Returns
-        (request, entry, tokens) of those that got an entry."""
-        taken: List[Tuple[GenRequest, int, int]] = []
-        if not rows:  # (always, without a prefix cache to publish them to)
-            return taken
-        with self._lock:
-            detached = self._prefix.snapshot_evictions
-            for req, tokens in rows:
-                entry = self._alloc_snapshot_locked()
-                if entry >= 0:
-                    taken.append((req, entry, tokens))
-            in_use = self._snap_pool.in_use
-            self._state_snapshots_taken += len(taken)
-            detached = self._prefix.snapshot_evictions - detached
-        if detached:
-            metric_defs.LLM_STATE_SNAPSHOTS_EVICTED.inc(detached)
-        if not taken:
-            return taken
-        slots, entries = np.zeros(self.B, np.int32), np.zeros(self.B, np.int32)
-        for j, (req, entry, _) in enumerate(taken):
-            slots[j], entries[j] = req.slot, entry
-        with jax.profiler.TraceAnnotation("llm::state_snapshot"):
-            self._snaps = self._snapshot_state(self._snaps, self._cache, jnp.asarray(slots), jnp.asarray(entries),
-                                               jnp.int32(len(taken)))
-        metric_defs.LLM_STATE_SNAPSHOTS_TAKEN.inc(len(taken))
-        metric_defs.LLM_STATE_SNAPSHOTS_IN_USE.set(in_use, self._depth_tags)
-        return taken
-
-    def _keep_snapshot(self, req: GenRequest, entry: int, tokens: int) -> None:
-        """``req``'s newest snapshot replaces the one it held."""
-        with self._lock:
-            self._drop_snapshot_locked(req)
-            req.snap = (entry, tokens)
-
     def _finish_prefill(self, req: GenRequest, logits) -> None:
-        """Prompt is fully in the paged cache: sample the first token and
-        hand the slot to the decode batch (or, for an export request,
-        stage the block set for migration instead)."""
-        if self._bk > 1:
-            self._open_first_block(req)
-            return
-        tp = len(req.prompt)
-        self._key, sub = jax.random.split(self._key)
-        tok0 = int(
-            self._sample(
-                sub, logits[None, :], jnp.asarray([req.temperature], jnp.float32)
-            )[0]
-        )
-        if req.export_mig_id is not None:
-            self._export_staged(req, tok0)
-            return
-        req.generated = [tok0]
-        self._note_first_token(req)
-        req.emit(tok0)
+        """Prompt is fully in the paged cache: sample the first token, where
+        one comes from prefill, and hand the slot to the decode batch (or,
+        for an export request, stage the block set for migration instead)."""
+        tok0 = None
+        if self._steps.first_from_prefill:
+            tok0 = self.runner.sample_first(logits, req.temperature)
+            if req.export_mig_id is not None:
+                self._export_staged(req, tok0)
+                return
+            req.generated = [tok0]
+            self._note_first_token(req)
+            req.emit(tok0)
         with self._lock:
-            slot = req.slot
-            self._slots[slot] = req
-            self._active[slot] = True
-            self._reserved[slot] = False
-            self._join_tok[slot] = tok0
-            self._pos[slot] = tp
-            self._temps[slot] = req.temperature
-        self._maybe_finish(req, tok0)
+            self._join_locked(req, tok0)
+        if tok0 is not None:
+            self._maybe_finish(req, tok0)
 
-    def _open_first_block(self, req: GenRequest) -> None:
-        """A diffusion config's prompt is in the paged cache as far as its
-        whole blocks go: hand the slot to the decode batch with the first
-        block opened, the prompt's tail as its known positions. No token
-        comes from prefill; the first ones come with the block's commit."""
-        fill = self._fill_len(req)
-        known = len(req.prompt) - fill
-        req.block_known = known
-        req.forwards_left = min(self._bk - known, req.denoising_steps) + 1
-        with self._lock:
-            slot = req.slot
-            self._slots[slot] = req
-            self._active[slot] = True
-            self._reserved[slot] = False
-            self._join["row"][slot] = True
-            self._join["known"][slot] = known
-            self._join["toks"][slot, :known] = req.prompt[fill:]
-            self._join["steps"][slot] = req.denoising_steps
-            self._pos[slot] = fill
-            self._temps[slot] = req.temperature
-
-    def _join_arrays(self):
-        """Copies of the join mirrors for the device (a transfer may read its
-        host buffer after the call returns, and the mirrors change at once)."""
-        return {k: jnp.asarray(v.copy()) for k, v in self._join.items()}
+    def _join_locked(self, req: GenRequest, tok0: Optional[int]) -> None:
+        """``req``'s slot joins the decode batch, as the runner's steps have a row join."""
+        slot = req.slot
+        self._slots[slot] = req
+        self._active[slot] = True
+        self._reserved[slot] = False
+        self._pos[slot] = self._steps.join_row(req, slot, tok0)
+        self._temps[slot] = req.temperature
 
     def _adopt_admitted(self, req: GenRequest, *, had_cow: bool) -> None:
         """Activate an admitted IMPORT request: write the pulled block
@@ -1858,46 +1146,22 @@ class LLMEngine:
 
         ticket = req.import_ticket or {}
         mig_id = ticket.get("mig_id", "?")
-        tp = len(req.prompt)
-        bs = self.kv_block_size
-        n_blocks = -(-tp // bs)
         if not had_cow:
-            # prefill_pos = matched tokens (a multiple of block_size);
             # with a full-hit COW every prompt position is already paged
             # in, so there is nothing to write at all
-            writes = []
-            for bidx in range(req.prefill_pos // bs, n_blocks):
-                arr = (req.import_arrays or {}).get(bidx)
-                if arr is None:
-                    self._fail_admit(req, KVMigrationError(
-                        mig_id, "pulled",
-                        f"block {bidx} neither locally cached nor pulled "
-                        f"(local prefix match shrank to {req.prefill_pos} "
-                        "tokens after the probe)",
-                    ))
-                    return
-                writes.append(
-                    (arr, int(self._block_tables[req.slot, bidx]))
-                )
-            if writes:
-                bucket = 1
-                while bucket < len(writes):
-                    bucket *= 2
-                while len(writes) < bucket:  # idempotent scatter pad
-                    writes.append(writes[-1])
-                try:
-                    # host-side stack: jnp.stack dispatches an expand_dims
-                    # per block (~1.5ms for a long prompt's 32); np views
-                    # of CPU-backend arrays memcpy in ~80µs, and the jit
-                    # boundary ships one contiguous buffer
-                    self._cache = self._write_blocks(
-                        self._cache,
-                        np.stack([np.asarray(a) for a, _ in writes]),
-                        np.asarray([p for _, p in writes], np.int32),
-                    )
-                except BaseException as exc:  # noqa: BLE001
-                    self._fail_admit(req, exc)
-                    return
+            try:
+                missing = self.store.land_migrated(req)
+            except BaseException as exc:  # noqa: BLE001
+                self._fail_admit(req, exc)
+                return
+            if missing >= 0:
+                self._fail_admit(req, KVMigrationError(
+                    mig_id, "pulled",
+                    f"block {missing} neither locally cached nor pulled "
+                    f"(local prefix match shrank to {req.prefill_pos} "
+                    "tokens after the probe)",
+                ))
+                return
         tok0 = int(ticket.get("tok0", 0))
         req.generated = [tok0]
         now = time.perf_counter()
@@ -1908,13 +1172,7 @@ class LLMEngine:
             req.trace.mark("kv_migrate")
         req.emit(tok0)
         with self._lock:
-            slot = req.slot
-            self._slots[slot] = req
-            self._active[slot] = True
-            self._reserved[slot] = False
-            self._join_tok[slot] = tok0
-            self._pos[slot] = tp
-            self._temps[slot] = req.temperature
+            self._join_locked(req, tok0)
             self.num_migrations_in += 1
         self._maybe_finish(req, tok0)
 
@@ -1936,12 +1194,7 @@ class LLMEngine:
         self._note_first_token(req)
         # engine-thread-only cache reads: the exported blocks are NEW
         # buffers, so the copies survive later donated steps
-        arrays = []
-        for bidx in range(n_blocks):
-            page = int(self._block_tables[req.slot, bidx])
-            arrays.append(self._export_page(self._cache, page))
-        if arrays:
-            jax.block_until_ready(arrays[-1])
+        arrays = self.store.export_pages(req)
         transfer_addr = device_plane.transfer_address()
         if transfer_addr is not None:
             for bidx, arr in enumerate(arrays):
@@ -1964,17 +1217,18 @@ class LLMEngine:
             # pool pages retire into the prefix cache NOW (cached tokens =
             # the prompt: tok0 was sampled, never written back) — the one
             # free of the migrated block set on this replica
-            evicted_n = self._retire_blocks_locked(req)
-            self._staged[mig_id] = {
+            self._reserved[req.slot] = False
+            evicted_n = self.store.retire_locked(req)
+            self.store.staged[mig_id] = {
                 "arrays": arrays,
                 "prompt": list(req.prompt),
                 "n_blocks": n_blocks,
             }
             self.num_migrations_out += 1
-            gauges = self._pool_gauges_locked()
+            gauges = self.store.pool_gauges_locked()
         if evicted_n:
             metric_defs.LLM_PREFIX_EVICTIONS.inc(evicted_n)
-        self._publish_pool_gauges(*gauges)
+        self.store.publish_pool_gauges(*gauges)
         ticket = disagg.make_ticket(
             mig_id,
             prompt=req.prompt,
@@ -2000,110 +1254,15 @@ class LLMEngine:
             req.stream_queue.put(_STREAM_END)
         if req.slot >= 0:
             with self._lock:
-                self._release_blocks_locked(req.slot)
-                self._drop_snapshot_locked(req)
-                gauges = self._pool_gauges_locked()
-            self._publish_pool_gauges(*gauges)
-        if next(iter(self._cache.values())).is_deleted():
+                self._reserved[req.slot] = False
+                self.store.release_locked(req.slot, req)
+                gauges = self.store.pool_gauges_locked()
+            self.store.publish_pool_gauges(*gauges)
+        if self.runner.cache_lost():
             # a donated chunk or page write consumed the cache then failed: the
             # shared cache is gone, taking every in-flight slot with it
             self._fail_inflight(RuntimeError(f"cache lost in failed prefill: {exc!r}"))
             self._reset_cache()
-
-    def _release_blocks_locked(self, slot: int) -> None:
-        """Drop a slot's page references (a request holds exactly ONE per
-        block-table entry, shared or not, so every release path — finish,
-        shed, evict, crash — is this same free). Caller holds ``self._lock``.
-
-        A decode step may still be in flight for this slot (an EOS is read
-        one step late, a cancel whenever it comes). Freeing under it is
-        sound: that step writes the row's K/V at positions >= ``pos``, in
-        pages only this request could write (``_cow_shared_writes``) and
-        that ``_retire_blocks_locked`` never publishes; whoever is given the
-        pages next enqueues its writes later, the device runs programs in
-        the order they were enqueued, and no one reads a position of its
-        page before writing it. The row's tokens are dropped at
-        ``_collect`` by the request's identity, never through the slot."""
-        blocks = self._slot_blocks[slot]
-        self._slot_blocks[slot] = []
-        self._block_tables[slot, :] = 0
-        self._reserved[slot] = False
-        if blocks:
-            self._allocator.free(blocks)
-
-    def _retire_blocks_locked(self, req: GenRequest) -> int:
-        """Finish path: publish the request's full KV blocks into the prefix
-        cache (the request's reference TRANSFERS to the cache for newly
-        adopted nodes) and free everything else. Returns the number of
-        pages LRU-evicted to respect ``prefix_cache_max_blocks``. Caller
-        holds ``self._lock``."""
-        slot = req.slot
-        blocks = self._slot_blocks[slot]
-        self._slot_blocks[slot] = []
-        self._block_tables[slot, :] = 0
-        self._reserved[slot] = False
-        if not blocks or self._prefix is None:
-            self._drop_snapshot_locked(req)
-            if blocks:
-                self._allocator.free(blocks)
-            return 0
-        # the last sampled token was never written back to the KV cache;
-        # every token before it was — cache exactly those full blocks. (A
-        # step in flight past an EOS writes position len(cached) and up:
-        # in no full block of ``cached``, so never in a published page)
-        # A diffusion config committed every token it emitted; what its last
-        # block holds past them was dropped, so that block's page is not full
-        cached = req.prompt + (req.generated if self._bk > 1 else req.generated[:-1])
-        bs = self.kv_block_size
-        keys = tuple(chain_keys(cached, len(cached) // bs, bs, req.block_keys))  # the reply's blocks behind the prompt's
-        adopted, evicted = self._prefix.insert(cached, blocks, self._evictable, keys)
-        if req.snap is not None:
-            # the state after exactly ``tokens`` of ``cached`` goes to the node that ends them;
-            # where that node is not cached, or has one already, the entry is free again
-            entry, tokens = req.snap
-            if tokens <= len(cached) and self._prefix.attach_snapshot(cached, tokens, entry, keys):
-                req.snap = None
-            self._drop_snapshot_locked(req)
-        self._reclaim_snapshots_locked()
-        if evicted:
-            self._allocator.free(evicted)
-        rest = [b for b in blocks if b not in adopted]
-        if rest:
-            self._allocator.free(rest)
-        return len(evicted)
-
-    def _cow_shared_writes(self, slot: int, start: int, n: int) -> None:
-        """Copy-on-write guard for the position range ``[start, start+n)``
-        of ``slot``: any page the write would touch that is still shared
-        (refcount > 1) is replaced by a freshly allocated copy and the
-        block-table entry swapped, so shared pages are only ever READ.
-        By construction the admission path never maps a to-be-written block
-        to a shared page, so this is an invariant net, not a hot path."""
-        if n < 1:
-            return
-        bs = self.kv_block_size
-        lo = max(0, start // bs)
-        # decode overshoot past the table scatters into page 0 — no COW
-        hi = min((start + n - 1) // bs, self.max_blocks_per_slot - 1)
-        for bidx in range(lo, hi + 1):
-            with self._lock:
-                old = int(self._block_tables[slot, bidx])
-                if old == 0 or self._allocator.refcount(old) <= 1:
-                    continue
-                if self._allocator.free_blocks < 1 and self._prefix is not None:
-                    evicted = self._evict_pages_locked(1)
-                    if evicted:
-                        self._allocator.free(evicted)
-                new = self._allocator.alloc(1)[0]  # typed shed if truly none
-            # the old page holds >= 2 refs (ours included) so it cannot be
-            # reallocated while the device copy reads it
-            self._cache = self._copy_page(self._cache, jnp.int32(old), jnp.int32(new))
-            with self._lock:
-                bl = self._slot_blocks[slot]
-                bl[bl.index(old)] = new
-                self._block_tables[slot, bidx] = new
-                self._allocator.free([old])
-                self._cow_count += 1
 
     def _prefill_enqueue(self):
         """Enqueue one chunk of the head prefilling request and return what
@@ -2117,8 +1276,8 @@ class LLMEngine:
         with self._lock:
             while self._prefilling and self._prefilling[0].cancelled:
                 req = self._prefilling.pop(0)
-                self._release_blocks_locked(req.slot)
-                self._drop_snapshot_locked(req)
+                self._reserved[req.slot] = False
+                self.store.release_locked(req.slot, req)
                 self.num_shed += 1
                 admission.record_shed("engine", "disconnect")
                 self._record_done(
@@ -2133,8 +1292,8 @@ class LLMEngine:
             if not self._prefilling:
                 return None
             req = self._prefilling[0]
-            gauges = self._pool_gauges_locked()
-        self._publish_pool_gauges(*gauges)
+            gauges = self.store.pool_gauges_locked()
+        self.store.publish_pool_gauges(*gauges)
         tp = self._fill_len(req)
         start = req.prefill_pos
         chunk = self.prefill_chunk_tokens
@@ -2145,7 +1304,7 @@ class LLMEngine:
         # a config with linear layers: a snapshot after the prompt's whole pages
         # needs a chunk that ends there (what is left of the prompt, under a
         # page, is then a chunk of its own)
-        snap_at = self._snapshot_after_prompt(req) if self._hybrid else 0
+        snap_at = self.store.snapshot_after_prompt(req)
         if start < snap_at < start + n:
             n = snap_at - start
         if start < req.branch_at < start + n:
@@ -2157,21 +1316,17 @@ class LLMEngine:
             # invariant net: admission never maps a to-be-written block to a
             # shared page (the full-hit tail is COW'd eagerly), but writes
             # must still never land on refcount > 1 pages
-            self._cow_shared_writes(req.slot, start, n)
+            self.store.cow_shared_writes(req.slot, start, n)
             # a copy of the row: the transfer may read (or alias) the host
             # buffer after the call returns, and the mirror is rewritten at
             # will before the chunk has been waited for
-            bt = jnp.asarray(self._block_tables[req.slot : req.slot + 1].copy())
-            slot = (jnp.asarray([req.slot], jnp.int32),) if self._hybrid else ()
-            logits, self._cache, *moe = self._prefill_chunk(
-                self.params, self._cache, jnp.asarray(toks), bt,
-                jnp.int32(start), jnp.int32(n), *slot,
-            )
+            logits, moe = self.runner.prefill_chunk(
+                toks, self.store.block_tables[req.slot : req.slot + 1].copy(), start, n, req.slot)
             if snap_at and start + n == snap_at:
-                for taken in self._take_snapshots([(req, snap_at)]):
-                    self._keep_snapshot(*taken)
+                for taken in self.store.take_snapshots([(req, snap_at)]):
+                    self.store.keep_snapshot(*taken)
             elif req.branch_at and start + n == req.branch_at:
-                self._snapshot_branch(req)
+                self.store.snapshot_branch(req)
         except BaseException as exc:  # noqa: BLE001
             with self._lock:
                 self._prefilling.pop(0)
@@ -2202,8 +1357,8 @@ class LLMEngine:
         metric_defs.LLM_PREFILL_CHUNKS.inc()
         if req.trace is not None:
             req.trace.note_prefill_chunk()
-        visited = self._chunk_kv_visited(req.prefill_pos, n)
-        capacity = self.max_blocks_per_slot * self.kv_block_size
+        visited = self.store.chunk_kv_visited(req.prefill_pos, n)
+        capacity = self.store.max_blocks_per_slot * self.kv_block_size
         metric_defs.LLM_PREFILL_KV_VISITED.inc(visited)
         metric_defs.LLM_PREFILL_KV_CAPACITY.inc(capacity)
         with self._lock:
@@ -2241,14 +1396,6 @@ class LLMEngine:
         if decode:
             self._moe_experts_hit_decode += hit
 
-    def _chunk_kv_visited(self, start: int, n: int) -> float:
-        """Cached tokens the attention of a chunk of ``n`` tokens at
-        ``start`` has to visit in a layer, averaged over the layers: all
-        ``start + n`` in a full layer, less those below its first query's
-        window in a sliding one."""
-        seen = sum(count * (start + n - (max(0, start - w + 1) if w else 0)) for w, count in self._layers_by_window)
-        return seen / self.cfg.n_layers
-
     def kv_read_share(self) -> float:
         """Of the cached tokens of the live sequences, the share a decode
         step must read: a sliding layer sees the last ``min(len, window)`` of
@@ -2271,11 +1418,12 @@ class LLMEngine:
         from the page of its window's first position on. What the paged
         decode kernel's work follows."""
         bs = self.kv_block_size
-        lens = self._pos[self._active].astype(np.int64) + self._bk  # to the end of the step's writes
+        cfg = self.cfg
+        lens = self._pos[self._active].astype(np.int64) + cfg.block  # to the end of the step's writes
         last = -(-lens // bs)
-        if self._hybrid:  # the full (or latent) layers walk every page, the linear layers none
-            return self._attn_layers * int(last.sum()) / self.cfg.n_layers
-        windows = self.cfg.layer_windows or (0,)
+        if cfg.hybrid:  # the full (or latent) layers walk every page, the linear layers none
+            return (cfg.kv_layers + cfg.latent_layers) * int(last.sum()) / cfg.n_layers
+        windows = cfg.layer_windows or (0,)
         visited = sum(int((last - np.maximum(lens - w, 0) // bs).sum()) if w else int(last.sum()) for w in windows)
         return visited / len(windows)
 
@@ -2287,11 +1435,12 @@ class LLMEngine:
             with self._lock:
                 self._active[req.slot] = False
                 self._slots[req.slot] = None
-                evicted_n = self._retire_blocks_locked(req)
-                gauges = self._pool_gauges_locked()
+                self._reserved[req.slot] = False
+                evicted_n = self.store.retire_locked(req)
+                gauges = self.store.pool_gauges_locked()
             if evicted_n:
                 metric_defs.LLM_PREFIX_EVICTIONS.inc(evicted_n)
-            self._publish_pool_gauges(*gauges)
+            self.store.publish_pool_gauges(*gauges)
             self._record_done(req, "finish")
             req.future.set_result(req.generated)
             if req.stream_queue is not None:
@@ -2312,18 +1461,17 @@ class LLMEngine:
         self._dispatches[kind] += 1
 
     def _dispatch(self, behind_chunk: bool) -> Optional[_Flight]:
-        """Enqueue one decode step (``decode_chunk`` tokens a row) for every
-        row still owed a token and return its handle unread; None if there is
+        """Enqueue one decode step (what one is: ``runner.steps``) for every
+        row that still owes one and return its handle unread; None if there is
         no such row. The host knows everything the step needs ahead of the
-        device but the rows' last tokens, and those the program takes from
-        its own previous run (``_dev_toks``) or, for a row that joined since,
-        from ``_join_tok``. Positions and the ``max_tokens`` count advance
-        here, so the next step can be dispatched before this one is read.
+        device but the rows' last tokens (a diffusion config: their blocks),
+        and those the program takes from its own previous run or, for a row
+        that joined since, from the steps' join mirrors. Positions and the
+        ``max_tokens`` count advance here, so the next step can be dispatched
+        before this one is read.
         ``behind_chunk``: this iteration enqueued a prefill chunk ahead."""
         self._clock.lap("dispatch_rows")
-        if self._bk > 1:
-            return self._dispatch_blocks(behind_chunk)
-        K = self.decode_chunk
+        steps, store = self._steps, self.store
         rows: List[Tuple[int, GenRequest]] = []
         live = np.zeros(self.B, bool)
         # rt-lint: disable=lock-discipline -- engine-thread-owned: every
@@ -2331,12 +1479,12 @@ class LLMEngine:
         # same engine loop thread; _lock exists for cross-thread READERS
         # (stats, abandon flags), not for us
         for i, req in enumerate(self._slots):
-            if req is None or req.dispatched >= req.max_tokens - 1:
-                continue  # free, or its last tokens are in flight: known by count
-            # copy-on-write net: the step writes positions [pos, pos + K) —
-            # if any of those blocks still maps to a shared page, give the
-            # slot its own copy before stepping
-            self._cow_shared_writes(i, int(self._pos[i]), K)
+            if req is None or req.dispatched >= req.max_tokens - steps.unwritten:
+                continue  # free, or the last it owes is in flight: known by count
+            # copy-on-write net: the step writes ``span`` positions from pos (a
+            # block's tentative or final) — if any of those blocks still maps to a
+            # shared page, give the slot its own copy before stepping
+            store.cow_shared_writes(i, int(self._pos[i]), steps.span)
             rows.append((i, req))
             live[i] = True
         if not rows:
@@ -2346,175 +1494,56 @@ class LLMEngine:
         # page 0, so freed pages are never written after release. The device
         # gets copies of the mirrors: a transfer may read (or alias) its host
         # buffer after the call returns, and the mirrors change right below
-        bt = jnp.asarray(self._block_tables * live[:, None].astype(np.int32))
-        join, pos, temps = jnp.asarray(self._join_tok.copy()), jnp.asarray(self._pos.copy()), jnp.asarray(self._temps.copy())
+        bt = jnp.asarray(store.block_tables * live[:, None].astype(np.int32))
+        join, pos, temps = steps.uploads(), jnp.asarray(self._pos.copy()), jnp.asarray(self._temps.copy())
         self._note_dispatch(behind_chunk)
-        out, self._cache, self._key, self._dev_toks, *moe = self._decode_k_paged(
-            self.params, self._cache, self._dev_toks, join, pos, temps, self._key, bt,
-        )
-        self._join_tok[:] = -1
-        snaps = self._take_snapshots(self._rows_ending_a_page(rows)) if self._hybrid else []
-        for i, req in rows:
-            req.dispatched += K
-            self._pos[i] += K
-        return _Flight(out, moe, rows, snaps=snaps)
-
-    def _rows_ending_a_page(self, rows: List[Tuple[int, GenRequest]]) -> List[Tuple[GenRequest, int]]:
-        """Of the rows of the decode step just enqueued (``_pos`` not yet
-        advanced), those whose state after it is to be snapshotted, each
-        with the tokens that state covers: the step writes position ``pos``
-        and that ends a page; a row with an EOS to wait for at every such
-        step, any other at the last one of its reply (the last position it
-        writes is known by count)."""
-        bs = self.kv_block_size
-        if not self._n_snapshots or self._prefix is None:
-            return []
-        out = []
-        for i, req in rows:
-            covered = int(self._pos[i]) + 1
-            if covered % bs:
-                continue
-            last_written = len(req.prompt) + req.max_tokens - 2
-            if req.eos_id is not None or covered + bs > last_written + 1:
-                out.append((req, covered))
-        return out
-
-    def _dispatch_blocks(self, behind_chunk: bool) -> Optional[_Flight]:
-        """:meth:`_dispatch` for a diffusion config: enqueue one block step
-        for every row that still owes a token. A row's schedule is known by
-        count (a block of ``m`` masked positions takes ``min(m, steps)``
-        denoise forwards, then the commit), so positions and the
-        ``max_tokens`` count advance here, at the commit's dispatch, ahead of
-        the device; which rows committed is read back with the tokens."""
-        Bk = self._bk
-        rows: List[Tuple[int, GenRequest]] = []
-        live = np.zeros(self.B, bool)
-        # rt-lint: disable=lock-discipline -- engine-thread-owned (see _dispatch)
-        for i, req in enumerate(self._slots):
-            if req is None or req.dispatched >= req.max_tokens:
-                continue  # free, or its last commit is in flight: known by count
-            # the step writes the block's positions, tentative or final, into
-            # pages only this row holds: copy-on-write before the first write
-            self._cow_shared_writes(i, int(self._pos[i]), Bk)
-            rows.append((i, req))
-            live[i] = True
-        if not rows:
-            return None
-        self._clock.lap("dispatch_enqueue")
-        bt = jnp.asarray(self._block_tables * live[:, None].astype(np.int32))
-        join, pos, temps = self._join_arrays(), jnp.asarray(self._pos.copy()), jnp.asarray(self._temps.copy())
-        self._note_dispatch(behind_chunk)
-        done, self._cache, self._key, self._dev_toks, *moe = self._decode_k_paged(
-            self.params, self._cache, self._dev_toks, join, pos, temps, self._key, bt,
-        )
-        self._join["row"][:] = False
-        commits: Dict[int, int] = {}
-        for i, req in rows:
-            req.forwards_left -= 1
-            if req.forwards_left == 0:  # this step commits the row's block; the next opens all masked
-                commits[i] = req.block_known
-                req.dispatched += Bk - req.block_known
-                self._pos[i] += Bk
-                req.block_known = 0
-                req.forwards_left = min(Bk, req.denoising_steps) + 1
-        return _Flight(done, moe, rows, commits=commits)
-
-    def _collect_blocks(self, flight: _Flight) -> None:
-        """:meth:`_collect` for a diffusion config: a row yields nothing on a
-        denoise step and its block's new tokens, as one stream event, on a
-        commit (cut at ``max_tokens`` or after an EOS: what the block holds
-        beyond is dropped)."""
-        done = jax.device_get(flight.out)
-        self._clock.lap("collect_counts")
-        self._decode_step_count += 1
-        self._note_moe(flight.moe, decode=True)
-        # rt-lint: disable=lock-discipline -- engine-thread-owned (see _dispatch)
-        rows = [(i, req) for i, req in flight.rows if self._slots[i] is req and not req.cancelled]
-        self._decode_row_steps_discarded += len(flight.rows) - len(rows)
-        self._block_row_forwards += len(rows)
-        self._clock.lap("emit")
-        for i, req in rows:
-            known = flight.commits.get(i)
-            if bool(done["committed"][i]) != (known is not None):
-                raise RuntimeError(f"block step out of step with its schedule in slot {i}: the device "
-                                   f"{'committed' if known is None else 'did not commit'} a block")
-            if known is None:
-                continue
-            toks = done["toks"][i, known:].tolist()[: req.max_tokens - len(req.generated)]
-            if req.eos_id is not None and req.eos_id in toks:
-                toks = toks[: toks.index(req.eos_id) + 1]
-            req.generated.extend(toks)
-            self._block_commits += 1
-            self._tokens_emitted += len(toks)
-            self._tokens_unmasked += self._bk - known
-            self._note_block(req, len(toks))
-            req.emit_block(toks, done["unmasked_at"][i, known : known + len(toks)].tolist())
-            self._maybe_finish(req, toks[-1])
+        out, moe = self.runner.step(join, pos, temps, bt)
+        steps.clear()
+        snaps = store.take_snapshots(store.rows_ending_a_page(rows, self._pos))
+        return _Flight(out, moe, rows, steps.advance(rows, self._pos), snaps)
 
     def _collect(self, flight: _Flight) -> None:
-        """Read a dispatched step's tokens (this waits for that step only,
-        not for one dispatched after it) and emit them. A row whose request
-        left its slot since the dispatch (an EOS read one step late, a
+        """Read a dispatched step (this waits for that step only, not for one
+        dispatched after it) and emit what it hands each row. A row whose
+        request left its slot since the dispatch (an EOS read one step late, a
         cancelled stream evicted) is dropped whole, by identity: the slot
         may be another request's by now."""
         self._clock.lap("collect_wait")
-        if self._bk > 1:
-            return self._collect_blocks(flight)
-        sampled = np.asarray(flight.out)  # [B, K]
+        steps = self._steps
+        host = steps.read(flight.out)
         self._clock.lap("collect_counts")
-        K = sampled.shape[1]
-        self._decode_step_count += K
+        self._decode_step_count += steps.count
         self._note_moe(flight.moe, decode=True)
         # rt-lint: disable=lock-discipline -- engine-thread-owned (see _dispatch)
         rows = [(i, req) for i, req in flight.rows if self._slots[i] is req and not req.cancelled]
-        self._decode_row_steps_discarded += (len(flight.rows) - len(rows)) * K
+        self._decode_row_steps_discarded += (len(flight.rows) - len(rows)) * steps.count
         for req, entry, tokens in flight.snaps:
             # taken behind this step: kept if the row's step is (before its request may finish
             # below and publish it); a discarded row-step's token is in its state, so it goes
             # rt-lint: disable=lock-discipline -- engine-thread-owned (see _dispatch)
             if self._slots[req.slot] is req and not req.cancelled:
-                self._keep_snapshot(req, entry, tokens)
+                self.store.keep_snapshot(req, entry, tokens)
             else:
-                with self._lock:
-                    self._snap_pool.free(entry)
+                self.store.free_snapshot(entry)
         self._clock.lap("emit")
-        for k in range(K):
-            for i, req in rows:
-                # rt-lint: disable=lock-discipline -- engine-thread-owned (see _dispatch)
-                if self._slots[i] is not req:
-                    continue  # finished earlier in this chunk
-                tok = int(sampled[i, k])
-                req.generated.append(tok)
+        for i, req, toks, unmasked_at in steps.handed(host, rows, flight.commits):
+            # rt-lint: disable=lock-discipline -- engine-thread-owned (see _dispatch)
+            if self._slots[i] is not req:
+                continue  # finished earlier in this chunk
+            req.generated.extend(toks)
+            if unmasked_at is None:  # a token, or a committed block as one stream event
                 self._note_next_token(req)
-                req.emit(tok)
-                self._maybe_finish(req, tok)
+                req.emit(toks[0])
+            else:
+                self._note_block(req, len(toks))
+                req.emit_block(toks, unmasked_at)
+            self._maybe_finish(req, toks[-1])
 
     def _reset_cache(self) -> None:
-        """(Re)allocate the decode cache — also the recovery path after a
+        """(Re)allocate the device state — also the recovery path after a
         failed donated step leaves the old buffers deleted."""
-        init = functools.partial(init_paged_cache, self.cfg, self.kv_num_blocks, self.kv_block_size,
-                                 **({"slots": self.B} if self._hybrid else {}))
-        if self._hybrid:
-            # the slots' states went with the cache: so do the snapshots of them
-            size = self._n_snapshots
-            with self._lock:
-                self._snap_pool = SnapshotPool(size)
-            self._snaps = init_sequence_state(self.cfg, size) if size else None
-            one = init_sequence_state(self.cfg, 1)
-            self._state_bytes_per_slot = int(sum(a.size * a.dtype.itemsize for a in one.values()))
-        if self._kv_sharding is not None:
-            # each device zeroes its own shard: the whole pool never lies on one
-            init = jax.jit(init, out_shardings=self._kv_sharding)
-        self._cache = init()
-        # bytes a cached token takes in the pools as built, all attention layers
-        pools = [self._cache[name] for name in page_pools(self._cache)]
-        self._kv_bytes_per_token = int(sum(a.shape[0] * a.shape[-1] * a.dtype.itemsize for a in pools))
-        # the rows' last tokens as the decode program last returned them:
-        # part of the same device state (a step that failed in flight leaves
-        # its outputs poisoned). No row reads it before joining from the host
-        self._dev_toks = jnp.zeros(self.B, jnp.int32)
-        if self._bk > 1:  # a diffusion config: the rows' blocks (models/generation.open_blocks)
-            self._dev_toks = open_blocks(self.cfg, jnp.ones(self.B, jnp.int32))
+        self.store.reset_snapshots()
+        self.runner.reset()
 
     def _fail_inflight(self, error: BaseException) -> None:
         """Fail every queued, prefilling, and in-slot request (loop-crash
@@ -2530,25 +1559,14 @@ class LLMEngine:
             self._queued_tokens = 0
             self._slots = [None] * self.B
             self._active[:] = False
-            for i in range(self.B):
-                self._release_blocks_locked(i)
-            if self._prefix is not None:
-                # the device pool is about to be re-initialized; cached
-                # page CONTENTS die with it, so the index must too —
-                # drop every node and its reference unconditionally
-                stale = self._prefix.drain()
-                if stale:
-                    self._allocator.free(stale)
-                self._prefix.take_freed_snapshots()  # ``_reset_cache`` makes the snapshot pool anew
-            for r in victims:
-                r.snap = None
+            self._reserved[:] = False
+            self.store.drop_all_locked(victims)
         # the step in flight goes with them: its rows' requests are victims
         # (engine-thread state, like the loop that dispatched it)
         self._flight = None
-        self._join_tok[:] = -1
-        self._join["row"][:] = False
+        self._steps.clear()
         metric_defs.ADMISSION_QUEUE_DEPTH.set(0, self._depth_tags)
-        self._publish_pool_gauges(0, 0, 0)
+        self.store.publish_pool_gauges(0, 0, 0)
         for r in victims:
             self._record_done(r, "crash", str(error))
             if not r.future.done():
@@ -2569,15 +1587,12 @@ class LLMEngine:
             for i, r in victims:
                 self._slots[i] = None
                 self._active[i] = False
-                self._release_blocks_locked(i)
-                self._drop_snapshot_locked(r)
-            if self._bk > 1:
-                # a row of a diffusion config always has a block under way:
-                # its tentative K/V go with its pages, which nothing shared
-                self._blocks_dropped += len(victims)
-            gauges = self._pool_gauges_locked()
+                self._reserved[i] = False
+                self.store.release_locked(i, r)
+            self._steps.dropped(len(victims))
+            gauges = self.store.pool_gauges_locked()
         if victims:
-            self._publish_pool_gauges(*gauges)
+            self.store.publish_pool_gauges(*gauges)
         for _, r in victims:
             self.num_slots_evicted += 1
             metric_defs.LLM_SLOTS_EVICTED.inc(tags=_EVICT_DISCONNECT_TAGS)
@@ -2778,10 +1793,10 @@ class LLMServer:
         return self.engine.stats()
 
     def state_snapshot(self, tokens: List[int]) -> Optional[Dict[str, Any]]:
-        return self.engine.state_snapshot(tokens)
+        return self.engine.store.state_snapshot(tokens)
 
     def lowered_decode_text(self) -> str:
-        return self.engine.lowered_decode_text()
+        return self.engine.runner.lowered_decode_text()
 
     # -- disaggregated prefill/decode (called by the router's dispatcher) --
     def disagg_prefill(self, request: Dict[str, Any], mig_id: str) -> dict:
@@ -2807,7 +1822,7 @@ class LLMServer:
         prompt = list(ticket["prompt"])
         bs = self.engine.kv_block_size
         n_blocks = int(ticket["n_blocks"])
-        matched = self.engine.peek_prefix_match(prompt)
+        matched = self.engine.store.peek_prefix_match(prompt)
         arrays: Dict[int, Any] = {}
         rung = "device"
         try:
@@ -2869,11 +1884,11 @@ class LLMServer:
     def disagg_release(self, mig_id: str) -> bool:
         """Drop a staged export (dispatcher calls exactly once per
         migration, whatever the outcome)."""
-        return self.engine.release_migration(mig_id)
+        return self.engine.store.release_migration(mig_id)
 
     def kv_free_blocks(self) -> int:
         """Decode-pool routing signal for the role-aware router."""
-        return self.engine.kv_free_blocks()
+        return self.engine.store.kv_free_blocks()
 
     def __del__(self):
         try:
@@ -2881,211 +1896,3 @@ class LLMServer:
         except Exception:
             pass
 
-
-class OpenAICompatLLMServer(LLMServer):
-    """OpenAI-compatible request/response adapter over :class:`LLMServer`.
-
-    Accepts the body shapes of ``POST /v1/completions`` (``model`` +
-    ``prompt``) and ``POST /v1/chat/completions`` (``model`` +
-    ``messages``) and answers in the matching OpenAI response envelopes,
-    including streaming chunk events over the proxy's SSE path.  Dispatch
-    is by body shape — the HTTP proxy routes whole apps by path prefix, so
-    one deployment serves both the native protocol and the OpenAI one.
-    (Beyond reference parity: the reference delegates OpenAI-compatible
-    LLM serving to vLLM.)
-
-    Text prompts/messages need the model_factory to supply a tokenizer;
-    token-id prompts work without one.  ``stop`` supports a single token id
-    (honored in-engine as eos) or, with a tokenizer, a string trimmed from
-    the non-streaming response.
-    """
-
-    def __call__(self, request: Any):
-        if isinstance(request, dict) and ("messages" in request or "model" in request):
-            return self._openai(request)
-        return super().__call__(request)
-
-    # ------------------------------------------------------------- openai
-    def _openai(self, body: Dict[str, Any]):
-        import uuid
-
-        self._reject_unsupported(body)
-        chat = "messages" in body
-        prompt_ids = self._openai_prompt(body, chat)
-        stop = body.get("stop")
-        eos_id = None
-        stop_text = None
-        if isinstance(stop, int):
-            eos_id = stop
-        elif isinstance(stop, str):
-            if self.tokenizer is not None:
-                enc = self.tokenizer.encode(stop)
-                if len(enc) == 1:
-                    eos_id = enc[0]
-                else:
-                    stop_text = stop
-            else:
-                raise ValueError("string stop requires a tokenizer")
-        elif isinstance(stop, list) and len(stop) == 1:
-            return self._openai({**body, "stop": stop[0]})
-        elif stop is not None:
-            raise ValueError("stop: a single token id or string is supported")
-
-        kw = dict(
-            max_tokens=int(body.get("max_tokens", 16)),
-            # OpenAI semantics: absent temperature means 1.0 (sampling) —
-            # defaulting to greedy here would silently answer a different
-            # distribution than every OpenAI SDK client expects
-            temperature=float(body.get("temperature", 1.0)),
-            eos_id=eos_id,
-        )
-        rid = ("chatcmpl-" if chat else "cmpl-") + uuid.uuid4().hex[:24]
-        model = body.get("model", "ray_tpu")
-        created = int(time.time())
-        obj = "chat.completion" if chat else "text_completion"
-
-        if body.get("stream"):
-            if stop_text is not None:
-                raise ValueError(
-                    "streaming with a multi-token stop string is not "
-                    "supported — use a stop that encodes to one token"
-                )
-            stream = self.engine.submit_stream(prompt_ids, **kw)
-
-            def chunks():
-                reason = "length"
-                for tok in stream:
-                    if eos_id is not None and tok == eos_id:
-                        # OpenAI semantics: the stop sequence is excluded
-                        # from the streamed output
-                        reason = "stop"
-                        continue  # engine ends the stream after eos
-                    piece = (
-                        self.tokenizer.decode([tok])
-                        if self.tokenizer is not None
-                        else None
-                    )
-                    delta = (
-                        {"delta": {"content": piece}, "index": 0, "finish_reason": None}
-                        if chat
-                        else {"text": piece, "token_ids": [tok], "index": 0,
-                              "finish_reason": None}
-                    )
-                    yield {"id": rid, "object": obj + ".chunk", "created": created,
-                           "model": model, "choices": [delta]}
-                final = (
-                    {"delta": {}, "index": 0, "finish_reason": reason}
-                    if chat
-                    else {"text": "", "index": 0, "finish_reason": reason}
-                )
-                yield {"id": rid, "object": obj + ".chunk", "created": created,
-                       "model": model, "choices": [final]}
-
-            return chunks()
-
-        out = self.engine.generate(prompt_ids, **kw)
-        finish = "stop" if (eos_id is not None and out and out[-1] == eos_id) else "length"
-        if finish == "stop":
-            out = out[:-1]  # OpenAI semantics: stop sequence excluded
-        text = self.tokenizer.decode(out) if self.tokenizer is not None else None
-        if text is not None and stop_text and stop_text in text:
-            # trim at TOKEN granularity so token_ids stay faithful to what
-            # the model generated (re-encoding trimmed text could produce
-            # ids the model never emitted): keep the longest generated
-            # prefix whose decode does not yet contain the stop text, and
-            # derive text from it so decode(token_ids) == text
-            # contains-stop is monotone in the prefix length, so binary
-            # search the cut (a linear scan would decode O(n) prefixes on
-            # the serving hot path when the stop lands early)
-            lo, hi = 0, len(out)  # invariant: decode(out[:lo]) lacks stop
-            while lo < hi:
-                mid = (lo + hi + 1) // 2
-                if stop_text in self.tokenizer.decode(out[:mid]):
-                    hi = mid - 1
-                else:
-                    lo = mid
-            out = out[:lo]
-            text = self.tokenizer.decode(out)
-            finish = "stop"
-        choice: Dict[str, Any] = {"index": 0, "finish_reason": finish, "token_ids": out}
-        if chat:
-            choice["message"] = {"role": "assistant", "content": text}
-        else:
-            choice["text"] = text
-        return {
-            "id": rid,
-            "object": obj,
-            "created": created,
-            "model": model,
-            "choices": [choice],
-            "usage": {
-                "prompt_tokens": len(prompt_ids),
-                "completion_tokens": len(out),
-                "total_tokens": len(prompt_ids) + len(out),
-            },
-        }
-
-    def _reject_unsupported(self, body: Dict[str, Any]) -> None:
-        """Unimplemented OpenAI sampling params fail loudly — silently
-        ignoring them would return samples the client didn't ask for.
-        Values matching OpenAI defaults (top_p=1, n=1, zero penalties)
-        pass, since SDKs send those unprompted."""
-        bad = []
-        top_p = body.get("top_p")
-        if top_p is not None and top_p < 1.0:
-            # sampling config is per-ENGINE: a request may restate the
-            # engine's own top_p, but asking for a different distribution
-            # must not be silently overridden.  top_p=1.0 always passes —
-            # SDKs send the OpenAI default unprompted.
-            eng_p = self.engine.top_p
-            if eng_p is None or abs(float(top_p) - float(eng_p)) > 1e-9:
-                bad.append(
-                    f"top_p={top_p} (engine is configured with "
-                    f"top_p={eng_p}; per-request nucleus sampling is not "
-                    "supported — configure it on the deployment)"
-                )
-        if body.get("n", 1) not in (None, 1):
-            bad.append("n > 1")
-        if body.get("best_of", 1) not in (None, 1):
-            bad.append("best_of > 1")
-        lp = body.get("logprobs")
-        if lp is not None and lp is not False:  # NOT `in (None, False)`: 0 == False
-            bad.append("logprobs")
-        for k in ("presence_penalty", "frequency_penalty"):
-            if body.get(k):
-                bad.append(k)
-        if body.get("echo"):
-            bad.append("echo")
-        if self.engine.cfg.block > 1:
-            # generation by diffusion over blocks: a token comes from a
-            # confidence schedule over several forwards of its block, not from
-            # one next-token distribution, so nothing that rests on that
-            # distribution can be honoured, now or by a later sampler: say so
-            why = (f" (the model generates by diffusion over blocks of {self.engine.cfg.block}: "
-                   "no next-token distribution a position)")
-            bad = [b + why if b in ("logprobs", "n > 1", "best_of > 1") else b for b in bad]
-            bad += [k + why for k in ("top_logprobs", "logit_bias") if body.get(k)]
-        if bad:
-            raise ValueError(
-                "unsupported OpenAI parameter(s): " + ", ".join(bad)
-            )
-
-    def _openai_prompt(self, body: Dict[str, Any], chat: bool) -> List[int]:
-        if chat:
-            messages = body["messages"]
-            if self.tokenizer is None:
-                raise ValueError("chat completions require a tokenizer")
-            template = getattr(self.tokenizer, "apply_chat_template", None)
-            if template is not None:
-                ids = template(messages, add_generation_prompt=True)
-                return list(ids)
-            joined = "\n".join(f"{m['role']}: {m['content']}" for m in messages)
-            return list(self.tokenizer.encode(joined + "\nassistant:"))
-        prompt = body.get("prompt")
-        if isinstance(prompt, str):
-            if self.tokenizer is None:
-                raise ValueError("string prompts require a tokenizer")
-            return list(self.tokenizer.encode(prompt))
-        if isinstance(prompt, list) and all(isinstance(t, int) for t in prompt):
-            return prompt
-        raise ValueError("prompt must be a string or a list of token ids")

@@ -22,7 +22,8 @@ from benchmark.models import sdar_moe  # noqa: E402
 from ray_tpu.models.generation import (init_paged_cache, open_blocks, paged_block_step,  # noqa: E402
                                        paged_forward_counted, select_rows, unmask_step)
 from ray_tpu.models.transformer import TransformerConfig, forward, init_params  # noqa: E402
-from ray_tpu.serve.llm import LLMEngine, OpenAICompatLLMServer, TokenBlock  # noqa: E402
+from ray_tpu.serve.llm import LLMEngine, TokenBlock  # noqa: E402
+from ray_tpu.serve.openai_compat import OpenAICompatLLMServer  # noqa: E402
 
 BK, MASK = 4, 500
 C = dict(hidden_size=64, intermediate_size=96, moe_intermediate_size=48, num_attention_heads=4,
@@ -416,11 +417,11 @@ def test_a_causal_configs_programs_do_not_know_about_blocks(params):
         eng = LLMEngine(cfg, params, max_batch_size=2, max_seq_len=64, prefill_chunk_tokens=CHUNK)
         try:
             abstract = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype)  # noqa: E731
-            p, cache = jax.tree.map(abstract, (eng.params, eng._cache))
+            p, cache = jax.tree.map(abstract, (eng.runner.params, eng.runner.cache))
             i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
-            chunk = eng._prefill_chunk.lower(p, cache, i32(1, CHUNK), i32(1, 4), i32(), i32()).as_text()
-            texts.append((eng.lowered_decode_text(), chunk))
-            assert eng._dev_toks.shape == (2,) and "block_steps" not in eng.stats()
+            chunk = eng.runner._prefill_chunk.lower(p, cache, i32(1, CHUNK), i32(1, 4), i32(), i32()).as_text()
+            texts.append((eng.runner.lowered_decode_text(), chunk))
+            assert eng.runner.dev_toks.shape == (2,) and "block_steps" not in eng.stats()
         finally:
             eng.shutdown()
     assert texts[0] == texts[1]

@@ -81,7 +81,7 @@ def _assert_no_leak(eng):
     accounted for by the prefix cache, and flushing it empties the pool."""
     st = eng.stats()
     assert st["kv_blocks_in_use"] == st["prefix_cache_blocks"]
-    eng.flush_prefix_cache()
+    eng.store.flush_prefix_cache()
     st = eng.stats()
     assert st["kv_blocks_in_use"] == 0 and st["prefix_cache_blocks"] == 0
 
@@ -93,7 +93,7 @@ def _migrate(p_eng, d_eng, prompt, mig_id, max_tokens=6):
     actually pulled (empty on a full decode-side prefix hit)."""
     ticket = p_eng.prefill_export(prompt, mig_id=mig_id).result(timeout=120)
     bs = d_eng.kv_block_size
-    matched = d_eng.peek_prefix_match(prompt)
+    matched = d_eng.store.peek_prefix_match(prompt)
     arrays, rungs = {}, []
     for bidx in range(matched // bs, int(ticket["n_blocks"])):
         arr, rung = disagg.pull_block(ticket, bidx)
@@ -141,7 +141,7 @@ def test_ticket_is_header_only(params):
         assert ticket["tok0"] == _reference(params, prompt, 1)[0]
         # header-only really means header-only: a few hundred bytes
         assert len(json.dumps(ticket)) < 2048
-        assert p_eng.release_migration("t/hdr")
+        assert p_eng.store.release_migration("t/hdr")
     finally:
         p_eng.shutdown()
 
@@ -160,8 +160,8 @@ def test_migration_bit_identical(params):
         assert st["migrations_out"] == 1 and st["staged_migrations"] == 1
         assert d_eng.stats()["migrations_in"] == 1
         # exactly-once: release drops the staging, the second is a no-op
-        assert p_eng.release_migration("t/ident") is True
-        assert p_eng.release_migration("t/ident") is False
+        assert p_eng.store.release_migration("t/ident") is True
+        assert p_eng.store.release_migration("t/ident") is False
         _wait(lambda: d_eng.stats()["active_slots"] == 0)
         _assert_no_leak(p_eng)
         _assert_no_leak(d_eng)
@@ -179,18 +179,18 @@ def test_warm_decode_prefix_short_circuits_re_migration(params):
         _, out1, rungs1 = _migrate(p_eng, d_eng, prompt, "t/warm1")
         assert out1 == ref
         assert len(rungs1) == 2  # cold decode side: every block pulled
-        assert p_eng.release_migration("t/warm1")
+        assert p_eng.store.release_migration("t/warm1")
         _wait(lambda: d_eng.stats()["active_slots"] == 0)
 
         # migrated blocks landed in the DECODE replica's prefix cache
-        assert d_eng.peek_prefix_match(prompt) == 32
+        assert d_eng.store.peek_prefix_match(prompt) == 32
 
         # same prompt again: full prefix hit, ZERO blocks re-migrated,
         # tokens still bit-for-bit
         _, out2, rungs2 = _migrate(p_eng, d_eng, prompt, "t/warm2")
         assert out2 == ref
         assert rungs2 == []
-        assert p_eng.release_migration("t/warm2")
+        assert p_eng.store.release_migration("t/warm2")
 
         # extended prompt: only the uncached suffix block crosses the wire
         prompt3 = prompt + [11, 12, 13, 14, 15, 16, 17, 18]  # 40 -> 3 blocks
@@ -199,7 +199,7 @@ def test_warm_decode_prefix_short_circuits_re_migration(params):
                                    max_tokens=5)
         assert out3 == ref3
         assert len(rungs3) == 1
-        assert p_eng.release_migration("t/warm3")
+        assert p_eng.store.release_migration("t/warm3")
 
         _wait(lambda: d_eng.stats()["active_slots"] == 0)
         _assert_no_leak(p_eng)
@@ -217,7 +217,7 @@ def test_released_staging_raises_typed_error(params):
     try:
         prompt = list(range(2, 21))
         ticket = p_eng.prefill_export(prompt, mig_id="t/gone").result(timeout=120)
-        assert p_eng.release_migration("t/gone")
+        assert p_eng.store.release_migration("t/gone")
         with pytest.raises(KVMigrationError) as exc:
             disagg.pull_block(ticket, 0)
         assert exc.value.mig_id == "t/gone"

@@ -71,7 +71,7 @@ def prompt_of(n, seed=0):
 def whole(eng):
     """Both pools' counts add up: pages in use are the cache's, snapshot entries held are the nodes'."""
     s = eng.stats()
-    return (s["kv_blocks_in_use"] == s["prefix_cache_blocks"] and s["state_snapshots_in_use"] == eng._prefix.snapshots
+    return (s["kv_blocks_in_use"] == s["prefix_cache_blocks"] and s["state_snapshots_in_use"] == eng.store.prefix.snapshots
             and s["active_slots"] == 0)
 
 
@@ -280,7 +280,7 @@ def test_a_follow_up_turn_from_a_restored_snapshot_is_the_cold_engines(params, r
         want = reference[0](params, jnp.asarray(turn2 + r2), jnp.arange(len(turn2) - 1, len(turn2) + 11))
         assert np.asarray(jnp.argmax(want, -1)).tolist() == r2
         assert settle(eng)
-        assert eng.flush_prefix_cache() > 0 and eng.stats()["state_snapshots_in_use"] == 0  # node freed -> snapshot freed
+        assert eng.store.flush_prefix_cache() > 0 and eng.stats()["state_snapshots_in_use"] == 0  # node freed -> snapshot freed
     finally:
         eng.shutdown()
 
@@ -294,8 +294,8 @@ def test_a_page_match_deeper_than_its_snapshot_skips_only_to_the_snapshot(params
         r1 = eng.generate(turn1, max_tokens=20)
         assert settle(eng)
         with eng._lock:  # the deeper snapshot goes, as when the pool is full; its pages stay
-            deep = max(eng._prefix._snap_nodes.values(), key=lambda nd: nd.seq)
-            eng._snap_pool.free(eng._prefix._detach(deep))
+            deep = max(eng.store.prefix._snap_nodes.values(), key=lambda nd: nd.seq)
+            eng.store.snap_pool.free(eng.store.prefix._detach(deep))
         before = eng.stats()
         turn2 = turn1 + r1 + prompt_of(9, 2)
         assert eng.generate(turn2, max_tokens=12) == cold.generate(turn2, max_tokens=12)
@@ -325,17 +325,17 @@ def test_a_shared_prefix_whose_lone_first_session_aged_its_snapshot_out_of_a_ful
         assert (r1, r2) == (cold.generate(turn1, max_tokens=20), cold.generate(turn2, max_tokens=12))
         assert settle(eng)
         # three entries, all held by nodes: the document's (aged), turn 1's (aged) and turn 2's
-        assert eng._prefix.snapshot_at(doc) == (eng._prefix.snapshot_at(doc)[0], 96) and eng._prefix.snapshots == 3
+        assert eng.store.prefix.snapshot_at(doc) == (eng.store.prefix.snapshot_at(doc)[0], 96) and eng.store.prefix.snapshots == 3
         other = prompt_of(40, 7)  # its snapshot needs an entry: the oldest goes, which is the document's
         eng.generate(other, max_tokens=1)
-        assert settle(eng) and eng._prefix.snapshot_at(doc) == (-1, 0)
+        assert settle(eng) and eng.store.prefix.snapshot_at(doc) == (-1, 0)
         before = eng.stats()
         a = doc + prompt_of(30, 3)
         assert eng.generate(a, max_tokens=20) == cold.generate(a, max_tokens=20)
         s = eng.stats()
         assert s["state_restores"] == before["state_restores"] and s["state_zeroed"] - before["state_zeroed"] == 1
         assert s["prefill_chunks"] - before["prefill_chunks"] == 4  # 32 + 32 + 32 to the document's end, then the rest
-        assert settle(eng) and eng._prefix.snapshot_at(doc)[1] == 96  # left at the branch, while the prompt was prefilled
+        assert settle(eng) and eng.store.prefix.snapshot_at(doc)[1] == 96  # left at the branch, while the prompt was prefilled
         before = eng.stats()
         b = doc + prompt_of(30, 4)
         assert eng.generate(b, max_tokens=20) == cold.generate(b, max_tokens=20)
@@ -360,7 +360,7 @@ def test_a_prefix_whose_first_reader_ran_on_past_it_gets_a_snapshot_from_the_sec
             assert eng.generate(q, max_tokens=8) == cold.generate(q, max_tokens=8)
             assert eng.stats()["state_restores"] - before == restores
             assert settle(eng)
-        assert eng._prefix.snapshot_at(doc)[1] == 96
+        assert eng.store.prefix.snapshot_at(doc)[1] == 96
     finally:
         eng.shutdown()
 
@@ -394,7 +394,7 @@ def test_a_discarded_row_step_never_reaches_a_snapshot(params, cold):
         assert st["decode_row_steps_discarded"] == 1
         # what was published: the prompt's two whole pages, the second with the snapshot taken during
         # prefill; nothing at 48 tokens, where the discarded step's state stood
-        carries = [nd.snapshot >= 0 for nd in eng._prefix._chain(prompt + out, 3)]
+        carries = [nd.snapshot >= 0 for nd in eng.store.prefix._chain(prompt + out, 3)]
         assert carries == [False, True] and st["state_snapshots_taken"] == 2 and st["state_snapshots_in_use"] == 1
         follow = prompt + out + prompt_of(11, 3)
         assert eng.generate(follow, max_tokens=6) == cold.generate(follow, max_tokens=6)
@@ -427,7 +427,7 @@ def test_snapshot_pool_exhaustion_and_eviction_fail_no_request_and_keep_the_coun
         assert eng.stats()["state_snapshots_in_use"] <= 1
     finally:
         eng.shutdown()
-    assert eng._allocator.used_blocks == len(eng._prefix) and eng._snap_pool.in_use == eng._prefix.snapshots
+    assert eng.store.allocator.used_blocks == len(eng.store.prefix) and eng.store.snap_pool.in_use == eng.store.prefix.snapshots
 
 
 def test_the_engine_reads_out_the_state_a_served_turn_left_and_it_is_the_references(params, reference):
@@ -444,10 +444,10 @@ def test_the_engine_reads_out_the_state_a_served_turn_left_and_it_is_the_referen
         r2 = eng.generate(turn2, max_tokens=12)
         other.result(timeout=120)
         assert settle(eng)
-        got = eng.state_snapshot(turn2 + r2)
-        assert eng.state_snapshot(prompt_of(64, 5)) is None                # no node on that path
-        ticks = (eng._prefix._tick, eng.stats()["state_restores"])
-        assert eng.state_snapshot(turn2 + r2)["tokens"] == got["tokens"] and ticks == (eng._prefix._tick, eng.stats()["state_restores"])
+        got = eng.store.state_snapshot(turn2 + r2)
+        assert eng.store.state_snapshot(prompt_of(64, 5)) is None                # no node on that path
+        ticks = (eng.store.prefix._tick, eng.stats()["state_restores"])
+        assert eng.store.state_snapshot(turn2 + r2)["tokens"] == got["tokens"] and ticks == (eng.store.prefix._tick, eng.stats()["state_restores"])
         _, want = reference[0](params, jnp.asarray(turn2 + r2), jnp.asarray([0]), states_after=got["tokens"])
         assert got["tokens"] == (len(turn2) + 12 - 1) // BS * BS and got["state"].shape == want.shape
         return float(jnp.linalg.norm(got["state"] - want) / jnp.linalg.norm(want))
@@ -493,16 +493,16 @@ def test_a_cancel_and_a_cache_reset_free_both_kinds(params):
         eng.generate(prompt_of(48, 14), max_tokens=1)
         assert eng.stats()["state_snapshots_in_use"] == 1
         # the loop's own recovery (``_fail_inflight`` + ``_reset_cache`` on the engine thread): a step that raises
-        eng._decode_k_paged = lambda *a, **k: (_ for _ in ()).throw(RuntimeError("the device pool is gone"))
+        eng.runner._decode_k_paged = lambda *a, **k: (_ for _ in ()).throw(RuntimeError("the device pool is gone"))
         with pytest.raises(RuntimeError):
             eng.submit(prompt_of(30, 15), max_tokens=200, eos_id=511).result(timeout=30)
         assert settle(eng)
         s = eng.stats()
         assert (s["kv_blocks_in_use"], s["prefix_cache_blocks"], s["state_snapshots_in_use"]) == (0, 0, 0)
         deadline = time.time() + 10  # the request fails before the loop has made the pools anew
-        while float(jnp.abs(eng._cache["state"]).max()) and time.time() < deadline:
+        while float(jnp.abs(eng.runner.cache["state"]).max()) and time.time() < deadline:
             time.sleep(0.02)
-        assert float(jnp.abs(eng._cache["state"]).max()) == 0.0
+        assert float(jnp.abs(eng.runner.cache["state"]).max()) == 0.0
     finally:
         eng.shutdown()
 
@@ -542,7 +542,7 @@ def test_a_config_without_linear_layers_takes_no_snapshot_pool_and_reports_no_st
     try:
         eng.generate([1, 2, 3], max_tokens=3)
         assert not [k for k in eng.stats() if k.startswith("state_") or k == "prefix_tokens_matched"]
-        assert set(eng._cache) == {"k", "v"} and eng._snaps is None
+        assert set(eng.runner.cache) == {"k", "v"} and eng.runner.snaps is None
     finally:
         eng.shutdown()
 

@@ -389,7 +389,7 @@ def test_a_cache_reset_rebuilds_the_one_pool_and_frees_both_kinds(params):
         assert eng.stats()["state_snapshots_in_use"] >= 1
         eng._fail_inflight(RuntimeError("test"))
         eng._reset_cache()
-        assert set(eng._cache) == {"latent", "state", "conv"} and not bool(eng._cache["latent"].any())
+        assert set(eng.runner.cache) == {"latent", "state", "conv"} and not bool(eng.runner.cache["latent"].any())
         assert eng.stats()["state_snapshots_in_use"] == 0 and eng.stats()["kv_blocks_in_use"] == 0
         assert len(eng.submit(prompt_of(40), max_tokens=4).result(timeout=120)) == 4
     finally:

@@ -155,8 +155,8 @@ class _Unready:
 
 def test_a_step_is_cold_after_an_empty_batch_and_queued_behind_a_running_one(params):
     eng = engine_of(params)
-    program = eng._decode_k_paged
-    eng._decode_k_paged = lambda *a: (lambda out, *rest: (_Unready(out), *rest))(*program(*a))
+    program = eng.runner._decode_k_paged
+    eng.runner._decode_k_paged = lambda *a: (lambda out, *rest: (_Unready(out), *rest))(*program(*a))
     try:
         assert len(eng.generate([3, 1, 4], max_tokens=10)) == 10
         assert eng.stats()["decode_dispatches"] == {"queued": 8, "dry": 0, "cold": 1}

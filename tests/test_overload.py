@@ -218,7 +218,7 @@ def test_engine_deadline_expired_while_queued_never_takes_slot():
         # drains the pool back to empty
         st = eng.stats()
         assert st["kv_blocks_in_use"] == st["prefix_cache_blocks"]
-        eng.flush_prefix_cache()
+        eng.store.flush_prefix_cache()
         assert eng.stats()["kv_blocks_in_use"] == 0
     finally:
         eng.shutdown()
@@ -288,7 +288,7 @@ def test_engine_abandoned_queued_stream_never_admits():
         assert eng.stats()["active_slots"] == 0
         st = eng.stats()
         assert st["kv_blocks_in_use"] == st["prefix_cache_blocks"]
-        eng.flush_prefix_cache()
+        eng.store.flush_prefix_cache()
         assert eng.stats()["kv_blocks_in_use"] == 0
     finally:
         eng.shutdown()

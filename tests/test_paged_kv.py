@@ -59,7 +59,7 @@ def _assert_no_leak(eng):
     accounted for by the prefix cache, and flushing it empties the pool."""
     st = eng.stats()
     assert st["kv_blocks_in_use"] == st["prefix_cache_blocks"]
-    eng.flush_prefix_cache()
+    eng.store.flush_prefix_cache()
     st = eng.stats()
     assert st["kv_blocks_in_use"] == 0 and st["prefix_cache_blocks"] == 0
 
@@ -648,15 +648,15 @@ def test_blocks_released_on_deadline_shed(params):
 def test_blocks_released_on_prefill_crash(params):
     eng = _paged(params, prefill_chunk_tokens=8)
     try:
-        real = eng._prefill_chunk
-        eng._prefill_chunk = lambda *a, **k: (_ for _ in ()).throw(
+        real = eng.runner._prefill_chunk
+        eng.runner._prefill_chunk = lambda *a, **k: (_ for _ in ()).throw(
             RuntimeError("injected prefill fault")
         )
         fut = eng.submit([1, 2, 3, 4, 5], max_tokens=4)
         with pytest.raises(RuntimeError, match="prefill failed"):
             fut.result(timeout=120)
         _wait(lambda: eng.stats()["kv_blocks_in_use"] == 0)
-        eng._prefill_chunk = real
+        eng.runner._prefill_chunk = real
         # pool intact: the engine keeps serving
         assert len(eng.generate([1, 2, 3], max_tokens=3)) == 3
         _assert_no_leak(eng)
@@ -679,7 +679,7 @@ def test_blocks_released_on_loop_crash(params, fault):
     resets the pool and serves the next request."""
     eng = _paged(params)
     try:
-        real, calls = eng._decode_k_paged, []
+        real, calls = eng.runner._decode_k_paged, []
 
         def program(*a, **k):
             calls.append(1)
@@ -688,14 +688,14 @@ def test_blocks_released_on_loop_crash(params, fault):
             out = real(*a, **k)
             return (_Poisoned(), *out[1:]) if fault == "collect" else out
 
-        eng._decode_k_paged = program
+        eng.runner._decode_k_paged = program
         futs = [eng.submit([1, 2, 3], max_tokens=8), eng.submit([9, 8, 7, 6], max_tokens=8)]
         for fut in futs:
             with pytest.raises(RuntimeError):
                 fut.result(timeout=120)
         _wait(lambda: eng.stats()["kv_blocks_in_use"] == 0)
         assert eng._flight is None and eng.stats()["active_slots"] == 0
-        eng._decode_k_paged = real
+        eng.runner._decode_k_paged = real
         # _fail_inflight + _reset_cache recovered the engine
         assert eng.generate([1, 2, 3], max_tokens=6) == _reference(params, [1, 2, 3], 6)
         _assert_no_leak(eng)
@@ -716,7 +716,7 @@ def test_eos_read_one_step_late_drops_the_row_and_frees_its_pages(params, K):
     # 3 + 40 - 1 positions in pages of 4: 11 pages, and the pool has exactly 11
     eng = _paged(params, max_batch_size=2, decode_chunk=K, kv_block_size=4, kv_num_blocks=12)
     try:
-        assert eng.kv_free_blocks() == 11
+        assert eng.store.kv_free_blocks() == 11
         assert eng.generate(prompt, max_tokens=40, eos_id=out[j]) == out[: j + 1]
         # the future resolves where the EOS is read; the step behind it is read next
         _wait(lambda: eng._flight is None and eng.stats()["decode_row_steps_discarded"] > 0)
@@ -732,7 +732,7 @@ def test_eos_read_one_step_late_drops_the_row_and_frees_its_pages(params, K):
         other = [31, 32, 33]
         assert eng.generate(other, max_tokens=40) == _reference(params, other, 40)
         _assert_no_leak(eng)
-        assert eng.kv_free_blocks() == 11
+        assert eng.store.kv_free_blocks() == 11
     finally:
         eng.shutdown()
 
@@ -800,7 +800,7 @@ def test_paged_snapshot_and_metrics_registered(params):
     try:
         snap = [s for s in admission.sources_snapshot()
                 if s.get("layer") == "engine"][-1]
-        assert snap["kv_block_pool_size"] == eng._allocator.capacity
+        assert snap["kv_block_pool_size"] == eng.store.allocator.capacity
         assert snap["kv_blocks_in_use"] == 0
         assert snap["kv_block_occupancy"] == 0.0
     finally:

@@ -73,7 +73,7 @@ def _engine_sources():
 def _assert_no_leak(eng):
     st = eng.stats()
     assert st["kv_blocks_in_use"] == st["prefix_cache_blocks"]
-    eng.flush_prefix_cache()
+    eng.store.flush_prefix_cache()
     st = eng.stats()
     assert st["kv_blocks_in_use"] == 0 and st["prefix_cache_blocks"] == 0
 
@@ -528,14 +528,14 @@ def test_decode_write_to_a_page_shared_mid_flight_is_copied_first(params):
         _wait(lambda: eng.stats()["decode_steps_overlapped"] > 2)
         with eng._lock:  # another reader appears for every page the row holds
             slot = next(i for i, r in enumerate(eng._slots) if r is not None)
-            pages = [int(b) for b in eng._block_tables[slot] if b > 0]
-            eng._allocator.share(pages)
+            pages = [int(b) for b in eng.store.block_tables[slot] if b > 0]
+            eng.store.allocator.share(pages)
         assert fut.result(timeout=120) == want
         st = eng.stats()
         # the tail page of that moment and every page after it were copied; full ones are only read
         assert 1 <= st["cow_copies"] <= len(pages)
         with eng._lock:
-            eng._allocator.free(pages)  # the other reader goes
+            eng.store.allocator.free(pages)  # the other reader goes
         _assert_no_leak(eng)
     finally:
         eng.shutdown()
@@ -567,14 +567,14 @@ def test_blocks_released_on_disconnect_mid_prefill_under_sharing(params):
         p = list(range(1, 25))
         eng.generate(p, max_tokens=3)  # warm: 3+ blocks cached
         entered = threading.Event()
-        real = eng._prefill_chunk
+        real = eng.runner._prefill_chunk
 
         def slow(*a, **k):
             entered.set()
             time.sleep(0.1)
             return real(*a, **k)
 
-        eng._prefill_chunk = slow
+        eng.runner._prefill_chunk = slow
         # partial hit + a 12-token uncached suffix -> at least 2 chunks
         stream = eng.submit_stream(p + list(range(50, 62)), max_tokens=20)
         assert entered.wait(timeout=60)
@@ -584,7 +584,7 @@ def test_blocks_released_on_disconnect_mid_prefill_under_sharing(params):
               and eng.stats()["queued"] == 0)
         _wait(lambda: eng.stats()["kv_blocks_in_use"]
               == eng.stats()["prefix_cache_blocks"])
-        eng._prefill_chunk = real
+        eng.runner._prefill_chunk = real
         # the pool still serves warm traffic afterwards
         assert len(eng.generate(p, max_tokens=3)) == 3
         _assert_no_leak(eng)
@@ -601,15 +601,15 @@ def test_loop_crash_invalidates_whole_cache(params):
         p = list(range(1, 18))
         want = eng.generate(p, max_tokens=4)
         assert eng.stats()["prefix_cache_blocks"] > 0
-        real = eng._decode_k_paged
-        eng._decode_k_paged = lambda *a, **k: (_ for _ in ()).throw(
+        real = eng.runner._decode_k_paged
+        eng.runner._decode_k_paged = lambda *a, **k: (_ for _ in ()).throw(
             RuntimeError("injected decode fault")
         )
         with pytest.raises(RuntimeError):
             eng.submit(p, max_tokens=8).result(timeout=120)
         _wait(lambda: eng.stats()["kv_blocks_in_use"] == 0)
         assert eng.stats()["prefix_cache_blocks"] == 0  # drained, not leaked
-        eng._decode_k_paged = real
+        eng.runner._decode_k_paged = real
         misses = eng.stats()["prefix_cache_misses"]
         assert eng.generate(p, max_tokens=4) == want  # recomputed, identical
         assert eng.stats()["prefix_cache_misses"] == misses + 1
@@ -703,7 +703,7 @@ def test_engine_eviction_deterministic_across_runs(params):
             for p in prompts:
                 eng.generate(p, max_tokens=3)
             st = eng.stats()
-            return (sorted(eng._prefix.keys()), st["prefix_evictions"],
+            return (sorted(eng.store.prefix.keys()), st["prefix_evictions"],
                     st["prefix_cache_hits"], st["prefix_cache_partial"],
                     st["prefix_cache_misses"])
         finally:

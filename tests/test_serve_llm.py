@@ -152,7 +152,7 @@ def test_quantized_engine_generates(params):
         CFG, params, max_batch_size=2, max_seq_len=64, quantize=True, quantize_min_size=256
     )
     try:
-        q_layers = eng_q.params["layers"]
+        q_layers = eng_q.runner.params["layers"]
         assert q_layers["wq"].dtype == jnp2.int8
         assert q_layers["attn_norm"].dtype == CFG.param_dtype  # norms untouched
         prompt = [3, 14, 15]
@@ -160,13 +160,13 @@ def test_quantized_engine_generates(params):
 
         deq_layers = {
             k: (
-                dequantize_int8(w, eng_q._layer_scales[k], CFG.param_dtype)
+                dequantize_int8(w, eng_q.runner._layer_scales[k], CFG.param_dtype)
                 if w.dtype == jnp2.int8
                 else w
             )
             for k, w in q_layers.items()
         }
-        ref_params = {**eng_q.params, "layers": deq_layers}
+        ref_params = {**eng_q.runner.params, "layers": deq_layers}
         assert q_out == _reference(ref_params, prompt, 8)
     finally:
         eng_q.shutdown()
@@ -309,7 +309,7 @@ def test_mesh_sharded_engine(params, chunk):
     eng_s = LLMEngine(CFG, params, **kw)
     try:
         # params really sharded over tp
-        wq_sh = eng_m.params["layers"]["wq"].sharding
+        wq_sh = eng_m.runner.params["layers"]["wq"].sharding
         assert wq_sh.spec[2] == "tp"
         prompts = [[3, 14, 15], [7, 8], list(range(1, 20))]
         m_out = [eng_m.generate(p, max_tokens=6) for p in prompts]
@@ -351,17 +351,17 @@ def test_mesh_engine_pool_keeps_whole_pages_of_its_heads_on_each_device(params, 
     eng = LLMEngine(CFG, params, max_batch_size=2, max_seq_len=64, mesh=_tp_mesh(tp))
     try:
         def shards():
-            return {tuple(sh.data.shape) for kk in ("k", "v") for sh in eng._cache[kk].addressable_shards}
+            return {tuple(sh.data.shape) for kk in ("k", "v") for sh in eng.runner.cache[kk].addressable_shards}
 
         want = {(CFG.n_layers, eng.kv_num_blocks, eng.kv_block_size, heads * CFG.head_dim)}
         assert shards() == want
-        placed = eng._cache["k"].sharding
+        placed = eng.runner.cache["k"].sharding
         prompt = list(range(1, 33))
         eng.generate(prompt, max_tokens=4)
         eng.generate(prompt, max_tokens=4)  # full hit: the tail page is copied
         st = eng.stats()
         assert st["decode_steps"] >= 6 and st["cow_copies"] == 1
-        assert shards() == want and eng._cache["k"].sharding == placed
+        assert shards() == want and eng.runner.cache["k"].sharding == placed
     finally:
         eng.shutdown()
 
@@ -434,7 +434,7 @@ def test_mesh_dropless_expert_engine_returns_counts_beside_the_pool():
         assert eng.generate(prompt, max_tokens=6) == want
         st = eng.stats()
         assert st["moe_assignments"] > 0 and st["prefix_cache_hits"] == 1
-        assert eng._cache["k"].sharding.spec[3] == "tp"
+        assert eng.runner.cache["k"].sharding.spec[3] == "tp"
     finally:
         eng.shutdown()
 
@@ -637,7 +637,7 @@ class _Tok:
 
 @pytest.fixture()
 def oai(params):
-    from ray_tpu.serve.llm import OpenAICompatLLMServer
+    from ray_tpu.serve.openai_compat import OpenAICompatLLMServer
 
     srv = OpenAICompatLLMServer(
         lambda: (CFG, params, _Tok()), max_batch_size=4, max_seq_len=64
@@ -706,7 +706,7 @@ def test_openai_over_http(params):
 
     import ray_tpu
     from ray_tpu import serve
-    from ray_tpu.serve.llm import OpenAICompatLLMServer
+    from ray_tpu.serve.openai_compat import OpenAICompatLLMServer
 
     ray_tpu.init(num_cpus=4)
     serve.start(http_port=0)
@@ -860,7 +860,7 @@ def test_openai_rejects_unsupported_sampling_params(oai, params):
 
 
 def test_openai_top_p_allowed_when_engine_configured(params):
-    from ray_tpu.serve.llm import OpenAICompatLLMServer
+    from ray_tpu.serve.openai_compat import OpenAICompatLLMServer
 
     srv = OpenAICompatLLMServer(
         lambda: (CFG, params, _Tok()), max_batch_size=2, max_seq_len=64,
@@ -955,7 +955,7 @@ def test_lowered_decode_text_is_the_program_the_loop_dispatches(engine):
     host and the ``[B]`` last tokens that stay on the device."""
     import re
 
-    text = engine.lowered_decode_text()
+    text = engine.runner.lowered_decode_text()
     main = re.search(r"func\.func public @main\((.*?)\) -> \((.*?)\) \{", text, re.S)
     assert main is not None
     assert "tensor<4x1xi32>" in main.group(2) and "tensor<4xi32>" in main.group(2)
@@ -1004,7 +1004,7 @@ def test_prefill_kv_counters_leave_out_what_a_window_hides():
         # sliding: 8 - 0, 16 - 1, 20 - 9 (the first query at 16 sees from 9 on); full: 8, 16, 20
         assert _prefill_kv(eng) == (3, ((8 + 15 + 11) + (8 + 16 + 20)) / 2, 3 * 64)
         # one bucketed call a prompt (prefill_chunk_tokens 0) counts the same way
-        one_shot = LLMEngine(cfg, eng.params, max_batch_size=2, max_seq_len=64, kv_block_size=8)
+        one_shot = LLMEngine(cfg, eng.runner.params, max_batch_size=2, max_seq_len=64, kv_block_size=8)
         try:
             assert len(one_shot.generate(list(range(1, 21)), max_tokens=2)) == 2
             assert _prefill_kv(one_shot) == (1, 20, 64)

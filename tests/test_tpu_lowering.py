@@ -198,15 +198,15 @@ def test_engine_paged_decode_program_compiles_for_v5e(as_chip, v5e):
 
 
 def _engine_program_args(eng, sharding=None):
-    """Abstract arguments of ``LLMEngine._decode_k_paged`` as the loop passes
+    """Abstract arguments of ``ModelRunner._decode_k_paged`` as the loop passes
     them: params, the pool, the rows' last tokens as the program's previous
     run left them on the device, the tokens the host sampled since (-1: none),
     positions, temperatures, the key and the masked block tables."""
     def abstract(x):
         return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
 
-    params, cache, key = jax.tree.map(abstract, (eng.params, eng._cache, eng._key))
-    toks, temps, bt = _abstract([((eng.B,), I32), ((eng.B,), F32), (eng._block_tables.shape, I32)], sharding)
+    params, cache, key = jax.tree.map(abstract, (eng.runner.params, eng.runner.cache, eng.runner.key))
+    toks, temps, bt = _abstract([((eng.B,), I32), ((eng.B,), F32), (eng.store.block_tables.shape, I32)], sharding)
     return params, cache, toks, toks, toks, temps, key, bt
 
 
@@ -229,19 +229,19 @@ def test_the_engines_own_decode_program_keeps_its_tokens_on_the_device(small_eng
     for the host beside the pool, the key and the ``[B]`` last tokens that
     the next run takes unread. Lowered for the chip, the kernel is in it."""
     eng = small_engine
-    traced = eng._decode_k_paged.trace(*_engine_program_args(eng))
+    traced = eng.runner._decode_k_paged.trace(*_engine_program_args(eng))
     out = traced.out_info
     assert [(o.shape, o.dtype) for o in (out[0], out[2], out[3])] == [
-        ((8, 2), I32), ((), eng._key.dtype), ((8,), I32)]
-    assert jax.tree.structure(out[1]) == jax.tree.structure(eng._cache) and len(out) == 4
+        ((8, 2), I32), ((), eng.runner.key.dtype), ((8,), I32)]
+    assert jax.tree.structure(out[1]) == jax.tree.structure(eng.runner.cache) and len(out) == 4
     assert "tpu_custom_call" in traced.lower(lowering_platforms=("tpu",)).as_text()
 
 
 def test_the_engines_own_decode_program_compiles_for_v5e(small_engine, v5e):
     eng = small_engine
     args = _engine_program_args(eng, SingleDeviceSharding(v5e[0]))
-    compiled = eng._decode_k_paged.trace(*args).lower(lowering_platforms=("tpu",)).compile()
-    pool_bytes = sum(int(np.prod(x.shape)) * x.dtype.itemsize for x in jax.tree.leaves(eng._cache))
+    compiled = eng.runner._decode_k_paged.trace(*args).lower(lowering_platforms=("tpu",)).compile()
+    pool_bytes = sum(int(np.prod(x.shape)) * x.dtype.itemsize for x in jax.tree.leaves(eng.runner.cache))
     assert compiled.memory_analysis().alias_size_in_bytes >= pool_bytes  # donated through, as before
 
 
