@@ -81,8 +81,16 @@ def _refuse_ring_for_hybrid(cfg: TransformerConfig, what: str) -> None:
                          "init_paged_cache(..., slots=) and paged_forward_with_cache(..., slots=)")
 
 
+def _refuse_latent_stack(cfg: TransformerConfig, what: str) -> None:
+    if cfg.latent_layers and not cfg.hybrid:
+        raise ValueError(f'{what} does not serve an all-latent stack ("latent" in every layer, the shared key '
+                         "part rotated): it is trained (forward, make_train_step); the latent pool caches an "
+                         "unrotated row behind linear layers only")
+
+
 def init_cache(cfg: TransformerConfig, batch: int, max_len: int, dtype=None) -> KVCache:
     """Preallocated KV cache: {"k","v"}: [L, B, Hkv, max_len, Dh]."""
+    _refuse_latent_stack(cfg, "init_cache")
     _refuse_ring_for_hybrid(cfg, "init_cache (the ring cache of forward_with_cache and generate)")
     dt = dtype or cfg.dtype
     shape = (cfg.n_layers, batch, cfg.kv_heads, max_len, cfg.head_dim)
@@ -126,6 +134,7 @@ def init_paged_cache(
     a 64-wide head pads to 128 lanes and XLA puts the page axis on the lanes
     instead — every layer then pays a layout conversion of its whole slice.)
     """
+    _refuse_latent_stack(cfg, "init_paged_cache")
     dt = dtype or cfg.dtype
     if cfg.latent_layers:
         # one pool, no K and no V: a token's row is its key for every head and, in its first lanes, its value

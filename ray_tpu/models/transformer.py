@@ -146,13 +146,17 @@ class TransformerConfig:
     # OLMo 2/3's norm placement is post_norms without pre_norms: x + norm(branch(x))
     pre_norms: bool = True              # False => no RMSNorm on a branch's input (needs post_norms)
     qk_norm_whole: bool = False         # qk_norm's gains span the whole projection (H*Dh, Hkv*Dh), not head_dim
-    # "latent" layers (multi-head latent attention, in a period's last place):
-    # n_heads queries of latent_nope_dim + latent_rope_dim, keys and values
-    # expanded from one latent of latent_rank a token, values of
-    # latent_value_dim; the latent_rope_dim key numbers are shared by every
-    # head and, like the full layers of such a config, carry no rotation
-    # (rope_full_layers must be False). The cache holds latent_rank +
-    # latent_rope_dim numbers a token a layer, read as keys and as values
+    # "latent" layers (multi-head latent attention): n_heads queries of
+    # latent_nope_dim + latent_rope_dim, keys and values expanded from one
+    # latent of latent_rank a token, values of latent_value_dim; the
+    # latent_rope_dim key numbers are shared by every head. Two layouts: in a
+    # period's last place behind "linear" layers they carry no rotation
+    # (rope_full_layers must be False) and the cache holds latent_rank +
+    # latent_rope_dim numbers a token a layer, read as keys and as values;
+    # as EVERY layer of the plain stack (dense layers, then expert layers)
+    # they rotate the shared key part and each query's last latent_rope_dim
+    # numbers at rope_theta, adjacent pairs (rope_full_layers must be True):
+    # that stack is trained (forward, make_train_step), not yet served
     latent_rank: int = 0
     latent_nope_dim: int = 0
     latent_rope_dim: int = 0
@@ -233,15 +237,22 @@ class TransformerConfig:
             )
 
     def _check_latent(self) -> None:
-        """A config with "latent" layers: sizes given, no rotation, and each
-        the last layer of a period of linear layers."""
+        """A config with "latent" layers: sizes given, and either each the
+        last layer of a period of linear layers, without rotation, or every
+        layer of the plain stack, with it."""
         if min(self.latent_rank, self.latent_nope_dim, self.latent_value_dim) < 1 or self.latent_rope_dim < 0:
             raise ValueError('a "latent" layer needs latent_rank, latent_nope_dim and latent_value_dim >= 1')
-        refused = {"rope_full_layers=True (a latent layer rotates nothing here: its shared key part is cached as "
-                   "it is projected)": self.rope_full_layers,
-                   'layer_types without "linear" layers (a latent layer is the last of a period of them)':
-                       "linear" not in self.layer_types,
+        period = "linear" in self.layer_types
+        refused = {"rope_full_layers=True (behind linear layers a latent layer rotates nothing: its shared key part "
+                   "is cached as it is projected)": self.rope_full_layers and period,
+                   'rope_full_layers=False in layer_types without "linear" layers (an all-latent stack rotates its '
+                   "shared key part; a latent layer without rotation is the last of a period of linear layers)":
+                       not self.rope_full_layers and not period,
+                   "an odd or missing latent_rope_dim (the rotation takes pairs)":
+                       not period and (self.latent_rope_dim < 2 or self.latent_rope_dim % 2),
                    '"full" or "sliding" layers beside it': bool({"full", "sliding"} & set(self.layer_types)),
+                   'attention="ring" (the ring kernel takes one head size)': self.attention == "ring" and not period,
+                   "block_length > 1": self.block_length > 1 and not period,
                    "qk_norm": self.qk_norm, "attn_gate": self.attn_gate}
         bad = [name for name, hit in refused.items() if hit]
         if bad:
@@ -451,9 +462,9 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
             names += ["ffn_norm"] * cfg.pre_norms + ["post_ffn_norm"] * cfg.post_norms
         return {name: jnp.ones((d,), pd) for name in names}
 
-    def latent_layer(k):
+    def latent_layer(k, experts: bool = False):
         """A latent attention layer's mixer (``lat_*``) and, unless the FFNs
-        are stacks of their own, its dense FFN."""
+        are stacks of their own, its FFN (dense, or the expert layer's)."""
         r, nope, rope, dv_ = cfg.latent_rank, cfg.latent_nope_dim, cfg.latent_rope_dim, cfg.latent_value_dim
         ks = jax.random.split(jax.random.fold_in(k, 30), 4)
         layer = {
@@ -465,7 +476,7 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
             **mixer_norms(not cfg.split_ffn),
         }
         if not cfg.split_ffn:
-            layer.update(ffn_leaves(k, False))
+            layer.update(ffn_leaves(k, experts))
         return layer
 
     def linear_layer(k):
@@ -540,6 +551,10 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
         return params
 
     nd = cfg.dense_stack
+    def make(experts: bool):
+        """An all-latent stack: the same two stacks, each layer's mixer the latent one (None: :func:`one_layer`)."""
+        return partial(latent_layer, experts=experts) if cfg.latent_layers else None
+
     params = {
         # embedding at 1/sqrt(d) std (a tied unembed wants unit row norms so
         # init logits are O(1) — std-1 rows made the model a confident
@@ -547,11 +562,11 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
         # multiplies by cfg.embed_scale (default sqrt(d)) in embed_tokens() to
         # keep the residual stream at its usual scale
         "embed": dense_init(k_embed, (cfg.vocab_size, d), d),
-        "layers": stack(layer_keys[nd:], cfg.num_experts > 0),
+        "layers": stack(layer_keys[nd:], cfg.num_experts > 0, make(cfg.num_experts > 0)),
         "final_norm": jnp.ones((d,), pd),
     }
     if nd:
-        params["dense_layers"] = stack(layer_keys[:nd], False)
+        params["dense_layers"] = stack(layer_keys[:nd], False, make(False))
     if not cfg.tie_embeddings:
         params["head"] = dense_init(k_head, (cfg.vocab_size, d), d)
     return params
@@ -703,6 +718,22 @@ def _rope(x, positions, theta: float):
     x1, x2 = x[..., :half], x[..., half:]
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)
+
+
+def _rope_pairs(x, positions, theta: float):
+    """Rotary embedding over ADJACENT pairs ``(x[2i], x[2i + 1])``, pair ``i``
+    at ``theta ** (-2i / dh)``: the pairing of the published ``deepseek_v3``
+    code, which de-interleaves a projection's numbers before it rotates
+    halves. The result lies de-interleaved as there (first components, then
+    second): queries and keys alike, so their products are the pairing's.
+    x: [B, T, ..., dh]; positions: [B, T]."""
+    half = x.shape[-1] // 2
+    freqs = jnp.exp(-jnp.arange(0, half, dtype=jnp.float32) * (math.log(theta) / half))
+    angles = positions.astype(jnp.float32).reshape(*positions.shape, *(1,) * (x.ndim - 2)) * freqs
+    cos, sin = jnp.cos(angles), jnp.sin(angles)
+    pairs = x.astype(jnp.float32).reshape(*x.shape[:-1], half, 2)
+    x1, x2 = pairs[..., 0], pairs[..., 1]
+    return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1).astype(x.dtype)
 
 
 def _repeat_kv(x, n_rep: int):
@@ -867,7 +898,7 @@ def grouped_matmul(rows, weights, group_sizes):
     On the chip the Pallas kernel of ``ops/grouped_matmul.py``: each group
     that holds rows has its weights copied out of HBM once, at a product as
     tall as its rows, and a group without rows costs nothing. Elsewhere
-    ``jax.lax.ragged_dot``, which is also the kernel's gradient."""
+    ``jax.lax.ragged_dot``."""
     if backend.on_tpu():
         return grouped_matmul_kernel(rows, weights, group_sizes)
     return _ragged_dot(rows, weights, group_sizes)
@@ -893,7 +924,8 @@ def scanned_leaves(cfg: TransformerConfig, stack):
     return stack
 
 
-def moe_ffn_dropless(cfg: TransformerConfig, layer, x, valid=None, *, stack=None, index=0, kernel=True):
+def moe_ffn_dropless(cfg: TransformerConfig, layer, x, valid=None, *, stack=None, index=0, kernel=True,
+                     count_routed: bool = False):
     """The dropless routed + shared expert layer: route, sort the N x k
     assignments by expert, one grouped product a projection over exactly
     those N x k rows, unsort, weigh and add; the shared experts see every
@@ -905,17 +937,26 @@ def moe_ffn_dropless(cfg: TransformerConfig, layer, x, valid=None, *, stack=None
     which only this layer's hold rows, so nothing is sliced out of it.
     ``kernel=False`` keeps the products XLA's own on the chip too: under a
     mesh, where GSPMD partitions ``ragged_dot`` and refuses a Mosaic call.
+    Read from ``layer`` the weights are cast to the activations' type first,
+    as a dense layer's are: a train step's unrolled loop hands each layer its
+    own slice, so the products' cotangent is the layer's, not a stack ``L``
+    times its size.
 
     A config that holds a share of the experts (``cfg.experts_held``: ``E``
     below is then the experts held, the router's width stays
     ``cfg.num_experts``) routes and normalises over all of them and adds the
-    terms of its own.
+    terms of its own. (Of its ``N x k`` sorted assignments only the first
+    ``sum(group_sizes)`` are rows of an expert held, 1 in 8 at a share of an
+    eighth, yet every product, gather and activation here is ``N x k`` rows
+    tall: PERF.md has what that costs a train step.)
 
     Returns (out [B, T, d], assignments int32[E]): how many (token, choice)
     pairs each expert got, counting only tokens ``valid`` [B, T] marks. Bucket
     padding and idle decode rows still compute (their rows are there), but
     they follow the first valid token's experts, so they make the products
-    read no expert that no real token asked for."""
+    read no expert that no real token asked for. ``count_routed``: the counts
+    are over all ``cfg.num_experts`` routed experts instead, int32[num_experts],
+    whatever share is held: what the router's load rule moves its bias by."""
     B, T, d = x.shape
     N, E, k = B * T, cfg.experts_here, cfg.expert_top_k
     x2 = x.reshape(N, d)
@@ -937,7 +978,10 @@ def moe_ffn_dropless(cfg: TransformerConfig, layer, x, valid=None, *, stack=None
         order = jnp.argsort(flat)
         group_sizes = jnp.zeros((E,), jnp.int32).at[flat].add(1, mode="drop")
     rows = x2[order // k]                           # [N*k, d]: each assignment's token
-    w = {name: layer[name][None] for name in EXPERT_WEIGHTS} if stack is None else stack
+    if stack is None:   # the layer's own weights, a stack of one
+        w, index = {name: layer[name][None].astype(x.dtype) for name in EXPERT_WEIGHTS}, 0
+    else:
+        w = stack
     L = w["we1"].shape[0]
     in_stack = jnp.zeros((L, E), jnp.int32).at[index].set(group_sizes).reshape(L * E)
 
@@ -953,7 +997,10 @@ def moe_ffn_dropless(cfg: TransformerConfig, layer, x, valid=None, *, stack=None
     if cfg.num_shared_experts:
         shared = jax.nn.silu(x2 @ layer["ws3"].astype(x.dtype)) * (x2 @ layer["ws1"].astype(x.dtype))
         y = y + shared @ layer["ws2"].astype(x.dtype)
-    if valid is None:
+    if count_routed:
+        every = jnp.ones((N,), jnp.int32) if valid is None else real.astype(jnp.int32)
+        counted = jnp.zeros((cfg.num_experts,), jnp.int32).at[experts.reshape(N * k)].add(jnp.repeat(every, k))
+    elif valid is None:
         counted = group_sizes
     else:
         # (index E, a share's dropped assignment, is out of range: not counted)
@@ -1014,11 +1061,13 @@ def block_attn_out(cfg: TransformerConfig, layer, x, h, o):
     return x + a
 
 
-def block_ffn(cfg: TransformerConfig, layer, x, valid=None, *, stack=None, index=0, kernel=True):
+def block_ffn(cfg: TransformerConfig, layer, x, valid=None, *, stack=None, index=0, kernel=True,
+              count_routed: bool = False):
     """The feed-forward branch: dense or expert layer by the layer's own
     leaves (``stack``, ``index``: where a layer loop keeps the dropless
     experts' weights, see :func:`scanned_leaves`; ``kernel``: whether its
-    grouped products may be a Mosaic call, see :func:`moe_ffn_dropless`).
+    grouped products may be a Mosaic call, ``count_routed``: which experts
+    the counts are of, see :func:`moe_ffn_dropless`).
     Returns (x, the dropless layer's assignment counts or None)."""
     h = pre_norm(cfg, layer, "ffn_norm", x)
     counts = None
@@ -1027,7 +1076,8 @@ def block_ffn(cfg: TransformerConfig, layer, x, valid=None, *, stack=None, index
     elif cfg.moe_capacity_factor > 0:
         ffn = _moe_ffn_capacity(cfg, layer, h)
     else:
-        ffn, counts = moe_ffn_dropless(cfg, layer, h, valid, stack=stack, index=index, kernel=kernel)
+        ffn, counts = moe_ffn_dropless(cfg, layer, h, valid, stack=stack, index=index, kernel=kernel,
+                                       count_routed=count_routed)
     if cfg.post_norms:
         ffn = _rms_norm(ffn, layer["post_ffn_norm"], cfg.norm_eps)
     return x + ffn, counts
@@ -1150,22 +1200,37 @@ def latent_scale(cfg: TransformerConfig) -> float:
     return 1.0 / math.sqrt(cfg.latent_nope_dim + cfg.latent_rope_dim)
 
 
-def latent_attention_expanded(cfg: TransformerConfig, layer, x, h):
+def latent_attention_expanded(cfg: TransformerConfig, layer, x, h, positions=None, use_flash: bool = False):
     """A latent layer's whole attention branch in the published, expanded
     form, causal over the call's own ``T`` tokens: every head's keys
     ``[k_nope; k_pe]`` and values expanded from the latents, softmax at
     ``1 / sqrt(nope + rope)``, ``W_o``, residual. What :func:`forward` runs;
     the cached paths run the absorbed form (:func:`latent_absorb`,
-    :func:`latent_out`), the same function."""
+    :func:`latent_out`), the same function.
+
+    An all-latent stack (``rope_full_layers``) rotates ``k_pe`` and each
+    query's last ``rope`` numbers at ``positions`` [B, T] first
+    (:func:`_rope_pairs`). ``use_flash``: the flash kernel, keys of ``nope +
+    rope`` numbers a head against values of ``latent_value_dim``, instead of
+    the ``T x T`` scores."""
     B, T, _ = h.shape
     r, nope = cfg.latent_rank, cfg.latent_nope_dim
     q, row = latent_qkv(cfg, layer, h)
     kv = jnp.einsum("btr,rhk->bthk", row[..., :r], layer["lat_wkvb"].astype(h.dtype))   # [B, T, H, nope + v]
-    k_pe = jnp.broadcast_to(row[:, :, None, r:], (B, T, cfg.n_heads, cfg.latent_rope_dim))
-    k = jnp.concatenate([kv[..., :nope], k_pe], axis=-1)
+    k_pe = row[..., r:]
+    if cfg.rope_full_layers:
+        q = jnp.concatenate([q[..., :nope], _rope_pairs(q[..., nope:], positions, cfg.rope_theta)], axis=-1)
+        k_pe = _rope_pairs(k_pe, positions, cfg.rope_theta)
+    k_pe = jnp.broadcast_to(k_pe[:, :, None, :], (B, T, cfg.n_heads, cfg.latent_rope_dim))
+    k, v = jnp.concatenate([kv[..., :nope], k_pe], axis=-1), kv[..., nope:]
+    if use_flash:
+        with jax.named_scope("latent_flash_attention"):
+            qt, kt, vt = (jnp.transpose(a, (0, 2, 1, 3)) for a in (q, k, v))
+            o = jnp.transpose(flash_attention_with_lse(qt, kt, vt, latent_scale(cfg), True)[0], (0, 2, 1, 3))
+        return _latent_wo(cfg, layer, x, o)
     s = jnp.einsum("bthk,bshk->bhts", q.astype(jnp.float32), k.astype(jnp.float32)) * latent_scale(cfg)
     s = jnp.where(jnp.tril(jnp.ones((T, T), bool))[None, None], s, NEG_INF)
-    o = jnp.einsum("bhts,bshv->bthv", jax.nn.softmax(s, axis=-1), kv[..., nope:].astype(jnp.float32)).astype(h.dtype)
+    o = jnp.einsum("bhts,bshv->bthv", jax.nn.softmax(s, axis=-1), v.astype(jnp.float32)).astype(h.dtype)
     return _latent_wo(cfg, layer, x, o)
 
 
@@ -1291,6 +1356,13 @@ def ring_placement(cfg: TransformerConfig, mesh, sp_axis, T: int):
     return ring_order(T, n) if ring_layout(T, n, causal=True) == "zigzag" else None
 
 
+def load_ruled(cfg: TransformerConfig) -> bool:
+    """Whether a train step moves this config's router bias by load: it has
+    one, and its dropless expert layers are the plain layer stack's (a config
+    with "linear" layers keeps its counts in ``hybrid_scan``: served only)."""
+    return cfg.router_bias and cfg.dropless and not cfg.hybrid
+
+
 def forward(
     cfg: TransformerConfig,
     params: Dict[str, Any],
@@ -1311,6 +1383,16 @@ def forward(
     natural order whatever the attention: under a zigzag ring it places the
     ids itself and restores the order on the final hidden states (one
     gather a call, before the head)."""
+    return forward_and_load(cfg, params, tokens, act_spec=act_spec, mesh=mesh, sp_axis=sp_axis, positions=positions)[0]
+
+
+def forward_and_load(cfg: TransformerConfig, params, tokens, *, act_spec=None, mesh=None, sp_axis=None,
+                     positions=None):
+    """:func:`forward`, and what a train step's load rule reads beside the
+    logits: (logits, each expert layer's assignment counts over all routed
+    experts, int32[expert layers, num_experts]) for a config whose bias the
+    rule moves (:func:`load_ruled`), (logits, None) for any other, whose
+    program gains nothing."""
     use_flash = cfg.attention == "flash" or (
         cfg.attention == "auto" and backend.on_tpu() and act_spec is None
     )
@@ -1330,29 +1412,39 @@ def forward(
     if cfg.hybrid:
         if act_spec is not None:
             raise ValueError('a config with "linear" layers runs on one device: its forward takes no mesh')
-        return unembed(cfg, params, _hybrid_forward(cfg, params, x, positions, use_flash))
+        return unembed(cfg, params, _hybrid_forward(cfg, params, x, positions, use_flash)), None
+    counted = load_ruled(cfg)
 
     def layer_fn(stack, x, layer_xs):
         layer, kind, index = layer_xs
         h = pre_norm(cfg, layer, "attn_norm", x)
-        q, k, v = block_qkv(cfg, layer, h, positions, kind)
-        o = _attention(cfg, q, k, v, use_flash, mesh=mesh, sp_axis=sp_axis,
-                       window=None if kind is None else kind["window"])
-        x = block_attn_out(cfg, layer, x, h, o)
-        x, _ = block_ffn(cfg, layer, x, stack=stack, index=index, kernel=act_spec is None)
+        if cfg.latent_layers:
+            x = latent_attention_expanded(cfg, layer, x, h, positions, use_flash)
+        else:
+            q, k, v = block_qkv(cfg, layer, h, positions, kind)
+            o = _attention(cfg, q, k, v, use_flash, mesh=mesh, sp_axis=sp_axis,
+                           window=None if kind is None else kind["window"])
+            x = block_attn_out(cfg, layer, x, h, o)
+        x, counts = block_ffn(cfg, layer, x, stack=stack, index=index, kernel=act_spec is None, count_routed=counted)
         if act_spec is not None:
             x = jax.lax.with_sharding_constraint(x, act_spec)
-        return x, None
+        return x, counts if counted else None
 
+    load = []
     for stack, first, last in layer_stacks(cfg, params):
         step = partial(layer_fn, stack)
+        leaves = scanned_leaves(cfg, stack)
+        if not cfg.scan_layers and leaves is not stack:
+            # unrolled, a dropless layer takes its own slice of the experts'
+            # weights (moe_ffn_dropless): nothing rides a scan here
+            step, leaves = partial(layer_fn, None), stack
         if cfg.remat == "dots":
             step = jax.checkpoint(step, policy=jax.checkpoint_policies.dots_saveable)
         elif cfg.remat:
             step = jax.checkpoint(step)
-        leaves = scanned_leaves(cfg, stack)
         if cfg.scan_layers:
-            x, _ = jax.lax.scan(step, x, (leaves, layer_kinds(cfg, first, last), jnp.arange(last - first)))
+            x, counts = jax.lax.scan(step, x, (leaves, layer_kinds(cfg, first, last), jnp.arange(last - first)))
+            load.append(counts)
             continue
         # Unrolled layer loop: under remat, scan stacks every saved
         # activation through dynamic-update-slice writes (and reads them
@@ -1362,10 +1454,11 @@ def forward(
             layer_i = jax.tree_util.tree_map(lambda a: a[i - first], leaves)
             kind = None if cfg.layer_types is None else {
                 "window": cfg.layer_windows[i], "rope": cfg.layer_rope[i]}
-            x, _ = step(x, (layer_i, kind, i - first))
+            x, counts = step(x, (layer_i, kind, i - first))
+            load.append(None if counts is None else counts[None])
     if restore is not None:
         x = x[:, restore]
-    return unembed(cfg, params, x)
+    return unembed(cfg, params, x), jnp.concatenate([c for c in load if c is not None]) if counted else None
 
 
 def embed_tokens(cfg: TransformerConfig, params, tokens) -> jax.Array:
@@ -1388,6 +1481,12 @@ def loss_fn(cfg: TransformerConfig, params, tokens, *, act_spec=None, mesh=None,
     activations force XLA to pad/slice every (8,128)-tiled tensor in the
     step (measured ~2% of a 602M train step), while full-T stays
     tile-aligned."""
+    return loss_and_load(cfg, params, tokens, act_spec=act_spec, mesh=mesh, sp_axis=sp_axis)[0]
+
+
+def loss_and_load(cfg: TransformerConfig, params, tokens, *, act_spec=None, mesh=None, sp_axis=None):
+    """(:func:`loss_fn`'s loss, :func:`forward_and_load`'s counts or None):
+    what the train step differentiates, the counts its aux."""
     from ray_tpu.parallel._compat import spmd_roll
 
     B, T = tokens.shape
@@ -1396,7 +1495,7 @@ def loss_fn(cfg: TransformerConfig, params, tokens, *, act_spec=None, mesh=None,
     # loss is a mean, so nothing wide is ever re-laid
     order = ring_placement(cfg, mesh, sp_axis, T)
     placed = tokens if order is None else tokens[:, order]
-    logits = forward(cfg, params, placed, act_spec=act_spec, mesh=mesh, sp_axis=sp_axis, positions=order)
+    logits, load = forward_and_load(cfg, params, placed, act_spec=act_spec, mesh=mesh, sp_axis=sp_axis, positions=order)
     targets = spmd_roll(tokens, -1, axis=1)  # [:, T-1] rolls around: masked
     if order is not None:
         targets = targets[:, order]
@@ -1405,12 +1504,15 @@ def loss_fn(cfg: TransformerConfig, params, tokens, *, act_spec=None, mesh=None,
     mask = (jnp.arange(T) < T - 1).astype(nll.dtype)[None, :]
     if order is not None:
         mask = mask[:, order]
-    return jnp.sum(nll * mask) / (B * (T - 1))
+    return jnp.sum(nll * mask) / (B * (T - 1)), load
 
 
 # ---------------------------------------------------------------------------
 # train step
 # ---------------------------------------------------------------------------
+ROUTER_BIAS_RATE = 1e-3   # what the load rule moves a router's selection bias by, an expert a step (DeepSeek-V3's gamma)
+
+
 def make_train_step(
     cfg: TransformerConfig,
     *,
@@ -1422,14 +1524,46 @@ def make_train_step(
     ep: Optional[str] = None,
 ):
     """Build (init_state, train_step). Jitted to one XLA program; with a mesh,
-    params/opt shard per ``param_specs`` and batch shards over (dp, sp)."""
+    params/opt shard per ``param_specs`` and batch shards over (dp, sp).
+    ``learning_rate``: AdamW's, a number or an optax schedule (step -> rate).
+
+    A config with ``router_bias`` trains its routers' selection bias by load,
+    not by gradient (the auxiliary-loss-free balance of DeepSeek-V3,
+    ``topk_method: noaux_tc``): after the optimizer's update each expert
+    layer's ``b_i += ROUTER_BIAS_RATE * sign(mean(n) - n_i)``, ``n`` the
+    step's assignments a routed expert over all ``num_experts`` (this
+    program's tokens; a share held masks no selection). The bias is no leaf
+    of the optimizer: no moments, no weight decay, no gradient taken. The
+    state then carries ``expert_load``, uint32[expert layers, num_experts]:
+    the assignments counted since the state was made (the rule uses a step's
+    own increment; a host reads the array when it likes). A config without
+    the bias has neither and compiles to the step it always did."""
     import optax
 
     opt = optax.adamw(learning_rate)
+    balanced = load_ruled(cfg)
+    if cfg.router_bias and cfg.dropless and not balanced:
+        raise ValueError('router_bias beside "linear" layers is served, not trained: the load rule reads the '
+                         "plain layer stack's assignment counts (forward_and_load)")
+
+    def split_bias(params):
+        """(the leaves the optimizer trains, the routers' bias [expert layers, num_experts])."""
+        layers = dict(params["layers"])
+        bias = layers.pop("router_bias")
+        return {**params, "layers": layers}, bias
+
+    def join_bias(trained, bias):
+        return {**trained, "layers": {**trained["layers"], "router_bias": bias}}
+
+    def new_state(params):
+        state = {"params": params, "opt": opt.init(split_bias(params)[0] if balanced else params),
+                 "step": jnp.zeros((), jnp.int32)}
+        if balanced:
+            state["expert_load"] = jnp.zeros((cfg.expert_layers, cfg.num_experts), jnp.uint32)
+        return state
 
     def init_state(key):
-        params = init_params(cfg, key)
-        return {"params": params, "opt": opt.init(params), "step": jnp.zeros((), jnp.int32)}
+        return new_state(init_params(cfg, key))
 
     act_spec = None
     ring_mesh = None
@@ -1466,12 +1600,25 @@ def make_train_step(
             from ray_tpu.parallel.ring import ring_layout
 
             step.ring_layout = ring_layout(tokens.shape[1], mesh.shape[sp_ax], causal=True)
+        if balanced:
+            return balanced_step(state, tokens)
         loss, grads = jax.value_and_grad(
             lambda p: loss_fn(cfg, p, tokens, act_spec=act_spec, mesh=ring_mesh, sp_axis=sp_ax)
         )(state["params"])
         updates, new_opt = opt.update(grads, state["opt"], state["params"])
         new_params = optax.apply_updates(state["params"], updates)
         return {"params": new_params, "opt": new_opt, "step": state["step"] + 1}, loss
+
+    def balanced_step(state, tokens):
+        trained, bias = split_bias(state["params"])
+        (loss, load), grads = jax.value_and_grad(
+            lambda p: loss_and_load(cfg, join_bias(p, bias), tokens, act_spec=act_spec, mesh=ring_mesh, sp_axis=sp_ax),
+            has_aux=True)(trained)
+        updates, new_opt = opt.update(grads, state["opt"], trained)
+        n = load.astype(jnp.float32)
+        bias = bias + ROUTER_BIAS_RATE * jnp.sign(jnp.mean(n, axis=-1, keepdims=True) - n).astype(bias.dtype)
+        return {"params": join_bias(optax.apply_updates(trained, updates), bias), "opt": new_opt,
+                "step": state["step"] + 1, "expert_load": state["expert_load"] + load.astype(jnp.uint32)}, loss
 
     if mesh is None:
         return init_state, jax.jit(train_step, donate_argnums=(0,))
@@ -1483,8 +1630,7 @@ def make_train_step(
         # params placed per the TP layout; the (eagerly-run) optax init then
         # inherits each leaf's sharding through zeros_like, so opt state is
         # laid out identically with no explicit spec tree.
-        params = jax.tree.map(lambda x, s: jax.device_put(x, s), init_params(cfg, key), param_sh)
-        return {"params": params, "opt": opt.init(params), "step": jnp.zeros((), jnp.int32)}
+        return new_state(jax.tree.map(lambda x, s: jax.device_put(x, s), init_params(cfg, key), param_sh))
 
     def shard_batch(tokens):
         return jax.device_put(tokens, NamedSharding(mesh, P(dp, None)))

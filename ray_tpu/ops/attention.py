@@ -68,10 +68,12 @@ def _attn_fwd_kernel(
 ):
     """Grid (bh, q_block, k_block); k innermost streams K/V through VMEM.
 
-    q_ref: [block_q, D]; k_ref/v_ref: [block_k, D] (this step's tile);
-    o_ref: [block_q, D]; lse_ref: [1, block_q] (this q-block's slice —
-    per-block mapping keeps stores statically aligned and Megacore-safe);
-    scratch: m/l [block_q, LANES] lane-replicated, acc [block_q, D].
+    q_ref: [block_q, D]; k_ref: [block_k, D], v_ref: [block_k, Dv] (this
+    step's tile; the values' size is their own: latent attention's heads
+    have keys of 192 and values of 128); o_ref: [block_q, Dv]; lse_ref:
+    [1, block_q] (this q-block's slice — per-block mapping keeps stores
+    statically aligned and Megacore-safe); scratch: m/l [block_q, LANES]
+    lane-replicated, acc [block_q, Dv].
     """
     qi = pl.program_id(1)
     ki = pl.program_id(2)
@@ -139,9 +141,9 @@ def _pad_to(x, axis, multiple):
 
 
 def _flash_forward(q, k, v, sm_scale: float, causal: bool, block_q: int, block_k: int, interpret: bool, window=None):
-    """Returns (out [B,H,Tq,D], lse [B*H, 1, Tq_padded])."""
+    """Returns (out [B,H,Tq,Dv], lse [B*H, 1, Tq_padded]). q, k: [B,H,T,D]; v: [B,H,Tk,Dv]."""
     B, H, Tq, D = q.shape
-    Tk = k.shape[2]
+    Tk, Dv = k.shape[2], v.shape[3]
     bq = min(block_q, Tq)
     bk = min(block_k, Tk)
     # pad ragged tails to block multiples: padded q rows are computed then
@@ -152,7 +154,7 @@ def _flash_forward(q, k, v, sm_scale: float, causal: bool, block_q: int, block_k
     Tq_p, Tk_p = q.shape[2], k.shape[2]
     qf = q.reshape(B * H, Tq_p, D)
     kf = k.reshape(B * H, Tk_p, D)
-    vf = v.reshape(B * H, Tk_p, D)
+    vf = v.reshape(B * H, Tk_p, Dv)
 
     grid = (B * H, Tq_p // bq, Tk_p // bk)
     out, lse = pl.pallas_call(
@@ -161,30 +163,30 @@ def _flash_forward(q, k, v, sm_scale: float, causal: bool, block_q: int, block_k
             block_q=bq, block_k=bk, kv_len=Tk, tk_padded=Tk_p, window=window,
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((B * H, Tq_p, D), q.dtype),
+            jax.ShapeDtypeStruct((B * H, Tq_p, Dv), q.dtype),
             jax.ShapeDtypeStruct((B * H, 1, Tq_p), jnp.float32),
         ],
         grid=grid,
         in_specs=[
             pl.BlockSpec((None, bq, D), lambda bh, i, j: (bh, i, 0)),
             pl.BlockSpec((None, bk, D), lambda bh, i, j: (bh, j, 0)),
-            pl.BlockSpec((None, bk, D), lambda bh, i, j: (bh, j, 0)),
+            pl.BlockSpec((None, bk, Dv), lambda bh, i, j: (bh, j, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((None, bq, D), lambda bh, i, j: (bh, i, 0)),
+            pl.BlockSpec((None, bq, Dv), lambda bh, i, j: (bh, i, 0)),
             pl.BlockSpec((None, 1, bq), lambda bh, i, j: (bh, 0, i)),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, _LANES), jnp.float32),
             pltpu.VMEM((bq, _LANES), jnp.float32),
-            pltpu.VMEM((bq, D), jnp.float32),
+            pltpu.VMEM((bq, Dv), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
     )(qf, kf, vf)
-    return out.reshape(B, H, Tq_p, D)[:, :, :Tq, :], lse
+    return out.reshape(B, H, Tq_p, Dv)[:, :, :Tq, :], lse
 
 
 def _bwd_dq_kernel(
@@ -193,7 +195,8 @@ def _bwd_dq_kernel(
 ):
     """Grid (bh, q_block, k_block); streams K/V. dq accumulates in scratch.
 
-    q/do/dq: [block_q, D]; k/v: [block_k, D]; lse/delta: [1, block_q].
+    q/dq: [block_q, D]; do: [block_q, Dv]; k: [block_k, D]; v: [block_k, Dv];
+    lse/delta: [1, block_q].
     """
     qi = pl.program_id(1)
     ki = pl.program_id(2)
@@ -242,7 +245,8 @@ def _bwd_dkv_kernel(
 ):
     """Grid (bh, k_block, q_block); streams Q/dO. dk/dv accumulate in scratch.
 
-    k/v/dk/dv: [block_k, D]; q/do: [block_q, D]; lse/delta: [1, block_q].
+    k/dk: [block_k, D]; v/dv: [block_k, Dv]; q: [block_q, D]; do: [block_q, Dv];
+    lse/delta: [1, block_q].
     """
     ki = pl.program_id(1)
     qi = pl.program_id(2)
@@ -296,7 +300,7 @@ def _bwd_dkv_kernel(
 
 def _flash_backward(q, k, v, out, lse, g, sm_scale, causal, block_q, block_k, interpret, g_lse=None, window=None):
     B, H, Tq, D = q.shape
-    Tk = k.shape[2]
+    Tk, Dv = k.shape[2], v.shape[3]
     bq = min(block_q, Tq)
     bk = min(block_k, Tk)
     qp = _pad_to(q, 2, bq)
@@ -307,9 +311,9 @@ def _flash_backward(q, k, v, out, lse, g, sm_scale, causal, block_q, block_k, in
     Tq_p, Tk_p = qp.shape[2], kp.shape[2]
     qf = qp.reshape(B * H, Tq_p, D)
     kf = kp.reshape(B * H, Tk_p, D)
-    vf = vp.reshape(B * H, Tk_p, D)
-    gf = gp.reshape(B * H, Tq_p, D)
-    of = op.reshape(B * H, Tq_p, D)
+    vf = vp.reshape(B * H, Tk_p, Dv)
+    gf = gp.reshape(B * H, Tq_p, Dv)
+    of = op.reshape(B * H, Tq_p, Dv)
     # delta = rowsum(dO * O): cheap elementwise, plain XLA
     delta = jnp.sum(gf.astype(jnp.float32) * of.astype(jnp.float32), axis=-1)[:, None, :]
     if g_lse is not None:
@@ -328,8 +332,8 @@ def _flash_backward(q, k, v, out, lse, g, sm_scale, causal, block_q, block_k, in
         in_specs=[
             pl.BlockSpec((None, bq, D), lambda bh, i, j: (bh, i, 0)),
             pl.BlockSpec((None, bk, D), lambda bh, i, j: (bh, j, 0)),
-            pl.BlockSpec((None, bk, D), lambda bh, i, j: (bh, j, 0)),
-            pl.BlockSpec((None, bq, D), lambda bh, i, j: (bh, i, 0)),
+            pl.BlockSpec((None, bk, Dv), lambda bh, i, j: (bh, j, 0)),
+            pl.BlockSpec((None, bq, Dv), lambda bh, i, j: (bh, i, 0)),
             pl.BlockSpec((None, 1, bq), lambda bh, i, j: (bh, 0, i)),
             pl.BlockSpec((None, 1, bq), lambda bh, i, j: (bh, 0, i)),
         ],
@@ -349,24 +353,24 @@ def _flash_backward(q, k, v, out, lse, g, sm_scale, causal, block_q, block_k, in
         ),
         out_shape=[
             jax.ShapeDtypeStruct((B * H, Tk_p, D), k.dtype),
-            jax.ShapeDtypeStruct((B * H, Tk_p, D), v.dtype),
+            jax.ShapeDtypeStruct((B * H, Tk_p, Dv), v.dtype),
         ],
         grid=(B * H, Tk_p // bk, Tq_p // bq),
         in_specs=[
             pl.BlockSpec((None, bq, D), lambda bh, j, i: (bh, i, 0)),
             pl.BlockSpec((None, bk, D), lambda bh, j, i: (bh, j, 0)),
-            pl.BlockSpec((None, bk, D), lambda bh, j, i: (bh, j, 0)),
-            pl.BlockSpec((None, bq, D), lambda bh, j, i: (bh, i, 0)),
+            pl.BlockSpec((None, bk, Dv), lambda bh, j, i: (bh, j, 0)),
+            pl.BlockSpec((None, bq, Dv), lambda bh, j, i: (bh, i, 0)),
             pl.BlockSpec((None, 1, bq), lambda bh, j, i: (bh, 0, i)),
             pl.BlockSpec((None, 1, bq), lambda bh, j, i: (bh, 0, i)),
         ],
         out_specs=[
             pl.BlockSpec((None, bk, D), lambda bh, j, i: (bh, j, 0)),
-            pl.BlockSpec((None, bk, D), lambda bh, j, i: (bh, j, 0)),
+            pl.BlockSpec((None, bk, Dv), lambda bh, j, i: (bh, j, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((bk, D), jnp.float32),
-            pltpu.VMEM((bk, D), jnp.float32),
+            pltpu.VMEM((bk, Dv), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
@@ -376,7 +380,7 @@ def _flash_backward(q, k, v, out, lse, g, sm_scale, causal, block_q, block_k, in
 
     dq = dq.reshape(B, H, Tq_p, D)[:, :, :Tq, :]
     dk = dk.reshape(B, H, Tk_p, D)[:, :, :Tk, :]
-    dv = dv.reshape(B, H, Tk_p, D)[:, :, :Tk, :]
+    dv = dv.reshape(B, H, Tk_p, Dv)[:, :, :Tk, :]
     return dq, dk, dv
 
 
@@ -390,8 +394,11 @@ def _reference_attention(q, k, v, sm_scale: float, causal: bool):
     return jnp.einsum("bhqk,bhkd->bhqd", p, v.astype(jnp.float32)).astype(q.dtype)
 
 
-def default_blocks(head_dim: int) -> tuple:
-    """(block_q, block_k) for the flash kernels: (512, 1024) at every shape.
+def default_blocks(head_dim: int, itemsize: int = 2) -> tuple:
+    """(block_q, block_k) for the flash kernels: (512, 1024) at every shape of
+    two-byte operands; (512, 512) for float32 ones, whose backward tiles at
+    keys of 192 and values of 128 otherwise pass the kernel's VMEM by 2 MB
+    (the compiler's refusal, chipless, PR 51).
 
     The 602M train step (T=2048, D=128) compiles and runs with these on a
     v5e (``chip_smoke.py``). No per-shape block timing taken from a compiled
@@ -399,7 +406,7 @@ def default_blocks(head_dim: int) -> tuple:
     before this grows a per-shape table.
     """
     del head_dim  # shape-independent today
-    return (512, 1024)
+    return (512, 1024) if itemsize <= 2 else (512, 512)
 
 
 def flash_attention(
@@ -450,7 +457,9 @@ def flash_attention_with_lse(
 ):
     """Flash attention that also returns the per-row logsumexp.
 
-    Returns (out [B,H,Tq,D], lse [B,H,Tq] f32). The lse output is what
+    q, k: [B,H,T,D]; v: [B,H,Tk,Dv], a size of its own (latent attention's
+    heads: keys of 192, values of 128). Returns (out [B,H,Tq,Dv], lse
+    [B,H,Tq] f32). The lse output is what
     makes partial-attention results combinable — ring attention merges
     per-step outputs with lse-softmax weights (``parallel/ring.py``).
 
@@ -458,7 +467,7 @@ def flash_attention_with_lse(
     invoked with the wrapper's original nondiff args, so a None default
     resolved inside the primal body would leak into the grad path."""
     if block_q is None or block_k is None:
-        dq, dk = default_blocks(q.shape[-1])
+        dq, dk = default_blocks(q.shape[-1], q.dtype.itemsize)
         block_q = block_q or dq
         block_k = block_k or dk
     return _flash_with_lse_cv(q, k, v, sm_scale, causal, block_q, block_k, window)

@@ -9,7 +9,9 @@ has its ``[a, b-tile]`` of weights copied out of HBM exactly once, in one
 piece, the next groups' copies in flight meanwhile, and a group without rows
 costs one scalar comparison: no copy, no product. ``G`` may be a whole layer
 stack's ``L x E`` groups of which one layer's hold rows; the weights stay
-where they lie.
+where they lie. In a train step the same kernel is the rows' gradient (the
+cotangent against the transposed weights); the weights' gradient is XLA's
+``ragged_dot`` (``_bwd``).
 
 Grid ``(b tiles, row tiles)``. The rows and the result ride BlockSpecs in
 tiles of ``_ROW_TILE``; the weights stay in HBM and the body copies them. The
@@ -42,17 +44,31 @@ from ray_tpu.ops.attention import _use_interpret
 _ALIGN = 16                   # rows of a bf16 sublane tile: where a window may start
 _ROW_TILE = 512               # rows of a BlockSpec tile of the rows and the result
 _WINDOWS = (32, 128)          # rows of one product: a small group's, a large group's pieces
-_WEIGHT_TILE_BYTES = 4 << 20  # the most one group's [a, b-tile] may hold
+_WEIGHT_TILE_BYTES = 4 << 20  # the most one group's [a, b-tile] may hold ...
+# ... and where the rows outnumber the groups' tile columns (a train step's hundreds of rows a group): there the
+# product is paid by the rows' passes, one a b-tile, not by the weights' copies, so the tile is the whole width
+# where that fits (2048 x 1408 bf16 is 5.8 MB: 1 pass over the rows instead of 11 tiles of 128)
+_TALL_WEIGHT_TILE_BYTES = 8 << 20
 _SLOTS = 3                    # weight buffers: two copies in flight behind the one in use
 
 
-def _b_tile(a: int, b: int, itemsize: int) -> int:
+def _b_tile(a: int, b: int, itemsize: int, budget: int = _WEIGHT_TILE_BYTES) -> int:
     """The widest lane-tiled divisor of ``b`` whose ``[a, tile]`` fits the
     budget; all of ``b`` where it fits or has no such divisor."""
-    if a * b * itemsize <= _WEIGHT_TILE_BYTES or b % 128:
+    if a * b * itemsize <= budget or b % 128:
         return b
-    fits = [t for t in range(128, b, 128) if b % t == 0 and a * t * itemsize <= _WEIGHT_TILE_BYTES]
+    fits = [t for t in range(128, b, 128) if b % t == 0 and a * t * itemsize <= budget]
     return max(fits, default=128)
+
+
+def _tile_for(R: int, G: int, a: int, b: int, itemsize: int) -> int:
+    """The b-tile of a product of ``R`` rows over ``G`` groups, read off the
+    static shapes: every b-tile passes over all the rows (``R x a x b / tile``
+    numbers in all) and copies each live group's weights once (at most ``G x
+    a x b``), so where ``R > G x tile`` the rows' passes are what the product
+    pays for and the tile takes the larger budget."""
+    tile = _b_tile(a, b, itemsize)
+    return _b_tile(a, b, itemsize, _TALL_WEIGHT_TILE_BYTES) if R > G * tile else tile
 
 
 def _kernel(sizes_ref, rows_ref, w_hbm, out_ref, wbuf, sems, live_g, live_s, st, *, tn, windows):
@@ -142,7 +158,7 @@ def _forward(rows, weights, group_sizes):
         rows = jnp.pad(rows, ((0, padded - R), (0, 0)))
     TM = min(_ROW_TILE, padded)
     windows = tuple(min(w, TM) for w in _WINDOWS)
-    tn = _b_tile(a, b, weights.dtype.itemsize)
+    tn = _tile_for(padded, G, a, b, weights.dtype.itemsize)
     listable = min(G, padded) + 1
     need = 2 * TM * (a + tn) * rows.dtype.itemsize + _SLOTS * a * tn * weights.dtype.itemsize
     out = pl.pallas_call(
@@ -180,9 +196,20 @@ def _fwd(rows, weights, group_sizes):
 
 
 def _bwd(residuals, g):
+    """The rows' gradient is itself a grouped product, against the transposed
+    weights: the kernel again. The weights' is ``jax.lax.ragged_dot``'s own (a
+    product ragged in the contracted dimension). Rows past ``sum(group_sizes)``
+    belong to no group (a share's dropped assignments): their cotangent
+    reaches nothing, and is zeroed first, because XLA's ragged products on the
+    chip leave such rows of their result as they find them. The scope names
+    both in a profile."""
     rows, weights, group_sizes = residuals
-    _, vjp = jax.vjp(lambda r, w: jax.lax.ragged_dot(r, w.astype(r.dtype), group_sizes), rows, weights)
-    return (*vjp(g), None)
+    with jax.named_scope("grouped_matmul_bwd"):
+        grouped = (jnp.arange(rows.shape[0]) < jnp.sum(group_sizes))[:, None]
+        g = jnp.where(grouped, g, jnp.zeros((), g.dtype))
+        d_rows = _forward(g, jnp.swapaxes(weights, 1, 2), group_sizes)
+        _, vjp = jax.vjp(lambda w: jax.lax.ragged_dot(rows, w.astype(rows.dtype), group_sizes), weights)
+        return d_rows, *vjp(g), None
 
 
 grouped_matmul.defvjp(_fwd, _bwd)

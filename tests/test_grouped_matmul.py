@@ -133,3 +133,16 @@ def test_off_the_chip_the_expert_layer_keeps_ragged_dot(monkeypatch):
     monkeypatch.setattr(backend, "on_tpu", lambda: True)
     transformer.grouped_matmul(rows, weights, group_sizes)
     assert called
+
+
+def test_the_tile_takes_the_larger_budget_only_where_the_rows_outnumber_the_groups_tile_columns():
+    """A served step's products (a few thousand rows over a stack's hundreds
+    of groups) keep the 4 MiB tile they had; a train step's (49 152 rows over
+    a layer's 8 groups of 2048 x 1408) take the whole width: one pass over the
+    rows instead of eleven."""
+    for R, G, a, b in ((4096, 5 * 128, 2048, 1024), (96, 6 * 128, 2048, 768), (4096, 8 * 32, 2304, 1024),
+                       (256, 640, 2048, 1408)):
+        assert gm._tile_for(R, G, a, b, 2) == gm._b_tile(a, b, 2), (R, G, a, b)
+    assert gm._b_tile(2048, 1408, 2) == 128
+    assert gm._tile_for(49152, 8, 2048, 1408, 2) == 1408 and gm._tile_for(49152, 8, 1408, 2048, 2) == 2048
+    assert gm._tile_for(49152, 8, 4096, 14336, 2) == 1024     # 8 MiB of [4096, 1024]

@@ -646,6 +646,74 @@ def test_train_step_602m_compiles_for_one_v5e_and_fits(as_chip, v5e):
     assert need < 16 * 2**30, f"train step needs {need / 2**30:.2f} GiB"
 
 
+def test_the_moonlight_cells_train_step_compiles_for_one_v5e_and_fits(as_chip, v5e):
+    """The benchmark's Moonlight configuration as its file states it (layer 0
+    dense + 5 expert layers at published widths, 8 of 64 experts and 20 480
+    vocabulary rows held, 8192 tokens a step): ``make_train_step``'s one
+    program compiles for a v5e with the flash kernels at keys of 192 and
+    values of 128 and the grouped kernel by name, carries the load counter,
+    updates the donated state where it lies, and fits the chip. The memory
+    analysis is what the configuration file's ``sizing`` quotes."""
+    from benchmark import system
+    from ray_tpu.models.transformer import make_train_step
+
+    config = system.load_json("benchmark/configs/moonlight-16b-a3b-train-ep8.json")
+    run = config["run"]
+    cfg = system.model_module(config).program_config(
+        config, max_seq_len=run["seq_len"], dtype=run["dtype"], param_dtype=run["param_dtype"],
+        attention=run["attention"], remat=run["remat"], scan_layers=run["scan_layers"])
+    from benchmark.kinds.routed_train_steps import learning_rate
+
+    init_state, train_step = make_train_step(cfg, learning_rate=learning_rate(run))    # the cell's warm-up
+    state = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=SingleDeviceSharding(v5e[0])),
+                         jax.eval_shape(init_state, jax.random.key(0)))
+    assert state["expert_load"].shape == (5, 64) and state["params"]["layers"]["we1"].shape == (5, 8, 2048, 1408)
+    assert state["params"]["head"].shape == (20480, 2048) and "router_bias" not in state["opt"][0].mu["layers"]
+    tokens = jax.ShapeDtypeStruct((run["batch"], run["seq_len"]), I32, sharding=SingleDeviceSharding(v5e[0]))
+    lowered = train_step.trace(state, tokens).lower(lowering_platforms=("tpu",))
+    text = lowered.as_text()
+    assert "grouped_matmul" in text and text.count("tpu_custom_call") >= 6 * 3 + 5 * 3
+    assert "bf16[16,8192,192]" in text.replace("tensor<16x8192x192xbf16>", "bf16[16,8192,192]")  # keys of 192 ...
+    assert "16x8192x128xbf16" in text                                                              # ... values of 128
+    m = lowered.compile().memory_analysis()
+    state_bytes = sum(int(np.prod(x.shape)) * x.dtype.itemsize for x in jax.tree.leaves(state))
+    assert m.alias_size_in_bytes >= state_bytes - 64  # parameters and moments are updated where they lie
+    need = m.argument_size_in_bytes + m.temp_size_in_bytes + m.output_size_in_bytes - m.alias_size_in_bytes
+    print("moonlight sizing:", {"state_gib": round(state_bytes / 2**30, 2), "temporaries_gib": round(m.temp_size_in_bytes / 2**30, 2),
+                                "needs_gib": round(need / 2**30, 2)})
+    assert 0.6 * 15.75 * 2**30 < need < 15.0 * 2**30, f"train step needs {need / 2**30:.2f} GiB"
+
+
+def test_the_moonlight_cells_float32_gradient_program_compiles_for_one_v5e_and_fits(as_chip, v5e):
+    """What the cell's comparison runs before its window beside the step: the
+    same ``loss_and_load`` at float32 activations and "highest" products. The
+    flash backward's tiles at keys of 192 and values of 128 fit the kernel's
+    VMEM only at the float32 blocks (``default_blocks``: (512, 1024) was
+    refused by 1.86 MB), and the program fits beside the step's gradients."""
+    from benchmark import system
+    from benchmark.kinds import routed_train_steps as kind
+    from ray_tpu.models.transformer import init_params, loss_and_load
+    from ray_tpu.ops.attention import default_blocks
+
+    assert default_blocks(192) == default_blocks(192, 2) == (512, 1024) and default_blocks(192, 4) == (512, 512)
+    config = system.load_json("benchmark/configs/moonlight-16b-a3b-train-ep8.json")
+    run = config["run"]
+    cfg, cfg32 = kind.program_configs(config, system.model_module(config))
+    assert cfg32.dtype == jnp.float32 and cfg32.attention == "flash" and cfg.dtype == jnp.bfloat16
+    one = SingleDeviceSharding(v5e[0])
+    params = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one),
+                          jax.eval_shape(lambda: init_params(cfg32, jax.random.key(0))))
+    tokens = jax.ShapeDtypeStruct((run["batch"], run["seq_len"]), I32, sharding=one)
+    fn = jax.jit(jax.value_and_grad(lambda p, t: loss_and_load(cfg32, p, t), has_aux=True))
+    with jax.default_matmul_precision("highest"):
+        lowered = fn.trace(params, tokens).lower(lowering_platforms=("tpu",))
+    assert "16x8192x192xf32" in lowered.as_text()
+    m = lowered.compile().memory_analysis()
+    need = m.argument_size_in_bytes + m.temp_size_in_bytes + m.output_size_in_bytes
+    # beside it: the step's own gradients (2.49 GiB) wait for the reference
+    assert need + 2.5 * 2**30 < 15.0 * 2**30, f"the float32 gradient program needs {need / 2**30:.2f} GiB"
+
+
 @pytest.mark.parametrize("attention", ["auto", "dense", "ring"])
 def test_train_step_compiles_under_a_four_chip_mesh(as_chip, v5e, attention):
     """Every attention mode ``make_train_step`` accepts with a mesh compiles
@@ -782,3 +850,39 @@ def test_chip_smoke_last_line_is_the_verdict_and_nothing_else():
     assert last["device"] == {"platform": "cpu", "kind": "cpu", "count": jax.device_count()}
     assert report["rehearsal"] is True and report["claim"] is None and report["legs"] == {}
     assert chip_smoke.verdict(report) == last
+
+
+def test_the_digest_tool_reads_a_kernels_payload_and_not_where_its_caller_stands(as_chip):
+    """``benchmark/tools/lowered_digests.py``: a Mosaic payload is decoded
+    (the text escapes a quote as ``\\22``), parsed and printed without debug
+    information, so the same kernel called from two places in a file digests
+    alike, another kernel does not, and a payload that does not parse is
+    counted, not passed over (a comparison of unparsed payloads compares
+    nothing: PR 51's first round)."""
+    from benchmark.tools import lowered_digests as tool
+    from ray_tpu.ops.grouped_matmul import grouped_matmul
+
+    rows, w, sizes = _abstract([((64, 128), jnp.bfloat16), ((4, 128, 256), jnp.bfloat16), ((4,), I32)])
+
+    def here():
+        def product(rows, w, sizes):
+            return grouped_matmul(rows, w, sizes)
+        return product
+
+    def there():
+        def product(rows, w, sizes):
+
+            return grouped_matmul(rows, w, sizes)
+        return product
+
+    here, there = here(), there()
+
+    def lowered(fn, *args):
+        return jax.jit(fn).trace(*args).lower(lowering_platforms=("tpu",)).as_text()
+
+    a, b = tool.digest(lowered(here, rows, w, sizes)), tool.digest(lowered(there, rows, w, sizes))
+    assert a == b and a["payloads"] == 1 and a["unparsed"] == 0
+    wide = _abstract([((4, 128, 512), jnp.bfloat16)])[0]
+    assert tool.digest(lowered(here, rows, wide, sizes))["kernels"] != a["kernels"]
+    broken = lowered(here, rows, w, sizes).replace('\\22body\\22: \\22', '\\22body\\22: \\22AAAA')
+    assert tool.digest(broken)["unparsed"] == 1
