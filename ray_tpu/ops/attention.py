@@ -12,6 +12,12 @@ full-K/V-resident kernel dies at ~16k). Running max/denominator/accumulator
 state lives in VMEM scratch across inner steps; outputs are written on the
 last step (the standard revisited-output pattern).
 
+A tile pays for what it holds (``_tiles``, ``_dot``, ``_resident``): operands
+reach the MXU in the type they arrive in (bf16 x bf16 is one exact pass;
+float32 arrivals stay float32), and a grid step whose tile is skipped names
+the block already in VMEM, so the pipeline copies nothing for it.
+``tile_counts`` counts a shape's tiles that run, are crossed and are skipped.
+
 Forward saves the per-row logsumexp; backward rematerializes P blockwise in
 two kernels (dq over q-blocks, dk/dv over k-blocks — the FlashAttention-2
 split that avoids atomics), so both directions are linear in sequence memory.
@@ -29,6 +35,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -61,10 +68,105 @@ def _block_mask(q_start, k_start, block_q, block_k, causal, q_len, kv_len, windo
     return valid
 
 
+_NT = (((1,), (1,)), ((), ()))  # a [m, d] . b [n, d]^T
+_NN = (((1,), (0,)), ((), ()))  # a [m, n] . b [n, d]
+_TN = (((0,), (0,)), ((), ()))  # a [m, n]^T . b [m, d]
+
+
+def _tiles(*refs):
+    """The refs' tiles in the type they arrive in where that is bf16 for all
+    of them, else as float32."""
+    tiles = [r[...] for r in refs]
+    if all(t.dtype == jnp.bfloat16 for t in tiles):
+        return tiles
+    return [t.astype(jnp.float32) for t in tiles]
+
+
+def _dot(a, b, dims):
+    """The float32 product of two tiles. Two bf16 tiles: one pass of the MXU,
+    exact (a bf16 x bf16 product is exact in float32). A float32 factor (P,
+    dS) goes in the other tile's type: against a bf16 tile rounded to bf16,
+    which is what Mosaic makes of a float32 operand left to itself (one pass
+    of the rounded operand, the same error to the last digit; PERF.md
+    section 6, PR 52)."""
+    return jax.lax.dot_general(a.astype(b.dtype), b, dims, preferred_element_type=jnp.float32)
+
+
+def _across(x, width):
+    """A row statistic held in every lane, ``[rows, LANES]``, across ``width``
+    lanes: whole lane tiles repeated, no ``[rows, 1]`` column to broadcast
+    (a column costs the forward 8% of its time on the chip; PERF.md, PR 52)."""
+    if width % _LANES == 0:
+        return x if width == _LANES else pltpu.repeat(x, width // _LANES, axis=1)
+    if width < _LANES:
+        return x[:, :width]
+    return jnp.broadcast_to(x[:, :1], (x.shape[0], width))
+
+
+def _tile_runs(q_start, k_start, block_q, block_k, causal, window):
+    """Whether some pair of the tile at (q_start, k_start) may be visible: a
+    tile above the causal diagonal, or wholly left of the sliding window, has
+    none and is skipped. True where the call has neither."""
+    run = True
+    if causal:
+        run = k_start <= q_start + block_q - 1
+    if window is not None:
+        # the EARLIEST query (i = q_start) has the loosest bound j > i - window
+        run = run & (k_start + block_k - 1 > q_start - window)
+    return run
+
+
+def _k_blocks_run(i, block_q, block_k, num_k, causal, window):
+    """(first, last) K block whose tile runs for query block ``i``:
+    :func:`_tile_runs` solved for the block index."""
+    first, last = 0, num_k - 1
+    if causal:
+        last = jnp.minimum(last, (i * block_q + block_q - 1) // block_k)
+    if window is not None:
+        first = jnp.maximum(i * block_q - window + 1, 0) // block_k
+    return first, last
+
+
+def _q_blocks_run(j, block_q, block_k, num_q, causal, window):
+    """(first, last) query block whose tile runs for K block ``j``."""
+    first, last = 0, num_q - 1
+    if causal:
+        first = jnp.minimum(j * block_k // block_q, last)
+    if window is not None:
+        last = jnp.minimum(last, (j * block_k + block_k + window - 2) // block_q)
+    return first, last
+
+
+def _resident(step, first, last):
+    """The streamed block a grid step names: its own where its tile runs, else
+    the nearest one that does, which is the block already in VMEM, so the
+    pipeline issues no copy for a step that is skipped."""
+    if isinstance(first, int) and isinstance(last, int):
+        return step  # the whole grid: every tile runs
+    return jnp.clip(step, first, last)
+
+
+def tile_counts(Tq, Tk, block_q, block_k, causal, window=None):
+    """(run, crossed, skipped) tiles a head of a call's grid; static per
+    shape. A tile runs by the kernels' own predicate; one that runs is crossed
+    if the diagonal, the window's edge or a padded tail hides some pair of it
+    (the kernels mask every tile alike: the count sizes a block shape,
+    PERF.md section 7)."""
+    bq, bk = min(block_q, Tq), min(block_k, Tk)
+    q_start = np.arange(-(-Tq // bq))[:, None] * bq
+    k_start = np.arange(-(-Tk // bk))[None, :] * bk
+    run = np.broadcast_to(_tile_runs(q_start, k_start, bq, bk, causal, window), (q_start.size, k_start.size))
+    clear = (q_start + bq <= Tq) & (k_start + bk <= Tk)
+    if causal:
+        clear = clear & (k_start + bk - 1 <= q_start)
+    if window is not None:
+        clear = clear & (k_start > q_start + bq - 1 - window)
+    return int(run.sum()), int((run & ~clear).sum()), int((~run).sum())
+
+
 def _attn_fwd_kernel(
     q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
-    *, sm_scale: float, causal: bool, block_q: int, block_k: int, kv_len: int, tk_padded: int,
-    window=None,
+    *, sm_scale: float, causal: bool, block_q: int, block_k: int, kv_len, window=None,
 ):
     """Grid (bh, q_block, k_block); k innermost streams K/V through VMEM.
 
@@ -73,7 +175,8 @@ def _attn_fwd_kernel(
     have keys of 192 and values of 128); o_ref: [block_q, Dv]; lse_ref:
     [1, block_q] (this q-block's slice — per-block mapping keeps stores
     statically aligned and Megacore-safe); scratch: m/l [block_q, LANES]
-    lane-replicated, acc [block_q, Dv].
+    lane-replicated, acc [block_q, Dv]. ``kv_len``: the keys' true length
+    where their tail is padded, else None.
     """
     qi = pl.program_id(1)
     ki = pl.program_id(2)
@@ -87,38 +190,26 @@ def _attn_fwd_kernel(
 
     q_start = qi * block_q
     k_start = ki * block_k
-    # Skip blocks with no visible (q, k) pair: above the causal diagonal,
-    # or entirely left of the sliding window.
-    run = jnp.asarray(True) if not causal else (k_start <= q_start + block_q - 1)
-    if window is not None:
-        run = jnp.logical_and(run, k_start + block_k - 1 > q_start - window)
 
-    @pl.when(run)
+    @pl.when(_tile_runs(q_start, k_start, block_q, block_k, causal, window))
     def _step():
-        q = q_ref[...].astype(jnp.float32) * sm_scale
-        k = k_ref[...].astype(jnp.float32)
-        v = v_ref[...].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-        valid = _block_mask(
-            q_start, k_start, block_q, block_k, causal,
-            None, kv_len if kv_len < tk_padded else None, window=window,
-        )
+        q, k, v = _tiles(q_ref, k_ref, v_ref)
+        if q.dtype == jnp.bfloat16:
+            s = _dot(q, k, _NT) * sm_scale  # as both backward kernels recompute it
+        else:
+            s = _dot(q * sm_scale, k, _NT)
+        valid = _block_mask(q_start, k_start, block_q, block_k, causal, None, kv_len, window=window)
         if valid is not None:
             s = jnp.where(valid, s, NEG_INF)
-        m_prev = m_scr[:, :1]                      # [bq, 1]
-        l_prev = l_scr[:, :1]
-        m_blk = s.max(axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_blk)
+        m_prev, l_prev = m_scr[...], l_scr[...]    # [bq, LANES], every lane the row's
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
         alpha = jnp.exp(jnp.where(m_prev == NEG_INF, NEG_INF, m_prev - m_new))
-        p = jnp.exp(s - m_new)
+        p = jnp.exp(s - _across(m_new, block_k))
         if valid is not None:
             p = jnp.where(valid, p, 0.0)
-        l_new = l_prev * alpha + p.sum(axis=-1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
+        l_scr[...] = l_prev * alpha + p.sum(axis=-1, keepdims=True)
+        m_scr[...] = m_new
+        acc_scr[...] = acc_scr[...] * _across(alpha, acc_scr.shape[1]) + _dot(p, v, _NN)
 
     @pl.when(ki == num_k - 1)
     def _final():
@@ -140,6 +231,11 @@ def _pad_to(x, axis, multiple):
     return jnp.pad(x, widths)
 
 
+def _kv_map(block_q, block_k, num_k, causal, window):
+    """The K/V index map of a (bh, q_block, k_block) grid."""
+    return lambda bh, i, j: (bh, _resident(j, *_k_blocks_run(i, block_q, block_k, num_k, causal, window)), 0)
+
+
 def _flash_forward(q, k, v, sm_scale: float, causal: bool, block_q: int, block_k: int, interpret: bool, window=None):
     """Returns (out [B,H,Tq,Dv], lse [B*H, 1, Tq_padded]). q, k: [B,H,T,D]; v: [B,H,Tk,Dv]."""
     B, H, Tq, D = q.shape
@@ -157,10 +253,11 @@ def _flash_forward(q, k, v, sm_scale: float, causal: bool, block_q: int, block_k
     vf = v.reshape(B * H, Tk_p, Dv)
 
     grid = (B * H, Tq_p // bq, Tk_p // bk)
+    kv_map = _kv_map(bq, bk, grid[2], causal, window)
     out, lse = pl.pallas_call(
         functools.partial(
             _attn_fwd_kernel, sm_scale=sm_scale, causal=causal,
-            block_q=bq, block_k=bk, kv_len=Tk, tk_padded=Tk_p, window=window,
+            block_q=bq, block_k=bk, kv_len=Tk if Tk < Tk_p else None, window=window,
         ),
         out_shape=[
             jax.ShapeDtypeStruct((B * H, Tq_p, Dv), q.dtype),
@@ -169,8 +266,8 @@ def _flash_forward(q, k, v, sm_scale: float, causal: bool, block_q: int, block_k
         grid=grid,
         in_specs=[
             pl.BlockSpec((None, bq, D), lambda bh, i, j: (bh, i, 0)),
-            pl.BlockSpec((None, bk, D), lambda bh, i, j: (bh, j, 0)),
-            pl.BlockSpec((None, bk, Dv), lambda bh, i, j: (bh, j, 0)),
+            pl.BlockSpec((None, bk, D), kv_map),
+            pl.BlockSpec((None, bk, Dv), kv_map),
         ],
         out_specs=[
             pl.BlockSpec((None, bq, Dv), lambda bh, i, j: (bh, i, 0)),
@@ -191,7 +288,7 @@ def _flash_forward(q, k, v, sm_scale: float, causal: bool, block_q: int, block_k
 
 def _bwd_dq_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_scr,
-    *, sm_scale, causal, block_q, block_k, kv_len, tk_padded, window=None,
+    *, sm_scale, causal, block_q, block_k, kv_len, window=None,
 ):
     """Grid (bh, q_block, k_block); streams K/V. dq accumulates in scratch.
 
@@ -208,31 +305,20 @@ def _bwd_dq_kernel(
 
     q_start = qi * block_q
     k_start = ki * block_k
-    run = jnp.asarray(True) if not causal else (k_start <= q_start + block_q - 1)
-    if window is not None:
-        run = jnp.logical_and(run, k_start + block_k - 1 > q_start - window)
 
-    @pl.when(run)
+    @pl.when(_tile_runs(q_start, k_start, block_q, block_k, causal, window))
     def _step():
-        q = q_ref[...].astype(jnp.float32)
-        do = do_ref[...].astype(jnp.float32)
+        q, k, v, do = _tiles(q_ref, k_ref, v_ref, do_ref)
         lse = lse_ref[0, :]
         delta = delta_ref[0, :]
-        k = k_ref[...].astype(jnp.float32)
-        v = v_ref[...].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32) * sm_scale
+        s = _dot(q, k, _NT) * sm_scale
         p = jnp.exp(s - lse[:, None])
-        valid = _block_mask(
-            q_start, k_start, block_q, block_k, causal,
-            None, kv_len if kv_len < tk_padded else None, window=window,
-        )
+        valid = _block_mask(q_start, k_start, block_q, block_k, causal, None, kv_len, window=window)
         if valid is not None:
             p = jnp.where(valid, p, 0.0)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+        dp = _dot(do, v, _NT)
         ds = p * (dp - delta[:, None])
-        dq_scr[...] = dq_scr[...] + jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
+        dq_scr[...] = dq_scr[...] + _dot(ds, k, _NN)
 
     @pl.when(ki == num_k - 1)
     def _final():
@@ -241,12 +327,13 @@ def _bwd_dq_kernel(
 
 def _bwd_dkv_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref, dk_scr, dv_scr,
-    *, sm_scale, causal, block_q, block_k, q_len, kv_len, tq_padded, tk_padded, window=None,
+    *, sm_scale, causal, block_q, block_k, q_len, kv_len, window=None,
 ):
     """Grid (bh, k_block, q_block); streams Q/dO. dk/dv accumulate in scratch.
 
     k/dk: [block_k, D]; v/dv: [block_k, Dv]; q: [block_q, D]; do: [block_q, Dv];
-    lse/delta: [1, block_q].
+    lse/delta: [1, block_q]. ``q_len``/``kv_len``: the true length where that
+    tail is padded, else None.
     """
     ki = pl.program_id(1)
     qi = pl.program_id(2)
@@ -259,38 +346,21 @@ def _bwd_dkv_kernel(
 
     q_start = qi * block_q
     k_start = ki * block_k
-    run = jnp.asarray(True) if not causal else (q_start + block_q - 1 >= k_start)
-    if window is not None:
-        # any-visible-pair condition: the EARLIEST query (i = q_start) has
-        # the loosest window bound j > i - window, so the pair is live iff
-        # the latest key clears it (same guard as the dq kernel)
-        run = jnp.logical_and(run, k_start + block_k - 1 > q_start - window)
 
-    @pl.when(run)
+    @pl.when(_tile_runs(q_start, k_start, block_q, block_k, causal, window))
     def _step():
-        qs = q_ref[...].astype(jnp.float32)
-        do = do_ref[...].astype(jnp.float32)
+        qs, k, v, do = _tiles(q_ref, k_ref, v_ref, do_ref)
         lse = lse_ref[0, :]
         delta = delta_ref[0, :]
-        k = k_ref[...].astype(jnp.float32)
-        v = v_ref[...].astype(jnp.float32)
-        s = jax.lax.dot_general(qs, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32) * sm_scale
+        s = _dot(qs, k, _NT) * sm_scale
         p = jnp.exp(s - lse[:, None])
-        valid = _block_mask(
-            q_start, k_start, block_q, block_k, causal,
-            q_len if q_len < tq_padded else None,
-            kv_len if kv_len < tk_padded else None, window=window,
-        )
+        valid = _block_mask(q_start, k_start, block_q, block_k, causal, q_len, kv_len, window=window)
         if valid is not None:
             p = jnp.where(valid, p, 0.0)
-        dv_scr[...] = dv_scr[...] + jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+        dv_scr[...] = dv_scr[...] + _dot(p, do, _TN)
+        dp = _dot(do, v, _NT)
         ds = p * (dp - delta[:, None])
-        dk_scr[...] = dk_scr[...] + jax.lax.dot_general(
-            ds, qs, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
+        dk_scr[...] = dk_scr[...] + _dot(ds, qs, _TN)
 
     @pl.when(qi == num_q - 1)
     def _final():
@@ -322,17 +392,24 @@ def _flash_backward(q, k, v, out, lse, g, sm_scale, causal, block_q, block_k, in
         glp = _pad_to(g_lse.astype(jnp.float32).reshape(B * H, Tq), 1, bq)
         delta = delta - glp[:, None, :]
 
+    q_len, kv_len = Tq if Tq < Tq_p else None, Tk if Tk < Tk_p else None
+    num_q, num_k = Tq_p // bq, Tk_p // bk
+    kv_map = _kv_map(bq, bk, num_k, causal, window)
+
+    def q_block(j, i):  # of the (bh, k_block, q_block) grid
+        return _resident(i, *_q_blocks_run(j, bq, bk, num_q, causal, window))
+
     dq = pl.pallas_call(
         functools.partial(
             _bwd_dq_kernel, sm_scale=sm_scale, causal=causal,
-            block_q=bq, block_k=bk, kv_len=Tk, tk_padded=Tk_p, window=window,
+            block_q=bq, block_k=bk, kv_len=kv_len, window=window,
         ),
         out_shape=jax.ShapeDtypeStruct((B * H, Tq_p, D), q.dtype),
-        grid=(B * H, Tq_p // bq, Tk_p // bk),
+        grid=(B * H, num_q, num_k),
         in_specs=[
             pl.BlockSpec((None, bq, D), lambda bh, i, j: (bh, i, 0)),
-            pl.BlockSpec((None, bk, D), lambda bh, i, j: (bh, j, 0)),
-            pl.BlockSpec((None, bk, Dv), lambda bh, i, j: (bh, j, 0)),
+            pl.BlockSpec((None, bk, D), kv_map),
+            pl.BlockSpec((None, bk, Dv), kv_map),
             pl.BlockSpec((None, bq, Dv), lambda bh, i, j: (bh, i, 0)),
             pl.BlockSpec((None, 1, bq), lambda bh, i, j: (bh, 0, i)),
             pl.BlockSpec((None, 1, bq), lambda bh, i, j: (bh, 0, i)),
@@ -348,21 +425,20 @@ def _flash_backward(q, k, v, out, lse, g, sm_scale, causal, block_q, block_k, in
     dk, dv = pl.pallas_call(
         functools.partial(
             _bwd_dkv_kernel, sm_scale=sm_scale, causal=causal,
-            block_q=bq, block_k=bk, q_len=Tq, kv_len=Tk, tq_padded=Tq_p, tk_padded=Tk_p,
-            window=window,
+            block_q=bq, block_k=bk, q_len=q_len, kv_len=kv_len, window=window,
         ),
         out_shape=[
             jax.ShapeDtypeStruct((B * H, Tk_p, D), k.dtype),
             jax.ShapeDtypeStruct((B * H, Tk_p, Dv), v.dtype),
         ],
-        grid=(B * H, Tk_p // bk, Tq_p // bq),
+        grid=(B * H, num_k, num_q),
         in_specs=[
-            pl.BlockSpec((None, bq, D), lambda bh, j, i: (bh, i, 0)),
+            pl.BlockSpec((None, bq, D), lambda bh, j, i: (bh, q_block(j, i), 0)),
             pl.BlockSpec((None, bk, D), lambda bh, j, i: (bh, j, 0)),
             pl.BlockSpec((None, bk, Dv), lambda bh, j, i: (bh, j, 0)),
-            pl.BlockSpec((None, bq, Dv), lambda bh, j, i: (bh, i, 0)),
-            pl.BlockSpec((None, 1, bq), lambda bh, j, i: (bh, 0, i)),
-            pl.BlockSpec((None, 1, bq), lambda bh, j, i: (bh, 0, i)),
+            pl.BlockSpec((None, bq, Dv), lambda bh, j, i: (bh, q_block(j, i), 0)),
+            pl.BlockSpec((None, 1, bq), lambda bh, j, i: (bh, 0, q_block(j, i))),
+            pl.BlockSpec((None, 1, bq), lambda bh, j, i: (bh, 0, q_block(j, i))),
         ],
         out_specs=[
             pl.BlockSpec((None, bk, D), lambda bh, j, i: (bh, j, 0)),

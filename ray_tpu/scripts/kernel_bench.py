@@ -41,7 +41,7 @@ def _timed_scan(step_fn: Callable, init_carry, iters: int) -> float:
 
 def _sync(tree) -> None:
     leaf = jax.block_until_ready(jax.tree_util.tree_leaves(tree)[0])
-    np.asarray(jax.device_get(leaf)).ravel()[:1]
+    np.asarray(leaf.reshape(-1)[:1])  # a host read of one number, not of the array
 
 
 # ---------------------------------------------------------------------------
@@ -75,21 +75,184 @@ def bench_decode(B=8, H=16, Hkv=4, D=128, S=4096, iters=50) -> Dict[str, float]:
             "speedup": t_dense / t_kernel}
 
 
-def bench_flash_blocks(B=1, H=8, T=8192, D=128, iters=8) -> Dict[str, float]:
-    """Flash fwd across block-size configs at T=8k (fits alongside scan)."""
-    from ray_tpu.ops.attention import flash_attention
+def _kernel_seconds(fn: Callable, args, iters: int) -> float:
+    """Device seconds the Mosaic kernels of a jitted ``fn`` take a call: the
+    custom-calls' durations in a profiler trace of ``iters`` calls (the
+    kernel alone: no dispatch, no XLA operation beside it)."""
+    import glob
+    import tempfile
 
-    key = jax.random.key(1)
-    q = jax.random.normal(key, (B, H, T, D), jnp.bfloat16)
-    k = jax.random.normal(key, (B, H, T, D), jnp.bfloat16)
-    v = jax.random.normal(key, (B, H, T, D), jnp.bfloat16)
+    from jax.profiler import ProfileData
 
+    _sync(fn(*args))  # compile + warm
+    with tempfile.TemporaryDirectory() as trace_dir:
+        with jax.profiler.trace(trace_dir):
+            for _ in range(iters):
+                out = fn(*args)
+            _sync(out)
+        data = ProfileData.from_file(sorted(glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True))[-1])
+    lines = [line for plane in data.planes if plane.name == "/device:TPU:0" for line in plane.lines if line.name == "XLA Ops"]
+    ns = sum(ev.duration_ns for line in lines for ev in line.events if " custom-call(" in ev.name)
+    if not ns:
+        raise SystemExit("no custom-call in the device trace: not a compiled Mosaic kernel")
+    return ns / iters / 1e9
+
+
+def _flash_inputs(B, H, Tq, Tk, D, Dv, dtype=jnp.bfloat16, seed=1):
+    """q, k, v and the output's cotangent, from ``seed``."""
+    kq, kk, kv, kg = jax.random.split(jax.random.key(seed), 4)
+    return (jax.random.normal(kq, (B, H, Tq, D), dtype), jax.random.normal(kk, (B, H, Tk, D), dtype),
+            jax.random.normal(kv, (B, H, Tk, Dv), dtype), jax.random.normal(kg, (B, H, Tq, Dv), dtype))
+
+
+def bench_flash_kernels(B=1, H=16, Tq=8192, Tk=None, D=192, Dv=128, causal=True, window=None, block_q=None,
+                        block_k=None, iters=10) -> Dict[str, float]:
+    """The three flash kernels apart, ms a call: the forward, the queries'
+    gradient and the keys' and values' (each backward program keeps one
+    ``pallas_call`` and XLA drops the other; both hold the row sum ``delta``,
+    an elementwise pass over ``dO`` and ``O``). Keys of ``D``, values of
+    ``Dv``; ``Tk`` defaults to ``Tq``. ``tiles``: ``tile_counts`` of the call."""
+    from ray_tpu.ops import attention
+
+    Tk = Tk or Tq
+    q, k, v, do = _flash_inputs(B, H, Tq, Tk, D, Dv)
+    default_q, default_k = attention.default_blocks(D, q.dtype.itemsize)
+    bq, bk = block_q or default_q, block_k or default_k
+    scale = D ** -0.5
+
+    # through the module, so a caller can stand another tree's kernels in their place
+    def fwd(q, k, v):
+        return attention._flash_forward(q, k, v, scale, causal, bq, bk, attention._use_interpret(), window=window)
+
+    def bwd(q, k, v, out, lse, do):
+        return attention._flash_backward(q, k, v, out, lse, do, scale, causal, bq, bk, attention._use_interpret(), window=window)
+
+    out, lse = jax.jit(fwd)(q, k, v)
+    res = (q, k, v, out, lse, do)
+    return {
+        "fwd_ms": _kernel_seconds(jax.jit(fwd), (q, k, v), iters) * 1e3,
+        "dq_ms": _kernel_seconds(jax.jit(lambda *a: bwd(*a)[0]), res, iters) * 1e3,
+        "dkv_ms": _kernel_seconds(jax.jit(lambda *a: bwd(*a)[1:]), res, iters) * 1e3,
+        "tiles": attention.tile_counts(Tq, Tk, bq, bk, causal, window),
+    }
+
+
+def flash_errors(B=1, H=16, Tq=2048, Tk=None, D=192, Dv=128, causal=True, window=None, seed=1) -> Dict[str, dict]:
+    """``out``, ``lse``, ``dq``, ``dk``, ``dv`` of the flash kernels at bf16
+    inputs against dense float32 attention of the same inputs at
+    ``Precision.HIGHEST`` (keep T <= 2048: the reference holds the scores).
+    ``rel_rms``: the relative RMS error; the outputs are bf16, so ~1.6e-3 is
+    their rounding alone. ``rounded_apart``: the share of numbers that differ
+    from the reference rounded to bf16, which sees what ``rel_rms`` cannot:
+    an error inside the kernel a hundredth of the last bit moves about that
+    share of the numbers across a rounding boundary."""
+    from ray_tpu.ops import attention
+
+    Tk = Tk or Tq
+    q, k, v, do = _flash_inputs(B, H, Tq, Tk, D, Dv, seed=seed)
+    scale = D ** -0.5
+
+    def dense(q, k, v):
+        hi = jax.lax.Precision.HIGHEST
+        s = jnp.einsum("bhqd,bhkd->bhqk", q, k, precision=hi) * scale
+        i, j = jnp.arange(Tq)[:, None], jnp.arange(Tk)[None, :]
+        seen = jnp.ones((Tq, Tk), bool) if not causal else j <= i
+        if window is not None:
+            seen = seen & (j > i - window)
+        s = jnp.where(seen, s, attention.NEG_INF)
+        lse = jax.nn.logsumexp(s, axis=-1)
+        return jnp.einsum("bhqk,bhkd->bhqd", jnp.exp(s - lse[..., None]), v, precision=hi), lse
+
+    def flash(q, k, v):
+        return attention.flash_attention_with_lse(q, k, v, scale, causal, None, None, window)
+
+    f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+    (want, want_lse), pull = jax.vjp(dense, *f32)
+    (got, got_lse), pull_flash = jax.vjp(flash, q, k, v)
+    wants = (want, want_lse) + pull((do.astype(jnp.float32), jnp.zeros_like(want_lse)))
+    gots = (got, got_lse) + pull_flash((do, jnp.zeros_like(got_lse)))
+    rms = lambda x: float(jnp.sqrt(jnp.mean(jnp.square(x.astype(jnp.float32)))))  # noqa: E731
+    names = ("out", "lse", "dq", "dk", "dv")
+    return {"rel_rms": {n: rms(g.astype(jnp.float32) - w) / rms(w) for n, g, w in zip(names, gots, wants)},
+            "rounded_apart": {n: float(jnp.mean(g != w.astype(g.dtype))) for n, g, w in zip(names, gots, wants) if n != "lse"}}
+
+
+def bench_flash_blocks(B=1, H=8, T=8192, D=128, Dv=None, iters=8) -> Dict[str, float]:
+    """The flash kernels across block-size configs at T=8k, fwd, dq and dkv apart."""
     out = {}
     for bq, bk in ((128, 128), (256, 512), (512, 1024)):
-        def step(q, bq=bq, bk=bk):
-            return flash_attention(q, k, v, causal=True, block_q=bq, block_k=bk).astype(q.dtype)
+        got = bench_flash_kernels(B, H, T, None, D, Dv or D, block_q=bq, block_k=bk, iters=iters)
+        out.update({f"flash_{bq}x{bk}_{k}": v for k, v in got.items() if k != "tiles"})
+    return out
 
-        out[f"flash_{bq}x{bk}_ms"] = _timed_scan(step, q, iters) * 1e3
+
+# the training cells' flash calls (B, H, Tq, Tk, D, Dv, causal): Moonlight's
+# expanded latent heads, SmolLM2 at depth 8, and the ring at tp2 x sp2: its own
+# zigzag shard under the causal mask and a hop against half a peer's, unmasked
+FLASH_CELL_CALLS = {
+    "moonlight": (1, 16, 8192, 8192, 192, 128, True),
+    "train-l8": (4, 32, 2048, 2048, 64, 64, True),
+    "ring4-own": (1, 16, 4096, 4096, 64, 64, True),
+    "ring4-hop": (1, 16, 4096, 2048, 64, 64, False),
+}
+
+
+def bench_flash_cells(iters=10, calls=None) -> Dict[str, dict]:
+    """Time (fwd, dq, dkv apart) and error of the flash kernels at each of the
+    training cells' calls; the error at 2048 positions of the same heads."""
+    out = {}
+    for name in calls or FLASH_CELL_CALLS:
+        B, H, Tq, Tk, D, Dv, causal = FLASH_CELL_CALLS[name]
+        out[name] = bench_flash_kernels(B, H, Tq, Tk, D, Dv, causal, iters=iters)
+        out[name]["error"] = flash_errors(1, H, 2048, 2048 * Tk // Tq, D, Dv, causal)
+    return out
+
+
+def bench_factor_product(D=128, steps=256, iters=10) -> Dict[str, dict]:
+    """How the MXU takes a float32 factor: one ``P [512, 1024] . V [1024, D]``
+    product a grid step from resident tiles, float32 out. ``f32``: both tiles
+    float32 (what a kernel that casts its bf16 operands up hands Mosaic);
+    ``terms N``: ``P`` split into N bf16 terms, a pass each, against the bf16
+    ``V`` (a float32 is three such terms exactly; one term is what
+    ``ops/attention.py::_dot`` does). us a product, and the error against the
+    float64 product of the same numbers."""
+    from jax.experimental import pallas as pl
+
+    def dot(a, b):
+        return jax.lax.dot_general(a, b, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+
+    kp, kv = jax.random.split(jax.random.key(2))
+    p = jax.random.uniform(kp, (512, 1024), jnp.float32)
+    v = jax.random.normal(kv, (1024, D), jnp.bfloat16)
+    want = np.asarray(p, np.float64) @ np.asarray(v.astype(jnp.float32), np.float64)
+
+    def run(terms):
+        def kernel(p_ref, v_ref, o_ref):
+            rest, v = p_ref[...], v_ref[...]
+            if not terms:
+                o_ref[...] = dot(rest, v.astype(jnp.float32))
+                return
+            term = rest.astype(jnp.bfloat16)
+            out = dot(term, v)
+            for _ in range(terms - 1):
+                rest = rest - term.astype(jnp.float32)
+                term = rest.astype(jnp.bfloat16)
+                out = out + dot(term, v)
+            o_ref[...] = out
+
+        return jax.jit(pl.pallas_call(
+            kernel, out_shape=jax.ShapeDtypeStruct((steps * 512, D), jnp.float32), grid=(steps,),
+            in_specs=[pl.BlockSpec((512, 1024), lambda i: (0, 0)), pl.BlockSpec((1024, D), lambda i: (0, 0))],
+            out_specs=pl.BlockSpec((512, D), lambda i: (i, 0))))
+
+    out = {}
+    for terms in (0, 1, 2, 3):
+        fn = run(terms)
+        err = np.asarray(fn(p, v)[:512], np.float64) - want
+        out["f32" if not terms else f"terms {terms}"] = {
+            "us": _kernel_seconds(fn, (p, v), iters) / steps * 1e6,
+            "rel_rms_error": float(np.sqrt(np.mean(err ** 2) / np.mean(want ** 2))),
+        }
     return out
 
 
@@ -97,6 +260,7 @@ def main(argv=None) -> None:
     """Examples:
 
         python -m ray_tpu.scripts.kernel_bench                 # decode + 8k/D=128
+        python -m ray_tpu.scripts.kernel_bench --flash-cells   # the training cells' calls: time and error
         python -m ray_tpu.scripts.kernel_bench --T 32768 --D 64 --H 4 --iters 2
         python -m ray_tpu.scripts.kernel_bench --T 8192 --D 64 --iters 4
     """
@@ -108,7 +272,10 @@ def main(argv=None) -> None:
     parser.add_argument("--D", type=int, default=128)
     parser.add_argument("--H", type=int, default=8)
     parser.add_argument("--iters", type=int, default=8)
+    parser.add_argument("--Dv", type=int, default=None, help="the values' size (default: D)")
     parser.add_argument("--skip-decode", action="store_true")
+    parser.add_argument("--flash-cells", action="store_true",
+                        help="the flash kernels at the training cells' calls, and the float32-factor probe; nothing else")
     args = parser.parse_args(argv)
 
     from ray_tpu.ops import backend
@@ -120,11 +287,15 @@ def main(argv=None) -> None:
             f"{dev.platform!r}, not a TPU"
         )
     backend.use_compile_cache()
-    results = {"device": getattr(dev, "device_kind", str(dev)),
-               "shape": f"T={args.T} D={args.D} H={args.H}"}
+    results = {"device": getattr(dev, "device_kind", str(dev))}
+    if args.flash_cells:
+        results.update(bench_flash_cells(args.iters), factor_product=bench_factor_product(iters=args.iters))
+        print(json.dumps(results))
+        return
+    results["shape"] = f"T={args.T} D={args.D} Dv={args.Dv or args.D} H={args.H}"
     if not args.skip_decode:
         results.update(bench_decode())
-    results.update(bench_flash_blocks(H=args.H, T=args.T, D=args.D, iters=args.iters))
+    results.update(bench_flash_blocks(H=args.H, T=args.T, D=args.D, Dv=args.Dv, iters=args.iters))
     print(json.dumps({k: (round(v, 2) if isinstance(v, float) else v) for k, v in results.items()}))
 
 

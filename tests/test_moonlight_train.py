@@ -8,9 +8,7 @@ a cotangent, the routers' load rule, and the refusals that remain. Toy size
 heads of 16 + 8 / 16, rank 32), float32, CPU.
 """
 
-import hashlib
 import os
-import re
 import sys
 
 import jax
@@ -26,7 +24,7 @@ from ray_tpu.models.generation import init_cache, init_paged_cache  # noqa: E402
 from ray_tpu.models.transformer import (TransformerConfig, forward, forward_and_load, init_params,  # noqa: E402
                                         latent_attention_expanded, loss_fn, make_train_step, moe_ffn_dropless,
                                         param_specs)
-from ray_tpu.ops.attention import flash_attention_with_lse  # noqa: E402
+from ray_tpu.ops.attention import flash_attention_with_lse, mha  # noqa: E402
 
 C = dict(model="moonlight", vocab_size=256, hidden_size=64, intermediate_size=128, num_hidden_layers=4,
          num_attention_heads=4, num_key_value_heads=4, hidden_act="silu", max_position_embeddings=128,
@@ -152,14 +150,12 @@ def test_the_flash_kernel_at_equal_sizes_is_unchanged_and_a_value_size_of_its_ow
         (out, lse), vjp = jax.vjp(lambda q, k, v: flash_attention_with_lse(q, k, v, 0.2, True, 16, 32, None), q, k, v)
         return out, lse, vjp((g, jnp.ones_like(lse)))
 
-    # equal sizes: the traced program (kernels, block specs, shapes; source lines cut) is, letter for
-    # letter, what it was before the values got a size of their own (sha256 taken at that commit)
-    same = jnp.zeros((1, 2, 48, 16))
-    text = re.sub(r" at [^\s\]\)]*:\d+", "", str(jax.make_jaxpr(run)(same, same, same, same)))
-    assert hashlib.sha256(text.encode()).hexdigest() == "da23bbed73ee671074c7b60c58d5b9b81f1e206a514809b7e6e710b614ed217c"
     # a size of its own: what the equal-size kernel gives on values zero-padded to the keys' size
+    # (PR 51 also pinned the equal-size program's text, letter for letter; PR 52 rewrote the tile
+    # bodies, so equal sizes are held to the dense lines instead)
     own, lse, (dq, dk, dv) = run(q, k, v, g)
     eq, lse_eq, (dq_eq, dk_eq, dv_eq) = run(q, k, pad(v), pad(g))
+    np.testing.assert_allclose(eq, mha(q, k, pad(v), causal=True, sm_scale=0.2), rtol=1e-5, atol=1e-5)
     assert own.shape == (1, 2, 48, 16) and dv.shape == v.shape and dq.shape == q.shape
     for a, b in ((own, eq[..., :16]), (lse, lse_eq), (dq, dq_eq), (dk, dk_eq), (dv, dv_eq[..., :16])):
         np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)

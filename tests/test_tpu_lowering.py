@@ -80,6 +80,13 @@ def _kernel_cases():
     qkv = [((f["B"], f["H"], f["T"], f["D"]), BF16)] * 3
     yield "flash_fwd", flash_attention, qkv
     yield "flash_bwd", _flash_grad, qkv
+    # the training cells' calls, forward and both backward kernels: bf16 operands
+    # as they arrive, P and dS in bf16 terms, transposed where the keys' and
+    # values' gradient contracts over the queries (Moonlight: keys of 192, values of 128)
+    for cell, (b, h, t, dk, dv) in {"moonlight": (1, 16, 8192, 192, 128), "train_l8": (4, 32, 2048, 64, 64)}.items():
+        shapes = [((b, h, t, dk), BF16)] * 2 + [((b, h, t, dv), BF16)]
+        yield f"flash_fwd_{cell}", flash_attention, shapes
+        yield f"flash_bwd_{cell}", _flash_grad, shapes
     for T in p["widths"]:
         yield f"prefill_T{T}", flash_attention, [((1, p["H"], T, p["D"]), BF16)] * 3
     for T in (33, 100, 2049):  # ragged: padded to the block, tail masked in-kernel
