@@ -75,6 +75,9 @@ FULL = dict(
     # x 4 positions, 32 query heads over 4 KV heads of 128, ~950 cached tokens
     # a row in a table of 256 pages
     block_step=dict(B=48, H=32, Hkv=4, D=128, Bk=4, M=256, tokens=950, reps=64),
+    # (name, pools, lanes a row, sequences, rows a sequence): the pools' write at the served families' rows
+    paged_write=(("smollm2_decode", 2, 2048, 40, 1), ("smollm2_chunk", 2, 2048, 1, 512), ("sdar_block_step", 2, 512, 48, 4),
+                 ("olmo_hybrid_decode", 2, 3840, 32, 1), ("kimi_latent_decode", 1, 640, 64, 1), ("kimi_latent_chunk", 1, 640, 1, 512)),
     # (name, tokens, a, b, layers, experts, k, live tokens): the expert layer's
     # up projection at the served MoE cells' shapes: a block step's 48 x 4
     # tokens and a prefill chunk's 512 over all 128 experts of one layer of
@@ -98,6 +101,7 @@ REHEARSAL = dict(
     paged_decode=(("packed", 2, 4, 4, 64), ("grouped", 3, 16, 2, 128)),
     paged_prefill=(("mha", 2, 2, 64, 32, 48, 20, None, 8), ("gqa_sliding", 8, 1, 128, 32, 80, 32, 40, 8)),
     block_step=dict(B=3, H=8, Hkv=2, D=64, Bk=4, M=8, tokens=70, reps=2),
+    paged_write=(("decode", 2, 256, 3, 1), ("block_step", 2, 128, 3, 4), ("latent_chunk", 1, 128, 1, 32)),
     grouped=dict(reps=2, shapes=(("all_live", 24, 128, 128, 2, 8, 2, 24), ("two_live", 12, 128, 256, 3, 8, 2, 2))),
     serve=dict(slots=4, seq=128, requests=8, prompt=32, new=8),
     train=dict(steps=4),
@@ -401,9 +405,41 @@ def leg_kernels(sz, on_chip):
             us = 1e6 * (time.perf_counter() - t0) / sz["grouped"]["reps"]
             grouped[name][label] = {"us_a_call": round(us, 1), "live_GB_per_s": round(live_bytes / us / 1e3, 1)}
         del rows, w, out, ref
+
+    # the pools' write (``paged_write_rows``), compiled, against the scatter it
+    # replaces on the chip: a call's new rows into layer 1 of the pools, every
+    # page but the garbage page 0 bit for bit. A decode call (a row a slot at a
+    # random offset, the last slot idle), a block step's few rows, a chunk from
+    # inside a page with a padded tail
+    from ray_tpu.models.generation import _paged_write_index
+    from ray_tpu.ops.decode_attention import paged_write_rows, paged_write_segments
+
+    written = {}
+    for name, n, lanes, B, T in sz["paged_write"]:
+        rng = np.random.default_rng(len(written))
+        M = -(-(3 * bs + 5 + T) // bs) + 1
+        N = B * M + 1
+        tables = rng.permutation(np.arange(1, N)).reshape(B, M).astype(np.int32)
+        keep = np.full(B, T if T < bs else T - 3)
+        if B > 1:
+            tables[-1], keep[-1] = 0, 0  # an idle slot
+        starts = rng.integers(0, 2 * bs // T, size=B) * T if T < bs else np.full(B, 3 * bs + 5)
+        positions = jnp.asarray(starts[:, None] + np.arange(T)[None, :], jnp.int32)
+        phys, off = _paged_write_index(jnp.asarray(tables), positions, jnp.asarray(np.arange(T)[None, :] < keep[:, None]), bs)
+        pools = tuple(rand(60 + i, (2, N, bs, lanes)) for i in range(n))
+        new = tuple(rand(70 + i, (B * T, lanes)) for i in range(n))
+
+        def kernel(pools, new, phys, off, B=B):
+            return paged_write_rows(pools, new, 1, paged_write_segments(phys, off, sequences=B, block_size=bs))
+
+        got = _compile(jax.jit(kernel), pools, new, phys, off, on_chip=on_chip)(pools, new, phys, off)
+        want = tuple(pool.at[1, phys, off].set(rows) for pool, rows in zip(pools, new))
+        written[name] = all(bool(jnp.array_equal(g[:, 1:], w[:, 1:])) for g, w in zip(got, want))
+        assert written[name], f"paged_write_{name}: the pools differ from the scatter's off the garbage page"
+        del pools, new, got, want
     return {"rel_err": errs, "tolerance": {"fwd": FWD_REL_TOL, "bwd": BWD_REL_TOL},
             "block_step_us_a_call": times, "block_step_kv_bytes": visible * 2 * Hkv * D * 2,
-            "grouped_product": grouped}
+            "grouped_product": grouped, "paged_write_is_the_scatter": written}
 
 
 # ---------------------------------------------------------------------------
@@ -486,6 +522,9 @@ def leg_serving(sz, on_chip):
     has_mosaic = "tpu_custom_call" in decode_text
     if on_chip:
         assert has_mosaic, "the engine's paged decode program has no Mosaic kernel"
+    # which write of the new K and V rows the programs hold, and that the decode program bears it out
+    assert stats["kv_write"] == ("kernel" if on_chip else "scatter"), stats["kv_write"]
+    assert ('kernel_name = "paged_write"' in decode_text) == on_chip, "the decode program's pool write is not what stats() says"
     return {
         "requests": 2 * len(prompts),
         "in_flight": s["slots"],
@@ -493,6 +532,7 @@ def leg_serving(sz, on_chip):
         "prefix_cache_hits": stats["prefix_cache_hits"],
         "prefix_tokens_reused": stats["prefix_tokens_reused"],
         "decode_program_has_tpu_custom_call": has_mosaic,
+        "kv_write": stats["kv_write"],
     }
 
 

@@ -426,9 +426,14 @@ def paged_forward_counted(
     ``positions`` starting mid-sequence — visibility is positional, so a
     chunk sees all previously cached chunks plus its own causal prefix.
 
-    The stacked pool is the layer loop's carry: layer ``l`` scatters its
-    K/V rows into ``pool[l]`` in place and attention reads them from there,
-    so a donated cache is never sliced, re-laid-out or copied.
+    The stacked pool is the layer loop's carry: layer ``l`` writes its K/V
+    rows into ``pool[l]`` in place and attention reads them from there, so a
+    donated cache is never sliced, re-laid-out or copied. With
+    ``use_decode_kernel`` the write is
+    :func:`~ray_tpu.ops.decode_attention.paged_write_rows` (whole pages, one
+    call a layer for both pools; a row bound for the garbage page 0 is not
+    written at all), else a scatter a pool, the plain reference: the pools
+    agree bit for bit on every page but page 0.
 
     A block-causal config (``cfg.block_length`` > 1) sees, per query, the keys
     to the end of the query's block as far as the call's real tokens reach
@@ -471,6 +476,19 @@ def paged_forward_counted(
     _refuse_scales_on_two_stacks(cfg, layer_scales)
 
     phys, off = _paged_write_index(block_tables, positions, valid, bs)
+    if use_decode_kernel:
+        from ray_tpu.ops.decode_attention import paged_write_rows, paged_write_segments
+
+        segments = paged_write_segments(phys, off, sequences=B, block_size=bs)  # once a call, not a layer
+
+    def write_rows(l, pools, rows):
+        """This call's new ``rows`` (``[B, T, ..]`` a pool) into layer ``l``
+        of ``pools``: on the chip whole pages by one Mosaic call for all the
+        pools, else (the plain reference) a scatter a pool."""
+        rows = [r.reshape(B * T, -1).astype(p.dtype) for p, r in zip(pools, rows)]
+        if use_decode_kernel:
+            return paged_write_rows(pools, rows, l, segments)
+        return tuple(p.at[l, phys, off].set(r) for p, r in zip(pools, rows))
 
     def dense_view(pool, l):
         # [B, Hkv, cap, Dh] view of layer l through the block tables, so the
@@ -498,8 +516,7 @@ def paged_forward_counted(
         layer ``l`` of it, attend, output gate, ``wo``, residual."""
         h = pre_norm(cfg, layer, "attn_norm", x)
         q, k, v = block_qkv(cfg, layer, h, positions, kind)
-        kc = kc.at[l, phys, off].set(k.reshape(B * T, -1).astype(kc.dtype))
-        vc = vc.at[l, phys, off].set(v.reshape(B * T, -1).astype(vc.dtype))
+        kc, vc = write_rows(l, (kc, vc), (k, v))
         if use_decode_kernel and T == 1 and cfg.block <= 1:
             from ray_tpu.ops.decode_attention import paged_decode_attention
 
@@ -570,7 +587,7 @@ def paged_forward_counted(
             q, row = latent_qkv(cfg, layer, h)
             lanes = lc.shape[-1]
             row = jnp.pad(row, ((0, 0), (0, 0), (0, lanes - row.shape[-1])))
-            lc = lc.at[fi, phys, off].set(row.reshape(B * T, lanes).astype(lc.dtype))
+            lc, = write_rows(fi, (lc,), (row,))
             qa = latent_absorb(cfg, layer, q, lanes)
             if use_decode_kernel and T == 1:
                 o = latent_paged_decode(qa[:, 0], lc, block_tables, starts + 1, fi, rank=cfg.latent_rank,

@@ -951,3 +951,205 @@ def latent_paged_prefill(
         q_index=lambda b, i, *_: (b, i, 0), rank=rank, span=max(1, _LANES // bs) * bs,
         vmem_limit_bytes=_PREFILL_VMEM_BYTES)
     return out.reshape(B, T + pad_t, H, rank)[:, :T]
+
+
+# ---------------------------------------------------------------------------
+# the pools' write: a call's new rows, a page at a time
+# ---------------------------------------------------------------------------
+_WRITE_VMEM_BYTES = 48 * 1024 * 1024
+# of it, the page buffers of the segments a grid step holds at once: every segment of every served call (a 512-row
+# chunk of 3840 lanes: 33 pages of 120 KiB, twice, two pools: 15.5 MiB; 40 decode rows of 2048: 7.5 MiB); a call of
+# more takes a grid step more
+_WRITE_BUFFER_BYTES = 24 * 1024 * 1024
+
+
+def _paged_write_kernel(layer_ref, page_ref, lo_ref, hi_ref, base_ref, *refs, pools: int, run: int, per_sequence: int):
+    """A grid step: as many segments of the call as its buffers hold (all of
+    them, at the served shapes). Segment ``s`` is the rows
+    ``[lo[s], hi[s])`` of page ``page[s]`` of layer ``layer`` (page 0, the
+    garbage page, or an empty range: nothing to do), in each of ``pools``
+    pools. The pools stay in HBM, input aliased to output; what moves is
+    whole pages, because a row of a page is no unit the chip can copy: a page
+    of 16 rows is one tile of the pool's layout, its rows interleaved in it.
+
+    ``run`` = 0 (runs of a page's rows or more): the source of segment ``s``
+    is ``src[s]``, a whole page in HBM whose rows ``[lo, hi)`` are the new
+    ones. A segment that fills its page goes as one copy from there to the
+    pool; any other has its page read into VMEM, the new rows laid over it
+    and the page written back. ``run`` > 0 (a sequence's ``run`` rows are
+    fewer than a page's; ``per_sequence`` segments each): ``src`` is
+    ``[B * run, 1, lanes]`` in VMEM, row ``t`` of the segment's sequence
+    bound for row ``base[s] + t`` of the page, where that lies in
+    ``[lo, hi)``. Every copy of a phase is started before any is waited for
+    (the semaphores count them: the pages' reads, the sources' reads, the
+    writes), so the call costs its bytes and three latencies, not a latency
+    a row."""
+    staged = run == 0
+    srcs, pools_in, pools_out = refs[:pools], refs[pools:2 * pools], refs[2 * pools:3 * pools]
+    bufs, src_bufs = refs[3 * pools:4 * pools], refs[4 * pools:5 * pools] if staged else None
+    sems = refs[-1]
+    layer = layer_ref[0]
+    S, bs = bufs[0].shape[:2]  # segments a grid step
+    s0 = pl.program_id(0) * S
+
+    def each(fn):
+        def body(s, carry):
+            page, lo, hi = page_ref[s0 + s], lo_ref[s0 + s], hi_ref[s0 + s]
+            live = jnp.logical_and(page > 0, hi > lo)
+            whole = jnp.logical_and(live, jnp.logical_and(lo == 0, hi == bs)) if staged else False
+            fn(s, page, lo, hi, jnp.logical_and(live, jnp.logical_not(whole)), whole)
+            return carry
+
+        jax.lax.fori_loop(0, S, body, None)
+
+    def reads(s, page, act):
+        for p in range(pools):
+            act(pltpu.make_async_copy(pools_in[p].at[layer, page], bufs[p].at[s], sems.at[p, 0]))
+            if staged:
+                act(pltpu.make_async_copy(srcs[p].at[s0 + s], src_bufs[p].at[s], sems.at[p, 1]))
+
+    def writes(s, page, from_src, act):
+        for p in range(pools):
+            held = srcs[p].at[s0 + s] if from_src else bufs[p].at[s]
+            act(pltpu.make_async_copy(held, pools_out[p].at[layer, page], sems.at[p, 2]))
+
+    def start_reads(s, page, lo, hi, partial, whole):
+        @pl.when(partial)
+        def _():
+            reads(s, page, lambda copy: copy.start())
+
+        if staged:  # a page the run fills needs no read: on its way at once
+            @pl.when(whole)
+            def _():
+                writes(s, page, True, lambda copy: copy.start())
+
+    def wait_reads(s, page, lo, hi, partial, whole):
+        @pl.when(partial)
+        def _():
+            reads(s, page, lambda copy: copy.wait())
+
+    def merge_and_write(s, page, lo, hi, partial, whole):
+        @pl.when(partial)
+        def _():
+            for p in range(pools):
+                tile = bufs[p][s]
+                row = jax.lax.broadcasted_iota(jnp.int32, tile.shape, 0)
+                mine = jnp.logical_and(row >= lo, row < hi)
+                if staged:
+                    tile = jnp.where(mine, src_bufs[p][s], tile)
+                for t in range(run):
+                    new = jnp.broadcast_to(srcs[p][s // per_sequence * run + t], tile.shape)
+                    tile = jnp.where(jnp.logical_and(mine, row == base_ref[s0 + s] + t), new, tile)
+                bufs[p][s] = tile
+            writes(s, page, False, lambda copy: copy.start())
+
+    def wait_writes(s, page, lo, hi, partial, whole):
+        @pl.when(partial)
+        def _():
+            writes(s, page, False, lambda copy: copy.wait())
+
+        if staged:
+            @pl.when(whole)
+            def _():
+                writes(s, page, True, lambda copy: copy.wait())
+
+    each(start_reads)
+    each(wait_reads)
+    each(merge_and_write)
+    each(wait_writes)
+
+
+def paged_write_segments(phys, off, *, sequences: int, block_size: int):
+    """The pages a call's rows touch, from where each row goes: ``phys``,
+    ``off`` ``[B*T]`` (``models/generation._paged_write_index``: the ``T``
+    rows of each of the ``sequences`` at consecutive positions, the rows to
+    keep a prefix of them, the others bound for page 0). Returns ``(page,
+    lo, hi, base, first)``: ``[B*P]`` each but the last, rows ``[lo, hi)`` of
+    page ``page`` a segment (``P`` pages a sequence: as many as ``T`` rows
+    from any offset can touch), ``base`` the row of the segment's page at
+    which its sequence's first row would stand (negative in a later page),
+    and ``first`` ``[B]``, the offset of a sequence's first row in its page.
+    The same for every layer of a call: a caller that loops over layers
+    computes it once."""
+    B, bs = sequences, block_size
+    T = phys.shape[0] // B
+    phys, off = phys.reshape(B, T).astype(jnp.int32), off.reshape(B, T).astype(jnp.int32)
+    first = off[:, 0]
+    P = (T + bs - 2) // bs + 1
+    nth = jnp.arange(P)[None, :]
+    segment = (first[:, None] + jnp.arange(T)[None, :]) // bs          # [B, T] the row's page of the P
+    mine = (segment[:, None, :] == nth[:, :, None]) & (phys > 0)[:, None, :]  # [B, P, T]
+    page = jnp.max(jnp.where(mine, phys[:, None, :], 0), axis=-1)
+    lo = jnp.where(nth == 0, first[:, None], 0)
+    hi = lo + mine.sum(-1).astype(jnp.int32)
+    base = first[:, None] - nth * bs
+    return page.reshape(-1), lo.reshape(-1), hi.reshape(-1), base.reshape(-1), first
+
+
+def paged_write_rows(pools, rows, layer, segments):
+    """Write a call's new rows into layer ``layer`` of paged pools, in place:
+    ``pools`` a tuple of one (a latent pool) or two (K and V)
+    ``[L, num_blocks, block_size, lanes]`` arrays, ``rows`` as many
+    ``[B*T, lanes]`` arrays of the pools' type, ``segments`` what
+    :func:`paged_write_segments` makes of where each row goes. Returns the
+    pools, byte for byte what ``pool.at[layer, phys, off].set(rows)`` gives
+    on every page but page 0.
+
+    The contract is the index's own: the ``T`` rows of a sequence stand at
+    consecutive positions; the rows to keep are a prefix of them and the rest
+    (a chunk's padded tail, an idle slot, a position past a table) are bound
+    for the garbage page 0, which this call never touches; no two sequences
+    write into one page. One Mosaic call (``paged_write``) serves every pool,
+    row width and row count: see :func:`_paged_write_kernel`. Runs shorter
+    than a page (a decode call's one row a sequence, a block step's few) ride
+    into VMEM with the call; longer runs are first laid out by XLA as the
+    whole pages they will be (``[B, P * block_size, lanes]``, the rows at
+    their offset), so that a page the run fills is one copy from there."""
+    page, lo, hi, base, first = segments
+    n = len(pools)
+    bs, lanes = pools[0].shape[2:]
+    B, S = first.shape[0], page.shape[0]
+    T = rows[0].shape[0] // B
+    P = S // B  # pages a sequence
+    staged = T >= bs
+    # sequences a grid step: their segments' page buffers (and the sources', read beside them or riding in twice) fit
+    held = n * bs * lanes * pools[0].dtype.itemsize * (2 if staged else 3)
+    per_step = max(1, min(B, _WRITE_BUFFER_BYTES // (held * P)))
+    steps = -(-B // per_step)
+    pad = steps * per_step - B
+    if pad:  # whole grid steps: the segments added are bound for page 0
+        page, lo, hi, base = (jnp.pad(a, (0, pad * P)) for a in (page, lo, hi, base))
+        first = jnp.pad(first, (0, pad))
+        rows = [jnp.pad(r, ((0, pad * T), (0, 0))) for r in rows]
+    if staged:
+        def lay_out(r):  # [B*T, lanes] -> [B*P, bs, lanes]: each sequence's rows from their offset in whole pages
+            def place(run, at):
+                return jax.lax.dynamic_update_slice(jnp.zeros((P * bs, lanes), r.dtype), run, (at, 0))
+
+            runs = r.reshape(-1, T, lanes)
+            pages = place(runs[0], first[0])[None] if runs.shape[0] == 1 else jax.vmap(place)(runs, first)
+            return pages.reshape(-1, bs, lanes)
+
+        srcs = [lay_out(r) for r in rows]
+        src_spec = pl.BlockSpec(memory_space=pl.ANY)
+    else:
+        srcs = [r[:, None, :] for r in rows]
+        src_spec = pl.BlockSpec((per_step * T, 1, lanes), lambda i, *_: (i, 0, 0))
+    buffers = [pltpu.VMEM((per_step * P, bs, lanes), pool.dtype) for pool in pools]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=5,
+        grid=(steps,),
+        in_specs=[src_spec] * n + [pl.BlockSpec(memory_space=pl.ANY)] * n,
+        out_specs=[pl.BlockSpec(memory_space=pl.ANY)] * n,
+        scratch_shapes=buffers * (2 if staged else 1) + [pltpu.SemaphoreType.DMA((n, 3))],
+    )
+    out = pl.pallas_call(
+        functools.partial(_paged_write_kernel, pools=n, run=0 if staged else T, per_sequence=P),
+        out_shape=[jax.ShapeDtypeStruct(pool.shape, pool.dtype) for pool in pools],
+        grid_spec=grid_spec,
+        input_output_aliases={5 + n + p: p for p in range(n)},  # the pools, counted with the five prefetched scalars
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",), vmem_limit_bytes=_WRITE_VMEM_BYTES),
+        interpret=_use_interpret(),
+        name="paged_write",
+    )(jnp.asarray(layer, jnp.int32).reshape(1), page, lo, hi, base, *srcs, *pools)
+    return tuple(out)

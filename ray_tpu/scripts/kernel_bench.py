@@ -11,6 +11,7 @@ Run: ``python -m ray_tpu.scripts.kernel_bench`` (through the chip tool).
 
 from __future__ import annotations
 
+import functools
 import time
 from typing import Callable, Dict
 
@@ -75,24 +76,34 @@ def bench_decode(B=8, H=16, Hkv=4, D=128, S=4096, iters=50) -> Dict[str, float]:
             "speedup": t_dense / t_kernel}
 
 
-def _kernel_seconds(fn: Callable, args, iters: int) -> float:
-    """Device seconds the Mosaic kernels of a jitted ``fn`` take a call: the
-    custom-calls' durations in a profiler trace of ``iters`` calls (the
-    kernel alone: no dispatch, no XLA operation beside it)."""
+def _device_ops(run: Callable) -> list:
+    """The device's operations (profiler events of ``/device:TPU:0``'s ``XLA
+    Ops`` line) during one ``run()``, which returns what to wait for."""
     import glob
     import tempfile
 
     from jax.profiler import ProfileData
 
-    _sync(fn(*args))  # compile + warm
     with tempfile.TemporaryDirectory() as trace_dir:
         with jax.profiler.trace(trace_dir):
-            for _ in range(iters):
-                out = fn(*args)
-            _sync(out)
+            _sync(run())
         data = ProfileData.from_file(sorted(glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True))[-1])
-    lines = [line for plane in data.planes if plane.name == "/device:TPU:0" for line in plane.lines if line.name == "XLA Ops"]
-    ns = sum(ev.duration_ns for line in lines for ev in line.events if " custom-call(" in ev.name)
+    return [ev for plane in data.planes if plane.name == "/device:TPU:0" for line in plane.lines
+            if line.name == "XLA Ops" for ev in line.events]
+
+
+def _kernel_seconds(fn: Callable, args, iters: int) -> float:
+    """Device seconds the Mosaic kernels of a jitted ``fn`` take a call: the
+    custom-calls' durations in a profiler trace of ``iters`` calls (the
+    kernel alone: no dispatch, no XLA operation beside it)."""
+    _sync(fn(*args))  # compile + warm
+
+    def calls():
+        for _ in range(iters):
+            out = fn(*args)
+        return out
+
+    ns = sum(ev.duration_ns for ev in _device_ops(calls) if " custom-call(" in ev.name)
     if not ns:
         raise SystemExit("no custom-call in the device trace: not a compiled Mosaic kernel")
     return ns / iters / 1e9
@@ -256,11 +267,120 @@ def bench_factor_product(D=128, steps=256, iters=10) -> Dict[str, dict]:
     return out
 
 
+# the serve cells' pool writes: (pools, lanes a row, sequences of a decode call, positions it writes a sequence: a
+# block step's 4). A prefill chunk is 512 rows of one sequence in every cell
+PAGED_WRITE_CALLS = {
+    "smollm2": (2, 2048, 40, 1),
+    "trinity-mini": (2, 512, 48, 1),
+    "sdar": (2, 512, 48, 4),
+    "olmo-hybrid": (2, 3840, 32, 1),
+    "kimi-linear": (1, 640, 64, 1),
+}
+_HBM_BYTES_PER_S = 819e9  # one v5e chip, published
+
+
+def _device_us(once: Callable, iters: int) -> float:
+    """Device microseconds an iteration of a program that scans ``iters`` of
+    them: every operation's duration in a profiler trace of one ``once()``
+    (which runs the program and returns what to wait for; control flow left
+    out: its time is its body's), over ``iters``."""
+    _sync(once())  # compile + warm
+    ns = sum(ev.duration_ns for ev in _device_ops(once)
+             if not any(f" {c}(" in ev.name for c in ("while", "conditional", "call")))
+    return ns / iters / 1e3
+
+
+def bench_paged_write(iters=48, pages=1024, layers=4, calls=None) -> Dict[str, dict]:
+    """The write of a call's new K and V rows (or latent rows) into the paged
+    pools, alone, at each serve cell's shapes: a decode call (a row a slot,
+    each in a page of its own at a random offset; SDAR: a block of 4), the
+    same with one slot live and the others idle (bound for page 0, as most
+    are in the cells) and a 512-row prefill chunk from a page boundary. us a call (device time of
+    everything the write costs, index arithmetic and layout included; both
+    pools) for ``scatter`` (``pool.at[l, phys, off].set``: XLA's scatter, a
+    row an update), ``scatter_told`` (the same told its indices are unique
+    and sorted), ``scatter_dropped`` (the idle rows out of bounds and
+    dropped), for a chunk ``page_scatter`` (a page an update: only right
+    where the chunk fills whole pages) and ``kernel``
+    (``ops.decode_attention.paged_write_rows``); ``bytes_us`` is the rows'
+    own bytes at the chip's bandwidth, ``page_bytes_us`` what moving the
+    touched pages costs (read and written once; written only where a chunk
+    fills them), which is what a copy engine that moves whole tiles can
+    reach. The pools are donated to the timed program and ride its scan."""
+    from ray_tpu.ops.decode_attention import paged_write_rows, paged_write_segments
+
+    bs, out = 16, {}
+    for name in calls or PAGED_WRITE_CALLS:
+        n, lanes, slots, span = PAGED_WRITE_CALLS[name]
+        out[name] = {}
+        for call, (B, T) in {"decode": (slots, span), "decode_one_live": (slots, span), "chunk": (1, 512)}.items():
+            rng = np.random.default_rng(B * T)
+            R = B * T
+            # a page a sequence and layer-step, distinct within a call; a chunk's pages in a row
+            first = rng.permutation(np.arange(1, pages - T // bs - 1))[:B]
+            start = np.zeros(B, np.int64) if call == "chunk" else rng.integers(0, bs // span, size=B) * span
+            pos = start[:, None] + np.arange(T)[None, :]
+            live = np.arange(B)[:, None] < (1 if call == "decode_one_live" else B)  # the others idle: page 0
+            phys = jnp.asarray(np.where(live, first[:, None] + pos // bs, 0).reshape(-1), jnp.int32)
+            off = jnp.asarray((pos % bs).reshape(-1), jnp.int32)
+            rows = tuple(jax.random.normal(jax.random.key(i), (R, lanes), jnp.bfloat16) for i in range(n))
+
+            def scatter(pools, layer, **told):
+                return tuple(p.at[layer, phys, off].set(r, **told) for p, r in zip(pools, rows))
+
+            def page_scatter(pools, layer):
+                return tuple(p.at[layer, phys[::bs]].set(r.reshape(R // bs, bs, lanes)) for p, r in zip(pools, rows))
+
+            def kernel(pools, layer):
+                return paged_write_rows(pools, rows, layer, paged_write_segments(phys, off, sequences=B, block_size=bs))
+
+            def scatter_dropped(pools, layer):  # the idle rows out of bounds and dropped, not sent to page 0
+                return tuple(p.at[layer, jnp.where(phys > 0, phys, pages), off].set(r, mode="drop") for p, r in zip(pools, rows))
+
+            ways = {"scatter": scatter, "kernel": kernel}
+            if call == "decode_one_live":
+                ways["scatter_dropped"] = scatter_dropped
+            else:
+                ways["scatter_told"] = functools.partial(scatter, unique_indices=True, indices_are_sorted=call == "chunk")
+            if call == "chunk":
+                ways["page_scatter"] = page_scatter
+            got = {"rows": R, "lanes": lanes, "pools": n}
+            some = tuple(jax.random.normal(jax.random.key(7 + i), (layers, pages, bs, lanes), jnp.bfloat16) for i in range(n))
+            got["kernel_is_scatter"] = all(bool(jnp.array_equal(a[:, 1:], b[:, 1:])) for a, b in zip(
+                jax.jit(kernel)(some, jnp.int32(1)), jax.jit(scatter)(some, jnp.int32(1))))
+            del some
+            for way, fn in ways.items():
+                def run(pools, fn=fn):
+                    def body(carry, i):
+                        return fn(carry, i % layers), None
+
+                    return jax.lax.scan(body, pools, jnp.arange(iters, dtype=jnp.int32))[0]
+
+                # fresh pools a way: the timed program is given them to keep, and hands them back
+                held = [tuple(jnp.zeros((layers, pages, bs, lanes), jnp.bfloat16) for _ in range(n))]
+                timed = jax.jit(run, donate_argnums=(0,))
+
+                def once(held=held, timed=timed):
+                    held[0] = timed(held[0])
+                    return held[0]
+
+                got[f"{way}_us"] = _device_us(once, iters)
+                held.clear()
+            row_bytes = R * lanes * 2 * n
+            got["us_a_row"] = {w[:-3]: v / R for w, v in got.items() if w.endswith("_us")}
+            got["bytes_us"] = row_bytes / _HBM_BYTES_PER_S * 1e6
+            touched = {"decode": 2 * B, "decode_one_live": 2, "chunk": R // bs}[call] * bs * lanes * 2 * n
+            got["page_bytes_us"] = touched / _HBM_BYTES_PER_S * 1e6
+            out[name][call] = got
+    return out
+
+
 def main(argv=None) -> None:
     """Examples:
 
         python -m ray_tpu.scripts.kernel_bench                 # decode + 8k/D=128
         python -m ray_tpu.scripts.kernel_bench --flash-cells   # the training cells' calls: time and error
+        python -m ray_tpu.scripts.kernel_bench --paged-write   # the serve cells' pool writes: scatter against kernel
         python -m ray_tpu.scripts.kernel_bench --T 32768 --D 64 --H 4 --iters 2
         python -m ray_tpu.scripts.kernel_bench --T 8192 --D 64 --iters 4
     """
@@ -276,6 +396,8 @@ def main(argv=None) -> None:
     parser.add_argument("--skip-decode", action="store_true")
     parser.add_argument("--flash-cells", action="store_true",
                         help="the flash kernels at the training cells' calls, and the float32-factor probe; nothing else")
+    parser.add_argument("--paged-write", action="store_true",
+                        help="the write of a call's K and V rows into the paged pools at the serve cells' shapes; nothing else")
     args = parser.parse_args(argv)
 
     from ray_tpu.ops import backend
@@ -288,6 +410,10 @@ def main(argv=None) -> None:
         )
     backend.use_compile_cache()
     results = {"device": getattr(dev, "device_kind", str(dev))}
+    if args.paged_write:
+        results.update(bench_paged_write())
+        print(json.dumps(results))
+        return
     if args.flash_cells:
         results.update(bench_flash_cells(args.iters), factor_product=bench_factor_product(iters=args.iters))
         print(json.dumps(results))

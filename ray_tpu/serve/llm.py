@@ -774,6 +774,7 @@ class LLMEngine:
                 "loop_phase_s": dict(self._clock.seconds),
                 "kv_read_share": self.kv_read_share(),
                 "kv_live_pages": self.kv_live_pages(),
+                "kv_write": self.runner.kv_write,
                 **self.store.stats_locked(),
                 **self._moe_stats_locked(),
                 **self._steps.stats(self._decode_step_count),

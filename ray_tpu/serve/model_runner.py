@@ -52,6 +52,7 @@ from ray_tpu.models.generation import (
     zero_sequence_state,
 )
 from ray_tpu.models.transformer import TransformerConfig
+from ray_tpu.ops import backend
 from ray_tpu.ops.gated_delta import lane_group, unpack_state
 
 def _abstract(x):
@@ -294,6 +295,10 @@ class ModelRunner:
         # under a mesh the einsum path partitions via GSPMD; the Pallas
         # paged kernels (decode and prefill) stay for the single-device engine
         use_kernel = None if mesh is None else False
+        # which write of a call's K and V rows the programs below hold: a fact of
+        # how they are built, recorded once (``stats()["kv_write"]``). "kernel":
+        # whole pages by ``ops.decode_attention.paged_write_rows``; "scatter": XLA's, a row an update
+        self.kv_write = "kernel" if (backend.on_tpu() if use_kernel is None else use_kernel) else "scatter"
         # under a mesh a program that returns the pool returns it as it was
         # placed: the donated buffers are updated where they lie and the next
         # call finds the sharding it was compiled for (left to itself GSPMD
@@ -459,18 +464,28 @@ class ModelRunner:
         """A donated program consumed the cache, then failed."""
         return next(iter(self.cache.values())).is_deleted()
 
-    def lowered_decode_text(self) -> str:
-        """StableHLO text of the decode program as the loop runs it (same
-        params, cache and slot-array shapes). ``chip_smoke.py`` looks for
-        ``tpu_custom_call`` in it: which attention path the engine compiled
-        is read from the program, not assumed from a flag."""
-
+    def traced_decode(self):
+        """The decode program as the loop runs it (same params, cache and
+        slot-array shapes), traced: ``.lower()`` it for a platform."""
         params, cache = jax.tree.map(_abstract, (self.params, self.cache))
         state, join = self.steps.abstract_rows(self.dev_toks)
         toks = jax.ShapeDtypeStruct((self.B,), jnp.int32)
         temps = jax.ShapeDtypeStruct((self.B,), jnp.float32)
         bt = jax.ShapeDtypeStruct(self.table_shape, jnp.int32)
-        return self._decode_k_paged.lower(params, cache, state, join, toks, temps, self.key, bt).as_text()
+        return self._decode_k_paged.trace(params, cache, state, join, toks, temps, self.key, bt)
+
+    def lowered_decode_text(self) -> str:
+        """StableHLO text of the decode program. ``chip_smoke.py`` looks for
+        ``tpu_custom_call`` in it: which attention path the engine compiled
+        is read from the program, not assumed from a flag."""
+        return self.traced_decode().lower().as_text()
+
+    def traced_prefill_chunk(self, chunk: int):
+        """The prefill program at a chunk of ``chunk`` tokens, traced."""
+        params, cache = jax.tree.map(_abstract, (self.params, self.cache))
+        i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
+        at = (i32(1),) if self.cfg.hybrid else ()
+        return self._prefill_chunk.trace(params, cache, i32(1, chunk), i32(1, self.table_shape[1]), i32(), i32(), *at)
 
     # -- programs: each enqueues and returns without waiting ----------------
     def prefill_chunk(self, toks, bt, start: int, n: int, slot: int):

@@ -259,3 +259,103 @@ def test_heads_a_unit_fills_one_sublane_tile_of_rows_with_whole_lane_tiles(shape
     from ray_tpu.ops.decode_attention import _heads_a_unit
 
     assert _heads_a_unit(*shape) == share
+
+
+# ---------------------------------------------------------------------------
+# the pools' write: paged_write_rows against the scatter, byte for byte
+# ---------------------------------------------------------------------------
+def _write_call(rng, B, T, starts, lengths, tables, bs):
+    """(phys, off) of a call as ``models/generation._paged_write_index`` gives them."""
+    from ray_tpu.models.generation import _paged_write_index
+
+    positions = jnp.asarray(np.asarray(starts)[:, None] + np.arange(T)[None, :], jnp.int32)
+    valid = jnp.asarray(np.arange(T)[None, :] < np.asarray(lengths)[:, None])
+    return _paged_write_index(jnp.asarray(tables), positions, valid, bs)
+
+
+# (pools, lanes, sequences, rows a sequence, first positions, rows to keep, layer): tables of 4 pages of 16
+_WRITE_CASES = {
+    "decode_two_pools_some_idle": (2, 512, 5, 1, [3, 17, 0, 40, 63], [1, 1, 0, 1, 1], 1),
+    "decode_latent_one_pool": (1, 640, 4, 1, [3, 31, 9, 15], [1, 0, 0, 1], 2),
+    "decode_2048_lanes": (2, 2048, 3, 1, [0, 15, 16], [1, 1, 1], 0),
+    "decode_3840_lanes_layer_2": (2, 3840, 2, 1, [33, 7], [1, 1], 2),
+    "decode_past_the_table": (2, 512, 3, 1, [64, 5, 200], [1, 1, 1], 1),
+    "chunk_from_a_page_boundary": (2, 512, 1, 32, [16], [32], 1),
+    "chunk_from_inside_a_page": (2, 512, 1, 32, [5], [32], 1),
+    "chunk_with_a_padded_tail": (2, 2048, 1, 32, [16], [21], 0),
+    "chunk_latent_inside_a_page_padded": (1, 640, 1, 48, [7], [30], 1),
+    "chunk_all_padding": (2, 512, 1, 32, [0], [0], 1),
+    "block_steps_of_four": (2, 512, 4, 4, [4, 12, 0, 60], [4, 4, 0, 4], 2),
+    "two_chunks_a_call": (1, 512, 2, 32, [7, 32], [20, 3], 0),
+    "short_runs_across_two_pages": (2, 512, 2, 4, [14, 30], [4, 3], 1),
+    "short_runs_latent_of_eight": (1, 640, 2, 8, [14, 9], [8, 3], 0),
+}
+
+
+@pytest.mark.parametrize("case", list(_WRITE_CASES), ids=list(_WRITE_CASES))
+def test_paged_write_rows_is_the_scatter_on_every_page_but_the_garbage_page(case):
+    """``paged_write_rows`` (interpreted) against ``pool.at[l, phys, off].set``:
+    one pool and two, rows of 512 to 3840 lanes, a decode call with idle rows
+    (all-zero tables), chunks from a page boundary and from inside a page,
+    with a padded tail and all padding, runs shorter than a page that cross
+    into the next, a layer that is not 0, positions past
+    a row's table (an all-zero table's, or clipped onto the last page with
+    ``valid`` False: page 0 either way). Every page but page 0 byte for byte,
+    the other layers with them."""
+    from ray_tpu.ops.decode_attention import paged_write_rows, paged_write_segments
+
+    n, lanes, B, T, starts, lengths, layer = _WRITE_CASES[case]
+    rng = np.random.default_rng(sorted(_WRITE_CASES).index(case))
+    L, bs, M = 3, 16, 4
+    N = 1 + B * M
+    pools = tuple(jnp.asarray(rng.standard_normal((L, N, bs, lanes)), jnp.bfloat16) for _ in range(n))
+    rows = tuple(jnp.asarray(rng.standard_normal((B * T, lanes)), jnp.bfloat16) for _ in range(n))
+    tables = rng.permutation(np.arange(1, N)).reshape(B, M).astype(np.int32)
+    tables[np.asarray(lengths) == 0] = 0  # an idle slot's table
+    if case == "decode_past_the_table":
+        lengths = [1, 1, 0]  # the row at 200 is past its table: not valid, or it would alias the last page
+        tables[0] = 0        # and the row at 64 walks an all-zero table, as a finished row does
+    phys, off = _write_call(rng, B, T, starts, lengths, tables, bs)
+    got = paged_write_rows(pools, rows, layer, paged_write_segments(phys, off, sequences=B, block_size=bs))
+    want = tuple(p.at[layer, phys, off].set(r) for p, r in zip(pools, rows))
+    assert len(got) == n
+    for g, w, before in zip(got, want, pools):
+        g, w = (np.asarray(a.view(jnp.uint16)) for a in (g, w))
+        np.testing.assert_array_equal(g[:, 1:], w[:, 1:])
+        if any(lengths):
+            assert (w[layer] != np.asarray(before.view(jnp.uint16))[layer]).any()  # the call wrote something
+
+
+def test_paged_write_rows_never_touches_the_garbage_page():
+    """A row bound for page 0 (an idle slot, a padded tail) is not copied at
+    all: page 0 comes back as it went in, where the scatter leaves the last
+    such row in it."""
+    from ray_tpu.ops.decode_attention import paged_write_rows, paged_write_segments
+
+    rng = np.random.default_rng(3)
+    pool = jnp.asarray(rng.standard_normal((2, 6, 16, 256)), jnp.bfloat16)
+    rows = jnp.asarray(rng.standard_normal((3, 256)), jnp.bfloat16)
+    phys, off = jnp.asarray([0, 4, 0], jnp.int32), jnp.asarray([2, 9, 2], jnp.int32)
+    got, = paged_write_rows((pool,), (rows,), 1, paged_write_segments(phys, off, sequences=3, block_size=16))
+    np.testing.assert_array_equal(np.asarray(got[:, 0].view(jnp.uint16)), np.asarray(pool[:, 0].view(jnp.uint16)))
+    np.testing.assert_array_equal(np.asarray(got[1, 4, 9].view(jnp.uint16)), np.asarray(rows[1].view(jnp.uint16)))
+
+
+def test_a_call_too_large_for_one_grid_steps_buffers_takes_more(monkeypatch):
+    """The page buffers a grid step holds are bounded: a call of more
+    sequences than fit takes more grid steps (the last padded with segments
+    bound for page 0) and writes the same pools."""
+    from ray_tpu.ops import decode_attention as da
+
+    rng = np.random.default_rng(9)
+    n, lanes, B, bs, M = 2, 256, 7, 16, 2
+    monkeypatch.setattr(da, "_WRITE_BUFFER_BYTES", 3 * (3 * n * bs * lanes * 2))  # three rows' buffers a step
+    for T, starts in ((1, [3, 17, 0, 31, 8, 20, 9]), (16, [5, 0, 16, 3, 9, 1, 2])):
+        pools = tuple(jnp.asarray(rng.standard_normal((2, 1 + B * M, bs, lanes)), jnp.bfloat16) for _ in range(n))
+        rows = tuple(jnp.asarray(rng.standard_normal((B * T, lanes)), jnp.bfloat16) for _ in range(n))
+        tables = rng.permutation(np.arange(1, 1 + B * M)).reshape(B, M).astype(np.int32)
+        phys, off = _write_call(rng, B, T, starts, [T] * B, tables, bs)
+        got = da.paged_write_rows(pools, rows, 1, da.paged_write_segments(phys, off, sequences=B, block_size=bs))
+        for g, pool, r in zip(got, pools, rows):
+            want = pool.at[1, phys, off].set(r)
+            np.testing.assert_array_equal(np.asarray(g.view(jnp.uint16))[:, 1:], np.asarray(want.view(jnp.uint16))[:, 1:])

@@ -415,10 +415,11 @@ def test_paged_runner_prefill_kernel_matches_the_dense_lines(shape):
     got, kernel_pool, _ = _chunked_prefill(cfg, params, use_decode_kernel=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-4, rtol=2e-4)
     for name in ("k", "v"):
-        np.testing.assert_array_equal(np.asarray(kernel_pool[name][0]), np.asarray(dense_pool[name][0]))
-        # but for the garbage page: the padded tail's writes went there, and what a padded row attends over means nothing
+        # but for the garbage page: the scatter sent the padded tail's rows there, the kernel's write
+        # (``paged_write_rows``) copies no row bound for it, and what a padded row attends over means nothing
+        np.testing.assert_array_equal(np.asarray(kernel_pool[name][0, 1:]), np.asarray(dense_pool[name][0, 1:]))
         np.testing.assert_allclose(np.asarray(kernel_pool[name][:, 1:]), np.asarray(dense_pool[name][:, 1:]), atol=2e-4, rtol=2e-4)
-        assert np.asarray(kernel_pool[name][:, 0]).any()
+        assert np.asarray(dense_pool[name][:, 0]).any() and not np.asarray(kernel_pool[name][:, 0]).any()
 
 
 def test_paged_runner_prefill_kernel_with_int8_layer_scales():
@@ -805,3 +806,53 @@ def test_paged_snapshot_and_metrics_registered(params):
         assert snap["kv_block_occupancy"] == 0.0
     finally:
         eng.shutdown()
+
+
+@pytest.mark.parametrize("family", ["dense", "block", "hybrid", "latent"])
+def test_the_kernels_write_leaves_the_scatters_logits_and_pools(family):
+    """``paged_forward_counted`` with ``use_decode_kernel=True`` (every kernel
+    interpreted; the new rows go into the pools by ``paged_write_rows``)
+    against ``use_decode_kernel=False`` (the scatter), for a dense config, one
+    that decodes by blocks of 4, a hybrid one (its full layers' pools beside
+    the linear layers' state) and a latent one (one pool): a chunk from inside
+    a page with a padded tail, then a decode call, an idle row in both. The
+    logits of the real tokens, and the pools on every page but page 0."""
+    import importlib
+
+    from ray_tpu.models.generation import init_paged_cache, paged_forward_counted
+
+    cfg = CFG if family == "dense" else importlib.import_module(
+        {"block": "test_block_diffusion", "hybrid": "test_hybrid_state", "latent": "test_kimi_linear"}[family]).CFG
+    p = init_params(cfg, jax.random.key(1))
+    B, bs, M, step = 3, 16, 4, cfg.block
+    rng = np.random.default_rng(5)
+    tables = rng.permutation(np.arange(1, 1 + B * M)).reshape(B, M).astype(np.int32)
+    tables[2] = 0  # an idle slot
+    bt = jnp.asarray(tables)
+    at = {"slots": jnp.arange(B, dtype=jnp.int32)} if cfg.hybrid else {}
+    calls = [  # (rows a sequence, first positions, rows to keep)
+        (24, [0, 20, 0], [24, 16, 0]),
+        (step, [24, 36, 0], [step, step, 0]),
+    ]
+    tokens = [jnp.asarray(rng.integers(1, cfg.vocab_size - 1, (B, T)), jnp.int32) for T, _, _ in calls]
+    out = {}
+    for kernel in (False, True):
+        cache = init_paged_cache(cfg, 1 + B * M, bs, **({"slots": B} if cfg.hybrid else {}))
+        logits = []
+        for toks, (T, starts, keep) in zip(tokens, calls):
+            positions = jnp.asarray(np.asarray(starts)[:, None] + np.arange(T)[None, :], jnp.int32)
+            valid = jnp.asarray(np.arange(T)[None, :] < np.asarray(keep)[:, None])
+            lg, cache, _ = paged_forward_counted(cfg, p, cache, bt, toks, positions, valid=valid, use_decode_kernel=kernel, **at)
+            logits.append(np.asarray(lg)[np.asarray(valid)])
+        out[kernel] = (logits, cache)
+    for got, want in zip(*(out[k][0] for k in (True, False))):
+        np.testing.assert_allclose(got, want, atol=3e-4, rtol=3e-4)
+    got, want = (out[k][1] for k in (True, False))
+    assert set(got) == set(want)
+    for name in want:
+        pages = slice(1, None) if name in ("k", "v", "latent") else slice(None)
+        np.testing.assert_allclose(np.asarray(got[name][:, pages]), np.asarray(want[name][:, pages]), atol=3e-4, rtol=3e-4)
+    first = "latent" if "latent" in want else "k"  # the first pool layer's rows pass through no attention: bit for bit
+    if family in ("dense", "block"):
+        np.testing.assert_array_equal(np.asarray(got[first][0, 1:]), np.asarray(want[first][0, 1:]))
+    assert np.asarray(want[first][:, 1:]).any()

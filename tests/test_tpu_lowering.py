@@ -31,7 +31,8 @@ import chip_smoke
 from bench import TRAIN_BATCH, train_config
 from ray_tpu.ops import backend
 from ray_tpu.ops.attention import flash_attention
-from ray_tpu.ops.decode_attention import decode_attention, paged_decode_attention, paged_prefill_attention
+from ray_tpu.ops.decode_attention import (decode_attention, paged_decode_attention, paged_prefill_attention,
+                                          paged_write_rows, paged_write_segments)
 from ray_tpu.ops.quantization import int8_matmul
 from ray_tpu.scripts.llm_bench import serving_config
 
@@ -138,6 +139,21 @@ def _kernel_cases():
     yield "block_step_sdar", block_causal, [((48, 4, 32, 128), BF16), pool, pool, ((48, 256), I32), ((48,), I32),
                                             ((48,), I32), ((), I32)]
     yield "int8_matmul", int8_matmul, [((512, 1024), BF16), ((1024, 1024), jnp.int8), ((1024,), F32)]
+    # the pools' write at the five served families' rows: a decode call (a row a
+    # slot: pages read, a row laid over, written back), a block step's 48 x 4
+    # rows, and a 512-row chunk (whole pages copied from where XLA laid them
+    # out); two pools, and the latent layers' one
+    for name, n, lanes, B, T in (
+            ("smollm2_decode", 2, 2048, 40, 1), ("smollm2_chunk", 2, 2048, 1, 512), ("trinity_decode", 2, 512, 48, 1),
+            ("sdar_block_step", 2, 512, 48, 4), ("olmo_hybrid_decode", 2, 3840, 32, 1), ("olmo_hybrid_chunk", 2, 3840, 1, 512),
+            ("kimi_latent_decode", 1, 640, 64, 1), ("kimi_latent_chunk", 1, 640, 1, 512)):
+        pool, rows, at = ((2, 65, 16, lanes), BF16), ((B * T, lanes), BF16), ((B * T,), I32)
+        yield f"paged_write_{name}", functools.partial(_paged_write, n, B), [pool] * n + [rows] * n + [((), I32), at, at]
+
+
+def _paged_write(n, B, *args):
+    pools, rows, (layer, phys, off) = args[:n], args[n:2 * n], args[2 * n:]
+    return paged_write_rows(pools, rows, layer, paged_write_segments(phys, off, sequences=B, block_size=pools[0].shape[2]))
 
 
 KERNEL_CASES = list(_kernel_cases())
@@ -252,6 +268,38 @@ def test_the_engines_own_decode_program_compiles_for_v5e(small_engine, v5e):
     assert compiled.memory_analysis().alias_size_in_bytes >= pool_bytes  # donated through, as before
 
 
+@pytest.mark.parametrize("on_chip", [True, False], ids=["on_the_chip", "off_it"])
+def test_the_engines_programs_hold_the_write_they_say(monkeypatch, on_chip):
+    """``stats()["kv_write"]`` is a fact of how the runner's two programs were
+    built, and the programs bear it out: on the chip the decode program and
+    the prefill chunk each hold one ``paged_write`` custom-call a layer stack
+    (beside the attention kernel's) and no scatter whose result is a pool;
+    off it (the plain reference) a scatter a pool and no such call. Through
+    the lowering only: nothing is compiled or run."""
+    import dataclasses
+
+    from ray_tpu.models import init_params
+    from ray_tpu.serve.llm import LLMEngine
+
+    monkeypatch.setattr(backend, "on_tpu", lambda: on_chip)
+    cfg = dataclasses.replace(serving_config(), n_layers=2, vocab_size=512)
+    eng = LLMEngine(cfg, init_params(cfg, jax.random.key(0)), max_batch_size=8, max_seq_len=256, prefill_chunk_tokens=64)
+    try:
+        assert eng.stats()["kv_write"] == eng.runner.kv_write == ("kernel" if on_chip else "scatter")
+        pool = "x".join(str(d) for d in eng.runner.cache["k"].shape)
+        for traced, attention in ((eng.runner.traced_decode(), "paged_decode"), (eng.runner.traced_prefill_chunk(64), "paged_prefill")):
+            text = traced.lower(lowering_platforms=("tpu",)).as_text()
+            results = re.findall(r'"stablehlo\.scatter".*?\}\) : \(.*?\) -> tensor<([\dx]+)x\w+>', text, re.S)
+            pool_scatters = [shape for shape in results if shape == pool]
+            names = re.findall(r'kernel_name = "(\w+)"', text)
+            if on_chip:
+                assert names == ["paged_write", attention] and not pool_scatters
+            else:
+                assert not names and len(pool_scatters) == 2
+    finally:
+        eng.shutdown()
+
+
 def _pallas_grids(jaxpr):
     """The grid of every ``pallas_call`` in ``jaxpr``, scans and calls walked."""
     for eqn in jaxpr.eqns:
@@ -268,7 +316,8 @@ def test_the_decode_programs_paged_kernel_has_one_grid_step_a_slot(as_chip):
     nothing and were most of a decode step)."""
     cfg, args = _engine_decode_args()
     grids = list(_pallas_grids(jax.make_jaxpr(_engine_decode_step(cfg))(*args).jaxpr))
-    assert grids == [(FULL["serve"]["slots"],)]
+    # a layer: the new rows' write (one grid step a call), then the attention
+    assert grids == [(1,), (FULL["serve"]["slots"],)]
 
 
 def _pool_sized_ops(hlo, sizes):
