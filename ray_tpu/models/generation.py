@@ -45,6 +45,7 @@ from ray_tpu.models.transformer import (
     block_ffn,
     block_qkv,
     block_last,
+    conv_mixer,
     embed_tokens,
     flash_by_kind,
     full_kind,
@@ -77,8 +78,9 @@ KVCache = Dict[str, jax.Array]
 def _refuse_ring_for_hybrid(cfg: TransformerConfig, what: str) -> None:
     if cfg.hybrid:
         raise ValueError(f'{what} keeps keys and values only: a config with "linear" layers (recurrent state a '
-                         'sequence) or "latent" layers (one latent row a token) is served through the paged path, '
-                         "init_paged_cache(..., slots=) and paged_forward_with_cache(..., slots=)")
+                         'sequence), "conv" layers (a convolution tail a sequence) or "latent" layers (one latent '
+                         "row a token) is served through the paged path, init_paged_cache(..., slots=) and "
+                         "paged_forward_with_cache(..., slots=)")
 
 
 def _refuse_latent_stack(cfg: TransformerConfig, what: str) -> None:
@@ -119,7 +121,10 @@ def init_paged_cache(
     oldest first, side by side: with an axis of 3 of its own XLA lays the
     array out with that axis on the 128 lanes inside the layer loop, 1.13 GB
     for 28 MB at the benchmark's sizes). A sequence names its pages by its
-    block table and its state by its slot.
+    block table and its state by its slot. A config with "conv" layers (a
+    gated short convolution as the whole mixer) keeps pages for its full
+    layers and, a sequence, ``"conv"`` ``[L_conv, slots, (width - 1) * d]``
+    alone: the tails of its conv layers and no recurrent matrix.
 
     Unlike :func:`init_cache` there is no batch axis — sequences own sets
     of pages named by an ``int32[B, max_blocks]`` block table, so HBM is
@@ -144,16 +149,22 @@ def init_paged_cache(
         cache = {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
     if cfg.hybrid:
         if slots < 1:
-            raise ValueError('a config with "linear" layers keeps a recurrent state a sequence: '
+            raise ValueError('a config with "linear" or "conv" layers keeps a state a sequence: '
                              "init_paged_cache needs slots >= 1")
         cache.update(init_sequence_state(cfg, slots, dt))
     return cache
 
 
 def init_sequence_state(cfg: TransformerConfig, slots: int, dtype=None) -> KVCache:
-    """``slots`` sequences' recurrent state and convolution tails, zero: the
-    ``"state"`` and ``"conv"`` of :func:`init_paged_cache`, and the layout of
-    a pool of snapshots of them (``serve/llm.py``)."""
+    """What ``slots`` sequences carry beside their pages, zero: the per-slot
+    arrays of :func:`init_paged_cache`, and the layout of a pool of snapshots
+    of them (``serve/model_runner.py``). A config with "linear" layers: the
+    recurrent ``"state"`` and the ``"conv"`` tails of its convolution over q,
+    k, v; a config with "conv" layers: the ``"conv"`` tails of its mixers
+    alone, ``[L_conv, slots, (conv_width - 1) * d_model]``. Every function of
+    a sequence's state walks the keys it finds (:data:`STATE_KEYS`)."""
+    if cfg.conv_layers:
+        return {"conv": jnp.zeros((cfg.conv_layers, slots, (cfg.conv_width - 1) * cfg.d_model), dtype or cfg.dtype)}
     H, dk, dv = cfg.linear_heads, cfg.linear_key_dim, cfg.linear_value_dim
     g = lane_group(H, dv)
     return {"state": jnp.zeros((cfg.linear_layers, slots, H // g, dk, g * dv), jnp.float32),
@@ -161,24 +172,29 @@ def init_sequence_state(cfg: TransformerConfig, slots: int, dtype=None) -> KVCac
                               dtype or cfg.dtype)}
 
 
+STATE_KEYS = ("state", "conv")  # a cache's arrays whose axis 1 is the sequence's slot
+
+
 def copy_sequence_state(dst: KVCache, src: KVCache, dst_slot, src_slot) -> KVCache:
-    """``dst``'s state and convolution tail of sequence ``dst_slot`` <- ``src``'s
-    of ``src_slot`` (every linear layer; the slots may be traced): a snapshot
-    taken (``dst`` the snapshot pool) or restored (``dst`` the cache). Other
-    keys of ``dst`` are passed through."""
+    """``dst``'s state of sequence ``dst_slot`` <- ``src``'s of ``src_slot``
+    (every layer that keeps one; the slots may be traced): a snapshot taken
+    (``dst`` the snapshot pool) or restored (``dst`` the cache). Other keys of
+    ``dst`` are passed through."""
     out = dict(dst)
-    for name in ("state", "conv"):
-        row = jax.lax.dynamic_slice_in_dim(src[name], src_slot, 1, axis=1)
-        out[name] = jax.lax.dynamic_update_slice_in_dim(dst[name], row.astype(dst[name].dtype), dst_slot, axis=1)
+    for name in STATE_KEYS:
+        if name in src:
+            row = jax.lax.dynamic_slice_in_dim(src[name], src_slot, 1, axis=1)
+            out[name] = jax.lax.dynamic_update_slice_in_dim(dst[name], row.astype(dst[name].dtype), dst_slot, axis=1)
     return out
 
 
 def zero_sequence_state(cache: KVCache, slot) -> KVCache:
-    """Sequence ``slot`` starts: its state and convolution tail are zero."""
+    """Sequence ``slot`` starts: what it carries beside its pages is zero."""
     out = dict(cache)
-    for name in ("state", "conv"):
-        zero = jnp.zeros_like(jax.lax.dynamic_slice_in_dim(cache[name], 0, 1, axis=1))
-        out[name] = jax.lax.dynamic_update_slice_in_dim(cache[name], zero, slot, axis=1)
+    for name in STATE_KEYS:
+        if name in cache:
+            zero = jnp.zeros_like(jax.lax.dynamic_slice_in_dim(cache[name], 0, 1, axis=1))
+            out[name] = jax.lax.dynamic_update_slice_in_dim(cache[name], zero, slot, axis=1)
     return out
 
 
@@ -391,7 +407,8 @@ def paged_forward_counted(
     use_decode_kernel: Optional[bool] = None,
     layer_scales: Optional[Dict[str, jax.Array]] = None,
     with_logits: bool = True,
-    slots: Optional[jax.Array] = None,  # [B] int32: each row's sequence slot (a config with linear layers)
+    slots: Optional[jax.Array] = None,  # [B] int32: each row's sequence slot (a config with linear or conv layers)
+    routes: bool = False,
 ) -> Tuple[jax.Array, KVCache, Dict[str, jax.Array]]:
     """:func:`forward_with_cache` over a paged pool instead of dense rows:
     (logits, cache, the expert layers' counts).
@@ -405,7 +422,10 @@ def paged_forward_counted(
     (on the chip the kernel, the state updated where it lies), a chunk
     through :func:`~ray_tpu.ops.gated_delta.gated_delta_chunked` from the
     state the slot holds. Positions ``valid`` marks False advance nothing: a
-    row without a valid token (an idle decode row) keeps its state.
+    row without a valid token (an idle decode row) keeps its state. A
+    config with "conv" layers likewise: a conv layer reads row ``b``'s tail
+    at ``cache["conv"][:, slots[b]]``, convolves tail and call, and writes
+    back the last ``conv_width - 1`` inputs up to the row's real tokens.
 
     Writes this call's K/V into the pool through the block tables and
     attends over every cached position up to ``positions``. With
@@ -452,6 +472,10 @@ def paged_forward_counted(
     its experts (``cfg.experts_held``) counts the experts held, and adds
     ``"routed"``: the pairs the routers chose over all the experts. A caller
     that drops them pays nothing: they fall out of the compiled program.
+    ``routes``: in their place ``{"routes": int32[expert layers, B * T, k]}``,
+    the experts each dropless layer ran each token through, in layer order
+    (:func:`~ray_tpu.models.transformer.moe_ffn_dropless`): a comparison hands
+    them to its reference, whose own router breaks a near-tie its own way.
     """
     B, T = tokens.shape
     M = block_tables.shape[1]
@@ -474,6 +498,8 @@ def paged_forward_counted(
         # a query sees past itself inside its block, but nothing past the call's last real token
         vis = vis & (kv_pos < jnp.broadcast_to(starts + lengths, (B,))[:, None])[:, None, None, :]
     _refuse_scales_on_two_stacks(cfg, layer_scales)
+    if routes and (cfg.num_experts <= 0 or cfg.moe_capacity_factor > 0 or (cfg.hybrid and not cfg.split_ffn)):
+        raise ValueError("routes: the config has no dropless expert layer whose selection could be handed out")
 
     phys, off = _paged_write_index(block_tables, positions, valid, bs)
     if use_decode_kernel:
@@ -508,7 +534,8 @@ def paged_forward_counted(
             layer, kind, l = layer_xs
         window = None if kind is None else kind["window"]
         x, kc, vc = paged_attention_block(x, kc, vc, layer, kind, l, window)
-        x, counts = block_ffn(cfg, layer, x, valid, stack=stack, index=l - first, kernel=use_decode_kernel)
+        x, counts = block_ffn(cfg, layer, x, valid, stack=stack, index=l - first, kernel=use_decode_kernel,
+                              routes=routes)
         return (x, kc, vc), counts
 
     def paged_attention_block(x, kc, vc, layer, kind, l, window):
@@ -548,10 +575,11 @@ def paged_forward_counted(
 
     if cfg.hybrid:
         if slots is None:
-            raise ValueError('a config with "linear" layers keeps a state a sequence: pass the rows\' slots')
+            raise ValueError('a config with "linear" or "conv" layers keeps a state a sequence: pass the rows\' slots')
         if layer_scales is not None:
-            raise ValueError('layer_scales (int8 weight-only serving) do not cover a config with "linear" layers')
-        G = lane_group(cfg.linear_heads, cfg.linear_value_dim)
+            raise ValueError('layer_scales (int8 weight-only serving) do not cover a config with "linear" or '
+                             '"conv" layers')
+        G = lane_group(cfg.linear_heads, cfg.linear_value_dim) if cfg.linear_layers else 0
         real = None if valid is None else jnp.broadcast_to(valid, (B, T))
         live = jnp.ones((B,), bool) if valid is None else lengths > 0
 
@@ -570,6 +598,15 @@ def paged_forward_counted(
                 st = st.at[li, slots].set(pack_state(S, G))
             cv = cv.at[li, slots].set(tail.reshape(B, -1).astype(cv.dtype))
             return (kc, vc, st, cv), linear_out(cfg, layer, x, h, o)
+
+        def conv_fn(carry, x, layer, ci):
+            kc, vc, st, cv = carry
+            tail = cv[ci, slots].reshape(B, cfg.conv_width - 1, cfg.d_model)
+            h = pre_norm(cfg, layer, "attn_norm", x)
+            # a row without real tokens reads its own tail back: nothing of it moves
+            x, tail = conv_mixer(cfg, layer, x, h, tail, None if valid is None else lengths)
+            cv = cv.at[ci, slots].set(tail.reshape(B, -1).astype(cv.dtype))
+            return (kc, vc, st, cv), x
 
         def full_fn(carry, x, layer, fi):
             kc, vc, st, cv = carry
@@ -599,33 +636,51 @@ def paged_forward_counted(
 
         latent = cfg.attn_kind == "latent"
         carry = (cache["latent"], None) if latent else (cache["k"], cache["v"])
-        (ks, vs, st, cv), x, counts = hybrid_scan(cfg, params, carry + (cache["state"], cache["conv"]), x, linear_fn,
-                                                  latent_fn if latent else full_fn, valid=valid, kernel=use_decode_kernel)
+        mixers = {"linear": linear_fn, "conv": conv_fn, "full": full_fn, "latent": latent_fn}
+        (ks, vs, st, cv), x, counts, extra = hybrid_scan(
+            cfg, params, carry + (cache.get("state"), cache["conv"]), x, mixers, valid=valid, kernel=use_decode_kernel,
+            routes=routes)
         logits = unembed(cfg, params, x) if with_logits else None
         pools = {"latent": ks} if latent else {"k": ks, "v": vs}
-        if counts is None:
+        if routes:
+            # layer order: the expert layers before the scanned periods, the periods' (a place that
+            # was a dense layer's in its period reads -1 and is no expert layer), those behind
+            nd, (lead, P, R) = cfg.num_dense_layers, cfg.plan
+            before = max(lead - nd, 0)
+            scanned = counts.reshape(R * P, B * T, -1)[max(nd - lead, 0):]
+            outside = extra if extra is not None else scanned[:0]
+            moe = {"routes": jnp.concatenate([outside[:before], scanned, outside[before:]])}
+        elif counts is None:
             moe = {"assignments": jnp.zeros((1,), jnp.int32), "pairs_hit": jnp.zeros((), jnp.int32)}
         else:  # [periods, layers a period, experts held] of the valid tokens
             moe = {"assignments": counts.sum((0, 1)), "pairs_hit": jnp.sum(counts > 0).astype(jnp.int32)}
+            if extra is not None:  # the expert layers before and behind the scanned periods
+                moe = {"assignments": moe["assignments"] + extra.sum(0),
+                       "pairs_hit": moe["pairs_hit"] + jnp.sum(extra > 0).astype(jnp.int32)}
             if cfg.experts_held is not None:
                 # (token, choice) pairs routed over all the experts, here or elsewhere: the counts' denominator
                 tokens_ = B * T if valid is None else jnp.broadcast_to(valid, (B, T)).sum()
                 moe["routed"] = jnp.asarray(tokens_ * cfg.expert_top_k * cfg.expert_layers, jnp.int32)
-        return logits, {**pools, "state": st, "conv": cv}, moe
+        return logits, {**pools, **({} if st is None else {"state": st}), "conv": cv}, moe
 
     carry = (x, cache["k"], cache["v"])
     assignments = jnp.zeros((max(cfg.num_experts, 1),), jnp.int32)
     pairs_hit = jnp.zeros((), jnp.int32)
+    chosen = []
     for stack, first, last in layer_stacks(cfg, params):
         xs = (scanned_leaves(cfg, stack), layer_kinds(cfg, first, last), jnp.arange(first, last, dtype=jnp.int32))
         if layer_scales is not None:
             xs = (stack, layer_scales) + xs[1:]
         carry, counts = jax.lax.scan(partial(layer_fn, stack, first), carry, xs)
-        if counts is not None:  # a dropless expert stack: [layers, E] assignments of the valid tokens
+        if routes:
+            chosen += [] if counts is None else [counts]
+        elif counts is not None:  # a dropless expert stack: [layers, E] assignments of the valid tokens
             assignments = assignments + counts.sum(0)
             pairs_hit = pairs_hit + jnp.sum(counts > 0).astype(jnp.int32)
     x, ks, vs = carry
     logits = unembed(cfg, params, x) if with_logits else None
+    if routes:
+        return logits, {"k": ks, "v": vs}, {"routes": jnp.concatenate(chosen)}
     return logits, {"k": ks, "v": vs}, {"assignments": assignments, "pairs_hit": pairs_hit}
 
 

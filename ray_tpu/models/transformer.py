@@ -38,6 +38,15 @@ nets are the closest analog, ``rllib/core/rl_module/rl_module.py``):
   while the scan's body stays one period. An expert layer may hold a
   contiguous share of its experts (``experts_held``): it routes over all of
   them and computes its own part.
+- A fifth kind, ``"conv"`` (a gated short convolution as a layer's whole
+  mixer: two products around a depthwise causal convolution of ``conv_width``
+  taps, :func:`conv_mixer`), keeps per sequence its last ``conv_width - 1``
+  inputs and nothing else. Its configs need not be periods that end in
+  attention: the layer loop's plan is read from ``layer_types``
+  (:func:`plan_layers`: leading layers traced one by one, the repeating
+  period one scanned body, a tail traced one by one), and their mixers lie
+  by that plan (``params["lead_layers"]``, ``["period_layers"]``: a stack
+  ``[repeats, ...]`` a place, ``["tail_layers"]``).
 - Attention: Pallas flash kernel (``ray_tpu.ops.attention``) on a single
   chip (no mesh); XLA einsum attention under any mesh; or
   ``attention="ring"`` — sequence-parallel ring attention
@@ -55,7 +64,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from functools import partial
+from functools import lru_cache, partial
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -68,6 +77,43 @@ from ray_tpu.ops.attention import NEG_INF, flash_attention_with_lse, mha
 from ray_tpu.ops.decode_attention import block_last
 from ray_tpu.ops.gated_delta import gated_delta_chunked
 from ray_tpu.ops.grouped_matmul import grouped_matmul as grouped_matmul_kernel
+
+
+@lru_cache(maxsize=None)
+def plan_layers(kinds: Tuple[str, ...], dense: int = 0) -> Tuple[int, int, int]:
+    """The layer loop of a list of layer kinds: ``(lead, period, repeats)``.
+    Layers ``[0, lead)`` are traced one by one, then ``kinds[lead : lead +
+    period]`` is one scanned body that runs ``repeats`` times, and what is
+    left behind it, a tail that is no whole period, is traced one by one.
+
+    The rule, read from the list alone: a period holds every kind the list
+    has (so a run of one kind is never a period of its own), and of all
+    ``(lead, period)`` the plan that traces the fewest layer bodies
+    (``len(kinds) - (repeats - 1) * period``: compile time follows what is
+    traced, not the depth) wins; then the one with the fewest places whose
+    layers are dense FFNs in some periods and expert FFNs in others (``dense``:
+    how many leading layers have a dense FFN beside expert layers; such a
+    place chooses by ``cond``, :func:`split_ffn`); then the shortest lead,
+    then the shortest period. ``(k x "linear", "full") x m`` gives ``(0, k + 1,
+    m)``; two leading layers and then periods that begin with their attention
+    layer give ``(2, period, m)``; a list that repeats nothing is one period,
+    scanned once."""
+    N, every = len(kinds), set(kinds)
+    best = None
+    for lead in range(N):
+        for period in range(1, N - lead + 1):
+            body = kinds[lead : lead + period]
+            if set(body) != every:
+                continue
+            repeats = 1
+            while kinds[lead + repeats * period : lead + (repeats + 1) * period] == body:
+                repeats += 1
+            last = lead + (repeats - 1) * period
+            mixed = sum(1 for j in range(period) if lead + j < dense <= last + j)
+            key = (N - (repeats - 1) * period, mixed, lead, period)
+            if best is None or key < best[0]:
+                best = (key, (lead, period, repeats))
+    return best[1]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -110,9 +156,12 @@ class TransformerConfig:
     qk_norm: bool = False               # RMSNorm over head_dim on q and k, before RoPE
     attn_gate: bool = False             # o * sigmoid(h @ wg) before wo
     post_norms: bool = False            # sandwich: RMSNorm on each branch's output before the residual add
-    # per-layer attention kinds: "sliding" (key j visible to query i iff
-    # i - sliding_window < j <= i; RoPE) or "full" (causal; RoPE unless
-    # rope_full_layers is False). None => every layer full with RoPE
+    # per-layer mixer kinds. "sliding" (key j visible to query i iff
+    # i - sliding_window < j <= i; RoPE) and "full" (causal; RoPE unless
+    # rope_full_layers is False) keep keys and values; "linear" (a recurrent
+    # state), "latent" (one latent row a token) and "conv" (a gated short
+    # convolution: the last conv_width - 1 inputs) are described with their
+    # fields below. None => every layer full with RoPE
     layer_types: Optional[Tuple[str, ...]] = None
     sliding_window: int = 0
     rope_full_layers: bool = True
@@ -174,6 +223,14 @@ class TransformerConfig:
     # num_experts; the layer adds the terms of the experts held (and the
     # shared experts) and leaves the absent ones' out
     experts_held: Optional[Tuple[int, int]] = None
+    # what the dropless router adds to the chosen scores' sum before it divides by it (route_norm)
+    route_norm_eps: float = 1e-20
+    # "conv" layers (a gated short convolution as the whole mixer, LFM2):
+    # [B | C | X] = h W_in (d -> 3d), u = B * X, a causal depthwise convolution
+    # of conv_width taps over u, times C, W_out; no activation, no bias. Beside
+    # "full" layers only, in any order (:func:`plan_layers`); a sequence keeps
+    # its last conv_width - 1 rows of u a conv layer
+    conv_width: int = 3
 
     def __post_init__(self):
         if self.block_length > 1:
@@ -189,18 +246,21 @@ class TransformerConfig:
             object.__setattr__(self, "head_dim", self.d_model // self.n_heads)
         if self.layer_types is not None:
             object.__setattr__(self, "layer_types", tuple(self.layer_types))
-            bad = set(self.layer_types) - {"sliding", "full", "linear", "latent"}
+            bad = set(self.layer_types) - {"sliding", "full", "linear", "latent", "conv"}
             if bad or len(self.layer_types) != self.n_layers:
                 raise ValueError(
-                    f'layer_types must name "sliding", "full", "linear" or "latent" for each of the {self.n_layers} '
-                    f"layers; got {self.layer_types!r}"
+                    f'layer_types must name "sliding", "full", "linear", "latent" or "conv" for each of the '
+                    f"{self.n_layers} layers; got {self.layer_types!r}"
                 )
             if "sliding" in self.layer_types and self.sliding_window < 1:
                 raise ValueError("a sliding layer needs sliding_window >= 1")
-            if "latent" in self.layer_types:
-                self._check_latent()
-            if "linear" in self.layer_types:
-                self._check_hybrid()
+            if "conv" in self.layer_types:
+                self._check_conv()  # it stands beside "full" layers only
+            else:
+                if "latent" in self.layer_types:
+                    self._check_latent()
+                if "linear" in self.layer_types:
+                    self._check_hybrid()
         if self.experts_held is not None:
             object.__setattr__(self, "experts_held", tuple(int(e) for e in self.experts_held))
             lo, hi = self.experts_held
@@ -278,14 +338,41 @@ class TransformerConfig:
         if bad:
             raise ValueError('"linear" layers do not go with ' + ", ".join(bad))
 
+    def _check_conv(self) -> None:
+        """A config with "conv" layers: beside "full" layers only, on one
+        device, autoregressive, its expert layers dropless."""
+        kinds = set(self.layer_types)
+        refused = {'"linear" layers in one config': "linear" in kinds, '"latent" layers in one config': "latent" in kinds,
+                   '"sliding" layers in one config': "sliding" in kinds,
+                   'no "full" layer at all (the paged pool would hold no layer)': "full" not in kinds,
+                   "conv_width < 2": self.conv_width < 2,
+                   'attention="ring"': self.attention == "ring", "block_length > 1": self.block_length > 1,
+                   "moe_capacity_factor > 0 (its expert layers are dropless)": self.moe_capacity_factor > 0,
+                   "experts_held (a share of the experts)": self.experts_held is not None}
+        bad = [name for name, hit in refused.items() if hit]
+        if bad:
+            raise ValueError('"conv" layers do not go with ' + "; ".join(bad))
+
     @property
     def kv_heads(self) -> int:
         return self.n_kv_heads or self.n_heads
 
     @property
     def hybrid(self) -> bool:
-        """Whether some layers are "linear" (recurrent state, no K/V)."""
-        return self.layer_types is not None and "linear" in self.layer_types
+        """Whether some layers keep a state a sequence and no K/V: "linear" (a
+        recurrent state and a convolution tail) or "conv" (a tail alone)."""
+        return self.layer_types is not None and bool({"linear", "conv"} & set(self.layer_types))
+
+    @property
+    def plan(self) -> Tuple[int, int, int]:
+        """The layer loop of a :attr:`hybrid` config, read from
+        ``layer_types`` (:func:`plan_layers`): ``(lead, period, repeats)``."""
+        return plan_layers(self.layer_types, self.num_dense_layers if self.num_experts > 0 else 0)
+
+    @property
+    def conv_layers(self) -> int:
+        """Layers whose whole mixer is a gated short convolution: the tails' layer axis."""
+        return self.layer_types.count("conv") if self.layer_types is not None else 0
 
     @property
     def attn_kind(self) -> str:
@@ -294,15 +381,16 @@ class TransformerConfig:
 
     @property
     def linear_per_period(self) -> int:
-        return self.layer_types.index(self.attn_kind) if self.hybrid else 0
+        return self.layer_types.index(self.attn_kind) if self.linear_layers else 0
 
     @property
     def periods(self) -> int:
-        return self.n_layers // (self.linear_per_period + 1) if self.hybrid else 0
+        """How often a hybrid config's period repeats: the layer scan's length."""
+        return self.plan[2] if self.hybrid else 0
 
     @property
     def linear_layers(self) -> int:
-        return self.periods * self.linear_per_period
+        return self.layer_types.count("linear") if self.layer_types is not None else 0
 
     @property
     def latent_layers(self) -> int:
@@ -312,7 +400,7 @@ class TransformerConfig:
     @property
     def kv_layers(self) -> int:
         """Layers that keep keys and values: the paged pool's layer axis."""
-        return self.n_layers - self.linear_layers - self.latent_layers
+        return self.n_layers - self.linear_layers - self.latent_layers - self.conv_layers
 
     @property
     def latent_row(self) -> int:
@@ -330,7 +418,7 @@ class TransformerConfig:
     @property
     def split_ffn(self) -> bool:
         """Whether mixers and FFNs are stacks of their own (:func:`split_ffn`):
-        a config with linear layers and expert layers."""
+        a config with linear or conv layers and expert layers."""
         return self.hybrid and self.num_experts > 0
 
     @property
@@ -397,7 +485,8 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
 
     layer_keys = jax.random.split(k_layers, cfg.n_layers)
 
-    def one_layer(k, experts: bool):
+    def attention_leaves(k):
+        """A "full" or "sliding" layer's mixer without its branch norms."""
         ks = jax.random.split(k, 8)
         layer = {
             "wq": dense_init(ks[0], (d, h, dh), d),
@@ -405,8 +494,6 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
             "wv": dense_init(ks[2], (d, hkv, dh), d),
             "wo": dense_init(ks[3], (h, dh, d), h * dh),
         }
-        if cfg.pre_norms:
-            layer.update(attn_norm=jnp.ones((d,), pd), ffn_norm=jnp.ones((d,), pd))
         # leaves of the newer switches draw from keys folded off the layer's
         # own, so the eight splits above stay what they were
         if cfg.qk_norm:
@@ -414,6 +501,12 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
             layer["k_norm"] = jnp.ones((hkv * dh if cfg.qk_norm_whole else dh,), pd)
         if cfg.attn_gate:
             layer["wg"] = dense_init(jax.random.fold_in(k, 8), (d, h, dh), d)
+        return layer
+
+    def one_layer(k, experts: bool):
+        layer = attention_leaves(k)
+        if cfg.pre_norms:
+            layer.update(attn_norm=jnp.ones((d,), pd), ffn_norm=jnp.ones((d,), pd))
         if cfg.post_norms:
             layer["post_attn_norm"] = jnp.ones((d,), pd)
             layer["post_ffn_norm"] = jnp.ones((d,), pd)
@@ -521,10 +614,62 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
         layer.update(mixer_norms(not cfg.split_ffn))
         return layer
 
+    def planned_mixer(kind: str):
+        """How a layer of ``kind`` is made in a config whose mixers lie by its
+        plan: the mixer's leaves, its branch norms and, unless the FFNs are
+        stacks of their own, the dense FFN's."""
+        def make(k):
+            if kind == "conv":
+                # in: d -> [B | C | X]; the taps [width, d], tap j weighs the input width - 1 - j steps back
+                ks = jax.random.split(jax.random.fold_in(k, 40), 3)
+                K = cfg.conv_width
+                layer = {"conv_in": dense_init(ks[0], (d, 3 * d), d), "conv_out": dense_init(ks[1], (d, d), d),
+                         "conv_w": jax.random.uniform(ks[2], (K, d), minval=-1.0, maxval=1.0).astype(pd) / math.sqrt(K)}
+            else:
+                layer = attention_leaves(k)
+            layer.update(mixer_norms(not cfg.split_ffn))
+            if not cfg.split_ffn:
+                layer.update(ffn_leaves(k, False))
+            return layer
+
+        return make
+
     def stack(keys, experts: bool, make=None):
         # stacked layers: leaves get a leading [layers] dim, scanned in forward.
         make = make or (lambda k: one_layer(k, experts))
         return jax.tree.map(lambda *xs: jnp.stack(xs), *[make(k) for k in keys])
+
+    def split_ffn_stacks():
+        """FFNs in stacks of their own, each kind in layer order: layer i's FFN
+        is dense_ffn[i] for i < num_dense_layers, else
+        expert_ffn[i - num_dense_layers] (:func:`split_ffn`)."""
+        nd_ = cfg.num_dense_layers
+        stacks = {"expert_ffn": stack(layer_keys[nd_:], True, lambda k: ffn_layer(k, True))}
+        if nd_:
+            stacks["dense_ffn"] = stack(layer_keys[:nd_], False, lambda k: ffn_layer(k, False))
+        return stacks
+
+    if cfg.conv_layers:
+        # mixers by the plan (:func:`plan_layers`): the leading and the
+        # trailing layers each a tree of its own, the period's places each a
+        # stack [repeats, ...], so that the scan's slice of it is one layer's
+        # weights, read where they lie
+        lead, period, repeats = cfg.plan
+        behind = lead + period * repeats
+        kinds = cfg.layer_types
+        params = {
+            "embed": dense_init(k_embed, (cfg.vocab_size, d), d),
+            "lead_layers": [planned_mixer(kinds[i])(layer_keys[i]) for i in range(lead)],
+            "period_layers": [stack(layer_keys[lead + j : behind : period], False, planned_mixer(kinds[lead + j]))
+                              for j in range(period)],
+            "tail_layers": [planned_mixer(kinds[i])(layer_keys[i]) for i in range(behind, cfg.n_layers)],
+            "final_norm": jnp.ones((d,), pd),
+        }
+        if cfg.split_ffn:
+            params.update(split_ffn_stacks())
+        if not cfg.tie_embeddings:
+            params["head"] = dense_init(k_head, (cfg.vocab_size, d), d)
+        return params
 
     if cfg.hybrid:
         # a period of k linear layers and one full layer is the scan's body: the
@@ -539,13 +684,7 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
             "final_norm": jnp.ones((d,), pd),
         }
         if cfg.split_ffn:
-            # mixers above, FFNs here, each kind a stack of its own in layer
-            # order: layer i's FFN is dense_ffn[i] for i < num_dense_layers,
-            # else expert_ffn[i - num_dense_layers] (:func:`split_ffn`)
-            nd_ = cfg.num_dense_layers
-            if nd_:
-                params["dense_ffn"] = stack(layer_keys[:nd_], False, lambda k: ffn_layer(k, False))
-            params["expert_ffn"] = stack(layer_keys[nd_:], True, lambda k: ffn_layer(k, True))
+            params.update(split_ffn_stacks())  # mixers above, FFNs here
         if not cfg.tie_embeddings:
             params["head"] = dense_init(k_head, (cfg.vocab_size, d), d)
         return params
@@ -589,7 +728,7 @@ def param_specs(
     ``kv_tp=False`` replicates wk/wv across tp — required under GQA when
     ``kv_heads`` isn't divisible by the tp axis size (callers with a mesh,
     e.g. :func:`make_train_step`, decide automatically)."""
-    refused = {'"latent" layers': cfg.latent_layers > 0, "experts_held (a share of the experts)": cfg.experts_held is not None,
+    refused = {'"latent" layers': cfg.latent_layers > 0, '"conv" layers': cfg.conv_layers > 0, "experts_held (a share of the experts)": cfg.experts_held is not None,
                "expert layers beside linear layers": cfg.split_ffn,
                'linear_gate="channel" or linear_gate_rank > 0': cfg.linear_gate != "head" or cfg.linear_gate_rank > 0}
     bad = [name for name, hit in refused.items() if hit]
@@ -888,7 +1027,7 @@ def route(cfg: TransformerConfig, layer, x2):
     _, experts = jax.lax.top_k(choose, cfg.expert_top_k)
     weights = jnp.take_along_axis(scores, experts, axis=-1)
     if cfg.route_norm:
-        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + cfg.route_norm_eps)
     return experts.astype(jnp.int32), weights * cfg.route_scale
 
 
@@ -925,7 +1064,7 @@ def scanned_leaves(cfg: TransformerConfig, stack):
 
 
 def moe_ffn_dropless(cfg: TransformerConfig, layer, x, valid=None, *, stack=None, index=0, kernel=True,
-                     count_routed: bool = False):
+                     count_routed: bool = False, routes: bool = False):
     """The dropless routed + shared expert layer: route, sort the N x k
     assignments by expert, one grouped product a projection over exactly
     those N x k rows, unsort, weigh and add; the shared experts see every
@@ -956,7 +1095,11 @@ def moe_ffn_dropless(cfg: TransformerConfig, layer, x, valid=None, *, stack=None
     they follow the first valid token's experts, so they make the products
     read no expert that no real token asked for. ``count_routed``: the counts
     are over all ``cfg.num_experts`` routed experts instead, int32[num_experts],
-    whatever share is held: what the router's load rule moves its bias by."""
+    whatever share is held: what the router's load rule moves its bias by.
+    ``routes``: instead of the counts, the selection itself, int32[N, k] (the
+    experts the products ran each token through, a pad's being the first valid
+    token's): what a comparison hands a reference whose own router would
+    break a near-tie the other way."""
     B, T, d = x.shape
     N, E, k = B * T, cfg.experts_here, cfg.expert_top_k
     x2 = x.reshape(N, d)
@@ -997,7 +1140,9 @@ def moe_ffn_dropless(cfg: TransformerConfig, layer, x, valid=None, *, stack=None
     if cfg.num_shared_experts:
         shared = jax.nn.silu(x2 @ layer["ws3"].astype(x.dtype)) * (x2 @ layer["ws1"].astype(x.dtype))
         y = y + shared @ layer["ws2"].astype(x.dtype)
-    if count_routed:
+    if routes:
+        counted = experts
+    elif count_routed:
         every = jnp.ones((N,), jnp.int32) if valid is None else real.astype(jnp.int32)
         counted = jnp.zeros((cfg.num_experts,), jnp.int32).at[experts.reshape(N * k)].add(jnp.repeat(every, k))
     elif valid is None:
@@ -1062,12 +1207,13 @@ def block_attn_out(cfg: TransformerConfig, layer, x, h, o):
 
 
 def block_ffn(cfg: TransformerConfig, layer, x, valid=None, *, stack=None, index=0, kernel=True,
-              count_routed: bool = False):
+              count_routed: bool = False, routes: bool = False):
     """The feed-forward branch: dense or expert layer by the layer's own
     leaves (``stack``, ``index``: where a layer loop keeps the dropless
     experts' weights, see :func:`scanned_leaves`; ``kernel``: whether its
     grouped products may be a Mosaic call, ``count_routed``: which experts
-    the counts are of, see :func:`moe_ffn_dropless`).
+    the counts are of, ``routes``: the selection in the counts' place, see
+    :func:`moe_ffn_dropless`).
     Returns (x, the dropless layer's assignment counts or None)."""
     h = pre_norm(cfg, layer, "ffn_norm", x)
     counts = None
@@ -1077,7 +1223,7 @@ def block_ffn(cfg: TransformerConfig, layer, x, valid=None, *, stack=None, index
         ffn = _moe_ffn_capacity(cfg, layer, h)
     else:
         ffn, counts = moe_ffn_dropless(cfg, layer, h, valid, stack=stack, index=index, kernel=kernel,
-                                       count_routed=count_routed)
+                                       count_routed=count_routed, routes=routes)
     if cfg.post_norms:
         ffn = _rms_norm(ffn, layer["post_ffn_norm"], cfg.norm_eps)
     return x + ffn, counts
@@ -1152,6 +1298,37 @@ def linear_out(cfg: TransformerConfig, layer, x, h, o):
     if cfg.post_norms:
         a = _rms_norm(a, layer["post_attn_norm"], cfg.norm_eps)
     return x + a
+
+
+def conv_mixer(cfg: TransformerConfig, layer, x, h, tail=None, lengths=None):
+    """A "conv" layer's whole mixer branch on its input ``h`` [B, T, d]: ``[B |
+    C | X] = h W_in``, ``u = B * X``, a causal depthwise convolution of
+    ``conv_width`` taps over ``u`` in float32, times ``C``, ``W_out``,
+    post-norm, residual. No activation and no bias. Returns (x, the tail
+    after the call).
+
+    ``tail`` [B, width - 1, d] holds ``u`` of the positions before this call
+    (None: zeros, the sequence starts here); the tail returned holds the last
+    ``width - 1`` rows of ``u`` up to ``lengths`` [B] real tokens of this call
+    (None: all ``T``), so a row without a real token gets its own tail back."""
+    B, T, d = h.shape
+    K = cfg.conv_width
+    with jax.named_scope("conv_mixer"):
+        b, c, xg = jnp.split(h @ layer["conv_in"].astype(h.dtype), 3, axis=-1)
+        u = b * xg
+        if tail is None:
+            tail = jnp.zeros((B, K - 1, d), u.dtype)
+        seq = jnp.concatenate([tail.astype(u.dtype), u], axis=1)                         # [B, K - 1 + T, d]
+        w = layer["conv_w"].astype(jnp.float32)
+        conv = sum(seq[:, j : j + T].astype(jnp.float32) * w[j] for j in range(K))
+        a = (c.astype(jnp.float32) * conv).astype(h.dtype) @ layer["conv_out"].astype(h.dtype)
+        if lengths is None:
+            new_tail = seq[:, T:]
+        else:
+            new_tail = jax.vmap(lambda s, n: jax.lax.dynamic_slice_in_dim(s, n, K - 1, axis=0))(seq, lengths)
+    if cfg.post_norms:
+        a = _rms_norm(a, layer["post_attn_norm"], cfg.norm_eps)
+    return x + a, new_tail
 
 
 def latent_qkv(cfg: TransformerConfig, layer, h):
@@ -1234,17 +1411,20 @@ def latent_attention_expanded(cfg: TransformerConfig, layer, x, h, positions=Non
     return _latent_wo(cfg, layer, x, o)
 
 
-def split_ffn(cfg: TransformerConfig, params, x, i, j: int, valid=None, kernel: bool = True):
-    """The FFN branch of layer ``i`` (traced) of a config whose FFNs are
-    stacks of their own (``cfg.split_ffn``); ``j`` is the layer's place in
-    its period, known while tracing. Layer ``i`` takes
-    ``params["dense_ffn"][i]`` while ``i < num_dense_layers`` and
-    ``params["expert_ffn"][i - num_dense_layers]`` after: a place that is
-    dense in the first period and routed in the others chooses by ``cond``,
+def split_ffn(cfg: TransformerConfig, params, x, i, first: int, valid=None, kernel: bool = True,
+              routes: bool = False):
+    """The FFN branch of layer ``i`` of a config whose FFNs are stacks of
+    their own (``cfg.split_ffn``). ``i`` is traced in the scanned period,
+    where ``first`` is the first layer that takes this place of the period
+    (known while tracing), and a Python int for a layer traced on its own.
+    Layer ``i`` takes ``params["dense_ffn"][i]`` while ``i < num_dense_layers``
+    and ``params["expert_ffn"][i - num_dense_layers]`` after: a place that is
+    dense in its first period and routed in the others chooses by ``cond``,
     every other place is routed and traces no branch. The small leaves are
     read at a traced index, as a scan reads its xs; the experts' weights stay
     where they lie (:func:`scanned_leaves`). Returns (x, the expert layer's
-    assignment counts int32[experts held]; zeros from a dense layer)."""
+    assignment counts int32[experts held]; zeros from a dense layer), or with
+    ``routes`` its selection int32[tokens, k] (-1 from a dense layer)."""
     nd = cfg.num_dense_layers
 
     def at(stack, index):
@@ -1252,63 +1432,106 @@ def split_ffn(cfg: TransformerConfig, params, x, i, j: int, valid=None, kernel: 
 
     def dense(x):
         layer = at(params["dense_ffn"], 0 if nd == 1 else jnp.clip(i, 0, nd - 1))
+        if routes:
+            return block_ffn(cfg, layer, x)[0], jnp.full((x.shape[0] * x.shape[1], cfg.expert_top_k), -1, jnp.int32)
         return block_ffn(cfg, layer, x)[0], jnp.zeros((cfg.experts_here,), jnp.int32)
 
     def routed(x):
         stack = params["expert_ffn"]
         index = jnp.maximum(i - nd, 0)
-        return block_ffn(cfg, at(scanned_leaves(cfg, stack), index), x, valid, stack=stack, index=index, kernel=kernel)
+        return block_ffn(cfg, at(scanned_leaves(cfg, stack), index), x, valid, stack=stack, index=index, kernel=kernel,
+                         routes=routes)
 
-    if j >= nd:
+    if isinstance(i, int):
+        return dense(x) if i < nd else routed(x)
+    if first >= nd:
         return routed(x)
     return jax.lax.cond(i < nd, dense, routed, x)
 
 
-def hybrid_scan(cfg: TransformerConfig, params, carry, x, linear_fn, full_fn, valid=None, kernel: bool = True):
-    """The layer loop of a config with linear layers: a ``lax.scan`` over its
-    periods whose body runs the period's ``k`` linear layers and then its
-    full (or latent) layer, so what is traced and compiled is one period,
-    whatever the depth. ``linear_fn(carry, x, layer, i)`` and ``full_fn(carry,
-    x, layer, i)`` run a layer's mixer branch and return ``(carry, x)``; ``i``
-    counts the layers of their kind from 0 (traced); ``carry`` is whatever
-    the caller keeps beside ``x`` (the caches, updated in place). The FFN
-    branch follows each here: the layer's own leaves, or for a config whose
-    FFNs are stacks of their own :func:`split_ffn` (``valid``, ``kernel``: as
-    :func:`block_ffn` takes them). Returns ``(carry, x, counts)``: the expert
-    layers' assignment counts ``int32[periods, k + 1, experts held]``, None
-    without expert layers.
+def planned_stacks(params):
+    """A hybrid config's mixers as its plan walks them: (the leading layers'
+    trees, the period's stacks a place, the trailing layers' trees). A config
+    with "linear" layers is whole periods, its linear places'
+    ``params["linear_layers"]`` and its last place ``params["layers"]``."""
+    if "period_layers" in params:
+        return params["lead_layers"], tuple(params["period_layers"]), params["tail_layers"]
+    return (), (*params["linear_layers"], params["layers"]), ()
 
-    The ``k`` linear layers are the body's own lines, each with its own
-    stack among the scan's xs: the scan's slice of a stack is then one
-    layer's weights, which the products read where they lie. (An inner scan
-    over a ``[periods, k, ...]`` stack takes the period's slice as a loop
-    invariant, and a static index into such a slice fares no better: XLA
-    copies the slice out of the stack every period. The chipless compile at
-    the benchmark's sizes: 1.3 GB of temporaries, which a decode step would
-    write and read besides the weights themselves.)"""
-    k = cfg.linear_per_period
 
-    def ffn(x, layer, j, p):
+def hybrid_scan(cfg: TransformerConfig, params, carry, x, mixers, valid=None, kernel: bool = True,
+                routes: bool = False):
+    """The layer loop of a config whose layers are not one stack (linear or
+    conv layers beside attention), by its plan (``cfg.plan``,
+    :func:`plan_layers`): the leading layers one by one, then a ``lax.scan``
+    over the period's repeats whose body runs the period's layers in their
+    order, then the trailing layers one by one; so what is traced and compiled
+    is the lead, one period and the tail, whatever the depth.
+    ``mixers[kind](carry, x, layer, i)`` runs the mixer branch of a layer of
+    ``kind`` and returns ``(carry, x)``; ``i`` counts the layers of that kind
+    from 0 (traced inside the period); ``carry`` is whatever the caller keeps
+    beside ``x`` (the caches, updated in place). The FFN branch follows each
+    here: the layer's own leaves, or for a config whose FFNs are stacks of
+    their own :func:`split_ffn` (``valid``, ``kernel``: as :func:`block_ffn`
+    takes them). Returns ``(carry, x, counts, extra)``: the expert layers'
+    assignment counts ``int32[repeats, period, experts held]`` of the scanned
+    layers and ``int32[layers, experts held]`` of the expert layers outside
+    it, each None where there are none; with ``routes`` (a config whose FFNs
+    are stacks of their own) the selections ``int32[.., tokens, k]`` in the
+    counts' places.
+
+    The period's layers are the body's own lines, each with its own stack
+    among the scan's xs: the scan's slice of a stack is then one layer's
+    weights, which the products read where they lie. (An inner scan over a
+    ``[periods, k, ...]`` stack takes the period's slice as a loop invariant,
+    and a static index into such a slice fares no better: XLA copies the slice
+    out of the stack every period. The chipless compile at the benchmark's
+    sizes: 1.3 GB of temporaries, which a decode step would write and read
+    besides the weights themselves.)"""
+    lead, P, R = cfg.plan
+    kinds = cfg.layer_types
+    body = kinds[lead : lead + P]
+    leads, places, tails = planned_stacks(params)
+
+    def before(i):
+        """Layers of layer ``i``'s kind that lie before it."""
+        return kinds[:i].count(kinds[i])
+
+    def ffn(x, layer, i, first):
         if cfg.split_ffn:
-            return split_ffn(cfg, params, x, p * (k + 1) + j, j, valid, kernel)
+            return split_ffn(cfg, params, x, i, first, valid, kernel, routes)
         return block_ffn(cfg, layer, x)
 
-    def period(state, xs):
-        lin, full, p = xs
+    def alone(state, layers, start):
+        """Layers ``start ...`` traced one by one, each from a tree of its own."""
         counts = []
-        for j in range(k + 1):
-            layer = lin[j] if j < k else full
-            carry, x = linear_fn(*state, layer, p * k + j) if j < k else full_fn(*state, layer, p)
-            x, c = ffn(x, layer, j, p)
+        for i, layer in enumerate(layers, start):
+            carry, x = mixers[kinds[i]](*state, layer, before(i))
+            x, c = ffn(x, layer, i, i)
+            state = (carry, x)
+            if c is not None and i >= cfg.num_dense_layers:
+                counts.append(c)
+        return state, counts
+
+    def period(state, xs):
+        *layers, p = xs
+        counts = []
+        for j in range(P):
+            per, off = body.count(body[j]), before(lead + j)
+            i = p if (per, off) == (1, 0) else p * per + off  # this layer's count among its kind
+            carry, x = mixers[body[j]](*state, layers[j], i)
+            x, c = ffn(x, layers[j], p * P + (lead + j), lead + j)
             state = (carry, x)
             counts.append(c)
         return state, jnp.stack(counts) if cfg.split_ffn else None
 
     if cfg.remat:
         period = jax.checkpoint(period, policy=jax.checkpoint_policies.dots_saveable if cfg.remat == "dots" else None)
-    (carry, x), counts = jax.lax.scan(
-        period, (carry, x), (tuple(params["linear_layers"]), params["layers"], jnp.arange(cfg.periods, dtype=jnp.int32)))
-    return carry, x, counts
+    state, extra = alone((carry, x), leads, 0)
+    state, counts = jax.lax.scan(period, state, (*places, jnp.arange(R, dtype=jnp.int32)))
+    (carry, x), more = alone(state, tails, lead + P * R)
+    extra = jnp.stack(extra + more) if extra + more else None
+    return carry, x, counts, extra
 
 
 def full_kind(cfg: TransformerConfig):
@@ -1326,14 +1549,19 @@ def _hybrid_forward(cfg: TransformerConfig, params, x, positions, use_flash: boo
         o, _ = gated_delta_chunked(S0, q, k, v, g, beta)
         return carry, linear_out(cfg, layer, x, h, o)
 
+    def conv_fn(carry, x, layer, _):
+        return carry, conv_mixer(cfg, layer, x, pre_norm(cfg, layer, "attn_norm", x))[0]
+
     def full_fn(carry, x, layer, _):
         h = pre_norm(cfg, layer, "attn_norm", x)
-        if cfg.attn_kind == "latent":
-            return carry, latent_attention_expanded(cfg, layer, x, h)
         q, k, v = block_qkv(cfg, layer, h, positions, full_kind(cfg))
         return carry, block_attn_out(cfg, layer, x, h, _attention(cfg, q, k, v, use_flash))
 
-    return hybrid_scan(cfg, params, (), x, linear_fn, full_fn)[1]
+    def latent_fn(carry, x, layer, _):
+        return carry, latent_attention_expanded(cfg, layer, x, pre_norm(cfg, layer, "attn_norm", x))
+
+    mixers = {"linear": linear_fn, "conv": conv_fn, "full": full_fn, "latent": latent_fn}
+    return hybrid_scan(cfg, params, (), x, mixers)[1]
 
 
 def unembed(cfg: TransformerConfig, params, x):
@@ -1411,7 +1639,7 @@ def forward_and_load(cfg: TransformerConfig, params, tokens, *, act_spec=None, m
 
     if cfg.hybrid:
         if act_spec is not None:
-            raise ValueError('a config with "linear" layers runs on one device: its forward takes no mesh')
+            raise ValueError('a config with "linear" or "conv" layers runs on one device: its forward takes no mesh')
         return unembed(cfg, params, _hybrid_forward(cfg, params, x, positions, use_flash)), None
     counted = load_ruled(cfg)
 
@@ -1542,6 +1770,9 @@ def make_train_step(
 
     opt = optax.adamw(learning_rate)
     balanced = load_ruled(cfg)
+    if cfg.conv_layers:
+        raise ValueError('a config with "conv" layers is served, not trained: make_train_step has no gradient test '
+                         "over the convolution mixer and its plan's lead, period and tail (forward and loss_fn run it)")
     if cfg.router_bias and cfg.dropless and not balanced:
         raise ValueError('router_bias beside "linear" layers is served, not trained: the load rule reads the '
                          "plain layer stack's assignment counts (forward_and_load)")

@@ -25,10 +25,11 @@ per block-table entry) or the prefix cache (one reference per cached
 node), so every existing release path stays a plain ``free`` of the slot's
 pages.
 
-A configuration with recurrent layers keeps a second pool beside the pages:
+A configuration whose layers keep a state a sequence keeps a second pool beside the pages:
 :class:`SnapshotPool`, the free list over the entries of a device array of
-state snapshots (one entry: every recurrent layer's state and convolution
-tail after some prefix). An entry has one owner at a time: a live request
+state snapshots (one entry: every such layer's state after some prefix: a
+recurrent state and a convolution tail, or where the mixer is a short
+convolution the tail alone, a few KB a layer). An entry has one owner at a time: a live request
 (a snapshot taken for it and not yet published) or a node of the prefix
 cache; there is nothing to share, so there are no reference counts.
 

@@ -15,7 +15,7 @@ for the device state, every program and what a decode step yields.
   admission pattern).
 - **Block-aware admission.** A request is admitted when the store can
   reserve its whole page budget (paged KV, prefix reuse, a config with linear
-  layers' recurrent state and its snapshots: ``serve/sequence_store.py``).
+  or conv layers' per-slot state and its snapshots: ``serve/sequence_store.py``).
 - **Chunked prefill.** Prompts prefill in fixed-size chunks interleaved
   between decode steps (Sarathi-style bounded per-iteration budget,
   ``prefill_chunk_tokens``; 0 = one-shot with power-of-2 bucketing), so a
@@ -109,14 +109,15 @@ _REFUSED = (
         "page": "kv_block_size {kv_block_size} (a page holds whole blocks of {block})",
         "length": "max_seq_len {max_seq_len} (whole blocks of {block})",
     }),
-    ("linear", 'a config with "linear" layers (recurrent state a sequence) cannot be served with ', {
+    ("slot state", 'a config whose layers keep a state a sequence at its slot ("linear": a recurrent state; "conv": '
+                   "a convolution tail) cannot be served with ", {
         "decode_chunk": "decode_chunk > 1 (a snapshot is taken behind one step's state)",
         "quantize": "quantize=True (the int8 scales ride one stack of layers)",
         "mesh": "mesh (the state and the snapshot pool are not sharded)",
     }),
-    ("no linear", "", {
-        "state_snapshots": 'state_snapshots={state_snapshots} belongs to a config with "linear" layers; '
-                           "this one keeps no recurrent state",
+    ("no slot state", "", {
+        "state_snapshots": 'state_snapshots={state_snapshots} belongs to a config with "linear" or "conv" layers; '
+                           "this one keeps no state a sequence beside its pages",
     }),
     ("mesh", "", {
         "quantize": "quantize=True with mesh is not supported yet",
@@ -130,9 +131,10 @@ _REFUSED = (
                     "(two layer stacks; expert weights read where they lie): serve it unquantized",
     }),
     # at submit
-    ("linear", "", {
-        "migration": 'prefill_export / adopt_migration are not supported for a config with "linear" layers: '
-                     "a migrated block set carries pages, not the sequence's recurrent state",
+    ("slot state", "", {
+        "migration": 'prefill_export / adopt_migration are not supported for a config with "linear" or "conv" '
+                     "layers: a migrated block set carries pages, not the sequence's recurrent state or "
+                     "convolution tails",
     }),
     ("autoregressive", "", {
         "denoising_steps": "denoising_steps belongs to a config that generates by diffusion over blocks "
@@ -149,7 +151,7 @@ _REFUSED = (
 def _check_combination(cfg: TransformerConfig, mesh: Any, asked: Dict[str, Any], **named) -> None:
     """No silent path: raise what ``_REFUSED`` holds against a call that
     ``asked`` for these (what is asked for by name -> whether it was)."""
-    held = {"block": cfg.block > 1, "autoregressive": cfg.block == 1, "linear": cfg.hybrid, "no linear": not cfg.hybrid,
+    held = {"block": cfg.block > 1, "autoregressive": cfg.block == 1, "slot state": cfg.hybrid, "no slot state": not cfg.hybrid,
             "mesh": mesh is not None, "dense_stack or dropless": bool(cfg.dense_stack or cfg.dropless)}
     for row, head, cells in _REFUSED:
         bad = [why.format(block=cfg.block, **named) for what, why in cells.items() if held[row] and asked.get(what)]
@@ -309,7 +311,7 @@ class LLMEngine:
     blocks stay cached and are shared into later requests, at most
     ``prefix_cache_max_blocks`` of them (0 = what the pool can spare).
     ``state_snapshots``: entries of the state-snapshot pool of a config with
-    linear layers (None = twice ``max_batch_size``; 0 = none: every request
+    linear or conv layers (None = twice ``max_batch_size``; 0 = none: every request
     prefills its whole prompt); any other config has no such pool and takes
     only None or 0.
     """
@@ -1091,6 +1093,7 @@ class LLMEngine:
         whatever the slot's last occupant still had in flight, so nothing of
         it survives. False: the copy failed and the request with it."""
         # (the tool named at ``_restore_state`` swaps this method and the two programs it passes)
+        t = time.perf_counter()
         try:
             if snapshot >= 0:
                 self.runner.restore_state(req.slot, snapshot, self._restore_state)
@@ -1099,7 +1102,9 @@ class LLMEngine:
         except BaseException as exc:  # noqa: BLE001
             self._fail_admit(req, exc)
             return False
+        spent = time.perf_counter() - t  # enqueue to return: the span ``llm::state_restore``
         with self._lock:
+            self.store.state_reset_s += spent
             if snapshot >= 0:
                 self.store.state_restores += 1
             else:
@@ -1422,7 +1427,7 @@ class LLMEngine:
         cfg = self.cfg
         lens = self._pos[self._active].astype(np.int64) + cfg.block  # to the end of the step's writes
         last = -(-lens // bs)
-        if cfg.hybrid:  # the full (or latent) layers walk every page, the linear layers none
+        if cfg.hybrid:  # the full (or latent) layers walk every page, the linear and conv layers none
             return (cfg.kv_layers + cfg.latent_layers) * int(last.sum()) / cfg.n_layers
         windows = cfg.layer_windows or (0,)
         visited = sum(int((last - np.maximum(lens - w, 0) // bs).sum()) if w else int(last.sum()) for w in windows)

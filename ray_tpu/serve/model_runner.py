@@ -1,7 +1,7 @@
 """The model runner: a serving engine's device state and every program over it.
 
 One :class:`ModelRunner` holds the parameters (sharded under a mesh, int8 with
-``quantize``), the paged pool, a config with linear layers' slot states and
+``quantize``), the paged pool, a config with linear or conv layers' slot states and
 snapshot pool, the sampling key and the rows' last tokens, and builds every
 jitted callable once, so the engine's loop never traces. Each method enqueues
 its program and returns without waiting: the caller decides when to read.
@@ -341,7 +341,7 @@ class ModelRunner:
             compile. Writes K/V for the chunk's ``length`` real tokens
             through the block table and returns the last real token's
             logits [V] (only the final chunk's are consumed). ``slot`` [1]
-            (a config with linear layers): where the sequence's state lives."""
+            (a config with linear or conv layers): where the sequence's state lives."""
             C = toks.shape[1]
             positions = start + jnp.arange(C)[None, :]
             valid = (jnp.arange(C) < length)[None, :]
@@ -386,7 +386,7 @@ class ModelRunner:
             # a live row's first page is never the garbage page 0 (idle
             # rows decode through all-zero tables): the expert layers
             # count the live rows' assignments only
-            # (and an idle row's recurrent state stays as it is)
+            # (and an idle row's recurrent state or convolution tail stays as it is)
             live = (bt[:, 0] > 0)[:, None] if moe_counted or hybrid else None
             slots = jnp.arange(bt.shape[0], dtype=jnp.int32) if hybrid else None
 
@@ -545,7 +545,12 @@ class ModelRunner:
                                               jnp.int32(n))
 
     def read_snapshot(self, snaps, entry: int):
-        """Entry ``entry`` of the snapshot arrays ``snaps`` as float32
-        [linear layers, heads, key dim, value dim]."""
+        """Entry ``entry`` of the snapshot arrays ``snaps`` as float32: the
+        recurrent state [linear layers, heads, key dim, value dim], or, of a
+        config whose state is its conv layers' tails alone, those: [conv
+        layers, width - 1, d], oldest input first."""
+        if "state" not in snaps:
+            tails = np.asarray(snaps["conv"][:, entry].astype(jnp.float32))
+            return tails.reshape(tails.shape[0], self.cfg.conv_width - 1, -1)
         group = lane_group(self.cfg.linear_heads, self.cfg.linear_value_dim)
         return np.asarray(unpack_state(snaps["state"][:, entry], group))

@@ -34,8 +34,9 @@ parent whose last child was just taken joins the heap. That is
 that needs 110 pages from a pool of 6 000 cached ones examines ~6 100
 nodes, not 110 x 6 000. ``scanned`` counts the nodes examined.
 
-**State snapshots** (a configuration with recurrent layers). There a page
-match alone is no hit: the recurrent state after the matched tokens has to
+**State snapshots** (a configuration whose layers keep a state a sequence: a
+recurrent state, or a convolution tail alone). There a page
+match alone is no hit: the state after the matched tokens has to
 exist too. A node may carry a *snapshot*: the index of an entry of the
 engine's snapshot pool that holds the state after exactly the tokens of the
 chain down to that node. ``match_snapshot`` returns, with the pages, the

@@ -24,10 +24,12 @@ called with it held, every other takes it itself.
   land in a shared page goes through copy-on-write; when the pool runs
   short, unreferenced cached leaves are LRU-evicted before admission holds
   or sheds (vLLM PagedAttention / SGLang RadixAttention idiom).
-- **Recurrent state beside the pages.** A config with "linear" layers
-  (Gated DeltaNet: ``cfg.hybrid``) keeps keys and values in its full layers
-  only; its linear layers carry a recurrent state and a convolution tail a
-  sequence, which live in the cache at the sequence's decode slot. A slot's
+- **A sequence's state beside the pages.** A config with "linear" layers
+  (Gated DeltaNet) or "conv" layers (a gated short convolution as the whole
+  mixer), ``cfg.hybrid``, keeps keys and values in its full layers only; a
+  linear layer carries a recurrent state and a convolution tail a sequence, a
+  conv layer a tail and nothing else, and they live in the cache at the
+  sequence's decode slot (whatever keys ``init_sequence_state`` gives). A slot's
   state is zeroed or restored from a snapshot on the device at admission, in
   order with the step in flight. A page match alone is no prefix hit there:
   a request skips prefill only as far as the deepest matched radix node that
@@ -110,8 +112,9 @@ class SequenceStore:
         self.max_blocks_per_slot = -(-S // kv_block_size)
         self.allocator = BlockAllocator(kv_num_blocks)
         self.prefix = PrefixCache(kv_block_size, max_blocks) if prefix_cache else None
-        # a config with linear layers keeps a recurrent state a sequence at
-        # its slot, and a pool of snapshots of it beside the page pool
+        # a config with linear or conv layers keeps a state a sequence at its
+        # slot (a recurrent state and tails, or tails alone), and a pool of
+        # snapshots of it beside the page pool
         self.keeps_state = cfg.hybrid
         self.n_snapshots = n_snapshots  # the pool's size: fixed, read without the lock
         self.snap_pool = SnapshotPool(n_snapshots)
@@ -129,6 +132,7 @@ class SequenceStore:
         self.state_snapshots_taken = 0
         self.state_restores = 0
         self.state_zeroed = 0
+        self.state_reset_s = 0.0  # host seconds the scheduler spent placing admitted slots' states
         # disaggregated serving: staged exports parked by migration id
         # (the extracted block arrays outlive the prefill request's pool
         # pages — those retire into the prefix cache at export)
@@ -136,7 +140,7 @@ class SequenceStore:
         # layers that walk pages: K and V, or a latent layer's one row a token
         attn_layers = cfg.kv_layers + cfg.latent_layers
         # (window, layers that have it); 0: a full layer
-        # (a linear layer has no K/V: ``chunk_kv_visited`` still averages over every layer)
+        # (a linear or conv layer has no K/V: ``chunk_kv_visited`` still averages over every layer)
         self._layers_by_window = sorted(Counter(
             (0,) * attn_layers if cfg.hybrid else cfg.layer_windows or (0,) * cfg.n_layers).items())
         self.gauges(1)
@@ -356,12 +360,13 @@ class SequenceStore:
                 self.snap_pool = SnapshotPool(self.n_snapshots)
 
     def state_snapshot(self, tokens: List[int]) -> Optional[Dict[str, Any]]:
-        """Read-out for a check (a config with linear layers, an engine at
-        rest): the deepest state snapshot the prefix cache holds on the path
-        of ``tokens``, as ``{"tokens": how many of them it covers, "state":
-        the recurrent state after exactly those, float32 [linear layers,
-        heads, key dim, value dim]}``, or None if no node on the path carries
-        one. No clock of either pool moves. The engine thread replaces the
+        """Read-out for a check (a config with linear or conv layers, an
+        engine at rest): the deepest state snapshot the prefix cache holds on
+        the path of ``tokens``, as ``{"tokens": how many of them it covers,
+        "state": what a sequence carries after exactly those}`` (float32: the
+        recurrent state [linear layers, heads, key dim, value dim], or a conv
+        config's tails [conv layers, width - 1, d]), or None if no node on
+        the path carries one. No clock of either pool moves. The engine thread replaces the
         pool's arrays whenever it takes a snapshot, so call this while
         nothing decodes."""
         if self.prefix is None or self._runner.snaps is None:
@@ -669,8 +674,8 @@ class SequenceStore:
         }
 
     def state_stats_locked(self) -> Dict[str, Any]:
-        """The recurrent state's own counters (absent for a config without
-        linear layers): the snapshot pool's size and entries held (by live
+        """The per-slot state's own counters (absent for a config without
+        linear or conv layers): the snapshot pool's size and entries held (by live
         requests and by radix nodes), snapshots taken, snapshots detached
         because the pool was full, slots restored from a snapshot and slots
         zeroed at admission, the prompt tokens a page match offered
@@ -687,8 +692,13 @@ class SequenceStore:
             "state_zeroed": self.state_zeroed,
             "prefix_tokens_matched": self.prefix_tokens_matched,
             "state_bytes_per_slot": self._runner.state_bytes_per_slot,
+            # host seconds spent giving admitted slots their state (zeroed or restored), enqueue to return
+            "state_reset_s": self.state_reset_s,
             # a config with latent layers: how many, and the bytes a cached token takes in all the
             # pools as they were built (every attention layer, the pad lanes included)
             **({"latent_layers": self.cfg.latent_layers, "kv_bytes_per_token": self._runner.kv_bytes_per_token}
                if self.cfg.latent_layers else {}),
+            # a config with conv layers: how many, and the bytes of one slot's tails (all of its state)
+            **({"conv_layers": self.cfg.conv_layers, "conv_tail_bytes_per_slot": self._runner.state_bytes_per_slot}
+               if self.cfg.conv_layers else {}),
         }

@@ -204,7 +204,7 @@ EXPERTS = TransformerConfig(vocab_size=64, d_model=32, n_layers=2, n_heads=2, d_
                             expert_top_k=2)
 MESH = object()  # the table asks only whether there is one
 # (a configuration with the row's property, one without it)
-ROWS = {"block": (BLOCK, PLAIN), "autoregressive": (PLAIN, BLOCK), "linear": (HYBRID, PLAIN), "no linear": (PLAIN, HYBRID),
+ROWS = {"block": (BLOCK, PLAIN), "autoregressive": (PLAIN, BLOCK), "slot state": (HYBRID, PLAIN), "no slot state": (PLAIN, HYBRID),
         "mesh": (PLAIN, PLAIN), "dense_stack or dropless": (EXPERTS, PLAIN)}
 NAMED = dict(kv_block_size=6, max_seq_len=62, state_snapshots=4, role="decode", tp="tp", axes=("x",), denoising_steps=9)
 

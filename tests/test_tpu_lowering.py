@@ -607,6 +607,72 @@ def test_the_kimi_linear_cells_decode_step_and_prefill_chunk_compile_for_v5e_and
           "cache_gb", round(nbytes(cache) / 1e9, 2), "latent_pool_gb", round(nbytes(cache["latent"]) / 1e9, 2))
 
 
+def test_the_lfm2_cells_decode_step_and_prefill_chunk_compile_for_v5e_and_fit(as_chip, v5e):
+    """The benchmark's LFM2 configuration as its file states it (14 layers at
+    published widths: 11 gated short convolutions and 3 GQA layers by the plan
+    (2, 4, 3); 2 dense FFNs and 12 expert layers of all 32 experts; 128 slots,
+    16 384 pages of 3 KV layers, 512 tail snapshots): a decode step of 128
+    rows and a prefill chunk of 512 tokens compile for a v5e with the paged
+    kernels and the grouped products by name, update the donated pools and
+    tails in place (no tail pool is copied: the temporaries stay under a chunk's
+    float32 logits and activations), keep no recurrent matrix, and fit the
+    chip's 16 GB beside the snapshot pool. The memory analysis is what the
+    configuration file's ``sizing`` quotes."""
+    from benchmark import system
+    from ray_tpu.models import init_params
+    from ray_tpu.models.generation import init_paged_cache, init_sequence_state, paged_forward_counted
+
+    config = system.load_json("benchmark/configs/lfm2-8b-a1b-serve-l14.json")
+    run = config["run"]
+    cfg = system.model_module(config).program_config(
+        config, max_seq_len=run["max_seq_len"], dtype=run["dtype"], param_dtype=run["param_dtype"])
+    assert cfg.plan == (2, 4, 3) and (cfg.conv_layers, cfg.kv_layers) == (11, 3)
+    one = SingleDeviceSharding(v5e[0])
+    B, bs, C = run["max_batch_size"], run["kv_block_size"], run["prefill_chunk_tokens"]
+    M = run["max_seq_len"] // bs
+    params = _abstract_tree(lambda: init_params(cfg, jax.random.key(0)), one)
+    cache = _abstract_tree(lambda: init_paged_cache(cfg, run["kv_num_blocks"], bs, slots=B), one)
+    snaps = _abstract_tree(lambda: init_sequence_state(cfg, run["state_snapshots"]), one)
+    assert set(cache) == {"k", "v", "conv"} and cache["k"].shape == (3, 16384, 16, 512)
+    assert cache["conv"].shape == (11, 128, 2 * 2048) and set(snaps) == {"conv"}
+    assert params["expert_ffn"]["we1"].shape == (12, 32, 2048, 1792) and params["dense_ffn"]["w1"].shape == (2, 2048, 7168)
+    assert [len(params[k]) for k in ("lead_layers", "period_layers", "tail_layers")] == [2, 4, 0]
+    toks, pos, bt, chunk, row, slot, scalar = _abstract(
+        [((B,), I32), ((B,), I32), ((B, M), I32), ((1, C), I32), ((1, M), I32), ((1,), I32), ((), I32)], one)
+    nbytes = lambda tree: sum(int(np.prod(x.shape)) * x.dtype.itemsize for x in jax.tree.leaves(tree))  # noqa: E731
+    assert sum(int(np.prod(x.shape)) for x in jax.tree.leaves(params)) == system.model_module(config).n_params(config)
+
+    def step(params, cache, toks, pos, bt):
+        live = (bt[:, 0] > 0)[:, None]
+        logits, cache, moe = paged_forward_counted(cfg, params, cache, bt, toks[:, None], pos[:, None], valid=live,
+                                                   slots=jnp.arange(B, dtype=I32))
+        return jnp.argmax(logits[:, 0], -1), cache, moe
+
+    def prefill(params, cache, toks, bt, start, length, slot):
+        valid = (jnp.arange(C) < length)[None, :]
+        logits, cache, moe = paged_forward_counted(cfg, params, cache, bt, toks, start + jnp.arange(C)[None, :],
+                                                   valid=valid, slots=slot)
+        return jax.lax.dynamic_index_in_dim(logits[0], length - 1, 0, keepdims=False), cache, moe
+
+    report = {}
+    for name, fn, args, kernels in (
+            ("decode", step, (params, cache, toks, pos, bt), ("paged_decode", "grouped_matmul", "paged_write")),
+            ("prefill_chunk", prefill, (params, cache, chunk, row, scalar, scalar, slot), ("paged_prefill", "grouped_matmul"))):
+        lowered = jax.jit(fn, donate_argnums=(1,)).trace(*args).lower(lowering_platforms=("tpu",))
+        text = lowered.as_text()
+        for kernel in kernels:
+            assert kernel in text, (name, kernel)
+        assert "gated_delta" not in text  # a tail a slot and no recurrent matrix
+        m = lowered.compile().memory_analysis()
+        assert m.alias_size_in_bytes >= nbytes(cache)  # the pools and the tails are updated where they lie
+        need = m.argument_size_in_bytes + m.temp_size_in_bytes + m.output_size_in_bytes - m.alias_size_in_bytes
+        report[name] = {"arguments_gb": round(m.argument_size_in_bytes / 1e9, 2), "temporaries_gb": round(m.temp_size_in_bytes / 1e9, 3)}
+        assert m.temp_size_in_bytes < 0.6e9, (name, report)  # no expert stack, mixer stack or pool is copied out
+        assert need + nbytes(snaps) < 15.5e9, (name, report)
+    print("lfm2 sizing:", report, "params_gb", round(nbytes(params) / 1e9, 2), "snapshots_gb", round(nbytes(snaps) / 1e9, 3),
+          "cache_gb", round(nbytes(cache) / 1e9, 2), "tails_gb", round(nbytes(cache["conv"]) / 1e9, 4))
+
+
 def _cell_programs(name):
     """A serve cell's decode (or block) step and prefill chunk at the
     benchmark's own configuration, abstract arguments and all, not placed."""
