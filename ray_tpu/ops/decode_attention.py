@@ -52,13 +52,17 @@ first query still sees to its last query's own, causal and windowed by
 position, so a prefill chunk reads the pages it can see and no
 ``[T, capacity]`` score tensor exists.
 
-:func:`latent_paged_decode` and :func:`latent_paged_prefill` are the same
-walk over the **one** pool of a latent attention layer (``[L, num_blocks,
-block_size, lanes]``: a token's normalised latent and the key part every head
-shares, side by side, zero lanes up to whole tiles): a group of cached rows is
-the keys of every head (all its lanes, against queries in the absorbed form)
-and, in its first ``rank`` lanes, the values, so each row is copied once and
-the heads are the rows of one ``QK^T`` and one ``P.V``.
+:func:`latent_paged_decode` and :func:`latent_paged_prefill` walk the **one**
+pool of a latent attention layer (``[L, num_blocks, block_size, lanes]``: a
+token's normalised latent and the key part every head shares, side by side,
+zero lanes up to whole tiles): a group of cached rows is the keys of every
+head (all its lanes, against queries in the absorbed form) and, in its first
+``rank`` lanes, the values, so each row is copied once and the heads are the
+rows of one ``QK^T`` and one ``P.V``. The prefill kernel is on
+:func:`_walk_pages`; the decode kernel, whose 20-KiB pages under one block of
+32 query rows cost as much to ask for as to multiply, has a walk of its own
+that starts a group's copies from inside the products of the group before
+(:func:`_walk_latent_pages`).
 
 No backward pass: decode is inference-only. Non-TPU backends run in
 interpret mode (tests exercise the same code path on CPU).
@@ -760,25 +764,147 @@ def paged_prefill_attention(
 # latent attention: one pool, a row is key and value
 # ---------------------------------------------------------------------------
 # cached rows a group of the decode walk: 64 pages of 16, two slots of 1.25 MiB at 640 lanes. The kernel alone on
-# the chip, 64 rows of 17k cached tokens, a layer: 5.22 ms at 256, 4.18 at 512, 3.77 at 1024, 3.59 at 2048 (the
-# rows' bytes at the chip's bandwidth: 1.70 ms): a group's fixed cost (the waits, the softmax step's rescale of a
-# [32, 512] accumulator) is paid half as often at each doubling, and past 1024 little is left of it
+# the chip, 64 rows of 17k cached tokens, a layer (``scripts/kernel_bench.py --latent-decode``; PR 55): 3.63 ms at
+# 256, 2.65 at 512, 2.11 at 1024, 2.09 at 2048 (as it stood on the shared walk: 5.15 / 4.09 / 3.69 / 3.52; the
+# rows' bytes at the chip's bandwidth: 1.70 ms; their copies alone 1.90; the arithmetic alone 1.10): what a group
+# costs besides its bytes (the softmax step's rescale of a [32, 512] accumulator, the copies that wait for the first
+# piece) is paid half as often at each doubling, and 2048 ties with 1024
 _LATENT_DECODE_SPAN = 1024
 _LATENT_PREFILL_ROWS = 2048  # query rows (queries x heads) a grid step of the prefill kernel
+# the decode walk's copies of the group after this one: the pages started in front of the wait for this one (32 of
+# 20 KiB keep the copy engines fed across it: the copies alone read 2.05 ms at 16, 1.93 at 24, 1.90 at 32 and at 64),
+# then the pages a straight run between two pieces of this one's work, and the cached rows a piece of its ``QK^T``
+# takes (2.11 ms at 32 / 8 / 256; 2.19 at runs of 16, 2.24 at 32; 2.13 at tiles of 512 and 2.28 with all 64 in front)
+_LATENT_COPIES_AHEAD = 32
+_LATENT_COPY_RUN = 8
+_LATENT_KEY_TILE = 256
+
+
+def _walk_latent_pages(tables_ref, row, layer, pool_hbm, buf, sems, block_size, held, work):
+    """The page walk of the latent decode kernel, and of no other: positions
+    ``[0, held)`` of the sequence in table row ``row``, a group of ``span``
+    cached rows at a time, into the two slots of ``buf [2, span, lanes]`` as
+    :func:`_walk_pages` fills them, each visible page by its own copy and no
+    other page fetched. What differs is where the copies stand. A page of one
+    latent pool is 20 KiB under one product of 32 query rows, so a group's 64
+    descriptors and 64 waits, issued in scalar loops in front of its products,
+    cost as much as the products do; here the copies of group ``g + 1`` are
+    started from inside the work of group ``g``, and a group is waited for at
+    once.
+
+    ``work(g, slot)`` is a generator: the group's products and softmax step on
+    ``buf[slot]``, yielding wherever copies may be started. While the group
+    after ``g`` is whole (all its pages visible), its copies go out in straight
+    runs, ``_LATENT_COPIES_AHEAD`` pages in front of the wait for ``g`` and
+    ``_LATENT_COPY_RUN`` at each yield, in one block of code with the
+    products, so that descriptors, MXU passes and the softmax chain share
+    instruction bundles; and ``g``, whole too, is waited for by ONE wait for
+    the slot's bytes (its copies signal one semaphore, which counts bytes). The last
+    whole group and a partial one behind it (or a short sequence's only one)
+    take the plain order: the next group's visible pages started and this
+    one's waited for a page at a time, then the work. Rows of a slot that no
+    copy of this group wrote keep what an earlier group left there; the work
+    masks them by position."""
+    span = buf.shape[1]
+    group = span // block_size
+    page_hi, group_hi, whole = pl.cdiv(held, block_size), pl.cdiv(held, span), held // span
+
+    def copy(page, at, slot):
+        """Page ``page`` of the sequence into rows ``[at, at + block_size)`` of ``buf[slot]``."""
+        return pltpu.make_async_copy(
+            pool_hbm.at[layer, tables_ref[row, page]], buf.at[slot, pl.ds(at, block_size)], sems.at[0, slot])
+
+    def start_run(g, slot, pages):
+        for j in pages:
+            copy(g * group + j, j * block_size, slot).start()
+
+    def visible_pages(g, slot, act):
+        def one_page(page, carry):
+            act(copy(page, pl.multiple_of((page - g * group) * block_size, block_size), slot))
+            return carry
+
+        jax.lax.fori_loop(g * group, jnp.minimum(page_hi, (g + 1) * group), one_page, None)
+
+    def wait_whole(slot):  # every copy into the slot, by the bytes the slot holds
+        pltpu.make_async_copy(buf.at[slot], buf.at[slot], sems.at[0, slot]).wait()
+
+    ahead_of_wait = min(_LATENT_COPIES_AHEAD, group)
+    runs = [range(0, ahead_of_wait)] + [
+        range(j, min(j + _LATENT_COPY_RUN, group)) for j in range(ahead_of_wait, group, _LATENT_COPY_RUN)]
+
+    def beside(g, carry):  # g + 1 is whole, and so is g
+        slot = g % 2
+        start_run(g + 1, 1 - slot, runs[0])
+        wait_whole(slot)
+        ahead = iter(runs[1:])
+        for _ in work(g, slot):
+            start_run(g + 1, 1 - slot, next(ahead, ()))
+        for run in ahead:
+            start_run(g + 1, 1 - slot, run)
+        return carry
+
+    def apart(g, carry):
+        slot = g % 2
+
+        @pl.when(g + 1 < group_hi)
+        def _():
+            visible_pages(g + 1, 1 - slot, lambda c: c.start())
+
+        jax.lax.cond(g < whole, lambda: wait_whole(slot), lambda: visible_pages(g, slot, lambda c: c.wait()))
+        for _ in work(g, slot):
+            pass
+        return carry
+
+    visible_pages(0, 0, lambda c: c.start())
+    last_beside = jnp.maximum(whole - 1, 0)
+    jax.lax.fori_loop(0, last_beside, beside, None)
+    jax.lax.fori_loop(last_beside, group_hi, apart, None)
+
+
+def _latent_group_work(g, slot, q_ref, buf, m_scr, l_scr, acc_scr, held, *, sm_scale: float, rank: int):
+    """Group ``g``'s work of the latent decode kernel on ``buf[slot]``, a
+    generator that yields between the pieces :func:`_walk_latent_pages` starts
+    copies between: ``QK^T`` a tile of ``_LATENT_KEY_TILE`` cached rows at a
+    time, the softmax step on the whole group's masked scores, ``P.V`` a lane
+    tile of the values at a time. The arithmetic is :func:`_softmax_step`'s
+    with :func:`_dot_pv` at one term: a score and an output number are each
+    one product's own, so the tiles change nothing in them."""
+    span = buf.shape[1]
+    tile = min(_LATENT_KEY_TILE, span)
+    pos = g * span + jax.lax.broadcasted_iota(jnp.int32, (1, span), 1)
+    q = q_ref[...]
+    scores = []
+    for t in range(0, span, tile):
+        scores.append(_dot_qk(q, buf[slot, t:t + tile]) * sm_scale)
+        yield
+    s = jnp.where(pos < held, jnp.concatenate(scores, axis=1), NEG_INF)
+    m_prev = m_scr[0, :, :1]
+    l_prev = l_scr[0, :, :1]
+    m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+    alpha = jnp.exp(jnp.where(m_prev == NEG_INF, NEG_INF, m_prev - m_new))
+    p = jnp.exp(s - m_new)
+    l_new = l_prev * alpha + p.sum(axis=-1, keepdims=True)
+    m_scr[0] = jnp.broadcast_to(m_new, m_scr.shape[1:])
+    l_scr[0] = jnp.broadcast_to(l_new, l_scr.shape[1:])
+    yield
+    for n in range(0, rank, _LANES):
+        lanes = slice(n, min(n + _LANES, rank))
+        acc_scr[0, :, lanes] = acc_scr[0, :, lanes] * alpha + _dot_pv(p, buf[slot, :, lanes], terms=1)
+        yield
 
 
 def _latent_decode_kernel(tables_ref, lengths_ref, layer_ref, q_ref, pool_hbm, o_ref, buf, sems, m_scr, l_scr, acc_scr,
                           *, sm_scale: float, block_size: int, rank: int):
     """Grid (B,): a sequence a grid step, its cached rows walked once
-    (:func:`_walk_pages`). q_ref ``[rows, lanes]``: a head a row, the query in
-    the absorbed form (zero rows pad the heads to a sublane tile); a group of
-    cached rows ``[span, lanes]`` is every head's keys, and its first ``rank``
-    lanes every head's values: one ``QK^T``, one softmax step and one ``P.V``
-    a group serve all heads. Operands go to the MXU as stored, ``P`` rounded
-    to the pool's type for one pass (:func:`_dot_pv`); scores and state are
-    float32. o_ref ``[rows, rank]``: ``sum_j a_j c_j`` a head."""
+    (:func:`_walk_latent_pages`). q_ref ``[rows, lanes]``: a head a row, the
+    query in the absorbed form (zero rows pad the heads to a sublane tile); a
+    group of cached rows ``[span, lanes]`` is every head's keys, and its first
+    ``rank`` lanes every head's values: one ``QK^T``, one softmax step and one
+    ``P.V`` a group serve all heads (:func:`_latent_group_work`). Operands go
+    to the MXU as stored, ``P`` rounded to the pool's type for one pass
+    (:func:`_dot_pv`); scores and state are float32. o_ref ``[rows, rank]``:
+    ``sum_j a_j c_j`` a head."""
     bi = pl.program_id(0)
-    span = buf.shape[1]
     held = jnp.minimum(lengths_ref[bi], tables_ref.shape[1] * block_size)
 
     @pl.when(bi == 0)
@@ -788,14 +914,11 @@ def _latent_decode_kernel(tables_ref, lengths_ref, layer_ref, q_ref, pool_hbm, o
     m_scr[...] = jnp.full_like(m_scr, NEG_INF)
     l_scr[...] = jnp.zeros_like(l_scr)
     acc_scr[...] = jnp.zeros_like(acc_scr)
-    one_pass = functools.partial(_dot_pv, terms=1)
 
-    def one_group(g, slot):
-        pos = g * span + jax.lax.broadcasted_iota(jnp.int32, (1, span), 1)
-        s = _dot_qk(q_ref[...], buf[slot]) * sm_scale
-        _softmax_step(jnp.where(pos < held, s, NEG_INF), buf[slot, :, :rank], 0, m_scr, l_scr, acc_scr, dot_pv=one_pass)
+    def work(g, slot):
+        return _latent_group_work(g, slot, q_ref, buf, m_scr, l_scr, acc_scr, held, sm_scale=sm_scale, rank=rank)
 
-    _walk_pages(tables_ref, bi, layer_ref[0], pool_hbm, None, buf, None, sems, block_size, 0, held, one_group)
+    _walk_latent_pages(tables_ref, bi, layer_ref[0], pool_hbm, buf, sems, block_size, held, work)
     o_ref[...] = _finished(m_scr, l_scr, acc_scr, 0).astype(o_ref.dtype)
 
 

@@ -375,12 +375,149 @@ def bench_paged_write(iters=48, pages=1024, layers=4, calls=None) -> Dict[str, d
     return out
 
 
+def _latent_decode_variant(walk: str, work: str, span: int, *, rank: int, scale: float):
+    """``latent_paged_decode`` (jitted, the same arguments) with another walk
+    (``beside``: the module's; ``shared``: :func:`_walk_pages`; ``none``: no
+    copy is issued) around another work a group (``pieces``: the module's;
+    ``one_piece``: ``_softmax_step`` on the whole group; ``no_chain``: the two
+    products, the scores standing in for the probabilities; ``nothing``)."""
+    from jax.experimental import pallas as pl
+
+    from ray_tpu.ops import decode_attention as da
+
+    def kernel(tables_ref, lengths_ref, layer_ref, q_ref, pool_hbm, o_ref, buf, sems, m_scr, l_scr, acc_scr):
+        bi = pl.program_id(0)
+        bs = pool_hbm.shape[2]
+        held = jnp.minimum(lengths_ref[bi], tables_ref.shape[1] * bs)
+        tile = min(da._LATENT_KEY_TILE, span)
+
+        @pl.when(bi == 0)
+        def _():
+            buf[...] = jnp.zeros_like(buf)
+
+        m_scr[...] = jnp.full_like(m_scr, da.NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+        one_pass = functools.partial(da._dot_pv, terms=1)
+
+        def one_piece(g, slot):
+            pos = g * span + jax.lax.broadcasted_iota(jnp.int32, (1, span), 1)
+            s = da._dot_qk(q_ref[...], buf[slot]) * scale
+            da._softmax_step(jnp.where(pos < held, s, da.NEG_INF), buf[slot, :, :rank], 0, m_scr, l_scr, acc_scr,
+                             dot_pv=one_pass)
+            yield
+
+        def no_chain(g, slot):
+            scores = []
+            for t in range(0, span, tile):
+                scores.append(da._dot_qk(q_ref[...], buf[slot, t:t + tile]) * scale)
+                yield
+            s = jnp.concatenate(scores, axis=1)
+            yield
+            for n in range(0, rank, da._LANES):
+                lanes = slice(n, n + da._LANES)
+                acc_scr[0, :, lanes] = acc_scr[0, :, lanes] + one_pass(s, buf[slot, :, lanes])
+                yield
+
+        def nothing(g, slot):  # as many places to start copies at as the module's work has
+            yield from [None] * (span // tile + 1 + rank // da._LANES)
+
+        def pieces(g, slot):
+            return da._latent_group_work(g, slot, q_ref, buf, m_scr, l_scr, acc_scr, held, sm_scale=scale, rank=rank)
+
+        group_work = {"pieces": pieces, "one_piece": one_piece, "no_chain": no_chain, "nothing": nothing}[work]
+
+        def whole(g, slot):
+            for _ in group_work(g, slot):
+                pass
+
+        if walk == "beside":
+            da._walk_latent_pages(tables_ref, bi, layer_ref[0], pool_hbm, buf, sems, bs, held, group_work)
+        elif walk == "shared":
+            da._walk_pages(tables_ref, bi, layer_ref[0], pool_hbm, None, buf, None, sems, bs, 0, held, whole)
+        else:
+            jax.lax.fori_loop(0, pl.cdiv(held, span), lambda g, c: whole(g, g % 2), None)
+        o_ref[...] = da._finished(m_scr, l_scr, acc_scr, 0).astype(o_ref.dtype)
+
+    def call(q, pool, tables, lengths, layer):
+        return da._latent_call(kernel, "latent_paged_decode", [tables, lengths, layer.reshape(1)], q, pool,
+                               grid=(q.shape[0],), rows=q.shape[1], q_index=lambda b, *_: (b, 0, 0), rank=rank, span=span)
+
+    return jax.jit(call)
+
+
+def bench_latent_decode(iters=8, rows=64, heads=32, lanes=640, rank=512, pages=32768, tokens=(16_500, 17_500),
+                        spans=(256, 512, 1024, 2048), seed=0) -> Dict[str, dict]:
+    """The latent decode kernel alone at ``doc-sessions``' shape: ``rows``
+    sequences of 16.5-17.5k cached tokens, tables that name pages anywhere in
+    a pool ``[2, pages, 16, lanes]`` bf16 (sequences share pages, as the
+    cell's sessions share their documents'), a layer. us a call (the
+    custom-call's device time) and ``bytes_share``, the share of it that the
+    visited pages' bytes take at the chip's bandwidth. ``split``, at the
+    module's group of cached rows: ``kernel`` (``latent_paged_decode`` as it
+    is) and ``shared_walk`` (the kernel as it stood on :func:`_walk_pages` and
+    ``_softmax_step``: a group's copies in loops in front of its products),
+    each checked against ``_latent_xla`` (``rel_err``: the largest difference
+    over the largest number); then what computes nothing to check
+    (:func:`_latent_decode_variant`): the module's work on the shared walk,
+    the copies alone of each walk (the work a no-op), the arithmetic alone on
+    whatever the buffers hold (no copy issued; in pieces and in one piece),
+    and the products without the softmax chain between them. ``spans``: both
+    kernels at each group size."""
+    from ray_tpu.ops import decode_attention as da
+
+    bs, scale = 16, 0.05
+    rng = np.random.default_rng(seed)
+    lengths = rng.integers(tokens[0], tokens[1] + 1, size=rows)
+    tables = jnp.asarray(rng.integers(1, pages, size=(rows, -(-tokens[1] // bs) + 1)), jnp.int32)
+    kq, kp = jax.random.split(jax.random.key(seed))
+    real = jnp.arange(lanes) < lanes - 64  # the pool's pad lanes hold zeros
+    pool = jnp.where(real, jax.random.normal(kp, (2, pages, bs, lanes), jnp.bfloat16), 0).astype(jnp.bfloat16)
+    q = jnp.where(real, jax.random.normal(kq, (rows, heads, lanes), jnp.bfloat16), 0).astype(jnp.bfloat16)
+    lens, layer = jnp.asarray(lengths, jnp.int32), jnp.int32(1)
+    args = (q, pool, tables, lens, layer)
+    bytes_us = float(np.sum(-(-lengths // bs))) * bs * lanes * 2 / _HBM_BYTES_PER_S * 1e6
+    variant = functools.partial(_latent_decode_variant, rank=rank, scale=scale)
+
+    def timed(fn) -> dict:
+        us = _kernel_seconds(fn, args, iters) * 1e6
+        return {"us": us, "bytes_share": bytes_us / us}
+
+    def checked(fn) -> dict:
+        got, worst, top = fn(*args), 0.0, 0.0
+        for b in range(0, rows, 8):  # the reference gathers a dense float32 view: eight sequences at a time
+            want = da.latent_paged_decode(q[b:b + 8], pool, tables[b:b + 8], lens[b:b + 8], layer, rank=rank,
+                                          sm_scale=scale, use_kernel=False).astype(jnp.float32)
+            worst = max(worst, float(jnp.abs(got[b:b + 8].astype(jnp.float32) - want).max()))
+            top = max(top, float(jnp.abs(want).max()))
+        return {**timed(fn), "rel_err": worst / top}
+
+    def the_kernel(span):
+        return jax.jit(functools.partial(da.latent_paged_decode, rank=rank, sm_scale=scale, span=span))
+
+    span = da._LATENT_DECODE_SPAN
+    out = {"rows": int(lengths.sum()), "bytes_us": bytes_us, "span": span, "split": {
+        "kernel": checked(the_kernel(span)),
+        "shared_walk": checked(variant("shared", "one_piece", span)),
+        "shared_walk_pieces": timed(variant("shared", "pieces", span)),
+        "copies_alone": timed(variant("beside", "nothing", span)),
+        "shared_walk_copies_alone": timed(variant("shared", "nothing", span)),
+        "arithmetic_alone": timed(variant("none", "pieces", span)),
+        "arithmetic_alone_one_piece": timed(variant("none", "one_piece", span)),
+        "products_alone": timed(variant("none", "no_chain", span)),
+    }}
+    out["spans"] = {str(s): {"kernel": timed(the_kernel(s)), "shared_walk": timed(variant("shared", "one_piece", s))}
+                    for s in spans}
+    return out
+
+
 def main(argv=None) -> None:
     """Examples:
 
         python -m ray_tpu.scripts.kernel_bench                 # decode + 8k/D=128
         python -m ray_tpu.scripts.kernel_bench --flash-cells   # the training cells' calls: time and error
         python -m ray_tpu.scripts.kernel_bench --paged-write   # the serve cells' pool writes: scatter against kernel
+        python -m ray_tpu.scripts.kernel_bench --latent-decode # the latent decode kernel alone, split and group sizes
         python -m ray_tpu.scripts.kernel_bench --T 32768 --D 64 --H 4 --iters 2
         python -m ray_tpu.scripts.kernel_bench --T 8192 --D 64 --iters 4
     """
@@ -398,6 +535,8 @@ def main(argv=None) -> None:
                         help="the flash kernels at the training cells' calls, and the float32-factor probe; nothing else")
     parser.add_argument("--paged-write", action="store_true",
                         help="the write of a call's K and V rows into the paged pools at the serve cells' shapes; nothing else")
+    parser.add_argument("--latent-decode", action="store_true",
+                        help="the latent decode kernel alone at doc-sessions' shape: the split and the group sizes; nothing else")
     args = parser.parse_args(argv)
 
     from ray_tpu.ops import backend
@@ -412,6 +551,10 @@ def main(argv=None) -> None:
     results = {"device": getattr(dev, "device_kind", str(dev))}
     if args.paged_write:
         results.update(bench_paged_write())
+        print(json.dumps(results))
+        return
+    if args.latent_decode:
+        results.update(bench_latent_decode(args.iters))
         print(json.dumps(results))
         return
     if args.flash_cells:

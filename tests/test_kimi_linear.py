@@ -219,6 +219,54 @@ def test_the_latent_decode_kernel_walks_only_live_rows_of_the_one_pool(span):
     assert not bool(got[2].any())  # a table that starts at the garbage page holds no sequence
 
 
+# what a walk that fetches whole groups ahead of their products can get wrong: lengths a row by the group's rows
+# ``span``, then rows whose table is all page 0 (idle), then whether the tables name consecutive pages
+_WALK_CASES = {
+    "whole_groups": (lambda s: (2 * s, s), (), False),
+    "one_row_past_a_group": (lambda s: (2 * s + 1, s + 1), (), False),  # a last group of one row in one page
+    "under_a_page": (lambda s: (5, 1), (), False),
+    "an_idle_row_between_live_ones": (lambda s: (s + 37, 3 * s, 48), (1,), False),
+    "a_long_row_then_a_short_one": (lambda s: (2 * s + 40, 7, s - 1), (), False),  # the buffers still hold the long one's
+    "consecutive_pages": (lambda s: (s + 300, 2 * s - 1), (), True),
+}
+
+
+@pytest.mark.parametrize("span", [256, 512, 1024])
+@pytest.mark.parametrize("case", list(_WALK_CASES))
+def test_the_latent_decode_walk_at_the_edges_of_its_groups(case, span):
+    lengths, idle, consecutive = _WALK_CASES[case]
+    lengths = np.asarray(lengths(span))
+    B, M = len(lengths), -(-int(lengths.max()) // BS) + 1
+    ks = jax.random.split(jax.random.key(span), 2)
+    pool = jax.random.normal(ks[0], (2, B * M + 1, BS, 128)).at[..., 40:].set(0)  # page 0 holds garbage too
+    pages = np.arange(1, B * M + 1)
+    bt = (pages if consecutive else np.random.default_rng(span).permutation(pages)).reshape(B, M)
+    bt[list(idle)] = 0
+    # a table entry past a row's last page may point anywhere: at the garbage page, as the engine's do
+    bt = jnp.asarray(np.where(np.arange(M)[None, :] * BS < lengths[:, None], bt, 0), jnp.int32)
+    q = jax.random.normal(ks[1], (B, 4, 128)).at[..., 40:].set(0)
+    args = (q, pool, bt, jnp.asarray(lengths, jnp.int32), jnp.int32(1))
+    got = latent_paged_decode(*args, rank=32, sm_scale=0.3, span=span)
+    want = latent_paged_decode(*args, rank=32, sm_scale=0.3, use_kernel=False)
+    np.testing.assert_allclose(got, want, atol=2e-6)
+    assert np.isfinite(got).all() and not np.asarray(got)[list(idle)].any()
+
+
+@pytest.mark.parametrize("walk,work", [("shared", "one_piece"), ("shared", "pieces"), ("beside", "pieces")])
+def test_the_kernel_benchs_stand_ins_that_compute_a_result_compute_the_kernels(walk, work):
+    """``scripts/kernel_bench.py --latent-decode`` times the kernel as it stood on
+    the shared walk, and the module's work on either walk: each is the kernel."""
+    from ray_tpu.scripts.kernel_bench import _latent_decode_variant
+
+    ks = jax.random.split(jax.random.key(5), 2)
+    pool = jax.random.normal(ks[0], (2, 60, BS, 128)).at[..., 40:].set(0)
+    bt = jnp.asarray(np.random.default_rng(5).permutation(np.arange(1, 60))[:58].reshape(2, 29), jnp.int32)
+    q = jax.random.normal(ks[1], (2, 8, 128)).at[..., 40:].set(0)
+    args = (q, pool, bt, jnp.array([450, 300], jnp.int32), jnp.int32(1))
+    got = _latent_decode_variant(walk, work, 128, rank=32, scale=0.3)(*args)
+    np.testing.assert_allclose(got, latent_paged_decode(*args, rank=32, sm_scale=0.3, use_kernel=False), atol=2e-6)
+
+
 # ---------------------------------------------------------------------------
 # the model against the reference
 # ---------------------------------------------------------------------------
